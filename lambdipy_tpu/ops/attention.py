@@ -8,7 +8,7 @@ execution is sequential, so the f32 scratch accumulators carry across k
 steps and are finalized on the last one.
 
 The pure-jax `mha_reference` is the numerics oracle (tests run the kernel
-in interpret mode against it) and the CPU fallback.
+in interpret mode against it) and what the model takes off the TPU.
 """
 
 from __future__ import annotations
@@ -83,13 +83,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention(q, k, v, *, causal: bool = False, scale: float | None = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool | None = None):
+                    interpret: bool = False):
     """Flash attention. q/k/v: [b, s, h, d]; kv heads broadcast for GQA.
-    Falls back to the reference when shapes don't tile (tiny test configs).
-    ``interpret=None`` auto-selects interpret mode on the CPU backend
-    (Mosaic compiles only for TPU)."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    Sequence lengths must tile by the (clamped) block sizes — raises
+    ``ValueError`` otherwise. ``interpret=True`` runs the Pallas
+    interpreter instead of compiling for the chip (tests)."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
     kvh = k.shape[2]
@@ -101,7 +99,9 @@ def flash_attention(q, k, v, *, causal: bool = False, scale: float | None = None
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     if sq % block_q or sk % block_k:
-        return mha_reference(q, k, v, causal=causal, scale=scale)
+        raise ValueError(
+            f"flash_attention: sequence lengths ({sq}, {sk}) do not tile by "
+            f"blocks ({block_q}, {block_k})")
 
     # [b, s, h, d] -> [b*h, s, d]
     def fold(x):

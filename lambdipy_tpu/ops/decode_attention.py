@@ -26,15 +26,14 @@ the repo's contiguous static cache):
   index maps and dequantize in VMEM right before the dot.
 
 The pure-jax :func:`decode_attention_reference` is the numerics oracle
-and the CPU fallback. Its math mirrors ``models/llama.py _attend``
+and what runs off the TPU. Its math mirrors ``models/llama.py _attend``
 operation for operation (same einsums, same f32 ``/ sqrt(d)`` scaling,
 same ``-1e9`` mask fill), so with a float KV cache its output is
 BITWISE the dense decode path's — the parity the blocked backend's
 on/off tests assert. ``decode_attention`` is the dispatcher the model
-layer calls: the kernel on TPU when shapes tile, the reference
-everywhere else (an interpret-mode Pallas call per decode-scan step
-would crawl on CPU; tests exercise the kernel explicitly via
-``interpret=True``).
+layer calls: the kernel on a TPU backend (a shape it cannot tile raises
+there), the reference on any other (Mosaic compiles only for the TPU;
+tests exercise the kernel explicitly via ``interpret=True``).
 """
 
 from __future__ import annotations
@@ -159,7 +158,7 @@ def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 
 def blocked_decode_attention(q, k, v, active_len, *, k_scale=None,
                              v_scale=None, scale=None, block_k: int = 128,
-                             interpret: bool | None = None):
+                             interpret: bool = False):
     """The Pallas blocked decode kernel. q: [b, 1, h, d]; k/v:
     [b, t, kvh, d] (float, or int8 with ``k_scale``/``v_scale``
     [b, t, kvh, 1] f32); active_len: [b] int32, PER-ROW >= 1 — a decode
@@ -167,11 +166,9 @@ def blocked_decode_attention(q, k, v, active_len, *, k_scale=None,
     model passes ``index + 1``), and the kernel relies on that: at
     ``active_len = 0`` no block ever computes, so the finalize would
     emit exact zeros where the reference emits the uniform-softmax mean
-    of V. Falls back to the reference when shapes don't tile
-    (t % block_k, or a multi-token q). ``interpret=None`` auto-selects
-    interpret mode on the CPU backend."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    of V. Raises ``ValueError`` for a multi-token q or a window that
+    does not tile (``t % block_k``). ``interpret=True`` runs the Pallas
+    interpreter instead of compiling for the chip (tests)."""
     b, s, h, d = q.shape
     t = k.shape[1]
     kvh = k.shape[2]
@@ -179,11 +176,9 @@ def blocked_decode_attention(q, k, v, active_len, *, k_scale=None,
     quant = k_scale is not None
     block_k = min(block_k, t)
     if s != 1 or t % block_k:
-        kd, vd = k, v
-        if quant:
-            kd = k.astype(q.dtype) * k_scale.astype(q.dtype)
-            vd = v.astype(q.dtype) * v_scale.astype(q.dtype)
-        return decode_attention_reference(q, kd, vd, active_len, scale=scale)
+        raise ValueError(
+            f"blocked_decode_attention: needs a single-token q and a window "
+            f"that tiles by block_k={block_k}; got s={s}, t={t}")
     scale = float(d ** -0.5 if scale is None else scale)
     nk = t // block_k
 
@@ -280,19 +275,21 @@ def paged_decode_attention_reference(q, k_pages, v_pages, block_tables,
 
 
 def _paged_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
-                  l_ref, acc_ref, *, page: int, scale: float,
+                  l_ref, acc_ref, *, page: int, kvh: int, scale: float,
                   quant: bool, ks_ref=None, vs_ref=None):
-    """One (row x kv-head, kv-page) grid step of the paged kernel: the
-    same online-softmax math as ``_decode_kernel``, with the K/V block
-    fetched through the row's BLOCK TABLE instead of a contiguous
-    offset. The table itself is consumed ONLY by the ``kv_index``
-    BlockSpec maps (scalar prefetch) — inside the kernel body the
-    indirection is already done, so only the shapes differ (refs carry
-    a singleton kv-head axis cut from the arena)."""
-    bh = pl.program_id(0)
+    """One (row, kv-page) grid step of the paged kernel: the same
+    online-softmax math as ``_decode_kernel``, with the K/V block fetched
+    through the row's BLOCK TABLE instead of a contiguous offset. The
+    table itself is consumed ONLY by the ``kv_index`` BlockSpec maps
+    (scalar prefetch) — inside the kernel body the indirection is
+    already done. A block is one whole arena page, ALL kv heads of it
+    (the arena's own layout: the compiler takes the last two block dims
+    only whole or in (8, 128) tiles, and one head of ``[page, kvh, d]``
+    is neither), so the body walks the kv heads itself."""
+    r = pl.program_id(0)
     ki = pl.program_id(1)
     nk = pl.num_programs(1)
-    alen = lens_ref[bh]
+    alen = lens_ref[r]
 
     @pl.when(ki == 0)
     def _init():
@@ -302,29 +299,30 @@ def _paged_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
 
     @pl.when(ki * page < alen)
     def _compute():
-        q = q_ref[0]           # [group, d]
-        k = k_ref[0, :, 0, :]  # [page, d]
-        v = v_ref[0, :, 0, :]
-        if quant:
-            k = k.astype(jnp.float32) * ks_ref[0, :, 0, :].astype(jnp.float32)
-            v = v.astype(jnp.float32) * vs_ref[0, :, 0, :].astype(jnp.float32)
-            k = k.astype(q.dtype)
-            v = v.astype(q.dtype)
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [group, page]
-        pos = ki * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < alen, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        for j in range(kvh):
+            q = q_ref[0, j]        # [group, d]
+            k = k_ref[0, :, j, :]  # [page, d]
+            v = v_ref[0, :, j, :]
+            if quant:
+                ks = ks_ref[0, :, j, :].astype(jnp.float32)
+                vs = vs_ref[0, :, j, :].astype(jnp.float32)
+                k = (k.astype(jnp.float32) * ks).astype(q.dtype)
+                v = (v.astype(jnp.float32) * vs).astype(q.dtype)
+            s = jax.lax.dot_general(
+                q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [group, page]
+            pos = ki * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(pos < alen, s, NEG_INF)
+            m_prev = m_ref[j]  # [group, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[j] = l_ref[j] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            m_ref[j] = m_new
+            acc_ref[j] = acc_ref[j] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -335,25 +333,23 @@ def _paged_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
 def paged_blocked_decode_attention(q, k_pages, v_pages, block_tables,
                                    active_len, *, k_scale_pages=None,
                                    v_scale_pages=None, scale=None,
-                                   interpret: bool | None = None):
+                                   interpret: bool = False):
     """The Pallas PAGED decode kernel: the length-aware blocked kernel
     with the contiguous clamp in its K/V index maps replaced by a BLOCK
-    TABLE lookup riding scalar-prefetch — each (row x kv-head, page)
-    program DMAs exactly the arena page its table names, so a row's KV
-    never has to be contiguous (and prefix pages shared between rows
-    are fetched from one physical location). Shapes as
+    TABLE lookup riding scalar-prefetch — each (row, page) program DMAs
+    exactly the arena page its table names, so a row's KV never has to
+    be contiguous (and prefix pages shared between rows are fetched from
+    one physical location). Shapes as
     :func:`paged_decode_attention_reference`; q must be single-token
-    ([b, 1, h, d]). Past-the-length pages clamp to the row's LAST
-    active table entry — consecutive identical page ids elide the DMA,
-    the same early-exit economics as the contiguous kernel."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    ([b, 1, h, d]) — raises ``ValueError`` otherwise. Past-the-length
+    pages clamp to the row's LAST active table entry — consecutive
+    identical page ids elide the DMA, the same early-exit economics as
+    the contiguous kernel. ``interpret=True`` runs the Pallas interpreter
+    instead of compiling for the chip (tests)."""
     b, s, h, d = q.shape
     if s != 1:
-        return paged_decode_attention_reference(
-            q, k_pages, v_pages, block_tables, active_len,
-            k_scale_pages=k_scale_pages, v_scale_pages=v_scale_pages,
-            scale=scale)
+        raise ValueError(
+            f"paged_blocked_decode_attention: single-token q only, got s={s}")
     page = k_pages.shape[1]
     kvh = k_pages.shape[2]
     group = h // kvh
@@ -361,29 +357,31 @@ def paged_blocked_decode_attention(q, k_pages, v_pages, block_tables,
     quant = k_scale_pages is not None
     scale = float(d ** -0.5 if scale is None else scale)
 
-    qf = q.reshape(b, kvh, group, d).reshape(b * kvh, group, d)
-    lens = jnp.repeat(jnp.asarray(active_len, jnp.int32).reshape(b), kvh)
+    qf = q.reshape(b, kvh, group, d)
+    lens = jnp.asarray(active_len, jnp.int32).reshape(b)
     tables = jnp.asarray(block_tables, jnp.int32)
 
-    def kv_index(bh, ki, lens_ref, tables_ref):
+    def kv_index(r, ki, lens_ref, tables_ref):
         # the paged indirection: the page COORDINATE comes from the
         # row's table, clamped to its last active entry so inactive
         # grid steps re-address the previous page (DMA elided) exactly
         # like the contiguous kernel's clamp
-        last = jnp.maximum((lens_ref[bh] + page - 1) // page - 1, 0)
-        pid = tables_ref[bh // kvh, jnp.minimum(ki, last)]
-        return (pid, 0, bh % kvh, 0)
+        last = jnp.maximum((lens_ref[r] + page - 1) // page - 1, 0)
+        return (tables_ref[r, jnp.minimum(ki, last)], 0, 0, 0)
+
+    def row_index(r, ki, lens_ref, tables_ref):
+        return (r, 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, group, d), lambda bh, ki, lens, tabs: (bh, 0, 0)),
-        pl.BlockSpec((1, page, 1, d), kv_index),
-        pl.BlockSpec((1, page, 1, d), kv_index),
+        pl.BlockSpec((1, kvh, group, d), row_index),
+        pl.BlockSpec((1, page, kvh, d), kv_index),
+        pl.BlockSpec((1, page, kvh, d), kv_index),
     ]
     operands = [qf, k_pages, v_pages]
     if quant:
         in_specs += [
-            pl.BlockSpec((1, page, 1, 1), kv_index),
-            pl.BlockSpec((1, page, 1, 1), kv_index),
+            pl.BlockSpec((1, page, kvh, 1), kv_index),
+            pl.BlockSpec((1, page, kvh, 1), kv_index),
         ]
         operands += [k_scale_pages, v_scale_pages]
 
@@ -396,45 +394,44 @@ def paged_blocked_decode_attention(q, k_pages, v_pages, block_tables,
             ks_ref, vs_ref = None, None
             o_ref, m_ref, l_ref, acc_ref = rest
         _paged_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
-                      m_ref, l_ref, acc_ref, page=page,
+                      m_ref, l_ref, acc_ref, page=page, kvh=kvh,
                       scale=scale, quant=quant, ks_ref=ks_ref,
                       vs_ref=vs_ref)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b * kvh, nb),
+        grid=(b, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, group, d),
-                               lambda bh, ki, lens, tabs: (bh, 0, 0)),
+        out_specs=pl.BlockSpec((1, kvh, group, d), row_index),
         scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, d), jnp.float32),
+            pltpu.VMEM((kvh, group, 1), jnp.float32),
+            pltpu.VMEM((kvh, group, 1), jnp.float32),
+            pltpu.VMEM((kvh, group, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((b * kvh, group, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, group, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
     )(lens, tables, *operands)
-    return out.reshape(b, kvh, group, d).reshape(b, 1, h, d)
+    return out.reshape(b, 1, h, d)
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, active_len,
                            *, k_scale_pages=None, v_scale_pages=None,
-                           scale=None, interpret: bool | None = None):
+                           scale=None):
     """Backend dispatcher for paged decode attention, mirroring
-    :func:`decode_attention`: the block-table kernel on TPU for
-    single-token steps, the gather-then-dense reference everywhere else
-    (bitwise the dense path on float KV — the runtime's paged engine
-    gathers through the same tables, so the two agree by
-    construction)."""
-    if jax.default_backend() == "tpu" and q.shape[1] == 1:
+    :func:`decode_attention`: the block-table kernel on a TPU backend
+    (single-token steps only — anything else raises there), the
+    gather-then-dense reference on any other (bitwise the dense path on
+    float KV — the runtime's paged engine gathers through the same
+    tables, so the two agree by construction)."""
+    if jax.default_backend() == "tpu":
         return paged_blocked_decode_attention(
             q, k_pages, v_pages, block_tables, active_len,
             k_scale_pages=k_scale_pages, v_scale_pages=v_scale_pages,
-            scale=scale, interpret=interpret)
+            scale=scale)
     return paged_decode_attention_reference(
         q, k_pages, v_pages, block_tables, active_len,
         k_scale_pages=k_scale_pages, v_scale_pages=v_scale_pages,
@@ -442,21 +439,21 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, active_len,
 
 
 def decode_attention(q, k, v, active_len, *, k_scale=None, v_scale=None,
-                     scale=None, block_k: int = 128,
-                     interpret: bool | None = None):
+                     scale=None, block_k: int = 128):
     """Backend dispatcher for the ``attn_backend="blocked"`` decode path.
 
-    On TPU with tileable shapes: the blocked kernel (real early-exit —
-    bytes scale with ``active_len``). Everywhere else: the pure-jax
-    reference, whose output is bitwise the dense path's on float KV —
-    the byte win on the XLA path comes from the runtime's window
-    bucketing instead (``runtime/continuous.py``), which shrinks ``t``
-    itself. Inputs/shapes as :func:`blocked_decode_attention`."""
-    if jax.default_backend() == "tpu" and q.shape[1] == 1 \
-            and k.shape[1] % min(block_k, k.shape[1]) == 0:
+    On a TPU backend: the blocked kernel (real early-exit — bytes scale
+    with ``active_len``); a multi-token q or a window that does not tile
+    raises there, it is never served by the reference under the
+    kernel's name. On any other backend: the pure-jax reference, whose
+    output is bitwise the dense path's on float KV — the byte win on the
+    XLA path comes from the runtime's window bucketing instead
+    (``runtime/continuous.py``), which shrinks ``t`` itself.
+    Inputs/shapes as :func:`blocked_decode_attention`."""
+    if jax.default_backend() == "tpu":
         return blocked_decode_attention(
             q, k, v, active_len, k_scale=k_scale, v_scale=v_scale,
-            scale=scale, block_k=block_k, interpret=interpret)
+            scale=scale, block_k=block_k)
     if k_scale is not None:
         k = k.astype(q.dtype) * k_scale.astype(q.dtype)
         v = v.astype(q.dtype) * v_scale.astype(q.dtype)

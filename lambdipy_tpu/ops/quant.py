@@ -8,8 +8,8 @@ math, and the fp32 accumulator is scaled once at finalize. Grid is
 sequential, so the f32 scratch accumulator carries across k steps (same
 pattern as ops/attention.py).
 
-The pure-jax ``int8_matmul_reference`` is the numerics oracle and the
-CPU/odd-shape fallback.
+The pure-jax ``int8_matmul_reference`` is the numerics oracle and what the
+model takes off the TPU.
 """
 
 from __future__ import annotations
@@ -47,18 +47,21 @@ def _kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, n_k: int):
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
                                              "interpret"))
 def int8_matmul(x, w_i8, scale, *, block_m: int = 128, block_n: int = 128,
-                block_k: int = 128, interpret: bool | None = None):
-    """Blocked int8-weight matmul. Falls back to the reference when shapes
-    don't tile (serving decode has m as small as 1) or on CPU without
-    interpret mode. ``interpret=None`` auto-selects interpret on CPU."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+                block_k: int = 128, interpret: bool = False):
+    """Blocked int8-weight matmul. ``m`` is clamped to one block when it
+    is smaller (serving decode has m as small as 1); a shape that then
+    does not tile raises ``ValueError``. ``interpret=True`` runs the
+    Pallas interpreter instead of compiling for the chip (tests)."""
     m, k = x.shape
     k2, n = w_i8.shape
-    assert k == k2 and scale.shape == (1, n), (x.shape, w_i8.shape, scale.shape)
+    if k != k2 or scale.shape != (1, n):
+        raise ValueError(f"int8_matmul: operand shapes disagree: x {x.shape}, "
+                         f"w {w_i8.shape}, scale {scale.shape}")
     block_m = min(block_m, m)
     if m % block_m or n % block_n or k % block_k:
-        return int8_matmul_reference(x, w_i8, scale)
+        raise ValueError(
+            f"int8_matmul: ({m}, {k}) x ({k}, {n}) does not tile by blocks "
+            f"({block_m}, {block_k}, {block_n})")
     n_k = k // block_k
     return pl.pallas_call(
         functools.partial(_kernel, n_k=n_k),

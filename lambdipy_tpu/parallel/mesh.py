@@ -17,7 +17,6 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import math
-import warnings
 
 import jax
 import numpy as np
@@ -41,54 +40,18 @@ def use_mesh(mesh: Mesh):
         _ACTIVE_MESH.reset(token)
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: the public alias only
-    landed after 0.4.x; this image's 0.4.37 still spells it
-    ``jax.experimental.shard_map.shard_map``. One shim so the manual-
-    collective modules (ring / spdecode / pipeline) run on both."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as sm_exp
-
-    # check_rep=False: the replication checker predates several of the
-    # collective patterns used here (psum_scatter in rings, gathered
-    # masks) and rejects valid programs on 0.4.x; the new jax path
-    # applies its own (correct) checking by default
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
 def pcast_varying(x, axes):
-    """``jax.lax.pcast(x, axes, to="varying")`` where it exists (the
-    post-0.4.x vma tracker needs carries marked device-varying), identity
-    on 0.4.x — whose shard_map (``check_rep=False`` via
-    :func:`shard_map_compat`) tracks no varying types to satisfy.
-    Axes the value already varies over are filtered out (pcast rejects
-    re-marking them); the ONE home of this compat logic for ring,
-    spdecode and pipeline."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None or not axes:
-        return x
-    have = getattr(jax.typeof(x), "vma", frozenset())
-    need = tuple(a for a in axes if a not in have)
-    return pcast(x, need, to="varying") if need else x
+    """Mark ``x`` device-varying over ``axes`` (shard_map's vma tracker
+    needs scan carries typed the way the loop body leaves them). Axes the
+    value already varies over are filtered out — pcast rejects re-marking
+    them; the ONE home of that rule for ring, spdecode and pipeline."""
+    need = tuple(a for a in axes if a not in jax.typeof(x).vma)
+    return jax.lax.pcast(x, need, to="varying") if need else x
 
 
 def current_mesh() -> Mesh | None:
-    """The ambient mesh: ours first, then jax's legacy with-mesh context."""
-    mesh = _ACTIVE_MESH.get()
-    if mesh is not None:
-        return mesh
-    try:
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            from jax.interpreters import pxla
-
-            phys = pxla.thread_resources.env.physical_mesh
-        return phys if phys.axis_names else None
-    except Exception:
-        return None
+    """The ambient mesh, as entered through :func:`use_mesh`."""
+    return _ACTIVE_MESH.get()
 
 
 def make_mesh(shape: dict[str, int], devices=None) -> Mesh:

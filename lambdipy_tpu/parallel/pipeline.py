@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from lambdipy_tpu.parallel.mesh import shard_map_compat
 
 from lambdipy_tpu.parallel.sharding import no_shard_hints
 
@@ -115,7 +114,7 @@ def pipeline_apply(stage_fn, stacked_params, microbatches, mesh: Mesh, *,
     x_spec = P(None, batch_axes if batch_axes else None)
     params_specs = jax.tree_util.tree_map(lambda _: P(axis), stacked_params)
     const_specs = jax.tree_util.tree_map(lambda _: P(), const)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         partial(_pipeline_local, stage_fn=stage_fn, axis_name=axis,
                 vary_axes=batch_axes + (axis,)),
         mesh=mesh,

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from lambdipy_tpu.parallel.mesh import shard_map_compat
 
 NEG_INF = -1e30
 
@@ -67,7 +66,7 @@ def _ring_attention_local(q, k, v, km=None, *, axis_name: str, causal: bool,
 
     # mark the initial accumulators as varying over the ring axis so the
     # scan carry type matches its device-varying outputs (jax vma
-    # tracking; identity on 0.4.x, which tracks none)
+    # tracking)
     def varying(x):
         from lambdipy_tpu.parallel.mesh import pcast_varying
 
@@ -170,7 +169,7 @@ def sp_chunk_attention(q, k, v, mask, mesh: Mesh, *, axis: str = "sp",
     mspec = P(bspec, axis, None)
     local = partial(_sp_chunk_local, nblocks=sp, scale=scale,
                     vary_axes=batch_axes + (axis,))
-    fn = shard_map_compat(local, mesh=mesh,
+    fn = jax.shard_map(local, mesh=mesh,
                           in_specs=(qspec, kspec, kspec, mspec),
                           out_specs=qspec)
     return fn(q, k, v, mask)
@@ -197,9 +196,9 @@ def ring_attention(q, k, v, mesh: Mesh, *, axis: str = "sp",
                     scale=scale, vary_axes=batch_axes + (axis,))
     if kv_mask is not None:
         mspec = P(batch_axes if batch_axes else None, axis)
-        fn = shard_map_compat(local, mesh=mesh,
+        fn = jax.shard_map(local, mesh=mesh,
                            in_specs=(spec, spec, spec, mspec), out_specs=spec)
         return fn(q, k, v, kv_mask)
-    fn = shard_map_compat(local, mesh=mesh, in_specs=(spec, spec, spec),
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
                        out_specs=spec)
     return fn(q, k, v)

@@ -38,7 +38,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from lambdipy_tpu.parallel.mesh import shard_map_compat
 from lambdipy_tpu.utils.logs import get_logger
 
 log = get_logger("lambdipy.spdecode")
@@ -201,7 +200,7 @@ def sp_decode_step(q, store_new: dict, cache: dict, index, mesh: Mesh,
     quant = "k_int8" in cache
     local = partial(_sp_decode_local, axis_name=axis, scale=scale,
                     quant=quant)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(rep, {name: rep for name in store_new},
                   {name: cspec for name in cache}, ispec),

@@ -20,10 +20,9 @@ declares
 from __future__ import annotations
 
 import re
+import tomllib
 from dataclasses import dataclass, field
 from pathlib import Path
-
-from lambdipy_tpu.utils.toml_compat import tomllib
 
 SCHEMA_VERSION = 1
 

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import importlib.metadata
 import json
+import tomllib
 from dataclasses import dataclass
 from pathlib import Path
 
@@ -23,7 +24,6 @@ from packaging.utils import canonicalize_name
 from packaging.version import Version
 
 from lambdipy_tpu.recipes.store import RecipeStore
-from lambdipy_tpu.utils.toml_compat import tomllib
 
 
 class ResolutionError(ValueError):

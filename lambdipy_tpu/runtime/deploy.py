@@ -22,6 +22,7 @@ from pathlib import Path
 
 from lambdipy_tpu.utils.fsutil import atomic_write_text
 from lambdipy_tpu.utils.logs import get_logger, log_event
+from lambdipy_tpu.utils.platform import child_env
 
 log = get_logger("lambdipy.deploy")
 
@@ -80,7 +81,10 @@ class LocalRuntime:
         (SURVEY.md §6 failure-detection row): a crashed server is respawned
         on the same port with backoff, so the deployment URL self-heals.
         ``ready_timeout`` is generous because cold start includes PJRT init
-        + first compile on a cold compile cache (BASELINE.md ~10 s floor).
+        + first compile on a cold compile cache. The server's environment
+        is built by ``utils.platform.child_env``: the caller's
+        LAMBDIPY_PLATFORM pin is NOT inherited — pass it in ``env`` to pin
+        the server too.
         """
         bundle_dir = Path(bundle_dir).resolve()
         state = self._load()
@@ -101,19 +105,14 @@ class LocalRuntime:
         module = ("lambdipy_tpu.runtime.supervisor" if watchdog
                   else "lambdipy_tpu.runtime.server")
         cmd = [sys.executable, "-m", module, str(bundle_dir), str(port)]
-        full_env = dict(os.environ)
-        full_env.update(env or {})
-        # the framework itself must be importable in the server process
-        repo_root = str(Path(__file__).resolve().parents[2])
-        full_env["PYTHONPATH"] = os.pathsep.join(
-            [repo_root] + [p for p in full_env.get("PYTHONPATH", "").split(os.pathsep) if p])
         # server stderr goes to a per-deployment log so a boot failure is
         # diagnosable (`serve.log` beside the state file)
         log_path = self.state_path.parent / f"{name}.serve.log"
         log_path.parent.mkdir(parents=True, exist_ok=True)
         stderr_f = open(log_path, "w")
         proc = subprocess.Popen(cmd, stdout=subprocess.PIPE, stderr=stderr_f,
-                                text=True, env=full_env, start_new_session=True)
+                                text=True, env=child_env(env),
+                                start_new_session=True)
         stderr_f.close()
 
         def _log_tail() -> str:

@@ -45,6 +45,7 @@ from lambdipy_tpu.sched import (
     set_request_context,
 )
 from lambdipy_tpu.utils.logs import get_logger, log_event
+from lambdipy_tpu.utils.platform import device_report
 
 log = get_logger("lambdipy.server")
 
@@ -299,6 +300,11 @@ class BundleServer:
                         "bundle": str(server_self.bundle_dir),
                         "uptime_s": round(time.time() - server_self.started, 1),
                         "cold_start": server_self.boot.stages,
+                        # what THIS process runs on, from jax.devices()
+                        # (None for non-jax bundles): the one place a
+                        # parent that must stay off the chip can check
+                        # that its server is on it
+                        "device": server_self.boot.device,
                         "skew": server_self.boot.skew,
                         "handler_meta": getattr(server_self.boot.state, "meta", {}),
                         # build-time warm outcome from the manifest: a
@@ -319,6 +325,13 @@ class BundleServer:
                                             lambda: {})()
                     if handler_stats:
                         report["handler"] = handler_stats
+                    if server_self.boot.device is not None:
+                        # live allocator statistics per device, and the
+                        # process's compile requests / persistent-cache
+                        # hits since boot
+                        report["device"] = device_report()
+                        report["compile"] = \
+                            server_self.boot.compile_counters.report()
                     self._send(200, report)
                 else:
                     self._send(404, {"ok": False, "error": "not found"})
@@ -1272,6 +1285,7 @@ class BundleServer:
             time.sleep(0.02)
         self._httpd.shutdown()
         self._httpd.server_close()
+        self.boot.close()
 
 
 def main(argv=None) -> int:

@@ -1,12 +1,11 @@
 """Build-time bundle warming: pre-populate the persistent compile cache.
 
-Cold start is interpreter + PJRT init + first compile (BASELINE.md: ~10 s
-floor measured, first jit 0.67 s for a trivial op, tens of seconds for real
-models). The builder runs this module as a subprocess against the freshly
-assembled bundle (same interpreter/platform as the serve runtime), so the
-XLA compilation cache the bundle ships is already hot and the serve boot's
-"first" compile is a cache hit — SURVEY.md §9.6: "persistent compilation
-cache shipped *inside* the bundle".
+Cold start is interpreter + PJRT init + first compile (tens of seconds for
+real models). The builder runs this module as a subprocess against the
+freshly assembled bundle (same interpreter/platform as the serve runtime),
+so the XLA compilation cache the bundle ships is already hot and the serve
+boot's "first" compile is a cache hit — SURVEY.md §9.6: "persistent
+compilation cache shipped *inside* the bundle".
 
 Usage: ``python -m lambdipy_tpu.runtime.warm <bundle_dir>``
 (honors LAMBDIPY_PLATFORM like the server).
@@ -25,13 +24,35 @@ def warm_bundle(bundle_dir: Path) -> dict:
 
     t0 = time.monotonic()
     report = load_bundle(Path(bundle_dir), warmup=True)
-    out = {
-        "warmed": True,
-        "wall_s": round(time.monotonic() - t0, 2),
-        "stages": report.stages,
-        "cache_entries": sum(1 for _ in (Path(bundle_dir) / "compile_cache").rglob("*")
-                             if _.is_file()) if (Path(bundle_dir) / "compile_cache").is_dir() else 0,
-    }
+    try:
+        # the warmup invoke releases the handler's background warm (listed
+        # buckets, the engine's group-prefill programs) on a daemon
+        # thread. Wait it out: those compiles belong in the cache this
+        # step exists to fill, and an interpreter that exits while a
+        # thread is still inside an XLA compile aborts (rc 134). The
+        # builder's warm timeout bounds the wait.
+        warming = getattr(report.state, "warming_fn", None)
+        while warming is not None and warming():
+            time.sleep(0.1)
+        stats = getattr(report.state, "stats", dict)()
+        background = stats.get("warm_buckets") or {}
+        cache_dir = report.compile_cache_dir
+        out = {
+            "warmed": not background.get("errors"),
+            "background_warm": background,
+            "wall_s": round(time.monotonic() - t0, 2),
+            "stages": report.stages,
+            "device": report.device,
+            "compile": (report.compile_counters.report()
+                        if report.compile_counters is not None else None),
+            "cache_dir": str(cache_dir) if cache_dir is not None else None,
+            "cache_entries": (sum(1 for f in cache_dir.rglob("*")
+                                  if f.is_file())
+                              if cache_dir is not None and cache_dir.is_dir()
+                              else 0),
+        }
+    finally:
+        report.close()
     return out
 
 
@@ -43,7 +64,12 @@ def main(argv=None) -> int:
     from lambdipy_tpu.utils.platform import apply_platform_override
 
     apply_platform_override()
-    print(json.dumps(warm_bundle(Path(argv[0]))), flush=True)
+    out = warm_bundle(Path(argv[0]))
+    print(json.dumps(out), flush=True)
+    if not out["warmed"]:
+        print(f"background warm failed: {out['background_warm']['errors']}",
+              file=sys.stderr)
+        return 1
     return 0
 
 

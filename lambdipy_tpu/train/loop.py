@@ -175,7 +175,9 @@ class Trainer:
             self._eval_fn = eval_loss
 
         total = 0.0
-        with self.mesh:
+        from lambdipy_tpu.parallel.mesh import use_mesh
+
+        with use_mesh(self.mesh):
             for _ in range(batches):
                 batch = eval_loader.place(eval_loader.next_batch(), self.mesh,
                                           self.batch_sharding)

@@ -1,0 +1,94 @@
+"""Where JAX's persistent compilation cache lives — decided in one place.
+
+The directory is part of how a cached program is found again, so a cache
+written under one path and read under another never hits. Hence:
+
+- ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and the program
+  sets no directory in code at all — the operator (or the machine) owns
+  the placement.
+- unset, with a bundle: ``<bundle>/compile_cache``, shipped warm by the
+  builder (``lambdipy build`` warms the bundle at the path it is served
+  from).
+- unset, no bundle (bench stages, measurement scripts): one fixed
+  directory inside the checkout — never a temporary, per-process or home
+  directory.
+"""
+
+from __future__ import annotations
+
+import os
+from pathlib import Path
+
+from lambdipy_tpu.utils.platform import REPO_ROOT
+
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT_CACHE = REPO_ROOT / ".lambdipy_cache" / "compile"
+
+
+def compile_cache_dir(bundle_dir: Path | None = None) -> Path | None:
+    """The directory this program would set in code, or None when the
+    environment already places the cache."""
+    if os.environ.get(CACHE_ENV):
+        return None
+    if bundle_dir is not None:
+        return Path(bundle_dir) / "compile_cache"
+    return CHECKOUT_CACHE
+
+
+def enable_compile_cache(bundle_dir: Path | None = None) -> Path:
+    """Turn the persistent cache on for every program, however small or
+    quick to compile, and return the directory in force."""
+    import jax
+
+    cache_dir = compile_cache_dir(bundle_dir)
+    if cache_dir is not None:
+        cache_dir.mkdir(parents=True, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return cache_dir or Path(os.environ[CACHE_ENV])
+
+
+class CompileCounters:
+    """Counts the XLA compile requests made in this process while the
+    object is open, and how many of them the persistent cache answered,
+    from jax's own monitoring events. The owner calls :meth:`close`."""
+
+    _REQUEST = "/jax/core/compile/backend_compile_duration"
+    _HIT = "/jax/compilation_cache/cache_hits"
+
+    def __init__(self):
+        import threading
+
+        import jax.monitoring
+
+        self._lock = threading.Lock()
+        self.requests = 0
+        self.request_s = 0.0
+        self.cache_hits = 0
+        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_duration(self, event: str, duration_secs: float, **_kw) -> None:
+        if event == self._REQUEST:
+            with self._lock:
+                self.requests += 1
+                self.request_s += duration_secs
+
+    def _on_event(self, event: str, **_kw) -> None:
+        if event == self._HIT:
+            with self._lock:
+                self.cache_hits += 1
+
+    def close(self) -> None:
+        import jax.monitoring
+
+        jax.monitoring.unregister_event_duration_listener(self._on_duration)
+        jax.monitoring.unregister_event_listener(self._on_event)
+
+    def report(self) -> dict:
+        with self._lock:
+            return {"requests": self.requests,
+                    "seconds": round(self.request_s, 3),
+                    "persistent_cache_hits": self.cache_hits,
+                    "compiled": self.requests - self.cache_hits}

@@ -9,7 +9,9 @@ measured wall-clock into
 - ``mfu``       — model FLOPs / (time x peak FLOP/s), and
 - ``hbm_util``  — model HBM bytes moved / (time x peak HBM GB/s),
 
-against TPU v5e (v5 lite) single-chip peaks. Decode of a large LM is
+against the peaks of the device the number was measured on, looked up by
+``device_kind`` in :data:`PEAKS` — a device that is not in the table is an
+error, never a default. Decode of a large LM is
 weight-bytes-bound (every step re-reads all weights plus the KV cache),
 so for serving the honest headline is ``hbm_util``; MFU is the training /
 prefill headline. ``bench.py`` and ``scripts/measure_baseline.py`` attach
@@ -26,12 +28,48 @@ from __future__ import annotations
 
 import dataclasses
 
-# TPU v5e (v5 lite) single-chip peaks (public spec: 197 bf16 TFLOP/s,
-# 394 int8 TOP/s, 819 GB/s HBM bandwidth, 16 GB HBM).
-V5E_BF16_FLOPS = 197e12
-V5E_INT8_OPS = 394e12
-V5E_HBM_BYTES_S = 819e9
-V5E_HBM_BYTES = 16 * 2**30
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Published peaks of one chip, with where they were published."""
+
+    bf16_flops: float   # FLOP/s
+    int8_ops: float     # OP/s
+    hbm_bytes_s: float  # bytes/s
+    hbm_bytes: float    # bytes
+    source: str
+
+
+# keyed by ``jax.devices()[0].device_kind``
+PEAKS: dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(
+        bf16_flops=197e12, int8_ops=393e12, hbm_bytes_s=819e9,
+        hbm_bytes=16e9,
+        source='Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, '
+               "393 TOP/s int8, 16 GB HBM at 819 GB/s per chip)"),
+}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """The peaks table entry for a device kind; raises ``KeyError`` for a
+    kind the table does not hold — a utilization against an assumed peak
+    is not a measurement."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)}. Add it to utils/roofline.py PEAKS with its "
+            "source before publishing a utilization.") from None
+
+
+def peaks_of(device) -> Peaks | None:
+    """Peaks of a jax device: its table entry on a TPU (raising for a kind
+    the table lacks), None on any other platform — a CPU rehearsal of a
+    measurement publishes no utilization."""
+    if device.platform != "tpu":
+        return None
+    return peaks_for(device.device_kind)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -41,26 +79,25 @@ class Cost:
     flops: float
     hbm_bytes: float
 
-    def time_lower_bound_ms(self, *, peak_flops: float = V5E_BF16_FLOPS,
-                            peak_bw: float = V5E_HBM_BYTES_S) -> float:
+    def time_lower_bound_ms(self, peaks: Peaks) -> float:
         """Roofline time bound: max of compute-bound and memory-bound."""
-        return max(self.flops / peak_flops, self.hbm_bytes / peak_bw) * 1e3
+        return max(self.flops / peaks.bf16_flops,
+                   self.hbm_bytes / peaks.hbm_bytes_s) * 1e3
 
-    def mfu(self, measured_s: float, *,
-            peak_flops: float = V5E_BF16_FLOPS) -> float:
-        return self.flops / (measured_s * peak_flops) if measured_s > 0 else 0.0
-
-    def hbm_util(self, measured_s: float, *,
-                 peak_bw: float = V5E_HBM_BYTES_S) -> float:
-        return (self.hbm_bytes / (measured_s * peak_bw)
+    def mfu(self, measured_s: float, peaks: Peaks) -> float:
+        return (self.flops / (measured_s * peaks.bf16_flops)
                 if measured_s > 0 else 0.0)
 
-    def utilization(self, measured_s: float) -> dict:
+    def hbm_util(self, measured_s: float, peaks: Peaks) -> float:
+        return (self.hbm_bytes / (measured_s * peaks.hbm_bytes_s)
+                if measured_s > 0 else 0.0)
+
+    def utilization(self, measured_s: float, peaks: Peaks) -> dict:
         """The fields published next to a measured number."""
         return {
-            "mfu": round(self.mfu(measured_s), 4),
-            "hbm_util": round(self.hbm_util(measured_s), 4),
-            "roofline_ms": round(self.time_lower_bound_ms(), 4),
+            "mfu": round(self.mfu(measured_s, peaks), 4),
+            "hbm_util": round(self.hbm_util(measured_s, peaks), 4),
+            "roofline_ms": round(self.time_lower_bound_ms(peaks), 4),
             "flops": self.flops,
             "hbm_bytes": self.hbm_bytes,
         }
@@ -136,10 +173,11 @@ def llama_decode_window_cost(cfg, *, batch: int, window_len: int,
     return Cost(float(flops), base.hbm_bytes)
 
 
-def llama_decode_tok_s_bound(cfg, *, batch: int, cache_len: int) -> float:
+def llama_decode_tok_s_bound(cfg, *, batch: int, cache_len: int,
+                             peaks: Peaks) -> float:
     """Roofline upper bound on decode tokens/second at this batch."""
     c = llama_decode_step_cost(cfg, batch=batch, cache_len=cache_len)
-    return batch / (c.time_lower_bound_ms() / 1e3)
+    return batch / (c.time_lower_bound_ms(peaks) / 1e3)
 
 
 def llama_prefill_cost(cfg, *, batch: int, seq_len: int) -> Cost:

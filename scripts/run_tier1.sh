@@ -16,8 +16,10 @@
 # modules together in 2a preserves their shared session-scoped
 # tiny_server compile cache.
 #
-# Phase 3 is a quick forced-CPU bench.py smoke (tiny model) so a bench
-# orchestration regression turns tier-1 red, not measurement day.
+# Phase 3 is a quick bench.py smoke pinned to the CPU by name
+# (LAMBDIPY_PLATFORM=cpu — without the pin bench.py refuses to run off
+# the TPU) on the tiny model, so a bench orchestration regression turns
+# tier-1 red, not measurement day.
 #
 # Phase 4 smokes the decode-window sweep; phase 5 the pipelined-engine
 # sweep (bitwise parity across pipeline depths + depth-2 tok/s beating
@@ -108,12 +110,14 @@ fi
 phase_end "phase 1"
 
 # the engine/serving stack: these share conftest.py's session-scoped
-# tiny_server (one compiled-program cache) and are the wall-clock-heavy
-# half of the suite
+# tiny_server (one compiled-program cache). The bring-up and chip-smoke
+# rehearsal modules ride here too: they boot servers (~2 min together)
+# and 2b is the shard nearer its cap
 ENGINE_SHARD="tests/test_continuous.py tests/test_continuous_pipeline.py \
 tests/test_faults.py tests/test_prefixstore.py tests/test_paged.py \
 tests/test_pagepool.py tests/test_decode_attention.py \
-tests/test_runtime.py tests/test_fleet.py tests/test_e2e.py"
+tests/test_runtime.py tests/test_fleet.py tests/test_e2e.py \
+tests/test_bringup.py tests/test_chip_smoke.py"
 
 set -o pipefail
 phase_begin "phase 2a: tier-1 engine/serving shard"
@@ -140,7 +144,7 @@ if [ "$rc" -ne 0 ]; then exit "$rc"; fi
 
 phase_begin "phase 3: bench.py CPU smoke"
 if ! timeout -k 10 600 env JAX_PLATFORMS=cpu \
-    LAMBDIPY_BENCH_FORCE_PLATFORM=cpu LAMBDIPY_BENCH_MODEL=resnet50-tiny \
+    LAMBDIPY_PLATFORM=cpu LAMBDIPY_BENCH_MODEL=resnet50-tiny \
     python bench.py; then
     echo "FATAL: bench.py CPU smoke failed" >&2
     exit 1

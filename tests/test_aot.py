@@ -166,8 +166,8 @@ def test_meshed_aot_rejects_other_mesh_shape(tmp_path, cpu_devices):
 def test_serving_programs_ride_aot_store(tmp_path):
     """The LlamaServer decode/stream programs snapshot into the bundle's
     AOT exec tier at warmup and a SECOND boot loads them instead of
-    compiling (the 8B cold start's dominant cost: ~70 s remote compile
-    per program)."""
+    compiling (the 8B cold start's dominant cost: ~40 s of compile per
+    program)."""
     from tests.test_runtime import make_model_bundle
     from lambdipy_tpu.runtime.loader import load_bundle
 
@@ -213,7 +213,7 @@ def test_partial_stream_pair_saves_and_loads(tmp_path):
 
     adapter = registry.get("llama-tiny").build()
     params = adapter.init_params(seed=0)
-    store = AotStore(tmp_path, gate_ms=60000)
+    store = AotStore(tmp_path)
     server = LlamaServer(adapter.module, params, aot=store)
     cb = ContinuousBatcher(server, slots=4, segment=4)
     ref = cb.generate([1, 2, 3], max_new_tokens=8)
@@ -227,7 +227,7 @@ def test_partial_stream_pair_saves_and_loads(tmp_path):
     assert not store.has(f"{name}-p0"), "prefill half never ran"
 
     server2 = LlamaServer(adapter.module, params,
-                          aot=AotStore(tmp_path, gate_ms=60000))
+                          aot=AotStore(tmp_path))
     cb2 = ContinuousBatcher(server2, slots=4, segment=4)
     out = cb2.generate([1, 2, 3], max_new_tokens=8)
     np.testing.assert_array_equal(out, ref)
@@ -244,12 +244,12 @@ def test_preload_overlaps_weight_load(tmp_path):
 
     adapter = registry.get("llama-tiny").build()
     params = adapter.init_params(seed=0)
-    store = AotStore(tmp_path, gate_ms=60000)
+    store = AotStore(tmp_path)
     server = LlamaServer(adapter.module, params, aot=store)
     ref = server.generate([1, 2, 3], max_new_tokens=8)
     assert server.aot_save_all() > 0
 
-    store2 = AotStore(tmp_path, gate_ms=60000)
+    store2 = AotStore(tmp_path)
     pre = store2.preload()          # no params anywhere in sight
     assert pre["names"], "saved serving programs must preload"
     assert store2._preloaded

@@ -1,0 +1,117 @@
+"""Every Pallas kernel in ``ops/`` compiled by the TPU's own compiler, for
+a v5e that is described and not attached, at Llama-3-8B head shapes.
+
+Interpret-mode tests cannot see what Mosaic refuses (block shapes off the
+(8, 128) tiling, too much VMEM): the paged decode kernel passed them from
+PR 8 to PR 20 and had never compiled. Nothing runs here — a compile that
+passes says nothing about results or times — so each case asserts only
+that the program lowers to a ``tpu_custom_call`` and the compiler accepts
+it. ~1 s each; skipped where the topology cannot be described.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# Llama-3-8B attention heads; a decode batch of 8 over a 2048 window
+B, H, KVH, D, T = 8, 32, 8, 128, 2048
+HIDDEN, MLP = 4096, 14336
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, no described chip
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_compilation_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: the next run would warn and
+    compile again. Keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _compile_for(chip, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), \
+        "no Mosaic kernel in the compiled program"
+
+
+def _kv(dtype, lead):
+    """K/V (+ int8 scales) operand shapes behind a leading shape."""
+    kv = [((*lead, KVH, D), dtype)] * 2
+    if dtype == jnp.int8:
+        kv += [((*lead, KVH, 1), jnp.float32)] * 2
+    return kv
+
+
+@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8kv"])
+def test_blocked_decode_attention_compiles(v5e, kv_dtype):
+    from lambdipy_tpu.ops.decode_attention import blocked_decode_attention
+
+    def fn(q, lens, k, v, *scales):
+        ks, vs = scales or (None, None)
+        return blocked_decode_attention(q, k, v, lens, k_scale=ks, v_scale=vs)
+
+    _compile_for(v5e, fn, ((B, 1, H, D), jnp.bfloat16), ((B,), jnp.int32),
+                 *_kv(kv_dtype, (B, T)))
+
+
+@pytest.mark.parametrize("page", [16, 128])
+@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8kv"])
+def test_paged_decode_attention_compiles(v5e, kv_dtype, page):
+    """At the arena's real layout ``[P, page, kvh, d]`` (models/llama.py
+    init_page_arena), for the smallest and largest page widths."""
+    from lambdipy_tpu.ops.decode_attention import (
+        paged_blocked_decode_attention)
+
+    n_pages, nb = B * T // page + 1, T // page
+
+    def fn(q, tables, lens, k, v, *scales):
+        ks, vs = scales or (None, None)
+        return paged_blocked_decode_attention(
+            q, k, v, tables, lens, k_scale_pages=ks, v_scale_pages=vs)
+
+    _compile_for(v5e, fn, ((B, 1, H, D), jnp.bfloat16),
+                 ((B, nb), jnp.int32), ((B,), jnp.int32),
+                 *_kv(kv_dtype, (n_pages, page)))
+
+
+def test_flash_attention_causal_compiles(v5e):
+    from lambdipy_tpu.ops.attention import flash_attention
+
+    _compile_for(v5e, lambda q, k, v: flash_attention(q, k, v, causal=True),
+                 ((1, T, H, D), jnp.bfloat16), ((1, T, KVH, D), jnp.bfloat16),
+                 ((1, T, KVH, D), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("m", [1, 8, 512])
+def test_int8_matmul_compiles(v5e, m):
+    """The MLP up-projection at decode (m = 1, 8) and prefill (512) widths."""
+    from lambdipy_tpu.ops.quant import int8_matmul
+
+    _compile_for(v5e, int8_matmul, ((m, HIDDEN), jnp.bfloat16),
+                 ((HIDDEN, MLP), jnp.int8), ((1, MLP), jnp.float32))

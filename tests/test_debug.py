@@ -43,7 +43,7 @@ def test_trainer_debug_numerics_catches_nan(cpu_devices):
     instead of logging nan losses forever."""
     from lambdipy_tpu.data.loader import ShardedLoader, TokenSource
     from lambdipy_tpu.models import registry
-    from lambdipy_tpu.parallel.mesh import make_mesh
+    from lambdipy_tpu.parallel.mesh import make_mesh, use_mesh
     from lambdipy_tpu.train.loop import Trainer, TrainerConfig
 
     adapter = registry.get("llama-tiny").build()
@@ -56,7 +56,7 @@ def test_trainer_debug_numerics_catches_nan(cpu_devices):
     loader = ShardedLoader(TokenSource(tokens, 16), 4, seed=0,
                            process_index=0, process_count=1)
     cfg = TrainerConfig(total_steps=2, log_every=1, debug_numerics=True)
-    with mesh:
+    with use_mesh(mesh):
         trainer = Trainer(adapter.forward, params, mesh, adapter.tp_rules,
                           loader, cfg)
         with pytest.raises(FloatingPointError):

@@ -87,23 +87,43 @@ def test_kernel_int8_kv_matches_dequant_reference():
                                rtol=2e-3, atol=2e-3)
 
 
-def test_untileable_and_multitoken_fall_back_to_reference():
+def test_untileable_and_multitoken_raise_in_the_kernel():
+    """A window that does not tile, or a multi-token q, is an error in the
+    kernel's own name; only the DISPATCHER picks the reference, and only
+    off the TPU."""
     b, h, kvh, d = 1, 2, 1, 16
     alen = jnp.asarray([7], jnp.int32)
-    # t=40 doesn't tile at block_k=16 -> reference, bitwise
     q = _rand((b, 1, h, d), 9)
     k, v = _rand((b, 40, kvh, d), 10), _rand((b, 40, kvh, d), 11)
-    out = blocked_decode_attention(q, k, v, alen, block_k=16)
-    ref = decode_attention_reference(q, k, v, alen)
-    assert (np.asarray(out) == np.asarray(ref)).all()
-    # s=2 (a continuation chunk) is not the kernel's job either
+    with pytest.raises(ValueError, match="tiles by block_k"):
+        blocked_decode_attention(q, k, v, alen, block_k=16, interpret=True)
     q2 = _rand((b, 2, h, d), 12)
-    out2 = blocked_decode_attention(q2, k, v, alen, block_k=8)
-    ref2 = decode_attention_reference(q2, k, v, alen)
-    assert (np.asarray(out2) == np.asarray(ref2)).all()
+    with pytest.raises(ValueError, match="single-token"):
+        blocked_decode_attention(q2, k, v, alen, block_k=8, interpret=True)
     # the dispatcher on CPU routes to the reference outright
+    ref = decode_attention_reference(q, k, v, alen)
     out3 = decode_attention(q, k, v, alen)
     assert (np.asarray(out3) == np.asarray(ref)).all()
+
+
+def test_dispatchers_take_the_kernel_on_a_tpu_backend(monkeypatch):
+    """On a TPU backend the dispatcher calls the kernel and lets it raise:
+    an untileable window is refused there, never served by the reference
+    (the kernel is stubbed — Mosaic does not compile on this CPU)."""
+    import lambdipy_tpu.ops.decode_attention as da
+
+    monkeypatch.setattr(da.jax, "default_backend", lambda: "tpu")
+    b, h, kvh, d = 1, 2, 1, 16
+    alen = jnp.asarray([7], jnp.int32)
+    q = _rand((b, 1, h, d), 9)
+    k, v = _rand((b, 40, kvh, d), 10), _rand((b, 40, kvh, d), 11)
+    with pytest.raises(ValueError, match="tiles by block_k"):
+        decode_attention(q, k, v, alen, block_k=16)
+    q2 = _rand((b, 2, h, d), 12)
+    k_pages, v_pages, tables = _paged_layout(
+        _rand((b, 64, kvh, d), 13), _rand((b, 64, kvh, d), 14), 32)
+    with pytest.raises(ValueError, match="single-token"):
+        da.paged_decode_attention(q2, k_pages, v_pages, tables, alen)
 
 
 # -- paged (block-table) decode attention ------------------------------------
@@ -211,9 +231,10 @@ def test_paged_kernel_int8_kv_matches_dequant_reference():
                                rtol=2e-3, atol=2e-3)
 
 
-def test_paged_dispatcher_multitoken_falls_back():
-    """s > 1 (a continuation chunk) routes to the reference — the
-    kernel is single-token by design, like the contiguous dispatcher."""
+def test_paged_dispatcher_serves_the_reference_off_the_tpu():
+    """Off the TPU the dispatcher is the reference outright, for any q
+    width (on a TPU backend a multi-token q raises in the kernel — see
+    test_dispatchers_take_the_kernel_on_a_tpu_backend)."""
     from lambdipy_tpu.ops.decode_attention import (
         paged_decode_attention, paged_decode_attention_reference)
 

@@ -62,7 +62,7 @@ def test_hybrid_mesh_runs_collectives(cpu_devices):
 
     mesh = make_hybrid_mesh({"dp": 2, "tp": 4})
     x = jnp.arange(8.0)
-    with mesh:
+    with use_mesh(mesh):
         xs = jax.device_put(x.reshape(2, 4), NamedSharding(mesh, P("dp", "tp")))
         total = jax.jit(jnp.sum)(xs)
     assert float(total) == float(x.sum())

@@ -284,7 +284,7 @@ def test_streamed_row_with_delivered_bytes_errors_not_replays(tiny_server):
 
 
 def test_watchdog_wedges_hung_engine_and_aborts_waiters(tiny_server):
-    """A hung device wait (the BENCH_r04/r05 transport wedge, injected)
+    """A hung device wait (injected)
     trips the watchdog within its bound: with no replay budget every
     waiter gets an explicit error instead of blocking forever, the
     engine reports wedged on its O(1) fault surface, and nothing is

@@ -105,7 +105,7 @@ def test_llama_tp_sharded_forward_matches_single_device(cpu_devices):
     to the unsharded run — XLA inserts the collectives (SURVEY.md §3.2)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from lambdipy_tpu.parallel.mesh import make_mesh
+    from lambdipy_tpu.parallel.mesh import make_mesh, use_mesh
     from lambdipy_tpu.parallel.sharding import param_shardings, shard_params
 
     adapter = registry.get("llama-tiny").build()
@@ -119,7 +119,7 @@ def test_llama_tp_sharded_forward_matches_single_device(cpu_devices):
     fwd = jax.jit(adapter.forward,
                   in_shardings=(shardings, NamedSharding(mesh, P("dp"))),
                   out_shardings=NamedSharding(mesh, P("dp")))
-    with mesh:
+    with use_mesh(mesh):
         out = fwd(sharded_params, jax.device_put(tokens, NamedSharding(mesh, P("dp"))))
     np.testing.assert_allclose(ref, np.asarray(out), rtol=2e-3, atol=2e-3)
 

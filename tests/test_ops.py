@@ -32,12 +32,13 @@ def test_flash_attention_gqa_broadcast():
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), rtol=2e-3, atol=2e-3)
 
 
-def test_flash_attention_untileable_falls_back():
-    b, s, h, d = 1, 10, 2, 16  # s=10 doesn't tile
+def test_flash_attention_untileable_raises():
+    """A shape the kernel cannot tile is an error in the kernel's own name,
+    never a quiet hand-off to the reference."""
+    b, s, h, d = 1, 200, 2, 16  # s=200 doesn't tile by 128
     q, k, v = (_rand((b, s, h, d), i) for i in range(3))
-    out = flash_attention(q, k, v, causal=True, interpret=True)
-    ref = mha_reference(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(ref), np.asarray(out), rtol=1e-5, atol=1e-5)
+    with pytest.raises(ValueError, match="do not tile"):
+        flash_attention(q, k, v, causal=True, interpret=True)
 
 
 # -- int8 weight-only matmul ------------------------------------------------
@@ -70,26 +71,44 @@ def test_int8_matmul_kernel_matches_reference():
                                rtol=2e-2, atol=0.15)
 
 
-def test_int8_matmul_fallback_on_odd_shapes():
+def test_int8_matmul_decode_sized_m_and_odd_shapes():
+    """m below one block is clamped (decode has m as small as 1) and runs
+    the kernel; k/n that do not tile raise instead of returning the
+    reference."""
     import numpy as np
 
     from lambdipy_tpu.ops.quant import int8_matmul, int8_matmul_reference
 
-    m, k, n = 3, 96, 80  # m=3: decode-sized, won't tile
+    m, k, n = 3, 256, 128
     x = jnp.asarray(np.random.default_rng(2).normal(size=(m, k)), jnp.float32)
     w_i8, scale = _quant_weights(k, n, 3)
     out = int8_matmul(x, w_i8, scale, interpret=True)
-    # same math, but under jit XLA fuses the bf16 dequant differently
     np.testing.assert_allclose(np.asarray(int8_matmul_reference(x, w_i8, scale)),
-                               np.asarray(out), rtol=2e-2, atol=0.1)
+                               np.asarray(out), rtol=2e-2, atol=0.15)
+    w_odd, scale_odd = _quant_weights(96, 80, 4)
+    with pytest.raises(ValueError, match="does not tile"):
+        int8_matmul(x[:, :96], w_odd, scale_odd, interpret=True)
 
 
-def test_qdense_pallas_backend_matches_xla():
-    """QDense(int8, backend=pallas) routes through the kernel (interpret on
-    CPU) and matches the XLA dequant path."""
+def test_qdense_pallas_backend_matches_xla(monkeypatch):
+    """QDense(int8, backend=pallas) routes through the kernel where kernels
+    compile — steered here onto the interpreter, since Mosaic compiles
+    only for a TPU backend — and matches the XLA dequant path."""
     import numpy as np
 
+    import lambdipy_tpu.ops as ops
+    import lambdipy_tpu.ops.quant as quant
     from lambdipy_tpu.models.llama import QDense
+
+    calls = []
+    real = quant.int8_matmul
+
+    def interpreted(*args, **kwargs):
+        calls.append(1)
+        return real(*args, interpret=True, **kwargs)
+
+    monkeypatch.setattr(ops, "kernels_compile_here", lambda: True)
+    monkeypatch.setattr(quant, "int8_matmul", interpreted)
 
     x = jnp.asarray(np.random.default_rng(5).normal(size=(2, 64, 128)),
                     jnp.float32)
@@ -97,5 +116,6 @@ def test_qdense_pallas_backend_matches_xla():
     params = ref_mod.init(jax.random.PRNGKey(0), x)
     ref = ref_mod.apply(params, x)
     out = QDense(256, "int8", jnp.float32, "pallas").apply(params, x)
+    assert calls, "pallas backend did not reach the kernel"
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
                                rtol=2e-2, atol=0.1)

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from lambdipy_tpu.parallel.mesh import make_mesh
+from lambdipy_tpu.parallel.mesh import make_mesh, use_mesh
 from lambdipy_tpu.parallel.pipeline import (
     merge_microbatches,
     pipeline_apply,
@@ -52,7 +52,7 @@ def test_pipeline_matches_sequential(cpu_devices, num_microbatches):
     mesh = make_mesh({"pp": 4}, devices=cpu_devices[:4])
     stacked = stack_stage_params(stages)
     mb = split_microbatches(x, num_microbatches)
-    with mesh:
+    with use_mesh(mesh):
         out = merge_microbatches(pipeline_apply(_stage_fn, stacked, mb, mesh))
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
                                rtol=1e-5, atol=1e-5)
@@ -67,7 +67,7 @@ def test_pipeline_composes_with_dp(cpu_devices):
     mesh = make_mesh({"dp": 2, "pp": 4})
     stacked = stack_stage_params(stages)
     mb = split_microbatches(x, 4)
-    with mesh:
+    with use_mesh(mesh):
         out = merge_microbatches(pipeline_apply(_stage_fn, stacked, mb, mesh))
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
                                rtol=1e-5, atol=1e-5)
@@ -90,7 +90,7 @@ def test_pipeline_const_and_jit(cpu_devices):
     mesh = make_mesh({"pp": 2}, devices=cpu_devices[:2])
     stacked = stack_stage_params(stages)
     mb = split_microbatches(x, 2)
-    with mesh:
+    with use_mesh(mesh):
         fn = jax.jit(lambda s, m: pipeline_apply(
             stage_fn, s, m, mesh, const={"shift": shift}))
         out = merge_microbatches(fn(stacked, mb))
@@ -128,7 +128,7 @@ def test_llama_pipeline_forward_matches(cpu_devices):
     ref = adapter.forward(params, tokens)
 
     mesh = make_mesh({"pp": 2}, devices=cpu_devices[:2])
-    with mesh:
+    with use_mesh(mesh):
         out = pipeline_forward(adapter.module, params, tokens, mesh,
                                num_microbatches=2)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
@@ -147,7 +147,7 @@ def test_llama_pipeline_forward_composes_with_dp(cpu_devices):
                          jnp.int32)
     ref = adapter.forward(params, tokens)
     mesh = make_mesh({"dp": 2, "pp": 2}, devices=cpu_devices[:4])
-    with mesh:
+    with use_mesh(mesh):
         out = pipeline_forward(adapter.module, params, tokens, mesh,
                                num_microbatches=2)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
@@ -174,7 +174,7 @@ def test_pipeline_forward_with_moe_blocks(cpu_devices):
                          jnp.int32)
     ref = adapter.forward(params, tokens)
     mesh = make_mesh({"pp": 2}, devices=cpu_devices[:2])
-    with mesh:
+    with use_mesh(mesh):
         out = pipeline_forward(adapter.module, params, tokens, mesh,
                                num_microbatches=2)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out),

@@ -284,7 +284,7 @@ def test_bench_shared_prefix_default_512(tmp_path):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     env.update({"JAX_PLATFORMS": "cpu",
-                "LAMBDIPY_BENCH_CACHE": str(tmp_path / "cache")})
+                "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")})
     proc = subprocess.run(
         [sys.executable, os.path.join(repo, "bench.py"), "--shared-prefix"],
         capture_output=True, text=True, env=env, timeout=900)

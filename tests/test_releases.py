@@ -6,7 +6,6 @@ index (find/list, token-protected uploads), hash-verified caching, and the
 CLI wiring (publish / fetch / releases / build --release-store).
 """
 
-import importlib.util
 import json
 import sys
 import tarfile
@@ -167,27 +166,6 @@ def test_fetch_into_registry(store_with_asset, tmp_path):
     assert (bundle / "handler.py").exists()
 
 
-def _has_pep517_build() -> bool:
-    """True only when the PEP-517 'build' PACKAGE is importable. A bare
-    ``find_spec("build") is not None`` check is wrong here: a stray
-    ``build/`` output directory on sys.path (the default sdist/wheel
-    output location!) resolves as a NAMESPACE package — a spec with
-    ``origin=None`` — and the test would then run and die on import
-    instead of skipping."""
-    try:
-        spec = importlib.util.find_spec("build")
-    except (ImportError, ValueError):
-        return False
-    return spec is not None and spec.origin is not None
-
-
-@pytest.mark.skipif(
-    not _has_pep517_build(),
-    reason="environment-bound: publishing certifi builds its sdist via the "
-           "PEP-517 'build' package, which is not importable here "
-           "(install with `pip install build` where the environment "
-           "allows it); the prebuilt-asset halves of the loop are "
-           "covered by the two tests below")
 def test_cli_publish_fetch_loop(tmp_path):
     """End-to-end over the CLI: maintainer publishes certifi, a fresh user
     registry fetches it prebuilt, and `build --release-store` prefers the

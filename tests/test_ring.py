@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from lambdipy_tpu.ops.attention import mha_reference
-from lambdipy_tpu.parallel.mesh import make_mesh
+from lambdipy_tpu.parallel.mesh import make_mesh, use_mesh
 from lambdipy_tpu.parallel.ring import ring_attention
 
 
@@ -21,7 +21,7 @@ def test_ring_attention_matches_full(cpu_devices, causal):
     q, k, v = (_rand((b, s, h, d), i) for i in range(3))
     ref = mha_reference(q, k, v, causal=causal)
     mesh = make_mesh({"sp": 8})
-    with mesh:
+    with use_mesh(mesh):
         out = ring_attention(q, k, v, mesh, causal=causal)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
                                rtol=2e-4, atol=2e-4)
@@ -34,7 +34,7 @@ def test_ring_attention_gqa(cpu_devices):
     v = _rand((b, s, kvh, d), 2)
     ref = mha_reference(q, k, v, causal=True)
     mesh = make_mesh({"sp": 8})
-    with mesh:
+    with use_mesh(mesh):
         out = ring_attention(q, k, v, mesh, causal=True)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
                                rtol=2e-4, atol=2e-4)
@@ -48,7 +48,7 @@ def test_ring_attention_composes_with_dp(cpu_devices):
     mesh = make_mesh({"dp": 2, "sp": 4})
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    with mesh:
+    with use_mesh(mesh):
         qs = jax.device_put(q, NamedSharding(mesh, P("dp", "sp")))
         ks = jax.device_put(k, NamedSharding(mesh, P("dp", "sp")))
         vs = jax.device_put(v, NamedSharding(mesh, P("dp", "sp")))
@@ -152,7 +152,7 @@ def test_sp_decode_step_matches_dense_reference(cpu_devices):
     ck = jnp.asarray(rng.standard_normal((b, T, kvh, d)), jnp.float32)
     cv = jnp.asarray(rng.standard_normal((b, T, kvh, d)), jnp.float32)
     idx = jnp.asarray([5, 17, 31], jnp.int32)
-    with mesh:
+    with use_mesh(mesh):
         out, ncache = jax.jit(
             lambda *a: sp_decode_step(*a, mesh=mesh))(
             q, {"k": kn, "v": vn}, {"k": ck, "v": cv}, idx)
@@ -239,7 +239,7 @@ def test_sp_decode_strongly_negative_logits_with_empty_shards(cpu_devices):
     kn = jnp.zeros((b, 1, kvh, d), jnp.float32).at[..., 0].set(-100.0)
     vn = jnp.full((b, 1, kvh, d), 7.0, jnp.float32)
     idx = jnp.asarray([0], jnp.int32)  # writes pos 0; only pos 0 valid
-    with mesh:
+    with use_mesh(mesh):
         out, _ = jax.jit(
             lambda *a: sp_decode_step(*a, mesh=mesh))(
             q, {"k": kn, "v": vn}, {"k": ck, "v": cv}, idx)

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from lambdipy_tpu.models import registry
-from lambdipy_tpu.parallel.mesh import make_mesh
+from lambdipy_tpu.parallel.mesh import make_mesh, use_mesh
 from lambdipy_tpu.train.step import sharded_train_step
 
 
@@ -13,7 +13,7 @@ def test_sharded_train_step_runs_and_loss_decreases(cpu_devices):
     adapter = registry.get("llama-tiny").build()
     params = adapter.init_params(seed=0)
     mesh = make_mesh({"dp": 2, "tp": 2, "sp": 2})
-    with mesh:
+    with use_mesh(mesh):
         step, state, batch_sharding = sharded_train_step(
             adapter.forward, params, mesh, adapter.tp_rules, learning_rate=5e-3)
         rng = np.random.default_rng(0)
@@ -37,7 +37,7 @@ def test_fsdp_params_actually_sharded(cpu_devices):
     adapter = registry.get("llama-tiny").build()
     params = adapter.init_params(seed=0)
     mesh = make_mesh({"dp": 4, "tp": 2})
-    with mesh:
+    with use_mesh(mesh):
         _, state, _ = sharded_train_step(
             adapter.forward, params, mesh, adapter.tp_rules)
     specs = {
@@ -114,7 +114,7 @@ def test_trainer_with_accumulation_and_schedule(cpu_devices, tmp_path):
                            process_index=0, process_count=1)
     cfg = TrainerConfig(total_steps=6, log_every=2, grad_clip=0.5,
                         warmup_steps=2, schedule="cosine", accum_steps=2)
-    with mesh:
+    with use_mesh(mesh):
         report = Trainer(adapter.forward, params, mesh, adapter.tp_rules,
                          loader, cfg).run()
     assert report.steps_run == 6
