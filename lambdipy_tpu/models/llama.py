@@ -345,18 +345,28 @@ class LlamaBlock(nn.Module):
         have exposed chunk by chunk."""
         cfg = self.cfg
         d = cfg.head_dim
-        h = RMSNorm(cfg.norm_eps, name="attn_norm")(x)
-        b, s, _ = h.shape
-        q = QDense(cfg.heads * d, cfg.quant, cfg.dtype, cfg.matmul_backend, name="q_proj")(h)
-        k = QDense(cfg.kv_heads * d, cfg.quant, cfg.dtype, cfg.matmul_backend, name="k_proj")(h)
-        v = QDense(cfg.kv_heads * d, cfg.quant, cfg.dtype, cfg.matmul_backend, name="v_proj")(h)
-        q = q.reshape(b, s, cfg.heads, d)
-        k = k.reshape(b, s, cfg.kv_heads, d)
-        v = v.reshape(b, s, cfg.kv_heads, d)
-        q, k = rope(q, k, positions, cfg.rope_theta, cfg.rope_scaling)
+        # the scope names below (qkv_proj, kv_write, attend, o_proj, mlp;
+        # embed, lm_head, sample further down) reach each device
+        # operation's op_name: the trace is split by them (PERF.md).
+        # Renaming or moving one: bump utils/compile_cache.NAMES_GEN
+        # and LlamaServer._AOT_GEN
+        b, s, _ = x.shape
+        with jax.named_scope("qkv_proj"):
+            h = RMSNorm(cfg.norm_eps, name="attn_norm")(x)
+            q = QDense(cfg.heads * d, cfg.quant, cfg.dtype,
+                       cfg.matmul_backend, name="q_proj")(h)
+            k = QDense(cfg.kv_heads * d, cfg.quant, cfg.dtype,
+                       cfg.matmul_backend, name="k_proj")(h)
+            v = QDense(cfg.kv_heads * d, cfg.quant, cfg.dtype,
+                       cfg.matmul_backend, name="v_proj")(h)
+            q = q.reshape(b, s, cfg.heads, d)
+            k = k.reshape(b, s, cfg.kv_heads, d)
+            v = v.reshape(b, s, cfg.kv_heads, d)
+            q, k = rope(q, k, positions, cfg.rope_theta, cfg.rope_scaling)
 
         if cache is None:
-            out = self._prefill_attend(q, k, v, mask, sp_prefill)
+            with jax.named_scope("attend"):
+                out = self._prefill_attend(q, k, v, mask, sp_prefill)
             new_cache = {"k": k, "v": v}
         else:
             from lambdipy_tpu.parallel.sharding import shard_hint
@@ -383,8 +393,9 @@ class LlamaBlock(nn.Module):
 
                     sp_new = _kv_store(cfg, k, v)
                     sp_cache = {name: cache[name] for name in sp_new}
-                    out, new_cache = sp_decode_step(
-                        q, sp_new, sp_cache, idx, sp_mesh)
+                    with jax.named_scope("attend"):
+                        out, new_cache = sp_decode_step(
+                            q, sp_new, sp_cache, idx, sp_mesh)
                     sp_done = True
                 elif sp_mesh is not None:
                     # a multi-token verify chunk under the ring backend:
@@ -405,123 +416,133 @@ class LlamaBlock(nn.Module):
 
                 note_standdown(f"attn_backend={cfg.attn_backend}")
             if not sp_done:
-                # quantize this chunk's k/v once under kv_quant; the
-                # cache stays int8 in HBM and the dequant fuses into
-                # the attention einsum
-                store = _kv_store(cfg, k, v)
-                new_cache = {}
-                if jnp.ndim(idx) == 0:
-                    for name, val in store.items():
-                        new_cache[name] = jax.lax.dynamic_update_slice(
-                            cache[name], val, (0, idx, 0, 0))
-                    # chunk query j attends keys <= idx + j — causal
-                    # within the chunk, everything before it. s == 1 is
-                    # the familiar decode-step mask; s > 1 is a
-                    # multi-token continuation chunk (prefix-cache
-                    # suffix prefill).
-                    t = new_cache[next(iter(store))].shape[1]
-                    valid = (jnp.arange(t)[None, None, :]
-                             <= (idx + jnp.arange(s))[None, :, None])
-                    if band:
-                        # long-context sliding band: query at cache
-                        # position p sees keys from the start of the
-                        # PREVIOUS band block — exactly the window the
-                        # serial window/2 slide schedule leaves resident
-                        # when p's chunk runs
-                        qpos = idx + jnp.arange(s)
-                        band_start = jnp.maximum(
-                            0, (qpos // band - 1) * band)
-                        valid = valid & (jnp.arange(t)[None, None, :]
-                                         >= band_start[None, :, None])
-                else:
-                    # ragged batch (rows decode from different prompt
-                    # lengths): per-row scatter of this step's (or
-                    # chunk's) positions. s == 1 is the familiar decode
-                    # step; s > 1 is a SPECULATIVE VERIFY CHUNK — row
-                    # r's chunk lands at idx[r]..idx[r]+s-1 and query j
-                    # attends keys <= idx[r]+j (causal within the
-                    # chunk). Out-of-bounds scatter indices DROP (jax
-                    # .at[] default), which is exactly the engine's
-                    # over-decode/rollback contract: a rejected tail or
-                    # past-the-window write lands nowhere a kept token
-                    # can read.
-                    rows = jnp.arange(b)
-                    cols = idx[:, None] + jnp.arange(s)[None, :]  # [b, s]
-                    for name, val in store.items():
-                        new_cache[name] = cache[name].at[
-                            rows[:, None], cols].set(val)
-                    t = new_cache[next(iter(store))].shape[1]
-                    valid = (jnp.arange(t)[None, None, :]
-                             <= cols[:, :, None])  # [b, s, t]
-                new_cache = {name: shard_hint(val, "dp", None, "tp")
-                             for name, val in new_cache.items()}
-                # length-aware blocked decode attention: one-token steps
-                # read each row's ACTIVE window instead of the full
-                # static cache (bytes scale with context actually held).
-                # Manual (unpartitioned) op like QDense's pallas backend:
-                # only taken with no ambient mesh; the valid mask built
-                # above is exactly "position < index + 1", so active_len
-                # = idx + 1 reproduces it row for row.
-                blocked = False
-                if cfg.attn_backend == "blocked" and s == 1:
-                    from lambdipy_tpu.ops.decode_attention import (
-                        decode_attention)
-                    from lambdipy_tpu.parallel.mesh import current_mesh
+                with jax.named_scope("kv_write"):
+                    # quantize this chunk's k/v once under kv_quant; the
+                    # cache stays int8 in HBM and the dequant fuses into
+                    # the attention einsum
+                    store = _kv_store(cfg, k, v)
+                    new_cache = {}
+                    if jnp.ndim(idx) == 0:
+                        for name, val in store.items():
+                            new_cache[name] = jax.lax.dynamic_update_slice(
+                                cache[name], val, (0, idx, 0, 0))
+                        # chunk query j attends keys <= idx + j — causal
+                        # within the chunk, everything before it. s == 1 is
+                        # the familiar decode-step mask; s > 1 is a
+                        # multi-token continuation chunk (prefix-cache
+                        # suffix prefill).
+                        t = new_cache[next(iter(store))].shape[1]
+                        valid = (jnp.arange(t)[None, None, :]
+                                 <= (idx + jnp.arange(s))[None, :, None])
+                        if band:
+                            # long-context sliding band: query at cache
+                            # position p sees keys from the start of the
+                            # PREVIOUS band block — exactly the window the
+                            # serial window/2 slide schedule leaves resident
+                            # when p's chunk runs
+                            qpos = idx + jnp.arange(s)
+                            band_start = jnp.maximum(
+                                0, (qpos // band - 1) * band)
+                            valid = valid & (jnp.arange(t)[None, None, :]
+                                             >= band_start[None, :, None])
+                    else:
+                        # ragged batch (rows decode from different prompt
+                        # lengths): per-row scatter of this step's (or
+                        # chunk's) positions. s == 1 is the familiar decode
+                        # step; s > 1 is a SPECULATIVE VERIFY CHUNK — row
+                        # r's chunk lands at idx[r]..idx[r]+s-1 and query j
+                        # attends keys <= idx[r]+j (causal within the
+                        # chunk). Out-of-bounds scatter indices DROP (jax
+                        # .at[] default), which is exactly the engine's
+                        # over-decode/rollback contract: a rejected tail or
+                        # past-the-window write lands nowhere a kept token
+                        # can read.
+                        rows = jnp.arange(b)
+                        cols = idx[:, None] + jnp.arange(s)[None, :]  # [b, s]
+                        for name, val in store.items():
+                            new_cache[name] = cache[name].at[
+                                rows[:, None], cols].set(val)
+                        t = new_cache[next(iter(store))].shape[1]
+                        valid = (jnp.arange(t)[None, None, :]
+                                 <= cols[:, :, None])  # [b, s, t]
+                    new_cache = {name: shard_hint(val, "dp", None, "tp")
+                                 for name, val in new_cache.items()}
+                with jax.named_scope("attend"):
+                    # length-aware blocked decode attention: one-token steps
+                    # read each row's ACTIVE window instead of the full
+                    # static cache (bytes scale with context actually held).
+                    # Manual (unpartitioned) op like QDense's pallas backend:
+                    # only taken with no ambient mesh; the valid mask built
+                    # above is exactly "position < index + 1", so active_len
+                    # = idx + 1 reproduces it row for row.
+                    blocked = False
+                    if cfg.attn_backend == "blocked" and s == 1:
+                        from lambdipy_tpu.ops.decode_attention import (
+                            decode_attention)
+                        from lambdipy_tpu.parallel.mesh import current_mesh
 
-                    if current_mesh() is None:
-                        active = jnp.broadcast_to(
-                            jnp.asarray(idx, jnp.int32) + 1, (b,))
+                        if current_mesh() is None:
+                            active = jnp.broadcast_to(
+                                jnp.asarray(idx, jnp.int32) + 1, (b,))
+                            if cfg.kv_quant == "int8":
+                                out = decode_attention(
+                                    q, new_cache["k_int8"],
+                                    new_cache["v_int8"], active,
+                                    k_scale=new_cache["k_scale"],
+                                    v_scale=new_cache["v_scale"])
+                            else:
+                                out = decode_attention(
+                                    q, new_cache["k"], new_cache["v"], active)
+                            blocked = True
+                    if not blocked:
                         if cfg.kv_quant == "int8":
-                            out = decode_attention(
-                                q, new_cache["k_int8"],
-                                new_cache["v_int8"], active,
-                                k_scale=new_cache["k_scale"],
-                                v_scale=new_cache["v_scale"])
+                            ck = _kv_dequantize(
+                                new_cache["k_int8"], new_cache["k_scale"],
+                                cfg.dtype)
+                            cv = _kv_dequantize(
+                                new_cache["v_int8"], new_cache["v_scale"],
+                                cfg.dtype)
                         else:
-                            out = decode_attention(
-                                q, new_cache["k"], new_cache["v"], active)
-                        blocked = True
-                if not blocked:
-                    if cfg.kv_quant == "int8":
-                        ck = _kv_dequantize(new_cache["k_int8"],
-                                            new_cache["k_scale"], cfg.dtype)
-                        cv = _kv_dequantize(new_cache["v_int8"],
-                                            new_cache["v_scale"], cfg.dtype)
-                    else:
-                        ck, cv = new_cache["k"], new_cache["v"]
-                    attn_mask = jnp.broadcast_to(valid, (b, s, t))
-                    sp_mesh = (_active_sp_mesh()
-                               if (sp_prefill >= 2 and s > 1
-                                   and jnp.ndim(idx) == 0
-                                   and s % sp_prefill == 0) else None)
-                    if sp_mesh is not None:
-                        # sp-prefill continuation chunk: queries shard
-                        # over sp, the cache stays replicated (as decode
-                        # keeps it) — score memory and the softmax walk
-                        # split across the mesh, no per-layer collective
-                        from lambdipy_tpu.parallel.ring import (
-                            sp_chunk_attention)
+                            ck, cv = new_cache["k"], new_cache["v"]
+                        attn_mask = jnp.broadcast_to(valid, (b, s, t))
+                        sp_mesh = (_active_sp_mesh()
+                                   if (sp_prefill >= 2 and s > 1
+                                       and jnp.ndim(idx) == 0
+                                       and s % sp_prefill == 0) else None)
+                        if sp_mesh is not None:
+                            # sp-prefill continuation chunk: queries shard
+                            # over sp, the cache stays replicated (as decode
+                            # keeps it) — score memory and the softmax walk
+                            # split across the mesh, no per-layer collective
+                            from lambdipy_tpu.parallel.ring import (
+                                sp_chunk_attention)
 
-                        out = sp_chunk_attention(q, ck, cv, attn_mask,
-                                                 sp_mesh)
-                    else:
-                        out = _attend(q, ck, cv, attn_mask)
+                            out = sp_chunk_attention(q, ck, cv, attn_mask,
+                                                     sp_mesh)
+                        else:
+                            out = _attend(q, ck, cv, attn_mask)
 
-        out = out.reshape(b, s, cfg.heads * d)
-        x = x + QDense(cfg.hidden, cfg.quant, cfg.dtype, cfg.matmul_backend, name="o_proj")(out)
+        with jax.named_scope("o_proj"):
+            out = out.reshape(b, s, cfg.heads * d)
+            x = x + QDense(cfg.hidden, cfg.quant, cfg.dtype,
+                           cfg.matmul_backend, name="o_proj")(out)
 
-        h = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
-        if cfg.moe_experts:
-            from lambdipy_tpu.models.moe import MoEMLP
+        with jax.named_scope("mlp"):
+            h = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
+            if cfg.moe_experts:
+                from lambdipy_tpu.models.moe import MoEMLP
 
-            x = x + MoEMLP(cfg.moe_experts, cfg.mlp, cfg.moe_top_k,
-                           cfg.moe_capacity_factor, cfg.dtype, cfg.quant,
-                           group_size=cfg.moe_group_size, name="moe")(h)
-        else:
-            gate = QDense(cfg.mlp, cfg.quant, cfg.dtype, cfg.matmul_backend, name="gate_proj")(h)
-            up = QDense(cfg.mlp, cfg.quant, cfg.dtype, cfg.matmul_backend, name="up_proj")(h)
-            x = x + QDense(cfg.hidden, cfg.quant, cfg.dtype, cfg.matmul_backend, name="down_proj")(
-                nn.silu(gate) * up)
+                x = x + MoEMLP(cfg.moe_experts, cfg.mlp, cfg.moe_top_k,
+                               cfg.moe_capacity_factor, cfg.dtype, cfg.quant,
+                               group_size=cfg.moe_group_size, name="moe")(h)
+            else:
+                gate = QDense(cfg.mlp, cfg.quant, cfg.dtype,
+                              cfg.matmul_backend, name="gate_proj")(h)
+                up = QDense(cfg.mlp, cfg.quant, cfg.dtype,
+                            cfg.matmul_backend, name="up_proj")(h)
+                x = x + QDense(cfg.hidden, cfg.quant, cfg.dtype,
+                               cfg.matmul_backend, name="down_proj")(
+                    nn.silu(gate) * up)
         return x, new_cache
 
 
@@ -558,7 +579,8 @@ class LlamaModel(nn.Module):
             mask = jnp.ones((b, s), dtype=jnp.bool_)
         emb = nn.Embed(cfg.vocab_size, cfg.hidden, dtype=cfg.dtype,
                        param_dtype=cfg.dtype, name="embed")
-        x = emb(tokens)
+        with jax.named_scope("embed"):
+            x = emb(tokens)
         new_cache = []
         for i in range(n_layers):
             layer_cache = None if cache is None else cache[i]
@@ -566,12 +588,14 @@ class LlamaModel(nn.Module):
                 x, positions, mask, layer_cache, sp_prefill=sp_prefill,
                 band=band)
             new_cache.append(c)
-        x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
-        if logit_positions is not None:
-            x = jnp.take_along_axis(
-                x, jnp.broadcast_to(logit_positions[:, None, None],
-                                    (b, 1, x.shape[-1])), axis=1)
-        logits = QDense(cfg.vocab_size, cfg.quant, jnp.float32, cfg.matmul_backend, name="lm_head")(x)
+        with jax.named_scope("lm_head"):
+            x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
+            if logit_positions is not None:
+                x = jnp.take_along_axis(
+                    x, jnp.broadcast_to(logit_positions[:, None, None],
+                                        (b, 1, x.shape[-1])), axis=1)
+            logits = QDense(cfg.vocab_size, cfg.quant, jnp.float32,
+                            cfg.matmul_backend, name="lm_head")(x)
         return logits, new_cache
 
 
@@ -742,6 +766,7 @@ def page_kv_bytes(cfg: LlamaConfig, page: int) -> int:
     return int(cfg.layers * per_layer)
 
 
+@jax.named_scope("attend")
 def _gather_page_cache(arena, tables, window: int, page: int, index):
     """Materialize each row's first ``window`` positions from its block
     table into a contiguous decode cache (one dict per layer, ``index``
@@ -771,6 +796,7 @@ def _gather_page_cache(arena, tables, window: int, page: int, index):
     return out
 
 
+@jax.named_scope("kv_write")
 def _scatter_page_cache(arena, tables, cache, page: int):
     """Write a contiguous per-row cache back into its block-table pages
     (the inverse of :func:`_gather_page_cache`; ``index`` dropped).
@@ -959,6 +985,7 @@ def filter_logits(logits, *, top_k: int | None = None, top_p: float | None = Non
     return logits
 
 
+@jax.named_scope("sample")
 def filter_logits_runtime(logits, top_k, top_p):
     """:func:`filter_logits` with the knobs as RUNTIME operands, so one
     compiled program serves every request (VERDICT r2 #3: static knobs
@@ -991,6 +1018,7 @@ def filter_logits_runtime(logits, top_k, top_p):
                      logits)
 
 
+@jax.named_scope("sample")
 def _split_rows(keys):
     """Advance per-row PRNG chains one step: ``[b, 2]`` uint32 keys ->
     (new keys ``[b, 2]``, per-row subkeys ``[b, 2]``). Each row's walk is
@@ -1078,6 +1106,7 @@ def _serve_decode(model: LlamaModel, params, prompt, length, temperature,
     return _scan_decode(model, params, select, *carry, eos_id, decode_steps)
 
 
+@jax.named_scope("sample")
 def _token_logprob(lg, tok):
     """Raw model logprob of ``tok`` under fp32 logits ``lg`` [b, v] —
     log_softmax at the chosen index (knob-independent: what the MODEL
@@ -1094,6 +1123,7 @@ def _serve_select(temperature, top_k, top_p):
     row r's subkey alone, so its tokens are independent of what shares
     the batch (VERDICT r5 #2)."""
 
+    @jax.named_scope("sample")
     def select(lg, keys):
         lg = lg.astype(jnp.float32)
         t_row = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32),
@@ -1185,6 +1215,7 @@ def _next_bucket(n: int, lo: int) -> int:
     return b
 
 
+@jax.named_scope("sample")
 def _spec_accept_resample(probs, draft, keys):
     """The deterministic-draft rejection-sampling core of SAMPLED
     speculative decoding (the delta-proposal case of Leviathan-style
@@ -1218,6 +1249,7 @@ def _spec_accept_resample(probs, draft, keys):
     return m, new_tok.astype(jnp.int32)
 
 
+@jax.named_scope("sample")
 def _spec_chain_verify(select, lg, draft, lp_in, keys):
     """Chain-deterministic draft verification — the continuous engine's
     accept/rollback core (the batched counterpart of the solo verify
@@ -1532,7 +1564,10 @@ class LlamaServer:
     # dir (which persists across in-place upgrade) orphans its stale
     # executables instead of loading them. g2 = round 5: per-row knob /
     # PRNG operands + the (1,)-shaped prefix-continuation carry.
-    _AOT_GEN = "g2"
+    # g3 = PR 24: scope names inside the programs (an executable keeps
+    # the names it was compiled with; utils/compile_cache.NAMES_GEN is
+    # the same switch for the persistent cache).
+    _AOT_GEN = "g3"
 
     @classmethod
     def aot_prefix(cls) -> str:
@@ -1719,14 +1754,14 @@ class LlamaServer:
         cache_len = min(sb + steps, self.model.cfg.max_len)
 
         def build():
-            def fn(params, prompt, length, temperature, top_k, top_p, rng,
-                   eos_id):
+            def generate(params, prompt, length, temperature, top_k, top_p,
+                         rng, eos_id):
                 return _serve_decode(
                     self.model, params, prompt, length, temperature, top_k,
                     top_p, rng, eos_id, decode_steps=steps,
                     cache_len=cache_len)
 
-            return jax.jit(fn)
+            return jax.jit(generate)
 
         return self._fn_cached((b, sb, steps), build)
 
@@ -1945,7 +1980,7 @@ class LlamaServer:
         """First-chunk prefix prefill: embed the (padded) chunk into a
         full-window cache, index = true length."""
         def build():
-            def pf(params, prompt, length):
+            def prefix_first(params, prompt, length):
                 _, prefill_cache = self.model.apply(
                     params, prompt,
                     logit_positions=jnp.zeros((1,), jnp.int32))
@@ -1955,7 +1990,7 @@ class LlamaServer:
                     entry["index"] = length  # int32 scalar
                 return cache
 
-            return jax.jit(pf)
+            return jax.jit(prefix_first)
 
         return self._fn_cached(("prefix", sb, cache_len), build)
 
@@ -1968,7 +2003,7 @@ class LlamaServer:
         ragged chunk's padding stays unreachable behind the cache
         index."""
         def build():
-            def ext(params, cache, chunk, chunk_len):
+            def prefix_ext(params, cache, chunk, chunk_len):
                 idx = cache[0]["index"].reshape(())
                 cache = [{**c, "index": idx} for c in cache]
                 positions = (idx + jnp.arange(sbs))[None, :]
@@ -1982,7 +2017,7 @@ class LlamaServer:
             # donate the incoming cache: it is single-owner inside the
             # chunk loop, and without donation every ext call copies the
             # full-window KV (multi-GB at 8B) to write one chunk
-            return jax.jit(ext, donate_argnums=(1,))
+            return jax.jit(prefix_ext, donate_argnums=(1,))
 
         return self._fn_cached(("prefix_ext", sbs), build)
 
@@ -1998,7 +2033,7 @@ class LlamaServer:
             raise ValueError(f"sp first-chunk width {sb} % sp={sp} != 0")
 
         def build():
-            def pf(params, prompt, length):
+            def sp_first(params, prompt, length):
                 _, prefill_cache = self.model.apply(
                     params, prompt,
                     logit_positions=jnp.zeros((1,), jnp.int32),
@@ -2009,7 +2044,7 @@ class LlamaServer:
                     entry["index"] = length  # int32 scalar
                 return cache
 
-            return jax.jit(pf)
+            return jax.jit(sp_first)
 
         return self._fn_cached(("sp_prefill", 1, sb // sp, cache_len, sp),
                                build)
@@ -2026,7 +2061,7 @@ class LlamaServer:
             raise ValueError(f"sp round width {sbs} % sp={sp} != 0")
 
         def build():
-            def ext(params, cache, chunk, chunk_len):
+            def sp_ext(params, cache, chunk, chunk_len):
                 idx = cache[0]["index"].reshape(())
                 cache = [{**c, "index": idx} for c in cache]
                 positions = (idx + jnp.arange(sbs))[None, :]
@@ -2038,7 +2073,7 @@ class LlamaServer:
                     entry["index"] = idx + chunk_len
                 return new_cache
 
-            return jax.jit(ext, donate_argnums=(1,))
+            return jax.jit(sp_ext, donate_argnums=(1,))
 
         return self._fn_cached(("sp_prefill_ext", 1, sbs // sp, sp), build)
 
@@ -2169,8 +2204,8 @@ class LlamaServer:
         cache_len = cache_width(cache)
 
         def build():
-            def fn(params, cache, suffix, suffix_len, temperature, top_k,
-                   top_p, rng, eos_id):
+            def generate_prefix(params, cache, suffix, suffix_len,
+                                temperature, top_k, top_p, rng, eos_id):
                 select = _serve_select(temperature, top_k, top_p)
                 carry = _continue_prefill(self.model, params, cache, suffix,
                                           suffix_len, select, rng, eos_id,
@@ -2178,7 +2213,7 @@ class LlamaServer:
                 return _scan_decode(self.model, params, select, *carry,
                                     eos_id, steps)
 
-            return jax.jit(fn)
+            return jax.jit(generate_prefix)
 
         cont_fn = self._fn_cached(("continue", sbs, steps, cache_len), build)
         suffix_op, _ = self._pad_rows(rows, lengths, 1, sbs)
@@ -2244,20 +2279,25 @@ class LlamaServer:
             def seg(params, temperature, top_k, top_p, first, lp, cache,
                     pos, done, rng, eos_id):
                 select = _serve_select(temperature, top_k, top_p)
-                win = [{name: (val if name == "index"
-                               else jax.lax.slice_in_dim(val, 0, window,
-                                                         axis=1))
-                        for name, val in entry.items()} for entry in cache]
+                # the window's two copies per segment have a scope of
+                # their own, apart from the step's kv_write and attend
+                with jax.named_scope("kv_window"):
+                    win = [{name: (val if name == "index"
+                                   else jax.lax.slice_in_dim(
+                                       val, 0, window, axis=1))
+                            for name, val in entry.items()}
+                           for entry in cache]
                 (toks, lps), carry = _scan_decode(
                     self.model, params, select, first, lp, win, pos, done,
                     rng, eos_id, segment, return_carry=True)
                 f2, lp2, wcache, pos2, done2, rng2 = carry
-                merged = [
-                    {name: (val if name == "index"
-                            else jax.lax.dynamic_update_slice_in_dim(
-                                cache[i][name], val, 0, axis=1))
-                     for name, val in entry.items()}
-                    for i, entry in enumerate(wcache)]
+                with jax.named_scope("kv_window"):
+                    merged = [
+                        {name: (val if name == "index"
+                                else jax.lax.dynamic_update_slice_in_dim(
+                                    cache[i][name], val, 0, axis=1))
+                         for name, val in entry.items()}
+                        for i, entry in enumerate(wcache)]
                 return (toks, lps), (f2, lp2, merged, pos2, done2, rng2)
 
             return jax.jit(seg)
@@ -2557,8 +2597,8 @@ class LlamaServer:
         duplicate, no peak-HBM spike; the hit's cost is a refcount
         bump plus the suffix prefill the request owes anyway."""
         def build():
-            def cont(params, arena, table, plen, suffix, suffix_len,
-                     temperature, top_k, top_p, rng, eos_id):
+            def paged_continue(params, arena, table, plen, suffix, suffix_len,
+                               temperature, top_k, top_p, rng, eos_id):
                 select = _serve_select(temperature, top_k, top_p)
                 cache = _gather_page_cache(arena, table, window, page, plen)
                 first, lp0, new_cache, start, done0, keys = \
@@ -2568,7 +2608,7 @@ class LlamaServer:
                                                 page)
                 return first, lp0, new_arena, start, done0, keys
 
-            return jax.jit(cont)
+            return jax.jit(paged_continue)
 
         return self._fn_cached(("pcont", sbs, n_pages, page, window), build)
 
@@ -2615,8 +2655,8 @@ class LlamaServer:
         prefill schedule; with ``base = 0`` and one chunk it computes
         exactly the paged continuation."""
         def build():
-            def cont(params, arena, table, local, base, suffix,
-                     suffix_len, temperature, top_k, top_p, rng, eos_id):
+            def lpaged_continue(params, arena, table, local, base, suffix,
+                                suffix_len, temperature, top_k, top_p, rng, eos_id):
                 select = _serve_select(temperature, top_k, top_p)
                 cache = _gather_page_cache(arena, table, window, page,
                                            local)
@@ -2628,7 +2668,7 @@ class LlamaServer:
                                                 page)
                 return first, lp0, new_arena, start, done0, keys
 
-            return jax.jit(cont)
+            return jax.jit(lpaged_continue)
 
         return self._fn_cached(("lpcont", sbs, n_pages, page, window),
                                build)
@@ -2652,8 +2692,8 @@ class LlamaServer:
         uw = (n_chunks + 1) * w2  # union view: prior half-window + round
 
         def build():
-            def rnd(params, arena, table, prior_len, base, chunk,
-                    round_len, temperature, top_k, top_p, rng, eos_id):
+            def lsp_round(params, arena, table, prior_len, base, chunk,
+                          round_len, temperature, top_k, top_p, rng, eos_id):
                 select = _serve_select(temperature, top_k, top_p)
                 cache = _gather_page_cache(arena, table, uw, page,
                                            prior_len)
@@ -2666,7 +2706,7 @@ class LlamaServer:
                                                 page)
                 return first, lp0, new_arena, start, done0, keys
 
-            return jax.jit(rnd)
+            return jax.jit(lsp_round)
 
         return self._fn_cached(
             ("sp_pprefill", n_chunks, n_pages, page, window, sp), build)
@@ -2676,11 +2716,11 @@ class LlamaServer:
         attached): the prefix store's extend path continues a cold walk
         from cached pages without any host-visible assembly."""
         def build():
-            def g(arena, table, index):
+            def page_gather(arena, table, index):
                 return _gather_page_cache(arena, table, window, page,
                                           index)
 
-            return jax.jit(g)
+            return jax.jit(page_gather)
 
         return self._fn_cached(("pgather", n_pages, page, window), build)
 
@@ -2690,7 +2730,7 @@ class LlamaServer:
         the prefix store's insertion primitive (one program total; the
         page id is a traced operand)."""
         def build():
-            def w(arena, pid, block_kv):
+            def page_write(arena, pid, block_kv):
                 new = []
                 for aentry, bentry in zip(arena, block_kv):
                     e = {}
@@ -2702,7 +2742,7 @@ class LlamaServer:
                     new.append(e)
                 return new
 
-            return jax.jit(w)
+            return jax.jit(page_write)
 
         return self._fn_cached(("pwrite", n_pages, page), build)
 
@@ -2718,14 +2758,14 @@ class LlamaServer:
         engine's chunked joiner prefill) — sharing the default key
         would collide with its shape-strict AOT executable."""
         def build():
-            def cont(params, cache, suffix, suffix_len, temperature, top_k,
-                     top_p, rng, eos_id):
+            def stream_prefix(params, cache, suffix, suffix_len,
+                              temperature, top_k, top_p, rng, eos_id):
                 select = _serve_select(temperature, top_k, top_p)
                 return _continue_prefill(self.model, params, cache, suffix,
                                          suffix_len, select, rng, eos_id,
                                          sbs)
 
-            return jax.jit(cont)
+            return jax.jit(stream_prefix)
 
         key = (("stream_prefix", sbs) if cache_len is None
                else ("stream_prefix", sbs, cache_len))
@@ -2867,7 +2907,7 @@ class LlamaServer:
         attention validity mask never exposes entries past it, so the
         stale K/V written for rejected drafts is unreachable."""
         def build():
-            def vf(params, draft, tok, cache):
+            def spec_verify(params, draft, tok, cache):
                 idx = cache[0]["index"].reshape(())  # scalar-index branch
                 cache = [{**c, "index": idx} for c in cache]
                 chunk = jnp.concatenate(
@@ -2893,7 +2933,7 @@ class LlamaServer:
                     entry["index"] = new_idx
                 return chunk[0], lp_g, count, new_tok, new_cache
 
-            return jax.jit(vf)
+            return jax.jit(spec_verify)
 
         return self._fn_cached(("spec", kb, cache_len), build)
 
@@ -3094,8 +3134,8 @@ class LlamaServer:
         sampled stream — the draw structure differs — but
         seed-deterministic within the speculative path)."""
         def build():
-            def vf(params, draft, tok, cache, temperature, top_k, top_p,
-                   keys):
+            def spec_sampled_verify(params, draft, tok, cache, temperature,
+                                    top_k, top_p, keys):
                 idx = cache[0]["index"].reshape(())
                 cache = [{**c, "index": idx} for c in cache]
                 chunk = jnp.concatenate(
@@ -3129,7 +3169,7 @@ class LlamaServer:
                 return (chunk[0], lp_out, count, new_tok.reshape(1),
                         new_cache)
 
-            return jax.jit(vf)
+            return jax.jit(spec_sampled_verify)
 
         return self._fn_cached(("spec_s", kb, cache_len), build)
 

@@ -32,6 +32,7 @@ import time
 from pathlib import Path
 from typing import Any, Callable, Sequence
 
+from lambdipy_tpu.runtime import spans
 from lambdipy_tpu.utils.fsutil import atomic_write_bytes, atomic_write_text
 from lambdipy_tpu.utils.logs import get_logger
 
@@ -301,7 +302,8 @@ class AotStore:
                 if tier not in meta.get("tiers", ()):
                     continue
                 try:
-                    with self._mesh_ctx():
+                    with spans.span("boot.aot_load", program=name,
+                                    tier=tier), self._mesh_ctx():
                         fn = self._load_tier(tier, paths)
                 except Exception:
                     continue
@@ -368,7 +370,8 @@ class AotStore:
             # weight upload); only the probe remains
             fn, tried = pre
             try:
-                with self._mesh_ctx():
+                with spans.span("boot.warm", program=name,
+                                tier=tried), self._mesh_ctx():
                     _probe(fn)
                 return fn, tried
             except Exception as e:
@@ -379,9 +382,13 @@ class AotStore:
                 continue
             try:
                 with self._mesh_ctx():
-                    fn = self._load_tier(tier, paths)
+                    with spans.span("boot.aot_load", program=name,
+                                    tier=tier):
+                        fn = self._load_tier(tier, paths)
                     if fn is not None:
-                        _probe(fn)
+                        with spans.span("boot.warm", program=name,
+                                        tier=tier):
+                            _probe(fn)
                         return fn, tier
             except Exception as e:
                 log.warning("aot %s: %s tier failed to load: %s", name, tier, e)

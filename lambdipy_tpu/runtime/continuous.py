@@ -114,6 +114,7 @@ import threading
 import time
 from typing import Any
 
+from lambdipy_tpu.runtime import spans
 from lambdipy_tpu.runtime.faults import EngineWatchdogTimeout, FaultPlan
 from lambdipy_tpu.utils.logs import get_logger
 
@@ -827,9 +828,10 @@ class ContinuousBatcher:
             if (key := _next_bucket(count, 1)) in seen:
                 continue
             seen.add(key)
-            warm([dict(row=[1, 2, 3], s=3, temperature=None,
-                       top_k=None, top_p=None, seed=None)
-                  for _ in range(count)])
+            with spans.span("boot.warm", program=f"group-prefill-{key}x3"):
+                warm([dict(row=[1, 2, 3], s=3, temperature=None,
+                           top_k=None, top_p=None, seed=None)
+                      for _ in range(count)])
         n = len(seen)
         # the long-prompt family: one warm at the largest joiner bucket.
         # Rows must still be engine-admittable (s + max_new <= cache_len)
@@ -839,9 +841,11 @@ class ContinuousBatcher:
         warm_sb = _next_bucket(s_warm, self.server.min_bucket)
         if counts and warm_sb != min_sb:
             row = list(range(1, s_warm + 1))
-            warm([dict(row=row, s=s_warm, temperature=None,
-                       top_k=None, top_p=None, seed=None)
-                  for _ in range(max(counts))])
+            with spans.span("boot.warm",
+                            program=f"group-prefill-{max(counts)}x{s_warm}"):
+                warm([dict(row=row, s=s_warm, temperature=None,
+                           top_k=None, top_p=None, seed=None)
+                      for _ in range(max(counts))])
             n += 1
         return n
 
@@ -1364,6 +1368,15 @@ class ContinuousBatcher:
         # time (the window was chosen then — recording it at collect
         # keeps DecodeWindowStats truthful about queued segments)
         inflight: deque = deque()
+        # the loop's leaf phases (eng.barrier, eng.prefill, eng.pack,
+        # eng.dispatch, eng.wait, eng.fetch, eng.book): one is open at
+        # any moment from here to the loop's exit, none encloses another
+        phase = spans.phases()
+
+        def rids(entries) -> str:
+            """The requests a phase works for, as its ``rids`` argument."""
+            return spans.rids_arg(e["rid"] for e in entries)
+
         ep_t0 = time.monotonic()
         # mark the episode open so report()'s wall (and overlap_ratio)
         # includes the in-progress episode: under sustained traffic the
@@ -1379,6 +1392,8 @@ class ContinuousBatcher:
             on pipeline_depth >= 2 the device is computing the next
             segment during this fetch + bookkeeping window."""
             rec = inflight.popleft()
+            served = rids(e for _, e in rec["rows"])
+            phase.enter("eng.wait", rids=served)
             # compute-ready marker for the overlap ratio: the device is
             # done with this segment here; whatever the fetch costs past
             # this point only keeps the device busy if another segment
@@ -1388,6 +1403,7 @@ class ContinuousBatcher:
             self._device_wait("transport", gen,
                               jax.block_until_ready, rec["toks"])
             t_ready = time.monotonic()
+            phase.enter("eng.fetch", rids=served)
             if self.synthetic_fetch_rtt_ms > 0:
                 # fetch-latency model: the delay starts once device
                 # compute is done and blocks only THIS fetch — segments
@@ -1420,6 +1436,7 @@ class ContinuousBatcher:
             block, lp_block, counts_h, pending_h = self._device_wait(
                 "segment_fetch", gen, fetch)
             t_end = time.monotonic()
+            phase.enter("eng.book", rids=served)
             if self._had_failure:
                 # first successful fetch after a failure: the engine is
                 # demonstrably serving again — clear the wedge and count
@@ -1530,6 +1547,7 @@ class ContinuousBatcher:
                 # retirement and joiner packing only happen at these
                 # drain barriers, so in-flight segments never see their
                 # slot repurposed under them. ----
+                barrier = phase.enter("eng.barrier")
                 with self._lock:
                     if gen != self._gen:
                         raise _StaleEngine()
@@ -1569,8 +1587,11 @@ class ContinuousBatcher:
                             self._joiners.remove(joiner)
                             joiner["slot"] = free.pop(0)
                             self._active[joiner["slot"]] = joiner
+                            spans.mark(joiner["rid"], "req.join")
                     packing = [a for a in self._active
                                if a is not None and not a.get("packed")]
+                    if packing:
+                        barrier.set(rids=rids(packing))
                     if not any(self._active):
                         # idle: engine exits; next request restarts it
                         self._engine_running = False
@@ -1601,6 +1622,8 @@ class ContinuousBatcher:
                 # evicted
                 for j in [a for a in packing if a.get("carry") is None
                           and a.get("prefix_toks") is not None]:
+                    phase.enter("eng.prefill", rids=rids([j]),
+                                bucket=j["s"], kind="prefix")
                     try:
                         if pool is not None:
                             # a replayed PAGED prefix row kept its pages
@@ -1638,6 +1661,8 @@ class ContinuousBatcher:
                     ck = self.server.prefill_chunk
                     chunked = (ck and j["s"] > ck
                                and self.cache_len % ck == 0)
+                    phase.enter("eng.prefill", rids=rids([j]),
+                                bucket=j["s"], kind="long")
                     try:
                         j["carry"] = self._device_wait(
                             "group_prefill", gen,
@@ -1662,6 +1687,10 @@ class ContinuousBatcher:
                             self._lock.notify_all()
                 group_carry = None
                 if raw:
+                    phase.enter(
+                        "eng.prefill", rids=rids(raw), kind="group",
+                        bucket=_next_bucket(max(j["s"] for j in raw),
+                                            server.min_bucket))
                     try:
                         group_carry = self._device_wait(
                             "group_prefill", gen, self._prefill_group, raw)
@@ -1709,6 +1738,7 @@ class ContinuousBatcher:
                                     attempted=retried)
                             self._lock.notify_all()
                         raw = []
+                phase.enter("eng.pack", rids=rids(raw + carried))
                 for src, joiner in enumerate(raw):
                     if pool is not None:
                         # scalars into the 5-leaf carry, the KV row
@@ -1719,6 +1749,7 @@ class ContinuousBatcher:
                         self._carry = self._pack(self._carry, group_carry,
                                                  src, joiner["slot"])
                     joiner["packed"] = True
+                    spans.mark(joiner["rid"], "req.prefill")
                 group_carry = None  # free the group cache
                 for joiner in carried:
                     if pool is not None and len(joiner["carry"]) == 5:
@@ -1738,6 +1769,7 @@ class ContinuousBatcher:
                                                  joiner["slot"])
                     joiner["carry"] = None  # free the 1-row cache
                     joiner["packed"] = True
+                    spans.mark(joiner["rid"], "req.prefill")
                 if pool is not None:
                     # the per-slot block tables the paged segment
                     # programs index by — host truth, rebuilt once per
@@ -1756,6 +1788,7 @@ class ContinuousBatcher:
                     # ladder level >= 1 forces the synchronous depth-1
                     # loop: a failing device gets one outstanding wait
                     # at a time, the easiest shape to recover
+                    dispatching = phase.enter("eng.dispatch")
                     eff_depth = (1 if self.fault_stats.degrade_level >= 1
                                  else self.pipeline_depth)
                     # speculative verify width for THIS dispatch: ladder
@@ -1946,6 +1979,8 @@ class ContinuousBatcher:
                     else:
                         seg = seg_full
                     t_disp = time.monotonic()
+                    dispatching.set(rids=rids(e for _, e in live),
+                                    window=window, rows=len(positions))
 
                     def dispatch():
                         knob_ops = (jnp.asarray(t_host),
@@ -2014,6 +2049,7 @@ class ContinuousBatcher:
                     while inflight:
                         collect_one()
         finally:
+            phase.exit()
             pstats.record_wall(time.monotonic() - ep_t0)
 
     def _prefill_prefix_row(self, prefix_tokens, row, s: int, entry: dict,
@@ -2053,7 +2089,8 @@ class ContinuousBatcher:
         import numpy as np
 
         from lambdipy_tpu.sched import (current_request_class,
-                                        current_request_deadline_ms)
+                                        current_request_deadline_ms,
+                                        current_request_rid)
 
         if max_new_tokens <= 0:
             return None
@@ -2092,7 +2129,10 @@ class ContinuousBatcher:
                  "row": row, "s": s, "prefix_toks": None,
                  "deadline_at": (time.monotonic() + deadline_ms / 1e3
                                  if deadline_ms else None),
-                 "cls": current_request_class(), "seq": next(_entry_seq)}
+                 "cls": current_request_class(), "seq": next(_entry_seq),
+                 # the request's span id: the engine stamps its tiles
+                 # (req.join, req.prefill) and names its phases by it
+                 "rid": current_request_rid()}
         # per-row draft-tier state (inert when spec is off): the row's
         # CURRENT provider along the fallback chain, its adaptive draft
         # width, and the acceptance EWMA the collector folds each
@@ -2209,6 +2249,10 @@ class ContinuousBatcher:
             except BaseException:
                 self._release_pages(entry)
                 raise
+        # everything since the scheduler's grant — the handler's own work
+        # and a long prompt's prefill on this thread — is req.admit; the
+        # wait for a slot (req.join) starts here
+        spans.mark(entry["rid"], "req.admit")
         with self._lock:
             self._joiners.append(entry)
             if not self._engine_running:

@@ -1093,6 +1093,8 @@ def generate_handler(spec: dict, ctx) -> HandlerState:
     # Progress rides /metrics (handler.warm_buckets).
     import threading
 
+    from lambdipy_tpu.runtime import spans
+
     # "in_flight" is the readiness signal /healthz exposes: True from the
     # moment the warm thread is committed until it finishes, so a fleet
     # router can hold traffic off a still-compiling replica
@@ -1130,8 +1132,9 @@ def generate_handler(spec: dict, ctx) -> HandlerState:
             # operator opted into that trade by listing warm_buckets.
             for size in warm_state["requested"]:
                 try:
-                    server.generate([list(range(1, size + 1))],
-                                    max_new_tokens=default_new)
+                    with spans.span("boot.warm", program=f"bucket-{size}"):
+                        server.generate([list(range(1, size + 1))],
+                                        max_new_tokens=default_new)
                     with _warm_lock:
                         warm_state["done"].append(size)
                 except Exception as e:  # background QoS, never fatal —

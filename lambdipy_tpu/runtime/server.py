@@ -31,7 +31,9 @@ import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from pathlib import Path
+from urllib.parse import parse_qs, urlsplit
 
+from lambdipy_tpu.runtime import spans
 from lambdipy_tpu.runtime.continuous import RequestCancelled
 from lambdipy_tpu.runtime.loader import BootReport, load_bundle
 from lambdipy_tpu.runtime.pagepool import PagesExhausted
@@ -48,6 +50,10 @@ from lambdipy_tpu.utils.logs import get_logger, log_event
 from lambdipy_tpu.utils.platform import device_report
 
 log = get_logger("lambdipy.server")
+
+# the longest wall window POST /profile traces: the profiler needed over two
+# minutes to write out 4 s of a serving 7B (PERF.md section 6)
+PROFILE_MAX_S = 4.0
 
 
 def _request_token_counts(request: dict | None,
@@ -224,6 +230,10 @@ class BundleServer:
     def _make_handler(server_self):
         class Handler(BaseHTTPRequestHandler):
             protocol_version = "HTTP/1.1"
+            # the request being served on this connection: its span id and
+            # the moment its body had been read (runtime/spans.py)
+            _rid = None
+            _t_read = None
 
             def log_message(self, fmt, *args):  # route through structured logs
                 log.debug(fmt % args)
@@ -321,6 +331,9 @@ class BundleServer:
                     # counts by reason/class, per-class queue-wait
                     # percentiles, cost-model state
                     report["sched"] = server_self.sched.report()
+                    # per-name span aggregates (count, sum_s, buckets):
+                    # they only grow, so two scrapes give a window
+                    report["spans"] = spans.report()
                     handler_stats = getattr(server_self.boot.state, "stats",
                                             lambda: {})()
                     if handler_stats:
@@ -333,6 +346,16 @@ class BundleServer:
                         report["compile"] = \
                             server_self.boot.compile_counters.report()
                     self._send(200, report)
+                elif urlsplit(self.path).path == "/spans":
+                    # the last finished requests, each with its tiles
+                    # (?last=N: only the newest N of the ring)
+                    last = parse_qs(urlsplit(self.path).query).get("last")
+                    try:
+                        self._send(200, spans.requests(
+                            int(last[0]) if last else None))
+                    except ValueError:
+                        self._send(400, {"ok": False, "error":
+                                         "last must be an integer"})
                 else:
                     self._send(404, {"ok": False, "error": "not found"})
 
@@ -342,6 +365,7 @@ class BundleServer:
                 try:
                     length = int(self.headers.get("Content-Length", 0))
                     body = json.loads(self.rfile.read(length) or b"{}")
+                    self._t_read = time.monotonic()
                     if not isinstance(body, dict):
                         raise ValueError("body must be a JSON object")
                     return body
@@ -366,6 +390,20 @@ class BundleServer:
                 self._send(shed.code, payload, headers)
 
             def _begin_invoke(self, request: dict | None = None, *,
+                              openai: bool = False):
+                """Open the request's span record (its ``rid`` rides the
+                ticket and the request context down to the engine), then
+                the admission gate; a refused request's record closes
+                here, with ``req`` alone."""
+                t_read, self._t_read = self._t_read, None  # of THIS request
+                self._rid = spans.begin_request(t_read)
+                ticket = self._admit_invoke(request, openai=openai)
+                if ticket is None:
+                    spans.end_request(self._rid)
+                    self._rid = None
+                return ticket
+
+            def _admit_invoke(self, request: dict | None = None, *,
                               openai: bool = False):
                 """Admission gate every invoke passes: draining check +
                 in-flight increment as one atomic step (stop() can then
@@ -412,9 +450,13 @@ class BundleServer:
                     request,
                     prefix_probe=getattr(server_self.boot.state,
                                          "prefix_probe", None))
+                spans.request_args(self._rid, prompt_tokens=prefill,
+                                   max_tokens=decode)
+                spans.mark(self._rid, None)  # req.sched starts here
                 out = server_self.sched.admit(
                     tenant=tenant, cls=cls, deadline_ms=deadline_ms,
-                    prefill_tokens=prefill, decode_tokens=decode)
+                    prefill_tokens=prefill, decode_tokens=decode,
+                    rid=self._rid)
                 if isinstance(out, Shed):
                     with server_self._inflight_lock:
                         server_self._inflight -= 1
@@ -429,14 +471,20 @@ class BundleServer:
                         Shed(503, "deadline",
                              max(0.05, out.cost_ms / 1e3)), openai=openai)
                     return None
+                spans.mark(self._rid, "req.sched")
                 # the batchers read the request's class from this context
                 # when forming batches (policy-ordered handoff)
                 set_request_context(cls=out.cls, tenant=tenant,
-                                    deadline_ms=deadline_ms)
+                                    deadline_ms=deadline_ms, rid=self._rid)
                 return out
 
             def _end_invoke(self, ticket, t0: float) -> None:
                 clear_request_context()
+                # everything is written: an unstreamed response was the
+                # request's first frame and its last
+                spans.first_frame(self._rid)
+                spans.end_request(self._rid)
+                self._rid = None
                 # feed the estimator with slot-occupancy time (errors
                 # included — an erroring request still held the slot)
                 server_self.sched.finish(
@@ -504,12 +552,15 @@ class BundleServer:
                     if req is None:
                         return
                     try:
-                        n = max(1, min(int(req.get("invokes", 3)), 100))
+                        seconds = float(req.get("seconds"))
+                        if not 0 < seconds <= PROFILE_MAX_S:
+                            raise ValueError
                     except (TypeError, ValueError):
-                        self._send(400, {"ok": False,
-                                         "error": "invokes must be an integer"})
+                        self._send(400, {"ok": False, "error":
+                                         f"seconds must be a number in "
+                                         f"(0, {PROFILE_MAX_S:g}]"})
                         return
-                    # capture a device trace around N warmup-shaped invokes;
+                    # trace that wall window of whatever traffic is live;
                     # serialized — concurrent start_trace calls would fail
                     try:
                         from lambdipy_tpu.utils.trace import (
@@ -520,9 +571,7 @@ class BundleServer:
                         out_dir = server_self.bundle_dir / "profiles" / str(int(time.time()))
                         with server_self._profile_lock:
                             with profile_trace(out_dir) as capture:
-                                for _ in range(n):
-                                    server_self.boot.handler.invoke(
-                                        server_self.boot.state, {"warmup": True})
+                                time.sleep(seconds)
                         payload = {"ok": capture.started, "dir": str(out_dir),
                                    "files": latest_trace_files(out_dir)}
                         if capture.error:
@@ -1097,6 +1146,7 @@ class BundleServer:
                     self.wfile.write(f"{len(body):x}\r\n".encode())
                     self.wfile.write(body)
                     self.wfile.write(b"\r\n")
+                    spans.first_frame(self._rid)  # counts once a request
                     return True
                 except OSError:
                     self.close_connection = True

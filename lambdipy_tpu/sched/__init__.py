@@ -36,7 +36,8 @@ from lambdipy_tpu.sched.queue import CLASSES, RequestQueue, Ticket
 
 __all__ = ["Scheduler", "Shed", "Ticket", "CLASSES",
            "set_request_context", "clear_request_context",
-           "current_request_class", "current_request_deadline_ms"]
+           "current_request_class", "current_request_deadline_ms",
+           "current_request_rid"]
 
 
 # -- request context ---------------------------------------------------------
@@ -49,12 +50,14 @@ _ctx = threading.local()
 
 
 def set_request_context(cls: str = "interactive", tenant: str = "anon",
-                        deadline_ms: float | None = None) -> None:
+                        deadline_ms: float | None = None,
+                        rid: int | None = None) -> None:
     _ctx.cls, _ctx.tenant, _ctx.deadline_ms = cls, tenant, deadline_ms
+    _ctx.rid = rid
 
 
 def clear_request_context() -> None:
-    _ctx.cls = _ctx.tenant = _ctx.deadline_ms = None
+    _ctx.cls = _ctx.tenant = _ctx.deadline_ms = _ctx.rid = None
 
 
 def current_request_class() -> str:
@@ -67,6 +70,12 @@ def current_request_deadline_ms() -> float | None:
     mid-decode at the next drain barrier instead of decoding them to
     completion."""
     return getattr(_ctx, "deadline_ms", None)
+
+
+def current_request_rid() -> int | None:
+    """The request's span id (``runtime/spans.py``), taken when the server
+    had read it; the engine stamps the request's tiles under it."""
+    return getattr(_ctx, "rid", None)
 
 
 # -- scheduler ---------------------------------------------------------------
@@ -141,7 +150,7 @@ class Scheduler:
 
     def admit(self, *, tenant: str = "anon", cls: str = "interactive",
               deadline_ms: float | None = None, prefill_tokens: int = 0,
-              decode_tokens: int = 0) -> Ticket | Shed:
+              decode_tokens: int = 0, rid: int | None = None) -> Ticket | Shed:
         if cls not in CLASSES:
             cls = "interactive"
         cost_ms = self.estimator.estimate(prefill_tokens, decode_tokens)
@@ -161,7 +170,7 @@ class Scheduler:
             ticket = Ticket(cls=cls, tenant=tenant,
                             deadline_ms=deadline_ms, cost_ms=cost_ms,
                             prefill_tokens=prefill_tokens,
-                            decode_tokens=decode_tokens)
+                            decode_tokens=decode_tokens, rid=rid)
             self.queue.push(ticket)
             self.admitted += 1
             self._pump_locked()

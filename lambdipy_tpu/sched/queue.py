@@ -36,6 +36,7 @@ class Ticket:
     granted: bool = False
     expired: bool = False          # deadline shed after admission
     wait_ms: float | None = None   # actual queue wait, stamped at grant
+    rid: int | None = None         # the request's span id (runtime/spans.py)
 
 
 class RequestQueue:

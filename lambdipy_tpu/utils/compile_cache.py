@@ -22,6 +22,13 @@ from pathlib import Path
 from lambdipy_tpu.utils.platform import REPO_ROOT
 
 CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# The cache's key leaves a program's names out (jax strips debug info before
+# it hashes), so a program whose computation is unchanged would be served
+# the executable compiled under its OLD scope names, and a trace would
+# show those. Bump this when a ``jax.named_scope`` of ``models/`` or
+# ``ops/`` is renamed, added or moved, or a jitted function is renamed
+# (``LlamaServer._AOT_GEN`` is the same switch for the bundle's AOT tier).
+NAMES_GEN = "names-1"
 CHECKOUT_CACHE = REPO_ROOT / ".lambdipy_cache" / "compile"
 
 
@@ -46,6 +53,13 @@ def enable_compile_cache(bundle_dir: Path | None = None) -> Path:
         jax.config.update("jax_compilation_cache_dir", str(cache_dir))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    from jax._src import cache_key
+
+    if not callable(getattr(cache_key, "custom_hook", None)):
+        raise RuntimeError("this jax has no cache_key.custom_hook: the "
+                           "persistent cache could serve programs compiled "
+                           "under other scope names")
+    cache_key.custom_hook = lambda: NAMES_GEN
     return cache_dir or Path(os.environ[CACHE_ENV])
 
 

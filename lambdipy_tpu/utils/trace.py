@@ -1,13 +1,12 @@
-"""Tracing/profiling (SURVEY.md §6: absent in the reference; first-class
-here).
+"""Profiler capture for a serving process.
 
-Two layers:
-- :func:`profile_trace` — ``jax.profiler`` capture to a directory, viewable
-  with tensorboard-plugin-profile (the canonical TPU stack per the
-  jax-stable-stack image, SURVEY.md §3.4 ``jss:tpu/Dockerfile:94``). Used
-  by the serve loop's ``/profile`` endpoint and ad-hoc by benchmarks.
-- build/serve stage timing — :class:`lambdipy_tpu.utils.timing.StageTimer`
-  records per-stage wall time into manifests and /healthz.
+:func:`profile_trace` captures a ``jax.profiler`` trace into a directory
+(an ``.xplane.pb`` under ``plugins/profile/<time>/``, read with
+``jax.profiler.ProfileData`` or opened in tensorboard-plugin-profile /
+xprof). ``POST /profile {"seconds": s}`` of the serve loop wraps a wall
+window of live traffic in it: device operations under their scope names
+(``models/llama.py``) and the program's ``eng.*`` / ``boot.*`` spans
+(``runtime/spans.py``) on one clock.
 """
 
 from __future__ import annotations
@@ -30,16 +29,21 @@ class TraceCapture:
 
 @contextmanager
 def profile_trace(out_dir: Path):
-    """Capture a jax profiler trace into ``out_dir`` (xplane protos +
-    trace.json.gz). Never raises — serving must not die to tracing — but
-    the yielded :class:`TraceCapture` reports whether the profiler engaged
-    (it won't if jax is absent or another trace is already active)."""
+    """Capture a jax profiler trace into ``out_dir``. Host events are
+    recorded at level 1 (the program's spans, jit dispatches); the
+    python tracer is off: its frames are large and nothing reads them.
+    Never raises — serving must not die to tracing — but the yielded
+    :class:`TraceCapture` reports whether the profiler engaged (it won't
+    if jax is absent or another trace is already active)."""
     capture = TraceCapture(out_dir)
     capture.out_dir.mkdir(parents=True, exist_ok=True)
     try:
         import jax
 
-        jax.profiler.start_trace(str(capture.out_dir))
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        jax.profiler.start_trace(str(capture.out_dir), profiler_options=opts)
         capture.started = True
     except Exception as e:
         capture.error = f"{type(e).__name__}: {e}"

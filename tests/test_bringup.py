@@ -131,6 +131,44 @@ def test_cache_dir_unset_is_fixed_inside_the_checkout(monkeypatch, tmp_path):
         tmp_path / "b" / "compile_cache"
 
 
+def test_the_cache_key_changes_with_the_names_generation(monkeypatch,
+                                                        tmp_path):
+    """jax leaves a program's names out of the persistent cache's key, so
+    the program salts the key with ``NAMES_GEN``: the same computation
+    under another generation of scope names is another entry."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax._src import cache_key, compiler
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "placed"))
+    monkeypatch.setattr(cache_key, "custom_hook", cache_key.custom_hook)
+    compile_cache.enable_compile_cache()
+    assert cache_key.custom_hook() == compile_cache.NAMES_GEN
+
+    def key_of(fn):
+        module = jax.jit(fn).lower(jnp.ones((4,))).compiler_ir()
+        devices = np.array(jax.devices()[:1])
+        options = compiler.get_compile_options(num_replicas=1,
+                                               num_partitions=1)
+        return cache_key.get(module, devices, options, devices[0].client)
+
+    def plain(x):
+        return x * 2
+
+    def scoped(x):
+        with jax.named_scope("mlp"):
+            return x * 2
+
+    scoped.__name__ = "plain"
+    # names alone do not move the key ...
+    assert key_of(plain) == key_of(scoped)
+    first = key_of(plain)
+    # ... the generation does
+    monkeypatch.setattr(compile_cache, "NAMES_GEN", "names-other")
+    assert key_of(plain) != first
+
+
 # -- mesh ----------------------------------------------------------------------------
 
 

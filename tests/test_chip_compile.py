@@ -115,3 +115,48 @@ def test_int8_matmul_compiles(v5e, m):
 
     _compile_for(v5e, int8_matmul, ((m, HIDDEN), jnp.bfloat16),
                  ((HIDDEN, MLP), jnp.int8), ((1, MLP), jnp.float32))
+
+
+def test_scope_names_survive_the_tpu_compiler(v5e):
+    """The decode segment (`jit_seg`, the window-bucketed variant the
+    continuous engine dispatches) at Mistral-7B widths and one layer: after
+    XLA's fusion every scope of ``models/llama.py`` is still the op_name of
+    some device operation — what ``benchmark/scopes.py`` splits a trace by
+    (PERF.md section 3). ~20 s."""
+    import re
+
+    from lambdipy_tpu.models.llama import (LlamaConfig, LlamaModel,
+                                           LlamaServer, init_decode_cache)
+
+    cfg = LlamaConfig(vocab_size=32768, hidden=HIDDEN, layers=1, heads=H,
+                      kv_heads=KVH, mlp=MLP, rope_theta=1e6, norm_eps=1e-5,
+                      max_len=8192, dtype=jnp.bfloat16, quant="int8")
+    model = LlamaModel(cfg)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e),
+            tree)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                                    jnp.zeros((1, 8), jnp.int32)))
+    cache = jax.eval_shape(lambda: init_decode_cache(cfg, B, T))
+    for entry in cache:
+        entry["index"] = jax.ShapeDtypeStruct((B,), jnp.int32)
+    row = {name: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+           for name, (shape, dtype) in {
+               "f32": ((B,), jnp.float32), "i32": ((B,), jnp.int32),
+               "bool": ((B,), jnp.bool_),
+               "keys": ((B, 2), jnp.uint32)}.items()}
+    seg = LlamaServer(model, None)._windowed_seg_fn(B, T, 512, 16)
+    assert seg.__name__ == "seg"
+    text = seg.lower(
+        params, row["f32"], row["i32"], row["f32"],      # knobs
+        row["i32"], row["f32"], on_chip(cache), row["i32"], row["bool"],
+        row["keys"], row["i32"]).compile().as_text()
+    found = set()
+    for op_name in re.findall(r' (?:fusion|copy|dynamic-update-slice)\('
+                              r'[^\n]*op_name="([^"]*)"', text):
+        found.update(op_name.split("/"))
+    assert {"embed", "qkv_proj", "kv_write", "attend", "o_proj", "mlp",
+            "lm_head", "sample", "kv_window"} <= found

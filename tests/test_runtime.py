@@ -2,6 +2,7 @@
 (SURVEY.md §4 E — the rebuild's #1 new call stack; §6 failure rows)."""
 
 import json
+import time
 import urllib.error
 import urllib.request
 from pathlib import Path
@@ -194,13 +195,37 @@ def test_warm_populates_compile_cache_and_speeds_boot(tmp_path):
 
 
 def test_profile_endpoint_captures_trace(llama_bundle):
+    """``POST /profile {"seconds": s}`` traces that wall window of whatever
+    traffic is live: the request sent meanwhile is in the trace. One mode:
+    the old ``invokes`` body is refused."""
+    import threading
+
+    from jax.profiler import ProfileData
+
     from lambdipy_tpu.runtime.server import BundleServer
 
     server = BundleServer(llama_bundle, port=0).start_background()
+    base = f"http://127.0.0.1:{server.port}"
     try:
-        out = _post(f"http://127.0.0.1:{server.port}/profile", {"invokes": 1})
-        assert out["ok"]
-        assert Path(out["dir"]).is_dir()
+        for bad in ({"invokes": 1}, {"seconds": 0}, {"seconds": 99},
+                    {"seconds": "soon"}):
+            with pytest.raises(urllib.error.HTTPError) as err:
+                _post(f"{base}/profile", bad)
+            assert err.value.code == 400
+        got = {}
+        th = threading.Thread(target=lambda: got.update(
+            _post(f"{base}/profile", {"seconds": 1.0})))
+        th.start()
+        time.sleep(0.3)
+        assert _post(f"{base}/invoke", {"tokens": [1, 2, 3]})["ok"]
+        th.join(timeout=120)
+        assert got["ok"] and Path(got["dir"]).is_dir()
+        trace = [f for f in got["files"] if f.endswith(".xplane.pb")]
+        assert trace
+        data = ProfileData.from_file(str(Path(got["dir"]) / trace[0]))
+        names = {ev.name for plane in data.planes for line in plane.lines
+                 for ev in line.events}
+        assert any(n.startswith("PjitFunction(") for n in names)
     finally:
         server.stop()
 
