@@ -18,6 +18,7 @@ TPU-first choices:
 from __future__ import annotations
 
 import dataclasses
+import math
 import threading
 from typing import Any
 
@@ -75,13 +76,15 @@ class LlamaConfig:
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
     moe_group_size: int = 256  # routing-group size (models/moe.py)
-    # int8 matmul backend: "xla" (dequant fused by XLA, works under TP
+    # int8 matmul backend: "xla" (QDense's dot on the converted int8
+    # kernel, scale applied to its float32 result; works under TP
     # sharding) or "pallas" (ops/quant.py blocked kernel — single-chip
-    # serving; falls back per-matmul when shapes don't tile). Measured
-    # head-to-head at 8B shapes (docs/kernels.md): XLA's fused dequant
-    # runs at 390-710 GB/s effective weight bandwidth vs the kernel's
-    # ~65, and the full 8B decode sits at 82% of the int8 roofline — the
-    # default follows the data.
+    # serving; falls back per-matmul when shapes don't tile). Isolated
+    # 8B-shape matmuls once measured 390-710 GB/s of weight bandwidth
+    # for "xla" against ~65 for the kernel (docs/kernels.md), and no
+    # cell of the benchmark runs "pallas". In the served decode step
+    # the weight stream of "xla" runs at 95 % (Mistral-7B) and 85 %
+    # (DeepSeek-7B) of the HBM roofline since PR 25 (PERF.md section 5).
     matmul_backend: str = "xla"
 
     @property
@@ -104,6 +107,17 @@ class RMSNorm(nn.Module):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
         y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
         return (y * scale).astype(dtype)
+
+
+# Rows (tokens) of one QDense call up to which the matmul is bound by
+# reading its int8 kernel, not by the MXU: 2 flops a row for every weight
+# byte against the v5e's 197 TFLOP/s over 819 GB/s = 240 flops a byte.
+# Decode, speculative verify and the smallest prefill buckets lie below
+# it. Above it a one-off dequantization is amortized over the rows, and
+# the float32 result the weight-bound form hands a row-parallel kernel's
+# all-reduce doubles its bytes: tp=4 group prefill of 8 x 256 tokens
+# 38.4 -> 46.9 ms with that form at every row count (PERF.md, PR 25).
+WEIGHT_BOUND_ROWS = 128
 
 
 class QDense(nn.Module):
@@ -148,6 +162,21 @@ class QDense(nn.Module):
                     flat = x.astype(self.dtype).reshape(-1, in_features)
                     out = int8_matmul(flat, w_i8, scale)
                     return out.reshape(*x.shape[:-1], self.features)
+            if math.prod(x.shape[:-1]) <= WEIGHT_BOUND_ROWS:
+                # the dot consumes the converted int8 kernel and nothing
+                # else (int8 -> dtype is exact), accumulates in float32,
+                # and the per-output-channel scale, constant along the
+                # contraction, multiplies the result: (x @ W8) * s ==
+                # x @ (W8 * s), less one rounding of every weight. A
+                # scale on the weight lets XLA split the dequantization
+                # off the dot: inside the decode loop it then writes a
+                # dtype copy of the kernel every step, and transposes
+                # the kernels at the head of every segment (PERF.md
+                # section 6, PR 25)
+                acc = jnp.matmul(x.astype(self.dtype),
+                                 w_i8.astype(self.dtype),
+                                 preferred_element_type=jnp.float32)
+                return (acc * scale).astype(self.dtype)
             w = w_i8.astype(self.dtype) * scale.astype(self.dtype)
         else:
             w = self.param("kernel", nn.initializers.lecun_normal(),
@@ -1560,14 +1589,18 @@ class LlamaServer:
     # -- AOT snapshot/restore of compiled serving programs -------------------
 
     # Serving-program AOT generation: bump when any serving program's
-    # SIGNATURE or carry shape changes, so a pre-change bundle's aot/
+    # SIGNATURE or carry shape changes, or its text changes under an
+    # unchanged key (family, shapes), so a pre-change bundle's aot/
     # dir (which persists across in-place upgrade) orphans its stale
     # executables instead of loading them. g2 = round 5: per-row knob /
     # PRNG operands + the (1,)-shaped prefix-continuation carry.
     # g3 = PR 24: scope names inside the programs (an executable keeps
     # the names it was compiled with; utils/compile_cache.NAMES_GEN is
-    # the same switch for the persistent cache).
-    _AOT_GEN = "g3"
+    # the same switch for the persistent cache). g4 = PR 25: QDense
+    # applies the int8 scale after the dot where the kernel's bytes
+    # bound it (names unchanged: the persistent cache's key hashes the
+    # new computation by itself).
+    _AOT_GEN = "g4"
 
     @classmethod
     def aot_prefix(cls) -> str:

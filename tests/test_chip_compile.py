@@ -10,6 +10,7 @@ it. ~1 s each; skipped where the topology cannot be described.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
 
@@ -117,46 +118,110 @@ def test_int8_matmul_compiles(v5e, m):
                  ((HIDDEN, MLP), jnp.int8), ((1, MLP), jnp.float32))
 
 
-def test_scope_names_survive_the_tpu_compiler(v5e):
-    """The decode segment (`jit_seg`, the window-bucketed variant the
-    continuous engine dispatches) at Mistral-7B widths and one layer: after
-    XLA's fusion every scope of ``models/llama.py`` is still the op_name of
-    some device operation — what ``benchmark/scopes.py`` splits a trace by
-    (PERF.md section 3). ~20 s."""
-    import re
-
+def _decode_segment_text(chip, *, steps, window=512, cache_len=T, layers=1,
+                         **widths):
+    """Optimized HLO of the decode segment (`jit_seg`, the window-bucketed
+    variant the continuous engine dispatches) compiled for ``chip``: int8
+    weights, bf16 activations, ``B`` rows with a per-row ``index``; shapes
+    only, nothing is placed or run."""
     from lambdipy_tpu.models.llama import (LlamaConfig, LlamaModel,
                                            LlamaServer, init_decode_cache)
 
-    cfg = LlamaConfig(vocab_size=32768, hidden=HIDDEN, layers=1, heads=H,
-                      kv_heads=KVH, mlp=MLP, rope_theta=1e6, norm_eps=1e-5,
-                      max_len=8192, dtype=jnp.bfloat16, quant="int8")
+    cfg = LlamaConfig(layers=layers, dtype=jnp.bfloat16, quant="int8",
+                      **widths)
     model = LlamaModel(cfg)
 
     def on_chip(tree):
         return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e),
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
             tree)
 
     params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0),
                                     jnp.zeros((1, 8), jnp.int32)))
-    cache = jax.eval_shape(lambda: init_decode_cache(cfg, B, T))
+    cache = jax.eval_shape(lambda: init_decode_cache(cfg, B, cache_len))
     for entry in cache:
         entry["index"] = jax.ShapeDtypeStruct((B,), jnp.int32)
-    row = {name: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+    row = {name: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
            for name, (shape, dtype) in {
                "f32": ((B,), jnp.float32), "i32": ((B,), jnp.int32),
                "bool": ((B,), jnp.bool_),
                "keys": ((B, 2), jnp.uint32)}.items()}
-    seg = LlamaServer(model, None)._windowed_seg_fn(B, T, 512, 16)
+    seg = LlamaServer(model, None)._windowed_seg_fn(B, cache_len, window,
+                                                    steps)
     assert seg.__name__ == "seg"
-    text = seg.lower(
+    return seg.lower(
         params, row["f32"], row["i32"], row["f32"],      # knobs
         row["i32"], row["f32"], on_chip(cache), row["i32"], row["bool"],
         row["keys"], row["i32"]).compile().as_text()
+
+
+MISTRAL_7B = dict(vocab_size=32768, hidden=HIDDEN, heads=H, kv_heads=KVH,
+                  mlp=MLP, rope_theta=1e6, norm_eps=1e-5, max_len=8192)
+DEEPSEEK_7B = dict(vocab_size=102400, hidden=HIDDEN, heads=H, kv_heads=H,
+                   mlp=11008, rope_theta=1e4, norm_eps=1e-6, max_len=4096)
+
+
+def test_scope_names_survive_the_tpu_compiler(v5e):
+    """The decode segment at Mistral-7B widths and one layer: after XLA's
+    fusion every scope of ``models/llama.py`` is still the op_name of some
+    device operation — what ``benchmark/scopes.py`` splits a trace by
+    (PERF.md section 3). ~20 s."""
+    text = _decode_segment_text(v5e, steps=16, **MISTRAL_7B)
     found = set()
     for op_name in re.findall(r' (?:fusion|copy|dynamic-update-slice)\('
                               r'[^\n]*op_name="([^"]*)"', text):
         found.update(op_name.split("/"))
     assert {"embed", "qkv_proj", "kv_write", "attend", "o_proj", "mlp",
             "lm_head", "sample", "kv_window"} <= found
+
+
+# HLO opcodes that name or view an array and write none
+NO_WRITE = {"parameter", "get-tuple-element", "tuple", "bitcast"}
+
+
+def dequantized_weights_in_loops(text: str, widths: dict) -> list:
+    """``(name, dtype, shape, op_name)`` of every operation inside a
+    ``while`` body of the optimized HLO ``text`` that PRODUCES an array
+    with the shape of one of the model's kernels (either way round) in
+    another type than the kernel's own int8: a dequantized copy of a
+    weight, written once per decode step. The int8 arrays of that shape
+    in a loop body are the compiler's prefetches of the kernels
+    themselves."""
+    hidden, mlp = widths["hidden"], widths["mlp"]
+    kv = hidden // widths["heads"] * widths["kv_heads"]
+    kernels = {(hidden, hidden), (hidden, kv), (hidden, mlp),
+               (hidden, widths["vocab_size"])}
+    kernels |= {(b, a) for a, b in kernels}
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
+    assert bodies, "no while loop in the program"
+    found, inside = [], False
+    for line in text.splitlines():
+        if not line.startswith(" "):  # a computation's header, or its "}"
+            head = re.match(r"%?([\w.\-]+) \(", line)
+            inside = bool(head) and head.group(1) in bodies
+            continue
+        op = inside and re.match(
+            r"\s+(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]+)\]\S* ([\w\-]+)\(",
+            line)
+        if not op or op.group(2) == "s8" or op.group(4) in NO_WRITE:
+            continue
+        shape = tuple(int(d) for d in op.group(3).split(","))
+        if shape in kernels:
+            name = re.search(r'op_name="([^"]*)"', line)
+            found.append((op.group(1), op.group(2), shape,
+                          name.group(1) if name else ""))
+    return found
+
+
+@pytest.mark.parametrize("widths", [MISTRAL_7B, DEEPSEEK_7B],
+                         ids=["mistral7b", "deepseek7b"])
+def test_decode_loop_writes_no_dequantized_weight(v5e, widths):
+    """A 2-layer, 4-step int8 decode scan at the benchmark's two widths:
+    no operation of the loop body writes a dequantized copy of a kernel.
+    With the scale applied to the weight before the dot (PR 24 and
+    earlier) XLA fused the MLP's convert-multiply into its dots but split
+    the attention projections' off: six ``qkv_proj/*/mul`` fusions, each
+    writing a whole bf16 kernel per step (PERF.md section 6, PR 25).
+    ~25 s each."""
+    text = _decode_segment_text(v5e, steps=4, layers=2, **widths)
+    assert dequantized_weights_in_loops(text, widths) == []

@@ -1,6 +1,8 @@
 """Model family tests on the virtual CPU mesh (SURVEY.md §5 plan items 3-4:
 numerics + mesh logic without hardware)."""
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -122,6 +124,75 @@ def test_llama_tp_sharded_forward_matches_single_device(cpu_devices):
     with use_mesh(mesh):
         out = fwd(sharded_params, jax.device_put(tokens, NamedSharding(mesh, P("dp"))))
     np.testing.assert_allclose(ref, np.asarray(out), rtol=2e-3, atol=2e-3)
+
+
+def test_llama_int8_tp_sharded_forward_matches_single_device(cpu_devices):
+    """The same under int8 weights on four devices: QDense multiplies the
+    ``[1, out]`` scale into the dot's float32 result, beside a kernel
+    sharded over its columns (q/k/v/gate/up/lm_head: the scale is sharded
+    with them) or over its rows (o/down: the scale is replicated and the
+    multiply sits beside the all-reduce, which is linear)."""
+    import dataclasses
+
+    from jax.sharding import PartitionSpec as P
+
+    from lambdipy_tpu.models.llama import LLAMA_TINY, LlamaModel, quantize_params
+    from lambdipy_tpu.parallel.mesh import make_mesh, use_mesh
+    from lambdipy_tpu.parallel.sharding import shard_params
+
+    model = LlamaModel(dataclasses.replace(LLAMA_TINY, quant="int8"))
+    tokens = jnp.asarray(np.random.default_rng(2).integers(0, 500, (2, 8)), jnp.int32)
+    params = quantize_params(LlamaModel(LLAMA_TINY).init(jax.random.PRNGKey(0), tokens))
+    forward = jax.jit(lambda p, t: model.apply(p, t)[0])
+    ref = np.asarray(forward(params, tokens))
+
+    mesh = make_mesh({"tp": 4}, devices=cpu_devices[:4])
+    sharded = shard_params(params, mesh, registry.get("llama-tiny").build().tp_rules)
+    block = sharded["params"]["layer_0"]
+    assert block["q_proj"]["kernel_int8"].sharding.spec == P(None, "tp")
+    assert block["q_proj"]["scale"].sharding.spec == P(None, "tp")
+    assert block["o_proj"]["kernel_int8"].sharding.spec == P("tp", None)
+    with use_mesh(mesh):
+        out = forward(sharded, tokens)
+    np.testing.assert_allclose(ref, np.asarray(out), rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("lead", [(8,), (2, 4), (2, 96)],
+                         ids=["2d", "3d", "compute_bound"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_qdense_int8_scale_after_dot(dtype, lead):
+    """QDense's int8 path against float64 ``x @ (int8 * scale)`` on a
+    non-square 4096 x 1536 kernel with a scale that differs per output
+    channel (a wrong broadcast of the ``[1, out]`` scale cannot pass):
+    the relative rms error is no larger than that of the expression the
+    weight-bound form replaced, ``x @ (int8.astype(dtype) *
+    scale.astype(dtype))``, which rounds every dequantized weight to
+    ``dtype`` once more (bf16: 0.17 % against 0.27 %) and which the rows
+    over ``WEIGHT_BOUND_ROWS`` still take."""
+    from lambdipy_tpu.models.llama import WEIGHT_BOUND_ROWS, QDense
+
+    assert (math.prod(lead) > WEIGHT_BOUND_ROWS) == (lead == (2, 96))
+
+    rng = np.random.default_rng(7)
+    n_in, n_out = 4096, 1536
+    w = rng.integers(-127, 128, (n_in, n_out)).astype(np.int8)
+    scale = rng.uniform(0.5, 2.0, (1, n_out)).astype(np.float32) / (127 * 64)
+    w8, s = jnp.asarray(w), jnp.asarray(scale)
+    x = jnp.asarray(rng.standard_normal((*lead, n_in)), dtype)
+    out = QDense(n_out, "int8", dtype).apply(
+        {"params": {"kernel_int8": w8, "scale": s}}, x)
+    assert out.shape == (*lead, n_out) and out.dtype == dtype
+    old = x @ (w8.astype(dtype) * s.astype(dtype))
+    exact = np.asarray(x, np.float64) @ (w.astype(np.float64) * scale)
+
+    def rel_rms(y):
+        return float(np.sqrt(np.mean((np.asarray(y, np.float64) - exact) ** 2)
+                             / np.mean(exact ** 2)))
+
+    # float32 has no extra rounding to lose: both forms sit at its epsilon
+    assert rel_rms(out) <= max(rel_rms(old), 1e-5), (rel_rms(out), rel_rms(old))
+    assert rel_rms(out) < (4e-3 if dtype == jnp.bfloat16 else 1e-5)
 
 
 def test_save_and_load_params_roundtrip_jax(tmp_path):
