@@ -2,10 +2,10 @@
 
 Copied from ``chip_smoke.py`` at commit fb0103a (``write_recipe``,
 ``build_bundle``, ``Served``), which stays a liveness check and may change:
-the recipe is DERIVED from the builtin llama recipe (same handler, dtype,
-quantization, base layer, engine settings), with the configuration file's
-widths, mesh and overrides on top, and built with ``lambdipy build`` as a
-user would. What differs from the smoke: the bundle is keyed by the
+the recipe is DERIVED from the builtin serving recipe (same handler, dtype,
+quantization, base layer, engine settings), with the widths the
+configuration's family reads from its file (``benchmark/families``), its
+mesh and overrides on top, and built with ``lambdipy build`` as a user would. What differs from the smoke: the bundle is keyed by the
 configuration file's content and kept in the work directory, so only a
 cell's first run in a checkout builds; the parameter file comes from
 ``benchmark/weights.py``.
@@ -22,7 +22,7 @@ import time
 import tomllib
 from pathlib import Path
 
-from benchmark import weights
+from benchmark import families, weights
 
 REPO = Path(__file__).resolve().parents[1]
 BASE_RECIPE = REPO / "lambdipy_tpu" / "recipes" / "builtin" / "jax-llama3-8b.toml"
@@ -64,7 +64,7 @@ def write_recipe(name: str, params: Path, config: dict, out_dir: Path) -> Path:
     if mesh:
         tables.append(("payload.mesh", mesh))
     tables.append(("payload.extra", {**base["payload"]["extra"],
-                                     **weights.dims_of(config),
+                                     **families.of(config).dims_of(config),
                                      **config.get("recipe_extra", {})}))
     lines = []
     for title, table in tables:

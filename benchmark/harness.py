@@ -12,7 +12,7 @@ import time
 from pathlib import Path
 
 from benchmark import bundle as B
-from benchmark import stats, traffic as T
+from benchmark import families, stats, traffic as T
 from benchmark.bundle import REPO, BenchFailure
 from benchmark.serve import Served
 
@@ -29,8 +29,9 @@ def load_cell(manifest_path: Path, workload: str) -> dict:
         raise BenchFailure(f"no workload {workload!r} in {manifest_path}")
     entry = next(c for c in manifest["configs"] if c["name"] == cell["config"])
     config_path = REPO / entry["file"]
+    config = json.loads(config_path.read_text())
     return {"manifest": manifest, "cell": cell, "config_path": config_path,
-            "config": json.loads(config_path.read_text()),
+            "config": config, "family": families.of(config),
             "traffic": T.load(cell["traffic"]),
             "rehearsal": bool(manifest.get("rehearsal"))}
 

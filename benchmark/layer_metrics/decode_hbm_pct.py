@@ -2,7 +2,8 @@
 over the traced slice.
 
 Bytes the algorithm needs = decode steps in the slice x (weight bytes + K/V
-bytes of the live rows at their actual mean context). Steps = segments run
+bytes of the live rows at their actual mean context), as the cell's family
+counts them (``decode_step_bytes``, ``benchmark/families``). Steps = segments run
 across the slice x segment length (/metrics deltas); live rows and their
 contexts are the harness's own count of the requests open at the slice's
 two ends (prompt length + tokens received). Divided by the device-busy
@@ -29,10 +30,10 @@ def read(ctx):
     live = sl.get("live") or []
     if segments <= 0 or not live:
         return None
-    shape = roofline.shape_of(ctx["config"])
     rows = sum(n for n, _ in live) / len(live)
     context = sum(c for _, c in live) / len(live)
-    step = roofline.decode_step_bytes(shape, rows=rows, context=context)
+    step = ctx["family"].decode_step_bytes(ctx["config"], rows=rows,
+                                           context=context)
     peaks = roofline.peaks_for(ctx["device"]["kind"])
     busy_s = tr["busy_s"] / tr["window_s"] * (sl["t1"] - sl["t0"])
     return 100.0 * segments * segment * step / busy_s / peaks.hbm_bytes_s

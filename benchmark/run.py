@@ -123,6 +123,12 @@ def main(argv=None) -> int:
         note(stage="check", **check, failed_requests=summary["failed"])
         correct = bool(check["correct"]) and summary["failed"] == 0 \
             and summary["attempted"] > 0
+        # each number compared, beside its limit: the result line's last key
+        # and this run's last lines on standard error
+        compared = {"widest_gap": {"value": check["widest_gap"],
+                                   "limit": check["limit"]},
+                    "failed_requests": {"value": summary["failed"],
+                                        "limit": 0}}
 
         values = dict(summary, setup_s=setup_s)
         if args.trace:
@@ -153,7 +159,11 @@ def main(argv=None) -> int:
             dev.update(busy_s=trace["busy_s"], window_s=trace["window_s"])
             line["breakdown"] = {"device_ops": trace["device_ops"],
                                  "idle_gaps": trace["idle_gaps"]}
+        line["compared"] = compared
         print(json.dumps(line), flush=True)
+        for name, c in compared.items():
+            print(f"compared {name} = {c['value']} limit {c['limit']}",
+                  file=sys.stderr, flush=True)
         return 0
     except BenchFailure as e:
         print(f"benchmark: {e}", file=sys.stderr)

@@ -1,5 +1,8 @@
 """The decode segment's device time, split by the scope names the program
-gives its operations (``jax.named_scope`` in ``lambdipy_tpu/models/llama.py``).
+gives its operations (``jax.named_scope`` in the model's module). Which names
+those are is the family's to say (``SCOPES`` and ``WITNESS`` of the cell's
+family, ``benchmark/families``): every function here that reads names takes
+them from the family it is handed.
 
 From the same ``.xplane.pb`` the reducer reads. A device plane's
 ``XLA Modules`` line holds one event per program run; the decode segment is
@@ -17,7 +20,7 @@ It is the stat ``tf_op`` of the event's METADATA, beside ``program_id``,
 those two from the file's protobuf wire format itself (the XSpace / XPlane /
 XEventMetadata / XStat messages of tsl's ``xplane.proto``): a few thousand
 metadata entries, never the events. An operation is counted under the
-INNERMOST path component of its op_name that is a scope of ``SCOPES``, or
+INNERMOST path component of its op_name that is one of the family's scopes, or
 under ``""`` when none is. Operations that only hold others (``%while`` ...)
 are left out: their time is their body's.
 
@@ -30,7 +33,7 @@ operation of the same run that has a scope (the weight stream of a matmul
 counts as that matmul's); ``waited_s`` says how much was charged so. Seconds
 are averaged over the device planes.
 
-``for_run()`` finds the trace of the run in progress the way ``run.py``
+``for_run(family)`` finds the trace of the run in progress the way ``run.py``
 does (``--work-dir``, else the default work directory, then ``trace/``) and
 reduces it once per process: the readers in ``layer_metrics/`` share it.
 """
@@ -47,19 +50,14 @@ from benchmark import xplane
 from benchmark.bundle import DEFAULT_WORK
 
 SEGMENT_MODULE = "jit_seg"
-SCOPES = ("embed", "qkv_proj", "kv_write", "attend", "o_proj", "mlp",
-          "lm_head", "sample", "kv_window")
-# scopes only a program with named scopes has: flax names a module's
-# operations after the module (``o_proj``, ``lm_head``, ``embed``) anyway
-WITNESS = ("qkv_proj", "mlp", "sample")
 PROGRAM_ID = re.compile(r"\((\d+)\)\s*$")
 CONTAINER, WAIT = "<container>", "<wait>"
 
 
-def scope_of(op_name: str) -> str:
-    """The innermost component of an op_name path that is in ``SCOPES``."""
+def scope_of(op_name: str, names) -> str:
+    """The innermost component of an op_name path that is in ``names``."""
     for part in reversed(op_name.split("/")):
-        if part in SCOPES:
+        if part in names:
             return part
     return ""
 
@@ -164,14 +162,15 @@ def whole_runs(runs: list) -> list:
     return runs[1:-1] if len(runs) >= 3 else runs
 
 
-def segment_split(path: Path) -> dict | None:
+def segment_split(path: Path, family) -> dict | None:
     """``{"runs", "run_s", "op_s", "by_scope": {scope: s}, "waited_s",
-    "scoped"}`` for the segment program, or None when no device plane ran
-    it. ``runs`` is the number of segment runs traced (mean over devices),
-    ``run_s`` the sum of their durations, ``op_s`` the sum of their
-    operations' durations (what ``by_scope`` adds up to), ``waited_s`` the
-    part of it that was waits charged to the operation behind them,
-    ``scoped`` whether the program's own scope names were found."""
+    "scoped"}`` for the segment program, split by ``family.SCOPES``, or
+    None when no device plane ran it. ``runs`` is the number of segment
+    runs traced (mean over devices), ``run_s`` the sum of their durations,
+    ``op_s`` the sum of their operations' durations (what ``by_scope`` adds
+    up to), ``waited_s`` the part of it that was waits charged to the
+    operation behind them, ``scoped`` whether the program's own scope names
+    (``family.WITNESS``) were found."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(str(path))
@@ -220,7 +219,8 @@ def segment_split(path: Path) -> dict | None:
                     scope = CONTAINER
                 else:
                     op_name = named.get(key) or named.get(("", name))
-                    scope = WAIT if op_name is None else scope_of(op_name)
+                    scope = WAIT if op_name is None \
+                        else scope_of(op_name, family.SCOPES)
                 seen[key] = scope
             if scope == CONTAINER:
                 continue
@@ -239,7 +239,8 @@ def segment_split(path: Path) -> dict | None:
     return {"runs": runs / devices, "run_s": run_s / devices,
             "op_s": sum(by_scope.values()), "by_scope": by_scope,
             "waited_s": waited_s / devices,
-            "scoped": any(by_scope.get(w, 0.0) > 0 for w in WITNESS)}
+            "scoped": any(by_scope.get(w, 0.0) > 0
+                          for w in family.WITNESS)}
 
 
 def work_dir() -> Path:
@@ -254,25 +255,25 @@ def work_dir() -> Path:
 
 
 @functools.cache
-def for_run() -> dict | None:
+def for_run(family) -> dict | None:
     found = xplane.find_trace(work_dir() / "trace")
-    return segment_split(found) if found else None
+    return segment_split(found, family) if found else None
 
 
 def step_ms(ctx: dict, scopes: tuple | None = None) -> float | None:
     """Milliseconds of device time a decode step took in the traced slice:
     all of the segment program (``scopes`` None: the runs' own durations),
-    or only its operations under ``scopes``. A step is one of the
-    ``handler.batching.segment`` steps of a run. None where the run has no
-    device trace, ran no segment, or (for a part) the program names no
-    scopes."""
+    or only its operations under ``scopes`` (names of the cell's family,
+    ``ctx["family"]``). A step is one of the ``handler.batching.segment``
+    steps of a run. None where the run has no device trace, ran no segment,
+    or (for a part) the program names no scopes."""
     if not ctx.get("trace"):
         return None
     try:
         segment = int(ctx["m_close"]["handler"]["batching"]["segment"])
     except (KeyError, TypeError, ValueError):
         return None
-    split = for_run()
+    split = for_run(ctx["family"])
     if not split or split["runs"] <= 0 or segment <= 0:
         return None
     steps = split["runs"] * segment

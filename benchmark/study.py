@@ -11,7 +11,8 @@ than the first) and how long the last request took to drain.
 
 ``--control 1``: the readings a ``correct`` limit is set from. After the
 server has stopped, every window's sample goes through the float32
-reference AND through its int4 control; each prints the program's widest
+reference AND through the control of the configuration's family (the
+nearest precision below the stated one); each prints the program's widest
 gap and the control's. The benchmark's own runs never run the control.
 """
 

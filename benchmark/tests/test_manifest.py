@@ -84,16 +84,30 @@ def test_files_under_paths_are_named_from_name_characters():
         assert ok.match(rel) and len(rel) <= 200, rel
 
 
-def test_the_harness_names_no_cell_configuration_or_mix():
+def test_the_harness_names_no_cell_configuration_mix_or_family():
     """A later PR adds files and entries and edits nothing: so no python
-    file of the harness may know a configuration, a mix or a metric."""
-    words = [c["name"] for c in MANIFEST["configs"]] \
-        + [w["traffic"] for w in MANIFEST["workloads"]] \
-        + ["rehearsal-tiny", "rehearsal-open", "rehearsal-closed"]
+    file of the harness may know a configuration, a mix, a metric or a
+    family (an architecture's own code lies under ``benchmark/families``,
+    found by the name in the configuration's file)."""
+    from benchmark import families
+
+    rehearsal = json.loads((REPO / "benchmark" / "rehearsal.json").read_text())
+    configs = MANIFEST["configs"] + rehearsal["configs"]
+    named = {families.name_of(json.loads((REPO / c["file"]).read_text()))
+             for c in configs}
+    stems = {p.stem for p in (REPO / "benchmark" / "families").glob("*.py")
+             if p.stem != "__init__"}
+    words = [c["name"] for c in configs] \
+        + [w["traffic"] for w in MANIFEST["workloads"] + rehearsal["workloads"]] \
+        + sorted(named | stems | {s.replace("_", "-") for s in stems})
     for py in (REPO / "benchmark").glob("*.py"):
         text = py.read_text()
         for word in words:
             assert word not in text, (py.name, word)
+    # and every configuration finds a family that has all its parts
+    for name in named:
+        family = families.load(name)
+        assert all(hasattr(family, part) for part in families.PARTS), name
 
 
 def test_bundle_key_follows_the_configuration_file(tmp_path):

@@ -44,11 +44,12 @@ def test_a_wrong_token_shows_as_a_wide_gap():
 
 
 def test_leaves_depend_on_seed_and_path_only():
-    a = weights.leaf(3, "layer_0/q_proj/kernel_int8", (64, 64), "int8", 64)
-    b = weights.leaf(3, "layer_0/q_proj/kernel_int8", (64, 64), "int8", 64)
-    c = weights.leaf(3, "layer_1/q_proj/kernel_int8", (64, 64), "int8", 64)
-    d = weights.leaf(4, "layer_0/q_proj/kernel_int8", (64, 64), "int8", 64)
+    a = weights.leaf(CONFIG, "layer_0/q_proj/kernel_int8", (64, 64), "int8")
+    b = weights.leaf(CONFIG, "layer_0/q_proj/kernel_int8", (64, 64), "int8")
+    c = weights.leaf(CONFIG, "layer_1/q_proj/kernel_int8", (64, 64), "int8")
+    d = weights.leaf(dict(CONFIG, weights_seed=4),
+                     "layer_0/q_proj/kernel_int8", (64, 64), "int8")
     assert (a == b).all() and (a != c).any() and (a != d).any()
-    e = weights.leaf(3, "embed/embedding", (512, 64), "float32", 64)
+    e = weights.leaf(CONFIG, "embed/embedding", (512, 64), "float32")
     import ml_dtypes
     assert (e.astype(ml_dtypes.bfloat16).astype(np.float32) == e).all()

@@ -32,7 +32,11 @@ def test_a_sound_run_is_correct_and_prints_the_contract_line(capsys, work):
     rc, lines = drive(capsys, work, "rehearsal-tiny.rehearsal-open")
     last = lines[-1]
     assert rc == 0 and last["correct"] is True, lines[-3:]
-    assert set(last) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(last) == ["correct", "attempted", "failed", "metrics",
+                          "device", "compared"]     # the numbers come last
+    assert set(last["compared"]) == {"widest_gap", "failed_requests"}
+    gap = last["compared"]["widest_gap"]
+    assert 0 <= gap["value"] <= gap["limit"]
     assert last["device"]["platform"] == "cpu"      # refused as a measurement
     assert set(last["metrics"]) == {"ttft_p90_ms", "tpot_p90_ms", "setup_s"}
     assert last["attempted"] > 0 and last["failed"] == 0

@@ -12,14 +12,15 @@ from pathlib import Path
 
 import pytest
 
-from benchmark import harness, scopes, xplane
+from benchmark import families, harness, scopes, xplane
 
 TRACE = Path(__file__).parent / "data" / "v5e_seg_3ms.xplane.pb"
+FAMILY = families.load("llama-hf")     # the recorded cuts are of its program
 
 
 @pytest.fixture(scope="module")
 def split():
-    return scopes.segment_split(TRACE)
+    return scopes.segment_split(TRACE, FAMILY)
 
 
 def device_ops():
@@ -70,7 +71,7 @@ def test_the_split_adds_up_and_waits_go_to_the_operation_behind(split):
             expected[nxt] = expected.get(nxt, 0.0) + ns / 1e9
             waited += ns / 1e9 if nxt else 0.0
             continue
-        scope = scopes.scope_of(op_name)
+        scope = scopes.scope_of(op_name, FAMILY.SCOPES)
         expected[scope] = expected.get(scope, 0.0) + ns / 1e9
         if scope:
             nxt = scope
@@ -116,13 +117,13 @@ def test_what_the_slice_shows(split):
      "o_proj"),
     ("", "")])
 def test_scope_of_takes_the_innermost_scope_of_the_list(op_name, scope):
-    assert scopes.scope_of(op_name) == scope
+    assert scopes.scope_of(op_name, FAMILY.SCOPES) == scope
 
 
 def test_step_ms_and_the_four_readers(split, monkeypatch):
-    monkeypatch.setattr(scopes, "for_run", lambda: split)
-    ctx = {"trace": {"busy_s": 1.0}, "m_close": {"handler": {"batching": {
-        "segment": 16}}}}
+    monkeypatch.setattr(scopes, "for_run", lambda family: split)
+    ctx = {"trace": {"busy_s": 1.0}, "family": FAMILY,
+           "m_close": {"handler": {"batching": {"segment": 16}}}}
     step = scopes.step_ms(ctx)
     assert step == pytest.approx(1e3 * split["run_s"] / 16)
     parts = {name: harness.layer_metric(name).read(ctx) for name in (
@@ -140,10 +141,10 @@ def test_step_ms_and_the_four_readers(split, monkeypatch):
     assert scopes.step_ms({"m_close": ctx["m_close"]}) is None
     assert scopes.step_ms({"trace": ctx["trace"], "m_close": {}}) is None
     monkeypatch.setattr(scopes, "for_run",
-                        lambda: dict(split, scoped=False))
+                        lambda family: dict(split, scoped=False))
     assert harness.layer_metric("decode_matmul_ms").read(ctx) is None
     assert harness.layer_metric("decode_step_ms").read(ctx) == step
-    monkeypatch.setattr(scopes, "for_run", lambda: None)
+    monkeypatch.setattr(scopes, "for_run", lambda family: None)
     assert harness.layer_metric("decode_step_ms").read(ctx) is None
 
 
@@ -158,7 +159,7 @@ def test_runs_cut_off_by_the_profiler_are_left_out():
 
 def test_a_trace_without_the_segment_program_splits_to_nothing():
     other = TRACE.parent / "v5e_chat_steady_30ms.xplane.pb"
-    assert scopes.segment_split(other) is None
+    assert scopes.segment_split(other, FAMILY) is None
     assert scopes.op_names(other) == {}
 
 
