@@ -20,11 +20,16 @@ from __future__ import annotations
 import dataclasses
 import math
 import threading
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+
+
+class LayerSpec(NamedTuple):
+    attn: str  # "kv" | "latent"
+    ffn: str   # "dense" | "capacity" | "routed"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -86,10 +91,114 @@ class LlamaConfig:
     # the weight stream of "xla" runs at 95 % (Mistral-7B) and 85 %
     # (DeepSeek-7B) of the HBM roofline since PR 25 (PERF.md section 5).
     matmul_backend: str = "xla"
+    # -- the per-layer description (ROADMAP D3 in the small): what the ONE
+    # block, the cache constructors and the registry read, so that an
+    # architecture is a setting of these fields and not a fork of this
+    # file. ``layer_spec(i)`` and ``cache_layout()`` below are the only
+    # readers of the two kinds.
+    # Attention kind: "kv" (per-head K/V rows: ``kv_heads`` x ``head_dim``,
+    # the llama block) or "latent" (multi-head latent attention as
+    # DeepSeek-V2/V3 publish it: the cache row of a token is ONE compressed
+    # latent of ``kv_lora_rank`` values, after its norm, plus ONE rotary
+    # key of ``qk_rope`` values shared by all heads, after rope; query/key
+    # heads are ``qk_nope + qk_rope`` wide, value heads ``v_head``; no
+    # query compression). Prefill attends expanded keys and values; every
+    # program that attends a cache absorbs the up-projection into the
+    # query and the output instead of re-expanding the window.
+    attn_kind: str = "kv"
+    qk_nope: int = 0
+    qk_rope: int = 0
+    v_head: int = 0
+    kv_lora_rank: int = 0
+    # the rotary dims arrive as (even, odd) pairs and are de-interleaved
+    # to halves before the usual rotate-half
+    rope_interleave: bool = False
+    # FFN kind of the layers from ``first_dense_layers`` on: "dense"
+    # (SwiGLU of width ``mlp``; with ``moe_experts`` > 0 the capacity form
+    # above, which drops) or "routed" (models/moe.py RoutedMLP: dropless
+    # top-``moe_top_k`` of ``moe_experts`` experts of width
+    # ``moe_intermediate`` beside ``n_shared_experts`` always-on ones).
+    # The leading layers are dense SwiGLUs of width ``mlp``.
+    ffn_kind: str = "dense"
+    first_dense_layers: int = 0
+    moe_intermediate: int = 0
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    scoring_func: str = "softmax"  # "softmax" | "sigmoid"
+
+    def __post_init__(self):
+        if self.attn_kind not in ("kv", "latent"):
+            raise ValueError(f"unknown attn_kind {self.attn_kind!r}; "
+                             "supported: kv, latent")
+        if self.ffn_kind not in ("dense", "routed"):
+            raise ValueError(f"unknown ffn_kind {self.ffn_kind!r}; "
+                             "supported: dense, routed")
+        if self.attn_kind == "latent":
+            if min(self.qk_nope, self.qk_rope, self.v_head,
+                   self.kv_lora_rank) <= 0 or self.qk_rope % 2:
+                raise ValueError(
+                    "latent attention needs qk_nope, qk_rope (even), v_head "
+                    "and kv_lora_rank")
+            if self.kv_quant is not None:
+                raise NotImplementedError(
+                    f"kv_quant={self.kv_quant!r} cannot hold a latent cache: "
+                    "the int8 cache layout quantizes per-head K/V rows "
+                    "(_kv_store)")
+            if self.attn_backend != "dense":
+                raise NotImplementedError(
+                    f"attn_backend={self.attn_backend!r} attends per-head "
+                    "K/V; latent attention runs the dense backend")
+        if self.ffn_kind == "routed":
+            if self.moe_experts < self.moe_top_k or self.moe_top_k < 1 \
+                    or self.moe_intermediate <= 0:
+                raise ValueError(
+                    "a routed FFN needs moe_experts >= moe_top_k >= 1 and "
+                    "moe_intermediate")
+            if self.scoring_func not in ("softmax", "sigmoid"):
+                raise ValueError(
+                    f"unknown scoring_func {self.scoring_func!r}; "
+                    "supported: softmax, sigmoid")
 
     @property
     def head_dim(self) -> int:
         return self.hidden // self.heads
+
+    def layer_spec(self, layer: int) -> LayerSpec:
+        """What layer ``layer`` is made of."""
+        if self.ffn_kind == "routed":
+            ffn = "dense" if layer < self.first_dense_layers else "routed"
+        else:
+            ffn = "capacity" if self.moe_experts else "dense"
+        return LayerSpec(self.attn_kind, ffn)
+
+    def cache_layout(self) -> dict:
+        """The cache row of one token of one layer: ``{leaf: (heads,
+        width)}`` in storage order. Every leaf is 4-D ``[rows, positions,
+        heads, width]`` (a latent leaf has a head axis of 1), so whatever
+        iterates a cache entry's leaves -- slicing, copying, window
+        buckets, the engine's pack -- never asks which kind it holds."""
+        if self.attn_kind == "latent":
+            return {"ckv": (1, self.kv_lora_rank), "kpe": (1, self.qk_rope)}
+        return {"k": (self.kv_heads, self.head_dim),
+                "v": (self.kv_heads, self.head_dim)}
+
+    @property
+    def counts_moe_load(self) -> bool:
+        """Whether the engine's segment programs return the routed FFN's
+        per-row expert load beside their tokens (``handler.moe``)."""
+        return self.ffn_kind == "routed"
+
+
+def require_kv_cache(cfg: LlamaConfig, holder: str) -> None:
+    """Raise for a cache holder that knows only per-head K/V leaves (no
+    silent fallback: the holder would store, ship or page rows of the
+    wrong layout)."""
+    if getattr(cfg, "attn_kind", "kv") != "kv":
+        raise NotImplementedError(
+            f"{holder} holds per-head k/v cache leaves and cannot take the "
+            f"{cfg.attn_kind} cache layout {sorted(cfg.cache_layout())} "
+            "(PERF.md section 7)")
 
 
 LLAMA3_8B = LlamaConfig()
@@ -120,6 +229,15 @@ class RMSNorm(nn.Module):
 WEIGHT_BOUND_ROWS = 128
 
 
+def _init_int8(key, shape, _dtype):
+    """A lecun-normal kernel [in, out] rounded to int8 under its own
+    per-output-channel scale (random init only: real weights come through
+    :func:`quantize_params`, which computes true scales)."""
+    w = nn.initializers.lecun_normal()(key, shape, jnp.float32)
+    scale = jnp.max(jnp.abs(w), axis=0, keepdims=True) / 127.0
+    return jnp.round(w / jnp.maximum(scale, 1e-8)).astype(jnp.int8)
+
+
 class QDense(nn.Module):
     """Linear layer with optional int8 weight-only quantization.
 
@@ -138,12 +256,7 @@ class QDense(nn.Module):
     def __call__(self, x):
         in_features = x.shape[-1]
         if self.quant == "int8":
-            def init_int8(key, shape, _dtype):
-                w = nn.initializers.lecun_normal()(key, shape, jnp.float32)
-                scale = jnp.max(jnp.abs(w), axis=0, keepdims=True) / 127.0
-                return jnp.round(w / jnp.maximum(scale, 1e-8)).astype(jnp.int8)
-
-            w_i8 = self.param("kernel_int8", init_int8,
+            w_i8 = self.param("kernel_int8", _init_int8,
                               (in_features, self.features), jnp.int8)
             # random-init scale approximates lecun magnitude; real weights
             # come through quantize_params() which computes true scales
@@ -182,6 +295,32 @@ class QDense(nn.Module):
             w = self.param("kernel", nn.initializers.lecun_normal(),
                            (in_features, self.features), self.dtype)
         return x.astype(self.dtype) @ w
+
+
+class QKernel(nn.Module):
+    """A linear layer's weights without the product: ``(kernel, scale)``
+    under :class:`QDense`'s names, layout and initializers (``kernel_int8``
+    [in, out] + float32 ``scale`` [1, out] per output channel under
+    quant="int8", else ``kernel`` and None). For the one weight that is
+    multiplied two ways: latent attention's up-projection, expanded at
+    prefill and absorbed per head into the query and the output when a
+    cache is attended."""
+
+    in_features: int
+    features: int
+    quant: str | None = None
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self):
+        shape = (self.in_features, self.features)
+        if self.quant != "int8":
+            return self.param("kernel", nn.initializers.lecun_normal(),
+                              shape, self.dtype), None
+        return (self.param("kernel_int8", _init_int8, shape, jnp.int8),
+                self.param("scale", nn.initializers.constant(
+                    1.0 / (127.0 * self.in_features ** 0.5)),
+                    (1, self.features), jnp.float32))
 
 
 def _scaled_rope_freqs(freqs, scaling):
@@ -223,6 +362,12 @@ def rope(q, k, positions, theta: float, scaling: tuple | None = None):
     return rot(q), rot(k)
 
 
+def _deinterleave(x):
+    """Rotary dims stored as (even, odd) pairs -> the two halves that
+    :func:`rope`'s rotate-half expects (``rope_interleave`` checkpoints)."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
 def _kv_quantize(x):
     """[..., d] float -> (int8 values, f32 scale [..., 1]) per-vector
     symmetric quantization (one scale per token per kv-head)."""
@@ -240,16 +385,20 @@ def cache_width(cache) -> int:
     """Sequence capacity of a decode/prefix cache (float or int8
     layout) — the ONE layout probe shared by the server's bucket math
     and the continuous engine's pack gate."""
-    entry = cache[0]
-    leaf = entry.get("k", entry.get("k_int8"))
-    return leaf.shape[1]
+    return next(val for name, val in cache[0].items()
+                if name != "index").shape[1]
 
 
 def _kv_store(cfg, k, v) -> dict:
     """This step's (or chunk's) K/V in the cache's storage layout: the
     float leaves, or int8 values + scales under ``cfg.kv_quant``. The
     ONE place the layout is built — the dense decode path, the sp
-    decode path, and prefill embedding all consume it."""
+    decode path, and prefill embedding all consume it. ``k`` and ``v``
+    are the two parts of ``cfg.cache_layout()`` in its order (a latent
+    cache: the compressed latent and the shared rotary key)."""
+    if cfg.attn_kind != "kv":
+        return {name: part.astype(cfg.dtype)
+                for name, part in zip(cfg.cache_layout(), (k, v))}
     if cfg.kv_quant == "int8":
         k_q, k_s = _kv_quantize(k)
         v_q, v_s = _kv_quantize(v)
@@ -302,7 +451,7 @@ def _attend(q, k, v, mask):
     logits (ring attention is the layout that never gathers k/v)."""
     from lambdipy_tpu.parallel.sharding import shard_hint
 
-    b, s, h, d = q.shape
+    b, s, h, d = q.shape  # values may be narrower than keys (latent)
     kvh = k.shape[2]
     group = h // kvh
     q = shard_hint(q.reshape(b, s, kvh, group, d), "dp", "sp", "tp")
@@ -315,11 +464,61 @@ def _attend(q, k, v, mask):
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     probs = shard_hint(probs, "dp", "tp", None, "sp", None)
     out = jnp.einsum("bkgst,btkd->bskgd", probs, v)
-    return shard_hint(out.reshape(b, s, h, d), "dp", "sp", "tp")
+    return shard_hint(out.reshape(b, s, h, v.shape[-1]), "dp", "sp", "tp")
+
+
+def _cache_write(cache, store, idx, b: int, s: int, band: int = 0):
+    """Write this step's (or chunk's) ``store`` leaves into the layer's
+    cache entry at ``idx`` (an int32 scalar, or ``[b]`` per-row positions)
+    and return ``(new cache entry, valid [.., s, t], t)``: which of the
+    ``t`` cached positions each of the ``s`` queries may attend. The ONE
+    write path of every cache kind: it iterates the leaves it is given."""
+    from lambdipy_tpu.parallel.sharding import shard_hint
+
+    new_cache = {}
+    if jnp.ndim(idx) == 0:
+        for name, val in store.items():
+            new_cache[name] = jax.lax.dynamic_update_slice(
+                cache[name], val, (0, idx, 0, 0))
+        # chunk query j attends keys <= idx + j — causal within the
+        # chunk, everything before it. s == 1 is the familiar decode-step
+        # mask; s > 1 is a multi-token continuation chunk (prefix-cache
+        # suffix prefill).
+        t = new_cache[next(iter(store))].shape[1]
+        valid = (jnp.arange(t)[None, None, :]
+                 <= (idx + jnp.arange(s))[None, :, None])
+        if band:
+            # long-context sliding band: query at cache position p sees
+            # keys from the start of the PREVIOUS band block — exactly the
+            # window the serial window/2 slide schedule leaves resident
+            # when p's chunk runs
+            qpos = idx + jnp.arange(s)
+            band_start = jnp.maximum(0, (qpos // band - 1) * band)
+            valid = valid & (jnp.arange(t)[None, None, :]
+                             >= band_start[None, :, None])
+    else:
+        # ragged batch (rows decode from different prompt lengths):
+        # per-row scatter of this step's (or chunk's) positions. s == 1 is
+        # the familiar decode step; s > 1 is a SPECULATIVE VERIFY CHUNK —
+        # row r's chunk lands at idx[r]..idx[r]+s-1 and query j attends
+        # keys <= idx[r]+j (causal within the chunk). Out-of-bounds scatter
+        # indices DROP (jax .at[] default), which is exactly the engine's
+        # over-decode/rollback contract: a rejected tail or
+        # past-the-window write lands nowhere a kept token can read.
+        rows = jnp.arange(b)
+        cols = idx[:, None] + jnp.arange(s)[None, :]  # [b, s]
+        for name, val in store.items():
+            new_cache[name] = cache[name].at[rows[:, None], cols].set(val)
+        t = new_cache[next(iter(store))].shape[1]
+        valid = jnp.arange(t)[None, None, :] <= cols[:, :, None]  # [b, s, t]
+    new_cache = {name: shard_hint(val, "dp", None, "tp")
+                 for name, val in new_cache.items()}
+    return new_cache, valid, t
 
 
 class LlamaBlock(nn.Module):
     cfg: LlamaConfig
+    layer: int = 0  # which layer of the model: cfg.layer_spec(layer)
 
     def _prefill_attend(self, q, k, v, mask, sp_prefill: int = 0):
         """Causal prefill attention via the configured backend.
@@ -358,8 +557,10 @@ class LlamaBlock(nn.Module):
     @nn.compact
     def __call__(self, x, positions, mask, cache, sp_prefill: int = 0,
                  band: int = 0):
-        """cache: None (prefill over full x) or dict(k, v, index) for decode.
-        Returns (y, new_cache_entry).
+        """cache: None (prefill over full x) or the layer's cache entry
+        (the leaves of ``cfg.cache_layout()`` plus ``index``) for decode.
+        Returns (y, new_cache_entry). What the layer is made of is
+        ``cfg.layer_spec(self.layer)``.
 
         sp_prefill: static int — when >= 2, this is a whole-prompt
         sequence-parallel prefill program: the no-cache branch
@@ -373,12 +574,147 @@ class LlamaBlock(nn.Module):
         attends exactly what the serial window/2 slide schedule would
         have exposed chunk by chunk."""
         cfg = self.cfg
-        d = cfg.head_dim
-        # the scope names below (qkv_proj, kv_write, attend, o_proj, mlp;
-        # embed, lm_head, sample further down) reach each device
+        spec = cfg.layer_spec(self.layer)
+        # the scope names (qkv_proj, kv_write, attend, o_proj, mlp; embed,
+        # lm_head, sample further down; mla_absorb, router, experts,
+        # shared_expert of the latent and routed kinds) reach each device
         # operation's op_name: the trace is split by them (PERF.md).
         # Renaming or moving one: bump utils/compile_cache.NAMES_GEN
         # and LlamaServer._AOT_GEN
+        b, s, _ = x.shape
+        if spec.attn == "latent":
+            out, new_cache = self._latent_attend(x, positions, mask, cache,
+                                                 band)
+        else:
+            out, new_cache = self._kv_attend(x, positions, mask, cache,
+                                             sp_prefill, band)
+
+        with jax.named_scope("o_proj"):
+            out = out.reshape(b, s, -1)
+            x = x + QDense(cfg.hidden, cfg.quant, cfg.dtype,
+                           cfg.matmul_backend, name="o_proj")(out)
+
+        with jax.named_scope("mlp"):
+            if spec.ffn == "routed":
+                from lambdipy_tpu.models.moe import RoutedMLP
+
+                # the router reads the norm's float32 result, before the
+                # cast that the experts' products take: a top-k of many
+                # has near-ties. Padding rows of a ragged group prefill
+                # route nowhere; a cache step's rows are all the device
+                # can know of
+                h = RMSNorm(cfg.norm_eps, name="mlp_norm")(
+                    x.astype(jnp.float32))
+                x = x + RoutedMLP(cfg, name="moe")(
+                    h, mask if cache is None else None).astype(x.dtype)
+                return x, new_cache
+            h = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
+            if spec.ffn == "capacity":
+                from lambdipy_tpu.models.moe import MoEMLP
+
+                x = x + MoEMLP(cfg.moe_experts, cfg.mlp, cfg.moe_top_k,
+                               cfg.moe_capacity_factor, cfg.dtype, cfg.quant,
+                               group_size=cfg.moe_group_size, name="moe")(h)
+            else:
+                gate = QDense(cfg.mlp, cfg.quant, cfg.dtype,
+                              cfg.matmul_backend, name="gate_proj")(h)
+                up = QDense(cfg.mlp, cfg.quant, cfg.dtype,
+                            cfg.matmul_backend, name="up_proj")(h)
+                x = x + QDense(cfg.hidden, cfg.quant, cfg.dtype,
+                               cfg.matmul_backend, name="down_proj")(
+                    nn.silu(gate) * up)
+        return x, new_cache
+
+    def _latent_attend(self, x, positions, mask, cache, band: int):
+        """Multi-head latent attention: returns the heads' outputs
+        ``[b, s, heads, v_head]`` and the new cache entry, the latent
+        ``ckv`` (after its norm) and the one rotary key ``kpe`` (after
+        rope) of each token. Without a cache (prefill) keys and values are
+        expanded through ``kv_b_proj`` and attended like any multi-head
+        layer. With a cache the same function is computed absorbed: the
+        key half of ``kv_b_proj`` goes into the query (per head, qk_nope ->
+        kv_lora_rank), scores and the weighted sum run over the cached
+        latents themselves, and the value half maps the sum to v_head; the
+        window is never re-expanded (8 rows x 400 tokens x 32 heads x 256
+        through a 512-wide matmul would be 0.4 TFLOP a step)."""
+        cfg = self.cfg
+        heads, dn, dr = cfg.heads, cfg.qk_nope, cfg.qk_rope
+        dv, rank = cfg.v_head, cfg.kv_lora_rank
+        b, s, _ = x.shape
+        with jax.named_scope("qkv_proj"):
+            h = RMSNorm(cfg.norm_eps, name="attn_norm")(x)
+            q = QDense(heads * (dn + dr), cfg.quant, cfg.dtype,
+                       cfg.matmul_backend, name="q_proj")(h)
+            kva = QDense(rank + dr, cfg.quant, cfg.dtype,
+                         cfg.matmul_backend, name="kv_a_proj")(h)
+            ckv = RMSNorm(cfg.norm_eps, name="kv_a_norm")(kva[..., :rank])
+            q = q.reshape(b, s, heads, dn + dr)
+            q_nope, q_pe = q[..., :dn], q[..., dn:]
+            k_pe = kva[..., rank:].reshape(b, s, 1, dr)
+            if cfg.rope_interleave:
+                q_pe, k_pe = _deinterleave(q_pe), _deinterleave(k_pe)
+            q_pe, k_pe = rope(q_pe, k_pe, positions, cfg.rope_theta,
+                              cfg.rope_scaling)
+            ckv = ckv.reshape(b, s, 1, rank)
+        w, w_scale = QKernel(rank, heads * (dn + dv), cfg.quant, cfg.dtype,
+                             name="kv_b_proj")()
+
+        if cache is None:
+            with jax.named_scope("qkv_proj"):
+                kv = jnp.matmul(ckv[:, :, 0], w.astype(cfg.dtype),
+                                preferred_element_type=jnp.float32)
+                if w_scale is not None:
+                    kv = kv * w_scale
+                kv = kv.astype(cfg.dtype).reshape(b, s, heads, dn + dv)
+                k = jnp.concatenate(
+                    [kv[..., :dn],
+                     jnp.broadcast_to(k_pe, (b, s, heads, dr))], axis=-1)
+                q = jnp.concatenate([q_nope, q_pe], axis=-1)
+            with jax.named_scope("attend"):
+                causal = jnp.tril(jnp.ones((s, s), dtype=jnp.bool_))
+                out = _attend(q, k, kv[..., dn:],
+                              mask[:, None, :] & causal[None, :, :])
+            return out, {"ckv": ckv, "kpe": k_pe}
+
+        with jax.named_scope("kv_write"):
+            new_cache, valid, t = _cache_write(
+                cache, _kv_store(cfg, ckv, k_pe), cache["index"], b, s, band)
+        w = w.reshape(rank, heads, dn + dv)
+        with jax.named_scope("mla_absorb"):
+            # q' = q_nope W_k^T per head; a per-output-channel scale sits
+            # on the contracted axis here, so it multiplies the query
+            if w_scale is not None:
+                q_nope = (q_nope.astype(jnp.float32)
+                          * w_scale.reshape(heads, dn + dv)[:, :dn]
+                          ).astype(cfg.dtype)
+            q_lat = jnp.einsum("bshd,rhd->bshr", q_nope,
+                               w[..., :dn].astype(cfg.dtype))
+        with jax.named_scope("attend"):
+            lat, kpe = new_cache["ckv"][:, :, 0], new_cache["kpe"][:, :, 0]
+            logits = (jnp.einsum("bshr,btr->bhst", q_lat, lat,
+                                 preferred_element_type=jnp.float32)
+                      + jnp.einsum("bshd,btd->bhst", q_pe, kpe,
+                                   preferred_element_type=jnp.float32))
+            logits = logits / jnp.sqrt(dn + dr).astype(jnp.float32)
+            logits = jnp.where(
+                jnp.broadcast_to(valid, (b, s, t))[:, None, :, :], logits,
+                jnp.float32(-1e9))
+            probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
+            ctx = jnp.einsum("bhst,btr->bshr", probs, lat)
+        with jax.named_scope("mla_absorb"):
+            out = jnp.einsum("bshr,rhd->bshd", ctx,
+                             w[..., dn:].astype(cfg.dtype),
+                             preferred_element_type=jnp.float32)
+            if w_scale is not None:
+                out = out * w_scale.reshape(heads, dn + dv)[:, dn:]
+        return out.astype(cfg.dtype), new_cache
+
+    def _kv_attend(self, x, positions, mask, cache, sp_prefill: int,
+                   band: int):
+        """Per-head K/V attention (the llama block): returns the heads'
+        outputs ``[b, s, heads, head_dim]`` and the new cache entry."""
+        cfg = self.cfg
+        d = cfg.head_dim
         b, s, _ = x.shape
         with jax.named_scope("qkv_proj"):
             h = RMSNorm(cfg.norm_eps, name="attn_norm")(x)
@@ -449,53 +785,8 @@ class LlamaBlock(nn.Module):
                     # quantize this chunk's k/v once under kv_quant; the
                     # cache stays int8 in HBM and the dequant fuses into
                     # the attention einsum
-                    store = _kv_store(cfg, k, v)
-                    new_cache = {}
-                    if jnp.ndim(idx) == 0:
-                        for name, val in store.items():
-                            new_cache[name] = jax.lax.dynamic_update_slice(
-                                cache[name], val, (0, idx, 0, 0))
-                        # chunk query j attends keys <= idx + j — causal
-                        # within the chunk, everything before it. s == 1 is
-                        # the familiar decode-step mask; s > 1 is a
-                        # multi-token continuation chunk (prefix-cache
-                        # suffix prefill).
-                        t = new_cache[next(iter(store))].shape[1]
-                        valid = (jnp.arange(t)[None, None, :]
-                                 <= (idx + jnp.arange(s))[None, :, None])
-                        if band:
-                            # long-context sliding band: query at cache
-                            # position p sees keys from the start of the
-                            # PREVIOUS band block — exactly the window the
-                            # serial window/2 slide schedule leaves resident
-                            # when p's chunk runs
-                            qpos = idx + jnp.arange(s)
-                            band_start = jnp.maximum(
-                                0, (qpos // band - 1) * band)
-                            valid = valid & (jnp.arange(t)[None, None, :]
-                                             >= band_start[None, :, None])
-                    else:
-                        # ragged batch (rows decode from different prompt
-                        # lengths): per-row scatter of this step's (or
-                        # chunk's) positions. s == 1 is the familiar decode
-                        # step; s > 1 is a SPECULATIVE VERIFY CHUNK — row
-                        # r's chunk lands at idx[r]..idx[r]+s-1 and query j
-                        # attends keys <= idx[r]+j (causal within the
-                        # chunk). Out-of-bounds scatter indices DROP (jax
-                        # .at[] default), which is exactly the engine's
-                        # over-decode/rollback contract: a rejected tail or
-                        # past-the-window write lands nowhere a kept token
-                        # can read.
-                        rows = jnp.arange(b)
-                        cols = idx[:, None] + jnp.arange(s)[None, :]  # [b, s]
-                        for name, val in store.items():
-                            new_cache[name] = cache[name].at[
-                                rows[:, None], cols].set(val)
-                        t = new_cache[next(iter(store))].shape[1]
-                        valid = (jnp.arange(t)[None, None, :]
-                                 <= cols[:, :, None])  # [b, s, t]
-                    new_cache = {name: shard_hint(val, "dp", None, "tp")
-                                 for name, val in new_cache.items()}
+                    new_cache, valid, t = _cache_write(
+                        cache, _kv_store(cfg, k, v), idx, b, s, band)
                 with jax.named_scope("attend"):
                     # length-aware blocked decode attention: one-token steps
                     # read each row's ACTIVE window instead of the full
@@ -550,29 +841,7 @@ class LlamaBlock(nn.Module):
                                                      sp_mesh)
                         else:
                             out = _attend(q, ck, cv, attn_mask)
-
-        with jax.named_scope("o_proj"):
-            out = out.reshape(b, s, cfg.heads * d)
-            x = x + QDense(cfg.hidden, cfg.quant, cfg.dtype,
-                           cfg.matmul_backend, name="o_proj")(out)
-
-        with jax.named_scope("mlp"):
-            h = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
-            if cfg.moe_experts:
-                from lambdipy_tpu.models.moe import MoEMLP
-
-                x = x + MoEMLP(cfg.moe_experts, cfg.mlp, cfg.moe_top_k,
-                               cfg.moe_capacity_factor, cfg.dtype, cfg.quant,
-                               group_size=cfg.moe_group_size, name="moe")(h)
-            else:
-                gate = QDense(cfg.mlp, cfg.quant, cfg.dtype,
-                              cfg.matmul_backend, name="gate_proj")(h)
-                up = QDense(cfg.mlp, cfg.quant, cfg.dtype,
-                            cfg.matmul_backend, name="up_proj")(h)
-                x = x + QDense(cfg.hidden, cfg.quant, cfg.dtype,
-                               cfg.matmul_backend, name="down_proj")(
-                    nn.silu(gate) * up)
-        return x, new_cache
+        return out, new_cache
 
 
 class LlamaModel(nn.Module):
@@ -613,7 +882,7 @@ class LlamaModel(nn.Module):
         new_cache = []
         for i in range(n_layers):
             layer_cache = None if cache is None else cache[i]
-            x, c = LlamaBlock(cfg, name=f"layer_{i}")(
+            x, c = LlamaBlock(cfg, i, name=f"layer_{i}")(
                 x, positions, mask, layer_cache, sp_prefill=sp_prefill,
                 band=band)
             new_cache.append(c)
@@ -629,6 +898,9 @@ class LlamaModel(nn.Module):
 
 
 def _empty_cache_entry(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
+    if cfg.attn_kind != "kv":
+        return {name: jnp.zeros((batch, max_len, heads, width), cfg.dtype)
+                for name, (heads, width) in cfg.cache_layout().items()}
     shape = (batch, max_len, cfg.kv_heads, cfg.head_dim)
     if cfg.kv_quant == "int8":
         return {"k_int8": jnp.zeros(shape, jnp.int8),
@@ -693,6 +965,13 @@ def validate_serving_mesh(cfg: LlamaConfig, mesh) -> None:
     ``tp=8`` over 4 kv heads would then pay an 8-chip mesh to replicate
     its dominant HBM object. Raise loudly instead."""
     shape = dict(getattr(mesh, "shape", {}) or {})
+    if (cfg.attn_kind != "kv" or cfg.ffn_kind != "dense") \
+            and any(int(n) > 1 for n in shape.values()):
+        raise NotImplementedError(
+            f"mesh {shape}: no sharding is written yet for latent attention "
+            "(a cache row has no head axis to split) or the dropless routed "
+            "FFN (a chip's share of the experts): serve this model on one "
+            "device (PERF.md section 7)")
     tp = int(shape.get("tp", 1))
     if tp <= 1:
         return
@@ -766,6 +1045,7 @@ def init_page_arena(cfg: LlamaConfig, n_pages: int, page: int, mesh=None):
     arena is placed kv-head-sharded over ``tp``
     (:func:`shard_page_arena`): per-device arena HBM drops ~1/tp and
     the paged gather/scatter programs keep the layout end to end."""
+    require_kv_cache(cfg, "the paged KV arena (init_page_arena)")
     shape = (n_pages, page, cfg.kv_heads, cfg.head_dim)
     if cfg.kv_quant == "int8":
         arena = [{"k_int8": jnp.zeros(shape, jnp.int8),
@@ -786,6 +1066,7 @@ def page_kv_bytes(cfg: LlamaConfig, page: int) -> int:
     no device access)."""
     import numpy as np
 
+    require_kv_cache(cfg, "the page pool's byte accounting (page_kv_bytes)")
     per_pos = cfg.kv_heads * cfg.head_dim
     if cfg.kv_quant == "int8":
         # int8 k + v values, f32 per-position-per-head scales
@@ -884,7 +1165,7 @@ def prefill_into_cache(cfg: LlamaConfig, prefill_cache, batch: int, max_len: int
 
     out = []
     for entry in prefill_cache:
-        store = _kv_store(cfg, entry["k"], entry["v"])
+        store = _kv_store(cfg, *(entry[name] for name in cfg.cache_layout()))
         dest = _empty_cache_entry(cfg, batch, max_len)
         for name, val in store.items():
             dest[name] = shard_hint(
@@ -949,6 +1230,11 @@ def pipeline_forward(model: LlamaModel, params, tokens, mesh, *,
         stack_stage_params)
 
     cfg = model.cfg
+    if cfg.ffn_kind != "dense":
+        raise NotImplementedError(
+            "pipeline_forward stacks the layers' trees, so they must be "
+            "alike: a model with leading dense layers before routed ones "
+            "is not")
     p = params["params"]
     n_stages = mesh.shape["pp"]
     if cfg.layers % n_stages:
@@ -1060,7 +1346,8 @@ def _split_rows(keys):
 
 def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
                  start, done0, keys, eos_id, decode_steps: int,
-                 return_carry: bool = False, pos_offset=None):
+                 return_carry: bool = False, pos_offset=None,
+                 count_load: bool = False):
     """The decode scan shared by the exact-shape path (:func:`_decode`),
     the bucketed serving path (:func:`_serve_decode`) and the streaming
     segment path: one compiled step per token over a static-shape cache.
@@ -1080,17 +1367,31 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
     windowed long-context path gathers a sliding view whose slot 0 is
     logical token ``pos_offset``), while RoPE sees ``pos + pos_offset``,
     the token's true logical position. None keeps every existing path
-    byte-identical (no extra operand is traced)."""
+    byte-identical (no extra operand is traced).
+
+    ``count_load`` (a routed-FFN model's engine segments,
+    ``cfg.counts_moe_load``): the emitted tuple gains a third member, the
+    assignments each row sent to each expert, int32 ``[b, experts]``
+    summed over the layers and the steps (``moe_stats/load`` as
+    ``RoutedMLP`` sows it); the carry is what it was."""
     b = first.shape[0]
     has_eos = eos_id >= 0
 
     def step(carry, _):
+        if count_load:
+            carry, load = carry
         tok, lp, cache, pos, done, keys = carry  # pos: int32 scalar or [b]
         rope_pos = pos if pos_offset is None else pos + pos_offset
         positions = (rope_pos[:, None] if jnp.ndim(rope_pos)
                      else jnp.broadcast_to(rope_pos[None, None], (b, 1)))
-        logits, new_cache = model.apply(params, tok[:, None],
-                                        positions=positions, cache=cache)
+        if count_load:
+            (logits, new_cache), sown = model.apply(
+                params, tok[:, None], positions=positions, cache=cache,
+                mutable=["moe_stats"])
+            load = load + sum(jax.tree.leaves(sown))
+        else:
+            logits, new_cache = model.apply(params, tok[:, None],
+                                            positions=positions, cache=cache)
         for entry in new_cache:
             entry["index"] = pos + 1
         keys, subs = _split_rows(keys)
@@ -1098,12 +1399,17 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
         nxt = jnp.where(done, eos_id, nxt)
         nlp = jnp.where(done, jnp.float32(0.0), nlp)
         done = done | (has_eos & (nxt == eos_id))
-        return (nxt, nlp, new_cache, pos + 1, done, keys), (tok, lp)
+        carry = (nxt, nlp, new_cache, pos + 1, done, keys)
+        return ((carry, load) if count_load else carry), (tok, lp)
 
-    carry, (toks, lps) = jax.lax.scan(
-        step, (first, lp0, cache, start, done0, keys), None,
-        length=decode_steps)
+    init = (first, lp0, cache, start, done0, keys)
+    if count_load:
+        init = (init, jnp.zeros((b, model.cfg.moe_experts), jnp.int32))
+    carry, (toks, lps) = jax.lax.scan(step, init, None, length=decode_steps)
     out = (jnp.transpose(toks), jnp.transpose(lps))  # [b, decode_steps] x2
+    if count_load:
+        carry, load = carry
+        out = (*out, load)
     return (out, carry) if return_carry else out
 
 
@@ -2283,7 +2589,8 @@ class LlamaServer:
                 select = _serve_select(temperature, top_k, top_p)
                 return _scan_decode(self.model, params, select, first, lp,
                                     cache, pos, done, rng, eos_id, segment,
-                                    return_carry=True)
+                                    return_carry=True,
+                                    count_load=self.model.cfg.counts_moe_load)
 
             return (jax.jit(prefill), jax.jit(seg))
 
@@ -2320,9 +2627,10 @@ class LlamaServer:
                                        val, 0, window, axis=1))
                             for name, val in entry.items()}
                            for entry in cache]
-                (toks, lps), carry = _scan_decode(
+                out, carry = _scan_decode(
                     self.model, params, select, first, lp, win, pos, done,
-                    rng, eos_id, segment, return_carry=True)
+                    rng, eos_id, segment, return_carry=True,
+                    count_load=self.model.cfg.counts_moe_load)
                 f2, lp2, wcache, pos2, done2, rng2 = carry
                 with jax.named_scope("kv_window"):
                     merged = [
@@ -2331,7 +2639,7 @@ class LlamaServer:
                                     cache[i][name], val, 0, axis=1))
                          for name, val in entry.items()}
                         for i, entry in enumerate(wcache)]
-                return (toks, lps), (f2, lp2, merged, pos2, done2, rng2)
+                return out, (f2, lp2, merged, pos2, done2, rng2)
 
             return jax.jit(seg)
 
@@ -2834,7 +3142,8 @@ class LlamaServer:
                          *knobs, key, eos)
             emitted = 0
             while emitted < max_new_tokens:
-                (toks, lps), carry = seg(self.params, *knobs, *carry, eos)
+                (toks, lps, *_), carry = seg(self.params, *knobs, *carry,
+                                             eos)
                 chunk = np.asarray(jax.device_get(toks))
                 take = min(chunk.shape[1], max_new_tokens - emitted)
                 emitted += take
@@ -2908,7 +3217,8 @@ class LlamaServer:
                             *knobs, key, eos)
             emitted = 0
             while emitted < max_new_tokens:
-                (toks, lps), carry = seg(self.params, *knobs, *carry, eos)
+                (toks, lps, *_), carry = seg(self.params, *knobs, *carry,
+                                             eos)
                 chunk = np.asarray(jax.device_get(toks))[:b]
                 take = min(chunk.shape[1], max_new_tokens - emitted)
                 emitted += take

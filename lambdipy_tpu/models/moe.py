@@ -1,6 +1,18 @@
-"""Sparse Mixture-of-Experts MLP with expert parallelism over ``ep``.
+"""Sparse Mixture-of-Experts FFNs. Two forms, for two uses.
 
-New TPU-first surface (the reference has no model code at all — SURVEY.md
+**The dropless form** (``RoutedMLP``, further down; ``ffn_kind="routed"``,
+the ``deepseek-v3`` model) is what a SERVED model routes with: no capacity,
+no dropped token, a token's result independent of what it is batched with;
+sigmoid or softmax scores, a routing bias that moves the choice and not the
+weight, scaled weights, shared experts, int8 expert stacks.
+
+**The capacity form** (``MoEMLP``, ``route_topk``; ``moe_experts > 0`` on a
+dense-kind config: ``llama-moe-tiny``, ``train/``) is the GShard
+formulation for training over an ``ep`` mesh axis: it DROPS what overflows
+an expert's capacity, so it is served only at a seat-every-token capacity
+(the benchmark's rehearsal family) and never as a cell.
+
+The capacity form: new TPU-first surface (the reference has no model code at all — SURVEY.md
 §3.2); this is the Mixtral-style sparse FFN for the Llama family
 (models/llama.py wires it in when ``LlamaConfig.moe_experts > 0``).
 
@@ -26,8 +38,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
-
-
 
 
 def route_topk(probs, top_k: int, capacity: int, valid=None):
@@ -75,6 +85,28 @@ def route_topk(probs, top_k: int, capacity: int, valid=None):
     return dispatch, combine, aux
 
 
+def _expert_stack(module, name: str, shape, quant, dtype):
+    """An expert-stacked weight ``[E, in, out]`` of ``module`` as ``(kernel,
+    scale)``: int8 ``<name>_int8`` with float32 ``<name>_scale`` [E, 1, out]
+    per (expert, output channel) under quant="int8" (the layout
+    ``llama.quantize_params`` writes), else the float ``<name>`` and None."""
+    if quant != "int8":
+        return module.param(
+            name, nn.initializers.lecun_normal(batch_axis=(0,)), shape,
+            dtype), None
+
+    def init_int8(key, shape, _dtype):
+        w = nn.initializers.lecun_normal(batch_axis=(0,))(
+            key, shape, jnp.float32)
+        scale = jnp.max(jnp.abs(w), axis=1, keepdims=True) / 127.0
+        return jnp.round(w / jnp.maximum(scale, 1e-8)).astype(jnp.int8)
+
+    return (module.param(f"{name}_int8", init_int8, shape, jnp.int8),
+            module.param(f"{name}_scale", nn.initializers.constant(
+                1.0 / (127.0 * shape[1] ** 0.5)),
+                (shape[0], 1, shape[2]), jnp.float32))
+
+
 class MoEMLP(nn.Module):
     """Top-k routed SwiGLU experts, expert dim sharded over ``ep``.
 
@@ -98,21 +130,10 @@ class MoEMLP(nn.Module):
     group_size: int = 256
 
     def _expert_weight(self, name: str, shape):
-        if self.quant == "int8":
-            def init_int8(key, shape, _dtype):
-                w = nn.initializers.lecun_normal(batch_axis=(0,))(
-                    key, shape, jnp.float32)
-                scale = jnp.max(jnp.abs(w), axis=1, keepdims=True) / 127.0
-                return jnp.round(w / jnp.maximum(scale, 1e-8)).astype(jnp.int8)
-
-            w_i8 = self.param(f"{name}_int8", init_int8, shape, jnp.int8)
-            scale = self.param(
-                f"{name}_scale",
-                nn.initializers.constant(1.0 / (127.0 * shape[1] ** 0.5)),
-                (shape[0], 1, shape[2]), jnp.float32)
-            return w_i8.astype(self.dtype) * scale.astype(self.dtype)
-        return self.param(name, nn.initializers.lecun_normal(batch_axis=(0,)),
-                          shape, self.dtype)
+        w, scale = _expert_stack(self, name, shape, self.quant, self.dtype)
+        if scale is None:
+            return w
+        return w.astype(self.dtype) * scale.astype(self.dtype)
 
     @nn.compact
     def __call__(self, x):
@@ -163,6 +184,231 @@ class MoEMLP(nn.Module):
         out = jnp.einsum("gtec,gech->gth", combine.astype(self.dtype), ye)
         out = out.reshape(g * gs, hidden)[:t]
         return out.reshape(b, s, hidden).astype(x.dtype)
+
+
+# -- the dropless form: what a served model routes with ----------------------
+
+def route_dropless(logits, bias, top_k: int, *, scoring: str, norm: bool,
+                   scaling: float):
+    """Top-k routing as DeepSeek-V3's ``noaux_tc`` gate publishes it, with
+    no capacity: ``logits`` [t, e] float32 -> (experts [t, k] int32,
+    weights [t, k] float32). Scores are ``sigmoid`` (or ``softmax``) of the
+    logits; the k experts with the largest ``score + bias`` are chosen
+    (ties to the lowest index); their weights are the scores WITHOUT the
+    bias, divided by their sum when ``norm``, times ``scaling``. The bias
+    (``e_score_correction_bias``) moves the choice, never the weight."""
+    scores = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    _, experts = jax.lax.top_k(scores + bias, top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if norm:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), weights * scaling
+
+
+def _expert_block(num_assignments: int, num_experts: int) -> int:
+    """Rows of one grouped-matmul block: a power of two near twice the mean
+    group, between 8 (a decode step's groups are one or two rows) and 128."""
+    want = max(1, -(-2 * num_assignments // num_experts))
+    block = 8
+    while block < min(want, 128):
+        block *= 2
+    return block
+
+
+def grouped_experts(tokens, experts, weights, valid, expert_fn,
+                    num_experts: int):
+    """``sum_k weights[t, k] * expert_fn(experts[t, k], tokens[t])`` without
+    a capacity: the t x k assignments are sorted by expert and cut into
+    blocks of rows that belong to ONE expert each; a loop over the blocks
+    that exist (its trip count is the data's: an expert nobody chose costs
+    nothing, and none of its weights is read) gathers a block's rows, runs
+    that expert on them and adds the weighted result to the rows' tokens.
+    A token's six results are added in the order of their experts' indices,
+    whatever else is in the batch. ``valid`` [t] bool or None: an invalid
+    token has no assignment. ``expert_fn(e, rows [block, h]) -> [block, h]``
+    float32. Returns [t, h] float32."""
+    t, k = experts.shape
+    a = t * k
+    block = _expert_block(a, num_experts)
+    flat_e = experts.reshape(a)
+    if valid is not None:
+        # past every real group: sorted last, counted nowhere
+        flat_e = jnp.where(jnp.repeat(valid, k), flat_e, num_experts)
+    order = jnp.argsort(flat_e, stable=True)
+    sorted_t = jnp.repeat(jnp.arange(t, dtype=jnp.int32), k)[order]
+    sorted_w = weights.reshape(a)[order]
+    counts = jnp.zeros((num_experts + 1,), jnp.int32).at[flat_e].add(1)[
+        :num_experts]
+    starts = jnp.cumsum(counts) - counts          # group e in the sorted rows
+    blocks = (counts + block - 1) // block        # blocks of group e
+    block_ends = jnp.cumsum(blocks)
+
+    def body(j, out):
+        e = jnp.searchsorted(block_ends, j, side="right").astype(jnp.int32)
+        first = starts[e] + (j - (block_ends[e] - blocks[e])) * block
+        at = first + jnp.arange(block, dtype=jnp.int32)
+        live = at < starts[e] + counts[e]
+        at = jnp.minimum(at, a - 1)
+        rows = sorted_t[at]
+        y = expert_fn(e, jnp.take(tokens, rows, axis=0))
+        y = jnp.where(live[:, None], y * sorted_w[at][:, None], 0.0)
+        return out.at[rows].add(y)
+
+    return jax.lax.fori_loop(
+        0, block_ends[-1], body,
+        jnp.zeros((t, tokens.shape[-1]), jnp.float32))
+
+
+def streamed_experts(tokens, experts, weights, valid, stacks, dtype):
+    """The same sum as :func:`grouped_experts` with EVERY expert run on every
+    token and the unchosen weighted zero: three batched products over the
+    whole stacks, no sort, no loop. For a handful of tokens (a decode step)
+    whose picks cover a good part of the experts anyway: the stacks stream
+    once at the weight roofline, where the grouped loop pays a data-dependent
+    iteration for each distinct expert. ``stacks``: (gate, up, down), each
+    ``(kernel [E, in, out], scale [E, 1, out] or None)``. [t, h] float32.
+    (XLA's CPU backend has no bfloat16 x bfloat16 -> float32 product with the
+    batch axis in the middle, which the last of the three is: on a CPU serve
+    a routed model in float32, as the benchmark's toy twin does. An
+    expert-major order runs there too but compiles to other convolutions on
+    the chip, so it waits for a PR that measures it: PERF.md section 7.)"""
+    t, _ = experts.shape
+    num_experts = stacks[0][0].shape[0]
+    if valid is not None:
+        weights = weights * valid[:, None]
+    gate_of = jnp.zeros((t, num_experts), jnp.float32).at[
+        jnp.arange(t)[:, None], experts].add(weights)
+
+    def product(spec, rows, stack):
+        w, scale = stack
+        out = jnp.einsum(spec, rows.astype(dtype), w.astype(dtype),
+                         preferred_element_type=jnp.float32)
+        return out if scale is None else out * scale[:, 0][None]
+
+    act = nn.silu(product("th,ehm->tem", tokens, stacks[0])) \
+        * product("th,ehm->tem", tokens, stacks[1])
+    out = product("tem,emh->teh", act, stacks[2])
+    return jnp.sum(out * gate_of[:, :, None], axis=1)
+
+
+# Tokens of one RoutedMLP call up to which every expert is run on every
+# token (``streamed_experts``) instead of grouping the assignments by expert
+# (``grouped_experts``): the measured crossover. One v5e, the expert
+# products alone at 128 experts of 3 x 2048 x 768 int8, top-6, ms a layer,
+# streamed / grouped (my chip run, PR 27, ``chiprun_out/callA/forms.out``):
+#
+#     tokens      8      64     256    512    1024    2048
+#     streamed  0.98    1.03   2.20   4.32    8.50   16.29
+#     grouped   1.07    2.85   3.32   4.06    5.40    5.78
+#
+# They cross near 460 tokens, and a call's token count is a power of two
+# (slots x 1, or joiners x prompt bucket): 256 is the last the streamed
+# form wins (by 1.5 x; by 2.8 x at a group prefill of 64), 512 the first
+# the loop wins, by 1.6 x at 1024 and 2.8 x at 2048, where streaming runs
+# 21 times the arithmetic a top-6 of 128 needs. Up to 256 the stacks stream
+# once (0.6 GB in 0.98 ms: 75 % of the HBM roofline here) and the loop pays
+# about 25 us for each block it visits; streaming in chunks of 256 tokens to bound its float32
+# intermediates (0.6 GB at 1024, 1.2 GB at 2048) costs 2-12 % more than
+# streaming whole and wins nowhere. A grouped kernel that reads each needed
+# expert once at the weight roofline would beat both at decode (PERF.md
+# section 7).
+STREAM_ROWS = 256
+
+
+class RoutedMLP(nn.Module):
+    """The dropless routed FFN of a served model (``ffn_kind="routed"``):
+    float32 router (``route_dropless``), ``moe_experts`` SwiGLU experts of
+    width ``moe_intermediate`` (``streamed_experts`` up to ``STREAM_ROWS``
+    tokens a call, ``grouped_experts`` above: one sum, two costs), and
+    ``n_shared_experts`` always-on experts as ONE :class:`QDense` SwiGLU of
+    their summed width. No capacity, no dropped token, and a token's result
+    does not depend on what it is batched with.
+
+    ``quant="int8"``: the expert stacks are int8 ``[E, in, out]`` with
+    float32 scales ``[E, 1, out]`` applied to the dot's float32 result, as
+    :class:`QDense` does below its weight-bound row count (models/llama.py
+    ``quantize_params`` writes this layout). The router and its bias stay
+    float32. Sows ``moe_stats/load``: this call's assignments per row and
+    expert, int32 [b, E] (the engine's segment programs sum it over layers
+    and steps for ``handler.moe``; nobody else asks for the collection)."""
+
+    cfg: Any  # LlamaConfig
+
+    @nn.compact
+    def __call__(self, x, valid=None):
+        """``x`` [b, s, hidden]: the FFN's normed input, float32 where the
+        caller has it (the router reads it as it comes; the experts'
+        products cast it to ``cfg.dtype``). Returns ``cfg.dtype``."""
+        from lambdipy_tpu.models.llama import QDense
+
+        cfg = self.cfg
+        b, s, hidden = x.shape
+        e, m = cfg.moe_experts, cfg.moe_intermediate
+        tokens = x.reshape(b * s, hidden)
+        if valid is not None:
+            valid = valid.reshape(b * s)
+
+        with jax.named_scope("router"):
+            router = self.param("router", nn.initializers.lecun_normal(),
+                                (hidden, e), jnp.float32)
+            bias = self.param("e_score_correction_bias",
+                              nn.initializers.zeros, (e,), jnp.float32)
+            # float32 all the way: a top-6 of 128 has near-ties, and a TPU
+            # runs a float32 matmul in bfloat16 passes unless told not to
+            logits = jnp.matmul(tokens.astype(jnp.float32), router,
+                                precision=jax.lax.Precision.HIGHEST)
+            experts, weights = route_dropless(
+                logits, bias, cfg.moe_top_k, scoring=cfg.scoring_func,
+                norm=cfg.norm_topk_prob, scaling=cfg.routed_scaling_factor)
+            load = jnp.zeros((b * s, e), jnp.int32).at[
+                jnp.arange(b * s)[:, None], experts].add(1)
+            if valid is not None:
+                load = load * valid[:, None]
+            if not self.is_initializing():  # init returns parameters only
+                self.sow("moe_stats", "load",
+                         load.reshape(b, s, e).sum(axis=1))
+
+        with jax.named_scope("experts"):
+            stacks = [_expert_stack(self, name, shape, cfg.quant, cfg.dtype)
+                      for name, shape in (("experts_gate", (e, hidden, m)),
+                                          ("experts_up", (e, hidden, m)),
+                                          ("experts_down", (e, m, hidden)))]
+
+            def product(rows, stack, i):
+                w, scale = stack
+                out = jnp.matmul(
+                    rows.astype(cfg.dtype),
+                    jax.lax.dynamic_index_in_dim(w, i, 0, False).astype(
+                        cfg.dtype),
+                    preferred_element_type=jnp.float32)
+                if scale is not None:
+                    out = out * jax.lax.dynamic_index_in_dim(scale, i, 0,
+                                                             False)
+                return out
+
+            def expert(i, rows):
+                act = nn.silu(product(rows, stacks[0], i)) \
+                    * product(rows, stacks[1], i)
+                return product(act, stacks[2], i)
+
+            if b * s <= STREAM_ROWS:
+                out = streamed_experts(tokens, experts, weights, valid,
+                                       stacks, cfg.dtype)
+            else:
+                out = grouped_experts(tokens, experts, weights, valid,
+                                      expert, e)
+            out = out.astype(cfg.dtype).reshape(b, s, hidden)
+
+        if cfg.n_shared_experts:
+            with jax.named_scope("shared_expert"):
+                width = cfg.n_shared_experts * m
+                dense = [QDense(n, cfg.quant, cfg.dtype, cfg.matmul_backend,
+                                name=f"shared_{name}_proj")
+                         for name, n in (("gate", width), ("up", width),
+                                         ("down", hidden))]
+                out = out + dense[2](nn.silu(dense[0](x)) * dense[1](x))
+        return out
 
 
 def moe_aux_loss(intermediates) -> jax.Array:

@@ -205,6 +205,12 @@ def _llama_tp_rules():
 
     return ShardingRules(rules=(
         ("*embed/embedding", P("tp", None)),
+        # latent attention's down-projection feeds ONE cache row shared by
+        # all heads: replicated. (Its up-projection kv_b_proj splits by
+        # head like q/k/v below. validate_serving_mesh refuses a mesh for
+        # the latent and routed kinds until a PR shards them; the rules
+        # are here so that the tree has no leaf without one.)
+        ("*kv_a_proj/*", P()),
         ("*o_proj/kernel*", P("tp", None)),
         ("*down_proj/kernel*", P("tp", None)),
         ("*o_proj/scale", P()),
@@ -223,6 +229,7 @@ def _llama_tp_rules():
         ("*moe/experts_down_scale", P("ep", None, None)),
         ("*moe/experts_down", P("ep", "tp", None)),
         ("*moe/router", P()),
+        ("*moe/e_score_correction_bias", P()),
     ))
 
 
@@ -363,6 +370,28 @@ def _build_llama_hf(dtype: str = "bfloat16", quant: str | None = None,
 
     cfg = LlamaConfig(dtype=_dtype(dtype), quant=quant,
                       **_llama_overrides(extra))
+    return _build_llama(cfg)
+
+
+@register("deepseek-v3", "jax",
+          "DeepSeek-V3-style block: latent attention, dropless routed experts")
+def _build_deepseek_v3(dtype: str = "bfloat16", quant: str | None = None,
+                       extra: dict | None = None) -> JaxModel:
+    """The ``deepseek_v3`` architecture through the one block
+    (models/llama.py ``LlamaConfig.layer_spec``): multi-head latent
+    attention without query compression in every layer,
+    ``first_dense_layers`` leading dense SwiGLUs of width ``mlp``, then
+    dropless routed FFNs (models/moe.py ``RoutedMLP``). Every shape key
+    comes from ``extra`` (docs/serving.md, "deepseek-v3 recipe keys");
+    the defaults of the kinds are DeepSeek-V3's own (sigmoid scores,
+    normalised top-k weights, interleaved rotary dims)."""
+    from lambdipy_tpu.models.llama import LlamaConfig
+
+    extra = {"rope_interleave": True, "scoring_func": "sigmoid",
+             "norm_topk_prob": True, **(extra or {})}
+    cfg = LlamaConfig(dtype=_dtype(dtype), quant=quant,
+                      **{**_llama_overrides(extra), "attn_kind": "latent",
+                         "ffn_kind": "routed"})
     return _build_llama(cfg)
 
 

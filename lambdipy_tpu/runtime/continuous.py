@@ -204,6 +204,7 @@ class ContinuousBatcher:
 
         from lambdipy_tpu.runtime.metrics import (DecodeWindowStats,
                                                   EngineFaultStats,
+                                                  MoeLoadStats,
                                                   PipelineStats,
                                                   PrefillStats,
                                                   SpecDecodeStats)
@@ -221,6 +222,10 @@ class ContinuousBatcher:
         # plain segment program still serves windows at the cache cap.
         self.window_bucketing = bool(window_bucketing)
         self.window_stats = DecodeWindowStats()
+        # a routed-FFN model's segment programs return each row's expert
+        # load beside the tokens (llama._scan_decode count_load); the
+        # collector books it here (/metrics handler.moe)
+        self.moe_stats = MoeLoadStats()
         # segments kept in flight on the device before the host fetches
         # the oldest: 1 = the fully synchronous loop (dispatch, fetch,
         # book, repeat — the device idles through every fetch RTT +
@@ -246,6 +251,11 @@ class ContinuousBatcher:
             from lambdipy_tpu.models.llama import _next_bucket
 
             self.spec_k = max(2, _next_bucket(int(spec_k), 2))
+            if getattr(server.model.cfg, "counts_moe_load", False):
+                raise NotImplementedError(
+                    "spec_k on a routed-FFN model: the verify segments "
+                    "return no expert load, so handler.moe would stop "
+                    "counting in silence")
         # spec verify chunks are multi-token steps, which the
         # sequence-parallel decode path cannot serve (spdecode is a
         # one-token formulation): under an sp mesh every verify would
@@ -1425,16 +1435,18 @@ class ContinuousBatcher:
                     want.append(rec["lps"])
                 if kb_rec:
                     want += [rec["counts"], rec["pending"]]
+                if rec["moe_load"] is not None:
+                    want.append(rec["moe_load"])
                 got = [np.asarray(x)
                        for x in jax.device_get(tuple(want))]
                 blk = got.pop(0)
                 lp = got.pop(0) if rec["need_lp"] else None
                 cnt = got.pop(0) if kb_rec else None
                 pend = got.pop(0) if kb_rec else None
-                return blk, lp, cnt, pend
+                return blk, lp, cnt, pend, (got.pop(0) if got else None)
 
-            block, lp_block, counts_h, pending_h = self._device_wait(
-                "segment_fetch", gen, fetch)
+            block, lp_block, counts_h, pending_h, load_h = \
+                self._device_wait("segment_fetch", gen, fetch)
             t_end = time.monotonic()
             phase.enter("eng.book", rids=served)
             if self._had_failure:
@@ -1459,6 +1471,9 @@ class ContinuousBatcher:
                 self.segments_run += 1
                 if self.mesh_stats is not None:
                     self.mesh_stats.record_segment()
+                if load_h is not None:
+                    self.moe_stats.record_rows(load_h[
+                        [slot for slot, e in rec["rows"] if not e["done"]]])
                 for slot, entry in rec["rows"]:
                     # per-row accepted width: everything for a plain
                     # segment; counts_h[slot] (1..kb) for a verify step
@@ -2015,10 +2030,14 @@ class ContinuousBatcher:
 
                     outs, self._carry = self._device_wait(
                         "segment_dispatch", gen, dispatch)
+                    moe_load = None
                     if kb:
                         toks, lps, counts_op, pending_op = outs
                     else:
-                        toks, lps = outs
+                        # a routed-FFN model's plain segments also return
+                        # the rows' expert load
+                        toks, lps, *more = outs
+                        moe_load = more[0] if more else None
                     # attended = per-row sum of positions each step's
                     # attention actually covered (pos + 1 keys at write
                     # index pos); a verify chunk computes all kb
@@ -2026,6 +2045,7 @@ class ContinuousBatcher:
                     # honest width either way
                     rec = {
                         "toks": toks, "lps": lps, "need_lp": need_lp,
+                        "moe_load": moe_load,
                         "rows": live, "window": window,
                         "t_dispatch": t_disp,
                         "attended": sum(adv * p + adv * (adv + 1) // 2

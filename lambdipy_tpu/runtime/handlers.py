@@ -1591,6 +1591,11 @@ def generate_handler(spec: dict, ctx) -> HandlerState:
                 "seconds": preload_state.get("seconds")}
         if batcher is not None:
             out["batching"] = batcher.stats()
+        if continuous is not None and getattr(
+                server.model.cfg, "counts_moe_load", False):
+            # the routed FFN's dropless check and per-expert load, booked
+            # by the engine's collector from each segment's fetch
+            out["moe"] = continuous.moe_stats.report()
         if getattr(server, "spec_metrics", None) is not None:
             # the solo `"speculative": k` path's cumulative acceptance
             # counters (the engine's batching.spec block shares this

@@ -88,10 +88,22 @@ def np_dtype(name: str) -> np.dtype:
             raise ValueError(f"unknown KV wire dtype {name!r}") from None
 
 
+def _require_kv_names(names) -> None:
+    """The wire holds per-head k/v leaves and nothing else: a frame of
+    another cache layout (a latent cache's ``ckv`` / ``kpe``) is refused
+    by name, on the way out as on the way in."""
+    names = list(names)
+    if len(set(names)) != len(names) or not set(names) <= _LEAF_NAMES:
+        raise ValueError(
+            f"bad KV frame leaf names {names}: the KV wire (kvwire) holds "
+            f"per-head k/v leaves {sorted(_LEAF_NAMES)} only")
+
+
 def _leaf_template_of(first_block) -> list:
     """``[name, dtype name, shape]`` rows (name-sorted) from one block's
     first-layer leaf dict — the wire's self-description."""
     names = sorted(first_block[0])
+    _require_kv_names(names)
     out = []
     for name in names:
         arr = np.asarray(first_block[0][name])
@@ -109,8 +121,7 @@ def _leaf_sizes(leaves, block: int) -> list[int]:
     """Per-leaf byte size, validating each leaf's geometry against the
     frame's block width. Raises ValueError on anything malformed."""
     names = [n for n, _, _ in leaves]
-    if len(set(names)) != len(names) or not set(names) <= _LEAF_NAMES:
-        raise ValueError(f"bad KV frame leaf names {names}")
+    _require_kv_names(names)
     per_leaf = []
     for name, dt, shape in leaves:
         if len(shape) != 4 or shape[0] != 1 or shape[1] != block or \

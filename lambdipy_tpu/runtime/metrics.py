@@ -112,6 +112,38 @@ class DecodeWindowStats:
 
 
 @dataclass
+class MoeLoadStats:
+    """Counters of a routed-FFN model's decode segments (the
+    ``handler.moe`` block on ``/metrics``), only growing. ``assignments``:
+    (token, expert) pairs the booked rows' steps sent to routed experts,
+    summed over the layers: with dropless routing exactly booked rows x
+    segment steps x routed layers x experts per token, so a dropped or
+    doubled assignment shows as a difference. ``load``: the same count per
+    expert. The engine's collector adds each booked row's vector from the
+    segment's own fetch; rows the device stepped for nobody (empty slots,
+    over-decode) are left out."""
+
+    assignments: int = 0
+    load: list = field(default_factory=list)   # per expert
+    _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
+
+    def record_rows(self, rows) -> None:
+        """``rows``: int array [booked rows, experts]."""
+        if not len(rows):
+            return
+        total = rows.sum(axis=0)
+        with self._lock:
+            if not self.load:
+                self.load = [0] * len(total)
+            self.load = [a + int(b) for a, b in zip(self.load, total)]
+            self.assignments += int(total.sum())
+
+    def report(self) -> dict:
+        with self._lock:
+            return {"assignments": self.assignments, "load": list(self.load)}
+
+
+@dataclass
 class MeshStats:
     """Gauges + counters for tensor-parallel sharded serving (the
     ``batching.mesh`` block on ``/metrics``). ``shape`` is the serving

@@ -123,9 +123,13 @@ class OffloadArena:
         """Install the wire leaf template up front (``[name, dtype name,
         shape]`` rows, e.g. from the prefix store's ``_leaf_template``)
         so even the FIRST spill skips array introspection."""
+        from lambdipy_tpu.runtime.kvwire import _require_kv_names
+
         self._leaves = [[str(n), str(d), [int(x) for x in s]]
                         for n, d, s in leaves]
         self._names = [n for n, _, _ in self._leaves]
+        # the host tier stores kvwire frames: it takes what the wire takes
+        _require_kv_names(self._names)
         self.stats.record_template_encode()
 
     def _ensure_template(self, block) -> None:

@@ -194,6 +194,9 @@ class PrefixStore:
         # too cheap to measure isolation against)
         self.faults = faults
         cfg = server.model.cfg
+        from lambdipy_tpu.models.llama import require_kv_cache
+
+        require_kv_cache(cfg, "the radix prefix store (PrefixStore)")
         # PAGED mode (runtime/pagepool.py): a radix block IS an arena
         # page. Nodes hold page ids instead of host-side KV slices, a
         # hit hands its pages out by refcount bump (acquire_pages — zero

@@ -1,0 +1,214 @@
+"""The benchmark's family seam under tier-1: the accepted families' golden
+values and the manifest's rules (``benchmark/tests/test_families.py`` and
+``test_manifest.py``, imported whole so that the driver's run counts them),
+and the ``deepseek-v3`` family that PR 27 brought: its leaf rules against
+the program's real tree at published widths, what it says a step needs, by
+hand, and its toy twin through the whole command on the CPU."""
+
+import json
+import os
+from pathlib import Path
+
+import jax
+import numpy as np
+import pytest
+
+from benchmark import families, run, weights
+from benchmark.tests.test_families import *  # noqa: F401,F403
+from benchmark.tests.test_manifest import *  # noqa: F401,F403
+
+BENCH = Path(__file__).resolve().parents[1] / "benchmark"
+KANANA = json.loads((BENCH / "configs" / "kanana2-30b.json").read_text())
+# the twin has a manifest of its own: the accepted rehearsal manifest is a
+# file the benchmark has, and only a benchmark PR edits it
+TWIN_MANIFEST = BENCH / "rehearsal-mla-moe.json"
+TWIN_CELL = "rehearsal-mla-moe.rehearsal-closed"
+
+# the catalog's row for kanana-2-30b-a3b-instruct-2601 (the model-configs
+# guide's architectures.jsonl, ``config``): every key, as published
+PUBLISHED = {
+    "attention_bias": False, "first_k_dense_replace": 1, "head_dim": 64,
+    "hidden_act": "silu", "hidden_size": 2048, "intermediate_size": 6144,
+    "kv_lora_rank": 512, "max_position_embeddings": 32768,
+    "model_type": "deepseek_v3", "moe_intermediate_size": 768,
+    "moe_layer_freq": 1, "n_group": 1, "n_routed_experts": 128,
+    "n_shared_experts": 2, "norm_topk_prob": True, "num_attention_heads": 32,
+    "num_experts_per_tok": 6, "num_hidden_layers": 48,
+    "num_key_value_heads": 32, "q_lora_rank": None, "qk_head_dim": 192,
+    "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "rms_norm_eps": 1e-06,
+    "rope_interleave": True, "rope_scaling": None, "rope_theta": 1000000,
+    "routed_scaling_factor": 2.448, "scoring_func": "sigmoid",
+    "tie_word_embeddings": False, "topk_group": 1, "topk_method": "noaux_tc",
+    "v_head_dim": 128, "vocab_size": 128256}
+
+
+def test_the_configuration_is_the_published_one_cut_in_depth_only():
+    changed = {k for k, v in PUBLISHED.items() if KANANA.get(k, "absent") != v}
+    assert changed == {"num_hidden_layers"}
+    assert KANANA["num_hidden_layers"] == 13 and KANANA["published"] == {
+        "num_hidden_layers": 48, "max_position_embeddings": 32768}
+    assert KANANA["reduced"] == ["num_hidden_layers", "engine_window",
+                                 "max_position_embeddings"]
+    assert set(KANANA["reduced_why"]) == set(KANANA["reduced"])
+    assert (KANANA["engine_window"], KANANA["context_served"]) == (4096, 8192)
+    assert set(KANANA["recipe_extra"]) == {"batch_cache_len", "max_new_tokens"}
+    # the floors of a cut: a whole period, 4 following layers, 8 experts
+    assert KANANA["num_hidden_layers"] - KANANA["first_k_dense_replace"] >= 4
+
+
+@pytest.fixture(scope="module")
+def real_tree():
+    """The program's parameter tree at the published widths: shapes only."""
+    from lambdipy_tpu.models import registry
+
+    adapter = registry.get(KANANA["model"]).build(
+        dtype="bfloat16", quant="int8",
+        extra=families.of(KANANA).dims_of(KANANA))
+    tree = jax.eval_shape(lambda: adapter.init_params(seed=0))
+    return {"/".join(str(k.key) for k in path if k.key != "params"): spec
+            for path, spec in jax.tree_util.tree_leaves_with_path(tree)}
+
+
+def test_the_leaf_rules_name_every_path_of_the_real_tree(real_tree):
+    family = families.of(KANANA)
+    params = 0
+    for path, spec in real_tree.items():
+        params += int(np.prod(spec.shape))
+        # a rule goes by path and dtype: ask it for a sliver of the leaf
+        sliver = tuple(min(n, 2) for n in spec.shape)
+        leaf = family.leaf(1, path, sliver, spec.dtype, KANANA)
+        assert leaf is not None and leaf.shape == sliver, path
+        assert leaf.dtype == np.dtype(spec.dtype), path
+    assert not any("moe_stats" in path for path in real_tree)
+    # the issue's arithmetic at 1 + 12 layers: 64 M + 12 x 640 M of kernels,
+    # 2 x 263 M of embedding and head (scales, norms, routers, biases besides)
+    attn = 2048 * 6144 + 2048 * 576 + 512 * 8192 + 4096 * 2048
+    layer0 = attn + 3 * 2048 * 6144
+    routed = attn + 3 * 2048 * 1536 + 128 * 3 * 2048 * 768 + 2048 * 128
+    kernels = layer0 + 12 * routed + 2 * 128256 * 2048
+    assert 0 < params - kernels < 0.002 * kernels
+    assert 8.2e9 < kernels < 8.6e9
+    shapes = {p: s.shape for p, s in real_tree.items()}
+    assert shapes["layer_3/moe/experts_down_scale"] == (128, 1, 2048)
+    assert shapes["layer_3/moe/e_score_correction_bias"] == (128,)
+    assert shapes["layer_0/kv_b_proj/kernel_int8"] == (512, 32 * 256)
+    assert "layer_0/moe/router" not in shapes and \
+        "layer_1/gate_proj/kernel_int8" not in shapes
+
+
+def test_the_seeded_values_are_what_the_configuration_says_it_assumed():
+    leaf = weights.leaf
+    assert np.all(leaf(KANANA, "layer_2/kv_a_norm/scale", (512,), "float32") == 1)
+    for path, shape, fan_in in (
+            ("layer_2/kv_b_proj/scale", (1, 8192), 512),
+            ("layer_2/o_proj/scale", (1, 2048), 4096),
+            ("layer_0/down_proj/scale", (1, 2048), 6144),
+            ("layer_2/moe/shared_down_proj/scale", (1, 2048), 1536),
+            ("layer_2/moe/experts_down_scale", (4, 1, 2048), 768),
+            ("layer_2/moe/experts_up_scale", (4, 1, 768), 2048),
+            ("lm_head/scale", (1, 64), 2048)):
+        np.testing.assert_allclose(leaf(KANANA, path, shape, "float32"),
+                                   1 / (127 * fan_in ** 0.5), rtol=1e-6)
+    router = leaf(KANANA, "layer_2/moe/router", (2048, 128), "float32")
+    logits = np.random.default_rng(0).normal(size=(64, 2048)) @ router
+    assert 0.8 < logits.std() < 1.6                     # of unit order
+    bias = leaf(KANANA, "layer_2/moe/e_score_correction_bias", (128,), "float32")
+    assert 0 < np.abs(bias).max() <= 0.05 and len(np.unique(bias)) > 64
+    # a layer's experts are kin: 7 parts of 8 the layer's common draw, whole
+    # numbers all the way, at the scale every kernel has (asserted above)
+    stack = leaf(KANANA, "layer_2/moe/experts_gate_int8", (3, 64, 32), "int8")
+    assert stack.dtype == np.int8 and len(np.unique(stack)) > 200
+    own = weights.int8_draw(11, "layer_2/moe/experts_gate_int8", (3, 64, 32))
+    common = weights.int8_draw(11, "layer_2/moe/experts_gate_int8/common",
+                               (64, 32))
+    want = np.floor((7.0 * common[None] + own + 4) / 8)
+    assert np.array_equal(stack, want) and np.abs(want).max() <= 128
+    flat = stack.reshape(3, -1).astype(np.float64)
+    assert np.corrcoef(flat)[0, 1] > 0.95 and not np.array_equal(flat[0], flat[1])
+    with pytest.raises(ValueError, match="deepseek-v3.*q_a_proj"):
+        leaf(KANANA, "layer_2/q_a_proj/kernel", (2, 2), "bfloat16")
+    with pytest.raises(ValueError, match="group-limited"):
+        families.of(KANANA).dims_of(dict(KANANA, n_group=8, topk_group=4))
+
+
+def test_each_fault_of_the_routed_ffn_is_seen_and_the_reference_is_not_moved(
+        capsys, tmp_path):
+    family = families.of(KANANA)
+    twin = json.loads((BENCH / "configs" / "rehearsal-mla-moe.json").read_text())
+    ids = np.random.default_rng(5).integers(0, twin["vocab_size"], (2, 24))
+    rows, at = np.repeat(np.arange(2), 16), np.tile(np.arange(8, 24), 2)
+    alone = np.asarray(family.walk(twin, ids, rows, at, (False,))[False])
+    flags = (False, True) + family.FAULTS
+    streams = family.walk(twin, ids, rows, at, flags)
+    assert np.array_equal(np.asarray(streams[False]), alone)
+    moved = {flag: float(np.abs(np.asarray(streams[flag]) - alone).max())
+             for flag in flags[1:]}
+    assert all(v > 1e-3 for v in moved.values()), moved
+    assert moved["no_routed"] > moved["half_scale"] > moved["int4_experts"]
+    # the command a limit's readings come from (PERF.md section 2)
+    path = tmp_path / "twin.json"
+    path.write_text(json.dumps(twin))
+    assert family.main(["--config", str(path), "--seeds", "3"]) == 0
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert set(line) == {"seed", "int4", *family.FAULTS}
+    assert all(line[k]["widest_gap"] >= 0 for k in line if k != "seed")
+
+
+def test_what_a_step_needs_by_hand_at_8_rows():
+    family = families.of(KANANA)
+    attn = 2048 * 6144 + 2048 * 576 + 512 * 8192 + 4096 * 2048   # 26.3 M
+    touched = 128 * (1 - (1 - 6 / 128) ** 8)                     # 40.9 of 128
+    assert 40.5 < touched < 41.5
+    moe = 12 * (4 * 2048 * 128 + 3 * 2048 * 1536
+                + touched * 3 * 2048 * 768)
+    assert family.moe_step_bytes(KANANA, rows=8) == pytest.approx(moe)
+    assert 2.4e9 < moe < 2.5e9          # against 7.2 GB of resident experts
+    cache = 8 * 300 * 13 * (512 + 64) * 2
+    want = 13 * attn + 3 * 2048 * 6144 + 2048 * 128256 + moe + cache
+    assert family.decode_step_bytes(KANANA, rows=8, context=300) == \
+        pytest.approx(want)
+    assert 3.0e9 < want < 3.2e9
+    # one row touches its six experts, not 41; flops follow the six
+    one = family.moe_step_bytes(KANANA, rows=1)
+    assert one == pytest.approx(12 * (4 * 2048 * 128 + 3 * 2048 * 1536
+                                      + 6 * 3 * 2048 * 768))
+    flops = family.decode_step_flops(KANANA, rows=2, context=100)
+    assert flops == 2 * family.decode_step_flops(KANANA, rows=1, context=100)
+    active = 13 * attn + 3 * 2048 * 6144 + 12 * (
+        2048 * 128 + 3 * 2048 * 1536 + 6 * 3 * 2048 * 768) + 2048 * 128256
+    assert family.decode_step_flops(KANANA, rows=1, context=0) == \
+        pytest.approx(2 * active)
+    head = 2048 * 128256                  # once, at the last position
+    assert family.prefill_flops(KANANA, rows=1, seq_len=128) > \
+        2 * (128 * (active - head) + head)
+
+
+def test_the_toy_twin_runs_the_whole_command_and_counts_its_experts(
+        capsys, tmp_path, monkeypatch):
+    # a rehearsal pins its children to one CPU device through this process's
+    # environment (harness.prepare): put it back for the tests that follow
+    for key in ("JAX_PLATFORMS", "XLA_FLAGS"):
+        monkeypatch.setenv(key, os.environ.get(key, ""))
+    rc = run.main(["--manifest", str(TWIN_MANIFEST), "--workload", TWIN_CELL,
+                   "--seed", str(2**31 + 5), "--seconds", "3", "--trace", "1",
+                   "--work-dir", str(tmp_path)])
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("{")]
+    last = lines[-1]
+    assert rc == 0 and last["correct"] is True, lines[-3:]
+    assert last["device"]["platform"] == "cpu" and last["failed"] == 0
+    window = next(ln for ln in lines if ln.get("stage") == "window")
+    assert window["compiles_in_window"] == 0, window
+    share = last["metrics"]["moe_load_max_share"]["value"]
+    assert 100 / 16 <= share < 60       # 16 experts: even routing reads 6.25
+    # a llama cell's line has no such metric: its reader finds no counter
+    from benchmark import harness
+
+    reader = harness.layer_metric("moe_load_max_share")
+    assert reader.read({"m_open": {"handler": {}}, "m_close": {"handler": {}}}) \
+        is None
+    for name in ("decode_moe_ms", "mla_absorb_ms", "moe_hbm_pct"):
+        assert harness.layer_metric(name).read(
+            {"family": families.load("llama-hf"), "trace": {"busy_s": 1},
+             "slice": {"live": [(8, 100.0)]}, "device": {"kind": "TPU v5 lite"},
+             "config": {}}) is None

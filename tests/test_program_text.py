@@ -1,0 +1,109 @@
+"""The serving programs of the llama block keep their text (ROADMAP D11).
+
+jax hashes a program's computation into the compile-cache key, so a change
+of text under an unchanged shape key is a cold first run for every bundle
+of the accepted cells (13-16 minutes each on the chip), and an AOT
+executable of the old text would be loaded for the new
+(``LlamaServer._AOT_GEN`` salts that by hand). The hashes below are of the
+lowered StableHLO (no source locations in it) of four programs of four
+builds, taken on the parent of PR 27 (commit 635a34e): a PR that adds a
+layer kind beside the llama block leaves them as they are. A PR that means
+to change a program's text bumps ``_AOT_GEN`` and takes the hashes anew in
+the same commit; a PR that moved one without meaning to has found out here
+and not on the chip.
+
+The toy builds above say that the block's text is kept; the second test
+says it at the accepted cells' OWN shape keys (the configuration files'
+widths, 8 slots, their engine window, int8 kernels, 16-step segments), on
+abstract parameters (``jax.eval_shape``: nothing of 7 B is allocated), so
+that a change which only shows at those widths (a row-count threshold, a
+window bucket) is caught here too. Same parent, same rule."""
+
+import hashlib
+import json
+from pathlib import Path
+
+import jax
+import pytest
+
+from benchmark import families
+from lambdipy_tpu.models import registry
+from lambdipy_tpu.models.llama import LlamaServer
+
+HF_TOY = dict(vocab_size=512, hidden=128, layers=2, heads=4, kv_heads=2,
+              mlp=256, max_len=256)
+# build -> (group prefill, full-window segment, window-bucketed segment,
+# fused generate)
+GOLDEN = {
+    ("llama-tiny", "int8", None): (
+        "ac8e71e0cf41", "4cb3e40c0117", "7b678b5bb05f", "5cbabb75c32d"),
+    ("llama-tiny", None, "int8"): (
+        "f8498cb116a5", "ccd1be5cec2c", "9df739b88baf", "6297c1b7e0a4"),
+    ("llama-moe-tiny", "int8", None): (
+        "58279adf20e1", "0910c464005e", "275a2fc53809", "7ae7322e8c17"),
+    ("llama-hf", "int8", None): (
+        "f09c98151802", "db88543dfb76", "ffdda39ac408", "dc8dc8bf0699"),
+}
+
+
+def text_hash(fn, *args) -> str:
+    return hashlib.sha256(fn.lower(*args).as_text().encode()).hexdigest()[:12]
+
+
+@pytest.mark.parametrize("model,quant,kv_quant", list(GOLDEN))
+def test_a_llama_program_lowers_to_the_text_its_generation_was_taken_at(
+        model, quant, kv_quant):
+    assert LlamaServer._AOT_GEN == "g4", "new generation: take the hashes anew"
+    extra = dict(HF_TOY) if model == "llama-hf" else {}
+    if kv_quant:
+        extra["kv_quant"] = kv_quant
+    adapter = registry.get(model).build(dtype="bfloat16", quant=quant,
+                                        extra=extra)
+    params = adapter.init_params(seed=0)
+    server = adapter.make_server(params)
+    key = ("stream", 4, 32, 128, 16)
+    prefill, seg = server._stream_fns(*key[1:])
+    pre_ops, seg_ops = server._aot_examples(key)
+    got = (text_hash(prefill, params, *pre_ops),
+           text_hash(seg, params, *seg_ops),
+           text_hash(server._windowed_seg_fn(4, 128, 64, 16), params, *seg_ops),
+           text_hash(server._compiled(2, 16, 16), params,
+                     *server._aot_examples((2, 16, 16))[0]))
+    assert got == GOLDEN[model, quant, kv_quant]
+
+
+# configuration -> (a window bucket its cells decode in; hashes of: the
+# first-burst prefill of 8 x 64, a group prefill of 2 joiners x 128, the
+# full-window segment, the window-bucketed segment)
+CELL_GOLDEN = {
+    "mistral7b": (512, (
+        "68b128eeb1bf", "dbf45417a475", "ca9524f17c20", "d592697fcc05")),
+    "deepseek7b": (256, (
+        "c9cc88b7ee32", "9eb36fcacfd8", "d57fca900194", "38b9a6743119")),
+}
+
+
+@pytest.mark.parametrize("name", list(CELL_GOLDEN))
+def test_an_accepted_cells_programs_keep_their_text_at_the_cells_shapes(name):
+    assert LlamaServer._AOT_GEN == "g4", "new generation: take the hashes anew"
+    window, golden = CELL_GOLDEN[name]
+    config = json.loads((Path(__file__).parents[1] / "benchmark" / "configs"
+                         / f"{name}.json").read_text())
+    adapter = registry.get(config["model"]).build(
+        dtype=config["precision"]["activations"],
+        quant=config["precision"]["weights"],
+        extra=families.of(config).dims_of(config))
+    params = jax.eval_shape(lambda: adapter.init_params(seed=0))
+    server = adapter.make_server(params)
+    slots, cache_len = 8, config["engine_window"]
+    key = ("stream", slots, 64, cache_len, 16)
+    prefill, seg = server._stream_fns(*key[1:])
+    pre_ops, seg_ops = jax.eval_shape(lambda: server._aot_examples(key))
+    join_key = ("stream", 2, 128, cache_len, 16)
+    join_ops = jax.eval_shape(lambda: server._aot_examples(join_key))[0]
+    got = (text_hash(prefill, params, *pre_ops),
+           text_hash(server._stream_fns(*join_key[1:])[0], params, *join_ops),
+           text_hash(seg, params, *seg_ops),
+           text_hash(server._windowed_seg_fn(slots, cache_len, window, 16),
+                     params, *seg_ops))
+    assert got == golden
