@@ -1370,16 +1370,18 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
     byte-identical (no extra operand is traced).
 
     ``count_load`` (a routed-FFN model's engine segments,
-    ``cfg.counts_moe_load``): the emitted tuple gains a third member, the
-    assignments each row sent to each expert, int32 ``[b, experts]``
-    summed over the layers and the steps (``moe_stats/load`` as
-    ``RoutedMLP`` sows it); the carry is what it was."""
+    ``cfg.counts_moe_load``): the emitted tuple gains two members, both
+    summed over the routed layers and the steps as ``RoutedMLP`` sows
+    them: the assignments each row sent to each expert, int32
+    ``[b, experts]`` (``moe_stats/load``), and the distinct experts a
+    layer's call picked, one int32 (``moe_reads/experts``); the carry is
+    what it was."""
     b = first.shape[0]
     has_eos = eos_id >= 0
 
     def step(carry, _):
         if count_load:
-            carry, load = carry
+            carry, (load, read) = carry
         tok, lp, cache, pos, done, keys = carry  # pos: int32 scalar or [b]
         rope_pos = pos if pos_offset is None else pos + pos_offset
         positions = (rope_pos[:, None] if jnp.ndim(rope_pos)
@@ -1387,8 +1389,9 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
         if count_load:
             (logits, new_cache), sown = model.apply(
                 params, tok[:, None], positions=positions, cache=cache,
-                mutable=["moe_stats"])
-            load = load + sum(jax.tree.leaves(sown))
+                mutable=["moe_stats", "moe_reads"])
+            load = load + sum(jax.tree.leaves(sown["moe_stats"]))
+            read = read + sum(jax.tree.leaves(sown["moe_reads"]))
         else:
             logits, new_cache = model.apply(params, tok[:, None],
                                             positions=positions, cache=cache)
@@ -1400,16 +1403,17 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
         nlp = jnp.where(done, jnp.float32(0.0), nlp)
         done = done | (has_eos & (nxt == eos_id))
         carry = (nxt, nlp, new_cache, pos + 1, done, keys)
-        return ((carry, load) if count_load else carry), (tok, lp)
+        return ((carry, (load, read)) if count_load else carry), (tok, lp)
 
     init = (first, lp0, cache, start, done0, keys)
     if count_load:
-        init = (init, jnp.zeros((b, model.cfg.moe_experts), jnp.int32))
+        init = (init, (jnp.zeros((b, model.cfg.moe_experts), jnp.int32),
+                       jnp.int32(0)))
     carry, (toks, lps) = jax.lax.scan(step, init, None, length=decode_steps)
     out = (jnp.transpose(toks), jnp.transpose(lps))  # [b, decode_steps] x2
     if count_load:
-        carry, load = carry
-        out = (*out, load)
+        carry, counts = carry
+        out = (*out, *counts)
     return (out, carry) if return_carry else out
 
 

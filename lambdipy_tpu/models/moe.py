@@ -4,7 +4,9 @@
 the ``deepseek-v3`` model) is what a SERVED model routes with: no capacity,
 no dropped token, a token's result independent of what it is batched with;
 sigmoid or softmax scores, a routing bias that moves the choice and not the
-weight, scaled weights, shared experts, int8 expert stacks.
+weight, scaled weights, shared experts, int8 expert stacks; for a call of
+few tokens (a decode step) a kernel that fetches only the experts its rows
+picked (``ops/grouped_experts.py``).
 
 **The capacity form** (``MoEMLP``, ``route_topk``; ``moe_experts > 0`` on a
 dense-kind config: ``llama-moe-tiny``, ``train/``) is the GShard
@@ -38,6 +40,9 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+
+from lambdipy_tpu.ops import kernels_compile_here
+from lambdipy_tpu.ops.grouped_experts import picked_experts, streamed_experts
 
 
 def route_topk(probs, top_k: int, capacity: int, valid=None):
@@ -260,78 +265,91 @@ def grouped_experts(tokens, experts, weights, valid, expert_fn,
         jnp.zeros((t, tokens.shape[-1]), jnp.float32))
 
 
-def streamed_experts(tokens, experts, weights, valid, stacks, dtype):
-    """The same sum as :func:`grouped_experts` with EVERY expert run on every
-    token and the unchosen weighted zero: three batched products over the
-    whole stacks, no sort, no loop. For a handful of tokens (a decode step)
-    whose picks cover a good part of the experts anyway: the stacks stream
-    once at the weight roofline, where the grouped loop pays a data-dependent
-    iteration for each distinct expert. ``stacks``: (gate, up, down), each
-    ``(kernel [E, in, out], scale [E, 1, out] or None)``. [t, h] float32.
-    (XLA's CPU backend has no bfloat16 x bfloat16 -> float32 product with the
-    batch axis in the middle, which the last of the three is: on a CPU serve
-    a routed model in float32, as the benchmark's toy twin does. An
-    expert-major order runs there too but compiles to other convolutions on
-    the chip, so it waits for a PR that measures it: PERF.md section 7.)"""
-    t, _ = experts.shape
-    num_experts = stacks[0][0].shape[0]
-    if valid is not None:
-        weights = weights * valid[:, None]
-    gate_of = jnp.zeros((t, num_experts), jnp.float32).at[
-        jnp.arange(t)[:, None], experts].add(weights)
-
-    def product(spec, rows, stack):
+def expert_by_index(stacks, dtype):
+    """``expert(i, rows)`` for :func:`grouped_experts` over the three stacks
+    of SwiGLU experts (gate, up, down), each ``(kernel [E, in, out], scale
+    [E, 1, out] or None)``: expert ``i``'s kernels are sliced out of the
+    stacks, cast to ``dtype`` (an int8 kernel exactly), the products
+    accumulate in float32 and the scale multiplies the dot's result."""
+    def product(rows, stack, i):
         w, scale = stack
-        out = jnp.einsum(spec, rows.astype(dtype), w.astype(dtype),
-                         preferred_element_type=jnp.float32)
-        return out if scale is None else out * scale[:, 0][None]
+        out = jnp.matmul(
+            rows.astype(dtype),
+            jax.lax.dynamic_index_in_dim(w, i, 0, False).astype(dtype),
+            preferred_element_type=jnp.float32)
+        if scale is not None:
+            out = out * jax.lax.dynamic_index_in_dim(scale, i, 0, False)
+        return out
 
-    act = nn.silu(product("th,ehm->tem", tokens, stacks[0])) \
-        * product("th,ehm->tem", tokens, stacks[1])
-    out = product("tem,emh->teh", act, stacks[2])
-    return jnp.sum(out * gate_of[:, :, None], axis=1)
+    def expert(i, rows):
+        act = nn.silu(product(rows, stacks[0], i)) \
+            * product(rows, stacks[1], i)
+        return product(act, stacks[2], i)
+
+    return expert
 
 
-# Tokens of one RoutedMLP call up to which every expert is run on every
-# token (``streamed_experts``) instead of grouping the assignments by expert
-# (``grouped_experts``): the measured crossover. One v5e, the expert
-# products alone at 128 experts of 3 x 2048 x 768 int8, top-6, ms a layer,
-# streamed / grouped (my chip run, PR 27, ``chiprun_out/callA/forms.out``):
+# Tokens of one RoutedMLP call up to which the experts' sum runs every token
+# through an expert it visits (the kernel ``picked_experts`` where Mosaic
+# compiles: only the experts the rows picked; elsewhere ``streamed_experts``:
+# all of them) instead of grouping the assignments by expert
+# (``grouped_experts``): the measured crossovers. One v5e, the routed sum of
+# a layer at 128 experts of 3 x 2048 x 768 int8, top-6, bfloat16, ms a
+# layer (``scripts/moe_forms.py``; my chip run, PR 28,
+# ``chiprun_out/c1/forms.out``; the router and a norm, 0.01 ms, in all):
 #
-#     tokens      8      64     256    512    1024    2048
-#     streamed  0.98    1.03   2.20   4.32    8.50   16.29
-#     grouped   1.07    2.85   3.32   4.06    5.40    5.78
+#     tokens          8      16     32     64     128    256
+#     distinct      40.5   67.7   99.2  121.2  127.4  128.0
+#     picked        0.302  0.477  0.682  0.826  0.892  1.675
+#     streamed      0.851  0.854  0.863  0.884  1.313  2.107
+#     grouped       0.917  1.492  2.181  2.674  2.908  3.198
 #
-# They cross near 460 tokens, and a call's token count is a power of two
-# (slots x 1, or joiners x prompt bucket): 256 is the last the streamed
-# form wins (by 1.5 x; by 2.8 x at a group prefill of 64), 512 the first
-# the loop wins, by 1.6 x at 1024 and 2.8 x at 2048, where streaming runs
-# 21 times the arithmetic a top-6 of 128 needs. Up to 256 the stacks stream
-# once (0.6 GB in 0.98 ms: 75 % of the HBM roofline here) and the loop pays
-# about 25 us for each block it visits; streaming in chunks of 256 tokens to bound its float32
+# and above (PR 27, ``chiprun_out/callA/forms.out``, one call of four layers
+# a reading, so about 0.1 ms a layer of dispatch in each), streamed /
+# grouped: 4.32 / 4.06 at 512 tokens, 8.50 / 5.40 at 1024, 16.29 / 5.78 at
+# 2048.
+#
+# The kernel is ahead at every power of two through 256, so it has no bound
+# of its own: at 8 tokens it fetches 40 experts where streaming reads 128
+# (6.2 us an expert, 93 % of the HBM roofline, beside 0.05 ms a call that
+# does not depend on the picks; a kernel that only touches its blocks runs
+# as long: it is bound by its DMA); from 64 tokens the picks cover the
+# experts and what is left is XLA's three batched products against one
+# fused pass. Streaming and the loop cross near 460 tokens and a call's
+# token count is a power of two (slots x 1, or joiners x prompt bucket):
+# 256 is the last the every-token form wins, 512 the first the loop wins,
+# by 1.6 x at 1024 and 2.8 x at 2048, where streaming runs 21 times the
+# arithmetic a top-6 of 128 needs; the loop pays about 25 us for each block
+# it visits; streaming in chunks of 256 tokens to bound its float32
 # intermediates (0.6 GB at 1024, 1.2 GB at 2048) costs 2-12 % more than
-# streaming whole and wins nowhere. A grouped kernel that reads each needed
-# expert once at the weight roofline would beat both at decode (PERF.md
-# section 7).
+# streaming whole and wins nowhere.
 STREAM_ROWS = 256
 
 
 class RoutedMLP(nn.Module):
     """The dropless routed FFN of a served model (``ffn_kind="routed"``):
     float32 router (``route_dropless``), ``moe_experts`` SwiGLU experts of
-    width ``moe_intermediate`` (``streamed_experts`` up to ``STREAM_ROWS``
-    tokens a call, ``grouped_experts`` above: one sum, two costs), and
-    ``n_shared_experts`` always-on experts as ONE :class:`QDense` SwiGLU of
-    their summed width. No capacity, no dropped token, and a token's result
-    does not depend on what it is batched with.
+    width ``moe_intermediate`` (one sum, three forms: up to ``STREAM_ROWS``
+    tokens a call the kernel ``picked_experts`` where Mosaic compiles and
+    ``streamed_experts`` elsewhere, ``grouped_experts`` above; ``init``,
+    which returns parameters only, never asks for the backend: it is traced
+    by processes that must not take the chip), and ``n_shared_experts``
+    always-on experts as ONE :class:`QDense` SwiGLU of their summed width.
+    No capacity, no dropped token, and a token's result does not depend on
+    what it is batched with. On a TPU the expert widths must tile by 128
+    lanes (``picked_experts`` raises otherwise: it is never served by
+    another form under its name).
 
     ``quant="int8"``: the expert stacks are int8 ``[E, in, out]`` with
     float32 scales ``[E, 1, out]`` applied to the dot's float32 result, as
     :class:`QDense` does below its weight-bound row count (models/llama.py
     ``quantize_params`` writes this layout). The router and its bias stay
     float32. Sows ``moe_stats/load``: this call's assignments per row and
-    expert, int32 [b, E] (the engine's segment programs sum it over layers
-    and steps for ``handler.moe``; nobody else asks for the collection)."""
+    expert, int32 [b, E], and ``moe_reads/experts``: the distinct experts
+    its valid rows picked, one int32: ``picked_experts``'s own count of
+    what it fetched where it ran, the same number from ``load`` elsewhere
+    (the engine's segment programs sum both over layers and steps for
+    ``handler.moe``; nobody else asks for the collections)."""
 
     cfg: Any  # LlamaConfig
 
@@ -375,30 +393,21 @@ class RoutedMLP(nn.Module):
                                           ("experts_up", (e, hidden, m)),
                                           ("experts_down", (e, m, hidden)))]
 
-            def product(rows, stack, i):
-                w, scale = stack
-                out = jnp.matmul(
-                    rows.astype(cfg.dtype),
-                    jax.lax.dynamic_index_in_dim(w, i, 0, False).astype(
-                        cfg.dtype),
-                    preferred_element_type=jnp.float32)
-                if scale is not None:
-                    out = out * jax.lax.dynamic_index_in_dim(scale, i, 0,
-                                                             False)
-                return out
-
-            def expert(i, rows):
-                act = nn.silu(product(rows, stacks[0], i)) \
-                    * product(rows, stacks[1], i)
-                return product(act, stacks[2], i)
-
-            if b * s <= STREAM_ROWS:
+            # the distinct experts the valid rows picked; the kernel reports
+            # its own count of what it fetched
+            read = jnp.sum(jnp.any(load > 0, axis=0).astype(jnp.int32))
+            if b * s > STREAM_ROWS:
+                out = grouped_experts(tokens, experts, weights, valid,
+                                      expert_by_index(stacks, cfg.dtype), e)
+            elif not self.is_initializing() and kernels_compile_here():
+                out, read = picked_experts(tokens, experts, weights, valid,
+                                           stacks, cfg.dtype)
+            else:
                 out = streamed_experts(tokens, experts, weights, valid,
                                        stacks, cfg.dtype)
-            else:
-                out = grouped_experts(tokens, experts, weights, valid,
-                                      expert, e)
             out = out.astype(cfg.dtype).reshape(b, s, hidden)
+            if not self.is_initializing():
+                self.sow("moe_reads", "experts", read)
 
         if cfg.n_shared_experts:
             with jax.named_scope("shared_expert"):

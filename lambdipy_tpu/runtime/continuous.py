@@ -223,9 +223,13 @@ class ContinuousBatcher:
         self.window_bucketing = bool(window_bucketing)
         self.window_stats = DecodeWindowStats()
         # a routed-FFN model's segment programs return each row's expert
-        # load beside the tokens (llama._scan_decode count_load); the
-        # collector books it here (/metrics handler.moe)
+        # load and the distinct experts its layer-steps picked beside the
+        # tokens (llama._scan_decode count_load); the collector books them
+        # here (/metrics handler.moe)
         self.moe_stats = MoeLoadStats()
+        self._routed_layers = (cfg.layers - cfg.first_dense_layers
+                               if getattr(cfg, "counts_moe_load", False)
+                               else 0)
         # segments kept in flight on the device before the host fetches
         # the oldest: 1 = the fully synchronous loop (dispatch, fetch,
         # book, repeat — the device idles through every fetch RTT +
@@ -1435,17 +1439,16 @@ class ContinuousBatcher:
                     want.append(rec["lps"])
                 if kb_rec:
                     want += [rec["counts"], rec["pending"]]
-                if rec["moe_load"] is not None:
-                    want.append(rec["moe_load"])
+                want += rec["moe"]
                 got = [np.asarray(x)
                        for x in jax.device_get(tuple(want))]
                 blk = got.pop(0)
                 lp = got.pop(0) if rec["need_lp"] else None
                 cnt = got.pop(0) if kb_rec else None
                 pend = got.pop(0) if kb_rec else None
-                return blk, lp, cnt, pend, (got.pop(0) if got else None)
+                return blk, lp, cnt, pend, got
 
-            block, lp_block, counts_h, pending_h, load_h = \
+            block, lp_block, counts_h, pending_h, moe_h = \
                 self._device_wait("segment_fetch", gen, fetch)
             t_end = time.monotonic()
             phase.enter("eng.book", rids=served)
@@ -1471,9 +1474,13 @@ class ContinuousBatcher:
                 self.segments_run += 1
                 if self.mesh_stats is not None:
                     self.mesh_stats.record_segment()
-                if load_h is not None:
-                    self.moe_stats.record_rows(load_h[
-                        [slot for slot, e in rec["rows"] if not e["done"]]])
+                if moe_h:
+                    load_h, read_h = moe_h
+                    self.moe_stats.record_segment(
+                        load_h[[slot for slot, e in rec["rows"]
+                                if not e["done"]]],
+                        experts_read=int(read_h),
+                        layer_steps=block.shape[1] * self._routed_layers)
                 for slot, entry in rec["rows"]:
                     # per-row accepted width: everything for a plain
                     # segment; counts_h[slot] (1..kb) for a verify step
@@ -2030,14 +2037,14 @@ class ContinuousBatcher:
 
                     outs, self._carry = self._device_wait(
                         "segment_dispatch", gen, dispatch)
-                    moe_load = None
+                    moe = []
                     if kb:
                         toks, lps, counts_op, pending_op = outs
                     else:
                         # a routed-FFN model's plain segments also return
-                        # the rows' expert load
-                        toks, lps, *more = outs
-                        moe_load = more[0] if more else None
+                        # the rows' expert load and the distinct experts
+                        # its layer-steps picked
+                        toks, lps, *moe = outs
                     # attended = per-row sum of positions each step's
                     # attention actually covered (pos + 1 keys at write
                     # index pos); a verify chunk computes all kb
@@ -2045,7 +2052,7 @@ class ContinuousBatcher:
                     # honest width either way
                     rec = {
                         "toks": toks, "lps": lps, "need_lp": need_lp,
-                        "moe_load": moe_load,
+                        "moe": moe,
                         "rows": live, "window": window,
                         "t_dispatch": t_disp,
                         "attended": sum(adv * p + adv * (adv + 1) // 2

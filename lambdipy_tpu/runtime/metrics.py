@@ -121,18 +121,30 @@ class MoeLoadStats:
     doubled assignment shows as a difference. ``load``: the same count per
     expert. The engine's collector adds each booked row's vector from the
     segment's own fetch; rows the device stepped for nobody (empty slots,
-    over-decode) are left out."""
+    over-decode) are left out. ``experts_read`` / ``layer_steps``: the mean
+    number of DISTINCT experts one routed layer's call picked in one decode
+    step, as sum and count: what a form that fetches only the picked experts
+    reads (``ops/grouped_experts.py picked_experts``; one that streams reads
+    them all, whatever this says). Counted by the program over ALL the
+    slots' rows, because a cache step routes every slot, live or empty;
+    ``layer_steps`` is fetched segments x segment steps x routed layers."""
 
     assignments: int = 0
     load: list = field(default_factory=list)   # per expert
+    experts_read: int = 0
+    layer_steps: int = 0
     _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
 
-    def record_rows(self, rows) -> None:
-        """``rows``: int array [booked rows, experts]."""
-        if not len(rows):
-            return
+    def record_segment(self, rows, *, experts_read: int,
+                       layer_steps: int) -> None:
+        """One fetched segment. ``rows``: int array [booked rows,
+        experts]."""
         total = rows.sum(axis=0)
         with self._lock:
+            self.experts_read += experts_read
+            self.layer_steps += layer_steps
+            if not len(rows):
+                return
             if not self.load:
                 self.load = [0] * len(total)
             self.load = [a + int(b) for a, b in zip(self.load, total)]
@@ -140,7 +152,9 @@ class MoeLoadStats:
 
     def report(self) -> dict:
         with self._lock:
-            return {"assignments": self.assignments, "load": list(self.load)}
+            return {"assignments": self.assignments, "load": list(self.load),
+                    "experts_read": self.experts_read,
+                    "layer_steps": self.layer_steps}
 
 
 @dataclass

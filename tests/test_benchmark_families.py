@@ -212,3 +212,30 @@ def test_the_toy_twin_runs_the_whole_command_and_counts_its_experts(
             {"family": families.load("llama-hf"), "trace": {"busy_s": 1},
              "slice": {"live": [(8, 100.0)]}, "device": {"kind": "TPU v5 lite"},
              "config": {}}) is None
+
+
+def test_the_experts_read_reader_takes_the_windows_delta_or_nothing():
+    """``moe_experts_read`` (PR 28): distinct experts a routed layer's call
+    picked in a decode step, from the window's two scrapes; None where the
+    program counts no such thing (a llama cell; the parent of PR 28, whose
+    ``handler.moe`` has ``assignments`` and ``load`` only)."""
+    from benchmark import harness
+
+    reader = harness.layer_metric("moe_experts_read")
+
+    def moe(**block):
+        return {"handler": {"moe": block}}
+
+    assert reader.read({
+        "m_open": moe(experts_read=4100, layer_steps=100, assignments=1),
+        "m_close": moe(experts_read=4100 + 192 * 41, layer_steps=292,
+                       assignments=2)}) == pytest.approx(41.0)
+    idle = moe(experts_read=5, layer_steps=1)
+    assert reader.read({"m_open": idle, "m_close": idle}) is None
+    parent = moe(assignments=7, load=[3, 4])
+    assert reader.read({"m_open": parent, "m_close": parent}) is None
+    assert reader.read({"m_open": {"handler": {}},
+                        "m_close": {"handler": {}}}) is None
+    entry = next(m for m in json.loads((REPO / "BENCHMARK.json").read_text())[
+        "per_layer"] if m["name"] == "moe_experts_read")
+    assert entry["workloads"] == ["kanana2-30b.decode-saturated"]

@@ -118,6 +118,33 @@ def test_int8_matmul_compiles(v5e, m):
                  ((HIDDEN, MLP), jnp.int8), ((1, MLP), jnp.float32))
 
 
+# kanana2-30b's routed FFN (benchmark/configs/kanana2-30b.json)
+EXPERTS, TOP_K, EXPERT_HIDDEN, EXPERT_MLP = 128, 6, 2048, 768
+
+
+@pytest.mark.parametrize("tokens", [8, 32, 256])
+def test_picked_experts_compiles(v5e, tokens):
+    """A decode step's 8 rows, and calls up to ``STREAM_ROWS``, at the
+    cell's widths: an expert's three int8 blocks are 4.7 MB, twice for the
+    pipeline, and the kernel asks for the fast memory it needs."""
+    from lambdipy_tpu.ops.grouped_experts import picked_experts
+
+    def fn(x, chosen, w, *stacks):
+        return picked_experts(x, chosen, w, None,
+                              list(zip(stacks[::2], stacks[1::2])),
+                              jnp.bfloat16)
+
+    def stack(fan_in, fan_out):
+        return [((EXPERTS, fan_in, fan_out), jnp.int8),
+                ((EXPERTS, 1, fan_out), jnp.float32)]
+
+    _compile_for(v5e, fn, ((tokens, EXPERT_HIDDEN), jnp.float32),
+                 ((tokens, TOP_K), jnp.int32), ((tokens, TOP_K), jnp.float32),
+                 *stack(EXPERT_HIDDEN, EXPERT_MLP),
+                 *stack(EXPERT_HIDDEN, EXPERT_MLP),
+                 *stack(EXPERT_MLP, EXPERT_HIDDEN))
+
+
 def _decode_segment_text(chip, *, steps, window=512, cache_len=T, layers=1,
                          **widths):
     """Optimized HLO of the decode segment (`jit_seg`, the window-bucketed
@@ -173,6 +200,39 @@ def test_scope_names_survive_the_tpu_compiler(v5e):
         found.update(op_name.split("/"))
     assert {"embed", "qkv_proj", "kv_write", "attend", "o_proj", "mlp",
             "lm_head", "sample", "kv_window"} <= found
+
+
+KANANA2 = dict(vocab_size=128256, hidden=EXPERT_HIDDEN, heads=32, kv_heads=32,
+               mlp=6144, rope_theta=1e6, norm_eps=1e-6, max_len=8192,
+               attn_kind="latent", qk_nope=128, qk_rope=64, v_head=128,
+               kv_lora_rank=512, rope_interleave=True, ffn_kind="routed",
+               first_dense_layers=1, moe_experts=EXPERTS, moe_top_k=TOP_K,
+               moe_intermediate=EXPERT_MLP, n_shared_experts=2,
+               routed_scaling_factor=2.448, scoring_func="sigmoid")
+
+
+def test_a_routed_decode_step_takes_the_kernel_under_its_scope(v5e,
+                                                               monkeypatch):
+    """The decode segment at kanana2-30b's widths, one dense and one routed
+    layer, lowered as a TPU backend lowers it (this process's backend is
+    the CPU, so the test says what ``kernels_compile_here`` would): the
+    routed sum of 8 rows is ONE Mosaic call whose op_name lies under
+    ``experts`` (what ``decode_moe_ms`` and ``moe_hbm_pct`` gather a
+    trace's operations by), beside the operations of ``router`` and
+    ``shared_expert``. ~30 s."""
+    from lambdipy_tpu.models import moe
+
+    assert B <= moe.STREAM_ROWS
+    monkeypatch.setattr(moe, "kernels_compile_here", lambda: True)
+    text = _decode_segment_text(v5e, steps=2, layers=2, **KANANA2)
+    calls = re.findall(r' custom-call\([^\n]*custom_call_target='
+                       r'"tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
+    assert len(calls) == 1, calls
+    assert "experts" in calls[0].split("/")
+    found = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        found.update(op_name.split("/"))
+    assert {"router", "experts", "shared_expert", "mla_absorb"} <= found
 
 
 # HLO opcodes that name or view an array and write none

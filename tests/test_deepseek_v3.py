@@ -185,8 +185,9 @@ def test_group_prefill_then_48_steps_through_the_latent_cache(server):
 def test_the_continuous_engine_with_ragged_joiners(server):
     """Requests join a running decode at segment boundaries (group prefill
     of the joiners, pack into the B-slot latent cache, window-bucketed
-    segments): every served token is the reference's choice, and the engine
-    booked one assignment per row-step, routed layer and pick: dropless."""
+    segments): every served token is the reference's choice, the engine
+    booked one assignment per row-step, routed layer and pick: dropless, and
+    the distinct experts each layer-step picked beside them."""
     eng = ContinuousBatcher(server, slots=4, segment=8)
     rows = prompts(7, seed=5)
     want = [24, 48, 16, 40, 48, 8, 32]
@@ -209,9 +210,21 @@ def test_the_continuous_engine_with_ragged_joiners(server):
         * ROUTED_LAYERS * TOP_K
     assert len(load["load"]) == CONFIG["n_routed_experts"]
     assert sum(load["load"]) == load["assignments"]
+    # the distinct experts a routed layer's call picked in one step, as sum
+    # and count: every fetched segment counts its steps and routed layers,
+    # and a call of 4 slots x 3 picks names between 3 and 12 of 16 experts
+    assert load["layer_steps"] == stats["segments_run"] * stats["segment"] \
+        * ROUTED_LAYERS
+    assert TOP_K <= load["experts_read"] / load["layer_steps"] \
+        <= min(CONFIG["n_routed_experts"], 4 * TOP_K)
     again = eng.moe_stats.report()
     eng.generate(rows[0], max_new_tokens=8)
-    assert eng.moe_stats.report()["assignments"] > again["assignments"]
+    after = eng.moe_stats.report()
+    assert after["assignments"] > again["assignments"]
+    grown = after["layer_steps"] - again["layer_steps"]
+    assert grown > 0 and grown % (8 * ROUTED_LAYERS) == 0
+    assert TOP_K * grown <= after["experts_read"] - again["experts_read"] \
+        <= 4 * TOP_K * grown
 
 
 # -- dropless routing ----------------------------------------------------------

@@ -17,7 +17,13 @@ says it at the accepted cells' OWN shape keys (the configuration files'
 widths, 8 slots, their engine window, int8 kernels, 16-step segments), on
 abstract parameters (``jax.eval_shape``: nothing of 7 B is allocated), so
 that a change which only shows at those widths (a row-count threshold, a
-window bucket) is caught here too. Same parent, same rule."""
+window bucket) is caught here too. Same parent, same rule.
+
+A routed-FFN model's programs have no golden text: PR 28 gave its segment
+programs a second counter, and on a TPU backend their small calls take a
+Pallas kernel (``ops/grouped_experts.py``), which changes their cache keys
+on its own. What is held here is the other side of that dispatch: on this
+backend the toy twin's programs contain no kernel call at any call size."""
 
 import hashlib
 import json
@@ -107,3 +113,31 @@ def test_an_accepted_cells_programs_keep_their_text_at_the_cells_shapes(name):
            text_hash(server._windowed_seg_fn(slots, cache_len, window, 16),
                      params, *seg_ops))
     assert got == golden
+
+
+def test_a_routed_models_programs_hold_no_kernel_call_on_this_backend():
+    """``RoutedMLP`` takes ``picked_experts`` for calls of at most
+    ``STREAM_ROWS`` tokens where Mosaic compiles; here (the CPU) the decode
+    segment of 4 slots x 1 token and the group prefill of 4 x 32 tokens of
+    the toy twin lower without a Pallas call: the pure-jax forms, as on the
+    parent. (The kernel's side of the dispatch, under the ``experts`` scope:
+    ``tests/test_chip_compile.py``; its bound: ``tests/test_grouped_experts
+    .py``.)"""
+    from lambdipy_tpu import ops
+
+    assert not ops.kernels_compile_here()
+    config = json.loads((Path(__file__).parents[1] / "benchmark" / "configs"
+                         / "rehearsal-mla-moe.json").read_text())
+    adapter = registry.get("deepseek-v3").build(
+        dtype="float32", quant="int8",
+        extra=families.of(config).dims_of(config))
+    params = jax.eval_shape(lambda: adapter.init_params(seed=0))
+    server = adapter.make_server(params)
+    key = ("stream", 4, 32, 128, 16)
+    prefill, seg = server._stream_fns(*key[1:])
+    pre_ops, seg_ops = jax.eval_shape(lambda: server._aot_examples(key))
+    for fn, operands in ((prefill, pre_ops), (seg, seg_ops),
+                         (server._windowed_seg_fn(4, 128, 64, 16), seg_ops)):
+        text = fn.lower(params, *operands).as_text()
+        assert "tensor<16x64x24xi8>" in text    # an expert stack: the sum is here
+        assert "custom_call" not in text and "pallas" not in text
