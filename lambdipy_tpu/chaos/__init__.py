@@ -1,6 +1,6 @@
 """Composed-fault chaos soak over the live CPU fleet.
 
-Every earlier chaos bench armed ONE fault site in a hand-curated
+Every per-feature fault test arms ONE fault site in a hand-curated
 scenario; the bugs that survived those gates lived in *cross-feature
 interactions* under *overlapping* faults (the PR-8/13/14 post-review
 hardening lists). This package is the Jepsen-style answer:
@@ -18,8 +18,8 @@ hardening lists). This package is the Jepsen-style answer:
   bitwise vs the reference or is an explicit, priced, counted failure;
   no waiter outlives its bound; and at quiesce all accounting converges
   (pagepool conservation, pins -> 0, spill depth -> 0);
-- :mod:`soak` — the orchestrator behind ``bench.py --soak``
-  (run_tier1 phase 14) and the ``--replay-timeline`` workflow.
+- :mod:`soak` — the orchestrator and its entry point (``python -m
+  lambdipy_tpu.chaos.soak``), with the ``--replay-timeline`` workflow.
 """
 
 from lambdipy_tpu.chaos.checker import check_history, check_quiesce

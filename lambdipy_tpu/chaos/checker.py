@@ -1,7 +1,7 @@
 """History checker: the chaos soak's single global oracle.
 
 Per-request contract (:func:`check_history`) — the zero-silent-loss
-bar every earlier chaos bench enforced per-feature, now fleet-wide
+bar every per-feature fault test enforces, now fleet-wide
 under composed faults:
 
 ==================  ========================================================
@@ -25,8 +25,8 @@ plus the WAITER BOUND (no request outlives ``waiter_bound_s``) and the
 accounting identity ``delivered + explicit == planned`` (a vanished
 request is a loss even if nobody saw an error). The deliberately
 breakable leg: ``suppress_sheds=True`` drops sheds from the explicit
-tally — the canary ``bench.py --soak`` uses to prove the oracle can
-actually reject a history.
+tally — the canary the soak uses to prove the oracle can actually
+reject a history (``tests/test_chaos.py`` holds it too).
 
 Quiesce contract (:func:`check_quiesce`), probed AFTER faults clear,
 sessions close, and leases lapse: every replica's

@@ -9,7 +9,7 @@ targets (replica names, or the in-process ``router``). Timelines are
   ``random.Random(seed)`` over a menu built from the
   ``runtime/faults.py`` site REGISTRY, so the same seed yields a
   byte-identical schedule run after run — the reproducibility spine of
-  ``bench.py --soak --seed N``;
+  ``python -m lambdipy_tpu.chaos.soak --seed N``;
 - **serializable**: one line per event (``@T action target [spec]``),
   round-tripped by :func:`render_timeline`/:func:`parse_timeline`, so a
   failing run's exact schedule replays from a file

@@ -1,4 +1,4 @@
-"""Composed-fault soak orchestrator (``bench.py --soak``).
+"""Composed-fault soak orchestrator (``python -m lambdipy_tpu.chaos.soak``).
 
 One soak window = one seed: a 2-replica MANAGED fleet (supervised
 subprocess bundle servers behind the resilient sticky-session router —
@@ -12,8 +12,8 @@ live accounting sweep.
 
 Replayability: a failing run writes its exact event timeline next to
 the verdict and names the one-command replay
-(``bench.py --soak --seed N --replay-timeline FILE``) — same seed, same
-workload, same schedule, same oracle.
+(``python -m lambdipy_tpu.chaos.soak --seed N --replay-timeline FILE``) —
+same seed, same workload, same schedule, same oracle.
 
 The fleet boots ONCE and serves every seed window: radix caches warm
 across windows (expected outputs never change — greedy or seeded
@@ -412,7 +412,7 @@ def run_window(fleet: SoakFleet, *, seed: int, duration_s: float,
         fleet.close_sessions([expiry_sid])
 
     # the fleet must serve BITWISE after the storm (the recovery bar
-    # every per-feature chaos bench set, now after composed faults)
+    # every per-feature fault test sets, now after composed faults)
     probe_row = [3, 1, 4, 1, 5, 9, 2, 6]
     post_expected = fleet.ref_completion(probe_row, {}, fleet.n_new)
     post_detail: str | None = None
@@ -514,7 +514,7 @@ def soak_record(*, seeds=(11, 23), duration_s: float = 22.0,
                 replay_timeline: str | None = None,
                 determinism: bool = True,
                 autoscale: bool = False) -> dict:
-    """The ``bench.py --soak`` entry point. CI mode (defaults): run the
+    """What :func:`main` runs. Default mode: run the
     fixed seed set, then re-run the FIRST seed and assert a
     byte-identical timeline with an identical verdict (schedule
     determinism on a live fleet, not just in the generator). Replay
@@ -578,7 +578,7 @@ def soak_record(*, seeds=(11, 23), duration_s: float = 22.0,
 
 
 def _gate(fleet: SoakFleet, rec: dict) -> None:
-    """Fail the bench on a bad window, leaving the replay artifact: the
+    """Fail the soak on a bad window, leaving the replay artifact: the
     seed + the exact event timeline, replayable in one command."""
     path = fleet.tmp / f"seed-{rec['seed']}.timeline"
     path.write_text(rec["timeline"] + "\n")
@@ -586,5 +586,48 @@ def _gate(fleet: SoakFleet, rec: dict) -> None:
     if not rec["ok"]:
         raise AssertionError(
             f"soak seed {rec['seed']} FAILED its oracle: "
-            f"{rec['violations'][:4]} — replay with: python bench.py "
-            f"--soak --seed {rec['seed']} --replay-timeline {path}")
+            f"{rec['violations'][:4]} — replay with: python -m "
+            f"lambdipy_tpu.chaos.soak --seed {rec['seed']} "
+            f"--replay-timeline {path}")
+
+
+def main(argv: list[str] | None = None) -> int:
+    import argparse
+
+    ap = argparse.ArgumentParser(
+        prog="python -m lambdipy_tpu.chaos.soak",
+        description="composed-fault soak of a live 2-replica CPU fleet")
+    ap.add_argument("--seed", type=int, action="append", default=None,
+                    help="soak seed (repeatable); default: the fixed "
+                         "set (11, 23) plus a determinism re-run")
+    ap.add_argument("--soak-seconds", type=float, default=None,
+                    help="window length per seed (default 22 s; longer "
+                         "randomized runs use this with --seed)")
+    ap.add_argument("--replay-timeline", type=str, default=None,
+                    help="timeline file from a failing run: replay its "
+                         "exact schedule under --seed's workload")
+    ap.add_argument("--no-determinism", action="store_true")
+    ap.add_argument("--autoscale", action="store_true",
+                    help="run the live FleetController over the soak "
+                         "fleet: its resizes join the nemesis timeline "
+                         "and the zero-loss bar must hold through them")
+    args = ap.parse_args(argv)
+    from lambdipy_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    replay = (Path(args.replay_timeline).read_text()
+              if args.replay_timeline else None)
+    kwargs = {"duration_s": args.soak_seconds} if args.soak_seconds else {}
+    # the determinism re-run is the default; explicit seeds/replays are
+    # operator iteration loops and skip it
+    determinism = (not args.no_determinism and args.seed is None
+                   and replay is None)
+    print(json.dumps(soak_record(seeds=tuple(args.seed or (11, 23)),
+                                 replay_timeline=replay,
+                                 determinism=determinism,
+                                 autoscale=args.autoscale, **kwargs)))
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

@@ -30,8 +30,8 @@ retire                                ``pool.retire`` — drain + stop one
 
 The controller never invents state: hysteresis, cooldowns, and the
 live-floor guard all live in the pure policy, so a recorded snapshot
-sequence replays to a byte-identical decision trace (the bench's
-determinism gate). In ``dry_run`` mode decisions are fully traced and
+sequence replays to a byte-identical decision trace
+(``tests/test_fleet_controller.py``). In ``dry_run`` mode decisions are fully traced and
 counted as INTENTS but no actuator fires — the recommended first step
 before trusting the loop in a new deployment.
 
@@ -80,8 +80,8 @@ class FleetController:
         # nemesis-visible ledger of APPLIED actions, in the soak event
         # grammar: {"t", "action", "target", "event"}
         self.events: list[dict] = []
-        # (snapshot, [rendered actions]) pairs — the bench's
-        # determinism gate replays decide() over these with a fresh
+        # (snapshot, [rendered actions]) pairs — replay_decisions()
+        # replays decide() over these with a fresh
         # PolicyState and diffs the rendered actions byte-for-byte
         self.decision_log: list[tuple[Snapshot, list[str]]] = []
         self._t0 = time.monotonic()
@@ -181,7 +181,7 @@ class FleetController:
 
     def tick(self) -> list[Action]:
         """Scrape -> decide -> act (or log intents). Safe to call
-        directly (the bench and tests do); the background thread just
+        directly (the tests do); the background thread just
         calls it on a timer."""
         self.stats.count("ticks")
         try:

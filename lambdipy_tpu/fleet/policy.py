@@ -5,8 +5,8 @@ signals every tick and asks :func:`decide` what to do about them. This
 module is deliberately free of I/O, clocks, and randomness: a decision
 is a pure function of (:class:`Snapshot`, :class:`PolicyState`,
 :class:`PolicyConfig`) — the same inputs always produce the same
-actions, which is what makes the bench's byte-identical decision-trace
-re-run possible and keeps every rule unit-testable as a table of
+actions, which is what makes a byte-identical decision-trace re-run
+possible and keeps every rule unit-testable as a table of
 snapshots.
 
 Signals -> actuators (ROADMAP direction 2):

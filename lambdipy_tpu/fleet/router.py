@@ -154,7 +154,7 @@ class FleetRouter:
                  breaker_outlier_ms: float = 0.0,
                  retry_budget: float = 0.0, retry_budget_min: int = 3,
                  warm_prefixes: int = 4,
-                 ship_window: int = 4, ship_pipelined: bool = True,
+                 ship_window: int = 4,
                  session_record_ttl_s: float = 3600.0,
                  faults: FaultPlan | None = None):
         self.pool = pool
@@ -206,11 +206,8 @@ class FleetRouter:
         # pipelined (chunked) shipping: ship_window bounds the relay's
         # in-flight chunk frames between the export and import legs
         # (0 = the pre-chunking monolithic ship, one LKV1 frame per
-        # round trip); ship_pipelined=False keeps the chunked wire but
-        # buffers the whole export before relaying — the blocking
-        # baseline bench.py --disagg-rtt measures the overlap against
+        # round trip)
         self.ship_window = max(0, int(ship_window))
-        self.ship_pipelined = bool(ship_pipelined)
         # per-class busy-fraction EWMAs (fleet.disagg.util), folded
         # from the pool's time-weighted occupancy at scrape time
         self._util_lock = threading.Lock()
@@ -845,10 +842,9 @@ class FleetRouter:
         response as the prefill produces them and a bounded queue
         (``ship_window`` frames) feeds the import leg's chunked POST —
         so wire transfer and the decode side's staging both overlap the
-        prefill chunks still running on ``src``. ``ship_pipelined=False``
-        keeps the chunked wire but buffers the full export first (the
-        blocking baseline); an ``LKV1`` response (a pre-chunking
-        replica, or ``ship_window=0``) relays as one monolithic frame.
+        prefill chunks still running on ``src``. An ``LKV1`` response (a
+        pre-chunking replica, or ``ship_window=0``) relays as one
+        monolithic frame.
 
         Returns ``(fallback_reason | None, info)``. Reasons distinguish
         unreachable legs (``export_unreachable``/``import_unreachable``
@@ -954,19 +950,14 @@ class FleetRouter:
         pages roll back and its tree (and the ship-dedup LRU above it)
         is never told about a half-arrived head."""
         split = FrameSplitter()
-        # the window only applies when a reader thread feeds a writer
-        # concurrently; the buffered baseline reads inline with nobody
-        # consuming yet, so its queue must be unbounded or it deadlocks
-        frames_q: Queue = Queue(
-            maxsize=max(1, self.ship_window) if self.ship_pipelined
-            else 0)
+        frames_q: Queue = Queue(maxsize=max(1, self.ship_window))
         rd_err: list = []
         # set when the writer gives up: a reader parked on a full
         # window must unblock NOW, not after the request timeout — a
         # dead import leg would otherwise pin one thread plus a
         # window's worth of KV frames per failed ship for minutes
         abort = threading.Event()
-        info["pipelined"] = self.ship_pipelined
+        info["pipelined"] = True
 
         def q_put(item) -> None:
             while True:
@@ -1000,32 +991,19 @@ class FleetRouter:
                 except Full:  # writer already gone; nothing drains
                     pass
 
-        if self.ship_pipelined:
-            threading.Thread(target=read_frames, daemon=True,
-                             name="kv-ship-relay").start()
+        threading.Thread(target=read_frames, daemon=True,
+                         name="kv-ship-relay").start()
 
-            def frame_iter():
-                while True:
-                    try:
-                        item = frames_q.get(timeout=max(
-                            0.1, deadline - time.monotonic()))
-                    except Empty:
-                        raise _ShipStalled(
-                            "export stream stalled") from None
-                    if item is None:
-                        return
-                    yield item
-        else:
-            # the blocking baseline: the whole export (prefill
-            # included) lands before the first import byte moves
-            read_frames()
-
-            def frame_iter():
-                while True:
-                    item = frames_q.get_nowait()
-                    if item is None:
-                        return
-                    yield item
+        def frame_iter():
+            while True:
+                try:
+                    item = frames_q.get(timeout=max(
+                        0.1, deadline - time.monotonic()))
+                except Empty:
+                    raise _ShipStalled("export stream stalled") from None
+                if item is None:
+                    return
+                yield item
 
         conn = None
         mid_stream = False
@@ -1902,10 +1880,9 @@ class FleetRouter:
         """Host-only fleet invariant sweep (GET /v1/debug/invariants):
         fans out to every replica's own sweep concurrently and folds the
         verdicts. ``ok`` covers the replicas that ANSWERED and are
-        routable — an ejected replica's accounting died with it (the
-        sessions bench's "died with its pins" rule); the router-side
-        gauges (spill depth, open sessions) ride along for the chaos
-        checker's quiesce assertions."""
+        routable — an ejected replica's accounting died with it; the
+        router-side gauges (spill depth, open sessions) ride along for
+        the chaos checker's quiesce assertions."""
         results: dict = {}
 
         def probe(name: str, url: str) -> None:

@@ -69,10 +69,10 @@ class LlamaConfig:
     # sequence-parallel ring attention for prefill AND sequence-sharded
     # flash-decoding for decode steps over the ambient mesh's sp axis
     # (parallel/ring.py + parallel/spdecode.py; the KV cache never
-    # gathers, per-step collectives are O(b*h*d)). Defaults measured,
-    # not assumed: docs/kernels.md — XLA dense wins at <=4k context on
-    # v5e; flash is the O(S)-memory fallback for contexts whose dense
-    # score tensor would not fit.
+    # gathers, per-step collectives are O(b*h*d)). Every cell of the
+    # benchmark runs "dense"; the others have never run on the attached
+    # v5e (docs/kernels.md); flash is the O(S)-memory fallback for
+    # contexts whose dense score tensor would not fit.
     attn_backend: str = "dense"
     # Sparse MoE FFN (Mixtral-style): >0 replaces the dense SwiGLU with
     # moe_experts top-k routed experts (models/moe.py), expert dim sharded
@@ -81,16 +81,6 @@ class LlamaConfig:
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
     moe_group_size: int = 256  # routing-group size (models/moe.py)
-    # int8 matmul backend: "xla" (QDense's dot on the converted int8
-    # kernel, scale applied to its float32 result; works under TP
-    # sharding) or "pallas" (ops/quant.py blocked kernel — single-chip
-    # serving; falls back per-matmul when shapes don't tile). Isolated
-    # 8B-shape matmuls once measured 390-710 GB/s of weight bandwidth
-    # for "xla" against ~65 for the kernel (docs/kernels.md), and no
-    # cell of the benchmark runs "pallas". In the served decode step
-    # the weight stream of "xla" runs at 95 % (Mistral-7B) and 85 %
-    # (DeepSeek-7B) of the HBM roofline since PR 25 (PERF.md section 5).
-    matmul_backend: str = "xla"
     # -- the per-layer description (ROADMAP D3 in the small): what the ONE
     # block, the cache constructors and the registry read, so that an
     # architecture is a setting of these fields and not a fork of this
@@ -250,7 +240,6 @@ class QDense(nn.Module):
     features: int
     quant: str | None = None
     dtype: Any = jnp.bfloat16
-    backend: str = "xla"  # "xla" | "pallas" (int8 only, unsharded)
 
     @nn.compact
     def __call__(self, x):
@@ -263,18 +252,6 @@ class QDense(nn.Module):
             scale = self.param(
                 "scale", nn.initializers.constant(1.0 / (127.0 * in_features ** 0.5)),
                 (1, self.features), jnp.float32)
-            if self.backend == "pallas":
-                from lambdipy_tpu.ops import kernels_compile_here
-                from lambdipy_tpu.ops.quant import int8_matmul
-                from lambdipy_tpu.parallel.mesh import current_mesh
-
-                # the blocked kernel is a manual (unpartitioned) op: only
-                # take it when no mesh is ambient (single-chip serving),
-                # and only where Mosaic compiles (a TPU backend)
-                if current_mesh() is None and kernels_compile_here():
-                    flat = x.astype(self.dtype).reshape(-1, in_features)
-                    out = int8_matmul(flat, w_i8, scale)
-                    return out.reshape(*x.shape[:-1], self.features)
             if math.prod(x.shape[:-1]) <= WEIGHT_BOUND_ROWS:
                 # the dot consumes the converted int8 kernel and nothing
                 # else (int8 -> dtype is exact), accumulates in float32,
@@ -591,8 +568,7 @@ class LlamaBlock(nn.Module):
 
         with jax.named_scope("o_proj"):
             out = out.reshape(b, s, -1)
-            x = x + QDense(cfg.hidden, cfg.quant, cfg.dtype,
-                           cfg.matmul_backend, name="o_proj")(out)
+            x = x + QDense(cfg.hidden, cfg.quant, cfg.dtype, name="o_proj")(out)
 
         with jax.named_scope("mlp"):
             if spec.ffn == "routed":
@@ -616,12 +592,9 @@ class LlamaBlock(nn.Module):
                                cfg.moe_capacity_factor, cfg.dtype, cfg.quant,
                                group_size=cfg.moe_group_size, name="moe")(h)
             else:
-                gate = QDense(cfg.mlp, cfg.quant, cfg.dtype,
-                              cfg.matmul_backend, name="gate_proj")(h)
-                up = QDense(cfg.mlp, cfg.quant, cfg.dtype,
-                            cfg.matmul_backend, name="up_proj")(h)
-                x = x + QDense(cfg.hidden, cfg.quant, cfg.dtype,
-                               cfg.matmul_backend, name="down_proj")(
+                gate = QDense(cfg.mlp, cfg.quant, cfg.dtype, name="gate_proj")(h)
+                up = QDense(cfg.mlp, cfg.quant, cfg.dtype, name="up_proj")(h)
+                x = x + QDense(cfg.hidden, cfg.quant, cfg.dtype, name="down_proj")(
                     nn.silu(gate) * up)
         return x, new_cache
 
@@ -643,10 +616,8 @@ class LlamaBlock(nn.Module):
         b, s, _ = x.shape
         with jax.named_scope("qkv_proj"):
             h = RMSNorm(cfg.norm_eps, name="attn_norm")(x)
-            q = QDense(heads * (dn + dr), cfg.quant, cfg.dtype,
-                       cfg.matmul_backend, name="q_proj")(h)
-            kva = QDense(rank + dr, cfg.quant, cfg.dtype,
-                         cfg.matmul_backend, name="kv_a_proj")(h)
+            q = QDense(heads * (dn + dr), cfg.quant, cfg.dtype, name="q_proj")(h)
+            kva = QDense(rank + dr, cfg.quant, cfg.dtype, name="kv_a_proj")(h)
             ckv = RMSNorm(cfg.norm_eps, name="kv_a_norm")(kva[..., :rank])
             q = q.reshape(b, s, heads, dn + dr)
             q_nope, q_pe = q[..., :dn], q[..., dn:]
@@ -718,12 +689,9 @@ class LlamaBlock(nn.Module):
         b, s, _ = x.shape
         with jax.named_scope("qkv_proj"):
             h = RMSNorm(cfg.norm_eps, name="attn_norm")(x)
-            q = QDense(cfg.heads * d, cfg.quant, cfg.dtype,
-                       cfg.matmul_backend, name="q_proj")(h)
-            k = QDense(cfg.kv_heads * d, cfg.quant, cfg.dtype,
-                       cfg.matmul_backend, name="k_proj")(h)
-            v = QDense(cfg.kv_heads * d, cfg.quant, cfg.dtype,
-                       cfg.matmul_backend, name="v_proj")(h)
+            q = QDense(cfg.heads * d, cfg.quant, cfg.dtype, name="q_proj")(h)
+            k = QDense(cfg.kv_heads * d, cfg.quant, cfg.dtype, name="k_proj")(h)
+            v = QDense(cfg.kv_heads * d, cfg.quant, cfg.dtype, name="v_proj")(h)
             q = q.reshape(b, s, cfg.heads, d)
             k = k.reshape(b, s, cfg.kv_heads, d)
             v = v.reshape(b, s, cfg.kv_heads, d)
@@ -892,8 +860,7 @@ class LlamaModel(nn.Module):
                 x = jnp.take_along_axis(
                     x, jnp.broadcast_to(logit_positions[:, None, None],
                                         (b, 1, x.shape[-1])), axis=1)
-            logits = QDense(cfg.vocab_size, cfg.quant, jnp.float32,
-                            cfg.matmul_backend, name="lm_head")(x)
+            logits = QDense(cfg.vocab_size, cfg.quant, jnp.float32, name="lm_head")(x)
         return logits, new_cache
 
 
@@ -1272,7 +1239,7 @@ def pipeline_forward(model: LlamaModel, params, tokens, mesh, *,
         stage_fn, stacked, split_microbatches(x, num_microbatches), mesh,
         const=const))
     x = RMSNorm(cfg.norm_eps).apply({"params": p["final_norm"]}, x)
-    return QDense(cfg.vocab_size, cfg.quant, jnp.float32, cfg.matmul_backend).apply(
+    return QDense(cfg.vocab_size, cfg.quant, jnp.float32).apply(
         {"params": p["lm_head"]}, x)
 
 
@@ -1608,7 +1575,7 @@ def _spec_chain_verify(select, lg, draft, lp_in, keys):
     that MATCHES it. Emitted tokens are therefore bitwise the
     non-speculative engine's for greedy AND seeded-sampled rows alike
     (speculation changes how many tokens each weight read verifies,
-    never which tokens) — the property ``bench.py --spec`` gates on.
+    never which tokens) — what ``tests/test_spec_engine.py`` holds.
     Relative to :func:`_spec_accept_resample`'s rejection sampling (the
     solo sampled path's distributional contract) the accept test is
     stricter — token equality instead of probability mass — costing

@@ -412,7 +412,7 @@ class RoutedMLP(nn.Module):
         if cfg.n_shared_experts:
             with jax.named_scope("shared_expert"):
                 width = cfg.n_shared_experts * m
-                dense = [QDense(n, cfg.quant, cfg.dtype, cfg.matmul_backend,
+                dense = [QDense(n, cfg.quant, cfg.dtype,
                                 name=f"shared_{name}_proj")
                          for name, n in (("gate", width), ("up", width),
                                          ("down", hidden))]

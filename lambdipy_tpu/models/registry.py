@@ -234,7 +234,6 @@ def _llama_tp_rules():
 
 
 _ATTN_BACKENDS = ("dense", "flash", "ring", "blocked")
-_MATMUL_BACKENDS = ("xla", "pallas")
 
 
 def _llama_overrides(extra: dict | None) -> dict:
@@ -246,6 +245,13 @@ def _llama_overrides(extra: dict | None) -> dict:
     from lambdipy_tpu.models.llama import LlamaConfig
 
     extra = dict(extra or {})
+    if "matmul_backend" in extra:
+        # a bundle that still asks for the removed kernel must not be
+        # served as if it had been honoured (no silent ignore: PR 21)
+        raise ValueError(
+            "bundle extra 'matmul_backend' is no longer accepted: the "
+            "Pallas int8 matmul it selected was removed in PR 29 and every "
+            "QDense runs XLA's dot; drop the key from [payload.extra]")
     # manifest JSON round-trips the rope_scaling tuple as a list; the
     # config field must be hashable (flax module attribute). A STRING here
     # means it came through the recipe schema's stringification — tuple()
@@ -290,9 +296,6 @@ def _llama_overrides(extra: dict | None) -> dict:
     if out.get("attn_backend", "dense") not in _ATTN_BACKENDS:
         raise ValueError(f"unknown attn_backend {out['attn_backend']!r}; "
                          f"supported: {_ATTN_BACKENDS}")
-    if out.get("matmul_backend", "xla") not in _MATMUL_BACKENDS:
-        raise ValueError(f"unknown matmul_backend {out['matmul_backend']!r}; "
-                         f"supported: {_MATMUL_BACKENDS}")
     if out.get("kv_quant") not in (None, "int8"):
         raise ValueError(f"unknown kv_quant {out['kv_quant']!r}; "
                          "supported: int8 (or omit for the float cache)")

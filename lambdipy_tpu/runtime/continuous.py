@@ -188,7 +188,6 @@ class ContinuousBatcher:
                  cache_len: int | None = None,
                  group_prefill_max: int = 256, policy: Any = None,
                  window_bucketing: bool = True, pipeline_depth: int = 2,
-                 synthetic_fetch_rtt_ms: float = 0.0,
                  watchdog_s: float = 0.0, max_replays: int = 1,
                  faults: FaultPlan | None = None,
                  degrade_window_s: float = 60.0,
@@ -365,11 +364,6 @@ class ContinuousBatcher:
         self.spec_metrics = getattr(server, "spec_metrics", None)
         if self.spec_metrics is None:
             self.spec_metrics = SpecDecodeStats()
-        # bench-only fetch-latency model (bench.py --pipeline): each
-        # collect pays this extra delay after device compute completes,
-        # WITHOUT stalling other queued segments — lets a CPU sweep show
-        # what pipelining buys at a given per-fetch latency
-        self.synthetic_fetch_rtt_ms = max(0.0, float(synthetic_fetch_rtt_ms))
         # sched policy: when slots are scarce, waiting joiners are packed
         # in POLICY order (priority / fair-share by request class from
         # the scheduler's context) instead of arrival order; None = FIFO
@@ -1418,12 +1412,6 @@ class ContinuousBatcher:
                               jax.block_until_ready, rec["toks"])
             t_ready = time.monotonic()
             phase.enter("eng.fetch", rids=served)
-            if self.synthetic_fetch_rtt_ms > 0:
-                # fetch-latency model: the delay starts once device
-                # compute is done and blocks only THIS fetch — segments
-                # already queued behind it keep the device busy meanwhile
-                time.sleep(self.synthetic_fetch_rtt_ms / 1e3)
-
             # one host fetch per segment: every device_get is a
             # synchronization with the device, so the logprob block rides
             # the same fetch — and only when some active request
@@ -1557,9 +1545,9 @@ class ContinuousBatcher:
                             self.fault_stats.record_replays(succeeded=1)
                 self._lock.notify_all()
             # fetch clock starts AFTER block_until_ready so fetch_block_s
-            # measures only the device_get transport window (plus the
-            # bench-only synthetic RTT), not the device-compute wait the
-            # collector pays when it outruns the device
+            # measures only the device_get transport window, not the
+            # device-compute wait the collector pays when it outruns the
+            # device
             pstats.record_collect(rec["t_dispatch"], t_ready,
                                   fetch_s=t_end - t_ready, wasted=wasted)
 

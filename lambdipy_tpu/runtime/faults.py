@@ -3,8 +3,8 @@
 The continuous engine's recovery machinery (watchdog, replay-on-restart,
 degradation ladder — runtime/continuous.py) only earns trust if every
 path through it runs in CI, not just when a TPU transport happens to
-wedge. This module gives tests and ``bench.py --chaos`` a deterministic
-way to make named SITES misbehave:
+wedge. This module gives the tests and the soak (``chaos/soak.py``) a
+deterministic way to make named SITES misbehave:
 
 ========================  ====================================================
 site                      where it fires
@@ -30,9 +30,7 @@ site                      where it fires
                           MID-STREAM transfer failure — the receiving
                           import aborts its staged pages and the request
                           degrades to mixed-mode; a delay is per-chunk
-                          synthetic wire time, the PR-5/PR-12 RTT idiom
-                          ``bench.py --disagg-rtt`` prices both ship
-                          modes with)
+                          wire time)
 ``session_pin``           the prefix store pinning a session's radix head
                           (fires once per turn, before any pin mutation;
                           an exception fails the pin OPEN — the turn
@@ -48,9 +46,9 @@ and fleet/pool.py): they make the *network* lie — dropped connections
 (``route_connect:exception``), connections dying mid-body
 (``route_body:exception``), latency spikes
 (``route_latency:delay@ms=300``), and flapping replicas
-(``probe:exception@seg=3,n=6``) — so ``bench.py --chaos-fleet`` can run
-a drop/latency/flap matrix against a live fleet with the same
-deterministic call counting the engine sites get.
+(``probe:exception@seg=3,n=6``) — so ``tests/test_fleet_resilience.py``
+and the soak's nemesis run drops, latency and flaps against a fleet with
+the same deterministic call counting the engine sites get.
 
 Each site can raise (``exception``), stall (``delay``, ``ms=``) or block
 indefinitely (``hang`` — until the plan is released, the watchdog aborts
@@ -155,8 +153,7 @@ REGISTRY: dict[str, FaultSite] = {s.name: s for s in (
               "replica (exception skips the re-ship, counted)"),
 )}
 
-# tuple view kept for spec validation, matrix iteration (bench.py
-# --chaos walks it) and backward compatibility with pre-registry callers
+# tuple view kept for spec validation and pre-registry callers
 SITES = tuple(REGISTRY)
 KINDS = ("exception", "delay", "hang")
 

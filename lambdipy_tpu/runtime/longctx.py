@@ -417,7 +417,7 @@ class LongContextRunner:
         frame decode and write them into freshly allocated arena pages
         through the page-write program (the same validated-insert path
         every kvwire import takes). ``timed`` marks a demand miss — the
-        wall clock it burns is the re-online stall the bench bounds."""
+        wall clock it burns is the re-online stall ``record_stall`` books."""
         import jax.numpy as jnp
 
         if not slots:

@@ -714,9 +714,8 @@ class PrefillStats:
     is one chunk. ``ring_collectives`` counts the modeled ring hops of
     sharded first-round programs (layers x sp ppermute steps each).
     ``critical_path_s`` is host wall time over whole walks — with
-    device time modeled through the ``prefix_walk`` delay site (the
-    --disagg / --sp-prefill bench idiom) it IS the modeled TTFT
-    critical path; ``serial_equiv_s`` scales each walk's wall by its
+    device time modeled through the ``prefix_walk`` delay site it IS
+    the modeled TTFT critical path; ``serial_equiv_s`` scales each walk's wall by its
     chunks/rounds ratio, the chunked-equivalent cost the sharded
     schedule avoided. ``standdowns`` mirrors the counted reasons a
     requested sp prefill ran chunked (no sp mesh axis, pool pressure,

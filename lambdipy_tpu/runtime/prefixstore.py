@@ -188,10 +188,9 @@ class PrefixStore:
         self.prefill_stats = prefill_stats
         # FaultPlan | None; site "prefix_walk" fires once per cold-walk
         # chunk dispatch: an injected exception fails the walk OPEN
-        # (route() serves the request unrouted), a delay models the
-        # chunk's prefill device time (bench.py --disagg uses it to put
-        # honest prefill occupancy on a CPU box whose real prefill is
-        # too cheap to measure isolation against)
+        # (route() serves the request unrouted), a delay stands for the
+        # chunk's prefill device time where the real prefill is too cheap
+        # to occupy a replica (tests/test_kvship.py holds the open failure)
         self.faults = faults
         cfg = server.model.cfg
         from lambdipy_tpu.models.llama import require_kv_cache
@@ -1327,10 +1326,9 @@ class PrefixStore:
 
     def _walk_fault(self) -> None:
         """``prefix_walk`` site: once per cold-walk chunk dispatch — and
-        in sp-prefill mode once per ROUND, which is exactly the tier's
-        bench story: both modes price identical modeled per-chunk device
-        time through this site, the sharded walk just stacks sp chunks
-        onto one critical-path slot."""
+        in sp-prefill mode once per ROUND: both modes price identical
+        modeled per-chunk device time through this site, the sharded
+        walk just stacks sp chunks onto one critical-path slot."""
         if self.faults is not None:
             self.faults.check("prefix_walk")
 

@@ -186,8 +186,8 @@ class Scheduler:
             now = time.monotonic()
             wait_ms = (now - ticket.enqueued) * 1e3
             # stamp the ticket so the server can echo queue_wait_ms in
-            # the response body — a client (the autoscale bench) can
-            # then window queue-wait client-side instead of reading the
+            # the response body — a client can then window queue-wait
+            # client-side instead of reading the
             # replica's cumulative reservoir
             ticket.wait_ms = wait_ms
             self.wait_stats[ticket.cls].record(wait_ms)

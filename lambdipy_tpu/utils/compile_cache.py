@@ -9,9 +9,9 @@ written under one path and read under another never hits. Hence:
 - unset, with a bundle: ``<bundle>/compile_cache``, shipped warm by the
   builder (``lambdipy build`` warms the bundle at the path it is served
   from).
-- unset, no bundle (bench stages, measurement scripts): one fixed
-  directory inside the checkout — never a temporary, per-process or home
-  directory.
+- unset, no bundle (today only the soak's own process, ``python -m
+  lambdipy_tpu.chaos.soak``): one fixed directory inside the checkout —
+  never a temporary, per-process or home directory.
 """
 
 from __future__ import annotations

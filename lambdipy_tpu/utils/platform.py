@@ -63,7 +63,7 @@ def prefer_cpu_backend() -> None:
 
 
 def child_env(overrides: dict | None = None) -> dict:
-    """Environment for a child process (server, warm step, bench stage).
+    """Environment for a child process (server, warm step, a benchmark run's deploy).
 
     The parent's environment minus LAMBDIPY_PLATFORM, plus ``overrides``,
     with the repo root first on PYTHONPATH so the child imports this

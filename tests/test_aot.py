@@ -192,8 +192,7 @@ def test_serving_programs_ride_aot_store(tmp_path):
     assert s2["aot_hits"] >= 2, s2
     # the cold-start overlap's observable (VERDICT r5 #5): the second
     # boot's preload thread deserialized the saved serving programs
-    # CONCURRENTLY with the params load, and reports it in the stats the
-    # 8B cold-start measurement reads (measure_8b --cold-start)
+    # CONCURRENTLY with the params load, and reports it in its stats
     assert s2.get("aot_preload", {}).get("programs", 0) >= 1, s2
     assert s2["aot_preload"]["seconds"] is not None
     out = r2.handler.invoke(r2.state, {"tokens": [1, 2, 3]})

@@ -3,7 +3,10 @@ values and the manifest's rules (``benchmark/tests/test_families.py`` and
 ``test_manifest.py``, imported whole so that the driver's run counts them),
 and the ``deepseek-v3`` family that PR 27 brought: its leaf rules against
 the program's real tree at published widths, what it says a step needs, by
-hand, and its toy twin through the whole command on the CPU."""
+hand, and its toy twin through the whole command on the CPU. At the end,
+the yardstick against the program: what every accepted configuration's
+family says a step reads and computes, against the program's own parameter
+tree, and the bounds the ledger's roofline shares stand on."""
 
 import json
 import os
@@ -13,7 +16,7 @@ import jax
 import numpy as np
 import pytest
 
-from benchmark import families, run, weights
+from benchmark import families, roofline, run, weights
 from benchmark.tests.test_families import *  # noqa: F401,F403
 from benchmark.tests.test_manifest import *  # noqa: F401,F403
 
@@ -239,3 +242,116 @@ def test_the_experts_read_reader_takes_the_windows_delta_or_nothing():
     entry = next(m for m in json.loads((REPO / "BENCHMARK.json").read_text())[
         "per_layer"] if m["name"] == "moe_experts_read")
     assert entry["workloads"] == ["kanana2-30b.decode-saturated"]
+
+
+# -- the yardstick against the program ---------------------------------------
+
+CELL_CONFIGS = ("mistral7b", "deepseek7b", "kanana2-30b")
+V5E = roofline.peaks_for("TPU v5 lite")
+
+
+def cell_config(name: str, **cut) -> dict:
+    return {**json.loads((BENCH / "configs" / f"{name}.json").read_text()),
+            **cut}
+
+
+@pytest.mark.parametrize("name", CELL_CONFIGS)
+def test_what_the_family_says_a_step_reads_is_the_programs_own_tree(name):
+    """``decode_hbm_pct`` and ``moe_hbm_pct`` divide the family's bytes by a
+    measured time: a kernel the program gained or lost and the family did
+    not makes a share nobody can read (``impossible_reading``). Cut to two
+    layers (``kanana2-30b``: its dense layer and one routed), shapes only:
+    with so many rows that every expert is touched and no cached context, a
+    step reads every int8 kernel once and the float32 routers, and nothing
+    else (the embedding is a gather; scales and norms are noise); one row's
+    matmuls use every kernel but its own ``top_k`` of the experts."""
+    from lambdipy_tpu.models import registry
+
+    config = cell_config(name, num_hidden_layers=2)
+    family = families.of(config)
+    adapter = registry.get(config["model"]).build(
+        dtype=config["precision"]["activations"],
+        quant=config["precision"]["weights"], extra=family.dims_of(config))
+    tree = jax.eval_shape(lambda: adapter.init_params(seed=0))
+    read = used = 0.0
+    share = config.get("num_experts_per_tok", 0) / config.get(
+        "n_routed_experts", 1)
+    for path, spec in jax.tree_util.tree_leaves_with_path(tree):
+        path = "/".join(str(k.key) for k in path)
+        size = int(np.prod(spec.shape))
+        if spec.dtype == np.int8:
+            read += size
+            used += size * (share if "/experts_" in path else 1)
+        elif path.endswith("moe/router"):
+            read += 4 * size
+            used += size
+    assert read > 1e8
+    assert family.decode_step_bytes(config, rows=1e9, context=0) == read
+    assert family.decode_step_flops(config, rows=1, context=0) == \
+        pytest.approx(2 * used, rel=1e-12)
+
+
+def step_bound_tok_s(config: dict, rows: int, context: int = 300) -> float:
+    """Tokens a second a v5e cannot exceed at ``rows`` live rows."""
+    family = families.of(config)
+    step_s = max(family.decode_step_bytes(config, rows=rows, context=context)
+                 / V5E.hbm_bytes_s,
+                 family.decode_step_flops(config, rows=rows, context=context)
+                 / V5E.bf16_flops)
+    return rows / step_s
+
+
+@pytest.mark.parametrize("name", CELL_CONFIGS)
+def test_a_decode_step_at_8_rows_is_bound_by_its_weight_bytes(name):
+    """The saturated cells' premise, and why their share is ``*_hbm_pct``:
+    at 8 rows the bytes take longer than the operations, and most of the
+    bytes are weights (the ledger's ``decode_step_ms`` 11.075 / 15.367 /
+    4.64 sit 20-45 % over these floors)."""
+    config = cell_config(name)
+    family = families.of(config)
+    need = family.decode_step_bytes(config, rows=8, context=300)
+    flops = family.decode_step_flops(config, rows=8, context=300)
+    assert need / V5E.hbm_bytes_s > 5 * flops / V5E.bf16_flops
+    assert family.decode_step_bytes(config, rows=8, context=0) > 0.8 * need
+
+
+@pytest.mark.parametrize("name,gain", [("mistral7b", 7.0), ("deepseek7b", 6.0),
+                                       ("kanana2-30b", 2.5)])
+def test_rows_amortize_the_weight_read(name, gain):
+    """Eight rows share one read of the weights: near-linear on the dense
+    models (less where the cache is wide), and under 3 x on the routed one,
+    whose 8 rows touch 41 experts a layer where one touches 6."""
+    config = cell_config(name)
+    ratio = step_bound_tok_s(config, 8) / step_bound_tok_s(config, 1)
+    assert gain < ratio <= 8.0
+
+
+@pytest.mark.parametrize("name,tokens", [("mistral7b", 1024),
+                                         ("deepseek7b", 1024),
+                                         ("kanana2-30b", 2048)])
+def test_a_long_prefill_is_bound_by_its_operations(name, tokens):
+    """The other side of ``WEIGHT_BOUND_ROWS``: 1024 tokens of one row keep
+    the MXU longer than one read of every weight keeps the HBM, and a
+    sixteenth of them do not. The routed model needs twice the tokens: a
+    token computes with 6 of the 128 experts the call reads, so its 1k
+    prefill still sits just under the ridge."""
+    config = cell_config(name)
+    family = families.of(config)
+
+    def mxu_over_hbm(seq_len):
+        flops = family.prefill_flops(config, rows=1, seq_len=seq_len)
+        once = family.decode_step_bytes(config, rows=seq_len, context=0)
+        return (flops / V5E.bf16_flops) / (once / V5E.hbm_bytes_s)
+
+    assert mxu_over_hbm(tokens) > 1 > mxu_over_hbm(tokens // 16)
+    if name == "kanana2-30b":
+        assert 0.9 < mxu_over_hbm(1024) < 1
+
+
+def test_no_share_is_computed_against_an_assumed_peak():
+    """Peaks come from a table keyed by ``device_kind``, each with its
+    source; a kind the table does not hold raises (this suite's own CPU
+    included)."""
+    assert V5E.source and (V5E.bf16_flops, V5E.hbm_bytes_s) == (197e12, 819e9)
+    with pytest.raises(KeyError, match="no published peaks"):
+        roofline.peaks_for(jax.devices()[0].device_kind)

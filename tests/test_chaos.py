@@ -1,8 +1,8 @@
 """Chaos-soak unit tests: timeline grammar + determinism, the nemesis
 executor, the workload plan, and the history/quiesce checker — the fast
 half of the soak contract. The live composed-fault run itself is
-``bench.py --soak`` (run_tier1 phase 14), which also re-runs a seed to
-prove determinism on a real fleet."""
+``python -m lambdipy_tpu.chaos.soak`` (about 6 minutes, run by hand),
+which also re-runs a seed to prove determinism on a real fleet."""
 
 import json
 import threading

@@ -109,15 +109,6 @@ def test_flash_attention_causal_compiles(v5e):
                  ((1, T, KVH, D), jnp.bfloat16))
 
 
-@pytest.mark.parametrize("m", [1, 8, 512])
-def test_int8_matmul_compiles(v5e, m):
-    """The MLP up-projection at decode (m = 1, 8) and prefill (512) widths."""
-    from lambdipy_tpu.ops.quant import int8_matmul
-
-    _compile_for(v5e, int8_matmul, ((m, HIDDEN), jnp.bfloat16),
-                 ((HIDDEN, MLP), jnp.int8), ((1, MLP), jnp.float32))
-
-
 # kanana2-30b's routed FFN (benchmark/configs/kanana2-30b.json)
 EXPERTS, TOP_K, EXPERT_HIDDEN, EXPERT_MLP = 128, 6, 2048, 768
 

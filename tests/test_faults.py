@@ -4,9 +4,9 @@ bitwise parity, the watchdog wedging a hung engine and aborting its
 waiters, drain-barrier cancellation (closed streams / expired deadlines),
 the degradation ladder, wedged-aware fleet health (stub replicas — no
 device), and — marked ``slow`` — the real-bundle-server e2e: /healthz
-flipping wedged and admission 503ing the accept hole. The full site x
-{exception, delay, hang} chaos matrix lives in ``bench.py --chaos``
-(run_tier1 phase 7); these tests pin the individual contracts."""
+flipping wedged and admission 503ing the accept hole. The engine-owned
+site x {exception, delay, hang} matrix is ``test_engine_fault_matrix`` at
+the end; the tests before it pin the individual contracts."""
 
 import json
 import threading
@@ -856,3 +856,106 @@ def test_fault_plan_clear_releases_hangs_without_poisoning_later_ones():
     plan.release()
     t2.join(5.0)
     assert ("second", "released") in results
+
+
+# -- the engine fault matrix -------------------------------------------------
+
+ENGINE_SITES = ("segment_dispatch", "segment_fetch", "group_prefill",
+                "prefix_assemble", "transport")
+MATRIX_SPECS = {"exception": "{site}:exception@seg=1",
+                "delay": "{site}:delay@ms=120,n=2",
+                # bounded: the watchdog trips, the replay lands on the
+                # recovered site; the permanent variant is its own case
+                "hang": "{site}:hang@seg=1,n=1"}
+MATRIX = [(site, kind, MATRIX_SPECS[kind].format(site=site))
+          for site in ENGINE_SITES for kind in MATRIX_SPECS]
+MATRIX.append(("segment_fetch", "hang_permanent", "segment_fetch:hang"))
+MATRIX_NEW, MATRIX_WATCHDOG_S = 16, 1.0
+MATRIX_PREFIX = list(range(1, 20))
+# one greedy and one seeded-sampled row (the sampled row's PRNG chain must
+# restart bitwise on a replay); the prefix row reaches prefix_assemble
+MATRIX_REQS = [([1, 2, 3, 4], {}, None),
+               ([9, 8, 7], dict(temperature=0.8, seed=7), None),
+               ([4, 5], {}, MATRIX_PREFIX)]
+
+
+@pytest.fixture(scope="module")
+def matrix_refs(tiny_server):
+    """Solo references, and every engine program the matrix can dispatch
+    compiled through a fault-free engine first (group prefill at 1-3
+    joiners, pack, the segment windows, prefix continuation): a 1 s
+    watchdog cannot tell a first-use compile from a wedge."""
+    refs = [tiny_server.generate((pfx or []) + row,
+                                 max_new_tokens=MATRIX_NEW, **kw)
+            for row, kw, pfx in MATRIX_REQS]
+    warm = ContinuousBatcher(tiny_server, slots=4, segment=4)
+    with ThreadPoolExecutor(max_workers=3) as ex:
+        for f in [ex.submit(warm.generate, row, max_new_tokens=MATRIX_NEW,
+                            prefix=pfx, **kw)
+                  for row, kw, pfx in MATRIX_REQS]:
+            f.result()
+    for row, kw, pfx in MATRIX_REQS:   # solo joins: the 1-row programs
+        warm.generate(row, max_new_tokens=MATRIX_NEW, prefix=pfx, **kw)
+    return refs
+
+
+@pytest.mark.parametrize("site,kind,spec", MATRIX,
+                         ids=[f"{s}-{k}" for s, k, _ in MATRIX])
+def test_engine_fault_matrix(tiny_server, matrix_refs, site, kind, spec):
+    """Every engine-owned site of ``faults.REGISTRY`` x {exception, delay,
+    hang} injected into a live continuous engine, plus one permanent
+    hang: no waiter outlives the watchdog's bound; every request returns
+    its bitwise solo tokens or an explicit error, never other tokens; a
+    delay never errors; an exception or a bounded hang at a site the
+    engine thread owns is replayed, and the replay delivers (at
+    ``prefix_assemble``, on the request's own thread, it is that request's
+    explicit error); the SAME batcher then serves bitwise and is not
+    wedged; against the permanent hang the waiters get errors and the
+    engine reports wedged. Nothing here is timed but the bound."""
+    refs = matrix_refs
+    plan = FaultPlan.from_spec(spec)
+    engine = ContinuousBatcher(tiny_server, slots=4, segment=4, faults=plan,
+                               watchdog_s=MATRIX_WATCHDOG_S, max_replays=1)
+    reqs = MATRIX_REQS if site == "prefix_assemble" else MATRIX_REQS[:2]
+    results: dict = {}
+
+    def one(i, row, kw, pfx):
+        try:
+            results[i] = engine.generate(row, max_new_tokens=MATRIX_NEW,
+                                         prefix=pfx, **kw)
+        except Exception as e:  # noqa: BLE001 — an explicit error is fine
+            results[i] = e
+
+    workers = [threading.Thread(target=one, args=(i, *req), daemon=True)
+               for i, req in enumerate(reqs)]
+    try:
+        for w in workers:
+            w.start()
+        deadline = time.monotonic() + 30.0
+        for w in workers:
+            w.join(timeout=max(0.0, deadline - time.monotonic()))
+        hung = [i for i, w in enumerate(workers) if w.is_alive()]
+        assert not hung, f"waiters {hung} still blocked past the bound"
+        errors = [i for i in results if isinstance(results[i], Exception)]
+        for i in set(results) - set(errors):
+            np.testing.assert_array_equal(
+                results[i], refs[i], err_msg=f"request {i}: WRONG tokens")
+        assert len(results) == len(reqs)
+        if kind == "delay":
+            assert not errors, f"a pure delay errored {errors}: {results}"
+        faults = engine.stats()["faults"]
+        if kind == "hang_permanent":
+            assert errors, "every waiter 'succeeded' against a dead site"
+            assert faults["wedged"] and engine.wedged
+            return
+    finally:
+        plan.release()
+    if kind != "delay":
+        if site == "prefix_assemble":
+            assert set(errors) <= {2}, results
+        else:
+            assert faults["replays"]["succeeded"] >= 1, faults
+    np.testing.assert_array_equal(
+        engine.generate(MATRIX_REQS[0][0], max_new_tokens=MATRIX_NEW),
+        refs[0])
+    assert not engine.wedged

@@ -1,7 +1,8 @@
 """Fleet subsystem: affinity hashing, pool health state machine, router
 failover/retry/hedging (scriptable stub replicas — no device, so these
-stay in the tight tier-1 phase-2 budget), and — marked ``slow``, run by
-run_tier1.sh phase 5 — everything that boots real bundle servers:
+stay in the tight tier-1 budget), and — marked ``slow``, run by hand
+(``pytest tests/test_fleet.py -m slow``, 3-4 min) — everything that
+boots real bundle servers:
 router-vs-direct bitwise parity, the readiness split on a live server,
 affinity concentrating the fleet prefix-cache hit rate, and subprocess
 fault injection with SIGKILL + supervisor re-admission and a rolling

@@ -6,9 +6,8 @@ scheduler's per-ticket wait stamp.
 Everything here is in-process and fake-backed: the policy is a pure
 function of (Snapshot, PolicyState, PolicyConfig) so the tables need no
 servers, and the controller is exercised against a fake pool/router
-that records actuator calls. The end-to-end loop (real subprocess
-replicas, a real spike, the P99 recovery gate) lives in
-``bench.py --autoscale``.
+that records actuator calls. The loop over real subprocess replicas is
+the soak's ``--autoscale`` leg (``python -m lambdipy_tpu.chaos.soak``).
 """
 
 from __future__ import annotations
@@ -272,7 +271,7 @@ def test_knob_cooldown_is_per_target_knob_pair():
 
 def test_decide_is_a_pure_function_of_its_inputs():
     """The same snapshot sequence through two fresh states renders the
-    same actions byte-for-byte — the bench's replay gate, pure-level."""
+    same actions byte-for-byte — the replay gate, pure-level."""
     rng = np.random.default_rng(7)
     snaps = []
     for tick in range(40):

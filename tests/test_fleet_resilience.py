@@ -2,8 +2,8 @@
 budget, the router spill queue, router-side network fault injection,
 and first-class attached (unmanaged) replicas. All on scriptable stub
 replicas — no device, no bundle boot — so the whole module stays in the
-fast tier-1 budget; the live-fleet end-to-end matrix is
-``bench.py --chaos-fleet`` (run_tier1.sh phase 8)."""
+fast tier-1 budget; live replicas behind the router are the ``slow``
+tests of ``tests/test_fleet.py``."""
 
 import json
 import threading
@@ -489,13 +489,16 @@ def test_fault_grammar_accepts_router_sites():
         FaultPlan.from_spec("route_nowhere:exception")
 
 
-def test_injected_route_connect_drops_and_fails_over(stub_pair):
-    """One injected drop: the request fails over to the other replica
-    and still lands. (Two consecutive drops would exhaust a 2-replica
-    fleet within one request — that shape is the spill tests' job.)"""
+@pytest.mark.parametrize("site", ["route_connect", "route_body"])
+def test_injected_route_drop_fails_over(stub_pair, site):
+    """One injected drop, at the connection or in the middle of the
+    replica's response body: the request fails over to the other replica
+    and still lands, with no error the client sees. (Two consecutive
+    drops would exhaust a 2-replica fleet within one request — that
+    shape is the spill tests' job.)"""
     s0, s1, pool = stub_pair
     pool.probe_all()
-    plan = FaultPlan.from_spec("route_connect:exception@seg=1,n=1")
+    plan = FaultPlan.from_spec(f"{site}:exception@seg=1,n=1")
     router = FleetRouter(pool, affinity_on=False, max_retries=3,
                          backoff_s=0.01, backoff_cap_s=0.05, faults=plan)
     router.start_background()
@@ -505,7 +508,7 @@ def test_injected_route_connect_drops_and_fails_over(stub_pair):
             assert _post(f"{base}/invoke", {"tokens": [i]})["ok"]
         rep = router.stats.report()
         assert rep["failovers"] >= 1 and rep["completed"] == 4
-        assert plan.counts()["route_connect"] >= 4
+        assert plan.counts()[site] >= 4
     finally:
         router.stop()
 

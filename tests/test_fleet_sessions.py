@@ -1,8 +1,8 @@
 """Sticky session routing, failover re-ship, and the import-miss pull —
 all on scriptable stub replicas (no device, no bundle boot) so the
-module stays in the fast tier-1 budget. The live-fleet end-to-end
-matrix (SIGKILL mid-conversation, bitwise transcript parity, TTFT gate,
-pin accounting) is ``bench.py --sessions`` (run_tier1.sh phase 13)."""
+module stays in the fast tier-1 budget. A live fleet with sessions, a
+SIGKILL and a drain under it is the soak's (``python -m
+lambdipy_tpu.chaos.soak``); pin accounting: ``tests/test_sessions.py``."""
 
 import json
 import urllib.request

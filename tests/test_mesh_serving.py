@@ -1,11 +1,11 @@
 """Tensor-parallel sharded serving (ISSUE 11 / ROADMAP direction 3).
 
-The heavy bitwise parity matrix (greedy/sampled x cold/prefix-hit x
-dense/paged x depths 1-2) lives in ``bench.py --mesh`` (run_tier1
-phase 11); this module covers the host-side pieces — the mesh-spec
-grammar, shape helpers, sharding-rule path matching, the serving-mesh
-validation contract, the cache placement math, and the engine-level
-stand-down + metrics surfaces — at unit-test cost.
+The host-side pieces — the mesh-spec grammar, shape helpers,
+sharding-rule path matching, the serving-mesh validation contract, the
+cache placement math, the engine-level stand-down + metrics surfaces —
+at unit-test cost, and at the end the bitwise parity matrix of the tp=2
+engine against the unsharded server (greedy/sampled x cold/prefix-hit x
+streamed x dense/paged x depths 1-2) at llama-tiny's dims.
 """
 
 import numpy as np
@@ -326,3 +326,144 @@ def test_engine_spec_k_stands_down_under_sp_mesh(cpu_devices):
     tp_server = dense.make_server(tp_params, mesh=tp_mesh)
     assert ContinuousBatcher(tp_server, slots=2, segment=4,
                              spec_k=4).spec_k == 4
+
+
+# -- the tp=2 engine against the unsharded server ----------------------------
+
+TP_NEW, TP_BLOCK = 12, 16
+TP_SAMPLE = dict(temperature=0.8, top_k=32, seed=11)
+
+
+@pytest.fixture(scope="module")
+def tp_pair(cpu_devices):
+    """An unsharded reference's tokens and the tp=2 server they are held
+    against: three cold rows (greedy and seeded-sampled) and two rows that
+    share a two-block prefix."""
+    adapter = registry.get("llama-tiny").build()
+    cfg = adapter.config
+    params = adapter.init_params(seed=0)
+    ref = adapter.make_server(params, prefix_cache_max=2)
+    rng = np.random.default_rng(0)
+    rows = [rng.integers(1, cfg.vocab_size, 4 + i).tolist() for i in range(3)]
+    shared = rng.integers(1, cfg.vocab_size, 2 * TP_BLOCK).tolist()
+    pfx_rows = [shared + rng.integers(1, cfg.vocab_size, 4).tolist()
+                for _ in range(2)]
+    refs = {tuple(r): ref.generate(r, max_new_tokens=TP_NEW)
+            for r in rows + pfx_rows}
+    refs_s = {tuple(r): ref.generate(r, max_new_tokens=TP_NEW, **TP_SAMPLE)
+              for r in rows[:2]}
+    mesh = make_mesh({"tp": 2}, devices=cpu_devices[:2])
+    with use_mesh(mesh):
+        sharded = shard_params(params, mesh, adapter.tp_rules)
+    server = adapter.make_server(sharded, mesh=mesh, prefix_cache_max=2)
+    return cfg, mesh, server, rows, pfx_rows, refs, refs_s
+
+
+def tp_engine(tp_pair, paged, **engine_kw):
+    """A 4-slot engine over the pair's tp=2 server, dense or over a page
+    arena placed on its mesh; ``(engine, pool or None)``."""
+    from lambdipy_tpu.models.llama import init_page_arena, page_kv_bytes
+    from lambdipy_tpu.runtime.continuous import ContinuousBatcher
+    from lambdipy_tpu.runtime.pagepool import PagePool, page_width
+
+    cfg, mesh, server = tp_pair[:3]
+    slots, pool = 4, None
+    if paged:
+        page = page_width(cfg.max_len, TP_BLOCK)
+        n_pages = slots * (cfg.max_len // page) + 1
+        pool = PagePool(n_pages=n_pages, page=page,
+                        page_bytes=page_kv_bytes(cfg, page),
+                        make_arena=lambda: init_page_arena(
+                            cfg, n_pages, page, mesh=mesh))
+    return ContinuousBatcher(server, slots=slots, segment=4, page_pool=pool,
+                             **engine_kw), pool
+
+
+def assert_cold_rows_bitwise(eng, tp_pair):
+    """Concurrent cold greedy rows, then seeded-sampled ones."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    rows, _, refs, refs_s = tp_pair[3:]
+    with ThreadPoolExecutor(max_workers=len(rows)) as ex:
+        outs = list(ex.map(
+            lambda r: eng.generate(r, max_new_tokens=TP_NEW), rows))
+    for r, o in zip(rows, outs):
+        np.testing.assert_array_equal(o, refs[tuple(r)])
+    for r in rows[:2]:
+        np.testing.assert_array_equal(
+            eng.generate(r, max_new_tokens=TP_NEW, **TP_SAMPLE),
+            refs_s[tuple(r)])
+
+
+def idle_stats(eng, pool):
+    """The engine's stats once its loop has gone idle; the pool's
+    accounting checked on the way."""
+    with eng._lock:
+        while eng._engine_running:
+            eng._lock.wait(0.05)
+    if pool is not None:
+        pool.check_invariants()
+    return eng.stats()
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_tp2_engine_is_bitwise_the_unsharded_server(tp_pair, paged, depth):
+    """The Megatron layout shards output channels, so reductions keep
+    their order: the sharded engine's tokens ARE the single-device
+    server's, for concurrent cold greedy rows, seeded-sampled rows, a
+    prefix's cold walk and its (dense or zero-copy) hit, and a streamed
+    hit; and after that traffic the LIVE gauges still read half the KV
+    and half the parameters a device (a segment program that resharded
+    the carry back to replicated would fail here, not at init)."""
+    from lambdipy_tpu.runtime.prefixstore import PrefixStore
+
+    server, pfx_rows, refs = tp_pair[2], tp_pair[4], tp_pair[5]
+    eng, pool = tp_engine(tp_pair, paged, pipeline_depth=depth)
+    store = PrefixStore(server, block=TP_BLOCK, budget_mb=64, pool=pool)
+    if pool is not None:
+        eng.prefix_pages_fn = store.acquire_pages
+
+    def routed(row, stream=False):
+        m = store.route(row)
+        pfx = np.asarray(row[:m], np.int32) if m > 0 else None
+        suf = np.asarray(row[m:], np.int32) if m > 0 else row
+        if stream:
+            return np.concatenate(list(eng.generate_stream(
+                suf, max_new_tokens=TP_NEW, prefix=pfx)), axis=1)[:, :TP_NEW]
+        return eng.generate(suf, max_new_tokens=TP_NEW, prefix=pfx)
+
+    assert_cold_rows_bitwise(eng, tp_pair)
+    for r in pfx_rows:              # the cold walk, then the hit
+        np.testing.assert_array_equal(routed(r), refs[tuple(r)])
+    np.testing.assert_array_equal(routed(pfx_rows[0], stream=True),
+                                  refs[tuple(pfx_rows[0])])
+    assert store.stats()["hits"] >= 2
+    mb = idle_stats(eng, pool)["mesh"]
+    assert mb["segments_sharded"] > 0
+    assert 0 < mb["kv_bytes_per_device"] <= 0.55 * mb["kv_bytes_replicated"]
+    assert 0 < mb["param_bytes_per_device"] <= 0.55 * mb["param_bytes_total"]
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_tp2_model_draft_engine_is_bitwise_the_unsharded_server(tp_pair,
+                                                                paged):
+    """Speculation composes with the mesh: the shallow-exit drafting
+    engine (``spec_k`` 4, ``draft_mode`` model, pipeline depth 2) over the
+    tp=2 server emits the unsharded plain server's tokens, greedy under
+    concurrency, seeded-sampled and streamed: the accept rule is
+    chain-deterministic, so a sharded draft can change how many tokens a
+    step verifies and never which."""
+    from lambdipy_tpu.runtime.metrics import SpecDecodeStats
+
+    rows, refs = tp_pair[3], tp_pair[5]
+    eng, pool = tp_engine(tp_pair, paged, pipeline_depth=2, spec_k=4,
+                          draft_mode="model")
+    eng.spec_metrics = SpecDecodeStats()
+    assert_cold_rows_bitwise(eng, tp_pair)
+    streamed = np.concatenate(list(eng.generate_stream(
+        rows[0], max_new_tokens=TP_NEW)), axis=1)[:, :TP_NEW]
+    np.testing.assert_array_equal(streamed, refs[tuple(rows[0])])
+    stats = idle_stats(eng, pool)
+    assert eng.spec_k == 4 and eng.spec_metrics.report()["steps"] > 0
+    assert stats["mesh"]["segments_sharded"] > 0

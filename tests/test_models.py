@@ -350,16 +350,23 @@ def test_torch_bert_cpu_smoke(tmp_path):
 
 
 def test_llama3_8b_builder_plumbs_backends():
-    """Recipe extras select the prefill-attention and int8-matmul backends
-    for the config-5 model without touching model code."""
+    """Recipe extras select the prefill-attention backend for the
+    config-5 model without touching model code; the int8-matmul key that
+    once rode beside it is refused by name, for any value (its kernel
+    went in PR 29: a bundle that still says it is not silently served)."""
+    import pytest as _pytest
+
     from lambdipy_tpu.models import registry
 
     spec = registry.get("llama3-8b")
     cfg = spec.build(extra={"attn_backend": "flash",
-                            "matmul_backend": "pallas",
                             "max_len": 4096}).config
     assert cfg.attn_backend == "flash"
-    assert cfg.matmul_backend == "pallas"
+    assert not hasattr(cfg, "matmul_backend")
+    for value in ("pallas", "xla"):
+        with _pytest.raises(ValueError, match="'matmul_backend'.*PR 29"):
+            spec.build(extra={"attn_backend": "flash",
+                              "matmul_backend": value})
     assert cfg.max_len == 4096 and cfg.quant == "int8"
 
 
@@ -370,8 +377,10 @@ def test_llama_builder_rejects_unknown_backend():
 
     with _pytest.raises(ValueError, match="attn_backend"):
         registry.get("llama3-8b").build(extra={"attn_backend": "Flash"})
-    with _pytest.raises(ValueError, match="matmul_backend"):
+    with _pytest.raises(ValueError, match="'matmul_backend'"):
         registry.get("llama-hf").build(extra={"matmul_backend": "cuda"})
+    with _pytest.raises(ValueError, match="'matmul_backend'"):
+        registry.get("deepseek-v3").build(extra={"matmul_backend": "xla"})
 
 
 def test_flatpack_device_load_matches_host_load(tmp_path):

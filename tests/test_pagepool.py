@@ -137,6 +137,27 @@ def test_stats_refcount_histogram_and_capacity_rows():
     assert st["window_bound_rows"] == 2
 
 
+def test_token_accounting_admits_more_mixed_length_rows_than_windows():
+    """What paged KV is for: in the budget a window-bound allocator spends
+    on ``slots`` rows (a full window each), admission by the rows' ACTUAL
+    tokens holds strictly more rows of mixed lengths, and never leaks."""
+    slots, window, page = 4, 128, 16
+    pool = mkpool(n_pages=slots * (window // page) + 1, page=page,
+                  window_pages=window // page)
+    assert pool.stats()["window_bound_rows"] == slots
+    rng = np.random.default_rng(7)
+    admitted = 0
+    with pytest.raises(PagesExhausted):
+        while True:
+            tokens = int(rng.integers(page, window // 2))
+            pool.alloc(-(-tokens // page), tokens=tokens)
+            admitted += 1
+    pool.check_invariants()
+    assert admitted > slots, (admitted, slots)
+    # rows of under half a window: at least two to a window's pages
+    assert admitted >= 2 * slots
+
+
 # -- randomized invariant fuzz ------------------------------------------------
 
 

@@ -1,7 +1,7 @@
 """Automatic cross-request prefix KV cache (radix reuse): bitwise
 on/off parity — greedy and seeded-sampled, solo, streamed and under
-concurrent continuous-batching traffic — plus budget eviction, the
-scheduler's suffix pricing, and the bench workload's roofline win."""
+concurrent continuous-batching traffic — plus budget eviction and the
+scheduler's suffix pricing."""
 
 from concurrent.futures import ThreadPoolExecutor
 
@@ -233,64 +233,3 @@ def test_handler_routes_automatically(tmp_path):
     pc = r.state.stats()["prefix_cache"]
     assert pc["hits"] >= 1 and pc["misses"] >= 1 and pc["bytes"] > 0
     assert r.state.prefix_probe(row) > 0
-
-
-def test_roofline_prefill_ratio_at_acceptance_dims():
-    """Pure-math check of the acceptance claim: at a repeated 512-token
-    prefix (8 requests, 16-token suffixes), suffix-only continuation
-    executes >= 4x fewer prefill FLOPs than full-prompt prefill — one
-    cold walk plus per-request continuations, the exact accounting
-    bench.py --shared-prefix reports."""
-    from lambdipy_tpu.models.llama import LLAMA3_8B
-    from lambdipy_tpu.utils import roofline
-
-    n, p, s = 8, 512, 16
-    off = n * roofline.llama_prefill_cost(
-        LLAMA3_8B, batch=1, seq_len=p + s).flops
-    on = roofline.llama_prefill_cost(LLAMA3_8B, batch=1, seq_len=p).flops
-    on += n * roofline.llama_prefix_continue_cost(
-        LLAMA3_8B, suffix_len=s, prefix_len=p).flops
-    assert off / on >= 4.0, off / on
-
-
-@pytest.mark.slow  # two compiled server instances (~20 s); the same
-# record is asserted at the acceptance dims by the subprocess test below
-def test_bench_shared_prefix_mode_reports_roofline_win():
-    """bench.py --shared-prefix: token parity on, nonzero hit rate, and
-    the roofline model reports >= 4x fewer prefill FLOPs with the cache
-    on for a shared-prefix workload (tiny dims keep this CPU-fast; the
-    ratio claim is dims-driven, dominated by prefix/suffix lengths)."""
-    import bench
-
-    rec = bench.shared_prefix_record(
-        n_requests=8, prefix_len=96, suffix_len=8, n_new=8, block=32,
-        extra={"vocab_size": 512, "hidden": 64, "layers": 2, "heads": 4,
-               "kv_heads": 2, "mlp": 128, "max_len": 256})
-    assert rec["parity"] is True
-    assert rec["prefill_flop_ratio"] >= 4.0, rec
-    assert rec["prefix_cache"]["hit_rate"] > 0, rec
-    assert rec["on_tok_s"] > 0 and rec["off_tok_s"] > 0
-
-
-@pytest.mark.slow
-def test_bench_shared_prefix_default_512(tmp_path):
-    """The acceptance workload verbatim: a repeated 512-token prefix
-    through `python bench.py --shared-prefix` (subprocess, CPU)."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    env.update({"JAX_PLATFORMS": "cpu",
-                "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")})
-    proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), "--shared-prefix"],
-        capture_output=True, text=True, env=env, timeout=900)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    rec = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert rec["parity"] is True
-    assert rec["prefix_len"] == 512
-    assert rec["prefill_flop_ratio"] >= 4.0, rec
-    assert rec["prefix_cache"]["hit_rate"] > 0, rec

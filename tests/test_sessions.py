@@ -8,8 +8,7 @@ renewed per turn) bound retention, the pin budget sheds new sessions
 priced by the lease horizon instead of starving live traffic, and an
 arena reset invalidates pins OBSERVABLY (counted, next turn re-prefills
 through the normal walk). Fleet-side stickiness/failover lives in
-tests/test_fleet_sessions.py; the live-fleet end-to-end matrix is
-``bench.py --sessions`` (run_tier1.sh phase 13)."""
+tests/test_fleet_sessions.py."""
 
 import json
 import threading

@@ -5,10 +5,9 @@ seam (aux twin models), and the knob/policy plumbing that steers it.
 Wall-clock discipline mirrors test_spec_engine.py: every non-slow
 engine test shares ONE shape (slots=2, segment=4, spec_k=4) over the
 session tiny_server, so the model-draft program family ("mspec", kb in
-{2, 4}) compiles once for the module. `bench.py --spec-draft` (tier-1
-phase 16) carries the expensive matrix — throughput, adaptive-k
-convergence, adversarial amortization, mesh + paged parity at scale —
-the slow-marked tests here are its in-repo twins."""
+{2, 4}) compiles once for the module; the paged twin and pipeline depth
+2 are ``test_model_draft_paged_parity`` and
+``test_model_draft_pipeline_depth2``."""
 
 import threading
 import time
@@ -214,8 +213,6 @@ def test_model_draft_budget_shorter_than_k(tiny_server):
         np.testing.assert_array_equal(out, ref)
 
 
-@pytest.mark.slow  # bench.py --spec-draft (tier-1 phase 16) gates
-# depth-2 model-draft parity on every CI pass; this is its in-repo twin
 def test_model_draft_pipeline_depth2(tiny_server):
     """Depth >= 2 composes with the model tier: the shallow chain runs
     in-program off the device-true carry, so drafts are never stale and
@@ -231,8 +228,6 @@ def test_model_draft_pipeline_depth2(tiny_server):
                     seed=5), ref_s)
 
 
-@pytest.mark.slow  # fresh model + paged mspec program family; bench
-# phase 16 runs the paged model-draft matrix on every CI pass
 def test_model_draft_paged_parity():
     """The paged twin of the model tier (_mspec_pseg_fn): shallow
     drafts over gathered pages, rejected tails absorbed by the null
@@ -430,7 +425,7 @@ def test_spec_stats_draft_block():
 
 
 @pytest.mark.slow  # two bundle loads; the validation itself is a pure
-# dict-in/dict-out fn and bench phase 16 drives the live knob at scale
+# dict-in/dict-out fn
 def test_knobs_draft_mode_validation(tmp_path):
     """The admin knob's whole validation surface: auto aliases model,
     model/aux require a spec-on boot, aux additionally a wired

@@ -5,9 +5,8 @@ greedy AND seeded-sampled rows (chain-deterministic acceptance).
 Wall-clock discipline: every non-slow test shares ONE engine shape
 (slots=2, segment=4, kb=4) over the session tiny_server so the
 ("spec_seg", ...) program family compiles once for the module; the
-bench gate (`bench.py --spec`, tier-1 phase 10) carries the expensive
-matrix (paged, depths, concurrency scale) — the `slow`-marked tests
-here are its in-repo twins."""
+paged twin and pipeline depth 2 are ``test_spec_engine_paged_parity``
+and ``test_spec_engine_pipeline_depth2``."""
 
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -117,8 +116,6 @@ def test_spec_engine_stream_and_logprobs(tiny_server):
     np.testing.assert_allclose(sl[:, :12], ref_l, rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.slow  # bench.py --spec (tier-1 phase 10) gates depth-1/2
-# parity on every CI pass; this is its in-repo twin
 def test_spec_engine_pipeline_depth2(tiny_server):
     """Depth-2 pipelining composes: in-flight records carry
     dispatch-time draft state (lookup extrapolated across in-flight
@@ -184,8 +181,6 @@ def test_spec_engine_replay_after_failure(tiny_server, monkeypatch):
     assert cb.fault_stats.replays_attempted >= 1
 
 
-@pytest.mark.slow  # fresh model + paged program family; bench.py --spec
-# (tier-1 phase 10) runs the paged parity matrix on every CI pass
 def test_spec_engine_paged_parity():
     """The paged twin (_spec_pseg_fn): gather/verify/scatter through
     block tables, rejected tails absorbed by the null page — cold,
@@ -234,8 +229,7 @@ def test_spec_engine_paged_parity():
 
 
 @pytest.mark.slow  # two bundle loads; the spec_k extra is one int cast
-# away from the tested ContinuousBatcher wiring, and bench phase 10
-# exercises engine spec on every CI pass
+# away from the tested ContinuousBatcher wiring
 def test_handler_spec_k_extra(tmp_path):
     """Bundle extra spec_k reaches the engine; batching.spec appears on
     the stats surface; tokens match the spec-off bundle's."""
