@@ -418,29 +418,56 @@ def resolve_sp_prefill(mode: str, mesh) -> int:
     return 0
 
 
-def _attend(q, k, v, mask):
+def _attend(q, k, v, mask, tail=None):
     """Grouped-query attention core. q: [b,s,h,d]; k/v: [b,t,kvh,d].
 
     The shard_hints pin ONE layout through softmax and its jvp/transpose —
     batch over dp, kv-heads over tp, query seq over sp, key seq gathered
     (replicated over sp) — so the SPMD partitioner never falls back to
     involuntary full rematerialization bouncing between dp- and sp-sharded
-    logits (ring attention is the layout that never gathers k/v)."""
+    logits (ring attention is the layout that never gathers k/v).
+
+    ``tail``: ``(k2, v2, seen [t2])``, further keys of the same rows (a
+    decode segment's own positions, :func:`_tail_write`), attended under
+    the ONE softmax: the same float32 logits and probabilities as if they
+    lay in ``k`` / ``v``, summed in two parts. Positions not ``seen`` are
+    never READ as numbers: their keys are masked like any other and their
+    values selected to zero, because the TPU compiler hands the scan a
+    tail it has not initialised (``_scan_decode``), and 0 x NaN is NaN."""
     from lambdipy_tpu.parallel.sharding import shard_hint
 
     b, s, h, d = q.shape  # values may be narrower than keys (latent)
     kvh = k.shape[2]
     group = h // kvh
     q = shard_hint(q.reshape(b, s, kvh, group, d), "dp", "sp", "tp")
+
+    def scores(k, mask):
+        logits = jnp.einsum("bskgd,btkd->bkgst", q, k).astype(jnp.float32)
+        logits = shard_hint(logits / jnp.sqrt(d).astype(jnp.float32),
+                            "dp", "tp", None, "sp", None)
+        return jnp.where(mask[:, None, None, :, :], logits, jnp.float32(-1e9))
+
     k = shard_hint(k, "dp", None, "tp")
     v = shard_hint(v, "dp", None, "tp")
-    logits = jnp.einsum("bskgd,btkd->bkgst", q, k).astype(jnp.float32)
-    logits = shard_hint(logits / jnp.sqrt(d).astype(jnp.float32),
-                        "dp", "tp", None, "sp", None)
-    logits = jnp.where(mask[:, None, None, :, :], logits, jnp.float32(-1e9))
+    logits = scores(k, mask)
+    if tail is not None:
+        k2, v2, seen = tail
+        k2 = shard_hint(k2, "dp", None, "tp")
+        v2 = shard_hint(jnp.where(seen[None, :, None, None], v2, 0),
+                        "dp", None, "tp")
+        logits = jnp.concatenate(
+            [logits, scores(k2, seen[None, None, :])], axis=-1)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     probs = shard_hint(probs, "dp", "tp", None, "sp", None)
-    out = jnp.einsum("bkgst,btkd->bskgd", probs, v)
+    if tail is None:
+        out = jnp.einsum("bkgst,btkd->bskgd", probs, v)
+    else:
+        t = k.shape[1]
+        out = (jnp.einsum("bkgst,btkd->bskgd", probs[..., :t], v,
+                          preferred_element_type=jnp.float32)
+               + jnp.einsum("bkgst,btkd->bskgd", probs[..., t:], v2,
+                            preferred_element_type=jnp.float32)
+               ).astype(v.dtype)
     return shard_hint(out.reshape(b, s, h, v.shape[-1]), "dp", "sp", "tp")
 
 
@@ -491,6 +518,28 @@ def _cache_write(cache, store, idx, b: int, s: int, band: int = 0):
     new_cache = {name: shard_hint(val, "dp", None, "tp")
                  for name, val in new_cache.items()}
     return new_cache, valid, t
+
+
+def _tail_write(cache, store):
+    """A decode segment's write (:func:`_scan_decode`, ``tail_window``):
+    the layer's cache leaves are READ here and never written; this step's
+    ``store`` leaves go to position ``cache["step"]`` of the segment's
+    tail, ``cache["tail"]`` (a leaf ``[b, segment, ...]`` for each cache
+    leaf, in its dtype), the same position for every row because a
+    segment's rows advance in lockstep. Returns ``(new tail, valid [b, 1,
+    t], seen [segment])``: a row attends what its cache held when the
+    segment began (``t < index``) and the tail positions written so far."""
+    from lambdipy_tpu.parallel.sharding import shard_hint
+
+    j = cache["step"]
+    tail = {name: shard_hint(
+                jax.lax.dynamic_update_slice(cache["tail"][name], val,
+                                             (0, j, 0, 0)), "dp", None, "tp")
+            for name, val in store.items()}
+    first = next(iter(store))
+    valid = (jnp.arange(cache[first].shape[1])[None, None, :]
+             < cache["index"][:, None, None])
+    return tail, valid, jnp.arange(tail[first].shape[1]) <= j
 
 
 class LlamaBlock(nn.Module):
@@ -748,7 +797,26 @@ class LlamaBlock(nn.Module):
                 from lambdipy_tpu.parallel.spdecode import note_standdown
 
                 note_standdown(f"attn_backend={cfg.attn_backend}")
-            if not sp_done:
+
+            def kv_of(leaves):
+                if cfg.kv_quant == "int8":
+                    return (_kv_dequantize(leaves["k_int8"],
+                                           leaves["k_scale"], cfg.dtype),
+                            _kv_dequantize(leaves["v_int8"],
+                                           leaves["v_scale"], cfg.dtype))
+                return leaves["k"], leaves["v"]
+
+            if "tail" in cache:
+                # a segment's step (_scan_decode, tail_window): the cache
+                # is read as the segment found it, this step's k/v joins
+                # the tail, one softmax over both
+                with jax.named_scope("kv_write"):
+                    new_cache, valid, seen = _tail_write(
+                        cache, _kv_store(cfg, k, v))
+                with jax.named_scope("attend"):
+                    out = _attend(q, *kv_of(cache), valid,
+                                  tail=(*kv_of(new_cache), seen))
+            elif not sp_done:
                 with jax.named_scope("kv_write"):
                     # quantize this chunk's k/v once under kv_quant; the
                     # cache stays int8 in HBM and the dequant fuses into
@@ -783,15 +851,7 @@ class LlamaBlock(nn.Module):
                                     q, new_cache["k"], new_cache["v"], active)
                             blocked = True
                     if not blocked:
-                        if cfg.kv_quant == "int8":
-                            ck = _kv_dequantize(
-                                new_cache["k_int8"], new_cache["k_scale"],
-                                cfg.dtype)
-                            cv = _kv_dequantize(
-                                new_cache["v_int8"], new_cache["v_scale"],
-                                cfg.dtype)
-                        else:
-                            ck, cv = new_cache["k"], new_cache["v"]
+                        ck, cv = kv_of(new_cache)
                         attn_mask = jnp.broadcast_to(valid, (b, s, t))
                         sp_mesh = (_active_sp_mesh()
                                    if (sp_prefill >= 2 and s > 1
@@ -1311,10 +1371,71 @@ def _split_rows(keys):
     return pair[:, 0], pair[:, 1]
 
 
+def segment_keeps_tail(cfg: LlamaConfig) -> bool:
+    """Whether a decode segment leaves its cache unwritten until its end
+    (:func:`_scan_decode`, ``tail_window``). Decided from the shapes, by
+    what the v5e compiler does with the per-step write (PERF.md section
+    6, PR 30; ``tests/test_chip_compile.py`` holds both halves):
+
+    - ONE query a KV head (multi-head K/V): the scores are a multiply-
+      reduce served from a prefetched copy of the cache, the scatter
+      updates that copy, and the WHOLE copy goes home every layer of every
+      step. The tail takes the write out of the loop: 15.6 -> 12.6 ms a
+      step at DeepSeek-7B widths.
+    - several queries a KV head (grouped-query K/V, the latent cache's one
+      shared row): the scores are a convolution that reads HBM and the
+      scatter is in place there, 0.6 ms a step at Mistral-7B widths; with
+      a read-only cache the compiler prefetches the leaves in place of
+      weights, 13.0 -> 13.7 ms at the full 2048 window. They keep the
+      per-step write.
+
+    The blocked Pallas kernel and the sp-sharded decode step
+    (``parallel/spdecode.py``) attend the ONE cache they are handed, so
+    their segments write it every step too. Asked while a segment program
+    is traced, under its mesh."""
+    if cfg.attn_kind != "kv" or cfg.heads != cfg.kv_heads:
+        return False
+    if cfg.attn_backend == "blocked":
+        return False
+    return cfg.attn_backend != "ring" or _active_sp_mesh() is None
+
+
+def _segment_decode(model: LlamaModel, params, select, first, lp, cache, pos,
+                    done, keys, eos_id, segment: int, window: int):
+    """One segment of the continuous engine's plain programs: ``segment``
+    steps whose attention reads the first ``window`` positions of the
+    B-slot cache; returns ``(emitted, carry)`` with the FULL cache in the
+    carry, advanced."""
+    def scan(cache, **form):
+        return _scan_decode(model, params, select, first, lp, cache, pos,
+                            done, keys, eos_id, segment, return_carry=True,
+                            count_load=model.cfg.counts_moe_load, **form)
+
+    if segment_keeps_tail(model.cfg):
+        return scan(cache, tail_window=window)
+    if window == cache_width(cache):
+        return scan(cache)
+    # the window's two copies per segment have a scope of their own,
+    # apart from the step's kv_write and attend
+    with jax.named_scope("kv_window"):
+        win = [{name: (val if name == "index"
+                       else jax.lax.slice_in_dim(val, 0, window, axis=1))
+                for name, val in entry.items()} for entry in cache]
+    out, carry = scan(win)
+    f2, lp2, wcache, pos2, done2, keys2 = carry
+    with jax.named_scope("kv_window"):
+        merged = [{name: (val if name == "index"
+                          else jax.lax.dynamic_update_slice_in_dim(
+                              cache[i][name], val, 0, axis=1))
+                   for name, val in entry.items()}
+                  for i, entry in enumerate(wcache)]
+    return out, (f2, lp2, merged, pos2, done2, keys2)
+
+
 def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
                  start, done0, keys, eos_id, decode_steps: int,
                  return_carry: bool = False, pos_offset=None,
-                 count_load: bool = False):
+                 count_load: bool = False, tail_window: int | None = None):
     """The decode scan shared by the exact-shape path (:func:`_decode`),
     the bucketed serving path (:func:`_serve_decode`) and the streaming
     segment path: one compiled step per token over a static-shape cache.
@@ -1342,9 +1463,40 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
     them: the assignments each row sent to each expert, int32
     ``[b, experts]`` (``moe_stats/load``), and the distinct experts a
     layer's call picked, one int32 (``moe_reads/experts``); the carry is
-    what it was."""
+    what it was.
+
+    ``tail_window`` (the engine's plain segments, where
+    :func:`segment_keeps_tail`): inside the scan the cache is READ-ONLY,
+    its first ``tail_window`` positions a loop invariant. The scan carries
+    instead, a layer and leaf, a tail ``[b, decode_steps, ...]`` of the
+    segment's own positions and the step number; a step writes tail
+    position ``j`` and attends cache (``t < start[r]``) and tail
+    (``j' <= j``) under one softmax (:func:`_tail_write`); after the scan
+    ONE scatter a leaf puts the tails at ``start[r] ..`` of the FULL cache
+    (:func:`_cache_write`'s ragged chunk: out-of-range positions drop, so
+    a finished slot's stale position lands nowhere live). The same keys,
+    values and probabilities as the per-step write, the sum's order apart;
+    done rows step as garbage into their own row's tail as they did into
+    their own row. Why: a cache the loop writes is prefetched whole,
+    updated and written back WHOLE every layer of every step (PERF.md
+    section 6, PR 30); a scan of hundreds of steps keeps the per-step
+    write, its tail would be a second cache."""
     b = first.shape[0]
     has_eos = eos_id >= 0
+    if tail_window is not None:
+        full, base = cache, jnp.broadcast_to(start, (b,))
+        with jax.named_scope("kv_window"):
+            frozen = [{name: jax.lax.slice_in_dim(val, 0, tail_window, axis=1)
+                       for name, val in entry.items() if name != "index"}
+                      for entry in full]
+        # zeros here; on the chip the compiler sees that the loop writes
+        # every position and hands it the buffer uninitialised
+        # (AllocateBuffer), whatever the value: _attend reads no position
+        # before its step wrote it
+        cache = ([{name: jnp.zeros((b, decode_steps) + val.shape[2:],
+                                   val.dtype)
+                   for name, val in entry.items()} for entry in frozen],
+                 jnp.int32(0))
 
     def step(carry, _):
         if count_load:
@@ -1353,6 +1505,10 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
         rope_pos = pos if pos_offset is None else pos + pos_offset
         positions = (rope_pos[:, None] if jnp.ndim(rope_pos)
                      else jnp.broadcast_to(rope_pos[None, None], (b, 1)))
+        if tail_window is not None:
+            tails, j = cache
+            cache = [{**entry, "index": base, "tail": tail, "step": j}
+                     for entry, tail in zip(frozen, tails)]
         if count_load:
             (logits, new_cache), sown = model.apply(
                 params, tok[:, None], positions=positions, cache=cache,
@@ -1362,8 +1518,11 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
         else:
             logits, new_cache = model.apply(params, tok[:, None],
                                             positions=positions, cache=cache)
-        for entry in new_cache:
-            entry["index"] = pos + 1
+        if tail_window is not None:
+            new_cache = (new_cache, j + 1)  # each layer's tail, grown by one
+        else:
+            for entry in new_cache:
+                entry["index"] = pos + 1
         keys, subs = _split_rows(keys)
         nxt, nlp = select_fn(logits[:, -1, :].astype(jnp.float32), subs)
         nxt = jnp.where(done, eos_id, nxt)
@@ -1381,6 +1540,15 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
     if count_load:
         carry, counts = carry
         out = (*out, *counts)
+    if tail_window is not None:
+        tok, lp, (tails, _), pos, done, keys = carry
+        with jax.named_scope("kv_write"):
+            # the ragged write of a chunk: out-of-range positions drop
+            merged = [_cache_write(entry, tail, base, b, decode_steps)[0]
+                      for entry, tail in zip(full, tails)]
+        for entry in merged:
+            entry["index"] = pos
+        carry = (tok, lp, merged, pos, done, keys)
     return (out, carry) if return_carry else out
 
 
@@ -1876,8 +2044,10 @@ class LlamaServer:
     # the same switch for the persistent cache). g4 = PR 25: QDense
     # applies the int8 scale after the dot where the kernel's bytes
     # bound it (names unchanged: the persistent cache's key hashes the
-    # new computation by itself).
-    _AOT_GEN = "g4"
+    # new computation by itself). g5 = PR 30: the two plain segment
+    # programs write a segment-long tail and merge it once
+    # (_scan_decode, tail_window); same signature, same carry.
+    _AOT_GEN = "g5"
 
     @classmethod
     def aot_prefix(cls) -> str:
@@ -2558,10 +2728,9 @@ class LlamaServer:
             def seg(params, temperature, top_k, top_p, first, lp, cache,
                     pos, done, rng, eos_id):
                 select = _serve_select(temperature, top_k, top_p)
-                return _scan_decode(self.model, params, select, first, lp,
-                                    cache, pos, done, rng, eos_id, segment,
-                                    return_carry=True,
-                                    count_load=self.model.cfg.counts_moe_load)
+                return _segment_decode(self.model, params, select, first, lp,
+                                       cache, pos, done, rng, eos_id,
+                                       segment, cache_width(cache))
 
             return (jax.jit(prefill), jax.jit(seg))
 
@@ -2576,7 +2745,9 @@ class LlamaServer:
         cache, runs the segment scan over that NARROW cache — decode
         attention reads ``window`` positions per step instead of
         ``cache_len`` — and writes the advanced window back into the
-        full carry. The decode-side twin of prefill's pow-2 bucketing:
+        full carry (:func:`_segment_decode`; where the segment keeps a
+        tail the slice is read-only and the tail merges into the full
+        carry). The decode-side twin of prefill's pow-2 bucketing:
         XLA KV reads scale with the live batch's actual context, no
         kernel required. Exactness: the engine only dispatches here when
         every active row's positions stay below ``window`` for the whole
@@ -2590,27 +2761,9 @@ class LlamaServer:
             def seg(params, temperature, top_k, top_p, first, lp, cache,
                     pos, done, rng, eos_id):
                 select = _serve_select(temperature, top_k, top_p)
-                # the window's two copies per segment have a scope of
-                # their own, apart from the step's kv_write and attend
-                with jax.named_scope("kv_window"):
-                    win = [{name: (val if name == "index"
-                                   else jax.lax.slice_in_dim(
-                                       val, 0, window, axis=1))
-                            for name, val in entry.items()}
-                           for entry in cache]
-                out, carry = _scan_decode(
-                    self.model, params, select, first, lp, win, pos, done,
-                    rng, eos_id, segment, return_carry=True,
-                    count_load=self.model.cfg.counts_moe_load)
-                f2, lp2, wcache, pos2, done2, rng2 = carry
-                with jax.named_scope("kv_window"):
-                    merged = [
-                        {name: (val if name == "index"
-                                else jax.lax.dynamic_update_slice_in_dim(
-                                    cache[i][name], val, 0, axis=1))
-                         for name, val in entry.items()}
-                        for i, entry in enumerate(wcache)]
-                return out, (f2, lp2, merged, pos2, done2, rng2)
+                return _segment_decode(self.model, params, select, first, lp,
+                                       cache, pos, done, rng, eos_id,
+                                       segment, window)
 
             return jax.jit(seg)
 

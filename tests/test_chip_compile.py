@@ -276,3 +276,92 @@ def test_decode_loop_writes_no_dequantized_weight(v5e, widths):
     ~25 s each."""
     text = _decode_segment_text(v5e, steps=4, layers=2, **widths)
     assert dequantized_weights_in_loops(text, widths) == []
+
+
+# what may have a cache leaf's shape inside the decode loop: the carried
+# tuple's members, and the compiler's read prefetch of a leaf into the
+# fast memory (slices, joined by a ConcatBitcast custom call)
+CACHE_READS = NO_WRITE | {"slice-start", "slice-done"}
+
+
+def cache_writes_in_loops(text: str, leaf_shapes: set) -> list:
+    """``(name, opcode, op_name)`` of every operation inside a ``while``
+    body of the optimized HLO ``text`` whose result has a cache leaf's
+    WHOLE shape and is not a read of it: a ``fusion`` that scatters into a
+    copy, a ``scatter`` or ``dynamic-update-slice`` in place, a
+    ``copy-done`` that takes the copy home."""
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
+    assert bodies, "no while loop in the program"
+    found, inside = [], False
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            head = re.match(r"%?([\w.\-]+) \(", line)
+            inside = bool(head) and head.group(1) in bodies
+            continue
+        op = inside and re.match(
+            r"\s+(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]+)\]\S* ([\w\-]+)\(", line)
+        if not op or op.group(3) in CACHE_READS:
+            continue
+        if op.group(3) == "custom-call" and "ConcatBitcast" in line:
+            continue
+        if tuple(int(d) for d in op.group(2).split(",")) in leaf_shapes:
+            name = re.search(r'op_name="([^"]*)"', line)
+            found.append((op.group(1), op.group(3),
+                          name.group(1) if name else ""))
+    return found
+
+
+@pytest.mark.parametrize("widths,layers,window,cache_len", [
+    (DEEPSEEK_7B, 30, 512, 512), (MISTRAL_7B, 32, 512, 2048)],
+    ids=["deepseek7b", "mistral7b"])
+def test_a_decode_segment_writes_back_no_whole_cache_leaf(
+        v5e, widths, layers, window, cache_len):
+    """The engine's plain segment at the two cells' own widths, depths and
+    windows, a 4-step scan, and the two halves of the rule
+    ``llama.segment_keeps_tail`` (PERF.md section 6, PR 30). ~30 s each.
+
+    DeepSeek (one query a KV head, 60 leaves of ``[8, 512, 32, 128]``)
+    keeps a tail: inside the loop NOTHING produces an array of a cache
+    leaf's whole shape but the loop's own tuple and the read prefetch; the
+    new positions go to the segment's tail and one scatter a leaf merges
+    it AFTER the loop. On the parent (PR 29, a scatter into the cache
+    every step) this trips on 52 ``fusion`` of ``kv_write/scatter`` whose
+    result lies in ``S(1)``, each followed by a ``copy-done`` of the
+    whole 33.5 MB leaf back to HBM (1745 MB a step), and 8 scatter
+    fusions in place in HBM. At 6 layers the parent reads 8 + 4 fusions
+    and 4 ``copy-done``, and the change still evicts ONE leaf it had
+    parked in the fast memory, which at the cell's depth it has no room
+    to: hence full depth. (The compiler also hands the loop its tails
+    UNINITIALISED, ``AllocateBuffer``, because it sees every position
+    written: ``_attend`` reads none before its step.)
+
+    Mistral (four queries a KV head, 64 leaves of ``[8, 512, 8, 128]``)
+    keeps the per-step write, because there the compiler does it where
+    the cache lies: 64 scatter fusions a step, at most 3 of them through
+    ``S(1)`` with a ``copy-done`` home (27 MB a step; none at the 1024
+    and 2048 windows). With a tail this program has no such operation
+    either, and on the chip it is 1 % faster at this window and 6 %
+    slower at the full 2048 one, where the compiler prefetches read-only
+    leaves in place of weights."""
+    from lambdipy_tpu.models.llama import LlamaConfig, segment_keeps_tail
+
+    text = _decode_segment_text(v5e, steps=4, layers=layers, window=window,
+                                cache_len=cache_len, **widths)
+    kv_heads, d = widths["kv_heads"], widths["hidden"] // widths["heads"]
+    writes = cache_writes_in_loops(
+        text, {(B, window, kv_heads, d), (B, cache_len, kv_heads, d)})
+    if segment_keeps_tail(LlamaConfig(layers=layers, **widths)):
+        assert writes == []
+        # the merge is there, once a leaf, outside the loop
+        assert len(re.findall(
+            r' fusion\([^\n]*op_name="jit\(seg\)/kv_write/scatter"',
+            text)) == 2 * layers
+    else:
+        by_opcode = {}
+        for _, opcode, op_name in writes:
+            by_opcode.setdefault(opcode, []).append(op_name)
+        assert set(by_opcode) <= {"fusion", "copy-done"}
+        assert len(by_opcode["fusion"]) == 2 * layers
+        assert all(name.endswith("kv_write/scatter")
+                   for name in by_opcode["fusion"])
+        assert len(by_opcode.get("copy-done", [])) <= 4
