@@ -28,7 +28,7 @@ from flax import linen as nn
 
 
 class LayerSpec(NamedTuple):
-    attn: str  # "kv" | "latent"
+    attn: str  # "kv" | "latent" | "eva"
     ffn: str   # "dense" | "capacity" | "routed"
 
 
@@ -116,11 +116,48 @@ class LlamaConfig:
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
     scoring_func: str = "softmax"  # "softmax" | "sigmoid"
+    # attn_kind "eva" (EVA chunked linearized attention, as EvaByte
+    # publishes it): positions lie in windows of ``window_size``; a query
+    # attends the keys of ITS window exactly (causal) and, under the same
+    # softmax, ONE learned summary (a pooled key and a pooled value) for
+    # every ``chunk_size`` positions of every earlier window. A layer's
+    # cache is therefore two kinds of leaf of different lengths: a ring of
+    # ``window_size`` K/V rows (position t at slot t mod window_size) and
+    # one summary row for every chunk the cache may serve (position t's
+    # chunk at t // chunk_size): ``cache_positions`` / ``cache_slot``.
+    window_size: int = 0
+    chunk_size: int = 0
+    # prediction heads: the head has ``pred_heads x vocab_size`` columns
+    # (EvaByte's multi-byte heads); the programs compute them all and
+    # serve the first ``vocab_size``, the next token's
+    pred_heads: int = 1
+    # RMSNorm multiplies by (1 + gain) and its gain starts at zero
+    norm_unit_offset: bool = False
 
     def __post_init__(self):
-        if self.attn_kind not in ("kv", "latent"):
+        if self.attn_kind not in ("kv", "latent", "eva"):
             raise ValueError(f"unknown attn_kind {self.attn_kind!r}; "
-                             "supported: kv, latent")
+                             "supported: kv, latent, eva")
+        if self.attn_kind == "eva":
+            if self.chunk_size < 1 or self.window_size < self.chunk_size \
+                    or self.window_size % self.chunk_size:
+                raise ValueError(
+                    "eva attention needs window_size, a multiple of "
+                    "chunk_size >= 1")
+            if self.heads != self.kv_heads:
+                raise ValueError(
+                    "eva attention is multi-head: kv_heads must equal heads")
+            if self.kv_quant is not None:
+                raise NotImplementedError(
+                    f"kv_quant={self.kv_quant!r} cannot hold an eva cache: "
+                    "the int8 cache layout quantizes one K/V row a token "
+                    "(_kv_store), not a ring beside pooled summaries")
+            if self.attn_backend != "dense":
+                raise NotImplementedError(
+                    f"attn_backend={self.attn_backend!r} attends one K/V "
+                    "row a token; eva attention runs the dense backend")
+        if self.pred_heads < 1:
+            raise ValueError("pred_heads must be >= 1")
         if self.ffn_kind not in ("dense", "routed"):
             raise ValueError(f"unknown ffn_kind {self.ffn_kind!r}; "
                              "supported: dense, routed")
@@ -170,14 +207,55 @@ class LlamaConfig:
         buckets, the engine's pack -- never asks which kind it holds."""
         if self.attn_kind == "latent":
             return {"ckv": (1, self.kv_lora_rank), "kpe": (1, self.qk_rope)}
-        return {"k": (self.kv_heads, self.head_dim),
-                "v": (self.kv_heads, self.head_dim)}
+        row = (self.kv_heads, self.head_dim)
+        if self.attn_kind == "eva":
+            # the ring, then the chunk summaries: ``cache_positions`` says
+            # how long each is and ``cache_slot`` where a position lies
+            return {"k": row, "v": row, "sk": row, "sv": row}
+        return {"k": row, "v": row}
+
+    def cache_positions(self, max_len: int) -> dict:
+        """``{leaf: slots}``: the length of each leaf's position axis in a
+        cache that serves absolute positions ``0 .. max_len - 1``. One row
+        a token for the "kv" and "latent" kinds; an eva ring never grows
+        past its window and a summary leaf holds one row a chunk."""
+        if self.attn_kind != "eva":
+            return dict.fromkeys(self.cache_layout(), max_len)
+        ring = min(self.window_size, max_len)
+        chunks = -(-max_len // self.chunk_size)
+        return {"k": ring, "v": ring, "sk": chunks, "sv": chunks}
+
+    def cache_slot(self, leaf: str, position):
+        """The slot of ``leaf``'s position axis that holds absolute
+        position ``position`` (an int or an int array)."""
+        if self.attn_kind != "eva":
+            return position
+        if leaf in ("sk", "sv"):
+            return position // self.chunk_size
+        return position % self.window_size
+
+    def prompt_bucket(self, s: int, lo: int) -> int:
+        """The padded length a prompt of ``s`` tokens prefills at: the next
+        power of two from ``lo``. An eva prompt past one window takes the
+        next whole window instead: its prefill is one body a window, so a
+        bucket of three windows costs three turns where the power of two
+        above it would cost four."""
+        if self.attn_kind == "eva" and s > self.window_size:
+            return -(-s // self.window_size) * self.window_size
+        return _next_bucket(s, lo)
 
     @property
     def counts_moe_load(self) -> bool:
         """Whether the engine's segment programs return the routed FFN's
         per-row expert load beside their tokens (``handler.moe``)."""
         return self.ffn_kind == "routed"
+
+    @property
+    def counts_eva_keys(self) -> bool:
+        """Whether the engine's segment programs return, a row, the keys
+        its steps had visible and the chunk summaries they wrote
+        (``handler.eva``)."""
+        return self.attn_kind == "eva"
 
 
 def require_kv_cache(cfg: LlamaConfig, holder: str) -> None:
@@ -191,6 +269,19 @@ def require_kv_cache(cfg: LlamaConfig, holder: str) -> None:
             "(PERF.md section 7)")
 
 
+def require_row_a_token(cfg: LlamaConfig, holder: str) -> None:
+    """Raise for a holder that cuts, joins or extends a cache along ONE
+    position axis (a prefix carried over, a chunk continued, a draft
+    verified and rolled back): an eva cache has a ring that forgets and
+    summaries that pool, so a span of positions is no slice of it."""
+    if getattr(cfg, "attn_kind", "kv") == "eva":
+        raise NotImplementedError(
+            f"{holder} keeps one cache row a token on one position axis "
+            "and cannot take the eva cache layout (a ring of "
+            f"{cfg.window_size} beside one summary for every "
+            f"{cfg.chunk_size} positions; PERF.md section 7)")
+
+
 LLAMA3_8B = LlamaConfig()
 LLAMA_TINY = LlamaConfig(vocab_size=512, hidden=64, layers=2, heads=4,
                          kv_heads=2, mlp=128, max_len=128, dtype=jnp.float32)
@@ -198,12 +289,18 @@ LLAMA_TINY = LlamaConfig(vocab_size=512, hidden=64, layers=2, heads=4,
 
 class RMSNorm(nn.Module):
     eps: float = 1e-5
+    # the gain is stored as its offset from one (``norm_add_unit_offset``)
+    unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x):
         dtype = x.dtype
         x32 = x.astype(jnp.float32)
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        init = nn.initializers.zeros if self.unit_offset \
+            else nn.initializers.ones
+        scale = self.param("scale", init, (x.shape[-1],), jnp.float32)
+        if self.unit_offset:
+            scale = 1.0 + scale
         y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
         return (y * scale).astype(dtype)
 
@@ -361,7 +458,9 @@ def _kv_dequantize(q_i8, scale, dtype):
 def cache_width(cache) -> int:
     """Sequence capacity of a decode/prefix cache (float or int8
     layout) — the ONE layout probe shared by the server's bucket math
-    and the continuous engine's pack gate."""
+    and the continuous engine's pack gate. Of a cache whose leaves hold
+    one row a token; the holders that ask refuse any other
+    (:func:`require_row_a_token`)."""
     return next(val for name, val in cache[0].items()
                 if name != "index").shape[1]
 
@@ -469,6 +568,103 @@ def _attend(q, k, v, mask, tail=None):
                             preferred_element_type=jnp.float32)
                ).astype(v.dtype)
     return shard_hint(out.reshape(b, s, h, v.shape[-1]), "dp", "sp", "tp")
+
+
+# queries one turn of an eva prefill's window loop attends. 32 heads x 128 x
+# (2048 + 384) float32 scores are 40 MB a row: the v5e compiler keeps them,
+# and every pass of the softmax over them, in the fast memory (compiled text
+# for a described v5e, PR 33), and a turn reads its window's K/V and the
+# summaries from HBM, 40 MB: 1.9 GB a layer of a 6144 prompt. At 512 queries
+# a turn the scores (160 MB) go to HBM, written twice and read three times:
+# 8.9 GB a layer, half the prefill's time; at 256 one of the two copies does
+EVA_QUERY_BLOCK = 128
+
+
+def _eva_pool(k, v, mu, phi, dtype):
+    """One summary a chunk: ``k``, ``v`` ``[..., chunk, heads, d]`` (keys
+    after rope) -> ``(sk, sv)`` ``[..., heads, d]`` in ``dtype``. The key
+    summary is the chunk's keys under softmax_j(k_j . mu_h), the value
+    summary its values under softmax_j(k_j . phi_h / sqrt(d)); both
+    softmaxes and sums in float32, as multiply-reduces (no product at the
+    MXU's precision)."""
+    k32, v32 = k.astype(jnp.float32), v.astype(jnp.float32)
+    kw = jax.nn.softmax(jnp.sum(k32 * mu, axis=-1), axis=-2)
+    vw = jax.nn.softmax(jnp.sum(k32 * phi, axis=-1)
+                        / jnp.sqrt(jnp.float32(k.shape[-1])), axis=-2)
+    return (jnp.sum(kw[..., None] * k32, axis=-3).astype(dtype),
+            jnp.sum(vw[..., None] * v32, axis=-3).astype(dtype))
+
+
+def _eva_ring(x, lengths, win: int):
+    """``x`` ``[b, s, h, d]`` (a prefill's keys or values) -> ``[b, win, h,
+    d]``: of each row the window its next position ``lengths[r]`` lies in,
+    position t at slot ``t mod win`` (zeros past the sequence; where the
+    next position opens a window past it, the last one, which the step
+    masks whole)."""
+    b, s = x.shape[:2]
+    n_win = -(-s // win)
+    x = jnp.pad(x, ((0, 0), (0, n_win * win - s), (0, 0), (0, 0)))
+    at = jnp.minimum(lengths // win, n_win - 1)
+    return jnp.take_along_axis(x.reshape(b, n_win, win, *x.shape[2:]),
+                               at[:, None, None, None, None], axis=1)[:, 0]
+
+
+def _eva_softmax_sum(q, parts):
+    """ONE float32 softmax over several key sets: ``q`` ``[b, s, h, d]``;
+    ``parts``: ``(keys [b, t, h, d], values [b, t, h, d], mask [b, s, t])``
+    each. Returns ``[b, s, h, d]``, float32 sums of the parts cast once."""
+    d = q.shape[-1]
+    logits = [jnp.where(
+        mask[:, None, :, :],
+        jnp.einsum("bshd,bthd->bhst", q, keys,
+                   preferred_element_type=jnp.float32)
+        / jnp.sqrt(d).astype(jnp.float32), jnp.float32(-1e9))
+        for keys, _, mask in parts]
+    probs = jax.nn.softmax(jnp.concatenate(logits, axis=-1), axis=-1)
+    out, at = 0.0, 0
+    for _, values, mask in parts:
+        t = mask.shape[-1]
+        out = out + jnp.einsum(
+            "bhst,bthd->bshd", probs[..., at:at + t].astype(values.dtype),
+            values, preferred_element_type=jnp.float32)
+        at += t
+    return out.astype(parts[0][1].dtype)
+
+
+def _eva_prefill_attend(q, k, v, sk, sv, mask, win: int, chunk: int):
+    """Prefill of more than one window: ONE body, a block of at most
+    ``EVA_QUERY_BLOCK`` queries a turn (``lax.map``), so the float32 scores
+    are ``[heads, block, win + chunks]`` whatever the prompt's length. A
+    query of window w attends that window's keys causally and the summaries
+    of the chunks before it."""
+    b, s, h, d = q.shape
+    n_win = -(-s // win)
+    pad = n_win * win - s
+    block = min(win, EVA_QUERY_BLOCK)
+    per_win = win // block
+
+    def cut(x, size):
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        return jnp.moveaxis(x.reshape(b, -1, size, *x.shape[2:]), 1, 0)
+
+    kw, vw, mw = cut(k, win), cut(v, win), cut(mask, win)
+    chunks = jnp.arange(sk.shape[1])
+
+    def body(args):
+        i, qi = args
+        w = i // per_win
+        at = (i % per_win) * block + jnp.arange(block)   # place in the window
+        own = jax.lax.dynamic_index_in_dim(mw, w, 0, False)[:, None, :] \
+            & (jnp.arange(win)[None, :] <= at[:, None])[None]
+        earlier = jnp.broadcast_to(chunks < w * (win // chunk),
+                                   (b, block, chunks.shape[0]))
+        return _eva_softmax_sum(
+            qi, ((jax.lax.dynamic_index_in_dim(kw, w, 0, False),
+                  jax.lax.dynamic_index_in_dim(vw, w, 0, False), own),
+                 (sk, sv, earlier)))
+
+    out = jax.lax.map(body, (jnp.arange(n_win * per_win), cut(q, block)))
+    return jnp.moveaxis(out, 0, 1).reshape(b, n_win * win, h, d)[:, :s]
 
 
 def _cache_write(cache, store, idx, b: int, s: int, band: int = 0):
@@ -582,7 +778,7 @@ class LlamaBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, mask, cache, sp_prefill: int = 0,
-                 band: int = 0):
+                 band: int = 0, lengths=None):
         """cache: None (prefill over full x) or the layer's cache entry
         (the leaves of ``cfg.cache_layout()`` plus ``index``) for decode.
         Returns (y, new_cache_entry). What the layer is made of is
@@ -611,6 +807,9 @@ class LlamaBlock(nn.Module):
         if spec.attn == "latent":
             out, new_cache = self._latent_attend(x, positions, mask, cache,
                                                  band)
+        elif spec.attn == "eva":
+            out, new_cache = self._eva_attend(x, positions, mask, cache,
+                                              lengths)
         else:
             out, new_cache = self._kv_attend(x, positions, mask, cache,
                                              sp_prefill, band)
@@ -633,7 +832,7 @@ class LlamaBlock(nn.Module):
                 x = x + RoutedMLP(cfg, name="moe")(
                     h, mask if cache is None else None).astype(x.dtype)
                 return x, new_cache
-            h = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
+            h = RMSNorm(cfg.norm_eps, cfg.norm_unit_offset, name="mlp_norm")(x)
             if spec.ffn == "capacity":
                 from lambdipy_tpu.models.moe import MoEMLP
 
@@ -729,15 +928,15 @@ class LlamaBlock(nn.Module):
                 out = out * w_scale.reshape(heads, dn + dv)[:, dn:]
         return out.astype(cfg.dtype), new_cache
 
-    def _kv_attend(self, x, positions, mask, cache, sp_prefill: int,
-                   band: int):
-        """Per-head K/V attention (the llama block): returns the heads'
-        outputs ``[b, s, heads, head_dim]`` and the new cache entry."""
+    def _project_qkv(self, x, positions):
+        """The per-head kinds' projections: ``q`` ``[b, s, heads, d]``,
+        ``k`` / ``v`` ``[b, s, kv_heads, d]``, ``q`` and ``k`` after rope."""
         cfg = self.cfg
         d = cfg.head_dim
         b, s, _ = x.shape
         with jax.named_scope("qkv_proj"):
-            h = RMSNorm(cfg.norm_eps, name="attn_norm")(x)
+            h = RMSNorm(cfg.norm_eps, cfg.norm_unit_offset,
+                        name="attn_norm")(x)
             q = QDense(cfg.heads * d, cfg.quant, cfg.dtype, name="q_proj")(h)
             k = QDense(cfg.kv_heads * d, cfg.quant, cfg.dtype, name="k_proj")(h)
             v = QDense(cfg.kv_heads * d, cfg.quant, cfg.dtype, name="v_proj")(h)
@@ -745,6 +944,15 @@ class LlamaBlock(nn.Module):
             k = k.reshape(b, s, cfg.kv_heads, d)
             v = v.reshape(b, s, cfg.kv_heads, d)
             q, k = rope(q, k, positions, cfg.rope_theta, cfg.rope_scaling)
+        return q, k, v
+
+    def _kv_attend(self, x, positions, mask, cache, sp_prefill: int,
+                   band: int):
+        """Per-head K/V attention (the llama block): returns the heads'
+        outputs ``[b, s, heads, head_dim]`` and the new cache entry."""
+        cfg = self.cfg
+        b, s, _ = x.shape
+        q, k, v = self._project_qkv(x, positions)
 
         if cache is None:
             with jax.named_scope("attend"):
@@ -871,6 +1079,115 @@ class LlamaBlock(nn.Module):
                             out = _attend(q, ck, cv, attn_mask)
         return out, new_cache
 
+    def _eva_attend(self, x, positions, mask, cache, lengths):
+        """EVA attention (``attn_kind`` "eva"): returns the heads' outputs
+        ``[b, s, heads, head_dim]`` and the new cache entry. Position t
+        attends the keys of its own window of ``window_size`` positions
+        exactly (causal) and, under the same float32 softmax, one pooled
+        key and value for every chunk of ``chunk_size`` positions of every
+        EARLIER window (:func:`_eva_pool`, the two learned vectors a head).
+
+        Without a cache (prefill, the whole forward) the sequence is cut
+        into windows and ONE body runs a block of a window's queries a turn
+        (:func:`_eva_prefill_attend`); the entry returned is already a
+        slot's: ``k`` / ``v`` the ring ``[b, window_size, ..]``
+        of the window each row's NEXT position ``lengths[r]`` lies in (the
+        whole sequence where ``lengths`` is None), each position at its
+        ``mod window_size`` slot, and ``sk`` / ``sv`` ALL the chunks'
+        summaries (:func:`_eva_ring`; a layer hands on a window, not the
+        sequence: 16 layers of an 8192 bucket would hold 2 GB). Right
+        padding is safe: a summary is attended only from a LATER window,
+        so a chunk that holds padding is attended by padding alone.
+
+        With a cache (one token a row): the step's K/V go to ring slot
+        ``t mod window_size``; the ring is attended under ``slot <= t mod
+        window_size`` and the summaries under ``chunk < (t // window_size)
+        * chunks a window``; when the step completes a chunk its rows,
+        all in the ring, are pooled and written at ``t // chunk_size``, and
+        otherwise that write drops (an out-of-range index, like a finished
+        slot's write). A window's summaries are all written before its ring
+        slots are overwritten, so nothing happens at a window's edge."""
+        cfg = self.cfg
+        d, heads = cfg.head_dim, cfg.heads
+        win, chunk = cfg.window_size, cfg.chunk_size
+        b, s, _ = x.shape
+        q, k, v = self._project_qkv(x, positions)
+        mu = self.param("adaptive_mu_k", nn.initializers.normal(1.0),
+                        (heads, d), jnp.float32)
+        phi = self.param("adaptive_phi", nn.initializers.normal(1.0),
+                         (heads, d), jnp.float32)
+
+        if cache is None:
+            with jax.named_scope("eva_summarize"):
+                n_chunks = -(-s // chunk)
+                pad = ((0, 0), (0, n_chunks * chunk - s), (0, 0), (0, 0))
+                sk, sv = _eva_pool(
+                    jnp.pad(k, pad).reshape(b, n_chunks, chunk, heads, d),
+                    jnp.pad(v, pad).reshape(b, n_chunks, chunk, heads, d),
+                    mu, phi, cfg.dtype)
+            with jax.named_scope("attend"):
+                if s <= win:  # one window: plain causal attention
+                    causal = jnp.tril(jnp.ones((s, s), dtype=jnp.bool_))
+                    out = _attend(q, k, v,
+                                  mask[:, None, :] & causal[None, :, :])
+                else:
+                    out = _eva_prefill_attend(q, k, v, sk, sv, mask, win,
+                                              chunk)
+            if lengths is None:
+                lengths = jnp.full((b,), s, jnp.int32)
+            return out, {"k": _eva_ring(k, lengths, win),
+                         "v": _eva_ring(v, lengths, win), "sk": sk, "sv": sv}
+
+        if s != 1:
+            raise NotImplementedError(
+                "an eva cache is stepped one token a row: a chunk of "
+                f"{s} positions against it (a prefix continued, a draft "
+                "verified) is not written (PERF.md section 7)")
+        idx = jnp.broadcast_to(cache["index"], (b,))
+        rows = jnp.arange(b)
+        ring, n_sum = cache["k"].shape[1], cache["sk"].shape[1]
+        slot = cfg.cache_slot("k", idx)
+        with jax.named_scope("kv_write"):
+            new_cache = {
+                "k": cache["k"].at[rows, slot].set(k[:, 0].astype(cfg.dtype)),
+                "v": cache["v"].at[rows, slot].set(v[:, 0].astype(cfg.dtype))}
+        with jax.named_scope("attend"):
+            seen = jnp.arange(ring)[None, :] <= slot[:, None]
+            earlier = (jnp.arange(n_sum)[None, :]
+                       < cfg.cache_slot("sk", idx // win * win)[:, None])
+            out = _eva_softmax_sum(
+                q, ((new_cache["k"], new_cache["v"], seen[:, None, :]),
+                    (cache["sk"], cache["sv"], earlier[:, None, :])))
+            if self.layer == 0:
+                # what a row's step had visible, and whether it completed
+                # a chunk: every layer's are the same (_scan_decode,
+                # count_keys; /metrics handler.eva)
+                self.sow("eva_stats", "keys", jnp.stack(
+                    [seen.sum(-1) + earlier.sum(-1),
+                     idx % chunk == chunk - 1], axis=-1).astype(jnp.int32))
+        with jax.named_scope("eva_summarize"):
+            # the chunk this position lies in: its rows are ring slots
+            # first .. first + chunk - 1, this step's own among them
+            first = slot // chunk * chunk
+
+            def take(leaf, at):
+                # a slice a row: as ONE gather the compiler copies the
+                # whole ring into a layout of the gather's liking, every
+                # layer of every step (compiled text for a v5e, PR 33)
+                return jnp.concatenate(
+                    [jax.lax.dynamic_slice(leaf, (r, at[r], 0, 0),
+                                           (1, chunk, heads, d))
+                     for r in range(b)], axis=0)
+
+            sk, sv = _eva_pool(take(new_cache["k"], first),
+                               take(new_cache["v"], first), mu, phi,
+                               cfg.dtype)
+            at = jnp.where(idx % chunk == chunk - 1,
+                           cfg.cache_slot("sk", idx), n_sum)
+            new_cache["sk"] = cache["sk"].at[rows, at].set(sk)
+            new_cache["sv"] = cache["sv"].at[rows, at].set(sv)
+        return out, new_cache
+
 
 class LlamaModel(nn.Module):
     cfg: LlamaConfig
@@ -878,7 +1195,7 @@ class LlamaModel(nn.Module):
     @nn.compact
     def __call__(self, tokens, positions=None, mask=None, cache=None,
                  logit_positions=None, exit_layer=None, sp_prefill=0,
-                 band=0):
+                 band=0, lengths=None):
         """Returns (logits, new_cache).
 
         prefill: cache=None, tokens [b, s] -> cache entries sized s.
@@ -888,6 +1205,8 @@ class LlamaModel(nn.Module):
         row of logits, not s: the full [b, s, vocab] f32 tensor is the
         largest activation of the whole serve path (8B at 8k context:
         4 GB) and s unneeded lm_head matmuls.
+        lengths: optional [b] int32 — each right-padded row's true length,
+        for a layer whose prefill entry depends on it (an eva ring).
         exit_layer: optional int — a SHALLOW-EXIT forward: run only
         layers 0..exit_layer-1, then final_norm + the TIED lm_head over
         that early hidden state (the self-drafting head for the
@@ -912,21 +1231,28 @@ class LlamaModel(nn.Module):
             layer_cache = None if cache is None else cache[i]
             x, c = LlamaBlock(cfg, i, name=f"layer_{i}")(
                 x, positions, mask, layer_cache, sp_prefill=sp_prefill,
-                band=band)
+                band=band, lengths=lengths)
             new_cache.append(c)
         with jax.named_scope("lm_head"):
-            x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
+            x = RMSNorm(cfg.norm_eps, cfg.norm_unit_offset,
+                        name="final_norm")(x)
             if logit_positions is not None:
                 x = jnp.take_along_axis(
                     x, jnp.broadcast_to(logit_positions[:, None, None],
                                         (b, 1, x.shape[-1])), axis=1)
-            logits = QDense(cfg.vocab_size, cfg.quant, jnp.float32, name="lm_head")(x)
+            logits = QDense(cfg.vocab_size * cfg.pred_heads, cfg.quant,
+                            jnp.float32, name="lm_head")(x)
+            if cfg.pred_heads > 1:
+                # the further heads predict the bytes after the next one;
+                # serving them as drafts is not written (PERF.md section 7)
+                logits = logits[..., :cfg.vocab_size]
         return logits, new_cache
 
 
 def _empty_cache_entry(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
     if cfg.attn_kind != "kv":
-        return {name: jnp.zeros((batch, max_len, heads, width), cfg.dtype)
+        slots = cfg.cache_positions(max_len)
+        return {name: jnp.zeros((batch, slots[name], heads, width), cfg.dtype)
                 for name, (heads, width) in cfg.cache_layout().items()}
     shape = (batch, max_len, cfg.kv_heads, cfg.head_dim)
     if cfg.kv_quant == "int8":
@@ -996,7 +1322,8 @@ def validate_serving_mesh(cfg: LlamaConfig, mesh) -> None:
             and any(int(n) > 1 for n in shape.values()):
         raise NotImplementedError(
             f"mesh {shape}: no sharding is written yet for latent attention "
-            "(a cache row has no head axis to split) or the dropless routed "
+            "(a cache row has no head axis to split), eva attention (a "
+            "ring beside pooled summaries) or the dropless routed "
             "FFN (a chip's share of the experts): serve this model on one "
             "device (PERF.md section 7)")
     tp = int(shape.get("tp", 1))
@@ -1036,6 +1363,7 @@ def concat_cache_blocks(cfg: LlamaConfig, blocks, cache_len: int):
     position-dependent (RoPE is applied before the cache store), so the
     caller must place blocks at the absolute positions they were sliced
     from; a radix path does that by construction."""
+    require_row_a_token(cfg, "concat_cache_blocks")
     from lambdipy_tpu.parallel.mesh import current_mesh
 
     total = sum(next(iter(b[0].values())).shape[1] for b in blocks)
@@ -1187,12 +1515,23 @@ def prefill_into_cache(cfg: LlamaConfig, prefill_cache, batch: int, max_len: int
     (kv-heads over tp) so prefill-produced caches — the prefix store's
     full-window entries included — leave their program tp-sharded
     instead of whatever replicated layout propagation falls back to
-    (no-op without an ambient mesh)."""
+    (no-op without an ambient mesh). An eva entry is already a slot's
+    (each row's ring, every chunk's summaries: ``LlamaBlock._eva_attend``)
+    and is cut to the slots the cache has: ring slots from the row's length
+    on hold what the step masks until it has written them, and the trailing
+    partial chunk's summary is overwritten when decode completes the chunk,
+    before anything may see it."""
     from lambdipy_tpu.parallel.sharding import shard_hint
 
     out = []
+    slots = cfg.cache_positions(max_len)
     for entry in prefill_cache:
-        store = _kv_store(cfg, *(entry[name] for name in cfg.cache_layout()))
+        if cfg.attn_kind == "eva":
+            store = {name: entry[name][:, :slots[name]].astype(cfg.dtype)
+                     for name in cfg.cache_layout()}
+        else:
+            store = _kv_store(cfg, *(entry[name]
+                                     for name in cfg.cache_layout()))
         dest = _empty_cache_entry(cfg, batch, max_len)
         for name, val in store.items():
             dest[name] = shard_hint(
@@ -1389,6 +1728,13 @@ def segment_keeps_tail(cfg: LlamaConfig) -> bool:
       weights, 13.0 -> 13.7 ms at the full 2048 window. They keep the
       per-step write.
 
+    - an eva cache (multi-head too: a ring and summaries) keeps the
+      per-step write: a step's row must be IN the ring when the chunk it
+      completes is pooled, and a tail would need the summaries' tail beside
+      it. At EvaByte widths the compiler then updates 14 of 32 ring leaves
+      in the fast memory and copies each home whole, 0.94 GB a step
+      (``tests/test_chip_compile.py``; PERF.md section 7).
+
     The blocked Pallas kernel and the sp-sharded decode step
     (``parallel/spdecode.py``) attend the ONE cache they are handed, so
     their segments write it every step too. Asked while a segment program
@@ -1406,20 +1752,28 @@ def _segment_decode(model: LlamaModel, params, select, first, lp, cache, pos,
     steps whose attention reads the first ``window`` positions of the
     B-slot cache; returns ``(emitted, carry)`` with the FULL cache in the
     carry, advanced."""
+    cfg = model.cfg
+
     def scan(cache, **form):
         return _scan_decode(model, params, select, first, lp, cache, pos,
                             done, keys, eos_id, segment, return_carry=True,
-                            count_load=model.cfg.counts_moe_load, **form)
+                            count_load=cfg.counts_moe_load,
+                            count_keys=cfg.counts_eva_keys, **form)
 
-    if segment_keeps_tail(model.cfg):
+    if segment_keeps_tail(cfg):
         return scan(cache, tail_window=window)
-    if window == cache_width(cache):
+    # the slots of each leaf that positions below ``window`` lie in: the
+    # window itself where a leaf holds one row a token
+    spans = {name: window for name in cache[0] if name != "index"}
+    if cfg.attn_kind == "eva":
+        spans = cfg.cache_positions(window)
+    if all(cache[0][name].shape[1] == span for name, span in spans.items()):
         return scan(cache)
     # the window's two copies per segment have a scope of their own,
     # apart from the step's kv_write and attend
     with jax.named_scope("kv_window"):
         win = [{name: (val if name == "index"
-                       else jax.lax.slice_in_dim(val, 0, window, axis=1))
+                       else jax.lax.slice_in_dim(val, 0, spans[name], axis=1))
                 for name, val in entry.items()} for entry in cache]
     out, carry = scan(win)
     f2, lp2, wcache, pos2, done2, keys2 = carry
@@ -1435,7 +1789,8 @@ def _segment_decode(model: LlamaModel, params, select, first, lp, cache, pos,
 def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
                  start, done0, keys, eos_id, decode_steps: int,
                  return_carry: bool = False, pos_offset=None,
-                 count_load: bool = False, tail_window: int | None = None):
+                 count_load: bool = False, tail_window: int | None = None,
+                 count_keys: bool = False):
     """The decode scan shared by the exact-shape path (:func:`_decode`),
     the bucketed serving path (:func:`_serve_decode`) and the streaming
     segment path: one compiled step per token over a static-shape cache.
@@ -1464,6 +1819,12 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
     ``[b, experts]`` (``moe_stats/load``), and the distinct experts a
     layer's call picked, one int32 (``moe_reads/experts``); the carry is
     what it was.
+
+    ``count_keys`` (an eva model's engine segments,
+    ``cfg.counts_eva_keys``): the emitted tuple gains one member, int32
+    ``[b, 2]`` summed over the steps as the block sows it (``eva_stats``):
+    the keys each row's steps had visible (ring rows and summaries) and
+    the chunk summaries they wrote.
 
     ``tail_window`` (the engine's plain segments, where
     :func:`segment_keeps_tail`): inside the scan the cache is READ-ONLY,
@@ -1501,6 +1862,8 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
     def step(carry, _):
         if count_load:
             carry, (load, read) = carry
+        elif count_keys:
+            carry, seen = carry
         tok, lp, cache, pos, done, keys = carry  # pos: int32 scalar or [b]
         rope_pos = pos if pos_offset is None else pos + pos_offset
         positions = (rope_pos[:, None] if jnp.ndim(rope_pos)
@@ -1515,6 +1878,11 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
                 mutable=["moe_stats", "moe_reads"])
             load = load + sum(jax.tree.leaves(sown["moe_stats"]))
             read = read + sum(jax.tree.leaves(sown["moe_reads"]))
+        elif count_keys:
+            (logits, new_cache), sown = model.apply(
+                params, tok[:, None], positions=positions, cache=cache,
+                mutable=["eva_stats"])
+            seen = seen + sum(jax.tree.leaves(sown["eva_stats"]))
         else:
             logits, new_cache = model.apply(params, tok[:, None],
                                             positions=positions, cache=cache)
@@ -1529,17 +1897,26 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
         nlp = jnp.where(done, jnp.float32(0.0), nlp)
         done = done | (has_eos & (nxt == eos_id))
         carry = (nxt, nlp, new_cache, pos + 1, done, keys)
-        return ((carry, (load, read)) if count_load else carry), (tok, lp)
+        if count_load:
+            carry = (carry, (load, read))
+        elif count_keys:
+            carry = (carry, seen)
+        return carry, (tok, lp)
 
     init = (first, lp0, cache, start, done0, keys)
     if count_load:
         init = (init, (jnp.zeros((b, model.cfg.moe_experts), jnp.int32),
                        jnp.int32(0)))
+    elif count_keys:
+        init = (init, jnp.zeros((b, 2), jnp.int32))
     carry, (toks, lps) = jax.lax.scan(step, init, None, length=decode_steps)
     out = (jnp.transpose(toks), jnp.transpose(lps))  # [b, decode_steps] x2
     if count_load:
         carry, counts = carry
         out = (*out, *counts)
+    elif count_keys:
+        carry, seen = carry
+        out = (*out, seen)
     if tail_window is not None:
         tok, lp, (tails, _), pos, done, keys = carry
         with jax.named_scope("kv_write"):
@@ -1635,10 +2012,12 @@ def _serve_prefill(model: LlamaModel, params, prompt, length, select, rng,
     b, sb = prompt.shape
     length = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (b,))
     # lm_head only at each row's last real position: [b, 1, v], never the
-    # [b, sb, v] full-prefill logits tensor
+    # [b, sb, v] full-prefill logits tensor; a layer whose prefill entry
+    # depends on the rows' lengths (an eva ring) reads them
     logits, prefill_cache = model.apply(params, prompt,
                                         logit_positions=length - 1,
-                                        sp_prefill=sp_prefill)
+                                        sp_prefill=sp_prefill,
+                                        lengths=length)
     cache = prefill_into_cache(cfg, prefill_cache, b, cache_len, 0)
     for entry in cache:
         entry["index"] = length
@@ -1923,6 +2302,7 @@ class LlamaServer:
         # Halve until it divides; disable if nothing >= min_bucket does.
         self.prefill_chunk = None
         if prefill_chunk:
+            require_row_a_token(model.cfg, "chunked prefill (prefill_chunk)")
             ck = max(self.min_bucket, _next_bucket(prefill_chunk, 16))
             while ck >= self.min_bucket and model.cfg.max_len % ck:
                 ck //= 2
@@ -2358,7 +2738,7 @@ class LlamaServer:
         # any request with s + max_new <= max_len must be servable
         steps = min(_next_bucket(max_new_tokens, self.min_bucket),
                     self.decode_cap, cfg.max_len - s)
-        sb = min(_next_bucket(s, self.min_bucket), cfg.max_len - steps)
+        sb = min(cfg.prompt_bucket(s, self.min_bucket), cfg.max_len - steps)
         # batch is bucketed too (micro-batching produces nondeterministic
         # sizes; each distinct b would otherwise compile at request time)
         bb = _next_bucket(b, 1)
@@ -2392,6 +2772,8 @@ class LlamaServer:
         Returns the cache key. The stored cache is sized to the full
         context window so any suffix + decode the window allows can
         continue from it."""
+        require_row_a_token(
+            self.model.cfg, "the server's prefix cache (cache_prefix)")
         cfg = self.model.cfg
         rows, lengths = self._normalize_prompts(prefix_tokens)
         if len(rows) != 1:
@@ -2450,6 +2832,8 @@ class LlamaServer:
         here so every existing ``prefix=`` path — fused, streaming,
         continuous-engine join, speculative — serves from it
         unchanged."""
+        require_row_a_token(
+            self.model.cfg, "the server's prefix cache (register_prefix)")
         with self._prefix_lock:
             self._prefixes[key] = (cache, int(length))
             self._prefixes.move_to_end(key)
@@ -2730,7 +3114,7 @@ class LlamaServer:
                 select = _serve_select(temperature, top_k, top_p)
                 return _segment_decode(self.model, params, select, first, lp,
                                        cache, pos, done, rng, eos_id,
-                                       segment, cache_width(cache))
+                                       segment, cache_len)
 
             return (jax.jit(prefill), jax.jit(seg))
 
@@ -3328,7 +3712,7 @@ class LlamaServer:
         n_segs = _next_bucket(n_needed, 1)
         if s + n_segs * segment > cfg.max_len:
             n_segs = n_needed  # shrink toward exact near the boundary
-        sb = max(s, min(_next_bucket(s, self.min_bucket),
+        sb = max(s, min(cfg.prompt_bucket(s, self.min_bucket),
                         cfg.max_len - n_segs * segment))
         bb = _next_bucket(b, 1)
         cache_len = min(sb + n_segs * segment, cfg.max_len)
@@ -3419,6 +3803,8 @@ class LlamaServer:
         program (only the suffix prefills; the prefix tokens still feed
         the lookup-draft context — a shared system prompt is prime
         n-gram material)."""
+        require_row_a_token(
+            self.model.cfg, "speculative decoding (_spec_steps)")
         cfg = self.model.cfg
         s = len(rows[0])
         cache_len = cfg.max_len

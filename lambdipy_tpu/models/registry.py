@@ -211,6 +211,9 @@ def _llama_tp_rules():
         # the latent and routed kinds until a PR shards them; the rules
         # are here so that the tree has no leaf without one.)
         ("*kv_a_proj/*", P()),
+        # eva attention's two learned pooling vectors a head: tiny, float32
+        # (validate_serving_mesh refuses a mesh for that kind too)
+        ("*adaptive_*", P()),
         ("*o_proj/kernel*", P("tp", None)),
         ("*down_proj/kernel*", P("tp", None)),
         ("*o_proj/scale", P()),
@@ -395,6 +398,26 @@ def _build_deepseek_v3(dtype: str = "bfloat16", quant: str | None = None,
     cfg = LlamaConfig(dtype=_dtype(dtype), quant=quant,
                       **{**_llama_overrides(extra), "attn_kind": "latent",
                          "ffn_kind": "routed"})
+    return _build_llama(cfg)
+
+
+@register("evabyte", "jax",
+          "EvaByte block: EVA chunked linearized attention, multi-byte heads")
+def _build_evabyte(dtype: str = "bfloat16", quant: str | None = None,
+                   extra: dict | None = None) -> JaxModel:
+    """The ``evabyte`` architecture through the one block (models/llama.py
+    ``attn_kind`` "eva"): multi-head attention over an exact window of
+    ``window_size`` positions beside one learned summary for every
+    ``chunk_size`` earlier positions, a dense SwiGLU, RMSNorm gains stored
+    as offsets from one, a head of ``pred_heads`` x ``vocab_size`` columns
+    of which the first ``vocab_size`` are served. Every shape key comes
+    from ``extra`` (docs/serving.md, "evabyte recipe keys"); the two
+    learned vectors a head stay float32 under int8."""
+    from lambdipy_tpu.models.llama import LlamaConfig
+
+    extra = {"norm_unit_offset": True, **(extra or {})}
+    cfg = LlamaConfig(dtype=_dtype(dtype), quant=quant,
+                      **{**_llama_overrides(extra), "attn_kind": "eva"})
     return _build_llama(cfg)
 
 

@@ -203,6 +203,7 @@ class ContinuousBatcher:
 
         from lambdipy_tpu.runtime.metrics import (DecodeWindowStats,
                                                   EngineFaultStats,
+                                                  EvaKeyStats,
                                                   MoeLoadStats,
                                                   PipelineStats,
                                                   PrefillStats,
@@ -226,6 +227,11 @@ class ContinuousBatcher:
         # tokens (llama._scan_decode count_load); the collector books them
         # here (/metrics handler.moe)
         self.moe_stats = MoeLoadStats()
+        # an eva-attention model's segment programs return, a row, the keys
+        # its steps had visible and the summaries they wrote
+        # (llama._scan_decode count_keys; /metrics handler.eva)
+        self.eva_stats = EvaKeyStats()
+        self._counts_eva = bool(getattr(cfg, "counts_eva_keys", False))
         self._routed_layers = (cfg.layers - cfg.first_dense_layers
                                if getattr(cfg, "counts_moe_load", False)
                                else 0)
@@ -254,6 +260,12 @@ class ContinuousBatcher:
             from lambdipy_tpu.models.llama import _next_bucket
 
             self.spec_k = max(2, _next_bucket(int(spec_k), 2))
+            if self._counts_eva:
+                raise NotImplementedError(
+                    "spec_k on an eva-attention model: a verify chunk is "
+                    "several positions wide and a rejected tail is rolled "
+                    "back, which a ring that forgets and summaries that "
+                    "pool cannot take (PERF.md section 7)")
             if getattr(server.model.cfg, "counts_moe_load", False):
                 raise NotImplementedError(
                     "spec_k on a routed-FFN model: the verify segments "
@@ -734,10 +746,8 @@ class ContinuousBatcher:
         the streaming path). The row's OWN sampling knobs and seed drive
         the first-token select, so the carry continues exactly the
         chain solo decode would walk; eos stays disabled (host-side)."""
-        from lambdipy_tpu.models.llama import _next_bucket
-
         server = self.server
-        sb = max(s, min(_next_bucket(s, server.min_bucket),
+        sb = max(s, min(server.model.cfg.prompt_bucket(s, server.min_bucket),
                         self.cache_len))
         sp = self.prefill_sp if (self.prefill_sp >= 2
                                  and sb % self.prefill_sp == 0) else 0
@@ -767,8 +777,9 @@ class ContinuousBatcher:
         rows = [e["row"] for e in entries]
         lens = [e["s"] for e in entries]
         bb = _next_bucket(len(rows), 1)
-        sb = max(max(lens), min(_next_bucket(max(lens), server.min_bucket),
-                                self.cache_len))
+        sb = max(max(lens), min(
+            server.model.cfg.prompt_bucket(max(lens), server.min_bucket),
+            self.cache_len))
         # sharded group prefill: the ONE ragged b-row program ring-shards
         # its prompt attention over the sp axis — same program count,
         # 1/sp the attention critical path per group
@@ -1462,11 +1473,14 @@ class ContinuousBatcher:
                 self.segments_run += 1
                 if self.mesh_stats is not None:
                     self.mesh_stats.record_segment()
-                if moe_h:
+                booked = [slot for slot, e in rec["rows"] if not e["done"]]
+                if moe_h and self._counts_eva:
+                    self.eva_stats.record_segment(moe_h[0][booked],
+                                                  steps=block.shape[1])
+                elif moe_h:
                     load_h, read_h = moe_h
                     self.moe_stats.record_segment(
-                        load_h[[slot for slot, e in rec["rows"]
-                                if not e["done"]]],
+                        load_h[booked],
                         experts_read=int(read_h),
                         layer_steps=block.shape[1] * self._routed_layers)
                 for slot, entry in rec["rows"]:

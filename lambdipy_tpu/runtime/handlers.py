@@ -1596,6 +1596,10 @@ def generate_handler(spec: dict, ctx) -> HandlerState:
             # the routed FFN's dropless check and per-expert load, booked
             # by the engine's collector from each segment's fetch
             out["moe"] = continuous.moe_stats.report()
+        if continuous is not None and getattr(
+                server.model.cfg, "counts_eva_keys", False):
+            # what the eva segments' rows attended and summarised
+            out["eva"] = continuous.eva_stats.report()
         if getattr(server, "spec_metrics", None) is not None:
             # the solo `"speculative": k` path's cumulative acceptance
             # counters (the engine's batching.spec block shares this

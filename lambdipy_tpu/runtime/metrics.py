@@ -158,6 +158,40 @@ class MoeLoadStats:
 
 
 @dataclass
+class EvaKeyStats:
+    """Counters of an eva-attention model's decode segments (the
+    ``handler.eva`` block on ``/metrics``), only growing, from the segment
+    programs' own fetch, for the rows the collector books (the rows the
+    device stepped for nobody are left out, as in :class:`MoeLoadStats`).
+    ``row_steps``: booked rows x segment steps. ``keys_attended``: the ring
+    rows and chunk summaries those steps had visible, summed:
+    ``keys_attended / row_steps`` is the mean number of keys a query
+    attended, beside the rows' mean context the compression the traffic
+    really got. ``chunks_written``: the summaries those steps wrote; a row
+    writes one every ``chunk_size`` steps, so ``chunks_written x chunk_size``
+    is ``row_steps`` to within one partial chunk a booked request."""
+
+    row_steps: int = 0
+    keys_attended: int = 0
+    chunks_written: int = 0
+    _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
+
+    def record_segment(self, rows, *, steps: int) -> None:
+        """One fetched segment. ``rows``: int array [booked rows, 2], each
+        row's (keys visible, chunks written) summed over the steps."""
+        with self._lock:
+            self.row_steps += len(rows) * steps
+            self.keys_attended += int(rows[:, 0].sum())
+            self.chunks_written += int(rows[:, 1].sum())
+
+    def report(self) -> dict:
+        with self._lock:
+            return {"row_steps": self.row_steps,
+                    "keys_attended": self.keys_attended,
+                    "chunks_written": self.chunks_written}
+
+
+@dataclass
 class MeshStats:
     """Gauges + counters for tensor-parallel sharded serving (the
     ``batching.mesh`` block on ``/metrics``). ``shape`` is the serving

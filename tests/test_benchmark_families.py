@@ -3,7 +3,8 @@ values and the manifest's rules (``benchmark/tests/test_families.py`` and
 ``test_manifest.py``, imported whole so that the driver's run counts them),
 and the ``deepseek-v3`` family that PR 27 brought: its leaf rules against
 the program's real tree at published widths, what it says a step needs, by
-hand, and its toy twin through the whole command on the CPU. At the end,
+hand, and its toy twin through the whole command on the CPU; the same for the
+``evabyte`` family that PR 33 brought. At the end,
 the yardstick against the program: what every accepted configuration's
 family says a step reads and computes, against the program's own parameter
 tree, and the bounds the ledger's roofline shares stand on."""
@@ -244,9 +245,217 @@ def test_the_experts_read_reader_takes_the_windows_delta_or_nothing():
     assert entry["workloads"] == ["kanana2-30b.decode-saturated"]
 
 
+# -- the evabyte family (PR 33) -------------------------------------------------
+
+EVABYTE = json.loads((BENCH / "configs" / "evabyte6b.json").read_text())
+EVA_TWIN_MANIFEST = BENCH / "rehearsal-eva.json"
+EVA_TWIN_CELL = "rehearsal-eva.rehearsal-closed"
+
+# the catalog's row for EvaByte (the model-configs guide's
+# architectures.jsonl, ``config``): every key, as published
+EVABYTE_PUBLISHED = {
+    "attention_bias": False, "attention_class": "eva", "chunk_size": 16,
+    "fp32_ln": False, "fp32_logits": True, "fp32_skip_add": True,
+    "hidden_act": "silu", "hidden_size": 4096, "init_cutoff_factor": None,
+    "init_fn": "v2", "init_std": 0.01275, "intermediate_size": 11008,
+    "lazy_init": True, "max_position_embeddings": 32768,
+    "max_seq_length": 32768, "mixedp_attn": True, "model_type": "evabyte",
+    "norm_add_unit_offset": True, "num_attention_heads": 32,
+    "num_chunks": None, "num_hidden_layers": 32, "num_key_value_heads": 32,
+    "num_pred_heads": 8, "rms_norm_eps": 1e-05, "rope_scaling": None,
+    "rope_theta": 100000, "tie_word_embeddings": False, "vocab_size": 320,
+    "window_size": 2048}
+
+
+def test_evabyte_is_the_published_configuration_cut_in_depth_only():
+    changed = {k for k, v in EVABYTE_PUBLISHED.items()
+               if EVABYTE.get(k, "absent") != v}
+    assert changed == {"num_hidden_layers"}
+    assert EVABYTE["num_hidden_layers"] in (16, 12)     # the depth rule
+    assert EVABYTE["published"] == {"num_hidden_layers": 32,
+                                    "max_position_embeddings": 32768}
+    assert EVABYTE["reduced"] == ["num_hidden_layers", "engine_window",
+                                  "max_position_embeddings"]
+    assert set(EVABYTE["reduced_why"]) == set(EVABYTE["reduced"])
+    # the program's max_len stays apart from the engine window: equal, the
+    # handler would switch on the prefix store, which refuses this layout
+    assert (EVABYTE["engine_window"], EVABYTE["context_served"]) == (8192,
+                                                                      16384)
+    assert EVABYTE["recipe_extra"] == {"batch_cache_len": 8192,
+                                       "batch_max": 4, "max_new_tokens": 16}
+    assert {"weights", "quantization", "tokenizer", "chunk_pooling",
+            "pooling_scale"} <= set(EVABYTE["assumed"])
+    traffic = json.loads((BENCH / "traffic" / "long-decode.json").read_text())
+    assert traffic["prompt_len"]["max"] + traffic["max_tokens"]["max"] \
+        <= EVABYTE["engine_window"]
+    # no request stays under one window, where EVA is plain attention
+    assert traffic["prompt_len"]["min"] >= EVABYTE["window_size"]
+    assert traffic["clients"] == 2 * EVABYTE["recipe_extra"]["batch_max"]
+
+
+def test_evabytes_leaf_rules_name_every_path_of_the_real_tree():
+    from lambdipy_tpu.models import registry
+
+    family = families.of(EVABYTE)
+    adapter = registry.get(EVABYTE["model"]).build(
+        dtype="bfloat16", quant="int8", extra=family.dims_of(EVABYTE))
+    tree = jax.eval_shape(lambda: adapter.init_params(seed=0))
+    shapes, params = {}, 0
+    for path, spec in jax.tree_util.tree_leaves_with_path(tree):
+        path = "/".join(str(k.key) for k in path if k.key != "params")
+        shapes[path] = spec.shape
+        params += int(np.prod(spec.shape))
+        sliver = tuple(min(n, 2) for n in spec.shape)
+        leaf = family.leaf(1, path, sliver, spec.dtype, EVABYTE)
+        assert leaf is not None and leaf.shape == sliver, path
+        assert leaf.dtype == np.dtype(spec.dtype), path
+    # ISSUE 33's arithmetic: 202.4 M a layer, 1.3 M of embedding, a head of
+    # 4096 x (8 x 320)
+    layers = EVABYTE["num_hidden_layers"]
+    layer = 4 * 4096 ** 2 + 3 * 4096 * 11008 + 2 * 32 * 128
+    assert 202.3e6 < layer < 202.5e6
+    kernels = layers * layer + 320 * 4096 + 4096 * 8 * 320
+    assert 0 < params - kernels < 0.001 * kernels
+    assert shapes["layer_3/adaptive_mu_k"] == (32, 128)
+    assert shapes["lm_head/kernel_int8"] == (4096, 2560)
+    assert shapes["embed/embedding"] == (320, 4096)
+    with pytest.raises(ValueError, match="evabyte.*kv_a_proj"):
+        weights.leaf(EVABYTE, "layer_2/kv_a_proj/kernel", (2, 2), "bfloat16")
+    with pytest.raises(ValueError, match="grouped K/V"):
+        family.dims_of(dict(EVABYTE, num_key_value_heads=8))
+
+
+def test_evabytes_seeded_values_are_what_the_configuration_says_it_assumed():
+    family = families.of(EVABYTE)
+    leaf = weights.leaf
+    assert np.all(leaf(EVABYTE, "layer_2/attn_norm/scale", (64,), "float32") == 0)
+    np.testing.assert_allclose(
+        leaf(EVABYTE, "layer_2/down_proj/scale", (1, 8), "float32"),
+        1 / (127 * 4096 ** 0.5), rtol=1e-6)
+    # pooling logits over a chunk of unit order: keys of the projection's
+    # own spread against the two learned vectors, the value side after 1/sqrt(d)
+    keys = np.random.default_rng(0).normal(size=(4096, 32, 128)) * family.KEY_STD
+    mu = leaf(EVABYTE, "layer_2/adaptive_mu_k", (32, 128), "float32")
+    phi = leaf(EVABYTE, "layer_2/adaptive_phi", (32, 128), "float32")
+    assert 0.8 < (keys * mu).sum(-1).std() < 1.25
+    assert 0.8 < ((keys * phi).sum(-1) / 128 ** 0.5).std() < 1.25
+    # far from the card's init_std, where a chunk's softmax is uniform
+    assert mu.std() > 5 * EVABYTE["init_std"]
+    assert not np.array_equal(mu, phi[:, ::-1]) and len(np.unique(mu)) > 200
+
+
+def test_what_an_eva_step_needs_by_hand():
+    family = families.of(EVABYTE)
+    layers = EVABYTE["num_hidden_layers"]
+    row = 2 * layers * 4096 * 2                  # K and V, bf16, all layers
+    assert row == layers * 16384                 # ISSUE 33's 16 KB a layer
+    kernels = layers * (4 * 4096 ** 2 + 3 * 4096 * 11008) + 4096 * 2560
+    # under one window: the context itself, no summary
+    assert family.keys_visible(EVABYTE, 300) == 300
+    assert family.decode_step_bytes(EVABYTE, rows=8, context=300) == \
+        kernels + 8 * 300 * row
+    # past it: half a window of ring rows and a summary for every 16
+    # positions before that; ISSUE 33's "1024 + 220" at a mean context of 4400
+    assert family.keys_visible(EVABYTE, 4400) == 1024 + (4400 - 1024) / 16
+    assert 1230 < family.keys_visible(EVABYTE, 4400) < 1240
+    assert family.eva_step_bytes(EVABYTE, rows=3.8, context=4400) == \
+        pytest.approx(3.8 * 1235 * row)
+    # the counter's own keys take the place of the estimate
+    assert family.eva_step_bytes(EVABYTE, rows=4, context=4400, keys=1250.0) \
+        == 4 * 1250 * row
+    assert family.decode_step_flops(EVABYTE, rows=2, context=0) == 4 * kernels
+    # a prefill's scores are window-local: 8192 positions cost 4 windows of
+    # 2048 squared and the summaries before each, far under 8192 squared
+    pre = family.prefill_flops(EVABYTE, rows=1, seq_len=8192)
+    matmuls = 2 * 8192 * (kernels - 4096 * 2560) + 2 * 4096 * 2560
+    own = layers * 4096 * 2 * 4 * 2048 ** 2
+    summaries = layers * 4096 * 4 * 2048 * 128 * (0 + 1 + 2 + 3)
+    assert pre == pytest.approx(matmuls + own + summaries)
+    assert pre - matmuls < 0.3 * layers * 2 * 4096 * 8192 ** 2
+
+
+def test_each_fault_of_eva_attention_is_seen_and_the_reference_is_not_moved(
+        capsys, tmp_path):
+    family = families.of(EVABYTE)
+    twin = json.loads((BENCH / "configs" / "rehearsal-eva.json").read_text())
+    ids = np.random.default_rng(5).integers(0, twin["vocab_size"], (2, 80))
+    rows, at = np.repeat(np.arange(2), 40), np.tile(np.arange(40, 80), 2)
+    alone = np.asarray(family.walk(twin, ids, rows, at, (False,))[False])
+    flags = (False, True) + family.FAULTS
+    assert family.FAULTS == ("no_summaries", "mean_pool", "swapped_pool",
+                             "stale_window")
+    streams = family.walk(twin, ids, rows, at, flags)
+    assert np.array_equal(np.asarray(streams[False]), alone)
+    moved = {flag: float(np.abs(np.asarray(streams[flag]) - alone).max())
+             for flag in flags[1:]}
+    assert all(v > 1e-2 for v in moved.values()), moved
+    assert moved["no_summaries"] > moved["mean_pool"]
+    # the command a limit's readings come from (PERF.md section 2)
+    path = tmp_path / "twin.json"
+    path.write_text(json.dumps(twin))
+    assert family.main(["--config", str(path), "--seeds", "3"]) == 0
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert set(line) == {"seed", "int4", *family.FAULTS}
+    assert all(line[k]["widest_gap"] >= 0 for k in line if k != "seed")
+
+
+def test_the_eva_twin_runs_the_whole_command_and_counts_its_keys(
+        capsys, tmp_path, monkeypatch):
+    for key in ("JAX_PLATFORMS", "XLA_FLAGS"):
+        monkeypatch.setenv(key, os.environ.get(key, ""))
+    rc = run.main(["--manifest", str(EVA_TWIN_MANIFEST), "--workload",
+                   EVA_TWIN_CELL, "--seed", str(2**31 + 7), "--seconds", "3",
+                   "--trace", "1", "--work-dir", str(tmp_path)])
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("{")]
+    last = lines[-1]
+    assert rc == 0 and last["correct"] is True, lines[-3:]
+    assert last["device"]["platform"] == "cpu" and last["failed"] == 0
+    window = next(ln for ln in lines if ln.get("stage") == "window")
+    assert window["compiles_in_window"] == 0, window
+    # requests of 32-80 positions over windows of 32 and chunks of 4: a
+    # query sees between 1 and 32 + 16 keys
+    keys = last["metrics"]["eva_keys_per_query"]["value"]
+    assert 8 < keys < 48
+
+
+def test_the_eva_readers_take_the_windows_delta_or_nothing():
+    """The three readers PR 33 added: what they read, and None where the
+    program has no such counter or scope (a llama cell; the parent)."""
+    from benchmark import harness
+
+    def eva(**block):
+        return {"handler": {"eva": block}}
+
+    reader = harness.layer_metric("eva_keys_per_query")
+    a = eva(row_steps=1600, keys_attended=2_000_000, chunks_written=100)
+    b = eva(row_steps=1600 + 640, keys_attended=2_000_000 + 640 * 1250,
+            chunks_written=140)
+    assert reader.read({"m_open": a, "m_close": b}) == pytest.approx(1250.0)
+    assert reader.read({"m_open": a, "m_close": a}) is None
+    assert reader.read({"m_open": {"handler": {}},
+                        "m_close": {"handler": {}}}) is None
+    llama = {"family": families.load("llama-hf"), "trace": {"busy_s": 1},
+             "slice": {"live": [(4, 4400.0)]},
+             "device": {"kind": "TPU v5 lite"}, "config": {},
+             "m_open": {"handler": {}}, "m_close": {"handler": {}}}
+    for name in ("eva_summarize_ms", "eva_cache_hbm_pct"):
+        assert harness.layer_metric(name).read(llama) is None
+    # no trace: no share, whatever the counters say
+    assert harness.layer_metric("eva_cache_hbm_pct").read(
+        {"family": families.of(EVABYTE), "config": EVABYTE, "trace": None,
+         "slice": {"live": [(4, 4400.0)]}, "device": {"kind": "TPU v5 lite"},
+         "m_open": a, "m_close": b}) is None
+    manifest = json.loads((REPO / "BENCHMARK.json").read_text())
+    for name in ("eva_summarize_ms", "eva_cache_hbm_pct",
+                 "eva_keys_per_query"):
+        entry = next(m for m in manifest["per_layer"] if m["name"] == name)
+        assert entry["workloads"] == ["evabyte6b.long-decode"]
+
+
 # -- the yardstick against the program ---------------------------------------
 
-CELL_CONFIGS = ("mistral7b", "deepseek7b", "kanana2-30b")
+CELL_CONFIGS = ("mistral7b", "deepseek7b", "kanana2-30b", "evabyte6b")
 V5E = roofline.peaks_for("TPU v5 lite")
 
 

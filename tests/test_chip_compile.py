@@ -137,10 +137,10 @@ def test_picked_experts_compiles(v5e, tokens):
 
 
 def _decode_segment_text(chip, *, steps, window=512, cache_len=T, layers=1,
-                         **widths):
+                         rows=B, **widths):
     """Optimized HLO of the decode segment (`jit_seg`, the window-bucketed
     variant the continuous engine dispatches) compiled for ``chip``: int8
-    weights, bf16 activations, ``B`` rows with a per-row ``index``; shapes
+    weights, bf16 activations, ``rows`` rows with a per-row ``index``; shapes
     only, nothing is placed or run."""
     from lambdipy_tpu.models.llama import (LlamaConfig, LlamaModel,
                                            LlamaServer, init_decode_cache)
@@ -156,15 +156,15 @@ def _decode_segment_text(chip, *, steps, window=512, cache_len=T, layers=1,
 
     params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0),
                                     jnp.zeros((1, 8), jnp.int32)))
-    cache = jax.eval_shape(lambda: init_decode_cache(cfg, B, cache_len))
+    cache = jax.eval_shape(lambda: init_decode_cache(cfg, rows, cache_len))
     for entry in cache:
-        entry["index"] = jax.ShapeDtypeStruct((B,), jnp.int32)
+        entry["index"] = jax.ShapeDtypeStruct((rows,), jnp.int32)
     row = {name: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
            for name, (shape, dtype) in {
-               "f32": ((B,), jnp.float32), "i32": ((B,), jnp.int32),
-               "bool": ((B,), jnp.bool_),
-               "keys": ((B, 2), jnp.uint32)}.items()}
-    seg = LlamaServer(model, None)._windowed_seg_fn(B, cache_len, window,
+               "f32": ((rows,), jnp.float32), "i32": ((rows,), jnp.int32),
+               "bool": ((rows,), jnp.bool_),
+               "keys": ((rows, 2), jnp.uint32)}.items()}
+    seg = LlamaServer(model, None)._windowed_seg_fn(rows, cache_len, window,
                                                     steps)
     assert seg.__name__ == "seg"
     return seg.lower(
@@ -365,3 +365,88 @@ def test_a_decode_segment_writes_back_no_whole_cache_leaf(
         assert all(name.endswith("kv_write/scatter")
                    for name in by_opcode["fusion"])
         assert len(by_opcode.get("copy-done", [])) <= 4
+
+
+# evabyte6b's widths (benchmark/configs/evabyte6b.json): 4 slots of 8192
+EVABYTE = dict(vocab_size=320, hidden=HIDDEN, heads=H, kv_heads=H, mlp=11008,
+               rope_theta=1e5, norm_eps=1e-5, max_len=16384, attn_kind="eva",
+               window_size=2048, chunk_size=16, pred_heads=8,
+               norm_unit_offset=True)
+
+
+def test_an_eva_segment_compiles_and_copies_no_ring(v5e):
+    """The engine's window-bucketed segment at ``evabyte6b.long-decode``'s
+    own shape key (4 slots, cache 8192, the 4096 bucket, 16 layers), a
+    4-step scan. ~20 s.
+
+    It compiles for the chip, and the pooling and the summary write carry
+    ``eva_summarize`` (what ``eva_summarize_ms`` and ``eva_cache_hbm_pct``
+    gather a trace's operations by), beside the llama block's scopes.
+
+    What the loop writes with a cache leaf's whole shape: two ring scatters
+    and two summary scatters a layer, and the ``copy-done`` of the ring
+    leaves the compiler updated in the fast memory and takes home whole
+    (14 of 32 at this depth, 0.94 GB a step: PR 30's finding on a kind that
+    keeps its per-step write, PERF.md section 7). NOT a ``copy``: with the
+    chunk's rows fetched by ONE gather (a vmapped ``dynamic_slice``) the
+    compiler copied every ring into a layout of the gather's liking, 32
+    copies of 67 MB a step; ``_eva_attend`` slices a row at a time."""
+    layers, window, slots = 16, 4096, 4
+    text = _decode_segment_text(v5e, steps=4, layers=layers, window=window,
+                                cache_len=8192, rows=slots, **EVABYTE)
+    found = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        found.update(op_name.split("/"))
+    assert {"embed", "qkv_proj", "kv_write", "attend", "eva_summarize",
+            "o_proj", "mlp", "lm_head", "sample", "kv_window"} <= found
+    writes = cache_writes_in_loops(
+        text, {(slots, 2048, H, D), (slots, window // 16, H, D)})
+    by_opcode = {}
+    for _, opcode, op_name in writes:
+        by_opcode.setdefault(opcode, []).append(op_name)
+    assert set(by_opcode) <= {"fusion", "copy-done"}, sorted(by_opcode)
+    scatters = by_opcode["fusion"]
+    assert sum(n.endswith("kv_write/scatter") for n in scatters) == 2 * layers
+    assert sum(n.endswith("eva_summarize/scatter")
+               for n in scatters) == 2 * layers
+    assert len(by_opcode.get("copy-done", [])) <= 2 * layers
+
+
+@pytest.mark.parametrize("block, in_hbm", [(128, False), (512, True)])
+def test_an_eva_prefill_keeps_a_turns_scores_in_the_fast_memory(
+        v5e, block, in_hbm, monkeypatch):
+    """The solo prefill of ``evabyte6b.long-decode``'s 6144 bucket (three
+    whole windows), one layer. ~12 s a case.
+
+    What ``EVA_QUERY_BLOCK`` rests on: at 128 queries a turn every float32
+    score tensor the loop's fusions hand on (32 heads x 128 x 2048 keys, x
+    384 summaries) lies in the chip's fast memory (``S(1)`` in the layout),
+    so the softmax's passes cost no HBM traffic; at 512 the 2048-key scores
+    are HBM buffers, written twice and read three times a turn."""
+    from lambdipy_tpu.models import llama
+
+    monkeypatch.setattr(llama, "EVA_QUERY_BLOCK", block)
+    cfg = llama.LlamaConfig(layers=1, dtype=jnp.bfloat16, quant="int8",
+                            **EVABYTE)
+    model = llama.LlamaModel(cfg)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e),
+            tree)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                                    jnp.zeros((1, 8), jnp.int32)))
+    server = llama.LlamaServer(model, None)
+    key = ("stream", 1, cfg.prompt_bucket(5000, 16), 8192, 16)
+    assert key[2] == 6144
+    operands = on_chip(jax.eval_shape(lambda: server._aot_examples(key))[0])
+    text = server._stream_fns(*key[1:])[0].lower(
+        params, *operands).compile().as_text()
+    handed_on = re.findall(
+        r"= \(?((?:f32\[(?:1,)?32,%d,\d+\]\{[^}]*\}(?:, )?)+)\)? fusion\("
+        % block, text)
+    scores = [layout for shapes in handed_on
+              for layout in re.findall(r"f32\[[\d,]+\]\{[^}]*\}", shapes)]
+    assert scores, "no fusion hands on a score tensor: the program changed"
+    assert any("S(1)" not in layout for layout in scores) == in_hbm, scores

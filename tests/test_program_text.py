@@ -26,6 +26,13 @@ abstract parameters (``jax.eval_shape``: nothing of 7 B is allocated), so
 that a change which only shows at those widths (a row-count threshold, a
 window bucket) is caught here too. Same parent, same rule.
 
+The ``evabyte`` model's programs (PR 33: ``attn_kind`` "eva") are held the
+same way at ``evabyte6b.long-decode``'s own shape key (4 slots of 8192): the
+solo prefills of the smallest and largest prompt bucket its mix reaches
+(2048; 6144, three whole windows), the full-window
+segment and the 4096 bucket's. They came with g5 and move with the block's
+eva path alone.
+
 A routed-FFN model's programs have no golden text: PR 28 gave its segment
 programs a second counter, and on a TPU backend their small calls take a
 Pallas kernel (``ops/grouped_experts.py``), which changes their cache keys
@@ -120,6 +127,43 @@ def test_an_accepted_cells_programs_keep_their_text_at_the_cells_shapes(name):
            text_hash(server._windowed_seg_fn(slots, cache_len, window, 16),
                      params, *seg_ops))
     assert got == golden
+
+
+# (solo prefill of the 2048 bucket, of the 6144 bucket (three whole windows:
+# ``LlamaConfig.prompt_bucket``), the full-window segment, the 4096 bucket's
+# segment) of ``evabyte6b`` at 4 slots of 8192
+EVA_GOLDEN = ("14d510489925", "601575a18f6e", "1f8f4f0d7a55", "47f60e45b3cb")
+
+
+def eva_hashes() -> tuple:
+    config = json.loads((Path(__file__).parents[1] / "benchmark" / "configs"
+                         / "evabyte6b.json").read_text())
+    adapter = registry.get(config["model"]).build(
+        dtype=config["precision"]["activations"],
+        quant=config["precision"]["weights"],
+        extra=families.of(config).dims_of(config))
+    params = jax.eval_shape(lambda: adapter.init_params(seed=0))
+    server = adapter.make_server(params)
+    slots = config["recipe_extra"]["batch_max"]
+    cache_len = config["engine_window"]
+    key = ("stream", slots, 16, cache_len, 16)
+    _, seg = server._stream_fns(*key[1:])
+    _, seg_ops = jax.eval_shape(lambda: server._aot_examples(key))
+    got = []
+    for bucket in (2048, 6144):
+        solo = ("stream", 1, bucket, cache_len, 16)
+        pre_ops = jax.eval_shape(lambda: server._aot_examples(solo))[0]
+        got.append(text_hash(server._stream_fns(*solo[1:])[0], params,
+                             *pre_ops))
+    got += [text_hash(seg, params, *seg_ops),
+            text_hash(server._windowed_seg_fn(slots, cache_len, 4096, 16),
+                      params, *seg_ops)]
+    return tuple(got)
+
+
+def test_the_eva_programs_keep_their_text_at_the_cells_shapes():
+    assert LlamaServer._AOT_GEN == "g5", "new generation: take the hashes anew"
+    assert eva_hashes() == EVA_GOLDEN
 
 
 def test_a_routed_models_programs_hold_no_kernel_call_on_this_backend():
