@@ -609,6 +609,18 @@ def _eva_ring(x, lengths, win: int):
                                at[:, None, None, None, None], axis=1)[:, 0]
 
 
+def _eva_chunk_rows(leaf, at, chunk: int):
+    """``leaf`` ``[b, ring, h, d]``, ``at`` ``[b]`` (or a scalar a row) ->
+    ``[b, chunk, h, d]``: of each row the ``chunk`` ring slots from
+    ``at[r]``. A slice a row: as ONE gather the compiler copies the whole
+    ring into a layout of the gather's liking, every layer of every step
+    (compiled text for a v5e, PR 33)."""
+    return jnp.concatenate(
+        [jax.lax.dynamic_slice(leaf, (r, at[r], 0, 0),
+                               (1, chunk) + leaf.shape[2:])
+         for r in range(leaf.shape[0])], axis=0)
+
+
 def _eva_softmax_sum(q, parts):
     """ONE float32 softmax over several key sets: ``q`` ``[b, s, h, d]``;
     ``parts``: ``(keys [b, t, h, d], values [b, t, h, d], mask [b, s, t])``
@@ -1143,6 +1155,8 @@ class LlamaBlock(nn.Module):
                 "an eva cache is stepped one token a row: a chunk of "
                 f"{s} positions against it (a prefix continued, a draft "
                 "verified) is not written (PERF.md section 7)")
+        if "tail" in cache:
+            return self._eva_tail_attend(q, k, v, mu, phi, cache)
         idx = jnp.broadcast_to(cache["index"], (b,))
         rows = jnp.arange(b)
         ring, n_sum = cache["k"].shape[1], cache["sk"].shape[1]
@@ -1159,34 +1173,100 @@ class LlamaBlock(nn.Module):
                 q, ((new_cache["k"], new_cache["v"], seen[:, None, :]),
                     (cache["sk"], cache["sv"], earlier[:, None, :])))
             if self.layer == 0:
-                # what a row's step had visible, and whether it completed
-                # a chunk: every layer's are the same (_scan_decode,
-                # count_keys; /metrics handler.eva)
+                # what a row's step had visible, whether it completed a
+                # chunk, and (a tail segment's column: 0 here) whether it
+                # came after a window edge inside its segment: every
+                # layer's are the same (_scan_decode, count_keys; /metrics
+                # handler.eva)
                 self.sow("eva_stats", "keys", jnp.stack(
                     [seen.sum(-1) + earlier.sum(-1),
-                     idx % chunk == chunk - 1], axis=-1).astype(jnp.int32))
+                     idx % chunk == chunk - 1, jnp.zeros_like(idx)],
+                    axis=-1).astype(jnp.int32))
         with jax.named_scope("eva_summarize"):
             # the chunk this position lies in: its rows are ring slots
             # first .. first + chunk - 1, this step's own among them
             first = slot // chunk * chunk
-
-            def take(leaf, at):
-                # a slice a row: as ONE gather the compiler copies the
-                # whole ring into a layout of the gather's liking, every
-                # layer of every step (compiled text for a v5e, PR 33)
-                return jnp.concatenate(
-                    [jax.lax.dynamic_slice(leaf, (r, at[r], 0, 0),
-                                           (1, chunk, heads, d))
-                     for r in range(b)], axis=0)
-
-            sk, sv = _eva_pool(take(new_cache["k"], first),
-                               take(new_cache["v"], first), mu, phi,
-                               cfg.dtype)
+            sk, sv = _eva_pool(_eva_chunk_rows(new_cache["k"], first, chunk),
+                               _eva_chunk_rows(new_cache["v"], first, chunk),
+                               mu, phi, cfg.dtype)
             at = jnp.where(idx % chunk == chunk - 1,
                            cfg.cache_slot("sk", idx), n_sum)
             new_cache["sk"] = cache["sk"].at[rows, at].set(sk)
             new_cache["sv"] = cache["sv"].at[rows, at].set(sv)
         return out, new_cache
+
+    def _eva_tail_attend(self, q, k, v, mu, phi, cache):
+        """A tail segment's step (:func:`_scan_decode`, ``tail_window``):
+        ring and summaries are READ as the segment found them and never
+        written. The segment's own rows lie in ``cache["tail"]``:
+
+        - ``k``, ``v`` ``[b, whole chunks, ..]``: consecutive positions
+          from the first of the chunk that was open when the segment
+          began, ``base[r] // chunk_size * chunk_size``: the ring's rows
+          of that chunk (:func:`_eva_tail_init`), over which, from
+          ``base[r]`` on, the segment's steps write theirs;
+        - ``sk``, ``sv`` ``[b, chunks, ..]``: slot m holding the summary of
+          chunk ``base[r] // chunk_size + m``, the m-th a row can complete
+          inside the segment.
+
+        The step, at position ``t = base[r] + j``, writes its K/V where the
+        tail holds t and attends under the ONE softmax
+
+        - the frozen ring while the row is in the window it began the
+          segment in, the slots written before the segment;
+        - the ring tail's positions so far that lie in t's window;
+        - the frozen summaries of the chunks of earlier windows that were
+          complete when the segment began;
+        - the summary tail's chunks of earlier windows: completed inside
+          the segment, before an edge the row has crossed since.
+
+        Where each lies is ``cache["plan"]``, the same for every layer
+        (:func:`_eva_tail_plan`). The same keys, values and probabilities
+        as the per-step write, the sum's order apart. The chunk t lies in
+        is pooled as the per-step write pools it, from ``chunk_size``
+        consecutive rows of ``k`` / ``v`` (open chunk and tail are one
+        array for that, and t's chunk one of its whole chunks), and lands
+        in the summary tail when t completes it; a chunk some row of which
+        is not yet written pools to garbage, which nothing selects.
+        Nothing of either tail is read as a number before its step wrote
+        it: keys are masked, values selected to zero (the v5e compiler
+        hands the scan a tail it has not initialised, and 0 x NaN is NaN).
+        Returns the heads' outputs and the new tail."""
+        cfg = self.cfg
+        tail, plan = cache["tail"], cache["plan"]
+        with jax.named_scope("kv_write"):
+            new_tail = {
+                name: tail[name].at[plan["rows"], plan["at"]].set(
+                    val[:, 0].astype(cfg.dtype))
+                for name, val in (("k", k), ("v", v))}
+        with jax.named_scope("attend"):
+            own = plan["own"][:, :, None, None]
+            inside = plan["inside"][:, :, None, None]
+            out = _eva_softmax_sum(q, (
+                (cache["k"], cache["v"], plan["held"][:, None, :]),
+                (new_tail["k"], jnp.where(own, new_tail["v"], 0),
+                 plan["here"][:, None, :]),
+                (cache["sk"], cache["sv"], plan["before"][:, None, :]),
+                (tail["sk"], jnp.where(inside, tail["sv"], 0),
+                 plan["inside"][:, None, :])))
+            if self.layer == 0:
+                # every layer's are the same (_scan_decode, count_keys;
+                # /metrics handler.eva)
+                self.sow("eva_stats", "keys", plan["stats"])
+        with jax.named_scope("eva_summarize"):
+            # the tail begins at a chunk's first position, so the chunk t
+            # lies in is one of its whole chunks
+            def chunk_of(leaf):
+                whole = leaf.reshape(leaf.shape[0], -1, cfg.chunk_size,
+                                     *leaf.shape[2:])
+                return jnp.take_along_axis(whole, plan["chunk"], axis=1)[:, 0]
+
+            sk, sv = _eva_pool(chunk_of(new_tail["k"]),
+                               chunk_of(new_tail["v"]), mu, phi, cfg.dtype)
+            lands = plan["lands"][:, :, None, None]
+            new_tail["sk"] = jnp.where(lands, sk[:, None], tail["sk"])
+            new_tail["sv"] = jnp.where(lands, sv[:, None], tail["sv"])
+        return out, new_tail
 
 
 class LlamaModel(nn.Module):
@@ -1728,22 +1808,139 @@ def segment_keeps_tail(cfg: LlamaConfig) -> bool:
       weights, 13.0 -> 13.7 ms at the full 2048 window. They keep the
       per-step write.
 
-    - an eva cache (multi-head too: a ring and summaries) keeps the
-      per-step write: a step's row must be IN the ring when the chunk it
-      completes is pooled, and a tail would need the summaries' tail beside
-      it. At EvaByte widths the compiler then updates 14 of 32 ring leaves
-      in the fast memory and copies each home whole, 0.94 GB a step
-      (``tests/test_chip_compile.py``; PERF.md section 7).
+    - an eva cache is multi-head too (a ring and chunk summaries, one
+      query a KV head): with a per-step write the compiler updates 14 of
+      32 ring leaves in the fast memory and copies each home whole, 0.94
+      GB a step at EvaByte widths (PERF.md section 6, PR 34). Its tail is
+      its own (:meth:`LlamaBlock._eva_tail_attend`): the segment's rows
+      behind those of the chunk that was open when it began, so that a
+      chunk is pooled from the tail alone, AND the summaries they
+      complete, since a row that completes chunk 127 at position 2047
+      attends it at 2048; the merge wraps round the ring
+      (:func:`_eva_tail_merge`).
 
     The blocked Pallas kernel and the sp-sharded decode step
     (``parallel/spdecode.py``) attend the ONE cache they are handed, so
     their segments write it every step too. Asked while a segment program
     is traced, under its mesh."""
-    if cfg.attn_kind != "kv" or cfg.heads != cfg.kv_heads:
+    if cfg.attn_kind == "latent" or cfg.heads != cfg.kv_heads:
         return False
     if cfg.attn_backend == "blocked":
         return False
     return cfg.attn_backend != "ring" or _active_sp_mesh() is None
+
+
+def _leaf_spans(cfg: LlamaConfig, entry: dict, positions: int) -> dict:
+    """``{leaf: slots}`` for the leaves of cache entry ``entry``: the slots
+    of each that ``positions`` consecutive positions from 0 lie in:
+    ``positions`` itself where a leaf holds one row a token."""
+    spans = cfg.cache_positions(positions) if cfg.attn_kind == "eva" else {}
+    return {name: spans.get(name, positions)
+            for name in entry if name != "index"}
+
+
+def _eva_tail_init(cfg: LlamaConfig, frozen: list, base, steps: int) -> list:
+    """An eva tail segment's tails, a layer, before its scan
+    (:meth:`LlamaBlock._eva_tail_attend` says what they hold). ``k``, ``v``
+    begin with the ring slots of the chunk each row's position ``base[r]``
+    lies in, the one chunk the segment completes whose first rows may lie
+    BEFORE it (every later chunk lies in the tail whole). Fetched here,
+    once a segment: sliced from the ring inside the scan, the pooled rows
+    hand the ring the tail's layout and the compiler transposes every ring
+    at the head of every segment (compiled text for a v5e, PR 34). The
+    rest is zeros; on the chip the compiler hands over uninitialised what
+    it sees the loop write."""
+    chunk = cfg.chunk_size
+    at = list(cfg.cache_slot("k", base // chunk * chunk))
+    k, sk = frozen[0]["k"], frozen[0]["sk"]
+    rest = jnp.zeros((k.shape[0], -(-steps // chunk) * chunk) + k.shape[2:],
+                     k.dtype)
+    none = jnp.zeros((sk.shape[0], cfg.cache_positions(steps)["sk"])
+                     + sk.shape[2:], sk.dtype)
+    return [{"k": jnp.concatenate(
+                 [_eva_chunk_rows(entry["k"], at, chunk), rest], axis=1),
+             "v": jnp.concatenate(
+                 [_eva_chunk_rows(entry["v"], at, chunk), rest], axis=1),
+             "sk": none, "sv": none} for entry in frozen]
+
+
+def _eva_tail_plan(cfg: LlamaConfig, entry: dict, tail: dict, base, j) -> dict:
+    """What every layer of an eva tail segment's step ``j`` reads alike,
+    computed once a step (``entry``, ``tail``: one layer's frozen leaves
+    and tails, for their lengths; ``base``: each row's position when the
+    segment began). With ``t = base[r] + j`` the step's position:
+
+    - ``rows``, ``at``: where the step's K/V go in the ring tail, a row's
+      own place (no lockstep: the tail begins at each row's open chunk);
+    - ``held`` ``[b, ring]``: the frozen ring's slots written before the
+      segment, while no window edge was crossed; ``own`` ``[b, tail]``:
+      the tail rows the segment has written so far, and ``here``: those
+      of t's window; ``before`` ``[b, summaries]``: the frozen summaries
+      of earlier windows complete when the segment began; ``inside``
+      ``[b, tail chunks]``: the summary tail's chunks of earlier windows;
+    - ``chunk`` ``[b, 1, 1, 1, 1]``: which of the ring tail's chunks t
+      lies in; ``lands`` ``[b, tail chunks]``: the slot t completes;
+    - ``stats`` int32 ``[b, 3]``: the keys visible, whether t completes a
+      chunk, whether t comes after a window edge inside the segment."""
+    win, chunk = cfg.window_size, cfg.chunk_size
+    t = base + j
+    ring, n_sum = entry["k"].shape[1], entry["sk"].shape[1]
+    # the position each row of the tail's k / v holds, and the chunk each
+    # slot of its sk / sv is for
+    held_at = (base // chunk * chunk)[:, None] \
+        + jnp.arange(tail["k"].shape[1])[None, :]
+    chunks = cfg.cache_slot("sk", base)[:, None] \
+        + jnp.arange(tail["sk"].shape[1])[None, :]
+    same = base // win == t // win          # no window edge crossed yet
+    held = same[:, None] & (jnp.arange(ring)[None, :]
+                            < cfg.cache_slot("k", base)[:, None])
+    own = (held_at >= base[:, None]) & (held_at <= t[:, None])
+    here = own & (held_at // win == (t // win)[:, None])
+    earlier = cfg.cache_slot("sk", t // win * win)[:, None]
+    before = jnp.arange(n_sum)[None, :] < jnp.minimum(earlier, chunks[:, :1])
+    inside = chunks < earlier
+    ends = t % chunk == chunk - 1
+    return {
+        "rows": jnp.arange(base.shape[0]), "at": base % chunk + j,
+        "held": held, "own": own, "here": here, "before": before,
+        "inside": inside,
+        "chunk": (t // chunk - base // chunk)[:, None, None, None, None],
+        "lands": (chunks == (t // chunk)[:, None]) & ends[:, None],
+        "stats": jnp.stack(
+            [held.sum(-1) + here.sum(-1) + before.sum(-1) + inside.sum(-1),
+             ends, ~same], axis=-1).astype(jnp.int32)}
+
+
+def _eva_tail_merge(cfg: LlamaConfig, full: list, tails: list, base,
+                    steps: int) -> list:
+    """An eva tail segment's ONE write of each layer's cache entry, after
+    its scan: the tail's row of position ``base[r] + j'`` goes to slot
+    ``(base[r] + j') mod window_size`` for each of the segment's steps j'
+    (it wraps where the row crossed a window's edge), and the
+    summary tail's slot m to chunk ``base[r] // chunk_size + m`` where the
+    segment completed that chunk; the others drop (an out-of-range index,
+    as the per-step write drops a step that completes none)."""
+    chunk = cfg.chunk_size
+    rows = jnp.arange(base.shape[0])[:, None]
+    slots = cfg.cache_slot("k", base[:, None] + jnp.arange(steps)[None, :])
+    # where in the ring tail the segment's own rows lie
+    own = ((base % chunk)[:, None]
+           + jnp.arange(steps)[None, :])[:, :, None, None]
+    chunks = cfg.cache_slot("sk", base)[:, None] \
+        + jnp.arange(tails[0]["sk"].shape[1])[None, :]
+    complete = (chunks + 1) * chunk <= base[:, None] + steps
+    at = jnp.where(complete, chunks, full[0]["sk"].shape[1])
+    merged = []
+    for entry, tail in zip(full, tails):
+        with jax.named_scope("kv_write"):
+            ring = {name: entry[name].at[rows, slots].set(
+                        jnp.take_along_axis(tail[name], own, axis=1))
+                    for name in ("k", "v")}
+        with jax.named_scope("eva_summarize"):
+            merged.append({**ring, **{
+                name: entry[name].at[rows, at].set(tail[name])
+                for name in ("sk", "sv")}})
+    return merged
 
 
 def _segment_decode(model: LlamaModel, params, select, first, lp, cache, pos,
@@ -1760,13 +1957,12 @@ def _segment_decode(model: LlamaModel, params, select, first, lp, cache, pos,
                             count_load=cfg.counts_moe_load,
                             count_keys=cfg.counts_eva_keys, **form)
 
-    if segment_keeps_tail(cfg):
+    spans = _leaf_spans(cfg, cache[0], window)
+    # (one merge would write an eva ring shorter than the segment twice:
+    # a cache of a few positions keeps the per-step write)
+    if segment_keeps_tail(cfg) and (cfg.attn_kind != "eva"
+                                    or segment <= spans["k"]):
         return scan(cache, tail_window=window)
-    # the slots of each leaf that positions below ``window`` lie in: the
-    # window itself where a leaf holds one row a token
-    spans = {name: window for name in cache[0] if name != "index"}
-    if cfg.attn_kind == "eva":
-        spans = cfg.cache_positions(window)
     if all(cache[0][name].shape[1] == span for name, span in spans.items()):
         return scan(cache)
     # the window's two copies per segment have a scope of their own,
@@ -1822,9 +2018,11 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
 
     ``count_keys`` (an eva model's engine segments,
     ``cfg.counts_eva_keys``): the emitted tuple gains one member, int32
-    ``[b, 2]`` summed over the steps as the block sows it (``eva_stats``):
-    the keys each row's steps had visible (ring rows and summaries) and
-    the chunk summaries they wrote.
+    ``[b, 3]`` summed over the steps as the block sows it (``eva_stats``):
+    the keys each row's steps had visible (ring rows and summaries), the
+    chunk summaries they wrote, and the steps taken after a window's edge
+    crossed inside the segment (a tail segment's rare branch; 0 from the
+    per-step write).
 
     ``tail_window`` (the engine's plain segments, where
     :func:`segment_keeps_tail`): inside the scan the cache is READ-ONLY,
@@ -1841,23 +2039,45 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
     their own row. Why: a cache the loop writes is prefetched whole,
     updated and written back WHOLE every layer of every step (PERF.md
     section 6, PR 30); a scan of hundreds of steps keeps the per-step
-    write, its tail would be a second cache."""
+    write, its tail would be a second cache.
+
+    An eva cache's tail is two (PR 34), with a write, masks and a merge of
+    their own: the frozen leaves are the whole ring and the summaries of
+    the first ``tail_window`` positions; the scan carries, a layer, a ring
+    tail ``k``, ``v`` that begins with the rows of each row's open chunk
+    (:func:`_eva_tail_init`) and has room behind them for the whole chunks
+    ``decode_steps`` positions reach into, and a summary tail ``sk``,
+    ``sv`` with one slot for every chunk ``decode_steps`` consecutive
+    positions can complete; what every layer of a step reads alike (the
+    four masks, the step's place in the tail, the counter's row) is
+    computed once a step (:func:`_eva_tail_plan`); a step attends frozen
+    ring, ring tail, frozen summaries and summary tail under one softmax
+    and pools the chunk it lies in from one whole chunk of the ring tail
+    (:meth:`LlamaBlock._eva_tail_attend`); after the scan one scatter a
+    leaf wraps the segment's rows round the ring and puts the completed
+    chunks at their slots (:func:`_eva_tail_merge`)."""
     b = first.shape[0]
     has_eos = eos_id >= 0
+    eva = model.cfg.attn_kind == "eva"
     if tail_window is not None:
         full, base = cache, jnp.broadcast_to(start, (b,))
+        spans = _leaf_spans(model.cfg, full[0], tail_window)
         with jax.named_scope("kv_window"):
-            frozen = [{name: jax.lax.slice_in_dim(val, 0, tail_window, axis=1)
+            frozen = [{name: jax.lax.slice_in_dim(val, 0, spans[name], axis=1)
                        for name, val in entry.items() if name != "index"}
                       for entry in full]
-        # zeros here; on the chip the compiler sees that the loop writes
-        # every position and hands it the buffer uninitialised
-        # (AllocateBuffer), whatever the value: _attend reads no position
-        # before its step wrote it
-        cache = ([{name: jnp.zeros((b, decode_steps) + val.shape[2:],
-                                   val.dtype)
-                   for name, val in entry.items()} for entry in frozen],
-                 jnp.int32(0))
+        if eva:
+            with jax.named_scope("eva_summarize"):
+                tails = _eva_tail_init(model.cfg, frozen, base, decode_steps)
+        else:
+            # zeros here; on the chip the compiler sees that the loop
+            # writes every position and hands it the buffer uninitialised
+            # (AllocateBuffer), whatever the value: _attend reads no
+            # position before its step wrote it
+            tails = [{name: jnp.zeros((b, decode_steps) + val.shape[2:],
+                                      val.dtype)
+                      for name, val in entry.items()} for entry in frozen]
+        cache = (tails, jnp.int32(0))
 
     def step(carry, _):
         if count_load:
@@ -1870,8 +2090,13 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
                      else jnp.broadcast_to(rope_pos[None, None], (b, 1)))
         if tail_window is not None:
             tails, j = cache
-            cache = [{**entry, "index": base, "tail": tail, "step": j}
-                     for entry, tail in zip(frozen, tails)]
+            if eva:
+                plan = _eva_tail_plan(model.cfg, frozen[0], tails[0], base, j)
+                cache = [{**entry, "index": base, "tail": tail, "plan": plan}
+                         for entry, tail in zip(frozen, tails)]
+            else:
+                cache = [{**entry, "index": base, "tail": tail, "step": j}
+                         for entry, tail in zip(frozen, tails)]
         if count_load:
             (logits, new_cache), sown = model.apply(
                 params, tok[:, None], positions=positions, cache=cache,
@@ -1908,7 +2133,7 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
         init = (init, (jnp.zeros((b, model.cfg.moe_experts), jnp.int32),
                        jnp.int32(0)))
     elif count_keys:
-        init = (init, jnp.zeros((b, 2), jnp.int32))
+        init = (init, jnp.zeros((b, 3), jnp.int32))
     carry, (toks, lps) = jax.lax.scan(step, init, None, length=decode_steps)
     out = (jnp.transpose(toks), jnp.transpose(lps))  # [b, decode_steps] x2
     if count_load:
@@ -1919,10 +2144,14 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
         out = (*out, seen)
     if tail_window is not None:
         tok, lp, (tails, _), pos, done, keys = carry
-        with jax.named_scope("kv_write"):
-            # the ragged write of a chunk: out-of-range positions drop
-            merged = [_cache_write(entry, tail, base, b, decode_steps)[0]
-                      for entry, tail in zip(full, tails)]
+        if eva:
+            merged = _eva_tail_merge(model.cfg, full, tails, base,
+                                     decode_steps)
+        else:
+            with jax.named_scope("kv_write"):
+                # the ragged write of a chunk: out-of-range positions drop
+                merged = [_cache_write(entry, tail, base, b, decode_steps)[0]
+                          for entry, tail in zip(full, tails)]
         for entry in merged:
             entry["index"] = pos
         carry = (tok, lp, merged, pos, done, keys)
@@ -2426,8 +2655,10 @@ class LlamaServer:
     # bound it (names unchanged: the persistent cache's key hashes the
     # new computation by itself). g5 = PR 30: the two plain segment
     # programs write a segment-long tail and merge it once
-    # (_scan_decode, tail_window); same signature, same carry.
-    _AOT_GEN = "g5"
+    # (_scan_decode, tail_window); same signature, same carry. g6 = PR
+    # 34: so do an eva model's (a ring tail and a summary tail,
+    # _eva_tail_attend), and their counter has a third column.
+    _AOT_GEN = "g6"
 
     @classmethod
     def aot_prefix(cls) -> str:

@@ -228,7 +228,8 @@ class ContinuousBatcher:
         # here (/metrics handler.moe)
         self.moe_stats = MoeLoadStats()
         # an eva-attention model's segment programs return, a row, the keys
-        # its steps had visible and the summaries they wrote
+        # its steps had visible, the summaries they wrote and the steps it
+        # took past a window edge inside the segment
         # (llama._scan_decode count_keys; /metrics handler.eva)
         self.eva_stats = EvaKeyStats()
         self._counts_eva = bool(getattr(cfg, "counts_eva_keys", False))

@@ -169,26 +169,34 @@ class EvaKeyStats:
     attended, beside the rows' mean context the compression the traffic
     really got. ``chunks_written``: the summaries those steps wrote; a row
     writes one every ``chunk_size`` steps, so ``chunks_written x chunk_size``
-    is ``row_steps`` to within one partial chunk a booked request."""
+    is ``row_steps`` to within one partial chunk a booked request.
+    ``edge_row_steps``: the steps a row took AFTER a window's edge crossed
+    inside their segment, the rare branch of a segment that keeps its ring
+    read-only (``llama.LlamaBlock._eva_tail_attend``): there the frozen
+    ring is masked whole and the row attends its tail alone."""
 
     row_steps: int = 0
     keys_attended: int = 0
     chunks_written: int = 0
+    edge_row_steps: int = 0
     _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
 
     def record_segment(self, rows, *, steps: int) -> None:
-        """One fetched segment. ``rows``: int array [booked rows, 2], each
-        row's (keys visible, chunks written) summed over the steps."""
+        """One fetched segment. ``rows``: int array [booked rows, 3], each
+        row's (keys visible, chunks written, steps after a window edge
+        inside the segment) summed over the steps."""
         with self._lock:
             self.row_steps += len(rows) * steps
             self.keys_attended += int(rows[:, 0].sum())
             self.chunks_written += int(rows[:, 1].sum())
+            self.edge_row_steps += int(rows[:, 2].sum())
 
     def report(self) -> dict:
         with self._lock:
             return {"row_steps": self.row_steps,
                     "keys_attended": self.keys_attended,
-                    "chunks_written": self.chunks_written}
+                    "chunks_written": self.chunks_written,
+                    "edge_row_steps": self.edge_row_steps}
 
 
 @dataclass

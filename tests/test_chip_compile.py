@@ -376,40 +376,64 @@ EVABYTE = dict(vocab_size=320, hidden=HIDDEN, heads=H, kv_heads=H, mlp=11008,
 
 def test_an_eva_segment_compiles_and_copies_no_ring(v5e):
     """The engine's window-bucketed segment at ``evabyte6b.long-decode``'s
-    own shape key (4 slots, cache 8192, the 4096 bucket, 16 layers), a
-    4-step scan. ~20 s.
+    own shape key (4 slots, cache 8192, the 4096 bucket, 16 layers, 16
+    steps: the cell's program itself; at 4 steps the compiler parks one
+    summary leaf in the fast memory and evicts it inside the loop, 8 MB).
+    ~20 s.
 
-    It compiles for the chip, and the pooling and the summary write carry
-    ``eva_summarize`` (what ``eva_summarize_ms`` and ``eva_cache_hbm_pct``
-    gather a trace's operations by), beside the llama block's scopes.
+    It compiles for the chip, and every scope ``benchmark/families/
+    evabyte.py`` gathers a trace's operations by is still the op_name of
+    some operation: the pooling and the summary writes carry
+    ``eva_summarize``, beside the llama block's scopes.
 
-    What the loop writes with a cache leaf's whole shape: two ring scatters
-    and two summary scatters a layer, and the ``copy-done`` of the ring
-    leaves the compiler updated in the fast memory and takes home whole
-    (14 of 32 at this depth, 0.94 GB a step: PR 30's finding on a kind that
-    keeps its per-step write, PERF.md section 7). NOT a ``copy``: with the
-    chunk's rows fetched by ONE gather (a vmapped ``dynamic_slice``) the
-    compiler copied every ring into a layout of the gather's liking, 32
-    copies of 67 MB a step; ``_eva_attend`` slices a row at a time."""
+    The segment keeps ring and summaries READ-ONLY inside its scan (PR 34,
+    ``llama.LlamaBlock._eva_tail_attend``): nothing in the loop produces an
+    array of a cache leaf's whole shape but the loop's own tuple and the
+    read prefetch, and after the loop ONE scatter a leaf merges the tails,
+    four a layer: two ring leaves under ``kv_write``, two summary leaves
+    under ``eva_summarize``. On the parent (PR 33, a scatter into ring and
+    summaries every step) this trips on 64 scatter fusions a step and the
+    ``copy-done`` of the 14 ring leaves of 32 the compiler updated in the
+    fast memory and took home whole, 67 MB each, 0.94 GB a step.
+
+    Neither does the program copy a ring into another layout, in the loop
+    or at its head, which it did twice on the way here: with a chunk's rows
+    fetched by ONE gather (PR 33: 32 copies of 67 MB a step; a slice a row
+    does not), and with those slices taken from the frozen ring INSIDE the
+    scan, where they are pooled beside the tail's rows and hand the ring
+    the tail's layout (batch next to the lanes): 32 transposing copies at
+    the head of every segment. ``_eva_tail_init`` fetches the open chunk
+    once, before the scan, a slice a row.
+
+    The no-write assertion also holds two forms of the merge and of that
+    fetch apart that read shorter and compile worse: scattering the WHOLE
+    ring tail (rows of other positions dropped by an out-of-range slot),
+    or fetching the open chunk as one gather over the ring seen as whole
+    chunks, makes the compiler park a ring in the fast memory and evict it
+    inside the loop, 67 MB written home a step (PR 34). The merge gathers
+    the segment's own rows from the (small) tail first."""
+    from benchmark.families import evabyte
+
     layers, window, slots = 16, 4096, 4
-    text = _decode_segment_text(v5e, steps=4, layers=layers, window=window,
+    text = _decode_segment_text(v5e, steps=16, layers=layers, window=window,
                                 cache_len=8192, rows=slots, **EVABYTE)
     found = set()
     for op_name in re.findall(r'op_name="([^"]*)"', text):
         found.update(op_name.split("/"))
     assert {"embed", "qkv_proj", "kv_write", "attend", "eva_summarize",
             "o_proj", "mlp", "lm_head", "sample", "kv_window"} <= found
-    writes = cache_writes_in_loops(
-        text, {(slots, 2048, H, D), (slots, window // 16, H, D)})
-    by_opcode = {}
-    for _, opcode, op_name in writes:
-        by_opcode.setdefault(opcode, []).append(op_name)
-    assert set(by_opcode) <= {"fusion", "copy-done"}, sorted(by_opcode)
-    scatters = by_opcode["fusion"]
-    assert sum(n.endswith("kv_write/scatter") for n in scatters) == 2 * layers
-    assert sum(n.endswith("eva_summarize/scatter")
-               for n in scatters) == 2 * layers
-    assert len(by_opcode.get("copy-done", [])) <= 2 * layers
+    assert set(evabyte.SCOPES) <= found
+    leaves = {(slots, 2048, H, D), (slots, window // 16, H, D),
+              (slots, 8192 // 16, H, D)}
+    assert cache_writes_in_loops(text, leaves) == []
+    for scope, shape in (("kv_write", "2048"), ("eva_summarize", "512")):
+        assert len(re.findall(
+            r' = bf16\[%d,%s,%d,%d\]\S* fusion\([^\n]*'
+            r'op_name="jit\(seg\)/%s/scatter"' % (slots, shape, H, D, scope),
+            text)) == 2 * layers, scope
+    # every ring keeps the layout it arrives in, positions major
+    assert not re.findall(r"bf16\[%d,2048,%d,%d\]\{3,0,2,1" % (slots, H, D),
+                          text)
 
 
 @pytest.mark.parametrize("block, in_hbm", [(128, False), (512, True)])

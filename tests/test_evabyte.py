@@ -203,8 +203,10 @@ def test_the_continuous_engine_is_solo_generation_token_for_token(server):
     """Ragged joiners pack into the B-slot cache (ring and summary leaves
     of their own lengths) and decode in 16-step segments through the
     window-bucketed programs, slots reused by shorter and longer requests
-    in turn: every request's tokens are what it gets alone, and the
-    counters the segment programs return add up."""
+    in turn: every request's tokens are what it gets alone (solo
+    generation writes ring and summaries every step; the engine's segments
+    keep them read-only and merge their tails, PR 34), and the counters the
+    segment programs return add up."""
     eng = ContinuousBatcher(server, slots=2, segment=16)
     rng = np.random.default_rng(5)
     lens = [70, 9, 33, 4, 62, 31]
@@ -246,6 +248,17 @@ def test_the_continuous_engine_is_solo_generation_token_for_token(server):
     assert after["keys_attended"] - before["keys_attended"] == sum(
         (t % WIN + 1) + (t // WIN) * (WIN // CHUNK) for t in range(40, 56))
     assert after["chunks_written"] - before["chunks_written"] == 4
+    assert after["edge_row_steps"] == before["edge_row_steps"]
+    # and from position 20: the segment crosses into the second window at
+    # 32, so its last four steps attend no frozen ring row
+    eng.generate(rows[0][:20], max_new_tokens=16)
+    edge = eng.eva_stats.report()
+    assert edge["edge_row_steps"] - after["edge_row_steps"] == 4
+    assert edge["keys_attended"] - after["keys_attended"] == sum(
+        (t % WIN + 1) + (t // WIN) * (WIN // CHUNK) for t in range(20, 36))
+    # the engine's segments kept their tails; solo generation wrote every step
+    assert llama.segment_keeps_tail(server.model.cfg)
+    assert edge["chunks_written"] * CHUNK == edge["row_steps"]
 
 
 def test_a_reused_slot_reads_nothing_of_the_last_tenant(adapter, params):
@@ -299,7 +312,7 @@ def test_the_description_is_what_the_constructors_read(adapter):
     assert cfg.cache_positions(20) == {"k": 20, "v": 20, "sk": 5, "sv": 5}
     assert (cfg.cache_slot("k", 70), cfg.cache_slot("sv", 70)) == (6, 17)
     assert cfg.counts_eva_keys and not cfg.counts_moe_load
-    assert not llama.segment_keeps_tail(cfg)
+    assert llama.segment_keeps_tail(cfg)       # a ring and a summary tail
     cache = llama.init_decode_cache(cfg, 3, 64)
     assert {k: v.shape for k, v in cache[0].items() if k != "index"} == {
         "k": (3, 32, 4, 16), "v": (3, 32, 4, 16),

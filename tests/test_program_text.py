@@ -33,6 +33,12 @@ solo prefills of the smallest and largest prompt bucket its mix reaches
 segment and the 4096 bucket's. They came with g5 and move with the block's
 eva path alone.
 
+Generation g6 (PR 34) moved TWO of those four and nothing else: the eva
+model's full-window and 4096-bucket segment programs keep ring and
+summaries read-only inside their scan (a ring tail and a summary tail,
+``llama.LlamaBlock._eva_tail_attend``). The two solo prefills and every
+llama, ``mistral7b`` and ``deepseek7b`` hash are what they were.
+
 A routed-FFN model's programs have no golden text: PR 28 gave its segment
 programs a second counter, and on a TPU backend their small calls take a
 Pallas kernel (``ops/grouped_experts.py``), which changes their cache keys
@@ -73,7 +79,7 @@ def text_hash(fn, *args) -> str:
 @pytest.mark.parametrize("model,quant,kv_quant", list(GOLDEN))
 def test_a_llama_program_lowers_to_the_text_its_generation_was_taken_at(
         model, quant, kv_quant):
-    assert LlamaServer._AOT_GEN == "g5", "new generation: take the hashes anew"
+    assert LlamaServer._AOT_GEN == "g6", "new generation: take the hashes anew"
     extra = dict(HF_TOY) if model == "llama-hf" else {}
     if kv_quant:
         extra["kv_quant"] = kv_quant
@@ -105,7 +111,7 @@ CELL_GOLDEN = {
 
 @pytest.mark.parametrize("name", list(CELL_GOLDEN))
 def test_an_accepted_cells_programs_keep_their_text_at_the_cells_shapes(name):
-    assert LlamaServer._AOT_GEN == "g5", "new generation: take the hashes anew"
+    assert LlamaServer._AOT_GEN == "g6", "new generation: take the hashes anew"
     window, golden = CELL_GOLDEN[name]
     config = json.loads((Path(__file__).parents[1] / "benchmark" / "configs"
                          / f"{name}.json").read_text())
@@ -132,7 +138,7 @@ def test_an_accepted_cells_programs_keep_their_text_at_the_cells_shapes(name):
 # (solo prefill of the 2048 bucket, of the 6144 bucket (three whole windows:
 # ``LlamaConfig.prompt_bucket``), the full-window segment, the 4096 bucket's
 # segment) of ``evabyte6b`` at 4 slots of 8192
-EVA_GOLDEN = ("14d510489925", "601575a18f6e", "1f8f4f0d7a55", "47f60e45b3cb")
+EVA_GOLDEN = ("14d510489925", "601575a18f6e", "b64dddbb4f8b", "ee9e9fd9fc61")
 
 
 def eva_hashes() -> tuple:
@@ -162,7 +168,7 @@ def eva_hashes() -> tuple:
 
 
 def test_the_eva_programs_keep_their_text_at_the_cells_shapes():
-    assert LlamaServer._AOT_GEN == "g5", "new generation: take the hashes anew"
+    assert LlamaServer._AOT_GEN == "g6", "new generation: take the hashes anew"
     assert eva_hashes() == EVA_GOLDEN
 
 

@@ -14,6 +14,13 @@ reference here: ``segment_keeps_tail`` patched to False gives the parent's
 programs, patched to True the tail whatever the shapes (the ``gqa``
 layout: the tail's arithmetic does not depend on the rule that picks it).
 
+An eva cache (a ring beside chunk summaries, PR 34) keeps a tail of its
+own: the segment's rows AND the summaries they complete, a merge that wraps
+round the ring (``LlamaBlock._eva_tail_attend``, ``llama._eva_tail_merge``).
+Its cases are at the end: the toy twin of ``tests/test_evabyte.py`` (window
+32) with chunks of 4, where a 16-step segment completes several, and of 16,
+where it completes one, rows placed on every side of a window's edge.
+
 CPU, float32. The two forms are the same function with the softmax's sum
 taken in another order (cache keys, then tail keys), and layer 2's K/V is
 computed from layer 1's attention, so logprobs, cache and the next step's
@@ -290,6 +297,8 @@ def test_which_segments_keep_a_tail():
     assert not keeps(attn_backend="blocked")
     assert not keeps(attn_kind="latent", qk_nope=16, qk_rope=8, v_head=16,
                      kv_lora_rank=32)
+    # an eva cache is multi-head by construction and takes its own tail
+    assert keeps(attn_kind="eva", window_size=32, chunk_size=4)
 
 
 def test_a_tp2_engine_keeps_the_tail_sharded_and_serves_the_same_tokens(
@@ -330,3 +339,235 @@ def test_a_tp2_engine_keeps_the_tail_sharded_and_serves_the_same_tokens(
     assert 0 < stats["mesh"]["kv_bytes_per_device"] \
         <= 0.55 * stats["mesh"]["kv_bytes_replicated"]
     assert len(stats["decode_window"]["buckets"]) >= 2
+
+
+# -- an eva cache: a ring tail and a summary tail (PR 34) ----------------------
+
+EVA_WIN, EVA_CACHE, EVA_BUCKET, EVA_SB = 32, 256, 128, 64
+# where each row's first segment begins: a window's edge falls at its first
+# step (row 0), inside it (row 1: position 64 at step 8), at its last step
+# (row 2: position 64 at step 15) and not at all (row 3)
+EVA_BASES = (32, 56, 49, 35)
+EVA_PROGRAMS = {"full": EVA_CACHE, "windowed": EVA_BUCKET}
+
+
+class ServedEva(Served):
+    """The toy twin of ``benchmark/configs/rehearsal-eva.json`` (window 32,
+    4 heads, 2 layers, two prediction heads) at a chunk size."""
+
+    def __init__(self, chunk):
+        import json
+        from pathlib import Path
+
+        from benchmark import families
+
+        config = json.loads((Path(__file__).resolve().parents[1] / "benchmark"
+                             / "configs" / "rehearsal-eva.json").read_text())
+        dims = families.of(config).dims_of(config)
+        assert dims["window_size"] == EVA_WIN
+        self.adapter = registry.get("evabyte").build(
+            dtype="float32", quant="int8",
+            extra={**dims, "chunk_size": chunk})
+        self.chunk = chunk
+        self.params = self.adapter.init_params(seed=0)
+        self.servers = {}
+
+    def seg(self, monkeypatch, tail: bool, window: int):
+        monkeypatch.setattr(llama, "segment_keeps_tail", lambda cfg: tail)
+        server = self.servers.setdefault(
+            tail, self.adapter.make_server(self.params))
+        if window == EVA_CACHE:
+            return server._stream_fns(B, EVA_SB, EVA_CACHE, SEGMENT)[1]
+        return server._windowed_seg_fn(B, EVA_CACHE, window, SEGMENT)
+
+    def carry(self, eos=None, bases=EVA_BASES):
+        server = self.servers.setdefault(
+            True, self.adapter.make_server(self.params))
+        t, k, p, rng, eos_id = server._knob_operands(0.0, None, None, 0, eos,
+                                                     b=B)
+        prefill = server._stream_fns(B, EVA_SB, EVA_CACHE, SEGMENT)[0]
+        prompt = jax.random.randint(jax.random.PRNGKey(1), (B, EVA_SB), 1, 300)
+        carry = prefill(self.params, prompt, jnp.asarray(bases, jnp.int32),
+                        t, k, p, rng, eos_id)
+        return carry, (t, k, p), eos_id
+
+
+@pytest.fixture(scope="module", params=[4, 16],
+                ids=["chunks_of_4", "chunks_of_16"])
+def eva(request):
+    return ServedEva(request.param)
+
+
+def edge_steps(base, segments):
+    """By hand: the steps of each of ``segments`` segments from ``base``
+    that come after a window's edge crossed inside that segment."""
+    out = []
+    for n in range(segments):
+        start = base + n * SEGMENT
+        out.append(sum(t // EVA_WIN != start // EVA_WIN
+                       for t in range(start, start + SEGMENT)))
+    return out
+
+
+@pytest.mark.parametrize("program", list(EVA_PROGRAMS))
+def test_an_eva_tail_segment_serves_what_the_per_step_write_served(
+        eva, program, monkeypatch):
+    """Three 16-step segments from rows on every side of a window's edge
+    (a row that completes a window's last chunk attends its summary at the
+    very next step, inside the same segment; a ring tail wraps at the
+    merge): the per-step write's tokens exactly; logprobs, ring and
+    summaries within 1e-5; the same keys visible and the same chunks
+    written, a row a segment; the edge column by hand."""
+    window = EVA_PROGRAMS[program]
+    carry, knobs, eos_id = eva.carry()
+    want, want_carry = eva.run(eva.seg(monkeypatch, False, window),
+                               carry, knobs, eos_id, segments=3)
+    got, got_carry = eva.run(eva.seg(monkeypatch, True, window),
+                             carry, knobs, eos_id, segments=3)
+    for n, (g, w) in enumerate(zip(got, want)):
+        np.testing.assert_array_equal(g[0], w[0])               # tokens
+        np.testing.assert_allclose(g[1], w[1], atol=TOL, rtol=0)  # logprobs
+        np.testing.assert_array_equal(g[2][:, :2], w[2][:, :2])
+        assert (np.asarray(w[2])[:, 2] == 0).all()
+        np.testing.assert_array_equal(
+            g[2][:, 2], [edge_steps(b, 3)[n] for b in EVA_BASES])
+        starts = np.asarray(EVA_BASES) + n * SEGMENT
+        np.testing.assert_array_equal(     # a chunk's end a row, by hand
+            g[2][:, 1], [sum(t % eva.chunk == eva.chunk - 1
+                             for t in range(b, b + SEGMENT)) for b in starts])
+    assert edge_steps(EVA_BASES[1], 1) == [8] \
+        and edge_steps(EVA_BASES[2], 1) == [1] \
+        and edge_steps(EVA_BASES[0], 3) == [0, 0, 0]
+    end = np.asarray(EVA_BASES) + 3 * SEGMENT
+    np.testing.assert_array_equal(got_carry[3], end)
+    for entry in got_carry[2]:
+        np.testing.assert_array_equal(entry["index"], end)
+    assert_cache_close(got_carry[2], want_carry[2])
+    model = eva.servers[True].model
+
+    def logits(c):
+        return model.apply(eva.params, c[0][:, None],
+                           positions=c[3][:, None], cache=c[2])[0]
+
+    np.testing.assert_allclose(logits(got_carry), logits(want_carry),
+                               atol=TOL, rtol=0)
+
+
+@pytest.mark.parametrize("program", list(EVA_PROGRAMS))
+def test_an_eva_tail_reads_nothing_of_a_done_row_or_of_the_last_tenant(
+        eva, program, monkeypatch):
+    """Row 1 meets its eos a few steps into the segment and row 2 enters it
+    done: both step on as garbage into their OWN row's tails, and every
+    slot the masks hide (ring slots at and past a row's place in its
+    window, summaries from its open chunk on: what a longer tenant of the
+    slot left) holds 1e4. Rows 0 and 3 serve, bit for bit, the tokens and
+    logprobs of a run over a clean cache in which nobody stopped, and row
+    1 too up to its eos."""
+    window = EVA_PROGRAMS[program]
+    seg = eva.seg(monkeypatch, True, window)
+    carry, knobs, no_eos = eva.carry()
+    (free,), _ = eva.run(seg, carry, knobs, no_eos)
+    row = np.asarray(free[0][1])
+    stop_at = next(j for j in range(2, SEGMENT - 2) if row[j] not in row[:j])
+    eos_tok = int(row[stop_at])
+    eos_id = jnp.asarray([-1, eos_tok, 7, -1], jnp.int32)
+    first, lp, cache, pos, done, keys = carry
+    bases = np.asarray(EVA_BASES)
+    hidden = {"k": np.arange(EVA_WIN)[None] >= (bases % EVA_WIN)[:, None],
+              "sk": np.arange(EVA_CACHE // eva.chunk)[None]
+              >= (bases // eva.chunk)[:, None]}
+    hidden.update(v=hidden["k"], sv=hidden["sk"])
+    dirty = [{name: (val if name == "index" else jnp.where(
+                  hidden[name][:, :, None, None], 1e4, val))
+              for name, val in entry.items()} for entry in cache]
+    stopped_in = (first, lp, dirty, pos, done.at[2].set(True), keys)
+    (out,), out_carry = eva.run(seg, stopped_in, knobs, eos_id)
+    toks, lps = np.asarray(out[0]), np.asarray(out[1])
+    np.testing.assert_array_equal(out_carry[4], [False, True, True, False])
+    for r in (0, 3):
+        np.testing.assert_array_equal(toks[r], free[0][r])
+        np.testing.assert_array_equal(lps[r], free[1][r])
+        np.testing.assert_array_equal(out[2][r], free[2][r])
+    upto = slice(0, stop_at + 1)
+    np.testing.assert_array_equal(toks[1, upto], free[0][1, upto])
+    assert (toks[1, stop_at + 1:] == eos_tok).all()
+    assert (toks[2, 1:] == 7).all() and (lps[2, 1:] == 0).all()
+
+
+def test_an_eva_step_reads_no_tail_slot_before_its_step_wrote_it(eva):
+    """On the chip the scan's tails arrive uninitialised where the loop
+    writes them (PR 30), and 0 x NaN is NaN. Steps 0-9 of a segment, then
+    step 10 over the tails they left and over the same tails with every
+    ring-tail row of a position from ``base + 10`` on and every summary
+    slot no step has completed yet poisoned with NaN: the same logits bit
+    for bit, finite; the step wrote its own ring-tail row and the summary
+    its rows completed, and nothing else. Row 1 is past the edge it crossed
+    at step 8 and attends the summary tail."""
+    cfg = eva.adapter.config
+    chunk = eva.chunk
+    model = eva.adapter.make_server(eva.params).model
+    carry, _, _ = eva.carry()
+    first, _, cache, pos, _, _ = carry
+    tails = llama._eva_tail_init(cfg, cache, pos, SEGMENT)
+    n_sum = tails[0]["sk"].shape[1]
+    assert n_sum == -(-SEGMENT // chunk)
+    assert tails[0]["k"].shape[1] == (1 + n_sum) * chunk
+
+    def step(tails, j, tok):
+        plan = llama._eva_tail_plan(cfg, cache[0], tails[0], pos, jnp.int32(j))
+        entries = [{**entry, "index": pos, "tail": t, "plan": plan}
+                   for entry, t in zip(cache, tails)]
+        return model.apply(eva.params, tok[:, None],
+                           positions=(pos + j)[:, None], cache=entries)
+
+    tok, j = first, 10
+    for i in range(j):
+        logits, tails = step(tails, i, tok)
+        tok = jnp.argmax(logits[:, 0, :cfg.vocab_size], axis=-1).astype(
+            first.dtype)
+    bases = np.asarray(EVA_BASES)
+    # the position each ring-tail row holds, and the last position of the
+    # chunk each summary slot is for
+    held_at = (bases // chunk * chunk)[:, None] \
+        + np.arange(tails[0]["k"].shape[1])[None]
+    ends = (bases[:, None] // chunk + np.arange(n_sum)[None] + 1) * chunk - 1
+    unwritten = {"k": held_at >= bases[:, None] + j,
+                 "sk": ends >= bases[:, None] + j}
+    unwritten.update(v=unwritten["k"], sv=unwritten["sk"])
+    assert unwritten["sk"].any()
+    poisoned = [{name: jnp.where(unwritten[name][:, :, None, None], jnp.nan,
+                                 val) for name, val in t.items()}
+                for t in tails]
+    clean, clean_tails = step(tails, j, tok)
+    dirty, dirty_tails = step(poisoned, j, tok)
+    assert np.isfinite(np.asarray(dirty)).all()
+    np.testing.assert_array_equal(clean, dirty)
+    mine = held_at == bases[:, None] + j       # the row step j writes
+    lands = ends == bases[:, None] + j         # the chunk step j completes
+    for a, b, before in zip(clean_tails, dirty_tails, poisoned):
+        for wrote, names in ((mine, ("k", "v")), (lands, ("sk", "sv"))):
+            for name in names:
+                np.testing.assert_array_equal(np.asarray(a[name])[wrote],
+                                              np.asarray(b[name])[wrote])
+                assert np.isfinite(np.asarray(b[name])[wrote]).all()
+                np.testing.assert_array_equal(np.asarray(b[name])[~wrote],
+                                              np.asarray(before[name])[~wrote])
+
+
+def test_an_eva_ring_shorter_than_the_segment_keeps_the_per_step_write(
+        monkeypatch):
+    """One merge would write a ring of 8 slots twice over from a 16-step
+    tail (a scatter with duplicate slots): ``_segment_decode`` gives such a
+    cache, which only a toy has, the per-step program whatever the rule
+    says."""
+    eva = ServedEva(4)
+
+    def text(tail):
+        monkeypatch.setattr(llama, "segment_keeps_tail", lambda cfg: tail)
+        server = eva.adapter.make_server(eva.params)
+        key = ("stream", B, 8, 8, SEGMENT)
+        seg_ops = jax.eval_shape(lambda: server._aot_examples(key))[1]
+        return server._stream_fns(*key[1:])[1].lower(
+            eva.params, *seg_ops).as_text()
+
+    assert text(True) == text(False)
