@@ -77,9 +77,26 @@ def _rope_scaling_from_hf(hf_cfg: dict) -> tuple | None:
         return ("llama3", float(rs["factor"]),
                 float(rs["low_freq_factor"]), float(rs["high_freq_factor"]),
                 float(rs["original_max_position_embeddings"]))
+    if kind == "yarn" and hf_cfg.get("model_type") == "deepseek_v32":
+        # the latent kind's YaRN: the frequency blend and the softmax
+        # scale's mscale squared (llama.LlamaConfig.attn_scale_mult); cos
+        # and sin stay unscaled, which is the published rule only where
+        # mscale / mscale_all_dim is 1. A llama checkpoint's yarn scales
+        # cos and sin instead and stays refused
+        mscale = float(rs.get("mscale", 1))
+        if float(rs.get("mscale_all_dim", 0)) != mscale:
+            raise ValueError(
+                "unsupported HF config field: rope_scaling mscale "
+                f"{rs.get('mscale')!r} differs from mscale_all_dim "
+                f"{rs.get('mscale_all_dim')!r} (cos and sin would be scaled "
+                "by their ratio, which is not implemented)")
+        return ("yarn", float(rs["factor"]),
+                float(rs["original_max_position_embeddings"]),
+                float(rs.get("beta_fast", 32)), float(rs.get("beta_slow", 1)),
+                mscale)
     raise ValueError(
         f"unsupported HF config field: rope_scaling type {kind!r} "
-        "(supported: default, linear, llama3)")
+        "(supported: default, linear, llama3; yarn for deepseek_v32)")
 
 
 def _check_supported_hf_config(hf_cfg: dict) -> None:

@@ -133,6 +133,34 @@ class LlamaConfig:
     pred_heads: int = 1
     # RMSNorm multiplies by (1 + gain) and its gain starts at zero
     norm_unit_offset: bool = False
+    # -- the latent kind as DeepSeek-V3.2 publishes it. ``q_lora_rank`` > 0:
+    # the query is compressed (``q_a_proj``, ``q_a_norm``, ``q_b_proj`` in
+    # place of ``q_proj``). ``index_topk`` > 0: DeepSeek Sparse Attention. A
+    # lightning indexer (``index_heads`` heads of ``index_head_dim``, fed
+    # by the compressed query) scores every cached position against one
+    # indexer key a token, the third cache leaf ``kidx``; a query attends
+    # only the ``index_topk`` positions of largest score (all of them while
+    # its context is shorter), chosen exactly, ties to the lowest position:
+    # the key set is chosen by CONTENT, so no holder that cuts a cache by
+    # position alone can take it (:func:`require_row_a_token`).
+    q_lora_rank: int = 0
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # Group-limited routing (DeepSeek-V3's ``n_group`` / ``topk_group``):
+    # the experts lie in ``moe_n_group`` groups of consecutive ids, a
+    # group's score is the sum of its two largest choice scores, and the
+    # top-k is taken inside the ``moe_topk_group`` best groups.
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    # A chip's share of a layer's routed experts: ``moe_experts_held`` > 0
+    # holds the stacks of experts ``moe_first_expert`` .. + held - 1 only.
+    # Router, bias, groups and top-k stay ``moe_experts`` wide and the
+    # weights are normalised over all picks; what the absent experts would
+    # add is computed by nobody here (the exchange between chips is not
+    # written: PERF.md section 7).
+    moe_experts_held: int = 0
+    moe_first_expert: int = 0
 
     def __post_init__(self):
         if self.attn_kind not in ("kv", "latent", "eva"):
@@ -176,6 +204,22 @@ class LlamaConfig:
                 raise NotImplementedError(
                     f"attn_backend={self.attn_backend!r} attends per-head "
                     "K/V; latent attention runs the dense backend")
+        if self.index_topk:
+            if self.attn_kind != "latent" or not self.q_lora_rank \
+                    or min(self.index_heads, self.index_head_dim) <= 0 \
+                    or not self.qk_rope <= self.index_head_dim:
+                raise ValueError(
+                    "sparse attention (index_topk) needs the latent kind "
+                    "with q_lora_rank, index_heads and index_head_dim >= "
+                    "qk_rope")
+        if self.rope_scaling and self.rope_scaling[0] == "yarn" \
+                and not self.index_topk:
+            # only the sparse paths multiply the softmax scale by
+            # attn_scale_mult: elsewhere prefill and decode would disagree
+            raise NotImplementedError(
+                "yarn rope scaling is served only under sparse attention "
+                "(the latent kind with index_topk): no other attention "
+                "path applies its mscale to the softmax scale")
         if self.ffn_kind == "routed":
             if self.moe_experts < self.moe_top_k or self.moe_top_k < 1 \
                     or self.moe_intermediate <= 0:
@@ -186,6 +230,17 @@ class LlamaConfig:
                 raise ValueError(
                     f"unknown scoring_func {self.scoring_func!r}; "
                     "supported: softmax, sigmoid")
+            if self.moe_experts % self.moe_n_group \
+                    or not 1 <= self.moe_topk_group <= self.moe_n_group:
+                raise ValueError(
+                    "group-limited routing needs moe_n_group to divide "
+                    "moe_experts and 1 <= moe_topk_group <= moe_n_group")
+            if self.moe_experts_held < 0 or self.moe_first_expert < 0 \
+                    or self.moe_first_expert + self.moe_experts_held \
+                    > self.moe_experts:
+                raise ValueError(
+                    "moe_first_expert .. + moe_experts_held must lie inside "
+                    "the moe_experts routed experts")
 
     @property
     def head_dim(self) -> int:
@@ -206,7 +261,11 @@ class LlamaConfig:
         iterates a cache entry's leaves -- slicing, copying, window
         buckets, the engine's pack -- never asks which kind it holds."""
         if self.attn_kind == "latent":
-            return {"ckv": (1, self.kv_lora_rank), "kpe": (1, self.qk_rope)}
+            row = {"ckv": (1, self.kv_lora_rank), "kpe": (1, self.qk_rope)}
+            if self.index_topk:
+                # the indexer's key of the token, on the same position axis
+                row["kidx"] = (1, self.index_head_dim)
+            return row
         row = (self.kv_heads, self.head_dim)
         if self.attn_kind == "eva":
             # the ring, then the chunk summaries: ``cache_positions`` says
@@ -242,7 +301,30 @@ class LlamaConfig:
         above it would cost four."""
         if self.attn_kind == "eva" and s > self.window_size:
             return -(-s // self.window_size) * self.window_size
+        if self.index_topk and s > DSA_KEY_BLOCK:
+            # a sparse prefill runs one body a block of keys (its queries
+            # score the keys up to their own block's end): whole blocks
+            return -(-s // DSA_KEY_BLOCK) * DSA_KEY_BLOCK
         return _next_bucket(s, lo)
+
+    @property
+    def moe_held(self) -> tuple:
+        """``(first, count)``: the routed experts whose stacks this chip
+        holds."""
+        if self.moe_experts_held:
+            return self.moe_first_expert, self.moe_experts_held
+        return 0, self.moe_experts
+
+    @property
+    def attn_scale_mult(self) -> float:
+        """What YaRN multiplies the softmax scale by: ``mscale`` squared,
+        ``mscale = 0.1 x mscale x ln(factor) + 1`` (DeepSeek's inference
+        code), 1 without it."""
+        if self.rope_scaling and self.rope_scaling[0] == "yarn":
+            factor, mscale = float(self.rope_scaling[1]), \
+                float(self.rope_scaling[5])
+            return (0.1 * mscale * math.log(factor) + 1.0) ** 2
+        return 1.0
 
     @property
     def counts_moe_load(self) -> bool:
@@ -256,6 +338,12 @@ class LlamaConfig:
         its steps had visible and the chunk summaries they wrote
         (``handler.eva``)."""
         return self.attn_kind == "eva"
+
+    @property
+    def counts_dsa_keys(self) -> bool:
+        """Whether the engine's segment programs return, a row, the keys
+        its steps selected and the keys they chose from (``handler.dsa``)."""
+        return bool(self.index_topk)
 
 
 def require_kv_cache(cfg: LlamaConfig, holder: str) -> None:
@@ -273,13 +361,21 @@ def require_row_a_token(cfg: LlamaConfig, holder: str) -> None:
     """Raise for a holder that cuts, joins or extends a cache along ONE
     position axis (a prefix carried over, a chunk continued, a draft
     verified and rolled back): an eva cache has a ring that forgets and
-    summaries that pool, so a span of positions is no slice of it."""
+    summaries that pool, so a span of positions is no slice of it; and a
+    sparse latent cache is attended through a selection that only the
+    whole-prompt prefill and the one-token step compute."""
     if getattr(cfg, "attn_kind", "kv") == "eva":
         raise NotImplementedError(
             f"{holder} keeps one cache row a token on one position axis "
             "and cannot take the eva cache layout (a ring of "
             f"{cfg.window_size} beside one summary for every "
             f"{cfg.chunk_size} positions; PERF.md section 7)")
+    if getattr(cfg, "index_topk", 0):
+        raise NotImplementedError(
+            f"{holder} attends every cached position a query may see and "
+            "cannot take sparse attention, whose indexer chooses the "
+            f"{cfg.index_topk} positions a query attends by content from a "
+            "third cache leaf (kidx; PERF.md section 7)")
 
 
 LLAMA3_8B = LlamaConfig()
@@ -397,16 +493,35 @@ class QKernel(nn.Module):
                     (1, self.features), jnp.float32))
 
 
-def _scaled_rope_freqs(freqs, scaling):
+def _scaled_rope_freqs(freqs, scaling, theta: float = 0.0):
     """Apply RoPE frequency scaling (inverse frequencies in, out).
 
     "llama3" is the Llama-3.1 scheme: low-frequency (long-wavelength)
     components are slowed by ``factor``, high-frequency ones kept, with a
     smooth ramp between the two wavelength thresholds derived from the
-    original context length."""
+    original context length. "yarn" (``("yarn", factor, original
+    positions, beta_fast, beta_slow, mscale)``, as DeepSeek's inference
+    code computes it): pair d keeps its frequency
+    below the correction dim of ``beta_fast`` rotations over the original
+    positions, is slowed by ``factor`` above that of ``beta_slow``, and is
+    blended linearly between; cos and sin are not scaled (the softmax
+    scale is: ``LlamaConfig.attn_scale_mult``)."""
     if scaling is None:
         return freqs
     kind = scaling[0]
+    if kind == "yarn":
+        factor, orig, fast, slow = map(float, scaling[1:5])
+        dim = 2 * freqs.shape[0]
+
+        def correction_dim(rotations):
+            return dim * math.log(orig / (rotations * 2 * math.pi)) \
+                / (2 * math.log(theta))
+
+        lo = max(math.floor(correction_dim(fast)), 0)
+        hi = min(math.ceil(correction_dim(slow)), dim - 1)
+        ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - lo)
+                        / (hi - lo if hi != lo else 0.001), 0.0, 1.0)
+        return freqs / factor * ramp + freqs * (1.0 - ramp)
     if kind == "linear":
         return freqs / jnp.float32(scaling[1])
     if kind == "llama3":
@@ -423,7 +538,7 @@ def rope(q, k, positions, theta: float, scaling: tuple | None = None):
     """Rotary position embeddings, fp32 trig, applied per head-dim pair."""
     head_dim = q.shape[-1]
     freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
-    freqs = _scaled_rope_freqs(freqs, scaling)
+    freqs = _scaled_rope_freqs(freqs, scaling, theta)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [b, s, hd/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
@@ -465,16 +580,17 @@ def cache_width(cache) -> int:
                 if name != "index").shape[1]
 
 
-def _kv_store(cfg, k, v) -> dict:
+def _kv_store(cfg, k, v, *more) -> dict:
     """This step's (or chunk's) K/V in the cache's storage layout: the
     float leaves, or int8 values + scales under ``cfg.kv_quant``. The
     ONE place the layout is built — the dense decode path, the sp
     decode path, and prefill embedding all consume it. ``k`` and ``v``
-    are the two parts of ``cfg.cache_layout()`` in its order (a latent
-    cache: the compressed latent and the shared rotary key)."""
+    (and ``more``) are the parts of ``cfg.cache_layout()`` in its order (a
+    latent cache: the compressed latent, the shared rotary key and, under
+    sparse attention, the indexer's key)."""
     if cfg.attn_kind != "kv":
         return {name: part.astype(cfg.dtype)
-                for name, part in zip(cfg.cache_layout(), (k, v))}
+                for name, part in zip(cfg.cache_layout(), (k, v, *more))}
     if cfg.kv_quant == "int8":
         k_q, k_s = _kv_quantize(k)
         v_q, v_s = _kv_quantize(v)
@@ -679,6 +795,115 @@ def _eva_prefill_attend(q, k, v, sk, sv, mask, win: int, chunk: int):
     return jnp.moveaxis(out, 0, 1).reshape(b, n_win * win, h, d)[:, :s]
 
 
+# A sparse (DeepSeek Sparse Attention) prefill runs one body a block of
+# DSA_QUERY_BLOCK queries (``lax.map``) inside each block of DSA_KEY_BLOCK
+# keys: a query block scores, selects among and attends the keys up to its
+# own key block's end, so a prompt of n key blocks costs n (n + 1) / 2 of
+# them, not n x n. Prompts past one key block prefill at whole key blocks
+# (``LlamaConfig.prompt_bucket``). Inside a turn the heads (the indexer's,
+# then the attention's) go a group at a time, so that no float32 score
+# tensor ``[heads of a group, queries, keys]`` is larger than
+# DSA_SCORE_BYTES: what the v5e compiler keeps in its fast memory through
+# every pass of the softmax (``tests/test_chip_compile.py``: 24 MiB it
+# keeps, 32 it spills); whole, 128 heads x 128 x 12288 float32 scores are
+# 0.8 GB a turn. 128 queries a turn is what this tree's served runs were
+# made at; alone on the chip (zero weights, no server) the 12288 prefill of
+# a routed layer took 0.337 s at 128 queries a turn, 0.318 at 256 and 0.312
+# at 512 (PERF.md section 6, PR 35, which also says why 128 stayed).
+DSA_QUERY_BLOCK = 128
+DSA_KEY_BLOCK = 4096
+DSA_SCORE_BYTES = 24 << 20
+
+
+def _head_group(heads: int, elements_a_head: int) -> int:
+    """The most heads (a divisor of ``heads``) whose float32 scores of
+    ``elements_a_head`` each stay within DSA_SCORE_BYTES; at least one."""
+    group = heads
+    while group > 1 and (heads % group
+                         or 4 * group * elements_a_head > DSA_SCORE_BYTES):
+        group -= 1
+    return group
+
+
+def _dsa_scores(q_idx, w_idx, k_idx):
+    """The lightning indexer's score of every key for every query:
+    ``q_idx`` ``[b, s, index heads, d]``, ``w_idx`` ``[b, s, index heads]``
+    float32 (already scaled), ``k_idx`` ``[b, t, d]`` -> ``[b, s, t]``
+    float32, ``sum_j w_j ReLU(q_j . k)``: the per-head products accumulate
+    in float32 and the weighted sum over heads is a float32
+    multiply-reduce, a group of heads at a time where all at once would be
+    a large tensor (:func:`_head_group`)."""
+    b, s, heads, d = q_idx.shape
+
+    def part(q_g, w_g):
+        dots = jnp.einsum("bsjd,btd->bsjt", q_g, k_idx,
+                          preferred_element_type=jnp.float32)
+        return jnp.sum(jax.nn.relu(dots) * w_g[..., None], axis=2)
+
+    group = _head_group(heads, b * s * k_idx.shape[1])
+    if group == heads:
+        return part(q_idx, w_idx)
+    q_g = jnp.moveaxis(q_idx.reshape(b, s, heads // group, group, d), 2, 0)
+    w_g = jnp.moveaxis(w_idx.reshape(b, s, heads // group, group), 2, 0)
+    total, _ = jax.lax.scan(
+        lambda acc, qw: (acc + part(*qw), None),
+        jnp.zeros((b, s, k_idx.shape[1]), jnp.float32), (q_g, w_g))
+    return total
+
+
+def _dsa_block_attend(q, k_groups, v_groups, seen, scale, dtype):
+    """A block of queries under its selection: ``q`` ``[b, block, heads,
+    d]``; ``k_groups`` / ``v_groups`` ``[groups, b, t, heads a group, d]``
+    (the expanded keys and values, regrouped once a key block); ``seen``
+    ``[b, block, t]`` bool. One float32 softmax a head over the seen keys,
+    a group of heads a turn. ``[b, block, heads, v width]``."""
+    groups, b, t, group, _ = k_groups.shape
+    block = q.shape[1]
+
+    def turn(args):
+        q_g, k_g, v_g = args
+        logits = jnp.einsum("bqhd,bthd->bhqt", q_g, k_g,
+                            preferred_element_type=jnp.float32) * scale
+        logits = jnp.where(seen[:, None], logits, jnp.float32(-1e9))
+        probs = jax.nn.softmax(logits, axis=-1)
+        return jnp.einsum("bhqt,bthd->bqhd", probs.astype(dtype), v_g)
+
+    q_groups = jnp.moveaxis(q.reshape(b, block, groups, group, q.shape[-1]),
+                            2, 0)
+    if groups == 1:
+        return turn((q_groups[0], k_groups[0], v_groups[0]))
+    out = jax.lax.map(turn, (q_groups, k_groups, v_groups))
+    return jnp.moveaxis(out, 0, 2).reshape(b, block, groups * group, -1)
+
+
+def _dsa_select_mask(scores, visible, k: int):
+    """``[..., t]`` bool: the ``k`` ``visible`` positions of largest float32
+    ``scores``, ties to the lowest position, all the visible ones where
+    they are fewer: the set ``jax.lax.top_k`` picks, as a mask and without
+    a sort. The k-th largest score is found exactly, bit by bit, on the
+    order-preserving integer image of the floats (32 counts over the row);
+    positions equal to it are taken from the lowest on until k are."""
+    bits = jax.lax.bitcast_convert_type(scores.astype(jnp.float32), jnp.int32)
+    # sign-magnitude floats -> integers of the same order, then unsigned
+    key = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    key = jax.lax.bitcast_convert_type(key, jnp.uint32) ^ jnp.uint32(1 << 31)
+    key = jnp.where(visible, key, jnp.uint32(0))
+
+    def bit(i, kth):
+        cand = kth | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+        enough = jnp.sum(key >= cand[..., None], axis=-1) >= k
+        return jnp.where(enough, cand, kth)
+
+    kth = jax.lax.fori_loop(0, 32, bit,
+                            jnp.zeros(scores.shape[:-1], jnp.uint32))
+    above = key > kth[..., None]
+    equal = key == kth[..., None]
+    room = k - jnp.sum(above, axis=-1, dtype=jnp.int32)
+    taken = equal & (jnp.cumsum(equal, axis=-1, dtype=jnp.int32)
+                     <= room[..., None])
+    return (above | taken) & visible
+
+
 def _cache_write(cache, store, idx, b: int, s: int, band: int = 0):
     """Write this step's (or chunk's) ``store`` leaves into the layer's
     cache entry at ``idx`` (an int32 scalar, or ``[b]`` per-row positions)
@@ -869,14 +1094,42 @@ class LlamaBlock(nn.Module):
         kv_lora_rank), scores and the weighted sum run over the cached
         latents themselves, and the value half maps the sum to v_head; the
         window is never re-expanded (8 rows x 400 tokens x 32 heads x 256
-        through a 512-wide matmul would be 0.4 TFLOP a step)."""
+        through a 512-wide matmul would be 0.4 TFLOP a step).
+
+        ``q_lora_rank`` > 0 compresses the query (``q_a_proj``,
+        ``q_a_norm``, ``q_b_proj`` in place of ``q_proj``). ``index_topk``
+        > 0 is DeepSeek Sparse Attention: a lightning indexer
+        (:meth:`_indexer`) scores every visible position for every query,
+        a query attends the ``index_topk`` positions of largest score
+        (exact, ties to the lowest position; every visible position while
+        they are fewer), and the entry gains the indexer's key ``kidx`` of
+        each token. Prefill then attends in blocks of queries and never
+        builds a ``[heads, s, s]`` score (:meth:`_sparse_prefill_attend`);
+        a one-token step scores the window's cached ``kidx`` rows, finds
+        the selection as a mask (:func:`_dsa_select_mask`) and attends the
+        window's rows under it, absorbed as ever."""
         cfg = self.cfg
         heads, dn, dr = cfg.heads, cfg.qk_nope, cfg.qk_rope
         dv, rank = cfg.v_head, cfg.kv_lora_rank
         b, s, _ = x.shape
+        if cfg.index_topk and (band or (cache is not None and s != 1)):
+            raise NotImplementedError(
+                "sparse attention is computed by the whole-prompt prefill "
+                f"and the one-token step: a chunk of {s} positions against "
+                "a cache (a prefix continued, a draft verified) or a "
+                "sliding band is not written (PERF.md section 7)")
         with jax.named_scope("qkv_proj"):
             h = RMSNorm(cfg.norm_eps, name="attn_norm")(x)
-            q = QDense(heads * (dn + dr), cfg.quant, cfg.dtype, name="q_proj")(h)
+            if cfg.q_lora_rank:
+                # the compressed query, which the indexer shares
+                c_q = RMSNorm(cfg.norm_eps, name="q_a_norm")(
+                    QDense(cfg.q_lora_rank, cfg.quant, cfg.dtype,
+                           name="q_a_proj")(h))
+                q = QDense(heads * (dn + dr), cfg.quant, cfg.dtype,
+                           name="q_b_proj")(c_q)
+            else:
+                q = QDense(heads * (dn + dr), cfg.quant, cfg.dtype,
+                           name="q_proj")(h)
             kva = QDense(rank + dr, cfg.quant, cfg.dtype, name="kv_a_proj")(h)
             ckv = RMSNorm(cfg.norm_eps, name="kv_a_norm")(kva[..., :rank])
             q = q.reshape(b, s, heads, dn + dr)
@@ -889,6 +1142,9 @@ class LlamaBlock(nn.Module):
             ckv = ckv.reshape(b, s, 1, rank)
         w, w_scale = QKernel(rank, heads * (dn + dv), cfg.quant, cfg.dtype,
                              name="kv_b_proj")()
+        if cfg.index_topk:
+            with jax.named_scope("dsa_index"):
+                q_idx, k_idx, w_idx = self._indexer(h, c_q, positions)
 
         if cache is None:
             with jax.named_scope("qkv_proj"):
@@ -901,6 +1157,10 @@ class LlamaBlock(nn.Module):
                     [kv[..., :dn],
                      jnp.broadcast_to(k_pe, (b, s, heads, dr))], axis=-1)
                 q = jnp.concatenate([q_nope, q_pe], axis=-1)
+            if cfg.index_topk:
+                out = self._sparse_prefill_attend(q, k, kv[..., dn:], q_idx,
+                                                  k_idx[:, :, 0], w_idx, mask)
+                return out, {"ckv": ckv, "kpe": k_pe, "kidx": k_idx}
             with jax.named_scope("attend"):
                 causal = jnp.tril(jnp.ones((s, s), dtype=jnp.bool_))
                 out = _attend(q, k, kv[..., dn:],
@@ -910,6 +1170,9 @@ class LlamaBlock(nn.Module):
         with jax.named_scope("kv_write"):
             new_cache, valid, t = _cache_write(
                 cache, _kv_store(cfg, ckv, k_pe), cache["index"], b, s, band)
+        if cfg.index_topk:
+            new_cache["kidx"], valid = self._sparse_select(
+                cache, q_idx, k_idx, w_idx, jnp.broadcast_to(valid, (b, s, t)))
         w = w.reshape(rank, heads, dn + dv)
         with jax.named_scope("mla_absorb"):
             # q' = q_nope W_k^T per head; a per-output-channel scale sits
@@ -927,6 +1190,8 @@ class LlamaBlock(nn.Module):
                       + jnp.einsum("bshd,btd->bhst", q_pe, kpe,
                                    preferred_element_type=jnp.float32))
             logits = logits / jnp.sqrt(dn + dr).astype(jnp.float32)
+            if cfg.attn_scale_mult != 1.0:
+                logits = logits * jnp.float32(cfg.attn_scale_mult)
             logits = jnp.where(
                 jnp.broadcast_to(valid, (b, s, t))[:, None, :, :], logits,
                 jnp.float32(-1e9))
@@ -939,6 +1204,127 @@ class LlamaBlock(nn.Module):
             if w_scale is not None:
                 out = out * w_scale.reshape(heads, dn + dv)[:, dn:]
         return out.astype(cfg.dtype), new_cache
+
+    def _indexer(self, h, c_q, positions):
+        """The lightning indexer's projections (under ``dsa_index``), from
+        the normed input ``h`` and the compressed query ``c_q``:
+        ``index_heads`` queries ``q_j = W_qb,j c_q`` ``[b, s, heads, d]``,
+        ONE key a token ``k = LayerNorm(W_k h)`` ``[b, s, 1, d]`` (gain and
+        bias), the first ``qk_rope`` dims of both roped with the
+        attention's frequencies as HALVES (never interleaved), and a
+        float32 weight a head ``w = W_w h x index_heads^-1/2 x
+        index_head_dim^-1/2`` ``[b, s, heads]``."""
+        cfg = self.cfg
+        n_idx, d_idx, dr = cfg.index_heads, cfg.index_head_dim, cfg.qk_rope
+        b, s, _ = h.shape
+        q_idx = QDense(n_idx * d_idx, cfg.quant, cfg.dtype,
+                       name="index_wq_b")(c_q).reshape(b, s, n_idx, d_idx)
+        k32 = QDense(d_idx, cfg.quant, cfg.dtype, name="index_wk")(h).astype(
+            jnp.float32)
+        k32 = k32 - jnp.mean(k32, axis=-1, keepdims=True)
+        k32 = k32 * jax.lax.rsqrt(
+            jnp.mean(k32 * k32, axis=-1, keepdims=True) + 1e-6)
+        k_idx = (k32 * self.param("index_k_norm_scale", nn.initializers.ones,
+                                  (d_idx,), jnp.float32)
+                 + self.param("index_k_norm_bias", nn.initializers.zeros,
+                              (d_idx,), jnp.float32)
+                 ).astype(cfg.dtype).reshape(b, s, 1, d_idx)
+        q_rot, k_rot = rope(q_idx[..., :dr], k_idx[..., :dr], positions,
+                            cfg.rope_theta, cfg.rope_scaling)
+        q_idx = jnp.concatenate([q_rot, q_idx[..., dr:]], axis=-1)
+        k_idx = jnp.concatenate([k_rot, k_idx[..., dr:]], axis=-1)
+        # float32 all the way, like the router: the weights decide a top-k
+        # with near-ties
+        w_idx = jnp.matmul(
+            h.astype(jnp.float32),
+            self.param("index_weights_proj", nn.initializers.lecun_normal(),
+                       (h.shape[-1], n_idx), jnp.float32),
+            precision=jax.lax.Precision.HIGHEST) \
+            * jnp.float32((n_idx * d_idx) ** -0.5)
+        return q_idx, k_idx, w_idx
+
+    def _sparse_prefill_attend(self, q, k, v, q_idx, k_idx, w_idx, mask):
+        """A sparse prefill's attention over the expanded keys ``k`` and
+        values ``v`` ``[b, s, heads, ..]``: ONE body runs a block of
+        ``DSA_QUERY_BLOCK`` queries a turn inside each block of
+        ``DSA_KEY_BLOCK`` keys: it scores the keys up to the key block's end
+        (``q_idx``, ``w_idx``; ``k_idx`` ``[b, s, d]``), selects
+        (:func:`_dsa_select_mask`) and attends under the selection's mask,
+        a group of heads at a time (:func:`_dsa_block_attend`). ``[b, s,
+        heads, v width]``."""
+        cfg = self.cfg
+        heads, topk = cfg.heads, cfg.index_topk
+        b, s = q.shape[:2]
+        scale = jnp.float32(cfg.attn_scale_mult / math.sqrt(q.shape[-1]))
+        block = min(s, DSA_QUERY_BLOCK)
+        outs = []
+        for at in range(0, s, DSA_KEY_BLOCK):
+            # the queries at .. t - 1 against the keys 0 .. t - 1
+            t = min(at + DSA_KEY_BLOCK, s)
+            turns = -(-(t - at) // block)
+            group = _head_group(heads, b * block * t)
+
+            def grouped(x, t=t, group=group):
+                return jnp.moveaxis(x[:, :t].reshape(
+                    b, t, heads // group, group, x.shape[-1]), 2, 0)
+
+            def blocks(x, at=at, t=t, turns=turns):
+                x = jnp.pad(x[:, at:t], ((0, 0), (0, turns * block
+                                                  - (t - at)))
+                            + ((0, 0),) * (x.ndim - 2))
+                return jnp.moveaxis(
+                    x.reshape(b, turns, block, *x.shape[2:]), 1, 0)
+
+            def body(args, at=at, t=t, k_t=grouped(k), v_t=grouped(v)):
+                i, q_i, qi_i, wi_i = args
+                pos = at + i * block + jnp.arange(block)
+                seen = mask[:, None, :t] & (jnp.arange(t)[None, :]
+                                            <= pos[:, None])[None]
+                if t > topk:
+                    with jax.named_scope("dsa_index"):
+                        score = _dsa_scores(qi_i, wi_i, k_idx[:, :t])
+                    with jax.named_scope("dsa_select"):
+                        seen = _dsa_select_mask(score, seen, topk)
+                with jax.named_scope("attend"):
+                    return _dsa_block_attend(q_i, k_t, v_t, seen, scale,
+                                             cfg.dtype)
+
+            out = jax.lax.map(body, (jnp.arange(turns), blocks(q),
+                                     blocks(q_idx), blocks(w_idx)))
+            outs.append(jnp.moveaxis(out, 0, 1).reshape(
+                b, turns * block, heads, v.shape[-1])[:, :t - at])
+        return jnp.concatenate(outs, axis=1)
+
+    def _sparse_select(self, cache, q_idx, k_idx, w_idx, valid):
+        """A sparse one-token step's selection: writes the step's indexer
+        key into the ``kidx`` leaf and scores every cached position of the
+        window (under ``dsa_index``), then finds, of the ``valid`` ``[b, 1,
+        t]`` positions, the ``index_topk`` of largest score as a MASK
+        (under ``dsa_select``; a window no longer than that keeps them
+        all). Returns ``(the new kidx leaf, the mask [b, 1, t])``. The
+        window's rows are then attended where they lie: at 4 rows of 16384
+        a gather of the picked rows behind ``jax.lax.top_k`` took 4.2 ms a
+        step of 7 layers (3.5 of it the gather, 0.6 the sort) where the
+        mask and the masked read take 1.6 (PERF.md section 6, PR 35); a
+        window many times ``index_topk`` long would want the gather back."""
+        cfg = self.cfg
+        b, _, t = valid.shape
+        with jax.named_scope("dsa_index"):
+            kidx = _cache_write(cache, {"kidx": k_idx.astype(cfg.dtype)},
+                                cache["index"], b, 1)[0]["kidx"]
+            score = _dsa_scores(q_idx, w_idx, kidx[:, :, 0])
+        picked = valid
+        if t > cfg.index_topk:
+            with jax.named_scope("dsa_select"):
+                picked = _dsa_select_mask(score, valid, cfg.index_topk)
+        if self.layer == 0:
+            # the keys a row's step attended and those it chose from: every
+            # layer's counts are the same (_scan_decode, count_dsa; /metrics
+            # handler.dsa)
+            self.sow("dsa_stats", "keys", jnp.stack(
+                [picked.sum(-1)[:, 0], valid.sum(-1)[:, 0]],
+                axis=-1).astype(jnp.int32))
+        return kidx, picked
 
     def _project_qkv(self, x, positions):
         """The per-head kinds' projections: ``q`` ``[b, s, heads, d]``,
@@ -1955,7 +2341,8 @@ def _segment_decode(model: LlamaModel, params, select, first, lp, cache, pos,
         return _scan_decode(model, params, select, first, lp, cache, pos,
                             done, keys, eos_id, segment, return_carry=True,
                             count_load=cfg.counts_moe_load,
-                            count_keys=cfg.counts_eva_keys, **form)
+                            count_keys=cfg.counts_eva_keys,
+                            count_dsa=cfg.counts_dsa_keys, **form)
 
     spans = _leaf_spans(cfg, cache[0], window)
     # (one merge would write an eva ring shorter than the segment twice:
@@ -1986,7 +2373,7 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
                  start, done0, keys, eos_id, decode_steps: int,
                  return_carry: bool = False, pos_offset=None,
                  count_load: bool = False, tail_window: int | None = None,
-                 count_keys: bool = False):
+                 count_keys: bool = False, count_dsa: bool = False):
     """The decode scan shared by the exact-shape path (:func:`_decode`),
     the bucketed serving path (:func:`_serve_decode`) and the streaming
     segment path: one compiled step per token over a static-shape cache.
@@ -2023,6 +2410,12 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
     chunk summaries they wrote, and the steps taken after a window's edge
     crossed inside the segment (a tail segment's rare branch; 0 from the
     per-step write).
+
+    ``count_dsa`` (a sparse-attention model's engine segments,
+    ``cfg.counts_dsa_keys``): the emitted tuple gains, LAST, one member,
+    int32 ``[b, 2]`` summed over the steps as layer 0 sows it
+    (``dsa_stats``): the keys each row's steps attended and the keys they
+    chose them from. It combines with ``count_load``.
 
     ``tail_window`` (the engine's plain segments, where
     :func:`segment_keeps_tail`): inside the scan the cache is READ-ONLY,
@@ -2079,11 +2472,21 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
                       for name, val in entry.items()} for entry in frozen]
         cache = (tails, jnp.int32(0))
 
+    # what the program counts beside its tokens: the collections the model
+    # sows, and where each one's sum starts
+    counted = {}
+    if count_load:
+        counted["moe_stats"] = jnp.zeros((b, model.cfg.moe_experts),
+                                         jnp.int32)
+        counted["moe_reads"] = jnp.int32(0)
+    elif count_keys:
+        counted["eva_stats"] = jnp.zeros((b, 3), jnp.int32)
+    if count_dsa:
+        counted["dsa_stats"] = jnp.zeros((b, 2), jnp.int32)
+
     def step(carry, _):
-        if count_load:
-            carry, (load, read) = carry
-        elif count_keys:
-            carry, seen = carry
+        if counted:
+            carry, counts = carry
         tok, lp, cache, pos, done, keys = carry  # pos: int32 scalar or [b]
         rope_pos = pos if pos_offset is None else pos + pos_offset
         positions = (rope_pos[:, None] if jnp.ndim(rope_pos)
@@ -2097,17 +2500,12 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
             else:
                 cache = [{**entry, "index": base, "tail": tail, "step": j}
                          for entry, tail in zip(frozen, tails)]
-        if count_load:
+        if counted:
             (logits, new_cache), sown = model.apply(
                 params, tok[:, None], positions=positions, cache=cache,
-                mutable=["moe_stats", "moe_reads"])
-            load = load + sum(jax.tree.leaves(sown["moe_stats"]))
-            read = read + sum(jax.tree.leaves(sown["moe_reads"]))
-        elif count_keys:
-            (logits, new_cache), sown = model.apply(
-                params, tok[:, None], positions=positions, cache=cache,
-                mutable=["eva_stats"])
-            seen = seen + sum(jax.tree.leaves(sown["eva_stats"]))
+                mutable=list(counted))
+            counts = tuple(c + sum(jax.tree.leaves(sown[name]))
+                           for name, c in zip(counted, counts))
         else:
             logits, new_cache = model.apply(params, tok[:, None],
                                             positions=positions, cache=cache)
@@ -2122,26 +2520,18 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
         nlp = jnp.where(done, jnp.float32(0.0), nlp)
         done = done | (has_eos & (nxt == eos_id))
         carry = (nxt, nlp, new_cache, pos + 1, done, keys)
-        if count_load:
-            carry = (carry, (load, read))
-        elif count_keys:
-            carry = (carry, seen)
+        if counted:
+            carry = (carry, counts)
         return carry, (tok, lp)
 
     init = (first, lp0, cache, start, done0, keys)
-    if count_load:
-        init = (init, (jnp.zeros((b, model.cfg.moe_experts), jnp.int32),
-                       jnp.int32(0)))
-    elif count_keys:
-        init = (init, jnp.zeros((b, 3), jnp.int32))
+    if counted:
+        init = (init, tuple(counted.values()))
     carry, (toks, lps) = jax.lax.scan(step, init, None, length=decode_steps)
     out = (jnp.transpose(toks), jnp.transpose(lps))  # [b, decode_steps] x2
-    if count_load:
+    if counted:
         carry, counts = carry
         out = (*out, *counts)
-    elif count_keys:
-        carry, seen = carry
-        out = (*out, seen)
     if tail_window is not None:
         tok, lp, (tails, _), pos, done, keys = carry
         if eva:

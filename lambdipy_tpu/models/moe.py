@@ -42,7 +42,10 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from lambdipy_tpu.ops import kernels_compile_here
-from lambdipy_tpu.ops.grouped_experts import picked_experts, streamed_experts
+from lambdipy_tpu.ops.grouped_experts import (VMEM_CEILING,
+                                              kernel_vmem_bytes,
+                                              picked_experts,
+                                              streamed_experts)
 
 
 def route_topk(probs, top_k: int, capacity: int, valid=None):
@@ -194,17 +197,32 @@ class MoEMLP(nn.Module):
 # -- the dropless form: what a served model routes with ----------------------
 
 def route_dropless(logits, bias, top_k: int, *, scoring: str, norm: bool,
-                   scaling: float):
+                   scaling: float, n_group: int = 1, topk_group: int = 1):
     """Top-k routing as DeepSeek-V3's ``noaux_tc`` gate publishes it, with
     no capacity: ``logits`` [t, e] float32 -> (experts [t, k] int32,
     weights [t, k] float32). Scores are ``sigmoid`` (or ``softmax``) of the
     logits; the k experts with the largest ``score + bias`` are chosen
     (ties to the lowest index); their weights are the scores WITHOUT the
     bias, divided by their sum when ``norm``, times ``scaling``. The bias
-    (``e_score_correction_bias``) moves the choice, never the weight."""
+    (``e_score_correction_bias``) moves the choice, never the weight.
+    ``n_group`` > 1 limits the choice to groups: the experts lie in
+    ``n_group`` groups of consecutive ids, a group's score is the sum of
+    its two largest ``score + bias``, and the k are chosen inside the
+    ``topk_group`` groups of largest score (ties to the lowest group)."""
     scores = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
               else jax.nn.softmax(logits, axis=-1))
-    _, experts = jax.lax.top_k(scores + bias, top_k)
+    if n_group > 1:
+        t, e = scores.shape
+        choice = (scores + bias).reshape(t, n_group, e // n_group)
+        _, kept = jax.lax.top_k(
+            jnp.sum(jax.lax.top_k(choice, 2)[0], axis=-1), topk_group)
+        inside = jnp.any(kept[:, :, None] == jnp.arange(n_group)[None, None],
+                         axis=1)
+        _, experts = jax.lax.top_k(
+            jnp.where(inside[:, :, None], choice, -jnp.inf).reshape(t, e),
+            top_k)
+    else:
+        _, experts = jax.lax.top_k(scores + bias, top_k)
     weights = jnp.take_along_axis(scores, experts, axis=-1)
     if norm:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
@@ -378,7 +396,8 @@ class RoutedMLP(nn.Module):
                                 precision=jax.lax.Precision.HIGHEST)
             experts, weights = route_dropless(
                 logits, bias, cfg.moe_top_k, scoring=cfg.scoring_func,
-                norm=cfg.norm_topk_prob, scaling=cfg.routed_scaling_factor)
+                norm=cfg.norm_topk_prob, scaling=cfg.routed_scaling_factor,
+                n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group)
             load = jnp.zeros((b * s, e), jnp.int32).at[
                 jnp.arange(b * s)[:, None], experts].add(1)
             if valid is not None:
@@ -386,22 +405,43 @@ class RoutedMLP(nn.Module):
             if not self.is_initializing():  # init returns parameters only
                 self.sow("moe_stats", "load",
                          load.reshape(b, s, e).sum(axis=1))
+            first, held = cfg.moe_held
+            if held != e:
+                # a chip's share: the stacks hold experts first .. first +
+                # held - 1 under LOCAL ids; an assignment to an absent
+                # expert gets the id past the last, which every form of
+                # the sum skips (no group, no fetch, a dropped scatter).
+                # Its weight stays in the normalisation above
+                experts = experts - first
+                experts = jnp.where((experts >= 0) & (experts < held),
+                                    experts, held)
+                load = load[:, first:first + held]
 
         with jax.named_scope("experts"):
             stacks = [_expert_stack(self, name, shape, cfg.quant, cfg.dtype)
-                      for name, shape in (("experts_gate", (e, hidden, m)),
-                                          ("experts_up", (e, hidden, m)),
-                                          ("experts_down", (e, m, hidden)))]
+                      for name, shape in (("experts_gate", (held, hidden, m)),
+                                          ("experts_up", (held, hidden, m)),
+                                          ("experts_down", (held, m, hidden)))]
 
-            # the distinct experts the valid rows picked; the kernel reports
-            # its own count of what it fetched
+            # the distinct experts (of those held here) the valid rows
+            # picked; the kernel reports its own count of what it fetched
             read = jnp.sum(jnp.any(load > 0, axis=0).astype(jnp.int32))
             if b * s > STREAM_ROWS:
                 out = grouped_experts(tokens, experts, weights, valid,
-                                      expert_by_index(stacks, cfg.dtype), e)
+                                      expert_by_index(stacks, cfg.dtype),
+                                      held)
             elif not self.is_initializing() and kernels_compile_here():
-                out, read = picked_experts(tokens, experts, weights, valid,
-                                           stacks, cfg.dtype)
+                if kernel_vmem_bytes(b * s, hidden, m, stacks[0][0].dtype,
+                                     cfg.dtype) > VMEM_CEILING:
+                    # an expert too wide for the kernel to hold whole: the
+                    # loop over the blocks that exist reads the picked
+                    # experts alone too, a block at a time
+                    out = grouped_experts(
+                        tokens, experts, weights, valid,
+                        expert_by_index(stacks, cfg.dtype), held)
+                else:
+                    out, read = picked_experts(tokens, experts, weights,
+                                               valid, stacks, cfg.dtype)
             else:
                 out = streamed_experts(tokens, experts, weights, valid,
                                        stacks, cfg.dtype)

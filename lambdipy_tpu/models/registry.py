@@ -211,6 +211,10 @@ def _llama_tp_rules():
         # the latent and routed kinds until a PR shards them; the rules
         # are here so that the tree has no leaf without one.)
         ("*kv_a_proj/*", P()),
+        # the compressed query feeds every head and the indexer; the
+        # indexer (sparse attention) scores with all its heads at once
+        ("*q_a_proj/*", P()),
+        ("*index_*", P()),
         # eva attention's two learned pooling vectors a head: tiny, float32
         # (validate_serving_mesh refuses a mesh for that kind too)
         ("*adaptive_*", P()),
@@ -395,6 +399,38 @@ def _build_deepseek_v3(dtype: str = "bfloat16", quant: str | None = None,
 
     extra = {"rope_interleave": True, "scoring_func": "sigmoid",
              "norm_topk_prob": True, **(extra or {})}
+    cfg = LlamaConfig(dtype=_dtype(dtype), quant=quant,
+                      **{**_llama_overrides(extra), "attn_kind": "latent",
+                         "ffn_kind": "routed"})
+    return _build_llama(cfg)
+
+
+@register("deepseek-v32", "jax",
+          "DeepSeek-V3.2-style block: latent attention with query compression "
+          "under sparse attention, group-routed experts of which a share")
+def _build_deepseek_v32(dtype: str = "bfloat16", quant: str | None = None,
+                        extra: dict | None = None) -> JaxModel:
+    """The ``deepseek_v32`` architecture through the one block: the
+    ``deepseek-v3`` kinds with the query compressed (``q_lora_rank``),
+    DeepSeek Sparse Attention (``index_heads``, ``index_head_dim``,
+    ``index_topk``: models/llama.py ``LlamaBlock._latent_attend``),
+    group-limited routing (``moe_n_group``, ``moe_topk_group``), a chip's
+    share of each layer's routed experts (``moe_experts_held``,
+    ``moe_first_expert``) and YaRN. A recipe's TOML cannot carry the
+    ``rope_scaling`` tuple, so YaRN comes as scalars, ``rope_factor``
+    (absent or 1: none), ``rope_original_len``, ``rope_beta_fast``,
+    ``rope_beta_slow``, ``rope_mscale`` (docs/serving.md, "deepseek-v32
+    recipe keys")."""
+    from lambdipy_tpu.models.llama import LlamaConfig
+
+    extra = {"rope_interleave": True, "scoring_func": "sigmoid",
+             "norm_topk_prob": True, **(extra or {})}
+    yarn = {key: float(extra.pop(f"rope_{key}", default))
+            for key, default in (("factor", 1.0), ("original_len", 4096),
+                                 ("beta_fast", 32.0), ("beta_slow", 1.0),
+                                 ("mscale", 1.0))}
+    if yarn["factor"] != 1.0:
+        extra["rope_scaling"] = ("yarn", *yarn.values())
     cfg = LlamaConfig(dtype=_dtype(dtype), quant=quant,
                       **{**_llama_overrides(extra), "attn_kind": "latent",
                          "ffn_kind": "routed"})

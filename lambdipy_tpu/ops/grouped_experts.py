@@ -111,6 +111,20 @@ def _kernel(ids_ref, count_ref, x_ref, gates_ref, wg_ref, sg_ref, wu_ref,
             * gates_ref[...]
 
 
+def kernel_vmem_bytes(t: int, hidden: int, mid: int, stack_dtype,
+                      dtype) -> int:
+    """Fast memory :func:`picked_experts` needs for ``t`` rows: an expert's
+    three blocks twice (the pipeline's two buffers), once more converted to
+    ``dtype``, and the rows' operands and intermediates. Over
+    ``VMEM_CEILING`` the kernel refuses (it holds experts whole: one of 3 x
+    7168 x 2048 int8 needs 172 MiB), and ``models/moe.py RoutedMLP`` asks
+    before it chooses the kernel."""
+    rows = -(-t // ROW_TILE) * ROW_TILE
+    return 3 * hidden * mid * (2 * jnp.dtype(stack_dtype).itemsize
+                               + jnp.dtype(dtype).itemsize) \
+        + rows * (hidden + mid) * 16 + (4 << 20)
+
+
 def picked_experts(tokens, experts, weights, valid, stacks, dtype, *,
                    interpret: bool = False):
     """The kernel: operands as :func:`streamed_experts`; returns (the sum
@@ -132,11 +146,7 @@ def picked_experts(tokens, experts, weights, valid, stacks, dtype, *,
             f"picked_experts: expert widths ({hidden}, {mid}) do not tile by "
             f"{LANES} lanes")
     rows = -(-t // ROW_TILE) * ROW_TILE
-    # an expert's three blocks twice (the pipeline's two buffers), once more
-    # converted to ``dtype``, and the rows' operands and intermediates
-    need = 3 * hidden * mid * (2 * wg.dtype.itemsize
-                               + jnp.dtype(dtype).itemsize) \
-        + rows * (hidden + mid) * 16 + (4 << 20)
+    need = kernel_vmem_bytes(t, hidden, mid, wg.dtype, dtype)
     if need > VMEM_CEILING:
         raise ValueError(
             f"picked_experts: one expert of 3 x {hidden} x {mid} "

@@ -202,6 +202,7 @@ class ContinuousBatcher:
         import jax
 
         from lambdipy_tpu.runtime.metrics import (DecodeWindowStats,
+                                                  DsaKeyStats,
                                                   EngineFaultStats,
                                                   EvaKeyStats,
                                                   MoeLoadStats,
@@ -226,7 +227,13 @@ class ContinuousBatcher:
         # load and the distinct experts its layer-steps picked beside the
         # tokens (llama._scan_decode count_load); the collector books them
         # here (/metrics handler.moe)
-        self.moe_stats = MoeLoadStats()
+        self.moe_stats = MoeLoadStats(
+            held=getattr(cfg, "moe_held", (0, None)))
+        # a sparse-attention model's segment programs return, a row and
+        # LAST, the keys its steps attended and chose from
+        # (llama._scan_decode count_dsa; /metrics handler.dsa)
+        self.dsa_stats = DsaKeyStats()
+        self._counts_dsa = bool(getattr(cfg, "counts_dsa_keys", False))
         # an eva-attention model's segment programs return, a row, the keys
         # its steps had visible, the summaries they wrote and the steps it
         # took past a window edge inside the segment
@@ -261,6 +268,12 @@ class ContinuousBatcher:
             from lambdipy_tpu.models.llama import _next_bucket
 
             self.spec_k = max(2, _next_bucket(int(spec_k), 2))
+            if self._counts_dsa:
+                raise NotImplementedError(
+                    "spec_k on a sparse-attention model: a verify chunk is "
+                    "several positions wide, and the selection is computed "
+                    "by the whole-prompt prefill and the one-token step "
+                    "alone (PERF.md section 7)")
             if self._counts_eva:
                 raise NotImplementedError(
                     "spec_k on an eva-attention model: a verify chunk is "
@@ -1475,6 +1488,9 @@ class ContinuousBatcher:
                 if self.mesh_stats is not None:
                     self.mesh_stats.record_segment()
                 booked = [slot for slot, e in rec["rows"] if not e["done"]]
+                if moe_h and self._counts_dsa:
+                    self.dsa_stats.record_segment(moe_h.pop()[booked],
+                                                  steps=block.shape[1])
                 if moe_h and self._counts_eva:
                     self.eva_stats.record_segment(moe_h[0][booked],
                                                   steps=block.shape[1])

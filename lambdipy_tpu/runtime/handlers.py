@@ -1600,6 +1600,10 @@ def generate_handler(spec: dict, ctx) -> HandlerState:
                 server.model.cfg, "counts_eva_keys", False):
             # what the eva segments' rows attended and summarised
             out["eva"] = continuous.eva_stats.report()
+        if continuous is not None and getattr(
+                server.model.cfg, "counts_dsa_keys", False):
+            # what the sparse segments' rows selected, and from how many
+            out["dsa"] = continuous.dsa_stats.report()
         if getattr(server, "spec_metrics", None) is not None:
             # the solo `"speculative": k` path's cumulative acceptance
             # counters (the engine's batching.spec block shares this

@@ -127,12 +127,18 @@ class MoeLoadStats:
     reads (``ops/grouped_experts.py picked_experts``; one that streams reads
     them all, whatever this says). Counted by the program over ALL the
     slots' rows, because a cache step routes every slot, live or empty;
-    ``layer_steps`` is fetched segments x segment steps x routed layers."""
+    ``layer_steps`` is fetched segments x segment steps x routed layers.
+    ``local_assignments``: of ``assignments``, those to the experts this
+    chip holds (``held``: first id and count; ``LlamaConfig.moe_held``):
+    all of them where it holds every expert, the share routing really gave
+    it where it holds a share."""
 
     assignments: int = 0
     load: list = field(default_factory=list)   # per expert
     experts_read: int = 0
     layer_steps: int = 0
+    held: tuple = (0, None)
+    local_assignments: int = 0
     _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
 
     def record_segment(self, rows, *, experts_read: int,
@@ -149,12 +155,47 @@ class MoeLoadStats:
                 self.load = [0] * len(total)
             self.load = [a + int(b) for a, b in zip(self.load, total)]
             self.assignments += int(total.sum())
+            first, count = self.held
+            self.local_assignments += int(
+                total[first:None if count is None else first + count].sum())
 
     def report(self) -> dict:
         with self._lock:
-            return {"assignments": self.assignments, "load": list(self.load),
+            return {"assignments": self.assignments,
+                    "local_assignments": self.local_assignments,
+                    "load": list(self.load),
                     "experts_read": self.experts_read,
                     "layer_steps": self.layer_steps}
+
+
+@dataclass
+class DsaKeyStats:
+    """Counters of a sparse-attention model's decode segments (the
+    ``handler.dsa`` block on ``/metrics``), only growing, from the segment
+    programs' own masks, for the rows the collector books. ``row_steps``:
+    booked rows x segment steps. ``keys_selected``: the cached positions
+    those steps attended, summed: ``min(context, index_topk)`` a step
+    exactly, so more or fewer shows as a difference. ``keys_visible``: the
+    positions they were chosen from (the step's context)."""
+
+    row_steps: int = 0
+    keys_selected: int = 0
+    keys_visible: int = 0
+    _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
+
+    def record_segment(self, rows, *, steps: int) -> None:
+        """One fetched segment. ``rows``: int array [booked rows, 2], each
+        row's (keys selected, keys visible) summed over the steps."""
+        with self._lock:
+            self.row_steps += len(rows) * steps
+            self.keys_selected += int(rows[:, 0].sum())
+            self.keys_visible += int(rows[:, 1].sum())
+
+    def report(self) -> dict:
+        with self._lock:
+            return {"row_steps": self.row_steps,
+                    "keys_selected": self.keys_selected,
+                    "keys_visible": self.keys_visible}
 
 
 @dataclass

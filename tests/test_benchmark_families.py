@@ -4,7 +4,8 @@ values and the manifest's rules (``benchmark/tests/test_families.py`` and
 and the ``deepseek-v3`` family that PR 27 brought: its leaf rules against
 the program's real tree at published widths, what it says a step needs, by
 hand, and its toy twin through the whole command on the CPU; the same for the
-``evabyte`` family that PR 33 brought. At the end,
+``evabyte`` family that PR 33 brought and the ``deepseek-v32`` family that
+PR 35 brought. At the end,
 the yardstick against the program: what every accepted configuration's
 family says a step reads and computes, against the program's own parameter
 tree, and the bounds the ledger's roofline shares stand on."""
@@ -242,7 +243,8 @@ def test_the_experts_read_reader_takes_the_windows_delta_or_nothing():
                         "m_close": {"handler": {}}}) is None
     entry = next(m for m in json.loads((REPO / "BENCHMARK.json").read_text())[
         "per_layer"] if m["name"] == "moe_experts_read")
-    assert entry["workloads"] == ["kanana2-30b.decode-saturated"]
+    assert entry["workloads"] == ["kanana2-30b.decode-saturated",
+                                  "deepseek-v32-exp.long-context-decode"]
 
 
 # -- the evabyte family (PR 33) -------------------------------------------------
@@ -478,6 +480,397 @@ def test_the_eva_readers_take_the_windows_delta_or_nothing():
                  "eva_keys_per_query"):
         entry = next(m for m in manifest["per_layer"] if m["name"] == name)
         assert entry["workloads"] == ["evabyte6b.long-decode"]
+
+
+# -- the deepseek-v32 family (PR 35) ----------------------------------------------
+
+DSV32 = json.loads((BENCH / "configs" / "deepseek-v32-exp.json").read_text())
+DSA_TWIN_MANIFEST = BENCH / "rehearsal-dsa.json"
+DSA_TWIN_CELL = "rehearsal-dsa.rehearsal-closed"
+DSA_CELL = "deepseek-v32-exp.long-context-decode"
+
+# the catalog's row for DeepSeek-V3.2-Exp (the model-configs guide's
+# architectures.jsonl, ``config``): every key, as published
+DSV32_PUBLISHED = {
+    "attention_bias": False, "ep_size": 1, "first_k_dense_replace": 3,
+    "hidden_act": "silu", "hidden_size": 7168, "index_head_dim": 128,
+    "index_n_heads": 64, "index_topk": 2048, "intermediate_size": 18432,
+    "kv_lora_rank": 512, "max_position_embeddings": 163840,
+    "model_type": "deepseek_v32", "moe_intermediate_size": 2048,
+    "moe_layer_freq": 1, "n_group": 8, "n_routed_experts": 256,
+    "n_shared_experts": 1, "norm_topk_prob": True, "num_attention_heads": 128,
+    "num_experts_per_tok": 8, "num_hidden_layers": 61,
+    "num_key_value_heads": 128, "num_nextn_predict_layers": 1,
+    "q_lora_rank": 1536, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+    "rms_norm_eps": 1e-06,
+    "rope_scaling": {"beta_fast": 32, "beta_slow": 1, "factor": 40,
+                     "mscale": 1, "mscale_all_dim": 1,
+                     "original_max_position_embeddings": 4096, "type": "yarn"},
+    "rope_theta": 10000, "routed_scaling_factor": 2.5,
+    "scoring_func": "sigmoid", "tie_word_embeddings": False, "topk_group": 4,
+    "topk_method": "noaux_tc", "v_head_dim": 128, "vocab_size": 129280}
+
+
+def test_deepseek_v32_is_the_published_configuration_cut_to_a_chips_share():
+    changed = {k for k, v in DSV32_PUBLISHED.items()
+               if DSV32.get(k, "absent") != v}
+    assert changed == {"num_hidden_layers", "first_k_dense_replace",
+                       "n_routed_experts", "vocab_size",
+                       "num_nextn_predict_layers"}
+    assert DSV32["reduced"] == [
+        "num_hidden_layers", "first_k_dense_replace", "n_routed_experts",
+        "vocab_size", "num_nextn_predict_layers", "engine_window",
+        "max_position_embeddings"]
+    assert set(DSV32["reduced_why"]) == set(DSV32["reduced"])
+    assert DSV32["published"] == {
+        k: DSV32_PUBLISHED[k] for k in DSV32["reduced"]
+        if k != "engine_window"}
+    # the depth rule, and the guide's floors: a period and 4 following
+    # layers, 8 experts, an eighth of the vocabulary
+    assert (DSV32["num_hidden_layers"], DSV32["first_k_dense_replace"]) in (
+        (7, 1), (6, 1), (5, 1))
+    assert DSV32["n_routed_experts"] == 16 >= 8
+    assert (DSV32["routed_experts_published"], DSV32["first_routed_expert"],
+            DSV32["layer_shared_by_chips"]) == (256, 0, 16)
+    assert DSV32["vocab_size"] * 8 == DSV32_PUBLISHED["vocab_size"]
+    assert DSV32["num_nextn_predict_layers"] == 0
+    # the program's max_len stays apart from the engine window: equal, the
+    # handler would switch on the prefix store, which refuses this layout
+    assert (DSV32["engine_window"], DSV32["context_served"]) == (16384, 32768)
+    assert DSV32["recipe_extra"] == {"batch_cache_len": 16384,
+                                     "batch_max": 4, "max_new_tokens": 16}
+    assert {"weights", "quantization", "indexer_precision",
+            "indexer_rotation", "indexer_layout", "rope_interleave", "mscale",
+            "multi_token_prediction", "tokenizer"} <= set(DSV32["assumed"])
+    manifest = json.loads((REPO / "BENCHMARK.json").read_text())
+    entry = next(c for c in manifest["configs"]
+                 if c["name"] == "deepseek-v32-exp")
+    assert entry["reduced"] == DSV32["reduced"]
+    assert entry["source"] == DSV32["source"]
+    cell = next(w for w in manifest["workloads"] if w["name"] == DSA_CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "deepseek-v32-exp", "long-context-decode", 1)
+    traffic = json.loads((BENCH / "traffic" / "long-context-decode.json"
+                          ).read_text())
+    assert (traffic["kind"], traffic["clients"], traffic["pool"],
+            traffic["lead_in_s"]) == ("closed_loop", 8, 192, 20)
+    assert (traffic["prompt_len"], traffic["max_tokens"]) == (
+        {"dist": "uniform", "min": 8192, "max": 12288},
+        {"dist": "uniform", "min": 1280, "max": 1792})    # ISSUE 35's fallback
+    # every context is 4-7 x index_topk and fits the engine window
+    assert traffic["prompt_len"]["min"] >= 4 * DSV32["index_topk"]
+    assert traffic["prompt_len"]["max"] + traffic["max_tokens"]["max"] \
+        <= DSV32["engine_window"]
+    assert traffic["clients"] == 2 * DSV32["recipe_extra"]["batch_max"]
+    # the warm-up's two singles ARE the program's two solo-prefill programs
+    from benchmark import warmup
+    from lambdipy_tpu.models import registry
+
+    cov = warmup.coverage(traffic, DSV32)
+    assert cov["singles"] == [(8192, 16), (12288, 16)]
+    assert cov["decode_windows"] == [16384] and cov["group_buckets"] == []
+    cfg = registry.get("deepseek-v32").build(
+        extra=families.of(DSV32).dims_of(DSV32)).config
+    assert {cfg.prompt_bucket(s, 16) for s in (8192,)} == {8192}
+    assert {cfg.prompt_bucket(s, 16) for s in (8193, 10000, 12288)} == {12288}
+
+
+def test_deepseek_v32s_leaf_rules_name_every_path_of_the_real_tree():
+    from lambdipy_tpu.models import registry
+
+    family = families.of(DSV32)
+    adapter = registry.get(DSV32["model"]).build(
+        dtype="bfloat16", quant="int8", extra=family.dims_of(DSV32))
+    tree = jax.eval_shape(lambda: adapter.init_params(seed=0))
+    shapes, sizes = {}, {}
+    for path, spec in jax.tree_util.tree_leaves_with_path(tree):
+        path = "/".join(str(k.key) for k in path if k.key != "params")
+        shapes[path] = spec.shape
+        sizes[path] = int(np.prod(spec.shape))
+        sliver = tuple(min(n, 2) for n in spec.shape)
+        leaf = family.leaf(1, path, sliver, spec.dtype, DSV32)
+        assert leaf is not None and leaf.shape == sliver, path
+        assert leaf.dtype == np.dtype(spec.dtype), path
+
+    def int8_of(prefix, skip=()):
+        return sum(n for p, n in sizes.items() if p.startswith(prefix)
+                   and p.endswith("int8") and not any(s in p for s in skip))
+
+    # ISSUE 35's arithmetic, a layer: attention 187.1 M, indexer 14.0 M
+    # (0.46 M of it float32), a dense layer 597.4 M, an expert layer HERE
+    # 951.5 M of which 704.6 M are the 16 held experts
+    attention = int8_of("layer_1/", ("index_", "moe"))
+    assert 187.0e6 < attention < 187.2e6
+    indexer = int8_of("layer_1/index_") + sizes["layer_1/index_weights_proj"]
+    assert 13.9e6 < indexer < 14.0e6
+    assert 597.3e6 < int8_of("layer_0/") + sizes[
+        "layer_0/index_weights_proj"] < 597.5e6
+    held = int8_of("layer_1/moe/experts_")
+    assert 704.5e6 < held < 704.7e6
+    here = int8_of("layer_1/") + sizes["layer_1/index_weights_proj"] \
+        + sizes["layer_1/moe/router"]
+    assert 951.4e6 < here < 951.6e6
+    total = int8_of("") + sizes["embed/embedding"]
+    assert 6.5e9 < total < 6.6e9            # 6.31 + 0.116 + 0.116 G values
+    assert shapes["layer_3/moe/experts_down_int8"] == (16, 2048, 7168)
+    assert shapes["layer_3/moe/router"] == (7168, 256)
+    assert shapes["layer_3/moe/e_score_correction_bias"] == (256,)
+    assert shapes["layer_3/index_wq_b/kernel_int8"] == (1536, 64 * 128)
+    assert shapes["layer_3/q_b_proj/kernel_int8"] == (1536, 128 * 192)
+    assert shapes["lm_head/kernel_int8"] == (7168, 16160)
+    assert "layer_0/moe/router" not in shapes and "layer_0/q_proj" not in str(
+        list(shapes))
+    with pytest.raises(ValueError, match="deepseek-v32.*k_proj"):
+        weights.leaf(DSV32, "layer_2/k_proj/kernel", (2, 2), "bfloat16")
+    with pytest.raises(ValueError, match="noaux_tc"):
+        family.dims_of(dict(DSV32, topk_method="greedy"))
+
+
+def test_deepseek_v32s_seeded_values_are_what_the_configuration_says():
+    family = families.of(DSV32)
+    leaf = weights.leaf
+    assert np.all(leaf(DSV32, "layer_2/q_a_norm/scale", (64,), "float32") == 1)
+    assert np.all(leaf(DSV32, "layer_2/index_k_norm_bias", (8,), "float32")
+                  == 0)
+    np.testing.assert_allclose(
+        leaf(DSV32, "layer_2/index_wq_b/scale", (1, 8), "float32"),
+        1 / (127 * 1536 ** 0.5), rtol=1e-6)
+    # what makes the selection matter: q_b_proj's scale, and it alone
+    np.testing.assert_allclose(
+        leaf(DSV32, "layer_2/q_b_proj/scale", (1, 8), "float32"),
+        family.Q_GAIN / (127 * 1536 ** 0.5), rtol=1e-6)
+    assert family.Q_GAIN == 1.5
+    w = leaf(DSV32, "layer_2/index_weights_proj", (7168, 64), "float32")
+    assert w.dtype == np.float32 and (w < 0).mean() > 0.4
+    # an expert is drawn under its PUBLISHED id: another share of the same
+    # layer (experts 16-31) holds other experts, kin to the same common draw
+    stack = "layer_2/moe/experts_up_int8"
+    here = leaf(DSV32, stack, (2, 64, 32), "int8")
+    there = weights.leaf(dict(DSV32, first_routed_expert=16), stack,
+                         (2, 64, 32), "int8")
+    again = weights.leaf(dict(DSV32, first_routed_expert=1), stack,
+                         (1, 64, 32), "int8")
+    assert np.array_equal(again[0], here[1])
+    assert not np.array_equal(here, there)
+    assert np.corrcoef(here.ravel().astype(float),
+                       there.ravel().astype(float))[0, 1] > 0.9
+
+
+def test_what_a_sparse_step_needs_by_hand():
+    family = families.of(DSV32)
+    d = family.dims_of(DSV32)
+    layers, routed = 7, 6
+    attention = 7168 * 1536 + 1536 * 128 * 192 + 7168 * 576 \
+        + 512 * 128 * 256 + 128 * 128 * 7168
+    indexer = 1536 * 64 * 128 + 7168 * 128 + 4 * 7168 * 64
+    expert = 3 * 7168 * 2048
+    # ISSUE 35: a cached token of a layer is 1152 B of latent row and 256 B
+    # of indexer key; the sparse side NEEDS, at 4 rows of 11k, 0.15 GB a step
+    need = family.dsa_step_bytes(DSV32, rows=4, visible=11000, selected=2048)
+    assert need == 4 * layers * (11000 * 256 + 2048 * 1152)
+    assert 0.14e9 < need < 0.16e9
+    # with so many rows that every HELD expert is touched and no context: a
+    # step reads every int8 kernel once, the float32 routers and the
+    # indexer's float32 head weights
+    once = layers * (attention + indexer) + 3 * 7168 * 18432 \
+        + 7168 * 16160 + routed * (4 * 7168 * 256 + expert + 16 * expert)
+    assert family.decode_step_bytes(DSV32, rows=1e9, context=0) == once
+    # at the cell's 4 rows: 1.9 of the 16 held experts a layer (4 x 8 picks x
+    # 16 / 256 = 2 assignments), a weight stream of about 2.7 GB
+    assert 1.8 < family.experts_touched(d, 4) < 2.0
+    step = family.decode_step_bytes(DSV32, rows=4, context=11000)
+    assert step == pytest.approx(
+        once - routed * (16 - family.experts_touched(d, 4)) * expert + need)
+    assert 2.6e9 < step - need < 2.8e9
+    # the selection caps what attention reads, not what the indexer scores
+    short = family.decode_step_bytes(DSV32, rows=4, context=1000)
+    assert step - short == 4 * layers * (10000 * 256 + 1048 * 1152)
+    flops = family.decode_step_flops(DSV32, rows=1, context=11000)
+    token = layers * (attention + indexer - 3 * 7168 * 64) \
+        + 3 * 7168 * 18432 + routed * (7168 * 256 + 1.5 * expert)
+    assert flops == pytest.approx(
+        2 * token + 2 * 7168 * 16160 + layers * (
+            2 * 64 * 128 * 11000 + 2 * 128 * 2048 * (2 * 512 + 64)))
+    # ISSUE 35: a 12288 prompt is about 14 TFLOP a layer
+    pre = family.prefill_flops(DSV32, rows=1, seq_len=12288)
+    assert 13e12 * layers < pre < 16e12 * layers
+
+
+def test_each_fault_of_sparse_attention_is_seen_and_the_reference_is_not_moved(
+        capsys, tmp_path):
+    family = families.of(DSV32)
+    twin = json.loads((BENCH / "configs" / "rehearsal-dsa.json").read_text())
+    ids = np.random.default_rng(5).integers(1, twin["vocab_size"], (2, 80))
+    rows, at = np.repeat(np.arange(2), 40), np.tile(np.arange(40, 80), 2)
+    alone = np.asarray(family.walk(twin, ids, rows, at, (False,))[False])
+    flags = (False, True) + family.FAULTS
+    assert family.FAULTS == (
+        "no_indexer", "topk_half", "stale_index", "index_interleaved",
+        "no_mscale", "no_yarn", "no_groups", "no_q_norm", "int4_experts")
+    streams = family.walk(twin, ids, rows, at, flags)
+    assert np.array_equal(np.asarray(streams[False]), alone)
+    moved = {flag: float(np.abs(np.asarray(streams[flag]) - alone).max())
+             for flag in flags[1:]}
+    assert all(v > 3e-3 for v in moved.values()), moved
+    assert min(moved["no_indexer"], moved["topk_half"]) > 1.0
+    # the command a limit's readings come from (PERF.md section 2)
+    path = tmp_path / "twin.json"
+    path.write_text(json.dumps(twin))
+    assert family.main(["--config", str(path), "--seeds", "3", "--length",
+                        "80", "--served", "40", "--rows", "2"]) == 0
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert set(line) == {"seed", "int4", *family.FAULTS}
+    assert all(line[k]["widest_gap"] >= 0 for k in line if k != "seed")
+    assert family.main(["--config", str(path), "--seeds", "3", "--length",
+                        "48", "--served", "8", "--faults",
+                        "int4,topk_half"]) == 0
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert set(line) == {"seed", "int4", "topk_half"}
+
+
+def test_the_dsa_twin_runs_the_whole_command_and_counts_its_keys(
+        capsys, tmp_path, monkeypatch):
+    """The whole command over the toy twin (contexts of 32-80 positions
+    against a top-16, experts 4-7 of 16 held). A run whose warm-up's burst
+    did not arrive as one group says so (``still_missing``) and is made
+    again over the bundle it built, as the eva twin's is."""
+    from benchmark import harness
+
+    for key in ("JAX_PLATFORMS", "XLA_FLAGS"):
+        monkeypatch.setenv(key, os.environ.get(key, ""))
+    windows = []
+    run_window = harness.run_window
+    monkeypatch.setattr(harness, "run_window", lambda *a, **kw: windows.append(
+        run_window(*a, **kw)) or windows[-1])
+    for attempt in range(5):
+        rc = run.main(["--manifest", str(DSA_TWIN_MANIFEST), "--workload",
+                       DSA_TWIN_CELL, "--seed", str(2**31 + 11 + attempt),
+                       "--seconds", "3", "--trace", "1",
+                       "--work-dir", str(tmp_path)])
+        lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+                 if ln.startswith("{")]
+        warm = next(ln for ln in lines if ln.get("stage") == "warmup")
+        if not warm["still_missing"]:
+            break
+    last = lines[-1]
+    assert rc == 0 and last["correct"] is True, lines[-3:]
+    assert last["device"]["platform"] == "cpu" and last["failed"] == 0
+    window = next(ln for ln in lines if ln.get("stage") == "window")
+    assert window["compiles_in_window"] == 0, (warm, window)
+    # prompts of 8-32 tokens: most steps attend 16 keys, the first of the
+    # shortest prompts fewer
+    keys = last["metrics"]["dsa_keys_per_query"]["value"]
+    assert 14 < keys <= 16
+    assert 5 < last["metrics"]["moe_local_share"]["value"] < 60
+    # over the server's life, warm-up included: a booked row-step attends
+    # min(context, 16) keys, so never more than 16 and never more than it saw
+    dsa = windows[-1]["m_close"]["handler"]["dsa"]
+    moe = windows[-1]["m_close"]["handler"]["moe"]
+    assert 0 < dsa["keys_selected"] <= 16 * dsa["row_steps"]
+    assert dsa["keys_selected"] < dsa["keys_visible"]
+    assert moe["assignments"] == dsa["row_steps"] * 2 * 4   # layers x picks
+    assert moe["local_assignments"] == sum(moe["load"][4:8])
+
+
+def test_the_prefill_share_is_the_window_less_its_decode_steps(monkeypatch):
+    """``dsa_prefill_share``: the window's seconds less its decode steps at
+    the traced step's device time; nothing without a trace, without
+    segments, or on a program of another family."""
+    from benchmark import harness, scopes
+
+    reader = harness.layer_metric("dsa_prefill_share")
+
+    def scrape(segments):
+        return {"handler": {"batching": {"segments_run": segments,
+                                         "segment": 16}}}
+
+    ctx = {"family": families.of(DSV32), "trace": {"busy_s": 2.0},
+           "m_open": scrape(100), "m_close": scrape(100 + 290)}
+    monkeypatch.setattr(scopes, "step_ms", lambda ctx, names=None: 5.0)
+    monkeypatch.setattr("sys.argv", ["run.py", "--seconds", "50"])
+    # 290 segments x 16 steps x 5 ms = 23.2 s of 50: 53.6 % is not decode
+    assert reader.read(ctx) == pytest.approx(53.6)
+    monkeypatch.setattr("sys.argv", ["run.py", "--seconds=25"])
+    assert reader.read(ctx) == pytest.approx(7.2)
+    assert reader.read(dict(ctx, m_close=scrape(100))) is None
+    assert reader.read(dict(ctx, m_open={})) is None
+    assert reader.read(dict(ctx, family=families.load("llama-hf"))) is None
+    monkeypatch.setattr("sys.argv", ["run.py"])
+    assert reader.read(ctx) is None
+    monkeypatch.setattr(scopes, "step_ms", lambda ctx, names=None: None)
+    monkeypatch.setattr("sys.argv", ["run.py", "--seconds", "50"])
+    assert reader.read(ctx) is None                      # an untraced run
+
+
+def test_the_dsa_readers_take_the_windows_delta_or_nothing():
+    """The readers PR 35 added: what they read, and None where the
+    program has no such counter or scope (a llama cell; the parent)."""
+    from benchmark import harness
+
+    def metrics(dsa, moe):
+        return {"handler": {"dsa": dsa, "moe": moe}}
+
+    a = metrics(dict(row_steps=1600, keys_selected=3_000_000,
+                     keys_visible=16_000_000),
+                dict(assignments=76800, local_assignments=4800))
+    b = metrics(dict(row_steps=1600 + 640, keys_selected=3_000_000 + 640 * 2048,
+                     keys_visible=16_000_000 + 640 * 11000),
+                dict(assignments=76800 + 30720, local_assignments=4800 + 1920))
+    ctx = {"m_open": a, "m_close": b}
+    assert harness.layer_metric("dsa_keys_per_query").read(ctx) == 2048.0
+    assert harness.layer_metric("dsa_keys_per_query").means(ctx) == (
+        2048.0, 11000.0)
+    assert harness.layer_metric("moe_local_share").read(ctx) == 6.25
+    empty = {"m_open": {"handler": {}}, "m_close": {"handler": {}}}
+    for name in ("dsa_keys_per_query", "moe_local_share"):
+        assert harness.layer_metric(name).read(empty) is None
+        assert harness.layer_metric(name).read({"m_open": a, "m_close": a}) \
+            is None
+    # the parent's handler.moe has no local count: nothing, not a KeyError
+    old = {"handler": {"moe": {"assignments": 5}}}
+    assert harness.layer_metric("moe_local_share").read(
+        {"m_open": old, "m_close": {"handler": {"moe": {"assignments": 9}}}}
+    ) is None
+    llama = {"family": families.load("llama-hf"), "trace": {"busy_s": 1},
+             "slice": {"live": [(4, 11000.0)]},
+             "device": {"kind": "TPU v5 lite"}, "config": {}, **empty}
+    for name in ("dsa_index_ms", "dsa_select_ms", "dsa_cache_hbm_pct",
+                 "dsa_prefill_share"):
+        assert harness.layer_metric(name).read(llama) is None
+    # no trace: no share, whatever the counters say
+    assert harness.layer_metric("dsa_cache_hbm_pct").read(
+        {"family": families.of(DSV32), "config": DSV32, "trace": None,
+         "slice": {"live": [(4, 11000.0)]}, "device": {"kind": "TPU v5 lite"},
+         **ctx}) is None
+    manifest = json.loads((REPO / "BENCHMARK.json").read_text())
+    for name in ("dsa_index_ms", "dsa_select_ms", "dsa_cache_hbm_pct",
+                 "dsa_keys_per_query", "moe_local_share",
+                 "dsa_prefill_share"):
+        entry = next(m for m in manifest["per_layer"] if m["name"] == name)
+        assert entry["workloads"] == [DSA_CELL]
+    for name in ("out_tok_s", "decode_hbm_pct", "hbm_peak_gb",
+                 "engine_host_ms", "decode_step_ms", "decode_matmul_ms",
+                 "decode_attend_ms", "decode_sample_ms", "decode_moe_ms",
+                 "mla_absorb_ms", "moe_load_max_share", "moe_experts_read"):
+        entry = next(m for m in manifest["end_to_end"] + manifest["per_layer"]
+                     if m["name"] == name)
+        assert entry["workloads"][-1] == DSA_CELL, name
+    # moe_hbm_pct takes its experts from the live rows alone: not this cell's
+    assert DSA_CELL not in next(m for m in manifest["per_layer"]
+                                if m["name"] == "moe_hbm_pct")["workloads"]
+    assert not hasattr(families.of(DSV32), "moe_step_bytes")
+
+
+def test_a_sparse_decode_step_at_4_rows_is_bound_by_its_weight_bytes():
+    """The cell's premise: at 4 rows of 11k the bytes take ten times longer
+    than the operations, nine tenths of the bytes are weights, and what the
+    sparse side NEEDS is a twentieth: what it takes is the finding."""
+    family = families.of(DSV32)
+    need = family.decode_step_bytes(DSV32, rows=4, context=11000)
+    flops = family.decode_step_flops(DSV32, rows=4, context=11000)
+    assert need / V5E.hbm_bytes_s > 10 * flops / V5E.bf16_flops
+    assert family.decode_step_bytes(DSV32, rows=4, context=0) > 0.9 * need
+    assert 3.2e-3 < need / V5E.hbm_bytes_s < 3.7e-3
 
 
 # -- the yardstick against the program ---------------------------------------

@@ -474,3 +474,115 @@ def test_an_eva_prefill_keeps_a_turns_scores_in_the_fast_memory(
               for layout in re.findall(r"f32\[[\d,]+\]\{[^}]*\}", shapes)]
     assert scores, "no fusion hands on a score tensor: the program changed"
     assert any("S(1)" not in layout for layout in scores) == in_hbm, scores
+
+
+# deepseek-v32-exp's widths (benchmark/configs/deepseek-v32-exp.json): 4
+# slots of 16384, this chip's 16 of 256 experts and its slice of the head
+DEEPSEEK_V32 = dict(
+    vocab_size=16160, hidden=7168, heads=128, kv_heads=128, mlp=18432,
+    rope_theta=1e4, norm_eps=1e-6, max_len=32768, attn_kind="latent",
+    qk_nope=128, qk_rope=64, v_head=128, kv_lora_rank=512,
+    rope_interleave=True, q_lora_rank=1536, index_heads=64,
+    index_head_dim=128, index_topk=2048, ffn_kind="routed",
+    first_dense_layers=1, moe_experts=256, moe_top_k=8, moe_intermediate=2048,
+    n_shared_experts=1, routed_scaling_factor=2.5, scoring_func="sigmoid",
+    moe_n_group=8, moe_topk_group=4, moe_experts_held=16, moe_first_expert=0,
+    rope_scaling=("yarn", 40.0, 4096.0, 32.0, 1.0, 1.0))
+
+
+def test_a_sparse_segment_compiles_and_what_it_does_with_its_three_leaves(
+        v5e, monkeypatch):
+    """The engine's segment at ``deepseek-v32-exp.long-context-decode``'s own
+    shape key (4 slots of 16384, the full window, 1 + 6 layers, 16 steps),
+    lowered as a TPU backend lowers it. ~40 s.
+
+    It compiles for the chip, and every scope ``benchmark/families/
+    deepseek_v32.py`` gathers a trace's operations by is the op_name of some
+    operation: ``dsa_index`` and ``dsa_select`` beside the latent and routed
+    kinds' (no ``kv_window``: the cell decodes in the full window).
+
+    What is recorded of the compiler, not asked of it. The selection is a
+    mask found by an exact bit-by-bit threshold: NO sort lies under
+    ``dsa_select`` (``jax.lax.top_k`` of the ``[4, 16384]`` scores lowers to
+    one full stable sort a layer on this backend, and the gather of the
+    picked rows behind it was the slower form on the chip: PERF.md section
+    6, PR 35). The three-leaf per-step write stays in place: nothing in the
+    loop produces an array of a cache leaf's whole shape but the loop's own
+    tuple and READS, some of them whole-leaf prefetches into the fast
+    memory ahead of the masked read (``copy-done`` into ``S(1)``: at most
+    one a leaf). So the latent kind keeps its per-step write under sparse
+    attention too (``llama.segment_keeps_tail``).
+
+    The routed sum holds no Mosaic call: an expert of 3 x 7168 x 2048 int8
+    is too wide for ``picked_experts`` to hold whole, ``RoutedMLP`` sees
+    that from the shapes and runs the grouped loop, whose trip count is the
+    picks'."""
+    from benchmark.families import deepseek_v32
+    from lambdipy_tpu.models import llama, moe
+
+    monkeypatch.setattr(moe, "kernels_compile_here", lambda: True)
+    layers, slots, window = 7, 4, 16384
+    text = _decode_segment_text(v5e, steps=16, layers=layers, window=window,
+                                cache_len=window, rows=slots, **DEEPSEEK_V32)
+    found = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        found.update(op_name.split("/"))
+    assert set(deepseek_v32.SCOPES) - {"kv_window"} <= found
+    sorts = re.findall(r' sort\([^\n]*op_name="([^"]*)"', text)
+    assert sorts and not any("dsa_select" in name for name in sorts)
+    assert "tpu_custom_call" not in text
+    leaves = {(slots, window, 1, width) for width in (512, 64, 128)}
+    writes = cache_writes_in_loops(text, leaves)
+    assert {opcode for _, opcode, _ in writes} <= {"copy-done"}
+    assert len(writes) <= 3 * layers
+    assert not llama.segment_keeps_tail(llama.LlamaConfig(
+        layers=layers, **DEEPSEEK_V32))
+
+
+def test_a_sparse_prefill_keeps_a_turns_scores_in_the_fast_memory(v5e,
+                                                                  monkeypatch):
+    """The solo prefill of the cell's 12288 bucket (three key blocks of
+    4096), one layer. ~45 s.
+
+    No operation of the program produces a ``[heads, s, s]`` score: the
+    largest float32 tensor a fusion hands on is a GROUP of heads' scores of
+    one block of 128 queries (``llama.DSA_SCORE_BYTES``: 4 heads at 12288
+    keys, 24 MiB), and every one of them lies in the chip's fast memory
+    (``S(1)`` in its layout), as do the index scores a turn selects by;
+    whole, a turn's 128 heads x 128 x 12288 float32 scores are 0.8 GB."""
+    from lambdipy_tpu.models import llama, moe
+
+    monkeypatch.setattr(moe, "kernels_compile_here", lambda: True)
+    cfg = llama.LlamaConfig(layers=1, dtype=jnp.bfloat16, quant="int8",
+                            **DEEPSEEK_V32)
+    model = llama.LlamaModel(cfg)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e),
+            tree)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                                    jnp.zeros((1, 8), jnp.int32)))
+    server = llama.LlamaServer(model, None)
+    key = ("stream", 1, cfg.prompt_bucket(9000, 16), 16384, 16)
+    assert key[2] == 12288
+    assert llama.DSA_QUERY_BLOCK == 128
+    assert llama._head_group(128, 128 * 12288) == 4
+    operands = on_chip(jax.eval_shape(lambda: server._aot_examples(key))[0])
+    text = server._stream_fns(*key[1:])[0].lower(
+        params, *operands).compile().as_text()
+    assert not re.search(r"f32\[(?:1,)?128,\d{4,},\d{4,}\]", text)
+    # (a fusion that only bitcasts hands on a view, not a tensor)
+    handed_on = re.findall(
+        r"= \(?((?:\w+\[[\d,]+\]\{[^}]*\}(?:, )?)+)\)? fusion\("
+        r"(?![^\n]*calls=%bitcast_fusion)", text)
+    scores = [layout for shapes in handed_on for layout in re.findall(
+        r"f32\[(?:\d+,)+(?:12288|8192)\]\{[^}]*\}", shapes)
+        if layout.count(",") >= 3]
+    assert any(s.startswith("f32[4,128,12288]") for s in scores), scores[:5]
+    assert all("S(1)" in layout for layout in scores), scores
+    found = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        found.update(op_name.split("/"))
+    assert {"dsa_index", "dsa_select", "attend", "qkv_proj"} <= found

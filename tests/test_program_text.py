@@ -43,7 +43,11 @@ A routed-FFN model's programs have no golden text: PR 28 gave its segment
 programs a second counter, and on a TPU backend their small calls take a
 Pallas kernel (``ops/grouped_experts.py``), which changes their cache keys
 on its own. What is held here is the other side of that dispatch: on this
-backend the toy twin's programs contain no kernel call at any call size."""
+backend the toy twin's programs contain no kernel call at any call size.
+Since PR 35, which put query compression, sparse attention, grouped routing
+and a chip's share of the experts into the same latent and routed paths,
+``kanana2-30b``'s four programs are held as THIS backend lowers them, at the
+cell's own shape key, like the two llama cells'."""
 
 import hashlib
 import json
@@ -106,6 +110,12 @@ CELL_GOLDEN = {
         "68b128eeb1bf", "dbf45417a475", "ca9524f17c20", "d592697fcc05")),
     "deepseek7b": (256, (
         "c9cc88b7ee32", "9eb36fcacfd8", "27bc15b2c4bf", "5e84d28f90b7")),
+    # the latent kind beside its sparse form (PR 35), taken on PR 35's
+    # parent (commit 4a60241), as THIS backend lowers them: the pure-jax
+    # forms of the routed sum (on a TPU the small calls hold a Pallas kernel
+    # and another text; tests/test_chip_compile.py compiles that side)
+    "kanana2-30b": (512, (
+        "6ec1c0eee436", "e79f79d82119", "722cd8459188", "7720ae423518")),
 }
 
 
