@@ -41,6 +41,17 @@ Design (all device work rides LlamaServer's compiled-program cache):
   in-flight segments) so packing sees host-truth slots and a
   host-materialized carry. The engine exits when idle and restarts on
   the next request.
+- A row's FIRST token does not wait for a segment. The prefill selects
+  it and the decode scan re-emits the token it consumes, so column 0 of
+  a row's first block is the token the pack wrote into the batch
+  carry, unchanged. The engine reads the packed carry's ``tok`` leaf
+  right after the dispatch that follows the pack (``deliver_first``:
+  phase ``eng.first``, fault site ``first_fetch``) and hands the token
+  over; the collector books the first block from its second column. A
+  stream's chunks are therefore 1 token, then ``segment - 1``, then
+  whole segments, and a stream stops being replayable when that one
+  token has been yielded. ``stats()`` ``first_tokens_early`` counts the
+  rows; beside ``requests_served`` it is a share, 1.0 expected.
 - Per-row independence makes this exact: each row's attention reads only
   its own cache row and position (models/llama.py ragged decode), so a
   row's greedy tokens are identical whether it decodes solo or packed
@@ -512,6 +523,11 @@ class ContinuousBatcher:
         # prefix= or the automatic radix store): suffix-only
         # continuation carries packed into the shared batch
         self.prefix_joins = 0
+        # rows whose first token (the prefill's, read from the packed
+        # carry) went out before their first segment was collected;
+        # beside requests_served a share, 1.0 when every row took that
+        # path
+        self.first_tokens_early = 0
 
     def set_prefill_mode(self, mode) -> str:
         """Resolve + apply the ``prefill_mode`` knob (``chunked`` |
@@ -962,11 +978,15 @@ class ContinuousBatcher:
         from lambdipy_tpu.models.llama import _lookup_draft_hit
 
         k = kb if k is None else max(2, min(int(k), kb))
-        base = ((entry.get("prefix_toks") or []) + entry["row"]
-                + entry["toks"])
+        toks, pend = entry["toks"], entry.get("spec_pend")
+        if entry.get("early"):
+            # the prefill's token went out ahead of the row's first
+            # verify block, which is not collected yet: it is that
+            # step's pending, fetched already
+            toks, pend = toks[:-1], toks[-1]
+        base = (entry.get("prefix_toks") or []) + entry["row"] + toks
         if q is None:
             q = entry["spec_inflight"]
-        pend = entry.get("spec_pend")
         if provider == "aux" and self.draft_provider is not None:
             # the aux draft model extrapolates the same way lookup
             # does: it proposes across the q assumed-accepted in-flight
@@ -1307,6 +1327,7 @@ class ContinuousBatcher:
                     entry["replays"] += 1
                     entry["toks"], entry["lps"] = [], []
                     entry["disp"] = 0
+                    entry["early"] = 0
                     entry["eos_at"] = None
                     entry["slot"] = None
                     entry["packed"] = False
@@ -1366,6 +1387,28 @@ class ContinuousBatcher:
 
     # -- engine --------------------------------------------------------------
 
+    def _book_locked(self, entry: dict, base: int) -> None:
+        """The eos / ``n`` bookkeeping of the tokens a row has just been
+        handed (``entry["toks"][base:]``: a collected block, or the one
+        token deliver_first sent ahead of it); the engine lock is held."""
+        eos = entry["eos_id"]
+        if eos is not None and entry["eos_at"] is None:
+            # scan only the newly appended tokens (the old
+            # `eos in entry["toks"]` rescan was O(n^2) over a long
+            # decode) and record the first-hit index so truncation needs
+            # no second scan — an eos INSIDE an accepted draft block
+            # lands here like any other token
+            new = entry["toks"][base:]
+            if eos in new:
+                entry["eos_at"] = base + new.index(eos)
+        if entry["eos_at"] is not None or len(entry["toks"]) >= entry["n"]:
+            entry["done"] = True
+            self.requests_served += 1
+            if entry["replays"]:
+                # a requeued row completed through the restarted
+                # engine — the replay delivered
+                self.fault_stats.record_replays(succeeded=1)
+
     def _engine_loop(self, gen: int):
         try:
             self._engine_body(gen)
@@ -1402,9 +1445,13 @@ class ContinuousBatcher:
         # keeps DecodeWindowStats truthful about queued segments)
         inflight: deque = deque()
         # the loop's leaf phases (eng.barrier, eng.prefill, eng.pack,
-        # eng.dispatch, eng.wait, eng.fetch, eng.book): one is open at
-        # any moment from here to the loop's exit, none encloses another
+        # eng.dispatch, eng.first, eng.wait, eng.fetch, eng.book): one is
+        # open at any moment from here to the loop's exit, none encloses
+        # another
         phase = spans.phases()
+        # rows packed since the last dispatch: their first tokens go out
+        # after it (deliver_first)
+        firsts: list = []
 
         def rids(entries) -> str:
             """The requests a phase works for, as its ``rids`` argument."""
@@ -1417,6 +1464,46 @@ class ContinuousBatcher:
         # must not divide device_busy_s by only the COMPLETED episodes'
         # wall (0.0 on the first, > 1.0 ratios later)
         pstats.begin_episode(ep_t0)
+
+        def deliver_first(tok, lp):
+            """Eager delivery of the prefill's token. The prefill selects
+            a row's first token and the scan re-emits the token it
+            consumes, so column 0 of the row's first block IS the carry's
+            token, carried through a whole segment untouched. It goes out
+            here instead, read from the PACKED batch carry (``tok`` and
+            ``lp``, its ``[B]`` leaves as the first segment takes them)
+            right after that segment's dispatch: the device has prefill,
+            pack and segment queued and never waits for this read, and
+            the read returns when the device has run the pack, so the
+            row's first segment is what runs next and nothing but that
+            segment lies between a stream's first two chunks. One
+            ``device_get`` of whole leaves, indexed on the host: no
+            program is added. The collector books that first block from
+            its second column."""
+            rows = firsts[:]
+            firsts.clear()
+            phase.enter("eng.first", rids=rids(rows))
+            tok_h, lp_h = self._device_wait(
+                "first_fetch", gen, jax.device_get,
+                (tok, lp if any(e["want_lp"] for e in rows) else None))
+            with self._lock:
+                if gen != self._gen:
+                    # a failure handler reset these entries meanwhile: a
+                    # token booked now would lead the replay's own
+                    raise _StaleEngine()
+                for entry in rows:
+                    if entry["done"]:
+                        continue  # cancelled at a barrier since its pack
+                    slot = entry["slot"]
+                    entry["toks"].append(int(tok_h[slot]))
+                    if entry["want_lp"]:
+                        entry["lps"].append(float(lp_h[slot]))
+                    entry["early"] = 1
+                    self.first_tokens_early += 1
+                    # an eos here, or max_new_tokens 1, finishes the
+                    # request: its first block is over-decode
+                    self._book_locked(entry, 0)
+                self._lock.notify_all()
 
         def collect_one():
             """The collector stage: fetch the OLDEST in-flight segment
@@ -1525,14 +1612,15 @@ class ContinuousBatcher:
                             entry["disp"] -= (kb_rec - c)
                         continue
                     self.rows_in_segments += 1
-                    row_toks = (block[slot][:c] if kb_rec
-                                else block[slot]).tolist()
+                    # a row's FIRST block begins with the prefill's
+                    # token, which deliver_first sent when the row was
+                    # packed: book it from its second column
+                    early, entry["early"] = entry["early"], 0
                     base = len(entry["toks"])
-                    entry["toks"].extend(row_toks)
+                    entry["toks"].extend(block[slot][early:c].tolist())
                     if lp_block is not None:
                         entry["lps"].extend(
-                            (lp_block[slot][:c] if kb_rec
-                             else lp_block[slot]).tolist())
+                            lp_block[slot][early:c].tolist())
                     if kb_rec:
                         # reconcile the optimistic dispatch accounting:
                         # disp assumed the full kb advance; the step
@@ -1555,25 +1643,7 @@ class ContinuousBatcher:
                                 emitted=c, hit=bool(hit),
                                 provider=prov, k=k_used)
                             self._spec_adapt(entry, prov, k_used, c)
-                    eos, n = entry["eos_id"], entry["n"]
-                    if eos is not None and entry["eos_at"] is None \
-                            and eos in row_toks:
-                        # scan only the newly appended block (the old
-                        # `eos in entry["toks"]` rescan was O(n^2) over
-                        # a long decode) and record the first-hit index
-                        # so truncation needs no second scan — an eos
-                        # INSIDE an accepted draft block lands here like
-                        # any other token
-                        entry["eos_at"] = base + \
-                            entry["toks"][base:].index(eos)
-                    if entry["eos_at"] is not None \
-                            or len(entry["toks"]) >= n:
-                        entry["done"] = True
-                        self.requests_served += 1
-                        if entry["replays"]:
-                            # a requeued row completed through the
-                            # restarted engine — the replay delivered
-                            self.fault_stats.record_replays(succeeded=1)
+                    self._book_locked(entry, base)
                 self._lock.notify_all()
             # fetch clock starts AFTER block_until_ready so fetch_block_s
             # measures only the device_get transport window, not the
@@ -1780,6 +1850,7 @@ class ContinuousBatcher:
                             self._lock.notify_all()
                         raw = []
                 phase.enter("eng.pack", rids=rids(raw + carried))
+                firsts.extend(raw + carried)
                 for src, joiner in enumerate(raw):
                     if pool is not None:
                         # scalars into the 5-leaf carry, the KV row
@@ -2054,6 +2125,10 @@ class ContinuousBatcher:
                             pool.arena = new_arena
                         return out, (f2, lp2, pos2, done2, rng2)
 
+                    # the batch carry as this segment takes it: its tok
+                    # and lp leaves hold the first tokens of the rows
+                    # packed since the last dispatch (deliver_first)
+                    tok, lp = self._carry[:2]
                     outs, self._carry = self._device_wait(
                         "segment_dispatch", gen, dispatch)
                     moe = []
@@ -2085,6 +2160,8 @@ class ContinuousBatcher:
                                     "assumed": assumed})
                     inflight.append(rec)
                     pstats.record_dispatch(len(inflight))
+                    if firsts:
+                        deliver_first(tok, lp)
                     if len(inflight) >= eff_depth:
                         collect_one()
                 # ---- drain: collect everything behind the frontier so
@@ -2152,6 +2229,10 @@ class ContinuousBatcher:
                  # segments are in flight) — the device-side decode
                  # position the pipelined loop windows and quotas by
                  "disp": 0,
+                 # 1 from the moment the prefill's token went out ahead of
+                 # the row's first block (deliver_first) until the
+                 # collector has booked that block from its second column
+                 "early": 0,
                  # absolute index of the row's first eos token, recorded
                  # by the collector's incremental block scan; None until
                  # (unless) one appears
@@ -2274,14 +2355,16 @@ class ContinuousBatcher:
                 if need > self.pool.capacity_pages:
                     return None
                 self._charge_pages(entry, s + max_new_tokens)
-            # The engine's segments emit the tokens either way (the
-            # scan re-emits the carry's first token, so everything
-            # flows from the segment outputs — nothing is delivered
-            # eagerly). Short prompts enqueue RAW and the engine
-            # prefills waiting joiners together in one ragged call;
-            # long prompts prefill here on the request thread — in
-            # chunks when the server has prefill_chunk, so engine
-            # segments interleave instead of stalling.
+            # Short prompts enqueue RAW and the engine prefills waiting
+            # joiners together in one ragged call; long prompts prefill
+            # here on the request thread — in chunks when the server
+            # has prefill_chunk, so engine segments interleave instead
+            # of stalling. Either way the prefill's token goes out when
+            # the ENGINE has packed the row and the device has run the
+            # pack (deliver_first), not from here: one sent before the
+            # row's first segment is next on the device would put the
+            # wait for a slot, or for another request's prefill queued
+            # ahead of the pack, between a stream's first two tokens.
             try:
                 if s <= self.group_prefill_max:
                     entry["carry"] = None
@@ -2413,15 +2496,18 @@ class ContinuousBatcher:
                         seed: int = 0, eos_id=None, segment: int = 16,
                         prefix=None, return_logprobs: bool = False):
         """Streaming over the SHARED engine batch (VERDICT r5 #3b): the
-        row joins in-flight decode like any other request and its slice
-        of each segment is yielded as it lands — segment-boundary
-        delivery IS a stream, so streamed requests no longer bypass
-        continuous batching. Yields ``[1, k]`` chunks ((tokens,
-        logprobs) pairs when asked); concatenated chunks equal the
-        non-streamed ``generate`` output up to the segment containing
-        eos, exactly like ``LlamaServer.generate_stream``. The chunk
-        cadence is the ENGINE's segment size (the per-request
-        ``segment`` knob applies only to the solo fallback)."""
+        row joins in-flight decode like any other request and what the
+        engine hands it is yielded as it lands, so streamed requests no
+        longer bypass continuous batching. Yields ``[1, k]`` chunks
+        ((tokens, logprobs) pairs when asked): the first holds ONE
+        token, the prefill's, sent when the engine has packed the row
+        (``deliver_first``); the second the other ``segment - 1`` of the
+        row's first segment; every later one a whole segment (the
+        ENGINE's size: the per-request ``segment`` knob applies only to
+        the solo fallback; a verify step's accepted tokens under
+        ``spec_k``). Concatenated chunks equal the non-streamed
+        ``generate`` output up to the chunk containing eos, whose rest
+        is eos filler, like ``LlamaServer.generate_stream``'s."""
         import numpy as np
 
         entry = self._admit(prompt_row, max_new_tokens, temperature, top_k,
@@ -2532,6 +2618,7 @@ class ContinuousBatcher:
                     "segments_run": self.segments_run,
                     "rows_in_segments": self.rows_in_segments,
                     "requests_served": self.requests_served,
+                    "first_tokens_early": self.first_tokens_early,
                     "prefill_groups": self.prefill_groups,
                     "rows_group_prefilled": self.rows_group_prefilled,
                     "prefix_joins": self.prefix_joins,

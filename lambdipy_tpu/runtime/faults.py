@@ -11,6 +11,8 @@ site                      where it fires
 ========================  ====================================================
 ``segment_dispatch``      the engine thread dispatching a decode segment
 ``segment_fetch``         the per-segment ``device_get`` in the collector
+``first_fetch``           the engine's read of the first tokens of the rows it
+                          has just packed, after the dispatch that follows
 ``group_prefill``         the engine's ragged b-row joiner prefill
 ``prefix_assemble``       continue-prefill from a cached prefix KV
 ``prefix_walk``           the prefix store's cold-walk, once per chunk
@@ -113,6 +115,10 @@ REGISTRY: dict[str, FaultSite] = {s.name: s for s in (
               "the engine thread dispatching a decode segment"),
     FaultSite("segment_fetch", "engine", _ENGINE_ENV,
               "the per-segment device_get in the collector"),
+    FaultSite("first_fetch", "engine", _ENGINE_ENV,
+              "the engine's read of the first tokens of the rows it has "
+              "just packed (the packed carry's tok leaf), after the "
+              "dispatch that follows the pack"),
     FaultSite("group_prefill", "engine", _ENGINE_ENV,
               "the engine's ragged b-row joiner prefill"),
     FaultSite("prefix_assemble", "engine", _ENGINE_ENV,

@@ -261,23 +261,108 @@ def test_done_but_undrained_row_survives_engine_error(tiny_server):
     assert faults["failures"].get("segment_fetch") == 1
 
 
-def test_streamed_row_with_delivered_bytes_errors_not_replays(tiny_server):
+@pytest.mark.parametrize("fetch,delivered", [(1, 1), (2, 4)],
+                         ids=["after_the_first_token",
+                              "after_the_first_segment"])
+def test_streamed_row_with_delivered_bytes_errors_not_replays(
+        tiny_server, fetch, delivered):
     """Once bytes reached the client a replay could splice a restarted
     decode onto the open stream — the row must surface the error as a
-    terminal event instead (and the stream must not hang)."""
+    terminal event instead (and the stream must not hang). The first
+    bytes are the prefill's token, sent when the row is packed: a failure
+    of the row's FIRST segment already finds the stream started."""
     # the transport delay before the failing fetch gives the consumer
-    # 150 ms to latch entry["streamed"] after chunk #1 is booked —
+    # 300 ms to latch entry["streamed"] on what was booked before it —
     # deterministic ordering, not a scheduler race
     cb = ContinuousBatcher(
         tiny_server, slots=2, segment=4,
         faults=FaultPlan.from_spec(
-            "transport:delay@seg=2,ms=150;segment_fetch:exception@seg=2"))
+            f"transport:delay@seg={fetch},ms=300;"
+            f"segment_fetch:exception@seg={fetch}"))
     chunks = []
     with pytest.raises(InjectedFault):
         for chunk in cb.generate_stream([1, 2, 3], max_new_tokens=16):
             chunks.append(chunk)
-    assert chunks, "the first segment should have streamed before the fault"
+    # exactly what went out before the fault, a prefix of the solo tokens,
+    # and nothing of a replay spliced behind it
+    got = np.concatenate(chunks, axis=1)
+    np.testing.assert_array_equal(
+        got, tiny_server.generate([1, 2, 3], max_new_tokens=16)[:, :delivered])
     assert cb.stats()["faults"]["replays"]["attempted"] == 0
+
+
+def test_failure_before_the_first_token_replays_a_stream_bitwise(tiny_server):
+    """The early read of the first tokens fails: no byte has reached the
+    client, so the row is requeued and its stream is, chunk for chunk, the
+    one a clean engine gives."""
+    cb = ContinuousBatcher(
+        tiny_server, slots=2, segment=4,
+        faults=FaultPlan.from_spec("first_fetch:exception@seg=1"))
+    kw = dict(temperature=0.9, seed=7)       # the PRNG chain restarts too
+    chunks = list(cb.generate_stream([1, 2, 3], max_new_tokens=11, **kw))
+    np.testing.assert_array_equal(
+        np.concatenate(chunks, axis=1),
+        tiny_server.generate([1, 2, 3], max_new_tokens=11, **kw))
+    stats = cb.stats()
+    assert stats["faults"]["failures"].get("first_fetch") == 1
+    assert stats["faults"]["replays"]["attempted"] == 1
+    assert stats["faults"]["replays"]["succeeded"] == 1
+    # the failed attempt delivered nothing: one row, one early token
+    assert stats["first_tokens_early"] == 1
+    assert stats["requests_served"] == 1
+
+
+def test_stale_generation_at_the_early_delivery_books_nothing(tiny_server):
+    """A failure handler requeues the rows between the early read and its
+    booking: the superseded engine thread raises ``_StaleEngine`` under
+    the lock and leaves the reset entries alone — a token booked there
+    would lead the replay's own."""
+    from lambdipy_tpu.runtime.continuous import _StaleEngine
+
+    cb = ContinuousBatcher(tiny_server, slots=2, segment=4)
+    real_wait, real_body = cb._device_wait, cb._engine_body
+    fired, ended = [], []
+
+    def racing(site, gen, fn=None, *args, **kw):
+        out = real_wait(site, gen, fn, *args, **kw)
+        if site == "first_fetch" and not fired:
+            fired.append(gen)
+            cb._fail_engine(RuntimeError("failed beside the early read"),
+                            site="test", gen=gen)
+        return out
+
+    def spy(gen):
+        try:
+            real_body(gen)
+        except BaseException as e:
+            ended.append((gen, type(e)))
+            raise
+
+    cb._device_wait, cb._engine_body = racing, spy
+    out = cb.generate([1, 2, 3], max_new_tokens=11)
+    np.testing.assert_array_equal(
+        out, tiny_server.generate([1, 2, 3], max_new_tokens=11))
+    assert ended == [(fired[0], _StaleEngine)]
+    stats = cb.stats()
+    assert stats["first_tokens_early"] == 1     # the replay's, not the stale
+    assert stats["faults"]["replays"]["succeeded"] == 1
+
+
+def test_hung_early_fetch_trips_the_watchdog_under_its_own_site(tiny_server):
+    """The early read is a device wait like the loop's others: one that
+    never answers trips the watchdog as ``watchdog:first_fetch``, and the
+    replay lands bitwise."""
+    cb = ContinuousBatcher(
+        tiny_server, slots=2, segment=4, watchdog_s=0.4,
+        faults=FaultPlan.from_spec("first_fetch:hang@seg=1,n=1"))
+    np.testing.assert_array_equal(
+        cb.generate([4, 2, 1], max_new_tokens=8),
+        tiny_server.generate([4, 2, 1], max_new_tokens=8))
+    faults = cb.stats()["faults"]
+    assert faults["watchdog_trips"] >= 1
+    assert faults["failures"].get("watchdog:first_fetch", 0) >= 1
+    assert faults["replays"]["succeeded"] == 1
+    assert not cb.wedged
 
 
 # -- watchdog ----------------------------------------------------------------
@@ -860,8 +945,8 @@ def test_fault_plan_clear_releases_hangs_without_poisoning_later_ones():
 
 # -- the engine fault matrix -------------------------------------------------
 
-ENGINE_SITES = ("segment_dispatch", "segment_fetch", "group_prefill",
-                "prefix_assemble", "transport")
+ENGINE_SITES = ("segment_dispatch", "segment_fetch", "first_fetch",
+                "group_prefill", "prefix_assemble", "transport")
 MATRIX_SPECS = {"exception": "{site}:exception@seg=1",
                 "delay": "{site}:delay@ms=120,n=2",
                 # bounded: the watchdog trips, the replay lands on the

@@ -267,11 +267,19 @@ def test_a_profiler_capture_holds_the_engine_phases_with_the_rid(
     events = engine[0]
     names = {e[2] for e in events}
     assert {"eng.barrier", "eng.prefill", "eng.pack", "eng.dispatch",
-            "eng.wait", "eng.fetch", "eng.book"} <= names
-    for name in ("eng.dispatch", "eng.wait", "eng.book", "eng.prefill"):
-        mine = [e for e in events if e[2] == name
-                and str(rid) in str(e[3].get("rids", "")).split("/")]
-        assert mine, name
+            "eng.first", "eng.wait", "eng.fetch", "eng.book"} <= names
+    named = {}
+    for name in ("eng.dispatch", "eng.first", "eng.wait", "eng.fetch",
+                 "eng.book", "eng.prefill"):
+        named[name] = [e for e in events if e[2] == name
+                       and str(rid) in str(e[3].get("rids", "")).split("/")]
+        assert named[name], name
+    # the request's first token is read and handed over once, after the
+    # dispatch of its first segment and BEFORE that segment is collected
+    assert len(named["eng.first"]) == 1
+    assert named["eng.dispatch"][0][1] <= named["eng.first"][0][0]
+    assert named["eng.first"][0][1] <= named["eng.wait"][0][0]
+    assert named["eng.first"][0][1] <= named["eng.fetch"][0][0]
     dispatch = next(e for e in events if e[2] == "eng.dispatch"
                     and "window" in e[3])
     assert int(dispatch[3]["rows"]) == 1 and int(dispatch[3]["window"]) >= 16
