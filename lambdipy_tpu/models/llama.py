@@ -2994,16 +2994,24 @@ class LlamaServer:
         share one compiled program instead of each tracing their own.
         With an AOT store attached, a miss first tries the bundle's
         serialized executables (outside the lock — a probe invokes the
-        program) before falling back to the jit wrapper."""
+        program) before falling back to the jit wrapper. A miss leaves
+        the key and what answered it in the program record (``GET /spans``):
+        the store's tiers write their own entries, the jit wrapper's is
+        written here (its trace, lowering and compile follow at first call,
+        under the jitted function's name)."""
         with self._fns_lock:
             fn = self._fns.get(key)
             if fn is not None:
                 self._fns.move_to_end(key)
                 return fn
+        from lambdipy_tpu.runtime import spans as _spans
+
         loaded = self._aot_load(key) if self._aot is not None else None
         with self._fns_lock:
             fn = self._fns.get(key)  # a racer may have won meanwhile
             if fn is None:
+                if loaded is None or any(p is None for p in loaded):
+                    _spans.program(self._aot_name(key), "jit", key=key)
                 if loaded is None:
                     fn = build()
                 else:
@@ -3168,7 +3176,7 @@ class LlamaServer:
                 parts.append(None)
                 continue
             with self._mesh_ctx():
-                hit = self._aot.load(part_name, (self.params, *ex))
+                hit = self._aot.load(part_name, (self.params, *ex), key=key)
             parts.append(None if hit is None else hit[0])
         if not any(p is not None for p in parts):
             return None

@@ -28,7 +28,6 @@ from __future__ import annotations
 import contextlib
 import json
 import pickle
-import time
 from pathlib import Path
 from typing import Any, Callable, Sequence
 
@@ -280,38 +279,39 @@ class AotStore:
         artifact just falls back to load()'s normal path."""
         import jax
 
-        t0 = time.monotonic()
         out: list[str] = []
         if not self.dir.is_dir():
             return {"names": out, "seconds": 0.0}
-        sig = _mesh_sig(self.mesh)
-        suffix = f".{jax.default_backend()}" + (f".{sig}" if sig else "")
-        env = _env_key(self.mesh)
-        for meta_path in sorted(self.dir.glob(f"{prefix}*{suffix}.json")):
-            name = meta_path.name[: -len(suffix + ".json")]
-            try:
-                meta = json.loads(meta_path.read_text())
-            except Exception:
-                continue
-            if any(meta.get(k) != env[k]
-                   for k in ("schema", "platform", "jax", "jaxlib",
-                             "n_devices", "mesh")):
-                continue
-            paths = self._paths(name)
-            for tier in ("exec", "hlo"):
-                if tier not in meta.get("tiers", ()):
-                    continue
+        with spans.span("boot.aot_preload") as whole:
+            sig = _mesh_sig(self.mesh)
+            suffix = f".{jax.default_backend()}" + (f".{sig}" if sig else "")
+            env = _env_key(self.mesh)
+            for meta_path in sorted(self.dir.glob(f"{prefix}*{suffix}.json")):
+                name = meta_path.name[: -len(suffix + ".json")]
                 try:
-                    with spans.span("boot.aot_load", program=name,
-                                    tier=tier), self._mesh_ctx():
-                        fn = self._load_tier(tier, paths)
+                    meta = json.loads(meta_path.read_text())
                 except Exception:
                     continue
-                if fn is not None:
-                    self._preloaded[name] = (fn, tier)
-                    out.append(name)
-                    break
-        return {"names": out, "seconds": round(time.monotonic() - t0, 3)}
+                if any(meta.get(k) != env[k]
+                       for k in ("schema", "platform", "jax", "jaxlib",
+                                 "n_devices", "mesh")):
+                    continue
+                paths = self._paths(name)
+                for tier in ("exec", "hlo"):
+                    if tier not in meta.get("tiers", ()):
+                        continue
+                    try:
+                        with spans.span("boot.aot_load", program=name,
+                                        tier=tier) as sp, self._mesh_ctx():
+                            fn = self._load_tier(tier, paths)
+                    except Exception:
+                        continue
+                    if fn is not None:
+                        spans.program(name, tier, aot_load=sp.seconds)
+                        self._preloaded[name] = (fn, tier)
+                        out.append(name)
+                        break
+        return {"names": out, "seconds": round(whole.seconds, 3)}
 
     def _load_tier(self, tier: str, paths: dict):
         """Deserialize one tier into a callable (no probing/gating)."""
@@ -330,10 +330,11 @@ class AotStore:
 
     # -- load ---------------------------------------------------------------
 
-    def load(self, name: str,
-             example_args: Sequence[Any] | None = None) -> tuple[Callable, str] | None:
+    def load(self, name: str, example_args: Sequence[Any] | None = None,
+             key=None) -> tuple[Callable, str] | None:
         """Return ``(callable, tier)`` for the best available artifact
-        matching the current environment, or None.
+        matching the current environment, or None. ``key`` (the caller's
+        own name for the program) only labels the program record's entry.
 
         When ``example_args`` is given each candidate tier is probe-invoked
         before being returned — an AOT executable can deserialize fine yet
@@ -371,8 +372,9 @@ class AotStore:
             fn, tried = pre
             try:
                 with spans.span("boot.warm", program=name,
-                                tier=tried), self._mesh_ctx():
+                                tier=tried) as warm, self._mesh_ctx():
                     _probe(fn)
+                spans.program(name, tried, key=key, warm=warm.seconds)
                 return fn, tried
             except Exception as e:
                 log.warning("aot %s: preloaded %s tier failed probe: %s",
@@ -383,12 +385,14 @@ class AotStore:
             try:
                 with self._mesh_ctx():
                     with spans.span("boot.aot_load", program=name,
-                                    tier=tier):
+                                    tier=tier) as sp:
                         fn = self._load_tier(tier, paths)
                     if fn is not None:
                         with spans.span("boot.warm", program=name,
-                                        tier=tier):
+                                        tier=tier) as warm:
                             _probe(fn)
+                        spans.program(name, tier, key=key,
+                                      aot_load=sp.seconds, warm=warm.seconds)
                         return fn, tier
             except Exception as e:
                 log.warning("aot %s: %s tier failed to load: %s", name, tier, e)
@@ -416,6 +420,7 @@ def cached_jit(ctx, name: str, fn: Callable, example_args: Sequence[Any],
     hit = store.load(name, example_args)
     if hit is not None:
         return hit
+    spans.program(name, "jit")
     if store.exhausted:
         # a matching meta already records that this platform's artifacts
         # don't work — re-saving would just reproduce them; serve from

@@ -17,6 +17,8 @@ import time
 from dataclasses import dataclass
 from typing import Any, Callable
 
+from lambdipy_tpu.runtime import spans
+
 
 @dataclass
 class HandlerState:
@@ -185,8 +187,14 @@ def _jax_adapter_and_params(spec: dict, ctx):
         # single-device payloads take the bulk-transfer device load; a
         # mesh payload loads host-side so the sharder can place it
         single = not any(v > 1 for v in (spec.get("mesh") or {}).values())
-        params = registry.load_params(spec["model"], ctx.params_dir,
-                                      device=single)
+        # file -> device as far as this call goes: the first transfer
+        # waits for the backend (the loader's pjrt-init thread), the
+        # transfers are queued, and the first program that takes the
+        # weights waits for them on the device (a meshed payload's are
+        # placed by _maybe_shard, under the same name)
+        with spans.span("boot.params"):
+            params = registry.load_params(spec["model"], ctx.params_dir,
+                                          device=single)
     else:
         params = adapter.init_params(seed=0)
     return adapter, params
@@ -253,7 +261,8 @@ def _maybe_shard(adapter, params, spec: dict):
     # must still honor the bundle's shape instead of erroring on the
     # device-count mismatch
     mesh = make_mesh(mesh_shape, devices=jax.devices()[:needed])
-    return shard_params(params, mesh, adapter.tp_rules), mesh
+    with spans.span("boot.params"):
+        return shard_params(params, mesh, adapter.tp_rules), mesh
 
 
 def image_classify_handler(spec: dict, ctx) -> HandlerState:
@@ -1092,8 +1101,6 @@ def generate_handler(spec: dict, ctx) -> HandlerState:
     # foreground warmup serializes the cold start.
     # Progress rides /metrics (handler.warm_buckets).
     import threading
-
-    from lambdipy_tpu.runtime import spans
 
     # "in_flight" is the readiness signal /healthz exposes: True from the
     # moment the warm thread is committed until it finishes, so a fleet

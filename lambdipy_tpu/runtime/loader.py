@@ -2,7 +2,9 @@
 
 The cold-start path (SURVEY.md §4 D/E): every stage is timed because the
 <10 s budget is consumed by interpreter + PJRT init + first compile
-(BASELINE.md). The loader:
+(BASELINE.md): each is a span ``boot.<stage>`` (``runtime/spans.py``), one
+after the other on the boot thread, and ``cold_start`` (the ready line,
+``/healthz``) is those spans' seconds. The loader:
 
 1. reads + verifies the manifest, checks base-layer version skew,
 2. layers sys.path: bundle ``site/`` first, base layer (host site) after,
@@ -24,8 +26,8 @@ from typing import Any
 
 from lambdipy_tpu.bundle.baselayer import check_skew, runtime_sys_path
 from lambdipy_tpu.bundle.format import load_manifest
+from lambdipy_tpu.runtime import spans
 from lambdipy_tpu.utils.logs import get_logger, log_event
-from lambdipy_tpu.utils.timing import StageTimer
 
 log = get_logger("lambdipy.runtime")
 
@@ -49,6 +51,9 @@ class BootReport:
     handler: Any
     state: Any
     stages: dict[str, float] = field(default_factory=dict)
+    # the stage spans as (name, begin, end) on time.monotonic(), in order;
+    # ``stages`` is their seconds under the stage's name, and the ``total``
+    stage_spans: list[tuple] = field(default_factory=list)
     skew: dict = field(default_factory=dict)
     warmup_result: Any = None
     manifest: dict = field(default_factory=dict)
@@ -64,9 +69,6 @@ class BootReport:
     compile_cache_dir: Path | None = None
     compile_counters: Any = None
 
-    def cold_start_s(self) -> float:
-        return sum(self.stages.values())
-
     def close(self) -> None:
         if self.compile_counters is not None:
             self.compile_counters.close()
@@ -74,9 +76,13 @@ class BootReport:
 
 def load_bundle(bundle_dir: Path, *, warmup: bool = True) -> BootReport:
     bundle_dir = Path(bundle_dir)
-    timer = StageTimer()
+    done: list[spans.span] = []
 
-    with timer.stage("manifest"):
+    def stage(name: str) -> spans.span:
+        done.append(spans.span(f"boot.{name}"))
+        return done[-1]
+
+    with stage("manifest"):
         manifest = load_manifest(bundle_dir)
         payload = manifest.get("payload")
         if payload is None:
@@ -86,13 +92,13 @@ def load_bundle(bundle_dir: Path, *, warmup: bool = True) -> BootReport:
         if skew:
             log_event(log, "base layer skew detected", skew=skew)
 
-    with timer.stage("syspath"):
+    with stage("syspath"):
         site_dir = bundle_dir / "site"
         for p in reversed(runtime_sys_path(site_dir, base.get("name", "none"))):
             if p not in sys.path:
                 sys.path.insert(0, p)
 
-    with timer.stage("compile_cache"):
+    with stage("compile_cache"):
         from lambdipy_tpu.models import registry as model_registry
 
         try:
@@ -117,7 +123,8 @@ def load_bundle(bundle_dir: Path, *, warmup: bool = True) -> BootReport:
                 try:
                     import jax
 
-                    jax.devices()
+                    with spans.span("boot.backend"):
+                        jax.devices()
                 except Exception as e:  # surfaced again, with context, by
                     log.warning("background PJRT init failed: %s", e)
 
@@ -134,13 +141,13 @@ def load_bundle(bundle_dir: Path, *, warmup: bool = True) -> BootReport:
         debug_flags = apply_debug_env()
 
     try:
-        with timer.stage("handler_import"):
+        with stage("handler_import"):
             spec = importlib.util.spec_from_file_location(
                 f"lambdipy_bundle_handler_{bundle_dir.name}", bundle_dir / "handler.py")
             module = importlib.util.module_from_spec(spec)
             spec.loader.exec_module(module)
 
-        with timer.stage("init"):
+        with stage("init"):
             params_dir = bundle_dir / "params"
             ctx = HandlerContext(
                 bundle_dir=bundle_dir,
@@ -149,26 +156,30 @@ def load_bundle(bundle_dir: Path, *, warmup: bool = True) -> BootReport:
                 spec=dict(payload),
             )
             state = module.init(ctx)
+            device = None
+            if uses_jax:
+                from lambdipy_tpu.utils.platform import device_identity
+
+                device = device_identity()
 
         warmup_result = None
         if warmup:
-            with timer.stage("warmup"):
+            with stage("warmup"):
                 warmup_result = module.invoke(state, {"warmup": True})
-        device = None
-        if uses_jax:
-            from lambdipy_tpu.utils.platform import device_identity
-
-            device = device_identity()
     except BaseException:
         if counters is not None:
             counters.close()  # nobody is left to own them
         raise
 
+    stages = {sp.name.removeprefix("boot."): round(sp.seconds, 4)
+              for sp in done}
+    stages["total"] = round(sum(sp.seconds for sp in done), 4)
     report = BootReport(
         bundle_dir=bundle_dir,
         handler=module,
         state=state,
-        stages=timer.report(),
+        stage_spans=[(sp.name, sp.t0, sp.t0 + sp.seconds) for sp in done],
+        stages=stages,
         skew=skew,
         warmup_result=warmup_result,
         manifest=manifest,

@@ -26,6 +26,13 @@ aggregate:
   gap by the host event overlapping it most would read ``req``
   everywhere. In a trace a request is followed by the ``rids`` argument
   of the ``eng.*`` phases that worked for it.
+- durations measured elsewhere — :func:`add` books one under a span name
+  (jax's own clock round a trace, a lowering, a compile: ``jit.*``, from
+  ``utils/compile_cache.CompileCounters``).
+
+Beside the request ring, :func:`program` keeps a bounded record of how each
+program entered the process (``GET /spans`` → ``programs``: "why did this
+replica take a minute to be ready", "which program was loaded twice").
 
 The aggregate of a name is ``count``, ``sum_s`` and counts in power-of-two
 millisecond buckets. All three only grow, so any two scrapes of
@@ -35,6 +42,8 @@ millisecond buckets. All three only grow, so any two scrapes of
 from __future__ import annotations
 
 import itertools
+import os
+import sys
 import threading
 import time
 from collections import deque
@@ -42,13 +51,32 @@ from collections import deque
 # upper edges in ms of the first 14 buckets; the 15th counts what is over
 BUCKET_EDGES_MS = tuple(2 ** i for i in range(14))  # 1 ... 8192
 RING = 1024
+PROGRAMS = 512
+
+
+def _process_start() -> float:
+    """``time.monotonic()`` when this process started: from the kernel's
+    record where there is one (Linux), else this module's import."""
+    now = time.monotonic()
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) \
+            - ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError, AttributeError):
+        return now
+    return now - max(0.0, age)
+
+
+T0 = _process_start()
 
 _lock = threading.Lock()
 _agg: dict = {}       # name -> [count, sum_s, [bucket counts]]
 _live: dict = {}      # rid -> open request record
 _ring: deque = deque(maxlen=RING)
+_programs: deque = deque(maxlen=PROGRAMS)
 _rids = itertools.count(1)
-_annotation = None    # jax.profiler.TraceAnnotation, False when jax is absent
+_annotation = None    # jax.profiler.TraceAnnotation, once jax is imported
 
 
 def _bucket(seconds: float) -> int:
@@ -77,17 +105,18 @@ def rids_arg(rids) -> str:
 class span:
     """``with span("eng.dispatch", rids="3/7") as sp: ... sp.set(window=256)``"""
 
-    __slots__ = ("name", "_ann", "_t0")
+    __slots__ = ("name", "_ann", "t0", "seconds")
 
     def __init__(self, name: str, **args):
         global _annotation
         if _annotation is None:
-            try:
-                from jax.profiler import TraceAnnotation
-
-                _annotation = TraceAnnotation
-            except ImportError:  # a bundle that serves without jax
-                _annotation = False
+            # taken from jax once SOMEBODY ELSE has imported it: a span
+            # never starts that import itself (seconds, which belong to
+            # the stage that needs jax; a bundle that serves without jax
+            # stays without; and an import begun here can collide with
+            # another thread's), and no profiler session runs before it
+            _annotation = getattr(sys.modules.get("jax.profiler"),
+                                  "TraceAnnotation", None)
         self.name = name
         self._ann = _annotation(name, **args) if _annotation else None
 
@@ -99,16 +128,40 @@ class span:
     def __enter__(self):
         if self._ann is not None:
             self._ann.__enter__()
-        self._t0 = time.monotonic()
+        self.t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
-        seconds = time.monotonic() - self._t0
+        self.seconds = time.monotonic() - self.t0
         if self._ann is not None:
             self._ann.__exit__(*exc)
         with _lock:
-            _add_locked(self.name, seconds)
+            _add_locked(self.name, self.seconds)
         return False
+
+
+def add(name: str, seconds: float) -> None:
+    """A duration somebody else measured, into the aggregate of ``name``."""
+    with _lock:
+        _add_locked(name, seconds)
+
+
+def program(name: str | None, source: str, **fields) -> None:
+    """One program's way into the process, into the bounded record:
+    ``name`` (the AOT program's, or the jitted function's), ``source``
+    (``exec`` / ``hlo``: deserialized from the bundle's AOT store; ``jit``:
+    traced, lowered and compiled or read from the persistent cache), the
+    ``key`` where the program cache knew it, and the seconds of whichever
+    of ``aot_load``, ``warm``, ``trace``, ``lower``, ``compile``,
+    ``cache_read`` happened (``cache_hit`` beside ``compile``). ``t`` is
+    seconds since :data:`T0` when the entry was written, i.e. at the END of
+    what it times."""
+    entry = {"t": time.monotonic() - T0, "name": name, "source": source,
+             "thread": threading.current_thread().name, **fields}
+    entry = {k: round(v, 4) if isinstance(v, float) else v
+             for k, v in entry.items() if v is not None or k == "name"}
+    with _lock:
+        _programs.append(entry)
 
 
 class phases:
@@ -224,10 +277,12 @@ def report() -> dict:
 
 def requests(last: int | None = None) -> dict:
     """``GET /spans``: the finished request records, oldest first (times
-    in seconds from the request's start), and the bucket edges."""
+    in seconds from the request's start), the bucket edges, and the
+    program record, oldest first (``last`` cuts the requests only)."""
     with _lock:
         done = list(_ring)
+        programs = list(_programs)
     if last is not None:
         done = done[-last:] if last > 0 else []
     return {"bucket_edges_ms": list(BUCKET_EDGES_MS), "ring": RING,
-            "requests": done}
+            "requests": done, "programs": programs}

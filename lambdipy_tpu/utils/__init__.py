@@ -1,6 +1,6 @@
 """Shared utilities: timing, structured logging, filesystem helpers, hashing."""
 
-from lambdipy_tpu.utils.timing import StageTimer, Timer
+from lambdipy_tpu.utils.timing import StageTimer
 from lambdipy_tpu.utils.logs import get_logger
 from lambdipy_tpu.utils.fsutil import (
     atomic_write_text,
@@ -13,7 +13,6 @@ from lambdipy_tpu.utils.fsutil import (
 
 __all__ = [
     "StageTimer",
-    "Timer",
     "get_logger",
     "atomic_write_text",
     "copy_tree",

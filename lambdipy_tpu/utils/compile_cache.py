@@ -17,6 +17,8 @@ written under one path and read under another never hits. Hence:
 from __future__ import annotations
 
 import os
+import threading
+import time
 from pathlib import Path
 
 from lambdipy_tpu.utils.platform import REPO_ROOT
@@ -63,36 +65,92 @@ def enable_compile_cache(bundle_dir: Path | None = None) -> Path:
     return cache_dir or Path(os.environ[CACHE_ENV])
 
 
+class _PerThread(threading.local):
+    """What one thread's jax events have said of the program on its way."""
+
+    traces: tuple = ()   # (began, seconds) of the traces no lowering followed
+    entry: dict | None = None   # the program record's entry, until its compile
+
+
 class CompileCounters:
     """Counts the XLA compile requests made in this process while the
     object is open, and how many of them the persistent cache answered,
-    from jax's own monitoring events. The owner calls :meth:`close`."""
+    from jax's own monitoring events. The owner calls :meth:`close`.
 
+    The same listeners book every program's way through jax into the span
+    aggregate (``runtime/spans.py``), by jax's own clock: ``jit.trace``,
+    ``jit.lower``, ``jit.compile`` (with a persistent hit: key hashing,
+    cache read, deserialise, load onto the device) and ``jit.cache_read``
+    (a part of ``jit.compile``), and write one entry of the program record
+    a compile. They fire only where something is traced or compiled: a
+    warm call of a jitted program passes none of this.
+
+    jax times every jitted function traced INSIDE another one too (each
+    ``jnp`` function is one), and those it meets while it lowers. So a
+    trace is held back, per thread, until the lowering that follows it:
+    only traces that lie inside no other and began before the lowering did
+    are booked, each once."""
+
+    _TRACE = "/jax/core/compile/jaxpr_trace_duration"
+    _LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
     _REQUEST = "/jax/core/compile/backend_compile_duration"
+    _CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
     _HIT = "/jax/compilation_cache/cache_hits"
 
     def __init__(self):
-        import threading
-
         import jax.monitoring
 
+        from lambdipy_tpu.runtime import spans
+
+        self._spans = spans
         self._lock = threading.Lock()
+        self._thread = _PerThread()
         self.requests = 0
         self.request_s = 0.0
         self.cache_hits = 0
         jax.monitoring.register_event_duration_secs_listener(self._on_duration)
         jax.monitoring.register_event_listener(self._on_event)
 
-    def _on_duration(self, event: str, duration_secs: float, **_kw) -> None:
-        if event == self._REQUEST:
+    def _on_duration(self, event: str, duration_secs: float,
+                     fun_name: str | None = None, **_kw) -> None:
+        spans, th = self._spans, self._thread
+        if event == self._TRACE:
+            # the events of one thread come in the order their work ENDED:
+            # what began after this trace did lies inside it
+            began = time.monotonic() - duration_secs
+            th.traces = (*(t for t in th.traces if t[0] < began),
+                         (began, duration_secs))
+        elif event == self._LOWER:
+            began = time.monotonic() - duration_secs
+            traces = [t for t in th.traces if t[0] < began]
+            th.traces = ()
+            for _, seconds in traces:
+                spans.add("jit.trace", seconds)
+            spans.add("jit.lower", duration_secs)
+            # the last trace before a lowering is that program's own; a
+            # program whose jaxpr jax still held has none
+            th.entry = {"name": fun_name, "lower": duration_secs,
+                        **({"trace": traces[-1][1]} if traces else {})}
+        elif event == self._CACHE_READ:
+            spans.add("jit.cache_read", duration_secs)
+            if th.entry is not None:
+                th.entry["cache_read"] = duration_secs
+        elif event == self._REQUEST:
             with self._lock:
                 self.requests += 1
                 self.request_s += duration_secs
+            spans.add("jit.compile", duration_secs)
+            entry, th.entry = th.entry or {"name": fun_name}, None
+            entry.setdefault("cache_hit", False)
+            spans.program(entry.pop("name"), "jit", **entry,
+                          compile=duration_secs)
 
     def _on_event(self, event: str, **_kw) -> None:
         if event == self._HIT:
             with self._lock:
                 self.cache_hits += 1
+            if self._thread.entry is not None:
+                self._thread.entry["cache_hit"] = True
 
     def close(self) -> None:
         import jax.monitoring

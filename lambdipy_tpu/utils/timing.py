@@ -1,10 +1,9 @@
 """Timing helpers.
 
-The serve path's cold-start budget (<10 s, BASELINE.md) is consumed almost
-entirely by interpreter + PJRT init + first compile, so every stage of boot
-and build is timed with :class:`StageTimer` and reported in structured logs.
-Mirrors the per-stage timing the build engine needs (SURVEY.md §6 tracing
-row: the reference has none; the rebuild makes it first-class).
+Every stage of a build is timed with :class:`StageTimer` and reported in
+structured logs (SURVEY.md §6 tracing row: the reference has none; the
+rebuild makes it first-class). The boot's stages are spans
+(``runtime/loader.py``, ``runtime/spans.py``).
 """
 
 from __future__ import annotations
@@ -12,22 +11,6 @@ from __future__ import annotations
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-
-
-@dataclass
-class Timer:
-    """Monotonic stopwatch."""
-
-    start: float = field(default_factory=time.monotonic)
-
-    def elapsed(self) -> float:
-        return time.monotonic() - self.start
-
-    def lap(self) -> float:
-        now = time.monotonic()
-        out = now - self.start
-        self.start = now
-        return out
 
 
 @dataclass
