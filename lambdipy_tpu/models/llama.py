@@ -26,6 +26,10 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from lambdipy_tpu.utils.logs import get_logger
+
+log = get_logger("lambdipy.llama")
+
 
 class LayerSpec(NamedTuple):
     attn: str  # "kv" | "latent" | "eva"
@@ -2860,6 +2864,93 @@ def _shallow_draft(model, params, tok, cache, pos, kb: int,
     return jnp.stack(drafts, axis=1)
 
 
+class _ServedProgram:
+    """One named serving program of a single-chip server whose bundle has an
+    AOT store: where it comes from is settled at its FIRST CALL, with that
+    call's own operands and no others.
+
+    - The store holds it (``AotStore.load_exec``: preloaded at boot, or
+      deserialised now): the first real call is the probe. An executable
+      that raises there is pruned and counted (``aot_fallbacks``), and the
+      jit wrapper serves the key.
+    - The store has never seen it: ``lower(*args).compile()`` obtains the
+      executable ONCE (the persistent compile cache is read and written on
+      that path as on a plain call), that ``Compiled`` runs this call and
+      every later one, and the same object goes to the store's saver thread
+      (``AotStore.save_later``), so the next boot of the bundle loads what
+      this one traced, lowered and compiled or read from the cache.
+
+    Afterwards a call is one attribute read more than the program's own
+    (both a ``Compiled`` and a jit wrapper dispatch from C++). A program
+    that is never called (the unused half of a streaming pair) is neither
+    loaded nor compiled."""
+
+    __slots__ = ("_server", "key", "name", "_jitted", "_fn", "_lock")
+
+    def __init__(self, server: "LlamaServer", key: tuple, name: str, jitted):
+        self._server = server
+        self.key = key
+        self.name = name
+        self._jitted = jitted
+        self._fn = None
+        self._lock = threading.Lock()
+
+    def __call__(self, *args):
+        fn = self._fn
+        if fn is None:
+            return self._first_call(args)
+        return fn(*args)
+
+    def _cache_size(self) -> int:
+        """``compile_count``'s question of a jit wrapper: programs held."""
+        fn = self._fn
+        if fn is None:
+            return 0
+        return getattr(fn, "_cache_size", lambda: 1)()
+
+    def _first_call(self, args):
+        from lambdipy_tpu.runtime import spans
+
+        server, store = self._server, self._server._aot
+        with self._lock:
+            if self._fn is None:
+                hit = store.load_exec(self.name)
+                if hit is not None:
+                    fn, load_s = hit
+                    try:
+                        with spans.span("boot.warm", program=self.name,
+                                        tier="exec") as warm:
+                            out = jax.block_until_ready(fn(*args))
+                    except Exception as e:  # noqa: BLE001 — the probe
+                        log.warning("aot %s: the loaded executable failed "
+                                    "its first call (%s): pruned, serving "
+                                    "from jit", self.name, e)
+                        store.drop_tier(self.name, "exec")
+                        with server._fns_lock:
+                            server.aot_fallbacks += 1
+                    else:
+                        spans.program(self.name, "exec", key=self.key,
+                                      aot_load=load_s, warm=warm.seconds)
+                        with server._fns_lock:
+                            server.aot_hits += 1
+                            server.aot_lazy_loads += load_s is not None
+                        self._fn = fn
+                        return out
+                # its trace, lowering and compile follow under the jitted
+                # function's own name
+                spans.program(self.name, "jit", key=self.key)
+                if store.pruned(self.name):
+                    # saving it again would write the same losing artifact
+                    self._fn = self._jitted
+                else:
+                    compiled = self._jitted.lower(*args).compile()
+                    out = compiled(*args)
+                    self._fn = compiled
+                    store.save_later(self.name, compiled, server._aot_boot)
+                    return out
+        return self._fn(*args)
+
+
 class LlamaServer:
     """Compile-once decode serving: prompt-length bucketing (pad right to a
     power of two) + sampling knobs as runtime operands.
@@ -2888,14 +2979,22 @@ class LlamaServer:
         self.min_bucket = min_bucket
         # optional runtime/aot.AotStore: serving programs are loaded from
         # the bundle's serialized-executable tier instead of compiled
-        # (the 8B boot pays ~40 s of compile PER program without this),
-        # and aot_save_all() snapshots freshly compiled programs
-        # after warmup so the next boot hits. Example operands for
-        # probe/export are SYNTHESIZED from each program key — shapes are
-        # fully determined by (bucket, cache_len, config).
-        self._aot = aot
-        self._aot_loaded: set = set()
+        # (the 8B boot pays ~40 s of compile PER program without this, and
+        # 8-10 s of trace, lowering and cache read where the persistent
+        # cache holds it). Every NAMED program (_aot_name) is a
+        # _ServedProgram: loaded at its first call if the store has it,
+        # else compiled there and snapshotted, with no example operands.
+        # The exec tier is single-chip by AotStore's own rule, so a meshed
+        # server takes no store (the handler offers it none): its programs
+        # come through jit and the persistent cache.
+        self._aot = aot if mesh is None else None
         self.aot_hits = 0  # programs served from the AOT store this boot
+        self.aot_lazy_loads = 0  # of them, deserialised at first use
+        self.aot_fallbacks = 0   # loaded executables whose first call failed
+        # True until the boot's own warm-up has ended (aot_save_all): what
+        # is compiled till then is the bundle's boot set (AotStore.preload)
+        self._aot_boot = True
+        self._aot_saved_seen = 0
         # Speculative-decoding counters. ``spec_stats`` (the legacy bare
         # dict — last call's counters, single-threaded convenience only)
         # is kept for back-compat; the LOCKED, cumulative,
@@ -2992,47 +3091,32 @@ class LlamaServer:
         so holding the lock through it is cheap; what the lock buys is
         that at most one wrapper per key ever exists, so concurrent racers
         share one compiled program instead of each tracing their own.
-        With an AOT store attached, a miss first tries the bundle's
-        serialized executables (outside the lock — a probe invokes the
-        program) before falling back to the jit wrapper. A miss leaves
-        the key and what answered it in the program record (``GET /spans``):
-        the store's tiers write their own entries, the jit wrapper's is
-        written here (its trace, lowering and compile follow at first call,
-        under the jitted function's name)."""
+        With an AOT store attached, each part of a NAMED key is a
+        :class:`_ServedProgram` round its jit wrapper: the bundle's
+        executable or a compile, settled at the part's first call. A miss
+        leaves the key and what answered it in the program record (``GET
+        /spans``): a served program writes its own entry at that call, an
+        un-named key's jit wrapper is written here (its trace, lowering
+        and compile follow at first call, under the jitted function's
+        name)."""
         with self._fns_lock:
             fn = self._fns.get(key)
             if fn is not None:
                 self._fns.move_to_end(key)
                 return fn
-        from lambdipy_tpu.runtime import spans as _spans
+            names = self._aot_part_names(key) \
+                if self._aot is not None else None
+            fn = build()
+            if names is None:
+                from lambdipy_tpu.runtime import spans as _spans
 
-        loaded = self._aot_load(key) if self._aot is not None else None
-        with self._fns_lock:
-            fn = self._fns.get(key)  # a racer may have won meanwhile
-            if fn is None:
-                if loaded is None or any(p is None for p in loaded):
-                    _spans.program(self._aot_name(key), "jit", key=key)
-                if loaded is None:
-                    fn = build()
-                else:
-                    # partial hits are real (ADVICE r4: the continuous
-                    # engine's pair only ever executes its seg half, so
-                    # the snapshot may hold one part): loaded parts are
-                    # used, missing parts fall back to the jit wrapper
-                    if all(p is not None for p in loaded):
-                        merged = list(loaded)
-                    else:
-                        built = build()
-                        built = (built if isinstance(built, tuple)
-                                 else (built,))
-                        merged = [l if l is not None else b
-                                  for l, b in zip(loaded, built)]
-                    fn = merged[0] if len(merged) == 1 else tuple(merged)
-                    for i, p in enumerate(loaded):
-                        if p is not None:
-                            self._aot_loaded.add((key, i))
-                            self.aot_hits += 1
-                self._fns[key] = fn
+                _spans.program(self._aot_name(key), "jit", key=key)
+            else:
+                parts = fn if isinstance(fn, tuple) else (fn,)
+                served = [_ServedProgram(self, key, n, part)
+                          for n, part in zip(names, parts)]
+                fn = served[0] if len(served) == 1 else tuple(served)
+            self._fns[key] = fn
             while len(self._fns) > self._fns_max:
                 self._fns.popitem(last=False)
                 self._fn_evictions += 1
@@ -3055,8 +3139,12 @@ class LlamaServer:
     # programs write a segment-long tail and merge it once
     # (_scan_decode, tail_window); same signature, same carry. g6 = PR
     # 34: so do an eva model's (a ring tail and a summary tail,
-    # _eva_tail_attend), and their counter has a third column.
-    _AOT_GEN = "g6"
+    # _eva_tail_attend), and their counter has a third column. g7 = PR
+    # 38: the set of names changes (``seg_w`` has one) and a server saves
+    # at first use, so an executable is trusted by its NAME in many more
+    # places: from here on a change of a window-bucketed segment's text
+    # bumps this too.
+    _AOT_GEN = "g7"
 
     @classmethod
     def aot_prefix(cls) -> str:
@@ -3073,17 +3161,35 @@ class LlamaServer:
         if isinstance(key[0], int):  # fused decode (b, sb, steps)
             return cls.aot_prefix() + "dec-" + "-".join(map(str, key))
         kind = key[0]
-        if kind in ("stream", "prefix", "continue", "stream_prefix",
+        if kind in ("stream", "seg_w", "prefix", "continue", "stream_prefix",
                     "spec", "spec_s"):
             return cls.aot_prefix() + f"{kind}-" + "-".join(map(str, key[1:]))
-        # "prefix_ext" stays un-AOT-able on purpose: warmup never compiles
-        # it, so there would be nothing to snapshot
+        # un-named, so jit alone serves them: "prefix_ext" and
+        # "sp_prefill_ext" donate their cache operand; the speculative
+        # segments ("spec_seg", "mspec_seg") and the paged families are
+        # what no measured cell runs. Naming one is a line here.
         return None
+
+    @classmethod
+    def _aot_part_names(cls, key: tuple) -> list[str] | None:
+        """One artifact name for each callable the key maps to (a
+        streaming pair is two parts, ``-p0`` the prefill and ``-p1`` the
+        segment); None = not AOT-able."""
+        name = cls._aot_name(key)
+        if name is None:
+            return None
+        if key[0] == "stream":
+            return [f"{name}-p0", f"{name}-p1"]
+        return [name]
 
     def _aot_examples(self, key: tuple):
         """Synthesized example operand tuples (excluding params) matching
         the traced shapes of the key's program(s). Returns a list — one
-        per callable the key maps to (streaming keys map to a pair)."""
+        per callable the key maps to (streaming keys map to a pair).
+        Serving builds none of these (a :class:`_ServedProgram` settles
+        with its first call's own operands); they describe a key's
+        programs to whoever lowers one without a request: the program-text
+        and chip-compile tests, under ``jax.eval_shape``."""
         cfg = self.model.cfg
 
         def knobs_for(b):
@@ -3103,6 +3209,12 @@ class LlamaServer:
             b, sb, _steps = key
             return [(*prompt_ops(b, sb), *knobs_for(b))]
         kind = key[0]
+        if kind == "seg_w":
+            # the stream pair's segment half's: the window is sliced
+            # inside the program, the carry is the full cache's
+            _, b, cache_len, _window, segment = key
+            return self._aot_examples(
+                ("stream", b, self.min_bucket, cache_len, segment))[1:]
         if kind == "stream":
             _, b, sb, cache_len, _segment = key
             t, k, p, rng, eos = knobs_for(b)
@@ -3146,97 +3258,28 @@ class LlamaServer:
                      jnp.zeros((kb, 2), jnp.uint32))]
         return None
 
-    def _aot_load(self, key: tuple):
-        """Best-effort load of the key's program(s) from the AOT store.
-        Returns a list aligned with the key's parts — loaded executable
-        per hit, None per miss — or None when nothing hit at all.
-        Multi-part keys (the streaming pair) load PARTIALLY: the
-        continuous engine only ever runs a pair's seg half, so a
-        snapshot legitimately holds one part (ADVICE r4) and the boot
-        should still skip that compile."""
-        name = self._aot_name(key)
-        if name is None:
-            return None
-        # existence first (a stat per part): synthesizing probe operands
-        # allocates full KV caches on device — wasted work for every
-        # never-saved key (first boots, fresh prefix buckets)
-        names = [name] if not isinstance(key[0], str) or \
-            key[0] != "stream" else [f"{name}-p0", f"{name}-p1"]
-        if not any(self._aot.has(n) for n in names):
-            return None
-        try:
-            examples = self._aot_examples(key)
-        except Exception:
-            return None
-        if len(examples) != len(names):
-            return None
-        parts = []
-        for part_name, ex in zip(names, examples):
-            if not self._aot.has(part_name):
-                parts.append(None)
-                continue
-            with self._mesh_ctx():
-                hit = self._aot.load(part_name, (self.params, *ex), key=key)
-            parts.append(None if hit is None else hit[0])
-        if not any(p is not None for p in parts):
-            return None
-        return parts
+    @property
+    def aot_saved(self) -> int:
+        """Artifacts this boot has written to the bundle's AOT store."""
+        return self._aot.saved if self._aot is not None else 0
 
-    def aot_save_all(self) -> int:
-        """Snapshot every compiled serving program that was NOT itself
-        loaded from the store into the bundle's AOT exec tier (called
-        after warmup — build-time by the warm runner, serve-time after a
-        fresh compile — so the next boot loads executables instead of
-        compiling). Returns the number of artifacts written."""
+    def aot_save_all(self, boot_done: bool = False) -> int:
+        """Called where a boot's own warm-up ends (the warm-up invoke, the
+        ``bucket-warm`` daemon; build-time by the warm runner, which must
+        not exit with a snapshot half written). Every program compiled so
+        far was queued for the store at its first run, so this only waits
+        for the saver thread. ``boot_done``: nothing more runs before the
+        deploy is ready, so what is saved from now on is the traffic's and
+        no boot preloads it (``AotStore.preload``). Returns the number of
+        artifacts written since the last call."""
         if self._aot is None:
             return 0
+        self._aot.drain()
         with self._fns_lock:
-            items = list(self._fns.items())
-        n = 0
-        for key, fn in items:
-            name = self._aot_name(key)
-            if name is None:
-                continue
-            try:
-                examples = self._aot_examples(key)
-            except Exception:
-                continue
-            fns = fn if isinstance(fn, tuple) else (fn,)
-            if len(fns) != len(examples):
-                continue
-            for i, (part, ex) in enumerate(zip(fns, examples)):
-                with self._fns_lock:
-                    # saved (or AOT-loaded) once; a later call (e.g.
-                    # after the background bucket warm) must not
-                    # re-export it
-                    if (key, i) in self._aot_loaded:
-                        continue
-                # only snapshot parts that actually COMPILED: a jit
-                # wrapper that never ran (e.g. the prefill half of a
-                # pair the continuous engine only uses the seg half of)
-                # would pay a fresh multi-second compile inside
-                # save_from_jitted's lower().compile() instead of the
-                # in-session cache hit the executed ones get. Parts save
-                # INDEPENDENTLY (ADVICE r4): the executed half of a
-                # pair snapshots even when its sibling never ran.
-                if getattr(part, "_cache_size", lambda: 0)() == 0:
-                    continue
-                part_name = (name if len(examples) == 1
-                             else f"{name}-p{i}")
-                try:
-                    # both tiers: exec loads in seconds where it works
-                    # (single-device), hlo + the warmed persistent cache
-                    # covers platforms where exec cannot load (e.g.
-                    # multi-device CPU)
-                    meta = self._aot.save_from_jitted(
-                        part_name, part, (self.params, *ex))
-                except Exception:  # noqa: BLE001 — AOT is best-effort
-                    continue
-                wrote = len(meta.get("tiers", ()))
-                if wrote:
-                    n += wrote
-                    with self._fns_lock:
-                        self._aot_loaded.add((key, i))
+            if boot_done:
+                self._aot_boot = False
+            n = self._aot.saved - self._aot_saved_seen
+            self._aot_saved_seen += n
         return n
 
     def _compiled(self, b: int, sb: int, steps: int):
@@ -3767,9 +3810,12 @@ class LlamaServer:
         segment, and positions past a row's index are masked to exact
         zeros either way, so tokens are bitwise the full-window
         program's (asserted in tests). Keyed ("seg_w", ...) in the LRU
-        program cache; deliberately not AOT-able (window buckets are
-        load-dependent — snapshotting every variant would bloat the
-        store for programs that compile in seconds at tiny windows)."""
+        program cache and NAMED for the bundle's AOT store: which window
+        buckets run is the traffic's choice, so a bucket is compiled and
+        snapshotted the first time a writable bundle meets it and loaded
+        from there at its first use in every later boot (a cache HIT of
+        one is 10-13 s at 7B widths, a load 2-7 s: PERF.md section 5;
+        an artifact a bucket, a mix asks for a handful)."""
         def build():
             def seg(params, temperature, top_k, top_p, first, lp, cache,
                     pos, done, rng, eos_id):
@@ -3799,8 +3845,10 @@ class LlamaServer:
         could expose them (the same rollback-by-index trick the solo
         verify fns use, batched). Same 6-leaf carry as the plain
         segment programs, so the pack/joiner machinery is untouched.
-        Keyed ("spec_seg", ...) in the LRU cache; deliberately not
-        AOT-able, like every load-dependent window variant."""
+        Keyed ("spec_seg", ...) in the LRU cache and NOT named for the
+        AOT store (``_aot_name``): no measured cell runs speculation, so
+        the variants (window x kb) enter through jax at every boot until
+        one does."""
         def build():
             def seg(params, temperature, top_k, top_p, draft, tok, lp,
                     cache, pos, done, rng, eos_id):

@@ -21,6 +21,15 @@ artifacts so the *next* boot — or the built bundle, when the builder's
 warm subprocess does this — is fast. The reference has no analog: its
 "AOT" is shipping pre-built wheels (SURVEY.md §1); this is the same idea
 one level down, at the XLA-program level.
+
+A single-chip ``LlamaServer`` uses the exec tier alone and needs no
+example operands (``models/llama.py`` ``_ServedProgram``): a program it had
+to compile is handed over as the ``Compiled`` it runs
+(:meth:`AotStore.save_later`, written on the store's own thread), a later
+boot takes it back with :meth:`AotStore.load_exec` at the program's first
+use, and that first real call is the probe. A meta's ``boot`` says whether
+a boot of the bundle runs the program before it is ready:
+:meth:`AotStore.preload` leaves the others to their first use.
 """
 
 from __future__ import annotations
@@ -28,6 +37,8 @@ from __future__ import annotations
 import contextlib
 import json
 import pickle
+import queue
+import threading
 from pathlib import Path
 from typing import Any, Callable, Sequence
 
@@ -75,6 +86,14 @@ class AotStore:
         # (callable, tier). load() consumes these instead of re-reading
         # the tier file, and still probes them.
         self._preloaded: dict[str, tuple] = {}
+        # save_later()'s queue and thread (started with the first save),
+        # the artifacts it has written, and whether the directory took
+        # them: one refused write (a read-only bundle, as under Lambda's
+        # /var/task) ends the saving for this boot, silently
+        self._pending: queue.Queue | None = None
+        self._saver_lock = threading.Lock()
+        self.saved = 0
+        self.writable = True
 
     def _mesh_ctx(self):
         """Trace/compile/probe under the payload mesh (models read it for
@@ -159,70 +178,6 @@ class AotStore:
             atomic_write_text(paths["meta"], json.dumps(meta, indent=1))
         return meta, jitted
 
-    def save_from_jitted(self, name: str, jitted: Callable,
-                         example_args: Sequence[Any],
-                         exec_only: bool = False) -> dict:
-        """Export an ALREADY-warmED ``jax.jit`` object's program (the
-        caller has invoked it at ``example_args``' shapes, so its compile
-        is done and cached in-session). Used by the serving path to
-        snapshot its compiled programs after warmup without paying the
-        extra trace+compile that :meth:`save`'s fresh ``jax.jit`` would.
-
-        ``exec_only`` skips the hlo tier (and its round-trip cache warm)
-        when the caller knows only the executable tier can win.
-        """
-        import jax
-        import jax.export
-
-        self.dir.mkdir(parents=True, exist_ok=True)
-        paths = self._paths(name)
-        meta = _env_key(self.mesh)
-        meta["tiers"] = []
-        with self._mesh_ctx():
-            if self.mesh is None:
-                try:
-                    from jax.experimental import serialize_executable
-
-                    # in-session this re-lower/compile is a compilation-
-                    # cache hit, not a fresh compile — the caller already
-                    # ran the program at these shapes
-                    compiled = jitted.lower(*example_args).compile()
-                    payload = serialize_executable.serialize(compiled)
-                    atomic_write_bytes(paths["exec"], pickle.dumps(payload))
-                    # self-test NOW (a deserialize + one call, seconds):
-                    # on some platforms (observed: multi-device CPU) a
-                    # serialized single-device executable cannot load
-                    # back; shipping it would make every boot pay the
-                    # failed attempt, and the skipped hlo warm below
-                    # would leave the real fallback cold
-                    fn = self._load_tier("exec", paths)
-                    jax.block_until_ready(fn(*example_args))
-                    meta["tiers"].append("exec")
-                except Exception as e:
-                    paths["exec"].unlink(missing_ok=True)
-                    log.info("aot %s: executable tier unavailable: %s",
-                             name, e)
-            if not exec_only:
-                try:
-                    exported = jax.export.export(jitted)(*example_args)
-                    atomic_write_bytes(paths["hlo"],
-                                       bytes(exported.serialize()))
-                    # exec is probed first at load, so "hlo" goes last
-                    meta["tiers"].append("hlo")
-                    if "exec" not in meta["tiers"]:
-                        # platforms that will actually BOOT from the hlo
-                        # tier need its round-tripped module warmed into
-                        # the persistent cache (same reasoning as
-                        # save()); exec-capable platforms never probe it,
-                        # so skip the extra compile there
-                        jax.block_until_ready(
-                            jax.jit(exported.call)(*example_args))
-                except Exception as e:
-                    log.warning("aot %s: jax.export failed: %s", name, e)
-        if meta["tiers"]:
-            atomic_write_text(paths["meta"], json.dumps(meta, indent=1))
-        return meta
-
     def prune_broken_tiers(self, name: str,
                            example_args: Sequence[Any]) -> list[str]:
         """Build-time self-test: load each just-saved tier on THIS platform
@@ -250,29 +205,133 @@ class AotStore:
             except Exception as e:
                 log.warning("aot %s: pruning %s tier (failed self-test: %s)",
                             name, tier, e)
-                meta["tiers"].remove(tier)
-                paths[tier].unlink(missing_ok=True)
+                self.drop_tier(name, tier)
                 pruned.append(tier)
-        if pruned:
-            # keep the meta even when no tiers survive: it records "tried
-            # and pruned on this platform", which stops every subsequent
-            # boot from re-exporting/re-probing the same losing artifacts
-            atomic_write_text(paths["meta"], json.dumps(meta, indent=1))
         return pruned
 
-    def has(self, name: str) -> bool:
-        """Cheap existence check (one stat) so callers can skip building
-        probe operands for artifacts that were never saved."""
-        return self._paths(name)["meta"].is_file()
+    def pruned(self, name: str) -> bool:
+        """THIS environment tried the artifact's exec tier and dropped it
+        (:meth:`drop_tier` keeps the meta): saving it again would write the
+        same losing artifact. Another environment's meta says nothing of
+        the kind, and is overwritten."""
+        meta = self._meta(self._paths(name)["meta"])
+        return meta is not None and "exec" not in meta.get("tiers", ())
+
+    def _meta(self, meta_path: Path) -> dict | None:
+        """The artifact's meta when it is THIS environment's (platform,
+        jax, jaxlib, device count, mesh); None for another's, or none."""
+        try:
+            meta = json.loads(meta_path.read_text())
+        except Exception:
+            return None
+        env = _env_key(self.mesh)
+        if any(meta.get(k) != v for k, v in env.items()):
+            log.info("aot %s: environment mismatch (%s vs %s), ignoring",
+                     meta_path.name, meta, env)
+            return None
+        return meta
+
+    # -- the exec tier without operands (a single-chip LlamaServer) ---------
+
+    def save_later(self, name: str, compiled, boot: bool) -> None:
+        """Queue the ``Compiled`` a server has just obtained and run for
+        the exec tier. Serialising and writing happen on the store's one
+        saver thread, so the caller's first run waits for none of it;
+        :meth:`drain` waits for the queue. ``boot``: see :meth:`preload`."""
+        if self.mesh is not None or not self.writable:
+            return
+        with self._saver_lock:
+            if self._pending is None:
+                self._pending = queue.Queue()
+                threading.Thread(target=self._save_loop, daemon=True,
+                                 name="aot-save").start()
+        self._pending.put((name, compiled, boot))
+
+    def _save_loop(self) -> None:
+        while True:
+            name, compiled, boot = self._pending.get()
+            try:
+                if self.writable:
+                    self._save_compiled(name, compiled, boot)
+            finally:
+                self._pending.task_done()
+
+    def _save_compiled(self, name: str, compiled, boot: bool) -> None:
+        from jax.experimental import serialize_executable
+
+        paths = self._paths(name)
+        try:
+            with spans.span("boot.aot_save", program=name):
+                payload = pickle.dumps(
+                    serialize_executable.serialize(compiled))
+                self.dir.mkdir(parents=True, exist_ok=True)
+                atomic_write_bytes(paths["exec"], payload)
+                atomic_write_text(paths["meta"], json.dumps(
+                    {**_env_key(), "tiers": ["exec"], "boot": boot},
+                    indent=1))
+            self.saved += 1
+        except Exception as e:  # a read-only bundle (OSError), or a
+            # backend that cannot serialise: met once, not once a program
+            self.writable = False
+            log.info("aot %s: not saved, and nothing after it (%s)", name, e)
+
+    def drain(self) -> None:
+        """Wait until everything queued by :meth:`save_later` is written."""
+        if self._pending is not None:
+            self._pending.join()
+
+    def load_exec(self, name: str):
+        """``(executable, aot_load seconds or None)`` of the exec tier, NOT
+        probed: deserialising needs no operands, and the caller's first real
+        call is the probe (:meth:`drop_tier` if it fails). The seconds are
+        None where :meth:`preload` had loaded it. None: no artifact, another
+        environment's, or a tier that does not load (dropped here)."""
+        paths = self._paths(name)
+        meta = self._meta(paths["meta"])
+        if meta is None or "exec" not in meta.get("tiers", ()):
+            self._preloaded.pop(name, None)
+            return None
+        pre = self._preloaded.pop(name, None)
+        if pre is not None and pre[1] == "exec":
+            return pre[0], None
+        try:
+            with spans.span("boot.aot_load", program=name,
+                            tier="exec") as sp:
+                fn = self._load_tier("exec", paths)
+        except Exception as e:
+            log.warning("aot %s: exec tier failed to load: %s", name, e)
+            self.drop_tier(name, "exec")
+            return None
+        return fn, sp.seconds
+
+    def drop_tier(self, name: str, tier: str) -> None:
+        """Prune a tier that does not load or run here. The meta stays,
+        without the tier, even when none survives: it records "tried on
+        this platform", so no later boot writes or probes the same losing
+        artifact again."""
+        paths = self._paths(name)
+        try:
+            meta = json.loads(paths["meta"].read_text())
+            meta["tiers"] = [t for t in meta.get("tiers", ()) if t != tier]
+            paths[tier].unlink(missing_ok=True)
+            atomic_write_text(paths["meta"], json.dumps(meta, indent=1))
+        except Exception as e:  # read-only bundle: the next boot tries again
+            log.info("aot %s: %s tier not pruned: %s", name, tier, e)
 
     def preload(self, prefix: str = "srv-") -> dict:
         """Deserialize (and device-load) every matching artifact's best
         tier WITHOUT probing. Deserializing and loading an executable
         needs NO operands — the model weights don't have to be resident —
         so a boot overlaps this with the weight upload instead of paying
-        programs-after-weights serially (VERDICT r5 #5). ``load()`` later
-        consumes the preloaded callable and runs its usual probe at first
-        invoke, when params exist.
+        programs-after-weights serially (VERDICT r5 #5). ``load()`` /
+        ``load_exec()`` later consume the preloaded callable; the probe is
+        its first invoke, when params exist.
+
+        Only the BOOT SET is loaded: an artifact whose meta says ``"boot":
+        false`` was saved by a server after its boot's own warm-up had
+        ended, for a program the traffic asked for. A deploy does not wait
+        for those (2-7 s each at 7B widths, one after the other): they are
+        loaded at their first use.
 
         Returns ``{"names": [...], "seconds": s}`` for the boot
         decomposition. Failures are per-artifact and silent — a broken
@@ -285,16 +344,10 @@ class AotStore:
         with spans.span("boot.aot_preload") as whole:
             sig = _mesh_sig(self.mesh)
             suffix = f".{jax.default_backend()}" + (f".{sig}" if sig else "")
-            env = _env_key(self.mesh)
             for meta_path in sorted(self.dir.glob(f"{prefix}*{suffix}.json")):
                 name = meta_path.name[: -len(suffix + ".json")]
-                try:
-                    meta = json.loads(meta_path.read_text())
-                except Exception:
-                    continue
-                if any(meta.get(k) != env[k]
-                       for k in ("schema", "platform", "jax", "jaxlib",
-                                 "n_devices", "mesh")):
+                meta = self._meta(meta_path)
+                if meta is None or meta.get("boot") is False:
                     continue
                 paths = self._paths(name)
                 for tier in ("exec", "hlo"):
@@ -322,7 +375,11 @@ class AotStore:
             from jax.experimental import serialize_executable
 
             payload = pickle.loads(paths["exec"].read_bytes())
-            return serialize_executable.deserialize_and_load(*payload)
+            # the exec tier is single-chip (mesh None): on a host with
+            # more devices it runs where unplaced operands live, on the
+            # first; without this the load asks for a shard a device
+            return serialize_executable.deserialize_and_load(
+                *payload, execution_devices=jax.devices()[:1])
         if tier == "hlo" and paths["hlo"].is_file():
             exported = jax.export.deserialize(bytearray(paths["hlo"].read_bytes()))
             return jax.jit(exported.call)
@@ -345,16 +402,8 @@ class AotStore:
         paths = self._paths(name)
         if not paths["meta"].is_file():
             return None
-        try:
-            meta = json.loads(paths["meta"].read_text())
-        except Exception:
-            return None
-        env = _env_key(self.mesh)
-        if any(meta.get(k) != env[k]
-               for k in ("schema", "platform", "jax", "jaxlib", "n_devices",
-                         "mesh")):
-            log.info("aot %s: environment mismatch (%s vs %s), ignoring",
-                     name, meta, env)
+        meta = self._meta(paths["meta"])
+        if meta is None:
             return None
 
         def _probe(fn: Callable) -> None:

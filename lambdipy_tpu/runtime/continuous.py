@@ -841,16 +841,19 @@ class ContinuousBatcher:
         bucket group prefill can see (the ``group_prefill_max`` bucket,
         clamped to what the engine cache admits) at the full-burst
         joiner count — without it a burst of long-ish prompts paid the
-        cliff the warm exists to remove (ADVICE r5). Residual cliff,
-        deliberate: prompt buckets BETWEEN the min and the max family
-        (e.g. 32/64/128 under a 256 cap) still compile at first use —
-        warming every (count, bucket) pair is quadratic in programs and
-        warm wall-time, and the two endpoints cover the dominant
-        traffic. Each program lands in the server's stream-pair AOT
-        store on the next ``aot_save_all``, so later boots preload them
-        instead of compiling at all. Returns programs touched; meant
-        for the handler's background warm daemon, never the boot
-        path."""
+        cliff the warm exists to remove (ADVICE r5). The prompt buckets
+        BETWEEN the min and the max family (e.g. 32/64/128 under a 256
+        cap) are not warmed here — every (count, bucket) pair is
+        quadratic in programs and in warm wall-time, and a deploy would
+        wait for pairs its traffic never sends. They cost their compile
+        ONCE in a writable bundle's life: a pair is snapshotted into the
+        server's AOT store where it first compiles (this warm or the
+        first burst that needs it) and loaded at its first use in every
+        later boot; only a read-only bundle whose build never ran the
+        pair pays the cliff each boot. Later boots preload the pairs
+        warmed here instead of compiling at all. Returns programs
+        touched; meant for the handler's background warm daemon, never
+        the boot path."""
         from lambdipy_tpu.models.llama import _next_bucket
 
         counts = []

@@ -491,10 +491,14 @@ def generate_handler(spec: dict, ctx) -> HandlerState:
             # window caches otherwise — at 8B dims that is HBM that the
             # operator must be able to cap per bundle)
             bcl = extra.get("batch_cache_len")
-            # length-aware window bucketing (on by default): pow-2
-            # window program variants are compiled AT FIRST USE per
-            # bucket (deliberately un-AOT-able), so a latency-critical
-            # bundle can opt out via
+            # length-aware window bucketing (on by default): a pow-2
+            # window program variant enters at the FIRST USE of its
+            # bucket: compiled (10-13 s a persistent-cache hit at 7B
+            # widths, PR 37) and snapshotted the first time a writable
+            # bundle sees it, loaded from the bundle's AOT exec tier
+            # (2-7 s) on every later boot. A read-only bundle whose
+            # build never ran that bucket pays the compile each boot; a
+            # latency-critical one can opt out via
             # `batch_window_bucketing = "0"` (or the
             # LAMBDIPY_WINDOW_BUCKETING env default) and keep the
             # single AOT-warmed full-window segment program. Same
@@ -1157,9 +1161,11 @@ def generate_handler(spec: dict, ctx) -> HandlerState:
                     with _warm_lock:
                         warm_state["errors"].append(f"group_prefill: {e}")
             # the programs this thread just compiled should boot from
-            # the AOT tier next time too
+            # the AOT tier next time too; with it the boot's own programs
+            # end: what is compiled from here on is the traffic's, loaded
+            # at first use and not before the deploy is ready
             try:
-                server.aot_save_all()
+                server.aot_save_all(boot_done=True)
             except Exception:  # noqa: BLE001 — AOT is best-effort
                 pass
             with _warm_lock:
@@ -1397,12 +1403,14 @@ def generate_handler(spec: dict, ctx) -> HandlerState:
         finally:
             if req.get("warmup") and server is not None:
                 # the warmup invoke itself compiled the fused decode
-                # program — snapshot everything compiled so far into the
-                # bundle's AOT exec tier so the NEXT boot loads
-                # executables instead of recompiling (no-op for programs
-                # that were themselves AOT-loaded)
+                # program — everything compiled so far is in the bundle's
+                # AOT exec tier before the boot goes on, so the NEXT boot
+                # loads executables instead of recompiling (no-op for
+                # programs that were themselves AOT-loaded). Where no
+                # warm daemon follows, the boot's own programs end here
                 try:
-                    server.aot_save_all()
+                    server.aot_save_all(boot_done=not (
+                        warm_state["requested"] or warm_group))
                 except Exception:  # noqa: BLE001 — AOT is best-effort
                     pass
             # first completed invoke (the boot warmup) releases the
@@ -1590,7 +1598,13 @@ def generate_handler(spec: dict, ctx) -> HandlerState:
         out = {"decode_buckets": [list(b) for b in server.buckets],
                "compile_count": server.compile_count,
                "program_evictions": server.program_evictions,
-               "aot_hits": getattr(server, "aot_hits", 0)}
+               # programs taken from the bundle's AOT store this boot; of
+               # them, deserialised at first use (not by the preload);
+               # loaded executables whose first call failed (served from
+               # jit, artifact pruned); artifacts written this boot
+               **{k: getattr(server, k, 0)
+                  for k in ("aot_hits", "aot_lazy_loads", "aot_fallbacks",
+                            "aot_saved")}}
         if preload_state:
             # programs deserialized concurrently with the weight upload
             # (cold-start overlap): count + seconds the preload took

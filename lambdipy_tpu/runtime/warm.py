@@ -7,6 +7,21 @@ so the XLA compilation cache the bundle ships is already hot and the serve
 boot's "first" compile is a cache hit — SURVEY.md §9.6: "persistent
 compilation cache shipped *inside* the bundle".
 
+What is warmed is what a BOOT of the bundle runs before it is ready: the
+warm-up invoke (on a generate bundle: the row prefill, the engine's
+start-window segment, the streaming pair) and the handler's warm daemon
+(``warm_buckets``, the group-prefill programs at the two ends of the bucket
+range). A single-chip server snapshots each of those into ``<bundle>/aot``
+as it compiles (``models/llama.py`` ``_ServedProgram``), marked as the
+bundle's boot set: every later boot loads them beside the weights. What the
+TRAFFIC adds (the other prompt buckets, joiner counts and decode windows)
+is not run here. A first serve from a writable bundle compiles each such
+program once, against this cache, and saves it beside the others, to be
+loaded at its first use from then on; a read-only bundle (Lambda's
+``/var/task``) saves nothing and pays the cache hit at every boot until its
+build runs the traffic's envelope too — the follow-up, which needs a recipe
+field that states the envelope (ROADMAP queue 2, R12).
+
 Usage: ``python -m lambdipy_tpu.runtime.warm <bundle_dir>``
 (honors LAMBDIPY_PLATFORM like the server).
 """

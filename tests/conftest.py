@@ -48,6 +48,24 @@ def tiny_server():
     return adapter.make_server(params)
 
 
+@pytest.fixture()
+def fresh_compiles():
+    """No persistent compile cache while the test runs. A worker that
+    booted a bundle earlier keeps that bundle's cache directory set (jax's
+    configuration is the process's), and XLA:CPU serialises an executable
+    the persistent cache ANSWERED without its kernels: it loads, and its
+    first call fails (``Function ... not found``). A test that snapshots
+    what it compiles into an AOT store compiles it afresh."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
 @pytest.fixture(scope="session")
 def cpu_devices():
     devices = jax.devices()

@@ -233,6 +233,66 @@ def test_a_program_cache_hit_adds_no_entry(booted):
     assert window(before, after, "eng.dispatch")[0] >= 3
 
 
+def test_a_second_boot_lists_the_engines_programs_under_exec(tmp_path):
+    """A bundle that has served its traffic once: the next boot's program
+    record holds the engine's prefill and segment programs under ``exec``
+    (no ``jit(prefill)``, no ``jit(seg)``), the ones the traffic asked for
+    loaded at their first use, and ``boot.aot_preload`` holds the boot's
+    own programs and no more: the deploy waits for nothing it may never
+    run."""
+    import time
+
+    from lambdipy_tpu.runtime.server import BundleServer
+
+    bundle = make_model_bundle(
+        tmp_path, model="llama-tiny",
+        handler="lambdipy_tpu.runtime.handlers:generate_handler",
+        extra={"batch_mode": "continuous", "batch_max": "4",
+               "batch_segment": "4", "max_new_tokens": "8",
+               "serve_aot": "1", "warm_group_prefill": "1"})
+
+    def boot():
+        before, t = spans.report(), now()
+        server = BundleServer(bundle, port=0).start_background()
+        base = f"http://127.0.0.1:{server.port}"
+        try:
+            while not _get(f"{base}/healthz")["ready"]:
+                time.sleep(0.05)
+            at_ready = _get(f"{base}/metrics")
+            served = stream_completion(server.port, list(range(1, 41)), 24)
+            handler = _get(f"{base}/metrics")["handler"]
+            record = [p for p in _get(f"{base}/spans?last=0")["programs"]
+                      if p["t"] > t]
+        finally:
+            server.stop()
+        loads = window(before, at_ready["spans"], "boot.aot_load")[0]
+        return served, at_ready["handler"], handler, record, loads
+
+    served1, ready1, after1, record1, loads1 = boot()
+    engine = [p for p in record1 if "key" in p
+              and p["key"][0] in ("stream", "seg_w")]
+    assert engine and {p["source"] for p in engine} == {"jit"}
+    assert loads1 == 0 and ready1["aot_saved"] > 0
+    assert after1["aot_saved"] > ready1["aot_saved"]   # the traffic's own
+    assert after1["aot_saved"] >= len(engine)  # + the fused decode program
+
+    served2, ready2, after2, record2, loads2 = boot()
+    assert served2 == served1
+    engine2 = [p for p in record2 if "key" in p
+               and p["key"][0] in ("stream", "seg_w")]
+    assert {p["source"] for p in engine2} == {"exec"}
+    assert sorted(p["name"] for p in engine2) \
+        == sorted(p["name"] for p in engine)
+    assert not [p for p in record2
+                if p["name"] in ("jit(prefill)", "jit(seg)")]
+    # the preload held the boot set: what the first boot had saved by the
+    # time it was ready, and not what the traffic added
+    assert ready2["aot_preload"]["programs"] == loads2 == ready1["aot_saved"]
+    assert after2["aot_lazy_loads"] \
+        == after1["aot_saved"] - ready1["aot_saved"] > 0
+    assert after2["aot_saved"] == after2["aot_fallbacks"] == 0
+
+
 def test_the_aot_store_writes_its_loads_and_first_runs(tmp_path):
     import jax.numpy as jnp
 

@@ -39,6 +39,10 @@ summaries read-only inside their scan (a ring tail and a summary tail,
 ``llama.LlamaBlock._eva_tail_attend``). The two solo prefills and every
 llama, ``mistral7b`` and ``deepseek7b`` hash are what they were.
 
+Generation g7 (PR 38) moved NONE of them: it changed the set of NAMES (a
+window-bucketed segment has one now, and a server snapshots a program where
+it first compiles), not a program's text.
+
 A routed-FFN model's programs have no golden text: PR 28 gave its segment
 programs a second counter, and on a TPU backend their small calls take a
 Pallas kernel (``ops/grouped_experts.py``), which changes their cache keys
@@ -83,7 +87,7 @@ def text_hash(fn, *args) -> str:
 @pytest.mark.parametrize("model,quant,kv_quant", list(GOLDEN))
 def test_a_llama_program_lowers_to_the_text_its_generation_was_taken_at(
         model, quant, kv_quant):
-    assert LlamaServer._AOT_GEN == "g6", "new generation: take the hashes anew"
+    assert LlamaServer._AOT_GEN == "g7", "new generation: take the hashes anew"
     extra = dict(HF_TOY) if model == "llama-hf" else {}
     if kv_quant:
         extra["kv_quant"] = kv_quant
@@ -121,7 +125,7 @@ CELL_GOLDEN = {
 
 @pytest.mark.parametrize("name", list(CELL_GOLDEN))
 def test_an_accepted_cells_programs_keep_their_text_at_the_cells_shapes(name):
-    assert LlamaServer._AOT_GEN == "g6", "new generation: take the hashes anew"
+    assert LlamaServer._AOT_GEN == "g7", "new generation: take the hashes anew"
     window, golden = CELL_GOLDEN[name]
     config = json.loads((Path(__file__).parents[1] / "benchmark" / "configs"
                          / f"{name}.json").read_text())
@@ -178,7 +182,7 @@ def eva_hashes() -> tuple:
 
 
 def test_the_eva_programs_keep_their_text_at_the_cells_shapes():
-    assert LlamaServer._AOT_GEN == "g6", "new generation: take the hashes anew"
+    assert LlamaServer._AOT_GEN == "g7", "new generation: take the hashes anew"
     assert eva_hashes() == EVA_GOLDEN
 
 
