@@ -191,6 +191,119 @@ def import_hf_llama(source, *, config_overrides: dict | None = None):
     return cfg, {"params": params}
 
 
+# -- MiniCPM-SALA: an attention kind a layer ---------------------------------
+
+_SALA_KINDS = {"minicpm4": "sparse_kv", "lightning-attn": "linear"}
+# MiniCPM4's published sparse_config (InfLLM-V2), for a config that carries
+# none of its own
+_SALA_SPARSE = {"kernel_size": 32, "kernel_stride": 16, "block_size": 64,
+                "topk": 64, "init_blocks": 1, "window_size": 2048,
+                "dense_len": 8192}
+# our module name -> the checkpoint's, under ``model.layers.<i>.``; the gate
+# and the head norms' names are as this file's author knows the published
+# ``modeling_minicpm_sala.py`` and could not be checked offline
+SALA_NAMES = {
+    "attn_norm": "input_layernorm", "mlp_norm": "post_attention_layernorm",
+    "q_proj": "self_attn.q_proj", "k_proj": "self_attn.k_proj",
+    "v_proj": "self_attn.v_proj", "o_proj": "self_attn.o_proj",
+    "q_norm": "self_attn.q_norm", "k_norm": "self_attn.k_norm",
+    "o_norm": "self_attn.o_norm", "out_gate_proj": "self_attn.o_gate",
+    "gate_proj": "mlp.gate_proj", "up_proj": "mlp.up_proj",
+    "down_proj": "mlp.down_proj"}
+
+
+def minicpm_sala_config_from_hf(hf_cfg: dict, **overrides):
+    """Map a ``minicpm_sala`` config dict onto our LlamaConfig: the layers'
+    kinds from ``mixer_types`` (``minicpm4`` -> ``sparse_kv``,
+    ``lightning-attn`` -> ``linear``), the muP scalars, the sparse settings
+    (``sparse_config``, MiniCPM4's where absent). ``scale_depth`` is over
+    ``num_hidden_layers``: a checkpoint cut in depth passes the published
+    count as ``published_layers``. Raises for what would silently change
+    numerics."""
+    import jax.numpy as jnp
+
+    from lambdipy_tpu.models.llama import LlamaConfig
+
+    _check_supported_hf_config(hf_cfg)
+    for key, want in (("attn_use_rope", False), ("tie_word_embeddings", False),
+                      ("lightning_scale", "1/sqrt(d)")):
+        if hf_cfg.get(key, want) != want:
+            raise ValueError(f"unsupported HF config field: {key}="
+                             f"{hf_cfg[key]!r} (served: {want!r})")
+    if hf_cfg["lightning_nkv"] != hf_cfg["lightning_nh"]:
+        raise ValueError("unsupported HF config field: lightning_nkv differs "
+                         "from lightning_nh (grouped linear heads are not "
+                         "implemented)")
+    kinds = tuple(_SALA_KINDS.get(m, m) for m in hf_cfg["mixer_types"])
+    sparse = {**_SALA_SPARSE, **(hf_cfg.get("sparse_config") or {})}
+    depth = int(overrides.pop("published_layers",
+                              hf_cfg["num_hidden_layers"]))
+    cfg = LlamaConfig(
+        vocab_size=int(hf_cfg["vocab_size"]),
+        hidden=int(hf_cfg["hidden_size"]),
+        layers=int(hf_cfg["num_hidden_layers"]),
+        heads=int(hf_cfg["num_attention_heads"]),
+        kv_heads=int(hf_cfg["num_key_value_heads"]),
+        mlp=int(hf_cfg["intermediate_size"]),
+        max_len=int(hf_cfg.get("max_position_embeddings", 8192)),
+        rope_theta=float(hf_cfg.get("rope_theta", 10000.0)),
+        norm_eps=float(hf_cfg.get("rms_norm_eps", 1e-5)),
+        dtype=jnp.bfloat16, layer_kinds=kinds,
+        qk_norm=bool(hf_cfg.get("qk_norm", True)),
+        attn_output_gate=bool(hf_cfg.get("attn_use_output_gate", True)),
+        sparse_kernel=int(sparse["kernel_size"]),
+        sparse_stride=int(sparse["kernel_stride"]),
+        sparse_block=int(sparse["block_size"]),
+        sparse_topk=int(sparse["topk"]),
+        sparse_init_blocks=int(sparse["init_blocks"]),
+        sparse_window=int(sparse["window_size"]),
+        sparse_dense_len=int(sparse["dense_len"]),
+        lin_heads=int(hf_cfg["lightning_nh"]),
+        lin_head_dim=int(hf_cfg["lightning_head_dim"]),
+        lin_rope=bool(hf_cfg.get("lightning_use_rope", True)),
+        lin_output_norm=bool(hf_cfg.get("use_output_norm", True)),
+        embed_scale=float(hf_cfg.get("scale_emb", 1.0)),
+        residual_scale=float(hf_cfg.get("scale_depth", 1.0)) / depth ** 0.5,
+        logit_divisor=float(hf_cfg["hidden_size"])
+        / float(hf_cfg.get("dim_model_base", hf_cfg["hidden_size"])))
+    return dataclasses.replace(cfg, **overrides) if overrides else cfg
+
+
+def import_minicpm_sala(state_dict, hf_cfg: dict, **config_overrides):
+    """Convert a ``minicpm_sala`` checkpoint's state dict (its config beside
+    it) into (LlamaConfig, params): float kernels, ``quantize_params``
+    after. A layer's leaves are its kind's: a ``lightning-attn`` layer has an
+    output norm, every layer the head norms and the gate the config
+    switches on."""
+    cfg = minicpm_sala_config_from_hf(hf_cfg, **config_overrides)
+    sd = {k: _to_numpy(v) for k, v in dict(state_dict).items()}
+
+    def leaf(ours, name):
+        w = sd[f"{name}.weight"]
+        return {"scale": w} if ours.endswith("norm") \
+            else {"kernel": np.ascontiguousarray(w.T)}
+
+    params: dict = {
+        "embed": {"embedding": sd["model.embed_tokens.weight"]},
+        "final_norm": {"scale": sd["model.norm.weight"]},
+        "lm_head": {"kernel": np.ascontiguousarray(sd["lm_head.weight"].T)}}
+    for i, kind in enumerate(cfg.layer_kinds):
+        skip = set()
+        if not cfg.qk_norm:
+            skip |= {"q_norm", "k_norm"}
+        if not cfg.attn_output_gate:
+            skip.add("out_gate_proj")
+        if kind != "linear" or not cfg.lin_output_norm:
+            skip.add("o_norm")
+        params[f"layer_{i}"] = {
+            ours: leaf(ours, f"model.layers.{i}.{theirs}")
+            for ours, theirs in SALA_NAMES.items() if ours not in skip}
+    n = sum(v.size for v in jax_tree_leaves(params))
+    log_event(log, "hf minicpm_sala imported", layers=cfg.layers,
+              n_params=int(n))
+    return cfg, {"params": params}
+
+
 def jax_tree_leaves(tree):
     import jax
 

@@ -32,7 +32,7 @@ log = get_logger("lambdipy.llama")
 
 
 class LayerSpec(NamedTuple):
-    attn: str  # "kv" | "latent" | "eva"
+    attn: str  # "kv" | "latent" | "eva" | "sparse_kv" | "linear"
     ffn: str   # "dense" | "capacity" | "routed"
 
 
@@ -165,11 +165,63 @@ class LlamaConfig:
     # written: PERF.md section 7).
     moe_experts_held: int = 0
     moe_first_expert: int = 0
+    # -- the attention kind a LAYER (ROADMAP R2, R4). ``layer_kinds``: one
+    # kind a layer in place of the model's one ``attn_kind``: "kv" (the
+    # llama block's per-step-write form) and the kinds that are modules of
+    # their own (:func:`attn_kind_module`): "sparse_kv" (models/sparse_kv.py:
+    # grouped-query K/V whose attended blocks are chosen by content from a
+    # second leaf of compressed keys: ``sparse_*``) and "linear"
+    # (models/linear_attn.py: a recurrent state a slot and no row a token:
+    # ``lin_*``). A layer's cache entry, its prefill, its step and what
+    # refuses it are its kind's; ``cache_layout`` / ``cache_positions`` /
+    # ``cache_slot`` take the layer. The latent and eva kinds stay one a
+    # model (``attn_kind``).
+    layer_kinds: tuple = ()
+    # RMSNorm with a learned gain over each head's query and key
+    qk_norm: bool = False
+    # the heads' outputs x sigmoid(out_gate_proj h) before o_proj
+    attn_output_gate: bool = False
+    sparse_kernel: int = 32
+    sparse_stride: int = 16
+    sparse_block: int = 64
+    sparse_topk: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window: int = 2048
+    sparse_dense_len: int = 8192
+    lin_heads: int = 0
+    lin_head_dim: int = 0
+    lin_rope: bool = True
+    lin_output_norm: bool = True
+    # MiniCPM's three scalars: the embedding x ``embed_scale``, each
+    # sublayer's output x ``residual_scale`` before it joins the residual,
+    # the final norm's output / ``logit_divisor`` before the head
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_divisor: float = 1.0
 
     def __post_init__(self):
         if self.attn_kind not in ("kv", "latent", "eva"):
             raise ValueError(f"unknown attn_kind {self.attn_kind!r}; "
                              "supported: kv, latent, eva")
+        if self.layer_kinds:
+            kinds = tuple(self.layer_kinds)
+            if len(kinds) != self.layers or self.attn_kind != "kv" \
+                    or not set(kinds) <= {"kv", *ATTN_KIND_MODULES}:
+                raise ValueError(
+                    f"layer_kinds {kinds!r}: one kind for each of the "
+                    f"{self.layers} layers, of kv, "
+                    f"{', '.join(ATTN_KIND_MODULES)} (attn_kind stays kv: "
+                    "the latent and eva kinds are one a model)")
+            for kind in sorted(set(kinds) - {"kv"}):
+                attn_kind_module(kind).validate(self)
+                if self.kv_quant is not None \
+                        or self.attn_backend != "dense":
+                    raise NotImplementedError(
+                        f"kv_quant={self.kv_quant!r} / attn_backend="
+                        f"{self.attn_backend!r}: the int8 cache layout and "
+                        "the flash, blocked and ring backends hold one "
+                        f"per-head K/V row a token; a {kind} layer runs "
+                        "the dense backend over its own leaves")
         if self.attn_kind == "eva":
             if self.chunk_size < 1 or self.window_size < self.chunk_size \
                     or self.window_size % self.chunk_size:
@@ -256,14 +308,25 @@ class LlamaConfig:
             ffn = "dense" if layer < self.first_dense_layers else "routed"
         else:
             ffn = "capacity" if self.moe_experts else "dense"
-        return LayerSpec(self.attn_kind, ffn)
+        return LayerSpec(self.layer_kinds[layer] if self.layer_kinds
+                         else self.attn_kind, ffn)
 
-    def cache_layout(self) -> dict:
-        """The cache row of one token of one layer: ``{leaf: (heads,
+    def first_layer_of(self, kind: str) -> int:
+        """The first layer of attention kind ``kind`` (the one that sows the
+        kind's counters: every layer's are the same), -1 where none is."""
+        return next((i for i in range(self.layers)
+                     if self.layer_spec(i).attn == kind), -1)
+
+    def cache_layout(self, layer: int = 0) -> dict:
+        """The cache row of one token of layer ``layer``: ``{leaf: (heads,
         width)}`` in storage order. Every leaf is 4-D ``[rows, positions,
-        heads, width]`` (a latent leaf has a head axis of 1), so whatever
-        iterates a cache entry's leaves -- slicing, copying, window
-        buckets, the engine's pack -- never asks which kind it holds."""
+        heads, width]`` (a latent leaf has a head axis of 1, a linear
+        layer's state a position axis of 1), so whatever iterates a cache
+        entry's leaves -- slicing, copying, window buckets, the engine's
+        pack -- never asks which kind it holds."""
+        module = attn_kind_module(self.layer_spec(layer).attn)
+        if module is not None:
+            return module.cache_layout(self)
         if self.attn_kind == "latent":
             row = {"ckv": (1, self.kv_lora_rank), "kpe": (1, self.qk_rope)}
             if self.index_topk:
@@ -277,20 +340,38 @@ class LlamaConfig:
             return {"k": row, "v": row, "sk": row, "sv": row}
         return {"k": row, "v": row}
 
-    def cache_positions(self, max_len: int) -> dict:
+    def cache_positions(self, max_len: int, layer: int = 0) -> dict:
         """``{leaf: slots}``: the length of each leaf's position axis in a
         cache that serves absolute positions ``0 .. max_len - 1``. One row
         a token for the "kv" and "latent" kinds; an eva ring never grows
-        past its window and a summary leaf holds one row a chunk."""
+        past its window and a summary leaf holds one row a chunk; a
+        block-sparse layer has a compressed key every ``sparse_stride``
+        positions beside its rows; a linear layer's state has one slot."""
+        module = attn_kind_module(self.layer_spec(layer).attn)
+        if module is not None:
+            return module.cache_positions(self, max_len)
         if self.attn_kind != "eva":
-            return dict.fromkeys(self.cache_layout(), max_len)
+            return dict.fromkeys(self.cache_layout(layer), max_len)
         ring = min(self.window_size, max_len)
         chunks = -(-max_len // self.chunk_size)
         return {"k": ring, "v": ring, "sk": chunks, "sv": chunks}
 
-    def cache_slot(self, leaf: str, position):
-        """The slot of ``leaf``'s position axis that holds absolute
-        position ``position`` (an int or an int array)."""
+    def cache_dtypes(self, layer: int = 0) -> dict:
+        """``{leaf: dtype}`` of layer ``layer``'s entry: ``dtype`` but for a
+        kind that says otherwise (a linear state is float32)."""
+        module = attn_kind_module(self.layer_spec(layer).attn)
+        if module is not None:
+            return module.cache_dtypes(self)
+        return dict.fromkeys(self.cache_layout(layer), self.dtype)
+
+    def cache_slot(self, leaf: str, position, layer: int = 0):
+        """The slot of ``leaf``'s position axis (in layer ``layer``'s entry)
+        that holds absolute position ``position`` (an int or an int
+        array): for a compressed key or a summary, the one whose span the
+        position BEGINS in; a state has one slot."""
+        module = attn_kind_module(self.layer_spec(layer).attn)
+        if module is not None:
+            return module.cache_slot(self, leaf, position)
         if self.attn_kind != "eva":
             return position
         if leaf in ("sk", "sv"):
@@ -305,6 +386,11 @@ class LlamaConfig:
         above it would cost four."""
         if self.attn_kind == "eva" and s > self.window_size:
             return -(-s // self.window_size) * self.window_size
+        if self.layer_kinds and s > 2 * SALA_PROMPT_BLOCK:
+            # a block-sparse prefill runs one body a block of keys and a
+            # linear one a scan over chunks: whole blocks (a 20k prompt
+            # must not pad to 32k)
+            return -(-s // SALA_PROMPT_BLOCK) * SALA_PROMPT_BLOCK
         if self.index_topk and s > DSA_KEY_BLOCK:
             # a sparse prefill runs one body a block of keys (its queries
             # score the keys up to their own block's end): whole blocks
@@ -349,16 +435,66 @@ class LlamaConfig:
         its steps selected and the keys they chose from (``handler.dsa``)."""
         return bool(self.index_topk)
 
+    @property
+    def counts_sala_keys(self) -> bool:
+        """Whether the engine's segment programs return, a row, what its
+        block-sparse steps attended, could see, whether they lay inside
+        ``sparse_dense_len`` and the compressed keys they wrote
+        (``handler.sala``)."""
+        return "sparse_kv" in self.layer_kinds
+
+    @property
+    def state_bytes_a_step(self) -> int:
+        """Bytes of recurrent state one row's decode step reads and writes:
+        every linear layer's, once each way."""
+        return 2 * 4 * self.lin_heads * self.lin_head_dim ** 2 \
+            * sum(kind == "linear" for kind in self.layer_kinds)
+
+
+# the kinds that are modules of their own, by name (imported on demand: they
+# import this module's layers)
+ATTN_KIND_MODULES = ("linear", "sparse_kv")
+# prompts past two of these prefill at whole multiples of it (models with
+# ``layer_kinds``): the keys of one block of a block-sparse prefill
+# (``sparse_kv.SPARSE_KEY_BLOCK``)
+SALA_PROMPT_BLOCK = 4096
+
+
+def attn_kind_module(kind: str):
+    """The module of attention kind ``kind`` (its cache layout, its prefill
+    and step, its refusals), None for the kinds ``LlamaBlock`` holds itself
+    (kv, latent, eva)."""
+    if kind == "sparse_kv":
+        from lambdipy_tpu.models import sparse_kv
+
+        return sparse_kv
+    if kind == "linear":
+        from lambdipy_tpu.models import linear_attn
+
+        return linear_attn
+    return None
+
 
 def require_kv_cache(cfg: LlamaConfig, holder: str) -> None:
     """Raise for a cache holder that knows only per-head K/V leaves (no
     silent fallback: the holder would store, ship or page rows of the
     wrong layout)."""
+    _refuse_kind_modules(cfg, holder)
     if getattr(cfg, "attn_kind", "kv") != "kv":
         raise NotImplementedError(
             f"{holder} holds per-head k/v cache leaves and cannot take the "
             f"{cfg.attn_kind} cache layout {sorted(cfg.cache_layout())} "
             "(PERF.md section 7)")
+
+
+def _refuse_kind_modules(cfg, holder: str) -> None:
+    """Raise, in the kind's own words, for a model one of whose layers is of
+    a kind that is a module of its own: none of them keeps plain per-head
+    K/V rows that every query reads whole."""
+    for kind in getattr(cfg, "layer_kinds", ()):
+        module = attn_kind_module(kind)
+        if module is not None:
+            raise NotImplementedError(module.refusal(cfg, holder))
 
 
 def require_row_a_token(cfg: LlamaConfig, holder: str) -> None:
@@ -367,7 +503,9 @@ def require_row_a_token(cfg: LlamaConfig, holder: str) -> None:
     verified and rolled back): an eva cache has a ring that forgets and
     summaries that pool, so a span of positions is no slice of it; and a
     sparse latent cache is attended through a selection that only the
-    whole-prompt prefill and the one-token step compute."""
+    whole-prompt prefill and the one-token step compute; so is a block-sparse
+    layer's, and a linear layer's state has no position axis at all."""
+    _refuse_kind_modules(cfg, holder)
     if getattr(cfg, "attn_kind", "kv") == "eva":
         raise NotImplementedError(
             f"{holder} keeps one cache row a token on one position axis "
@@ -1045,7 +1183,16 @@ class LlamaBlock(nn.Module):
         # Renaming or moving one: bump utils/compile_cache.NAMES_GEN
         # and LlamaServer._AOT_GEN
         b, s, _ = x.shape
-        if spec.attn == "latent":
+        kind = attn_kind_module(spec.attn)
+        if kind is not None:
+            if band or sp_prefill:
+                raise NotImplementedError(
+                    f"a {spec.attn} layer under a sliding band or the "
+                    "sequence-parallel prefill is not written (PERF.md "
+                    "section 7)")
+            out, new_cache = kind.attend(self, x, positions, mask, cache,
+                                         lengths)
+        elif spec.attn == "latent":
             out, new_cache = self._latent_attend(x, positions, mask, cache,
                                                  band)
         elif spec.attn == "eva":
@@ -1055,9 +1202,19 @@ class LlamaBlock(nn.Module):
             out, new_cache = self._kv_attend(x, positions, mask, cache,
                                              sp_prefill, band)
 
+        # (1.0 adds nothing to the program; the product in float32: as a
+        # bfloat16 constant 1.4 / sqrt(32) would be off by 0.17 %, in every
+        # sublayer alike)
+        def scaled(y):
+            if cfg.residual_scale == 1.0:
+                return y
+            return (y.astype(jnp.float32)
+                    * jnp.float32(cfg.residual_scale)).astype(y.dtype)
+
         with jax.named_scope("o_proj"):
             out = out.reshape(b, s, -1)
-            x = x + QDense(cfg.hidden, cfg.quant, cfg.dtype, name="o_proj")(out)
+            x = x + scaled(QDense(cfg.hidden, cfg.quant, cfg.dtype,
+                                  name="o_proj")(out))
 
         with jax.named_scope("mlp"):
             if spec.ffn == "routed":
@@ -1083,8 +1240,8 @@ class LlamaBlock(nn.Module):
             else:
                 gate = QDense(cfg.mlp, cfg.quant, cfg.dtype, name="gate_proj")(h)
                 up = QDense(cfg.mlp, cfg.quant, cfg.dtype, name="up_proj")(h)
-                x = x + QDense(cfg.hidden, cfg.quant, cfg.dtype, name="down_proj")(
-                    nn.silu(gate) * up)
+                x = x + scaled(QDense(cfg.hidden, cfg.quant, cfg.dtype,
+                                      name="down_proj")(nn.silu(gate) * up))
         return x, new_cache
 
     def _latent_attend(self, x, positions, mask, cache, band: int):
@@ -1696,6 +1853,8 @@ class LlamaModel(nn.Module):
                        param_dtype=cfg.dtype, name="embed")
         with jax.named_scope("embed"):
             x = emb(tokens)
+            if cfg.embed_scale != 1.0:
+                x = x * cfg.embed_scale
         new_cache = []
         for i in range(n_layers):
             layer_cache = None if cache is None else cache[i]
@@ -1710,6 +1869,8 @@ class LlamaModel(nn.Module):
                 x = jnp.take_along_axis(
                     x, jnp.broadcast_to(logit_positions[:, None, None],
                                         (b, 1, x.shape[-1])), axis=1)
+            if cfg.logit_divisor != 1.0:
+                x = x / cfg.logit_divisor
             logits = QDense(cfg.vocab_size * cfg.pred_heads, cfg.quant,
                             jnp.float32, name="lm_head")(x)
             if cfg.pred_heads > 1:
@@ -1719,11 +1880,14 @@ class LlamaModel(nn.Module):
         return logits, new_cache
 
 
-def _empty_cache_entry(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
-    if cfg.attn_kind != "kv":
-        slots = cfg.cache_positions(max_len)
-        return {name: jnp.zeros((batch, slots[name], heads, width), cfg.dtype)
-                for name, (heads, width) in cfg.cache_layout().items()}
+def _empty_cache_entry(cfg: LlamaConfig, batch: int, max_len: int,
+                       layer: int = 0) -> dict:
+    if cfg.layer_kinds or cfg.attn_kind != "kv":
+        slots = cfg.cache_positions(max_len, layer)
+        dtypes = cfg.cache_dtypes(layer)
+        return {name: jnp.zeros((batch, slots[name], heads, width),
+                                dtypes[name])
+                for name, (heads, width) in cfg.cache_layout(layer).items()}
     shape = (batch, max_len, cfg.kv_heads, cfg.head_dim)
     if cfg.kv_quant == "int8":
         return {"k_int8": jnp.zeros(shape, jnp.int8),
@@ -1736,8 +1900,8 @@ def _empty_cache_entry(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
 
 def init_decode_cache(cfg: LlamaConfig, batch: int, max_len: int):
     """Static-shape KV cache for decode (one entry per layer)."""
-    return [{**_empty_cache_entry(cfg, batch, max_len), "index": jnp.int32(0)}
-            for _ in range(cfg.layers)]
+    return [{**_empty_cache_entry(cfg, batch, max_len, layer),
+             "index": jnp.int32(0)} for layer in range(cfg.layers)]
 
 
 # The ONE KV-cache layout rule for tensor-parallel serving: every
@@ -1788,12 +1952,15 @@ def validate_serving_mesh(cfg: LlamaConfig, mesh) -> None:
     ``tp=8`` over 4 kv heads would then pay an 8-chip mesh to replicate
     its dominant HBM object. Raise loudly instead."""
     shape = dict(getattr(mesh, "shape", {}) or {})
-    if (cfg.attn_kind != "kv" or cfg.ffn_kind != "dense") \
+    if (cfg.attn_kind != "kv" or cfg.ffn_kind != "dense"
+            or cfg.layer_kinds) \
             and any(int(n) > 1 for n in shape.values()):
         raise NotImplementedError(
             f"mesh {shape}: no sharding is written yet for latent attention "
             "(a cache row has no head axis to split), eva attention (a "
-            "ring beside pooled summaries) or the dropless routed "
+            "ring beside pooled summaries), kinds chosen a layer (a "
+            "block-sparse layer's compressed keys, a linear layer's state) "
+            "or the dropless routed "
             "FFN (a chip's share of the experts): serve this model on one "
             "device (PERF.md section 7)")
     tp = int(shape.get("tp", 1))
@@ -1994,15 +2161,19 @@ def prefill_into_cache(cfg: LlamaConfig, prefill_cache, batch: int, max_len: int
     from lambdipy_tpu.parallel.sharding import shard_hint
 
     out = []
-    slots = cfg.cache_positions(max_len)
-    for entry in prefill_cache:
-        if cfg.attn_kind == "eva":
-            store = {name: entry[name][:, :slots[name]].astype(cfg.dtype)
-                     for name in cfg.cache_layout()}
+    for layer, entry in enumerate(prefill_cache):
+        slots = cfg.cache_positions(max_len, layer)
+        if cfg.attn_kind == "eva" \
+                or attn_kind_module(cfg.layer_spec(layer).attn) is not None:
+            # a slot's entry already (a kind's module hands on its leaves,
+            # each in its own dtype)
+            dtypes = cfg.cache_dtypes(layer)
+            store = {name: entry[name][:, :slots[name]].astype(dtypes[name])
+                     for name in cfg.cache_layout(layer)}
         else:
             store = _kv_store(cfg, *(entry[name]
-                                     for name in cfg.cache_layout()))
-        dest = _empty_cache_entry(cfg, batch, max_len)
+                                     for name in cfg.cache_layout(layer)))
+        dest = _empty_cache_entry(cfg, batch, max_len, layer)
         for name, val in store.items():
             dest[name] = shard_hint(
                 jax.lax.dynamic_update_slice(dest[name], val, (0, 0, 0, 0)),
@@ -2066,11 +2237,11 @@ def pipeline_forward(model: LlamaModel, params, tokens, mesh, *,
         stack_stage_params)
 
     cfg = model.cfg
-    if cfg.ffn_kind != "dense":
+    if cfg.ffn_kind != "dense" or cfg.layer_kinds:
         raise NotImplementedError(
             "pipeline_forward stacks the layers' trees, so they must be "
-            "alike: a model with leading dense layers before routed ones "
-            "is not")
+            "alike: a model with leading dense layers before routed ones, "
+            "or with an attention kind a layer, is not")
     p = params["params"]
     n_stages = mesh.shape["pp"]
     if cfg.layers % n_stages:
@@ -2213,6 +2384,12 @@ def segment_keeps_tail(cfg: LlamaConfig) -> bool:
     (``parallel/spdecode.py``) attend the ONE cache they are handed, so
     their segments write it every step too. Asked while a segment program
     is traced, under its mesh."""
+    if cfg.layer_kinds:
+        # a linear state is a carry of the scan by nature; a block-sparse
+        # layer is grouped-query K/V, whose per-step write is in place, and
+        # its compressed key is pooled from rows the step must find in the
+        # cache. (A "kv" layer beside them keeps the per-step write too.)
+        return False
     if cfg.attn_kind == "latent" or cfg.heads != cfg.kv_heads:
         return False
     if cfg.attn_backend == "blocked":
@@ -2220,11 +2397,14 @@ def segment_keeps_tail(cfg: LlamaConfig) -> bool:
     return cfg.attn_backend != "ring" or _active_sp_mesh() is None
 
 
-def _leaf_spans(cfg: LlamaConfig, entry: dict, positions: int) -> dict:
-    """``{leaf: slots}`` for the leaves of cache entry ``entry``: the slots
-    of each that ``positions`` consecutive positions from 0 lie in:
-    ``positions`` itself where a leaf holds one row a token."""
-    spans = cfg.cache_positions(positions) if cfg.attn_kind == "eva" else {}
+def _leaf_spans(cfg: LlamaConfig, entry: dict, positions: int,
+                layer: int = 0) -> dict:
+    """``{leaf: slots}`` for the leaves of layer ``layer``'s cache entry
+    ``entry``: the slots of each that ``positions`` consecutive positions
+    from 0 lie in: ``positions`` itself where a leaf holds one row a
+    token."""
+    spans = cfg.cache_positions(positions, layer) \
+        if cfg.attn_kind == "eva" or cfg.layer_kinds else {}
     return {name: spans.get(name, positions)
             for name in entry if name != "index"}
 
@@ -2346,8 +2526,11 @@ def _segment_decode(model: LlamaModel, params, select, first, lp, cache, pos,
                             done, keys, eos_id, segment, return_carry=True,
                             count_load=cfg.counts_moe_load,
                             count_keys=cfg.counts_eva_keys,
-                            count_dsa=cfg.counts_dsa_keys, **form)
+                            count_dsa=cfg.counts_dsa_keys,
+                            count_sala=cfg.counts_sala_keys, **form)
 
+    if cfg.layer_kinds:
+        return _kinds_segment_decode(cfg, scan, cache, window)
     spans = _leaf_spans(cfg, cache[0], window)
     # (one merge would write an eva ring shorter than the segment twice:
     # a cache of a few positions keeps the per-step write)
@@ -2373,11 +2556,43 @@ def _segment_decode(model: LlamaModel, params, select, first, lp, cache, pos,
     return out, (f2, lp2, merged, pos2, done2, keys2)
 
 
+def _kinds_segment_decode(cfg: LlamaConfig, scan, cache, window: int):
+    """:func:`_segment_decode` for a model of kinds a layer: each layer's
+    leaves are cut to THEIR spans of the window (a block-sparse layer's
+    rows and its compressed keys; a linear state has one slot whatever the
+    window and is handed through), the scan writes every step, and the
+    advanced windows go back into the full carry."""
+    spans = [_leaf_spans(cfg, entry, window, layer)
+             for layer, entry in enumerate(cache)]
+
+    def short(layer, name, val):     # whether the window cuts this leaf
+        return name != "index" and spans[layer][name] < val.shape[1]
+
+    if not any(short(i, name, val) for i, entry in enumerate(cache)
+               for name, val in entry.items()):
+        return scan(cache)
+    with jax.named_scope("kv_window"):
+        win = [{name: (jax.lax.slice_in_dim(val, 0, spans[i][name], axis=1)
+                       if short(i, name, val) else val)
+                for name, val in entry.items()}
+               for i, entry in enumerate(cache)]
+    out, carry = scan(win)
+    f2, lp2, wcache, pos2, done2, keys2 = carry
+    with jax.named_scope("kv_window"):
+        merged = [{name: (jax.lax.dynamic_update_slice_in_dim(
+                              cache[i][name], val, 0, axis=1)
+                          if short(i, name, cache[i][name]) else val)
+                   for name, val in entry.items()}
+                  for i, entry in enumerate(wcache)]
+    return out, (f2, lp2, merged, pos2, done2, keys2)
+
+
 def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
                  start, done0, keys, eos_id, decode_steps: int,
                  return_carry: bool = False, pos_offset=None,
                  count_load: bool = False, tail_window: int | None = None,
-                 count_keys: bool = False, count_dsa: bool = False):
+                 count_keys: bool = False, count_dsa: bool = False,
+                 count_sala: bool = False):
     """The decode scan shared by the exact-shape path (:func:`_decode`),
     the bucketed serving path (:func:`_serve_decode`) and the streaming
     segment path: one compiled step per token over a static-shape cache.
@@ -2420,6 +2635,13 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
     int32 ``[b, 2]`` summed over the steps as layer 0 sows it
     (``dsa_stats``): the keys each row's steps attended and the keys they
     chose them from. It combines with ``count_load``.
+
+    ``count_sala`` (the engine segments of a model with block-sparse
+    layers, ``cfg.counts_sala_keys``): the emitted tuple gains, LAST, one
+    member, int32 ``[b, 4]`` summed over the steps as the first such layer
+    sows it (``sala_stats``): the keys each row's steps attended, the keys
+    they could see, the steps that lay inside ``sparse_dense_len``, and the
+    compressed keys they wrote.
 
     ``tail_window`` (the engine's plain segments, where
     :func:`segment_keeps_tail`): inside the scan the cache is READ-ONLY,
@@ -2487,6 +2709,8 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
         counted["eva_stats"] = jnp.zeros((b, 3), jnp.int32)
     if count_dsa:
         counted["dsa_stats"] = jnp.zeros((b, 2), jnp.int32)
+    if count_sala:
+        counted["sala_stats"] = jnp.zeros((b, 4), jnp.int32)
 
     def step(carry, _):
         if counted:

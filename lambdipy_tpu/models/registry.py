@@ -437,6 +437,36 @@ def _build_deepseek_v32(dtype: str = "bfloat16", quant: str | None = None,
     return _build_llama(cfg)
 
 
+@register("minicpm-sala", "jax",
+          "MiniCPM-SALA block: block-sparse (InfLLM-V2) attention layers "
+          "among Lightning linear-attention layers, muP scalars")
+def _build_minicpm_sala(dtype: str = "bfloat16", quant: str | None = None,
+                        extra: dict | None = None) -> JaxModel:
+    """The ``minicpm_sala`` architecture through the one block, its attention
+    kind chosen a LAYER (``layer_kinds``, one of ``sparse_kv`` and
+    ``linear`` for each layer, as a comma-separated string from a recipe's
+    TOML or a sequence from a manifest): models/sparse_kv.py (grouped-query
+    K/V without rope whose attended blocks are chosen by content:
+    ``sparse_*``) and models/linear_attn.py (a recurrent state a slot:
+    ``lin_*``), both with ``qk_norm`` and an output gate, under MiniCPM's
+    three scalars (``embed_scale``, ``residual_scale``, ``logit_divisor``).
+    Every shape key comes from ``extra`` (docs/serving.md, "minicpm-sala
+    recipe keys")."""
+    from lambdipy_tpu.models.llama import LlamaConfig
+
+    extra = {"qk_norm": True, "attn_output_gate": True, **(extra or {})}
+    kinds = extra.get("layer_kinds") or ()
+    if isinstance(kinds, str):
+        kinds = [k.strip() for k in kinds.split(",") if k.strip()]
+    if not kinds:
+        raise ValueError("minicpm-sala needs layer_kinds: one of sparse_kv "
+                         "and linear for each layer")
+    extra["layer_kinds"] = tuple(kinds)
+    cfg = LlamaConfig(dtype=_dtype(dtype), quant=quant,
+                      **_llama_overrides(extra))
+    return _build_llama(cfg)
+
+
 @register("evabyte", "jax",
           "EvaByte block: EVA chunked linearized attention, multi-byte heads")
 def _build_evabyte(dtype: str = "bfloat16", quant: str | None = None,

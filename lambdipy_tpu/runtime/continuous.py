@@ -219,6 +219,7 @@ class ContinuousBatcher:
                                                   MoeLoadStats,
                                                   PipelineStats,
                                                   PrefillStats,
+                                                  SalaKeyStats,
                                                   SpecDecodeStats)
 
         self.server = server
@@ -251,6 +252,13 @@ class ContinuousBatcher:
         # (llama._scan_decode count_keys; /metrics handler.eva)
         self.eva_stats = EvaKeyStats()
         self._counts_eva = bool(getattr(cfg, "counts_eva_keys", False))
+        # the segment programs of a model with block-sparse layers return,
+        # a row and LAST, what its steps attended, could see, how many lay
+        # inside dense_len and the compressed keys they wrote
+        # (llama._scan_decode count_sala; /metrics handler.sala)
+        self.sala_stats = SalaKeyStats(
+            step_state_bytes=getattr(cfg, "state_bytes_a_step", 0))
+        self._counts_sala = bool(getattr(cfg, "counts_sala_keys", False))
         self._routed_layers = (cfg.layers - cfg.first_dense_layers
                                if getattr(cfg, "counts_moe_load", False)
                                else 0)
@@ -285,6 +293,13 @@ class ContinuousBatcher:
                     "several positions wide, and the selection is computed "
                     "by the whole-prompt prefill and the one-token step "
                     "alone (PERF.md section 7)")
+            if getattr(cfg, "layer_kinds", ()):
+                raise NotImplementedError(
+                    "spec_k on a model of attention kinds a layer: a verify "
+                    "chunk is several positions wide and a rejected tail is "
+                    "rolled back, which neither a block-sparse layer's "
+                    "selection nor a linear layer's recurrent state can "
+                    "take (PERF.md section 7)")
             if self._counts_eva:
                 raise NotImplementedError(
                     "spec_k on an eva-attention model: a verify chunk is "
@@ -1581,6 +1596,9 @@ class ContinuousBatcher:
                 if moe_h and self._counts_dsa:
                     self.dsa_stats.record_segment(moe_h.pop()[booked],
                                                   steps=block.shape[1])
+                if moe_h and self._counts_sala:
+                    self.sala_stats.record_segment(moe_h.pop()[booked],
+                                                   steps=block.shape[1])
                 if moe_h and self._counts_eva:
                     self.eva_stats.record_segment(moe_h[0][booked],
                                                   steps=block.shape[1])

@@ -1626,6 +1626,11 @@ def generate_handler(spec: dict, ctx) -> HandlerState:
                 server.model.cfg, "counts_dsa_keys", False):
             # what the sparse segments' rows selected, and from how many
             out["dsa"] = continuous.dsa_stats.report()
+        if continuous is not None and getattr(
+                server.model.cfg, "counts_sala_keys", False):
+            # what the block-sparse layers' steps attended and wrote, and
+            # the states the linear layers carried
+            out["sala"] = continuous.sala_stats.report()
         if getattr(server, "spec_metrics", None) is not None:
             # the solo `"speculative": k` path's cumulative acceptance
             # counters (the engine's batching.spec block shares this

@@ -199,6 +199,50 @@ class DsaKeyStats:
 
 
 @dataclass
+class SalaKeyStats:
+    """Counters of the decode segments of a model with block-sparse and
+    linear-attention layers (the ``handler.sala`` block on ``/metrics``),
+    only growing, from the segment programs' own masks, for the rows the
+    collector books. ``row_steps``: booked rows x segment steps.
+    ``keys_attended``: the keys a block-sparse layer's steps attended,
+    summed (a step's context while it lies inside ``sparse_dense_len``, at
+    most ``sparse_topk x sparse_block`` past it); ``keys_visible``: the
+    positions they could see; ``dense_steps``: the row-steps at or under
+    ``sparse_dense_len``; ``kc_writes``: the compressed keys written (one
+    every ``sparse_stride``-th step a row); ``state_bytes``: the recurrent
+    state the linear layers read and wrote (``step_state_bytes`` a row-step:
+    ``LlamaConfig.state_bytes_a_step``)."""
+
+    step_state_bytes: int = 0
+    row_steps: int = 0
+    keys_attended: int = 0
+    keys_visible: int = 0
+    dense_steps: int = 0
+    kc_writes: int = 0
+    _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
+
+    def record_segment(self, rows, *, steps: int) -> None:
+        """One fetched segment. ``rows``: int array [booked rows, 4], each
+        row's (keys attended, keys visible, dense steps, compressed keys
+        written) summed over the steps."""
+        with self._lock:
+            self.row_steps += len(rows) * steps
+            self.keys_attended += int(rows[:, 0].sum())
+            self.keys_visible += int(rows[:, 1].sum())
+            self.dense_steps += int(rows[:, 2].sum())
+            self.kc_writes += int(rows[:, 3].sum())
+
+    def report(self) -> dict:
+        with self._lock:
+            return {"row_steps": self.row_steps,
+                    "keys_attended": self.keys_attended,
+                    "keys_visible": self.keys_visible,
+                    "dense_steps": self.dense_steps,
+                    "kc_writes": self.kc_writes,
+                    "state_bytes": self.row_steps * self.step_state_bytes}
+
+
+@dataclass
 class EvaKeyStats:
     """Counters of an eva-attention model's decode segments (the
     ``handler.eva`` block on ``/metrics``), only growing, from the segment

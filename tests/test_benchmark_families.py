@@ -4,8 +4,8 @@ values and the manifest's rules (``benchmark/tests/test_families.py`` and
 and the ``deepseek-v3`` family that PR 27 brought: its leaf rules against
 the program's real tree at published widths, what it says a step needs, by
 hand, and its toy twin through the whole command on the CPU; the same for the
-``evabyte`` family that PR 33 brought and the ``deepseek-v32`` family that
-PR 35 brought. At the end,
+``evabyte`` family that PR 33 brought, the ``deepseek-v32`` family that
+PR 35 brought and the ``minicpm-sala`` family that PR 39 brought. At the end,
 the yardstick against the program: what every accepted configuration's
 family says a step reads and computes, against the program's own parameter
 tree, and the bounds the ledger's roofline shares stand on."""
@@ -854,7 +854,7 @@ def test_the_dsa_readers_take_the_windows_delta_or_nothing():
                  "mla_absorb_ms", "moe_load_max_share", "moe_experts_read"):
         entry = next(m for m in manifest["end_to_end"] + manifest["per_layer"]
                      if m["name"] == name)
-        assert entry["workloads"][-1] == DSA_CELL, name
+        assert DSA_CELL in entry["workloads"], name
     # moe_hbm_pct takes its experts from the live rows alone: not this cell's
     assert DSA_CELL not in next(m for m in manifest["per_layer"]
                                 if m["name"] == "moe_hbm_pct")["workloads"]
@@ -871,6 +871,297 @@ def test_a_sparse_decode_step_at_4_rows_is_bound_by_its_weight_bytes():
     assert need / V5E.hbm_bytes_s > 10 * flops / V5E.bf16_flops
     assert family.decode_step_bytes(DSV32, rows=4, context=0) > 0.9 * need
     assert 3.2e-3 < need / V5E.hbm_bytes_s < 3.7e-3
+
+
+# -- the minicpm-sala family (PR 39): an attention kind a layer ---------------
+
+SALA = json.loads((BENCH / "configs" / "minicpm-sala.json").read_text())
+SALA_TWIN_MANIFEST = BENCH / "rehearsal-sala.json"
+SALA_TWIN_CELL = "rehearsal-sala.rehearsal-closed"
+SALA_CELL = "minicpm-sala.long-document"
+_LIN, _SP = "lightning-attn", "minicpm4"
+
+# the catalog's row for MiniCPM-SALA (the model-configs guide's
+# architectures.jsonl, ``config``): every key, as published
+SALA_PUBLISHED = {
+    "attention_bias": False, "attn_use_rope": False, "head_dim": 128,
+    "hidden_act": "silu", "hidden_size": 4096, "intermediate_size": 16384,
+    "lightning_head_dim": 128, "lightning_nh": 32, "lightning_nkv": 32,
+    "lightning_scale": "1/sqrt(d)", "lightning_use_rope": True,
+    "max_position_embeddings": 524288, "model_type": "minicpm_sala",
+    "mixer_types": [_SP] + [_LIN] * 8 + [_SP] + [_LIN] * 6 + [_SP, _SP]
+    + [_LIN] * 4 + [_SP] + [_LIN] * 6 + [_SP] * 3,
+    "num_attention_heads": 32, "num_hidden_layers": 32,
+    "num_key_value_heads": 2, "qk_norm": True, "rand_init": False,
+    "rms_norm_eps": 1e-06, "vocab_size": 73448, "rope_theta": 10000,
+    "scale_emb": 12, "scale_depth": 1.4, "mup_denominator": 32,
+    "dim_model_base": 256, "tie_word_embeddings": False,
+    "use_output_gate": True, "use_output_norm": True,
+    "attn_use_output_gate": True}
+
+
+def test_minicpm_sala_is_the_published_configuration_cut_in_depth_only():
+    changed = {k for k, v in SALA_PUBLISHED.items()
+               if SALA.get(k, "absent") != v}
+    assert changed == {"num_hidden_layers", "mixer_types",
+                       "max_position_embeddings"}
+    assert SALA["reduced"] == ["num_hidden_layers", "mixer_types",
+                               "engine_window", "max_position_embeddings"]
+    assert set(SALA["reduced_why"]) == set(SALA["reduced"])
+    assert SALA["published"] == {k: SALA_PUBLISHED[k] for k in SALA["reduced"]
+                                 if k != "engine_window"}
+    # entries 9-24 of the published order: 4 + 12, the published 1 : 3, the
+    # two adjacent sparse layers among them
+    assert len(SALA_PUBLISHED["mixer_types"]) == 32
+    assert SALA["mixer_types"] == SALA_PUBLISHED["mixer_types"][9:25]
+    assert [i for i, m in enumerate(SALA["mixer_types"]) if m == _SP] == [
+        0, 7, 8, 13]
+    assert SALA["num_hidden_layers"] == 16
+    assert (SALA["engine_window"], SALA["context_served"]) == (32768, 65536)
+    assert SALA["recipe_extra"] == {"batch_cache_len": 32768, "batch_max": 8,
+                                    "max_new_tokens": 16}
+    assert SALA["sparse_config"] == {
+        "kernel_size": 32, "kernel_stride": 16, "block_size": 64, "topk": 64,
+        "init_blocks": 1, "window_size": 2048, "dense_len": 8192}
+    assert {"weights", "quantization", "sparse_config", "sparse_scores",
+            "qk_norm", "lightning_rope", "lightning_decay",
+            "lightning_output", "mup", "tokenizer"} <= set(SALA["assumed"])
+    assert SALA["cache_leaves"]["state"] == "float32"
+    manifest = json.loads((REPO / "BENCHMARK.json").read_text())
+    entry = next(c for c in manifest["configs"] if c["name"] == "minicpm-sala")
+    assert entry["reduced"] == SALA["reduced"]
+    assert entry["source"] == SALA["source"]
+    cell = next(w for w in manifest["workloads"] if w["name"] == SALA_CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "minicpm-sala", "long-document", 1)
+    traffic = json.loads((BENCH / "traffic" / "long-document.json"
+                          ).read_text())
+    assert (traffic["kind"], traffic["clients"], traffic["pool"],
+            traffic["lead_in_s"], traffic["order_seed"]) == (
+        "closed_loop", 16, 192, 30, 20261002)
+    assert traffic["prompt_len"] == {"dist": "uniform", "min": 16384,
+                                     "max": 20480}
+    assert traffic["max_tokens"]["dist"] == "uniform"
+    assert (traffic["max_tokens"]["min"], traffic["max_tokens"]["max"]) in (
+        (1536, 2048), (1664, 1920))        # ISSUE 39's one stated fallback
+    # every context is 2-2.75 x dense_len and fits the engine window
+    assert traffic["prompt_len"]["min"] >= 2 * SALA["sparse_config"][
+        "dense_len"]
+    assert traffic["prompt_len"]["max"] + traffic["max_tokens"]["max"] \
+        <= SALA["engine_window"]
+    assert traffic["clients"] == 2 * SALA["recipe_extra"]["batch_max"]
+    # the warm-up's two singles ARE the mix's two solo-prefill programs, and
+    # its one decode window the full one
+    from benchmark import warmup
+    from lambdipy_tpu.models import registry
+
+    cov = warmup.coverage(traffic, SALA)
+    assert cov["singles"] == [(16384, 16), (20480, 16)]
+    assert cov["decode_windows"] == [32768] and cov["group_buckets"] == []
+    cfg = registry.get("minicpm-sala").build(
+        extra=families.of(SALA).dims_of(SALA)).config
+    assert cfg.prompt_bucket(16384, 16) == 16384
+    assert {cfg.prompt_bucket(s, 16) for s in (16385, 18000, 20480)} == {20480}
+    assert cfg.residual_scale == pytest.approx(1.4 / 32 ** 0.5)
+    assert (cfg.embed_scale, cfg.logit_divisor) == (12.0, 16.0)
+
+
+def test_minicpm_salas_leaf_rules_name_every_path_of_the_real_tree():
+    from lambdipy_tpu.models import registry
+
+    family = families.of(SALA)
+    adapter = registry.get(SALA["model"]).build(
+        dtype="bfloat16", quant="int8", extra=family.dims_of(SALA))
+    tree = jax.eval_shape(lambda: adapter.init_params(seed=0))
+    shapes, sizes = {}, {}
+    for path, spec in jax.tree_util.tree_leaves_with_path(tree):
+        path = "/".join(str(k.key) for k in path if k.key != "params")
+        shapes[path] = spec.shape
+        sizes[path] = int(np.prod(spec.shape))
+        sliver = tuple(min(n, 2) for n in spec.shape)
+        leaf = family.leaf(1, path, sliver, spec.dtype, SALA)
+        assert leaf is not None and leaf.shape == sliver, path
+        assert leaf.dtype == np.dtype(spec.dtype), path
+
+    def int8_of(prefix):
+        return sum(n for p, n in sizes.items() if p.startswith(prefix)
+                   and p.endswith("int8"))
+
+    # ISSUE 39's arithmetic: a minicpm4 layer 253.8 M, a lightning-attn
+    # layer 285.2 M, 4 + 12 of them 4.44 G, embedding and head 300.8 M each
+    assert 253.7e6 < int8_of("layer_0/") < 253.9e6
+    assert 285.1e6 < int8_of("layer_1/") < 285.3e6
+    assert 4.43e9 < int8_of("layer_") < 4.45e9
+    assert sizes["embed/embedding"] == sizes["lm_head/kernel_int8"] \
+        == 73448 * 4096
+    assert shapes["layer_7/k_proj/kernel_int8"] == (4096, 256)
+    assert shapes["layer_1/k_proj/kernel_int8"] == (4096, 4096)
+    assert shapes["layer_8/out_gate_proj/kernel_int8"] == (4096, 4096)
+    assert shapes["layer_1/o_norm/scale"] == (128,)
+    assert "layer_0/o_norm/scale" not in shapes
+    with pytest.raises(ValueError, match="minicpm-sala.*kv_a_proj"):
+        weights.leaf(SALA, "layer_2/kv_a_proj/scale", (1, 2), "float32")
+    assert np.all(weights.leaf(SALA, "layer_2/q_norm/scale", (8,), "float32")
+                  == 1)
+    # what makes the selection matter: q_norm's gain in the minicpm4 layers,
+    # and it alone (layers 0, 7, 8, 13 here)
+    assert SALA["sparse_q_gain"] == 3.0
+    assert np.all(weights.leaf(SALA, "layer_7/q_norm/scale", (8,), "float32")
+                  == 3)
+    assert np.all(weights.leaf(SALA, "layer_7/k_norm/scale", (8,), "float32")
+                  == 1)
+    np.testing.assert_allclose(
+        weights.leaf(SALA, "layer_2/down_proj/scale", (1, 8), "float32"),
+        1 / (127 * 16384 ** 0.5), rtol=1e-6)
+    # a step reads every int8 kernel once (no rows, so no cache)
+    assert family.decode_step_bytes(SALA, rows=0, context=0) == \
+        int8_of("")
+    assert family.decode_step_flops(SALA, rows=1, context=0) == \
+        pytest.approx(2 * int8_of("") + 12 * 6 * 32 * 128 * 128, rel=1e-12)
+
+
+def test_what_a_sala_step_needs_by_hand():
+    family = families.of(SALA)
+    d = family.dims_of(SALA)
+    row, state = 2 * 2 * 128, 4 * 32 * 128 * 128
+    # ISSUE 39: a cached token is 1056 B a minicpm4 layer (K, V and a
+    # sixteenth of a compressed key), a state 2.10 MB a slot
+    assert 2 * row + row // 16 == 1056 and state == 2097152
+    assert family.visible_kc(d, 20000) == (20000 - 32) // 16 + 1
+    assert (family.attended_keys(d, 5000), family.attended_keys(d, 20000)) \
+        == (5000, 4096)
+    need = family.sala_step_bytes(SALA, rows=8, visible=20000, attended=4096)
+    assert need == 8 * (4 * row * (1249 + 2 * 4096) + 12 * 2 * state)
+    # what the two kinds NEED at 8 rows of 20k: 0.55 GB a step, three
+    # quarters of it the states; what the masked read FETCHES is 8 x 32768 x
+    # 4 x 1024 B = 1.07 GB of rows beside them (ISSUE 39)
+    assert 0.5e9 < need < 0.6e9
+    step = family.decode_step_bytes(SALA, rows=8, context=20000)
+    kernels = family.decode_step_bytes(SALA, rows=0, context=0)
+    assert step == kernels + need and 4.7e9 < kernels < 4.8e9
+    # ISSUE 39: a 20480 prefill is about 182 TFLOP, 8.9 GFLOP a token
+    pre = family.prefill_flops(SALA, rows=1, seq_len=20480)
+    assert 175e12 < pre < 200e12
+
+
+def test_each_fault_of_the_two_kinds_is_seen_and_the_reference_is_not_moved(
+        capsys, tmp_path):
+    family = families.of(SALA)
+    twin = json.loads((BENCH / "configs" / "rehearsal-sala.json").read_text())
+    ids = np.random.default_rng(5).integers(1, twin["vocab_size"], (2, 80))
+    rows, at = np.repeat(np.arange(2), 40), np.tile(np.arange(40, 80), 2)
+    alone = np.asarray(family.walk(twin, ids, rows, at, (False,))[False])
+    flags = (False, True) + family.FAULTS
+    assert family.FAULTS == ("dense_past", "no_forced", "stale_kc",
+                             "sparse_rope", "no_decay", "bf16_states")
+    streams = family.walk(twin, ids, rows, at, flags)
+    assert np.array_equal(np.asarray(streams[False]), alone)
+    moved = {flag: float(np.abs(np.asarray(streams[flag]) - alone).max())
+             for flag in flags[1:]}
+    assert all(v > 3e-3 for v in moved.values()), moved
+    # the command a limit's readings come from (PERF.md section 2)
+    path = tmp_path / "twin.json"
+    path.write_text(json.dumps(twin))
+    assert family.main(["--config", str(path), "--seeds", "3", "--length",
+                        "80", "--served", "40", "--rows", "2"]) == 0
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert set(line) == {"seed", "int4", *family.FAULTS}
+    assert all(line[k]["widest_gap"] >= 0 for k in line if k != "seed")
+
+
+def test_the_sala_twin_runs_the_whole_command_and_counts_its_keys(
+        capsys, tmp_path, monkeypatch):
+    """The whole command over the toy twin (contexts of 8-80 positions
+    against ``dense_len`` 32 and a top-4 of blocks of 8). A run whose
+    warm-up's burst did not arrive as one group says so (``still_missing``)
+    and is made again over the bundle it built, as the other twins' are."""
+    from benchmark import harness
+
+    for key in ("JAX_PLATFORMS", "XLA_FLAGS"):
+        monkeypatch.setenv(key, os.environ.get(key, ""))
+    windows = []
+    run_window = harness.run_window
+    monkeypatch.setattr(harness, "run_window", lambda *a, **kw: windows.append(
+        run_window(*a, **kw)) or windows[-1])
+    for attempt in range(5):
+        rc = run.main(["--manifest", str(SALA_TWIN_MANIFEST), "--workload",
+                       SALA_TWIN_CELL, "--seed", str(2**31 + 17 + attempt),
+                       "--seconds", "3", "--trace", "1",
+                       "--work-dir", str(tmp_path)])
+        lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+                 if ln.startswith("{")]
+        warm = next(ln for ln in lines if ln.get("stage") == "warmup")
+        if not warm["still_missing"]:
+            break
+    last = lines[-1]
+    assert rc == 0 and last["correct"] is True, lines[-3:]
+    assert last["device"]["platform"] == "cpu" and last["failed"] == 0
+    window = next(ln for ln in lines if ln.get("stage") == "window")
+    assert window["compiles_in_window"] == 0, (warm, window)
+    # prompts of 8-32 tokens and answers of 24-48: inside dense_len a step
+    # attends its context, past it 3 blocks of 8 and the open one
+    keys = last["metrics"]["sala_keys_per_query"]["value"]
+    assert 16 < keys <= 32
+    sala = windows[-1]["m_close"]["handler"]["sala"]
+    assert 0 < sala["keys_attended"] <= sala["keys_visible"]
+    assert 0 < sala["dense_steps"] < sala["row_steps"]
+    assert sala["state_bytes"] == sala["row_steps"] * 4 * 2 * 4 * 8 * 16 * 16
+    assert 0 < sala["kc_writes"] <= sala["row_steps"] // 2 + 64
+
+
+def test_the_sala_readers_take_the_windows_delta_or_nothing():
+    """The readers PR 39 added: what they read, and None where the program
+    has no such counter or scope (a llama cell; the parent)."""
+    from benchmark import harness
+
+    def metrics(**sala):
+        return {"handler": {"sala": sala}}
+
+    a = metrics(row_steps=1600, keys_attended=6_000_000,
+                keys_visible=30_000_000)
+    b = metrics(row_steps=1600 + 640, keys_attended=6_000_000 + 640 * 4070,
+                keys_visible=30_000_000 + 640 * 20000)
+    ctx = {"m_open": a, "m_close": b}
+    assert harness.layer_metric("sala_keys_per_query").read(ctx) == 4070.0
+    assert harness.layer_metric("sala_keys_per_query").means(ctx) == (
+        4070.0, 20000.0)
+    empty = {"m_open": {"handler": {}}, "m_close": {"handler": {}}}
+    reader = harness.layer_metric("sala_keys_per_query")
+    assert reader.read(empty) is None
+    assert reader.read({"m_open": a, "m_close": a}) is None
+    llama = {"family": families.load("llama-hf"), "trace": {"busy_s": 1},
+             "slice": {"live": [(8, 20000.0)]},
+             "device": {"kind": "TPU v5 lite"}, "config": {}, **empty}
+    for name in ("sala_select_ms", "lin_state_ms", "sala_cache_hbm_pct"):
+        assert harness.layer_metric(name).read(llama) is None
+    # no trace: no share, whatever the counters say
+    assert harness.layer_metric("sala_cache_hbm_pct").read(
+        {"family": families.of(SALA), "config": SALA, "trace": None,
+         "slice": {"live": [(8, 20000.0)]}, "device": {"kind": "TPU v5 lite"},
+         **ctx}) is None
+    manifest = json.loads((REPO / "BENCHMARK.json").read_text())
+    for name in ("sala_select_ms", "lin_state_ms", "sala_keys_per_query",
+                 "sala_cache_hbm_pct"):
+        entry = next(m for m in manifest["per_layer"] if m["name"] == name)
+        assert entry["workloads"] == [SALA_CELL]
+    for name in ("out_tok_s", "decode_hbm_pct", "hbm_peak_gb",
+                 "engine_host_ms", "decode_step_ms", "decode_matmul_ms",
+                 "decode_attend_ms", "decode_sample_ms"):
+        entry = next(m for m in manifest["end_to_end"] + manifest["per_layer"]
+                     if m["name"] == name)
+        assert entry["workloads"][-1] == SALA_CELL, name
+
+
+def test_a_sala_decode_step_at_8_rows_is_bound_by_its_bytes():
+    """The cell's premise: at 8 rows of 20k the bytes take some ten times
+    longer than the operations, nine tenths of the bytes are weights, and
+    what the two kinds NEED is a tenth."""
+    family = families.of(SALA)
+    need = family.decode_step_bytes(SALA, rows=8, context=20000)
+    flops = family.decode_step_flops(SALA, rows=8, context=20000)
+    assert need / V5E.hbm_bytes_s > 8 * flops / V5E.bf16_flops
+    assert family.decode_step_bytes(SALA, rows=0, context=0) > 0.88 * need
+    assert 6.0e-3 < need / V5E.hbm_bytes_s < 6.8e-3
 
 
 # -- the yardstick against the program ---------------------------------------

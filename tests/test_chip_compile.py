@@ -586,3 +586,116 @@ def test_a_sparse_prefill_keeps_a_turns_scores_in_the_fast_memory(v5e,
     for op_name in re.findall(r'op_name="([^"]*)"', text):
         found.update(op_name.split("/"))
     assert {"dsa_index", "dsa_select", "attend", "qkv_proj"} <= found
+
+
+# minicpm-sala's widths (benchmark/configs/minicpm-sala.json): 8 slots of
+# 32768, published layers 9-24: 4 block-sparse among 12 linear
+MINICPM_SALA = dict(
+    vocab_size=73448, hidden=4096, heads=32, kv_heads=2, mlp=16384,
+    rope_theta=1e4, norm_eps=1e-6, max_len=65536, qk_norm=True,
+    attn_output_gate=True, lin_heads=32, lin_head_dim=128, embed_scale=12.0,
+    residual_scale=1.4 / 32 ** 0.5, logit_divisor=16.0)
+SALA_KINDS = ("sparse_kv",) + ("linear",) * 6 + ("sparse_kv",) * 2 \
+    + ("linear",) * 4 + ("sparse_kv",) + ("linear",) * 2
+
+
+def test_a_sala_segment_compiles_and_what_it_does_with_its_leaves(v5e):
+    """The engine's segment at ``minicpm-sala.long-document``'s own shape key
+    (8 slots of 32768, the full window, 16 layers of kinds a layer, 16
+    steps), lowered as a TPU backend lowers it. ~40 s.
+
+    It compiles for the chip, and every scope ``benchmark/families/
+    minicpm_sala.py`` gathers a trace's operations by is the op_name of some
+    operation (no ``kv_window``: the cell decodes in the full window; no
+    ``lin_scan``: the prefill's). No sort lies under ``sala_select``: the
+    blocks are picked by the exact threshold, as a mask.
+
+    What is recorded of the compiler, not asked of it (ISSUE 39: "whether a
+    linear state write inside the scan costs a copy is read from the
+    compiled text"). A block-sparse layer's rows are written IN PLACE: the
+    only operations of the loop with a ``k`` / ``v`` leaf's whole shape are
+    the two scatter fusions a layer under ``kv_write``, and one under
+    ``sala_compress`` with the compressed keys' shape, each aliasing its
+    operand; nothing copies a K/V leaf home. A linear layer's state (8 x
+    2.1 MB a layer) is a carry that is rewritten whole every step by
+    nature: the compiler moves it between HBM and the fast memory
+    (``copy-done``), at most three such moves a layer a step, which is the
+    read and the write the recurrence needs and no more than a third of a
+    step's 0.4 GB on top."""
+    from benchmark.families import minicpm_sala
+    from lambdipy_tpu.models import llama
+
+    layers, slots, window = 16, 8, 32768
+    text = _decode_segment_text(v5e, steps=16, layers=layers, window=window,
+                                cache_len=window, rows=slots,
+                                layer_kinds=SALA_KINDS, **MINICPM_SALA)
+    found = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        found.update(op_name.split("/"))
+    assert set(minicpm_sala.SCOPES) - {"kv_window", "lin_scan"} <= found
+    sorts = re.findall(r' sort\([^\n]*op_name="([^"]*)"', text)
+    assert not any("sala_select" in name for name in sorts)
+    rows = cache_writes_in_loops(text, {(slots, window, 2, 128)})
+    assert sorted(op for _, op, _ in rows) == ["fusion"] * 8
+    assert all(name.endswith("kv_write/scatter") for _, _, name in rows)
+    # (the compressed keys, 1 MB a leaf, also travel to the fast memory and
+    # back: a copy-done a layer)
+    compressed = [w for w in cache_writes_in_loops(
+        text, {(slots, window // 16, 2, 128)}) if w[1] != "copy-done"]
+    assert [(op, name.split("/")[-2]) for _, op, name in compressed] == [
+        ("fusion", "sala_compress")] * 4
+    for name, _, _ in rows + compressed:
+        line = next(ln for ln in text.splitlines()
+                    if f"%{name} = " in ln)
+        assert '"aliasing_operands"' in line, name
+    states = cache_writes_in_loops(text, {(slots, 1, 32, 128 * 128)})
+    moves = [op for _, op, _ in states if op == "copy-done"]
+    assert {op for _, op, _ in states} <= {"copy-done", "reshape"}
+    assert len(moves) <= 3 * 12
+    assert not llama.segment_keeps_tail(llama.LlamaConfig(
+        layers=layers, layer_kinds=SALA_KINDS, **MINICPM_SALA))
+
+
+def test_neither_new_prefill_builds_a_heads_s_s_score(v5e):
+    """The solo prefill of the cell's 20480 bucket (five key blocks of
+    4096), one block-sparse and one linear layer. ~40 s.
+
+    No operation of the program produces a ``[heads, s, s]`` tensor of any
+    type: the largest float32 score the block-sparse layer's loop hands on
+    is 2 heads' of one block of 128 queries against the 20480 keys (21 MB:
+    ``llama.DSA_SCORE_BYTES``), beside the 16 heads of a KV group against
+    the 1280 compressed keys; the linear layer's is a chunk's ``[32, 256,
+    256]``. (The one float32 array with two long axes is the SwiGLU's
+    ``[20480, 16384]``.)"""
+    from lambdipy_tpu.models import llama, sparse_kv
+
+    cfg = llama.LlamaConfig(layers=2, dtype=jnp.bfloat16, quant="int8",
+                            layer_kinds=("sparse_kv", "linear"),
+                            **MINICPM_SALA)
+    model = llama.LlamaModel(cfg)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e),
+            tree)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                                    jnp.zeros((1, 8), jnp.int32)))
+    server = llama.LlamaServer(model, None)
+    key = ("stream", 1, cfg.prompt_bucket(17000, 16), 32768, 16)
+    assert key[2] == 20480 and sparse_kv.SPARSE_QUERY_BLOCK == 128
+    assert llama._head_group(16, 128 * 20480) == 2
+    operands = on_chip(jax.eval_shape(lambda: server._aot_examples(key))[0])
+    text = server._stream_fns(*key[1:])[0].lower(
+        params, *operands).compile().as_text()
+    shapes = {tuple(int(n) for n in dims.split(","))
+              for dims in re.findall(r"\w+\[([\d,]+)\]", text)}
+    long = [s for s in shapes if sum(n >= 4096 for n in s) >= 2]
+    assert all(set(s) - {1} <= {20480, 16384, 4096, 73448} and len(
+        [n for n in s if n > 1]) == 2 for s in long), long
+    assert (1, 1, 2, 128, 20480) in shapes and (1, 2, 16, 128, 1280) in shapes
+    found = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        found.update(op_name.split("/"))
+    assert {"sala_compress", "sala_select", "attend", "lin_scan",
+            "qkv_proj"} <= found

@@ -51,7 +51,13 @@ backend the toy twin's programs contain no kernel call at any call size.
 Since PR 35, which put query compression, sparse attention, grouped routing
 and a chip's share of the experts into the same latent and routed paths,
 ``kanana2-30b``'s four programs are held as THIS backend lowers them, at the
-cell's own shape key, like the two llama cells'."""
+cell's own shape key, like the two llama cells'.
+
+PR 39 (an attention kind a LAYER: ``LlamaConfig.layer_kinds``, the
+constructors and the window buckets read a layer's own leaves, three muP
+scalars in block and model) moved NONE of them either: a model without
+``layer_kinds`` takes every path it took, and the scalars are python
+comparisons against 1.0 that add nothing to a program."""
 
 import hashlib
 import json
