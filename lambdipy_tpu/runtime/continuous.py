@@ -41,6 +41,35 @@ Design (all device work rides LlamaServer's compiled-program cache):
   in-flight segments) so packing sees host-truth slots and a
   host-materialized carry. The engine exits when idle and restarts on
   the next request.
+- SLOT HANDOVER at a row's known end. A BATCH slot is a row of the
+  device carry; a RUN slot is the admission layer's (``sched``: at most
+  ``max_concurrency`` requests past the HTTP gate, floored at the
+  batcher's width), so with every batch slot taken the next request
+  waits in front of the gate, not in ``_joiners``, until a response has
+  been written. Two things keep a freed slot from decoding garbage
+  while that happens, both read off what the host knows at dispatch
+  time (``disp``, ``n``, no verify step of the row in flight: ``disp``
+  is exact then). (a) The dispatch that leaves a row at most one
+  segment of quota calls ``row_ending_fn`` once (the server sets it to
+  ``Scheduler.grant_ahead``: one queued ticket is granted on a credit
+  the next ``finish()`` repays), so the next request is a joiner by the
+  time the row's last segment is dispatched. (b) With a joiner waiting
+  and every slot taken, a live row whose whole output is dispatched
+  counts like a finished one: the loop drains (cause ``handover``)
+  instead of dispatching a segment that would step it as a garbage
+  row; the collector books its last block, the barrier frees the slot,
+  the joiner packs. That is what the depth-1 loop does anyway, so
+  tokens are unchanged; a row that ends at an eos is seen at collect
+  only and keeps the over-decode path. Both halves hold for requests
+  the BARRIER prefills (prompts up to ``group_prefill_max``: the grant
+  ahead passes that bound and a longer ticket waits for the release
+  itself; the drain wants a joiner without a carry of its own). A long
+  prompt prefills on its request thread the moment it is granted:
+  ahead of a release that prefill would run in front of the ending
+  row's last segments, and a slot handed to a prefilled joiner a
+  segment sooner puts the NEXT client's prefill behind that joiner's
+  first token instead of in front of it: no token later, but a whole
+  prefill inside somebody's TPOT (measured: PERF.md section 6, PR 40).
 - A row's FIRST token does not wait for a segment. The prefill selects
   it and the decode scan re-emits the token it consumes, so column 0 of
   a row's first block is the token the pack wrote into the batch
@@ -420,6 +449,14 @@ class ContinuousBatcher:
         # in POLICY order (priority / fair-share by request class from
         # the scheduler's context) instead of arrival order; None = FIFO
         self.policy = policy
+        # slot handover, the admission layer's half: called (from the
+        # engine thread, outside its lock, with group_prefill_max: the
+        # longest prompt the barrier prefills itself) once for each row
+        # when the dispatch that leaves it at most one segment of quota
+        # is made, i.e. a run slot is about to be released. The HTTP
+        # server sets it to Scheduler.grant_ahead; None = nobody gates
+        # admission in front of this engine
+        self.row_ending_fn = None
         self.cache_len = min(cache_len or cfg.max_len, cfg.max_len)
         # prompts up to this length enqueue RAW and the engine prefills
         # them together in one ragged b-row call (prefill MFU at short
@@ -1954,6 +1991,23 @@ class ContinuousBatcher:
                             # segments) reaches the packing barrier
                             cause = "joiner"
                             break
+                        if any(j.get("carry") is None
+                               for j in self._joiners) and any(
+                                e["disp"] >= e["n"]
+                                and not e["spec_inflight"]
+                                for _, e in live):
+                            # slot handover (module docstring): a
+                            # row's whole output is dispatched (disp is
+                            # exact: no verify step of it in flight) and
+                            # a joiner that the barrier itself prefills
+                            # waits for its slot, so the next segment
+                            # would only step the row as garbage: drain
+                            # instead. A row that ends at an eos is seen
+                            # at collect only and takes the branch
+                            # above, as does everything while only
+                            # joiners with a carry of their own wait.
+                            cause = "handover"
+                            break
                         # per-dispatch verify width: the pow-2 bucket of
                         # the live rows' ADAPTIVE k (legacy lookup mode
                         # pins every row at spec_k, reproducing the
@@ -2001,6 +2055,7 @@ class ContinuousBatcher:
                         use_model = False
                         assumed: dict = {}
                         to_draft: list = []
+                        ending = 0  # rows this dispatch leaves <= 1 segment
                         for slot, e in live:
                             if e["done"]:
                                 # finished mid-pipeline: still stepped
@@ -2040,6 +2095,24 @@ class ContinuousBatcher:
                                      min(int(e["k_row"]), kb)))
                                 e["spec_inflight"] += 1
                             e["disp"] += adv
+                            if not kb and not e["ending"] \
+                                    and not e["spec_inflight"] \
+                                    and e["n"] - e["disp"] <= self.segment:
+                                # the row ends with the NEXT segment at
+                                # the latest (exact: a plain dispatch,
+                                # no verify step of the row in flight)
+                                e["ending"] = True
+                                ending += 1
+                    # a run slot is about to be released for each such
+                    # row: the admission layer may send its next request
+                    # now, so that it waits in _joiners when the row's
+                    # last block is dispatched (the handover drain above)
+                    if ending and self.row_ending_fn is not None:
+                        try:
+                            for _ in range(ending):
+                                self.row_ending_fn(self.group_prefill_max)
+                        except Exception:  # noqa: BLE001 — a hint only
+                            log.exception("row_ending_fn failed")
                     # host-side drafting OUTSIDE the lock: the n-gram
                     # scan is O(context) per row, and admit/stream
                     # waiters must not queue behind it
@@ -2268,6 +2341,9 @@ class ContinuousBatcher:
                  # prompt row/prefix persist so a replayed entry can
                  # re-prefill from its admitted state
                  "replays": 0, "streamed": False, "abandoned": False,
+                 # set once, when the dispatch that leaves the row at most
+                 # one segment of quota has told row_ending_fn
+                 "ending": False,
                  # speculative draft state: the last FETCHED pending
                  # token (None = the device knows it, the host has not
                  # collected one yet) and the count of

@@ -48,6 +48,11 @@ class HandlerState:
     # server admission 503s instead of queueing requests into a dead
     # engine. Same cost contract as warming_fn: bare attribute reads.
     engine_fault_fn: Callable[[], dict] | None = None
+    # optional hook of the continuous engine's slot handover: called with
+    # the scheduler's grant_ahead, which the engine then calls (with the
+    # longest prompt its barrier prefills) whenever a row is about to
+    # release its run slot
+    row_ending_hook: Callable[[Callable[[int], Any]], None] | None = None
     # optional disaggregated-serving KV ship surface (runtime/kvwire.py
     # framing over the prefix store): kv_export_fn serves a request's
     # whole-block head as a wire frame (prefilling missing blocks — on
@@ -1675,6 +1680,8 @@ def generate_handler(spec: dict, ctx) -> HandlerState:
         # once-per-probe-interval health check may cost
         warming_fn=lambda: bool(warm_state["in_flight"]),
         engine_fault_fn=(continuous.fault_state
+                         if continuous is not None else None),
+        row_ending_hook=((lambda fn: setattr(continuous, "row_ending_fn", fn))
                          if continuous is not None else None),
         kv_export_fn=kv_export,
         kv_import_fn=kv_import,

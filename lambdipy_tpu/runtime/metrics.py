@@ -364,8 +364,11 @@ class PipelineStats:
     histograms the pipeline depth at each dispatch (how many segments
     were queued on the device, this one included); ``drains`` counts the
     barrier causes (``joiner`` = a pending joiner forced a bounded drain
-    so packing sees host-truth slots, ``complete`` = every live row
-    reached its dispatch quota). ``wasted_overdecode_tokens`` are tokens
+    so packing sees host-truth slots, ``handover`` = a live row's whole
+    output was dispatched while a joiner waited for its slot, so the
+    drain came before the segment that would have stepped it as a
+    garbage row, ``complete`` = every live row reached its dispatch
+    quota). ``wasted_overdecode_tokens`` are tokens
     fetched for rows that had already finished (EOS observed behind the
     dispatch frontier) and were discarded host-side. ``overlap_ratio`` =
     device-busy / wall: device-busy is the union of each segment's

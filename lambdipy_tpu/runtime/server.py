@@ -221,6 +221,16 @@ class BundleServer:
             cfg.max_concurrency = max(cfg.max_concurrency,
                                       int(extra.get("batch_max", 8)))
         self.sched = Scheduler(cfg)
+        # slot handover: a continuous engine tells the scheduler when a
+        # row's last segment is next, and the scheduler grants one queued
+        # ticket ahead of that run slot's release (Scheduler.grant_ahead),
+        # so the next request is at the engine when the batch slot frees.
+        # This, not a higher floor above, is what keeps batch slots full
+        # under a queue: a standing lookahead would hold long prompts'
+        # prefilled carries on the device through every burst
+        hook = getattr(self.boot.state, "row_ending_hook", None)
+        if hook is not None:
+            hook(self.sched.grant_ahead)
         self._httpd = ThreadingHTTPServer((host, port), self._make_handler())
         self.port = self._httpd.server_address[1]
         self._thread: threading.Thread | None = None

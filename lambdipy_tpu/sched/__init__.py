@@ -122,6 +122,10 @@ class Scheduler:
     then :meth:`wait_turn` (parks in its class lane until the policy
     grants it one of ``max_concurrency`` run slots); :meth:`finish`
     releases the slot, wakes the next grant, and feeds the estimator.
+    :meth:`grant_ahead` grants one queued ticket against a run slot that
+    is ABOUT to be released (the continuous engine calls it when a row's
+    last segment is next): the request is at the engine when the batch
+    slot frees instead of a response and a wake-up later.
     """
 
     def __init__(self, config: SchedConfig | None = None):
@@ -140,6 +144,10 @@ class Scheduler:
             rate=self.config.rate, burst=self.config.burst)
         self._cond = threading.Condition()
         self._running = 0
+        # grants made ahead of a release (grant_ahead) that no finish()
+        # has repaid yet: the gate stands that far over max_concurrency
+        self._ahead = 0
+        self.granted_ahead = 0
         self.draining = False
         # observability: per-class queue-wait reservoirs + counters
         self.wait_stats = {c: LatencyStats(capacity=512) for c in CLASSES}
@@ -178,10 +186,22 @@ class Scheduler:
 
     # -- slot handoff ---------------------------------------------------------
 
-    def _pump_locked(self) -> None:
-        while self._running < self.config.max_concurrency:
+    def _pump_locked(self, credit: int = 0,
+                     max_prefill_tokens: int | None = None) -> None:
+        """Grant queued tickets, in the policy's order, while a run slot
+        is free. ``credit`` counts run slots about to be released on top
+        of the grants ahead still owed, and ``max_prefill_tokens`` bounds
+        the prompt of a ticket granted that way (:meth:`grant_ahead`)."""
+        while self._running < (self.config.max_concurrency + self._ahead
+                               + credit):
             ticket = self.queue.pop(self.policy)
             if ticket is None:
+                return
+            if max_prefill_tokens is not None \
+                    and ticket.prefill_tokens > max_prefill_tokens:
+                # the policy's next is not to be granted ahead: it keeps
+                # its place and waits for the release itself
+                self.queue.unpop(ticket)
                 return
             now = time.monotonic()
             wait_ms = (now - ticket.enqueued) * 1e3
@@ -223,9 +243,34 @@ class Scheduler:
                 self._cond.wait(timeout=remaining)
             return not ticket.expired
 
+    def grant_ahead(self, max_prefill_tokens: int | None = None) -> bool:
+        """A run slot is about to be released: grant ONE queued ticket
+        now, on credit. The next :meth:`finish` repays the credit (it
+        frees the slot the grant was made against), so ``running`` never
+        exceeds ``max_concurrency`` + the releases announced and not yet
+        made. The policy's order and the deadline re-check at grant time
+        hold as for any grant; with a free run slot or an empty queue
+        nothing is granted and no credit is kept. ``max_prefill_tokens``
+        (the continuous engine passes the longest prompt it prefills
+        itself, at the barrier): a ticket whose prompt is longer is NOT
+        granted ahead and nobody overtakes it. Such a request prefills on
+        its own thread the moment it is granted, and ahead of a release
+        that prefill would queue on the device in front of the ending
+        row's last segments. Returns whether a ticket was granted."""
+        with self._cond:
+            self._pump_locked(credit=1,
+                              max_prefill_tokens=max_prefill_tokens)
+            if self._running <= self.config.max_concurrency + self._ahead:
+                return False
+            self._ahead += 1
+            self.granted_ahead += 1
+            return True
+
     def finish(self, ticket: Ticket, *, service_ms: float | None = None) -> None:
         with self._cond:
             self._running -= 1
+            if self._ahead:
+                self._ahead -= 1  # a grant ahead is repaid by this release
             self.completed += 1
             if service_ms is not None:
                 self.estimator.observe(service_ms, ticket.prefill_tokens,
@@ -249,6 +294,7 @@ class Scheduler:
             running = self._running
             depths = self.queue.snapshot()
             admitted, completed = self.admitted, self.completed
+            granted_ahead = self.granted_ahead
         waits = {}
         for c in CLASSES:
             rep = self.wait_stats[c].report()
@@ -264,6 +310,7 @@ class Scheduler:
             "queued": depths,
             "admitted": admitted,
             "completed": completed,
+            "granted_ahead": granted_ahead,
             "shed": self.admission.shed_report(),
             "queue_wait": waits,
             "estimator": self.estimator.report(),

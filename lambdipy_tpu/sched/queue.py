@@ -69,6 +69,11 @@ class RequestQueue:
         cls = policy.select(nonempty)
         return self._lanes[cls].popleft()
 
+    def unpop(self, ticket: Ticket) -> None:
+        """Put a ticket just popped back at the head of its lane (a grant
+        that was not made after all): it is next in its class again."""
+        self._lanes[ticket.cls].appendleft(ticket)
+
     def remove(self, ticket: Ticket) -> bool:
         """Withdraw a parked ticket (wait timeout / client gone)."""
         try:

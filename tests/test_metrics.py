@@ -5,6 +5,8 @@ decode-window (length-aware decode) counter block."""
 
 import threading
 
+import pytest
+
 from lambdipy_tpu.runtime.metrics import (DecodeWindowStats, LatencyStats,
                                           PipelineStats, PrefixCacheStats)
 
@@ -215,6 +217,42 @@ def test_pipeline_stats_counters_and_overlap_union():
     assert rep["fetch_block_s"] == 0.5
     assert rep["wall_s"] == 4.0
     assert rep["overlap_ratio"] == 0.5
+
+
+@pytest.mark.parametrize("cause", ["joiner", "handover", "complete"])
+def test_pipeline_stats_reports_each_drain_cause(cause):
+    """``drains`` is keyed by the barrier's cause, each counted on its
+    own: ``handover`` (a row's whole output dispatched with a joiner
+    waiting for its slot) beside ``joiner`` and ``complete``."""
+    st = PipelineStats(depth=2)
+    for other in ("joiner", "handover", "complete"):
+        st.record_drain(other)
+    st.record_drain(cause)
+    want = {"joiner": 1, "handover": 1, "complete": 1}
+    want[cause] = 2
+    assert st.report()["drains"] == want
+
+
+def test_scheduler_report_counts_grants_ahead():
+    """``/metrics`` ``sched.granted_ahead``: tickets granted against a run
+    slot about to be released; 0 in the report until one is, and a call
+    that finds nobody queued is not counted."""
+    from lambdipy_tpu.sched import SchedConfig, Scheduler
+
+    sched = Scheduler(SchedConfig(max_concurrency=1))
+    assert sched.report()["granted_ahead"] == 0
+    holder = sched.admit()
+    assert sched.grant_ahead() is False
+    assert sched.report()["granted_ahead"] == 0
+    queued = sched.admit()
+    assert sched.grant_ahead() is True
+    rep = sched.report()
+    assert rep["granted_ahead"] == 1 and rep["running"] == 2
+    assert rep["max_concurrency"] == 1
+    for t in (holder, queued):
+        sched.finish(t, service_ms=1.0)
+    rep = sched.report()
+    assert rep["granted_ahead"] == 1 and rep["running"] == 0
 
 
 def test_pipeline_stats_disjoint_intervals_sum():

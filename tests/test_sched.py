@@ -234,6 +234,172 @@ def test_deadline_shed_at_grant_time():
     assert sched.report()["shed"]["by_reason"]["deadline"] == 1
 
 
+def _parked(sched, n, **kw):
+    """``n`` tickets admitted behind a full gate, in admission order."""
+    out = [sched.admit(**kw) for _ in range(n)]
+    assert not any(isinstance(t, Shed) or t.granted for t in out)
+    return out
+
+
+def test_grant_ahead_is_one_ticket_on_credit_repaid_by_finish():
+    """A grant ahead raises ``running`` by ONE over the gate; the next
+    finish() repays it (no further grant), the one after grants as ever.
+    With a free run slot or an empty queue it grants nothing and keeps
+    no credit."""
+    sched = Scheduler(SchedConfig(policy="fifo", max_concurrency=2))
+    assert sched.grant_ahead() is False          # empty queue
+    held = [sched.admit() for _ in range(2)]
+    assert all(t.granted for t in held)
+    assert sched.grant_ahead() is False          # gate full, nobody queued
+    a, b, c = _parked(sched, 3)
+    assert sched.grant_ahead() is True
+    assert a.granted and not b.granted
+    rep = sched.report()
+    assert rep["running"] == 3 and rep["granted_ahead"] == 1
+    assert rep["queued"]["interactive"] == 2
+    # an arrival behind a gate that stands one over is still parked
+    d = _parked(sched, 1)[0]
+    sched.finish(held[0], service_ms=1.0)        # repays: no new grant
+    assert sched.report()["running"] == 2 and not b.granted
+    sched.finish(held[1], service_ms=1.0)        # an ordinary release
+    assert b.granted and not c.granted
+    assert sched.report()["running"] == 2
+    for t in (a, b):
+        sched.finish(t, service_ms=1.0)
+    assert c.granted and d.granted
+    rep = sched.report()
+    assert rep["running"] == 2 and rep["granted_ahead"] == 1
+
+
+@pytest.mark.parametrize("ending", [1, 2, 3])
+def test_grant_ahead_bounded_by_rows_ending(ending):
+    """``running`` never exceeds max_concurrency + the releases announced
+    and not yet made, however deep the queue; the credits are repaid one
+    a finish()."""
+    sched = Scheduler(SchedConfig(policy="fifo", max_concurrency=2))
+    held = [sched.admit() for _ in range(2)]
+    parked = _parked(sched, 6)
+    for i in range(ending):
+        assert sched.grant_ahead() is True
+        assert sched.report()["running"] == 2 + i + 1
+    assert [t.granted for t in parked] == [True] * ending \
+        + [False] * (6 - ending)
+    for i, t in enumerate(held + parked[:ending]):
+        sched.finish(t, service_ms=1.0)
+        # the first `ending` releases repay, the later ones grant anew
+        assert sched.report()["running"] == max(2, 2 + ending - i - 1)
+    assert sched.report()["granted_ahead"] == ending
+
+
+def test_grant_ahead_follows_policy_and_deadline_recheck():
+    """The ticket granted ahead is the policy's next (not the oldest),
+    and one whose deadline became unmeetable while queued is shed at
+    that moment like at any grant: the credit goes to the next."""
+    sched = Scheduler(SchedConfig(policy="priority", max_concurrency=1))
+    sched.estimator.observe(50.0)
+    holder = sched.admit()
+    late = sched.admit(cls="interactive", deadline_ms=120.0)
+    assert not isinstance(late, Shed)            # feasible when admitted
+    bg = sched.admit(cls="background")
+    ia = sched.admit(cls="interactive")
+    time.sleep(0.15)                             # late can no longer make it
+    assert sched.grant_ahead() is True
+    assert late.expired and sched.wait_turn(late, timeout=2) is False
+    assert ia.granted and not ia.expired and not bg.granted
+    rep = sched.report()
+    assert rep["running"] == 2 and rep["granted_ahead"] == 1
+    assert rep["shed"]["by_reason"]["deadline"] == 1
+    sched.finish(holder, service_ms=1.0)
+    assert not bg.granted
+    sched.finish(ia, service_ms=1.0)
+    assert bg.granted
+
+
+def test_grant_ahead_leaves_a_long_prompt_in_its_place():
+    """With ``max_prefill_tokens`` (the engine's group limit) a ticket
+    whose prompt is longer is not granted ahead, and nobody overtakes
+    it: it is the first grant of the release itself. Shorter ones are
+    granted as ever."""
+    sched = Scheduler(SchedConfig(policy="fifo", max_concurrency=1))
+    holder = sched.admit(prefill_tokens=10)
+    long_one = sched.admit(prefill_tokens=300)
+    short = sched.admit(prefill_tokens=20)
+    assert sched.grant_ahead(max_prefill_tokens=256) is False
+    assert not long_one.granted and not short.granted
+    rep = sched.report()
+    assert rep["running"] == 1 and rep["granted_ahead"] == 0
+    assert rep["queued"]["interactive"] == 2
+    sched.finish(holder, service_ms=1.0)
+    assert long_one.granted and not short.granted  # its place was kept
+    assert sched.grant_ahead(max_prefill_tokens=256) is True
+    assert short.granted and sched.report()["running"] == 2
+    assert sched.grant_ahead() is False             # nobody queued
+
+
+def test_grant_ahead_under_contention_keeps_the_gate():
+    """More request threads than cores admit, wait and finish while an
+    'engine' thread announces releases as fast as it can: the gate never
+    stands further over max_concurrency than the credits owed, every
+    credit is repaid, and nobody is left parked."""
+    import sys
+
+    sched = Scheduler(SchedConfig(policy="fifo", max_concurrency=3,
+                                  queue_cap=100_000))
+    stop, over, granted = threading.Event(), [], [0]
+
+    def requests():
+        for _ in range(40):
+            ticket = sched.admit()
+            assert sched.wait_turn(ticket, timeout=20)
+            with sched._cond:
+                if sched._running > 3 + sched._ahead:
+                    over.append((sched._running, sched._ahead))
+            time.sleep(0.0005)  # hold the run slot: a queue stands behind
+            sched.finish(ticket, service_ms=0.5)
+
+    def engine():
+        while not stop.is_set():
+            granted[0] += sched.grant_ahead()
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        eng = threading.Thread(target=engine)
+        eng.start()
+        workers = [threading.Thread(target=requests) for _ in range(24)]
+        for t in workers:
+            t.start()
+        for t in workers:
+            t.join(timeout=60)
+        stop.set()
+        eng.join(timeout=10)
+    finally:
+        sys.setswitchinterval(old)
+    assert not eng.is_alive() and not any(t.is_alive() for t in workers)
+    assert over == []
+    rep = sched.report()
+    assert rep["admitted"] == rep["completed"] == 24 * 40
+    assert rep["running"] == 0 and sched._ahead == 0
+    assert rep["granted_ahead"] == granted[0] > 0
+
+
+def test_server_hands_grant_ahead_to_the_engine(stub_server, monkeypatch):
+    """Where the scheduler is built the server gives the handler's engine
+    its grant_ahead (HandlerState.row_ending_hook); a handler without an
+    engine has no hook and nothing happens."""
+    got = []
+    real = _stub_boot
+
+    def boot(d, **kw):
+        rep = real(d, **kw)
+        rep.state.row_ending_hook = got.append
+        return rep
+
+    monkeypatch.setitem(globals(), "_stub_boot", boot)
+    srv = stub_server()
+    assert got == [srv.sched.grant_ahead]
+
+
 def test_degenerate_config_is_floored():
     """queue_cap=0 / max_concurrency=0 must not turn into a total outage
     (0 >= 0 would shed every request on an idle server)."""

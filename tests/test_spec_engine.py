@@ -138,6 +138,38 @@ def test_spec_engine_pipeline_depth2(tiny_server):
                     seed=5), solo_s)
 
 
+def test_spec_engine_keeps_todays_drains(tiny_server):
+    """The slot handover needs a row's EXACT dispatched count, and a
+    verify step books an upper bound the collector refunds: a row of a
+    speculative engine (lookup mode: every dispatch a verify step) is
+    never announced as ending and never handed over ahead of its
+    collect. With a joiner waiting for a slot from the start the drains
+    are today's causes and the outputs bitwise solo's."""
+    from tests.test_continuous_pipeline import drained, hold_first_prefill
+
+    # two lengths: the short row's booked count passes its n (with its
+    # verify steps still in flight) while the long one keeps the engine
+    # dispatching, which is where a handover would come too early
+    reqs = [([5, 6, 7, 8], 16), ([2, 4, 6], 32), ([9, 8, 7], 16)]
+    solo = [tiny_server.generate(p, max_new_tokens=n) for p, n in reqs]
+    cb = _mk(tiny_server, pipeline_depth=2)
+    told = []
+    cb.row_ending_fn = lambda limit: told.append(1)
+    # the third request is enqueued before the first two decode
+    hold_first_prefill(cb, lambda: sum(
+        e is not None for e in cb._active) + len(cb._joiners) == 3)
+    with ThreadPoolExecutor(max_workers=3) as ex:
+        outs = list(ex.map(
+            lambda r: cb.generate(r[0], max_new_tokens=r[1]), reqs))
+    for o, r in zip(outs, solo):
+        np.testing.assert_array_equal(o, r)
+    pipe = drained(cb)
+    assert told == []
+    assert pipe["drains"] and set(pipe["drains"]) <= {"joiner", "complete"}, \
+        pipe
+    assert cb.stats()["spec"]["steps"] > 0
+
+
 def test_spec_engine_prefix_rows_join(tiny_server):
     """A prefix= row joins the speculative engine from its cached KV;
     the prefix tokens feed the drafts and output parity holds."""
