@@ -32,7 +32,7 @@ log = get_logger("lambdipy.llama")
 
 
 class LayerSpec(NamedTuple):
-    attn: str  # "kv" | "latent" | "eva" | "sparse_kv" | "linear"
+    attn: str  # "kv" | "latent" | "eva" | "sparse_kv" | "linear" | "kda"
     ffn: str   # "dense" | "capacity" | "routed"
 
 
@@ -172,15 +172,20 @@ class LlamaConfig:
     # grouped-query K/V whose attended blocks are chosen by content from a
     # second leaf of compressed keys: ``sparse_*``) and "linear"
     # (models/linear_attn.py: a recurrent state a slot and no row a token:
-    # ``lin_*``). A layer's cache entry, its prefill, its step and what
-    # refuses it are its kind's; ``cache_layout`` / ``cache_positions`` /
-    # ``cache_slot`` take the layer. The latent and eva kinds stay one a
-    # model (``attn_kind``).
+    # ``lin_*``) and "kda" (models/kda.py: a gated delta-rule state and the
+    # tail of a short convolution a slot: ``kda_*``), and "latent" (the
+    # block's own, above, without ``index_topk``). A layer's cache entry,
+    # its prefill, its step and what refuses it are its kind's;
+    # ``cache_layout`` / ``cache_positions`` / ``cache_slot`` take the
+    # layer. The eva kind stays one a model (``attn_kind``).
     layer_kinds: tuple = ()
     # RMSNorm with a learned gain over each head's query and key
     qk_norm: bool = False
-    # the heads' outputs x sigmoid(out_gate_proj h) before o_proj
+    # the heads' outputs x sigmoid(out_gate_proj h) before o_proj: one gate
+    # a channel, or (``attn_gate_headwise``: the kda and latent layers) one
+    # a head
     attn_output_gate: bool = False
+    attn_gate_headwise: bool = False
     sparse_kernel: int = 32
     sparse_stride: int = 16
     sparse_block: int = 64
@@ -192,6 +197,10 @@ class LlamaConfig:
     lin_head_dim: int = 0
     lin_rope: bool = True
     lin_output_norm: bool = True
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_lower_bound: float = -5.0
     # MiniCPM's three scalars: the embedding x ``embed_scale``, each
     # sublayer's output x ``residual_scale`` before it joins the residual,
     # the final norm's output / ``logit_divisor`` before the head
@@ -206,14 +215,15 @@ class LlamaConfig:
         if self.layer_kinds:
             kinds = tuple(self.layer_kinds)
             if len(kinds) != self.layers or self.attn_kind != "kv" \
-                    or not set(kinds) <= {"kv", *ATTN_KIND_MODULES}:
+                    or not set(kinds) <= {"kv", "latent", *ATTN_KIND_MODULES}:
                 raise ValueError(
                     f"layer_kinds {kinds!r}: one kind for each of the "
-                    f"{self.layers} layers, of kv, "
+                    f"{self.layers} layers, of kv, latent, "
                     f"{', '.join(ATTN_KIND_MODULES)} (attn_kind stays kv: "
-                    "the latent and eva kinds are one a model)")
+                    "the eva kind is one a model)")
             for kind in sorted(set(kinds) - {"kv"}):
-                attn_kind_module(kind).validate(self)
+                if kind != "latent":
+                    attn_kind_module(kind).validate(self)
                 if self.kv_quant is not None \
                         or self.attn_backend != "dense":
                     raise NotImplementedError(
@@ -245,7 +255,7 @@ class LlamaConfig:
         if self.ffn_kind not in ("dense", "routed"):
             raise ValueError(f"unknown ffn_kind {self.ffn_kind!r}; "
                              "supported: dense, routed")
-        if self.attn_kind == "latent":
+        if "latent" in self.attn_kinds:
             if min(self.qk_nope, self.qk_rope, self.v_head,
                    self.kv_lora_rank) <= 0 or self.qk_rope % 2:
                 raise ValueError(
@@ -302,6 +312,11 @@ class LlamaConfig:
     def head_dim(self) -> int:
         return self.hidden // self.heads
 
+    @property
+    def attn_kinds(self) -> tuple:
+        """The attention kinds the layers have, each once."""
+        return tuple(dict.fromkeys(self.layer_kinds or (self.attn_kind,)))
+
     def layer_spec(self, layer: int) -> LayerSpec:
         """What layer ``layer`` is made of."""
         if self.ffn_kind == "routed":
@@ -327,7 +342,7 @@ class LlamaConfig:
         module = attn_kind_module(self.layer_spec(layer).attn)
         if module is not None:
             return module.cache_layout(self)
-        if self.attn_kind == "latent":
+        if self.layer_spec(layer).attn == "latent":
             row = {"ckv": (1, self.kv_lora_rank), "kpe": (1, self.qk_rope)}
             if self.index_topk:
                 # the indexer's key of the token, on the same position axis
@@ -444,16 +459,36 @@ class LlamaConfig:
         return "sparse_kv" in self.layer_kinds
 
     @property
+    def kda_layers(self) -> int:
+        return tuple(self.layer_kinds).count("kda")
+
+    @property
+    def kda_step_bytes(self) -> int:
+        """Bytes one row's decode step moves in ONE kda layer (state and
+        conv tail, once each way), 0 without such a layer."""
+        return attn_kind_module("kda").state_bytes_a_step(self) \
+            if self.kda_layers else 0
+
+    def kda_scan_chunks(self, rows: int, s: int) -> int:
+        """Chunks the kda layers' chunked form scans in ONE prefill of
+        ``rows`` rows padded to ``s`` positions (``handler.kda``)."""
+        if not self.kda_layers:
+            return 0
+        return rows * self.kda_layers * attn_kind_module("kda").scan_chunks(s)
+
+    @property
     def state_bytes_a_step(self) -> int:
         """Bytes of recurrent state one row's decode step reads and writes:
-        every linear layer's, once each way."""
+        every linear layer's state and every kda layer's state and conv
+        tail, once each way."""
         return 2 * 4 * self.lin_heads * self.lin_head_dim ** 2 \
-            * sum(kind == "linear" for kind in self.layer_kinds)
+            * tuple(self.layer_kinds).count("linear") \
+            + self.kda_layers * self.kda_step_bytes
 
 
 # the kinds that are modules of their own, by name (imported on demand: they
 # import this module's layers)
-ATTN_KIND_MODULES = ("linear", "sparse_kv")
+ATTN_KIND_MODULES = ("kda", "linear", "sparse_kv")
 # prompts past two of these prefill at whole multiples of it (models with
 # ``layer_kinds``): the keys of one block of a block-sparse prefill
 # (``sparse_kv.SPARSE_KEY_BLOCK``)
@@ -472,6 +507,10 @@ def attn_kind_module(kind: str):
         from lambdipy_tpu.models import linear_attn
 
         return linear_attn
+    if kind == "kda":
+        from lambdipy_tpu.models import kda
+
+        return kda
     return None
 
 
@@ -480,11 +519,14 @@ def require_kv_cache(cfg: LlamaConfig, holder: str) -> None:
     silent fallback: the holder would store, ship or page rows of the
     wrong layout)."""
     _refuse_kind_modules(cfg, holder)
-    if getattr(cfg, "attn_kind", "kv") != "kv":
+    other = next((layer for layer in range(cfg.layers)
+                  if cfg.layer_spec(layer).attn != "kv"), None) \
+        if hasattr(cfg, "layer_spec") else None
+    if other is not None:
         raise NotImplementedError(
             f"{holder} holds per-head k/v cache leaves and cannot take the "
-            f"{cfg.attn_kind} cache layout {sorted(cfg.cache_layout())} "
-            "(PERF.md section 7)")
+            f"{cfg.layer_spec(other).attn} cache layout "
+            f"{sorted(cfg.cache_layout(other))} (PERF.md section 7)")
 
 
 def _refuse_kind_modules(cfg, holder: str) -> None:
@@ -635,6 +677,22 @@ class QKernel(nn.Module):
                     (1, self.features), jnp.float32))
 
 
+def output_gate(cfg, out, h):
+    """``out`` ``[b, s, heads, d]`` x ``sigmoid(out_gate_proj h)`` where the
+    model gates its heads' outputs (``attn_output_gate``): one gate a head
+    under ``attn_gate_headwise``, else one a channel; in float32, as every
+    gate. For the kinds whose gate is not written into their own module (the
+    kda and latent layers); called under the block's ``nn.compact``."""
+    if not cfg.attn_output_gate:
+        return out
+    b, s, heads, d = out.shape
+    with jax.named_scope("qkv_proj"):
+        gate = jax.nn.sigmoid(QDense(
+            heads if cfg.attn_gate_headwise else heads * d, cfg.quant,
+            jnp.float32, name="out_gate_proj")(h)).reshape(b, s, heads, -1)
+        return (out.astype(jnp.float32) * gate).astype(out.dtype)
+
+
 def _scaled_rope_freqs(freqs, scaling, theta: float = 0.0):
     """Apply RoPE frequency scaling (inverse frequencies in, out).
 
@@ -722,17 +780,17 @@ def cache_width(cache) -> int:
                 if name != "index").shape[1]
 
 
-def _kv_store(cfg, k, v, *more) -> dict:
+def _kv_store(cfg, k, v, *more, layer: int = 0) -> dict:
     """This step's (or chunk's) K/V in the cache's storage layout: the
     float leaves, or int8 values + scales under ``cfg.kv_quant``. The
     ONE place the layout is built — the dense decode path, the sp
     decode path, and prefill embedding all consume it. ``k`` and ``v``
-    (and ``more``) are the parts of ``cfg.cache_layout()`` in its order (a
-    latent cache: the compressed latent, the shared rotary key and, under
+    (and ``more``) are the parts of ``cfg.cache_layout(layer)`` in its order
+    (a latent cache: the compressed latent, the shared rotary key and, under
     sparse attention, the indexer's key)."""
-    if cfg.attn_kind != "kv":
+    if cfg.layer_spec(layer).attn != "kv":
         return {name: part.astype(cfg.dtype)
-                for name, part in zip(cfg.cache_layout(), (k, v, *more))}
+                for name, part in zip(cfg.cache_layout(layer), (k, v, *more))}
     if cfg.kv_quant == "int8":
         k_q, k_s = _kv_quantize(k)
         v_q, v_s = _kv_quantize(v)
@@ -1326,11 +1384,12 @@ class LlamaBlock(nn.Module):
                 causal = jnp.tril(jnp.ones((s, s), dtype=jnp.bool_))
                 out = _attend(q, k, kv[..., dn:],
                               mask[:, None, :] & causal[None, :, :])
-            return out, {"ckv": ckv, "kpe": k_pe}
+            return output_gate(cfg, out, h), {"ckv": ckv, "kpe": k_pe}
 
         with jax.named_scope("kv_write"):
             new_cache, valid, t = _cache_write(
-                cache, _kv_store(cfg, ckv, k_pe), cache["index"], b, s, band)
+                cache, _kv_store(cfg, ckv, k_pe, layer=self.layer),
+                cache["index"], b, s, band)
         if cfg.index_topk:
             new_cache["kidx"], valid = self._sparse_select(
                 cache, q_idx, k_idx, w_idx, jnp.broadcast_to(valid, (b, s, t)))
@@ -1364,7 +1423,7 @@ class LlamaBlock(nn.Module):
                              preferred_element_type=jnp.float32)
             if w_scale is not None:
                 out = out * w_scale.reshape(heads, dn + dv)[:, dn:]
-        return out.astype(cfg.dtype), new_cache
+        return output_gate(cfg, out.astype(cfg.dtype), h), new_cache
 
     def _indexer(self, h, c_q, positions):
         """The lightning indexer's projections (under ``dsa_index``), from
@@ -1540,7 +1599,7 @@ class LlamaBlock(nn.Module):
                     from lambdipy_tpu.parallel.spdecode import (
                         sp_decode_step)
 
-                    sp_new = _kv_store(cfg, k, v)
+                    sp_new = _kv_store(cfg, k, v, layer=self.layer)
                     sp_cache = {name: cache[name] for name in sp_new}
                     with jax.named_scope("attend"):
                         out, new_cache = sp_decode_step(
@@ -1579,7 +1638,7 @@ class LlamaBlock(nn.Module):
                 # the tail, one softmax over both
                 with jax.named_scope("kv_write"):
                     new_cache, valid, seen = _tail_write(
-                        cache, _kv_store(cfg, k, v))
+                        cache, _kv_store(cfg, k, v, layer=self.layer))
                 with jax.named_scope("attend"):
                     out = _attend(q, *kv_of(cache), valid,
                                   tail=(*kv_of(new_cache), seen))
@@ -1589,7 +1648,8 @@ class LlamaBlock(nn.Module):
                     # cache stays int8 in HBM and the dequant fuses into
                     # the attention einsum
                     new_cache, valid, t = _cache_write(
-                        cache, _kv_store(cfg, k, v), idx, b, s, band)
+                        cache, _kv_store(cfg, k, v, layer=self.layer), idx,
+                        b, s, band)
                 with jax.named_scope("attend"):
                     # length-aware blocked decode attention: one-token steps
                     # read each row's ACTIVE window instead of the full
@@ -1955,6 +2015,8 @@ def validate_serving_mesh(cfg: LlamaConfig, mesh) -> None:
     if (cfg.attn_kind != "kv" or cfg.ffn_kind != "dense"
             or cfg.layer_kinds) \
             and any(int(n) > 1 for n in shape.values()):
+        _refuse_kind_modules(
+            cfg, f"mesh {shape}: the cache layout sharded by kv head")
         raise NotImplementedError(
             f"mesh {shape}: no sharding is written yet for latent attention "
             "(a cache row has no head axis to split), eva attention (a "
@@ -2172,7 +2234,8 @@ def prefill_into_cache(cfg: LlamaConfig, prefill_cache, batch: int, max_len: int
                      for name in cfg.cache_layout(layer)}
         else:
             store = _kv_store(cfg, *(entry[name]
-                                     for name in cfg.cache_layout(layer)))
+                                     for name in cfg.cache_layout(layer)),
+                              layer=layer)
         dest = _empty_cache_entry(cfg, batch, max_len, layer)
         for name, val in store.items():
             dest[name] = shard_hint(
@@ -2385,10 +2448,11 @@ def segment_keeps_tail(cfg: LlamaConfig) -> bool:
     their segments write it every step too. Asked while a segment program
     is traced, under its mesh."""
     if cfg.layer_kinds:
-        # a linear state is a carry of the scan by nature; a block-sparse
-        # layer is grouped-query K/V, whose per-step write is in place, and
-        # its compressed key is pooled from rows the step must find in the
-        # cache. (A "kv" layer beside them keeps the per-step write too.)
+        # a linear or kda state is a carry of the scan by nature; a
+        # block-sparse layer is grouped-query K/V, whose per-step write is
+        # in place, and its compressed key is pooled from rows the step must
+        # find in the cache. (A "kv" or "latent" layer beside them keeps the
+        # per-step write too.)
         return False
     if cfg.attn_kind == "latent" or cfg.heads != cfg.kv_heads:
         return False

@@ -437,6 +437,19 @@ def _build_deepseek_v32(dtype: str = "bfloat16", quant: str | None = None,
     return _build_llama(cfg)
 
 
+def _layer_kinds(extra: dict, model: str, kinds_of: str) -> tuple:
+    """``extra["layer_kinds"]`` as a tuple: a comma-separated string from a
+    recipe's TOML or a sequence from a manifest; a model of kinds a layer
+    cannot be built without it."""
+    kinds = extra.get("layer_kinds") or ()
+    if isinstance(kinds, str):
+        kinds = [k.strip() for k in kinds.split(",") if k.strip()]
+    if not kinds:
+        raise ValueError(f"{model} needs layer_kinds: one of {kinds_of} for "
+                         "each layer")
+    return tuple(kinds)
+
+
 @register("minicpm-sala", "jax",
           "MiniCPM-SALA block: block-sparse (InfLLM-V2) attention layers "
           "among Lightning linear-attention layers, muP scalars")
@@ -455,15 +468,48 @@ def _build_minicpm_sala(dtype: str = "bfloat16", quant: str | None = None,
     from lambdipy_tpu.models.llama import LlamaConfig
 
     extra = {"qk_norm": True, "attn_output_gate": True, **(extra or {})}
-    kinds = extra.get("layer_kinds") or ()
-    if isinstance(kinds, str):
-        kinds = [k.strip() for k in kinds.split(",") if k.strip()]
-    if not kinds:
-        raise ValueError("minicpm-sala needs layer_kinds: one of sparse_kv "
-                         "and linear for each layer")
-    extra["layer_kinds"] = tuple(kinds)
+    extra["layer_kinds"] = _layer_kinds(extra, "minicpm-sala",
+                                        "sparse_kv and linear")
     cfg = LlamaConfig(dtype=_dtype(dtype), quant=quant,
                       **_llama_overrides(extra))
+    return _build_llama(cfg)
+
+
+@register("bailing-hybrid", "jax",
+          "Ling-3.0 block: Kimi-Delta-Attention layers beside latent-"
+          "attention layers, group-routed experts of which a share")
+def _build_bailing_hybrid(dtype: str = "bfloat16", quant: str | None = None,
+                          extra: dict | None = None) -> JaxModel:
+    """The ``bailing_hybrid`` architecture through the one block, its
+    attention kind chosen a LAYER (``layer_kinds``, one of ``kda`` and
+    ``latent`` for each layer, as a comma-separated string from a recipe's
+    TOML or a sequence from a manifest): models/kda.py (a gated delta-rule
+    state behind a short convolution: ``kda_*``) and the block's latent
+    attention without query compression, both under a head-wise output gate;
+    ``first_dense_layers`` leading dense SwiGLUs, then the ``deepseek-v32``
+    builder's group-limited routing over a chip's share of the experts. Two
+    parts of the published model are refused by name, never guessed:
+    ``swiglu_limit`` (the clamped SwiGLU of its last layers) and
+    ``nextn_predict_layers`` (its multi-token-prediction layer) must be 0.
+    Every shape key comes from ``extra`` (docs/serving.md, "bailing-hybrid
+    recipe keys")."""
+    from lambdipy_tpu.models.llama import LlamaConfig
+
+    extra = {"rope_interleave": True, "scoring_func": "sigmoid",
+             "norm_topk_prob": True, "attn_output_gate": True,
+             "attn_gate_headwise": True, **(extra or {})}
+    for key, what in (("swiglu_limit", "a clamped SwiGLU (the catalog does "
+                       "not give the clamp's form)"),
+                      ("nextn_predict_layers", "a multi-token-prediction "
+                       "layer (it sits behind the last layer; ROADMAP R7)")):
+        if float(extra.pop(key, 0) or 0):
+            raise NotImplementedError(
+                f"bailing-hybrid: {key} must be 0: {what} is not written "
+                "(PERF.md section 7)")
+    extra["layer_kinds"] = _layer_kinds(extra, "bailing-hybrid",
+                                        "kda and latent")
+    cfg = LlamaConfig(dtype=_dtype(dtype), quant=quant,
+                      **{**_llama_overrides(extra), "ffn_kind": "routed"})
     return _build_llama(cfg)
 
 

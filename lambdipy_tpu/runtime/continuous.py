@@ -245,6 +245,7 @@ class ContinuousBatcher:
                                                   DsaKeyStats,
                                                   EngineFaultStats,
                                                   EvaKeyStats,
+                                                  KdaStats,
                                                   MoeLoadStats,
                                                   PipelineStats,
                                                   PrefillStats,
@@ -288,6 +289,11 @@ class ContinuousBatcher:
         self.sala_stats = SalaKeyStats(
             step_state_bytes=getattr(cfg, "state_bytes_a_step", 0))
         self._counts_sala = bool(getattr(cfg, "counts_sala_keys", False))
+        # a model with kda layers: the layer-steps its booked rows took and
+        # the chunks its prefills scanned, from shapes (/metrics handler.kda)
+        self.kda_stats = KdaStats(
+            layers=int(getattr(cfg, "kda_layers", 0)),
+            layer_bytes=int(getattr(cfg, "kda_step_bytes", 0)))
         self._routed_layers = (cfg.layers - cfg.first_dense_layers
                                if getattr(cfg, "counts_moe_load", False)
                                else 0)
@@ -842,6 +848,9 @@ class ContinuousBatcher:
         knobs = server._knob_operands(
             entry["temperature"], entry["top_k"], entry["top_p"],
             entry["seed"], None, b=1)
+        if self.kda_stats.layers:
+            self.kda_stats.record_prefill(
+                server.model.cfg.kda_scan_chunks(1, sb))
         with server._mesh_ctx():
             return prefill(server.params, prompt_op, length_op, *knobs)
 
@@ -879,6 +888,9 @@ class ContinuousBatcher:
             [e["top_p"] for e in entries],
             [e["seed"] for e in entries],
             None, b=bb)
+        if self.kda_stats.layers:
+            self.kda_stats.record_prefill(
+                server.model.cfg.kda_scan_chunks(bb, sb))
         with server._mesh_ctx():
             return prefill(server.params, prompt_op, length_op, *knobs)
 
@@ -1636,6 +1648,9 @@ class ContinuousBatcher:
                 if moe_h and self._counts_sala:
                     self.sala_stats.record_segment(moe_h.pop()[booked],
                                                    steps=block.shape[1])
+                if self.kda_stats.layers:
+                    self.kda_stats.record_segment(len(booked),
+                                                  steps=block.shape[1])
                 if moe_h and self._counts_eva:
                     self.eva_stats.record_segment(moe_h[0][booked],
                                                   steps=block.shape[1])

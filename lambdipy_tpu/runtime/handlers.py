@@ -1636,6 +1636,11 @@ def generate_handler(spec: dict, ctx) -> HandlerState:
             # what the block-sparse layers' steps attended and wrote, and
             # the states the linear layers carried
             out["sala"] = continuous.sala_stats.report()
+        if continuous is not None and getattr(
+                server.model.cfg, "kda_layers", 0):
+            # the layer-steps the kda layers' states took and the chunks
+            # their prefills scanned
+            out["kda"] = continuous.kda_stats.report()
         if getattr(server, "spec_metrics", None) is not None:
             # the solo `"speculative": k` path's cumulative acceptance
             # counters (the engine's batching.spec block shares this

@@ -243,6 +243,38 @@ class SalaKeyStats:
 
 
 @dataclass
+class KdaStats:
+    """Counters of a model with kda layers (the ``handler.kda`` block on
+    ``/metrics``), only growing, from shapes. ``row_steps``: booked rows x
+    segment steps x kda layers: the layer-steps that stepped a state for
+    somebody. ``scan_chunks``: the chunks the prefills' chunked form
+    scanned, every row and kda layer of every prefill program run
+    (``LlamaConfig.kda_scan_chunks``). ``state_bytes``: the state and conv
+    tail those layer-steps read and wrote, once each way (``layer_bytes`` a
+    row's step in one layer: ``models/kda.py state_bytes_a_step``)."""
+
+    layers: int = 0
+    layer_bytes: int = 0
+    row_steps: int = 0
+    scan_chunks: int = 0
+    _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
+
+    def record_segment(self, rows: int, *, steps: int) -> None:
+        with self._lock:
+            self.row_steps += rows * steps * self.layers
+
+    def record_prefill(self, chunks: int) -> None:
+        with self._lock:
+            self.scan_chunks += chunks
+
+    def report(self) -> dict:
+        with self._lock:
+            return {"row_steps": self.row_steps,
+                    "scan_chunks": self.scan_chunks,
+                    "state_bytes": self.row_steps * self.layer_bytes}
+
+
+@dataclass
 class EvaKeyStats:
     """Counters of an eva-attention model's decode segments (the
     ``handler.eva`` block on ``/metrics``), only growing, from the segment

@@ -5,7 +5,8 @@ and the ``deepseek-v3`` family that PR 27 brought: its leaf rules against
 the program's real tree at published widths, what it says a step needs, by
 hand, and its toy twin through the whole command on the CPU; the same for the
 ``evabyte`` family that PR 33 brought, the ``deepseek-v32`` family that
-PR 35 brought and the ``minicpm-sala`` family that PR 39 brought. At the end,
+PR 35 brought, the ``minicpm-sala`` family that PR 39 brought and the
+``bailing-hybrid`` family that PR 41 brought. At the end,
 the yardstick against the program: what every accepted configuration's
 family says a step reads and computes, against the program's own parameter
 tree, and the bounds the ledger's roofline shares stand on."""
@@ -243,8 +244,8 @@ def test_the_experts_read_reader_takes_the_windows_delta_or_nothing():
                         "m_close": {"handler": {}}}) is None
     entry = next(m for m in json.loads((REPO / "BENCHMARK.json").read_text())[
         "per_layer"] if m["name"] == "moe_experts_read")
-    assert entry["workloads"] == ["kanana2-30b.decode-saturated",
-                                  "deepseek-v32-exp.long-context-decode"]
+    assert entry["workloads"][:2] == ["kanana2-30b.decode-saturated",
+                                      "deepseek-v32-exp.long-context-decode"]
 
 
 # -- the evabyte family (PR 33) -------------------------------------------------
@@ -847,7 +848,9 @@ def test_the_dsa_readers_take_the_windows_delta_or_nothing():
                  "dsa_keys_per_query", "moe_local_share",
                  "dsa_prefill_share"):
         entry = next(m for m in manifest["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [DSA_CELL]
+        # (moe_local_share: the later cell that holds a share, PR 41, too)
+        assert entry["workloads"][0] == DSA_CELL and len(
+            entry["workloads"]) == 1 + (name == "moe_local_share")
     for name in ("out_tok_s", "decode_hbm_pct", "hbm_peak_gb",
                  "engine_host_ms", "decode_step_ms", "decode_matmul_ms",
                  "decode_attend_ms", "decode_sample_ms", "decode_moe_ms",
@@ -1149,7 +1152,7 @@ def test_the_sala_readers_take_the_windows_delta_or_nothing():
                  "decode_attend_ms", "decode_sample_ms"):
         entry = next(m for m in manifest["end_to_end"] + manifest["per_layer"]
                      if m["name"] == name)
-        assert entry["workloads"][-1] == SALA_CELL, name
+        assert SALA_CELL in entry["workloads"][-2:], name
 
 
 def test_a_sala_decode_step_at_8_rows_is_bound_by_its_bytes():
@@ -1162,6 +1165,317 @@ def test_a_sala_decode_step_at_8_rows_is_bound_by_its_bytes():
     assert need / V5E.hbm_bytes_s > 8 * flops / V5E.bf16_flops
     assert family.decode_step_bytes(SALA, rows=0, context=0) > 0.88 * need
     assert 6.0e-3 < need / V5E.hbm_bytes_s < 6.8e-3
+
+
+# -- the bailing-hybrid family (PR 41): kda and latent layers in one model ------
+
+LING = json.loads((BENCH / "configs" / "ling3-flash.json").read_text())
+LING_TWIN_MANIFEST = BENCH / "rehearsal-kda.json"
+LING_TWIN_CELL = "rehearsal-kda.rehearsal-closed"
+LING_CELL = "ling3-flash.reasoning-decode"
+LING_READERS = ("kda_state_ms", "kda_state_hbm_pct", "kda_scan_share",
+                "kda_bytes_per_row_step")
+
+# the catalog's row for Ling-3.0-flash (the model-configs guide's
+# architectures.jsonl, ``config``): every key, as published
+LING_PUBLISHED = {
+    "first_k_dense_replace": 2,
+    "gated_attention_proj_granularity_type": 'head_wise',
+    "group_norm_size": 1, "head_dim": 128, "hidden_act": 'silu',
+    "hidden_size": 2560, "intermediate_size": 6144, "kda_lower_bound": -5,
+    "kda_safe_gate": True, "kv_lora_rank": 512, "layer_group_size": 6,
+    "linear_silu": True, "max_position_embeddings": 262144,
+    "max_window_layers": 20, "moe_intermediate_size": 768,
+    "moe_router_enable_expert_bias": True,
+    "moe_shared_expert_intermediate_size": 768, "mtp_loss_scaling_factor": 0,
+    "mtp_use_kda": False, "n_group": 8, "no_kda_lora": True,
+    "norm_topk_prob": True, "num_attention_heads": 32, "num_experts": 512,
+    "num_experts_per_tok": 8, "num_hidden_layers": 42,
+    "num_key_value_heads": 32, "num_kv_heads_for_linear_attn": 0,
+    "num_nextn_predict_layers": 1, "num_shared_experts": 1,
+    "partial_rotary_factor": 0.5, "q_lora_rank": None, "qk_head_dim": 192,
+    "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "rms_norm_eps": 1e-06,
+    "rope_interleave": True, "rope_scaling": None, "rope_theta": 6000000,
+    "rotary_dim": 64, "routed_scaling_factor": 2.5,
+    "scale_router_input": False, "score_function": 'sigmoid',
+    "scoring_func": 'sigmoid', "seq_aux": True, "short_conv_kernel_size": 4,
+    "tie_word_embeddings": False, "topk_group": 4, "topk_method": 'noaux_tc',
+    "up_proj_norm": False, "use_bias": False, "use_kda_lora": False,
+    "use_mla_nope": False, "use_nGPT": False, "use_qk_norm": True,
+    "use_qkv_bias": False, "v_head_dim": 128, "value_norm": False,
+    "vocab_size": 157184, "model_type": 'bailing_hybrid',
+    "expert_swiglu_limit_list": [0] * 35 + [4] * 7,
+    "share_expert_swiglu_limit_list": [0] * 34 + [5] * 6 + [7] * 2}
+
+
+def test_ling3_flash_is_the_published_configuration_cut_to_a_chips_share():
+    changed = {k for k, v in LING_PUBLISHED.items()
+               if LING.get(k, "absent") != v}
+    assert changed == {"num_hidden_layers", "first_k_dense_replace",
+                       "num_experts", "vocab_size",
+                       "num_nextn_predict_layers", "expert_swiglu_limit_list",
+                       "share_expert_swiglu_limit_list",
+                       "max_position_embeddings"}
+    assert LING["reduced"] == [
+        "num_hidden_layers", "first_k_dense_replace", "num_experts",
+        "vocab_size", "num_nextn_predict_layers", "expert_swiglu_limit_list",
+        "share_expert_swiglu_limit_list", "engine_window",
+        "max_position_embeddings"]
+    assert set(LING["reduced_why"]) == set(LING["reduced"])
+    assert LING["published"] == {k: LING_PUBLISHED[k] for k in LING["reduced"]
+                                 if k != "engine_window"}
+    # 1 + 6 of 42: published layer 1 and one whole period in published order
+    held = LING["layers_held"]
+    assert held == [1, 6, 7, 8, 9, 10, 11] and LING["num_hidden_layers"] == 7
+    assert LING["expert_swiglu_limit_list"] == [
+        LING_PUBLISHED["expert_swiglu_limit_list"][i] for i in held] == [0] * 7
+    assert LING["share_expert_swiglu_limit_list"] == [0] * 7
+    d = families.of(LING).dims_of(LING)
+    assert d["layer_kinds"] == "kda,kda,kda,kda,kda,kda,latent"
+    assert (d["first_dense_layers"], d["moe_experts"], d["moe_experts_held"],
+            d["moe_first_expert"]) == (1, 512, 128, 0)
+    assert (LING["routed_experts_published"], LING["layer_shared_by_chips"],
+            LING["vocab_size"] * 4) == (512, 4, 157184)
+    assert (LING["engine_window"], LING["context_served"]) == (8192, 16384)
+    assert LING["recipe_extra"] == {"batch_cache_len": 8192, "batch_max": 16,
+                                    "max_new_tokens": 16}
+    assert {"mla_position", "kda_qk_norm", "rotary_dim", "kda_gate",
+            "kda_gate_rank", "kda_output_norm", "output_gate", "mla_qk_norm",
+            "group_score", "weights", "quantization", "tokenizer"} \
+        <= set(LING["assumed"])
+    assert "stands_for" in LING and "4 chips share each layer" in \
+        LING["stands_for"]
+    manifest = json.loads((REPO / "BENCHMARK.json").read_text())
+    entry = next(c for c in manifest["configs"] if c["name"] == "ling3-flash")
+    assert entry["reduced"] == LING["reduced"]
+    assert entry["source"] == LING["source"]
+    assert len(manifest["workloads"]) == 8
+    cell = next(w for w in manifest["workloads"] if w["name"] == LING_CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "ling3-flash", "reasoning-decode", 1)
+    traffic = json.loads((BENCH / "traffic" / "reasoning-decode.json"
+                          ).read_text())
+    assert (traffic["kind"], traffic["clients"], traffic["pool"]) == (
+        "closed_loop", 32, 192)
+    assert traffic["prompt_len"] == {"dist": "uniform", "min": 512,
+                                     "max": 1536}
+    assert traffic["max_tokens"] == {"dist": "uniform", "min": 3072,
+                                     "max": 4096}
+    assert traffic["lead_in_s"] >= 20
+    assert traffic["clients"] == 2 * LING["recipe_extra"]["batch_max"]
+    assert traffic["prompt_len"]["max"] < LING["vocab_size"]
+    # every prompt is a solo prefill of a power-of-two bucket, and the
+    # decode windows are the buckets of 528 .. 5632 positions
+    from benchmark import warmup
+
+    cov = warmup.coverage(traffic, LING)
+    assert cov["prompt_buckets"] == [512, 1024, 2048]
+    assert cov["group_buckets"] == [] and cov["slots"] == 16
+    assert cov["decode_windows"] == [1024, 2048, 4096, 8192]
+
+
+def test_ling3_flashs_leaf_rules_name_every_path_of_the_real_tree():
+    from lambdipy_tpu.models import registry
+
+    family = families.of(LING)
+    adapter = registry.get(LING["model"]).build(
+        dtype="bfloat16", quant="int8", extra=family.dims_of(LING))
+    tree = jax.eval_shape(lambda: adapter.init_params(seed=0))
+    shapes, sizes, dtypes = {}, {}, {}
+    for path, spec in jax.tree_util.tree_leaves_with_path(tree):
+        path = "/".join(str(k.key) for k in path if k.key != "params")
+        shapes[path], dtypes[path] = spec.shape, np.dtype(spec.dtype)
+        sizes[path] = int(np.prod(spec.shape))
+        sliver = tuple(min(n, 2) for n in spec.shape)
+        leaf = family.leaf(1, path, sliver, spec.dtype, LING)
+        assert leaf is not None and leaf.shape == sliver, path
+        assert leaf.dtype == np.dtype(spec.dtype), path
+
+    def params_of(prefix, skip=()):
+        return sum(n for p, n in sizes.items() if p.startswith(prefix)
+                   and p.endswith(("int8", "/router", "conv_weight"))
+                   and not any(s in p for s in skip))
+
+    # ISSUE 41's arithmetic: a KDA layer's attention 52.6 M, an MLA layer's
+    # 31.9 M, a routed FFN here 762.2 M, the dense FFN 47.2 M; the stage
+    # 99.8 + 5 x 814.8 + 794.1 M = 4.97 G of kernels
+    ffn = ("moe/", "gate_proj", "up_proj", "down_proj")
+    assert 52.5e6 < params_of("layer_0/", ffn + ("out_gate",)) + sizes[
+        "layer_0/out_gate_proj/kernel_int8"] < 52.7e6
+    assert 31.8e6 < params_of("layer_6/", ffn) < 32.0e6
+    assert 762.1e6 < params_of("layer_1/moe/") < 762.3e6
+    assert 47.1e6 < params_of("layer_0/", ("_proj/kernel_int8",)) \
+        - sizes["layer_0/conv_weight"] + sum(
+            sizes[f"layer_0/{n}_proj/kernel_int8"]
+            for n in ("gate", "up", "down")) < 47.3e6
+    assert 99.7e6 < params_of("layer_0/") < 99.9e6
+    assert 814.7e6 < params_of("layer_1/") < 814.9e6
+    assert 794.0e6 < params_of("layer_6/") < 794.2e6
+    assert 4.96e9 < params_of("layer_") < 4.98e9
+    assert sizes["embed/embedding"] == sizes["lm_head/kernel_int8"] \
+        == 39296 * 2560
+    assert shapes["layer_2/moe/experts_gate_int8"] == (128, 2560, 768)
+    assert shapes["layer_2/moe/router"] == (2560, 512)
+    assert shapes["layer_2/f_proj/kernel_int8"] == (2560, 4096)
+    assert shapes["layer_2/conv_weight"] == (4, 3, 4096)
+    assert shapes["layer_2/o_norm/scale"] == (4096,)
+    assert shapes["layer_6/out_gate_proj/kernel_int8"] == (2560, 32)
+    assert shapes["layer_6/kv_a_proj/kernel_int8"] == (2560, 576)
+    assert dtypes["layer_2/A_log"] == dtypes["layer_2/dt_bias"] == np.float32
+    with pytest.raises(ValueError, match="bailing-hybrid.*q_a_proj"):
+        weights.leaf(LING, "layer_2/q_a_proj/scale", (1, 2), "float32")
+    # what was drawn, and why (the configuration's ``assumed.weights``)
+    taps = weights.leaf(LING, "layer_2/conv_weight", (4, 3, 64), "float32")
+    assert np.abs(taps).max() <= 1.5 * 128 / 127 and np.std(taps) > 0.7
+    # taps of either sign in most channels: no moving average, no last tap
+    assert ((taps > 0).any(axis=0) & (taps < 0).any(axis=0)).mean() > 0.8
+    bias = weights.leaf(LING, "layer_2/dt_bias", (4096,), "float32")
+    assert -8.0 <= bias.min() < -7.9 and -0.1 < bias.max() <= 0.0
+    rate = weights.leaf(LING, "layer_2/A_log", (32,), "float32")
+    assert np.abs(rate).max() <= np.log(2) + 1e-6 and np.std(rate) > 0.2
+    np.testing.assert_allclose(
+        weights.leaf(LING, "layer_2/f_proj/scale", (1, 8), "float32"),
+        1 / (127 * 2560 ** 0.5), rtol=1e-6)
+    np.testing.assert_allclose(
+        weights.leaf(LING, "layer_2/moe/experts_down_scale", (2, 1, 8),
+                     "float32"), 1 / (127 * 768 ** 0.5), rtol=1e-6)
+    # a step with so many rows that every held expert is touched reads every
+    # int8 kernel once, the float32 routers, conv weights and gate biases
+    read = sum(n * (4 if dtypes[p] == np.float32 else 1)
+               for p, n in sizes.items()
+               if p.endswith(("int8", "/router", "conv_weight", "dt_bias")))
+    assert family.decode_step_bytes(LING, rows=1e9, context=0) - \
+        family.kda_step_bytes(LING, rows=1e9) == pytest.approx(read, rel=1e-9)
+
+
+def test_what_a_ling_step_needs_by_hand():
+    family = families.of(LING)
+    d = family.dims_of(LING)
+    state, tail = 4 * 32 * 128 * 128, 2 * 3 * 3 * 4096
+    # ISSUE 41: a slot is 6 x (2.10 MB of state + 74 KB of tail) = 13.0 MB
+    assert (state, tail) == (2097152, 73728)
+    assert 12.9e6 < 6 * (state + tail) < 13.1e6
+    assert family.kda_step_bytes(LING, rows=16) == 16 * 6 * 2 * (state + tail)
+    # 16 rows x top-8: 32 local assignments a layer touch about 28 of the 128
+    assert 28 < family.experts_touched(d, 16) < 29
+    step = family.decode_step_bytes(LING, rows=16, context=3000)
+    # about 2.0 GB: 1.0 GB of experts, 0.42 GB of state and tail both ways,
+    # 0.06 GB of latent rows, the rest other kernels and the head slice
+    assert 1.95e9 < step < 2.15e9
+    experts = 6 * family.experts_touched(d, 16) * 3 * 2560 * 768
+    assert 0.95e9 < experts < 1.05e9
+    rows = 16 * 3000 * 1152
+    assert step - family.decode_step_bytes(LING, rows=16, context=0) == rows
+    # the step is bound by its bytes, eight times over
+    flops = family.decode_step_flops(LING, rows=16, context=3000)
+    assert step / V5E.hbm_bytes_s > 8 * flops / V5E.bf16_flops
+    assert 2.3e-3 < step / V5E.hbm_bytes_s < 2.7e-3
+    # one 1536-token prompt is under 0.1 s of operations at the peak
+    assert family.prefill_flops(LING, rows=1, seq_len=1536) / V5E.bf16_flops \
+        < 0.1
+
+
+def test_the_kda_twin_runs_the_whole_command_and_counts_its_layer_steps(
+        capsys, tmp_path, monkeypatch):
+    """The whole command over the toy twin (prompts of 8-32 tokens, answers
+    of 24-48, through build, deploy, HTTP and the continuous engine). A run
+    whose warm-up's burst did not arrive as one group says so
+    (``still_missing``) and is made again over the bundle it built, as the
+    other twins' are."""
+    from benchmark import harness
+
+    for key in ("JAX_PLATFORMS", "XLA_FLAGS"):
+        monkeypatch.setenv(key, os.environ.get(key, ""))
+    windows = []
+    run_window = harness.run_window
+    monkeypatch.setattr(harness, "run_window", lambda *a, **kw: windows.append(
+        run_window(*a, **kw)) or windows[-1])
+    for attempt in range(5):
+        rc = run.main(["--manifest", str(LING_TWIN_MANIFEST), "--workload",
+                       LING_TWIN_CELL, "--seed", str(2**31 + 41 + attempt),
+                       "--seconds", "3", "--trace", "1",
+                       "--work-dir", str(tmp_path)])
+        lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+                 if ln.startswith("{")]
+        warm = next(ln for ln in lines if ln.get("stage") == "warmup")
+        if not warm["still_missing"]:
+            break
+    last = lines[-1]
+    assert rc == 0 and last["correct"] is True, lines[-3:]
+    assert last["device"]["platform"] == "cpu" and last["failed"] == 0
+    window = next(ln for ln in lines if ln.get("stage") == "window")
+    assert window["compiles_in_window"] == 0, (warm, window)
+    # the shape's value exactly: a float32 state of 8 x 16 x 16 and a
+    # float32 tail of 3 x 3 x 128, each once each way
+    assert last["metrics"]["kda_bytes_per_row_step"]["value"] == \
+        2 * (4 * 8 * 16 * 16 + 4 * 3 * 3 * 128)
+    counted = windows[-1]["m_close"]["handler"]["kda"]
+    assert counted["row_steps"] % 6 == 0 and counted["scan_chunks"] > 0
+    assert counted["state_bytes"] == counted["row_steps"] * 25600
+    # the routed FFN's load under layer_kinds: a quarter of the picks local
+    assert 10 < last["metrics"]["moe_local_share"]["value"] < 45
+    assert 0 < last["metrics"]["moe_experts_read"]["value"] <= 4
+
+
+def test_the_kda_readers_read_a_number_or_say_why_not(monkeypatch):
+    """The readers PR 41 added: a number where the program counts or names
+    what they read, None where it does not (a llama cell; the parent)."""
+    from benchmark import harness, scopes
+
+    def metrics(**kda):
+        return {"handler": {"kda": kda}}
+
+    a = metrics(row_steps=9600, state_bytes=9600 * 4341760)
+    b = metrics(row_steps=9600 + 960, state_bytes=(9600 + 960) * 4341760)
+    ctx = {"m_open": a, "m_close": b}
+    reader = harness.layer_metric("kda_bytes_per_row_step")
+    assert reader.read(ctx) == 4341760 == 2 * (2097152 + 73728)
+    empty = {"m_open": {"handler": {}}, "m_close": {"handler": {}}}
+    assert reader.read(empty) is None
+    assert reader.read({"m_open": a, "m_close": a}) is None
+    llama = {"family": families.load("llama-hf"), "trace": {"busy_s": 1},
+             "slice": {"live": [(16, 3000.0)]},
+             "device": {"kind": "TPU v5 lite"}, "config": {}, **empty}
+    for name in ("kda_state_ms", "kda_state_hbm_pct", "kda_scan_share"):
+        assert harness.layer_metric(name).read(llama) is None
+    family = families.of(LING)
+    ling = {**llama, "family": family, "config": LING,
+            "m_close": {"handler": {"batching": {"segment": 16}}}}
+    # no trace: no device number, whatever the counters say
+    for name in ("kda_state_ms", "kda_state_hbm_pct", "kda_scan_share"):
+        assert harness.layer_metric(name).read({**ling, "trace": None}) \
+            is None
+    # a split of 10 segment runs whose kda scopes took 1.2 ms a step: 16
+    # rows' 0.417 GB over 1.2 ms is 42.4 % of 819 GB/s
+    monkeypatch.setattr(scopes, "for_run", lambda family: {
+        "runs": 10, "run_s": 10 * 16 * 6e-3, "scoped": True,
+        "by_scope": {"kda_conv": 0.016, "kda_gate": 0.016,
+                     "kda_state": 0.16, "mlp": 0.4}})
+    assert harness.layer_metric("kda_state_ms").read(ling) == \
+        pytest.approx(1.2)
+    assert harness.layer_metric("kda_state_hbm_pct").read(ling) == \
+        pytest.approx(100 * 16 * 6 * 4341760 / 1.2e-3 / 819e9)
+    # the share of a scope over a recorded v5e trace (a llama segment: the
+    # scope it does have; kda_scan reads 0 there, not None)
+    share = harness.layer_metric("kda_scan_share").scope_share
+    fixture = BENCH / "tests" / "data" / "v5e_seg_3ms.xplane.pb"
+    assert 55 < share(fixture, families.load("llama-hf"), "mlp") < 65
+    assert share(fixture, family, "kda_scan") == 0.0
+    manifest = json.loads((REPO / "BENCHMARK.json").read_text())
+    for name in LING_READERS:
+        entry = next(m for m in manifest["per_layer"] if m["name"] == name)
+        assert entry["workloads"] == [LING_CELL]
+    for name in ("out_tok_s", "decode_hbm_pct", "hbm_peak_gb",
+                 "engine_host_ms", "decode_step_ms", "decode_matmul_ms",
+                 "decode_attend_ms", "decode_sample_ms", "decode_moe_ms",
+                 "mla_absorb_ms", "moe_load_max_share", "moe_experts_read",
+                 "moe_local_share"):
+        entry = next(m for m in manifest["end_to_end"] + manifest["per_layer"]
+                     if m["name"] == name)
+        assert entry["workloads"][-1] == LING_CELL, name
+    moe_hbm = next(m for m in manifest["per_layer"]
+                   if m["name"] == "moe_hbm_pct")
+    assert LING_CELL not in moe_hbm["workloads"]
 
 
 # -- the yardstick against the program ---------------------------------------

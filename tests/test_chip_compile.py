@@ -656,6 +656,61 @@ def test_a_sala_segment_compiles_and_what_it_does_with_its_leaves(v5e):
         layers=layers, layer_kinds=SALA_KINDS, **MINICPM_SALA))
 
 
+# ling3-flash's widths (benchmark/configs/ling3-flash.json): 16 slots of 8192,
+# a routed kda layer and the routed latent layer of one period
+LING3_FLASH = dict(
+    vocab_size=39296, hidden=2560, heads=32, kv_heads=32, mlp=6144,
+    rope_theta=6e6, norm_eps=1e-6, max_len=16384, kda_heads=32,
+    kda_head_dim=128, qk_nope=128, qk_rope=64, v_head=128, kv_lora_rank=512,
+    rope_interleave=True, attn_output_gate=True, attn_gate_headwise=True,
+    ffn_kind="routed", moe_experts=512, moe_experts_held=128, moe_n_group=8,
+    moe_topk_group=4, moe_top_k=8, moe_intermediate=768, n_shared_experts=1,
+    routed_scaling_factor=2.5, scoring_func="sigmoid")
+
+
+def test_a_kda_segment_compiles_with_its_two_leaves_and_the_latent_rows(
+        v5e, monkeypatch):
+    """The engine's segment at ``ling3-flash.reasoning-decode``'s own shape
+    key (16 slots of 8192, the 2048 window bucket, 16 steps), one routed kda
+    layer and the routed latent layer, lowered as a TPU backend lowers it.
+    ~25 s.
+
+    It compiles for the chip (the expert kernel at 128 held experts of 2560
+    x 768 and 16 rows among it), and every scope ``benchmark/families/
+    bailing_hybrid.py`` gathers a trace's operations by is the op_name of
+    some operation (no ``kda_scan``: the prefill's). The window bucket cuts
+    the latent layer's ``ckv`` and ``kpe`` alone: no operation of the loop
+    has a kda leaf's shape at another length than its own."""
+    from benchmark.families import bailing_hybrid
+    from lambdipy_tpu.models import llama, moe
+
+    # (this process's backend is the CPU: say what a TPU's would)
+    monkeypatch.setattr(moe, "kernels_compile_here", lambda: True)
+    slots, window = 16, 8192
+    text = _decode_segment_text(v5e, steps=16, layers=2, window=2048,
+                                cache_len=window, rows=slots,
+                                layer_kinds=("kda", "latent"), **LING3_FLASH)
+    found = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        found.update(op_name.split("/"))
+    assert set(bailing_hybrid.SCOPES) - {"kda_scan"} <= found
+    assert "tpu_custom_call" in text            # the picked-experts kernel
+    shapes = {tuple(int(n) for n in dims.split(","))
+              for dims in re.findall(r"\w+\[([\d,]+)\]", text)}
+    assert (slots, 2048, 1, 512) in shapes and (slots, window, 1, 512) in shapes
+    assert (slots, 1, 4096, 128) in shapes and (slots, 3, 3, 4096) in shapes
+    assert not any(s[0] == slots and s[2:] in ((4096, 128), (3, 4096))
+                   and s[1] not in (1, 3) for s in shapes if len(s) == 4)
+    # the step views the state leaf [slots, 1, heads x d_k, d_v] as [slots,
+    # heads, d_k, d_v] for free: with d_k x d_v flattened into the last axis
+    # the compiler re-tiled every state on its way in and out of every step
+    # (a reshape of 33.5 MB a layer each way: kda_state_hbm_pct 21, my chip
+    # run, PR 41)
+    assert not re.findall(r' reshape\([^\n]*op_name="[^"]*kda_state', text)
+    assert not llama.segment_keeps_tail(llama.LlamaConfig(
+        layers=2, layer_kinds=("kda", "latent"), **LING3_FLASH))
+
+
 def test_neither_new_prefill_builds_a_heads_s_s_score(v5e):
     """The solo prefill of the cell's 20480 bucket (five key blocks of
     4096), one block-sparse and one linear layer. ~40 s.

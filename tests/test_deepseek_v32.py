@@ -343,23 +343,38 @@ def test_the_continuous_engine_with_ragged_joiners_counts_exactly(server):
 
 # -- a chip's share of the experts ------------------------------------------------
 
-def _routed_ffn(held, first, shared, tree, x):
+def _routed_ffn(dims, held, first, shared, tree, x):
+    over = registry._llama_overrides(dims)
+    if "layer_kinds" in over:       # a recipe's comma-separated string
+        over["layer_kinds"] = tuple(over["layer_kinds"].split(","))
+    else:
+        over["attn_kind"] = "latent"
     cfg = llama.LlamaConfig(**{
-        **registry._llama_overrides(DIMS), "attn_kind": "latent",
-        "ffn_kind": "routed", "dtype": jnp.float32, "quant": None,
+        **over, "ffn_kind": "routed", "dtype": jnp.float32, "quant": None,
         "moe_experts_held": held, "moe_first_expert": first,
         "n_shared_experts": shared})
     return np.asarray(moe.RoutedMLP(cfg).apply({"params": tree}, x))
 
 
-def test_the_shares_of_a_layer_add_up_to_the_uncut_layer():
-    """The guide's share test. Four chips hold experts 0-3, 4-7, 8-11 and
+@pytest.mark.parametrize("twin", ["rehearsal-dsa", "rehearsal-kda"])
+def test_the_shares_of_a_layer_add_up_to_the_uncut_layer(twin):
+    """The guide's share test, for each model that holds a share (the
+    ``deepseek-v32`` and ``bailing-hybrid`` families' toy twins, groups and
+    top-k at full width). Four chips hold experts 0-3, 4-7, 8-11 and
     12-15 of one routed layer and route over all 16 alike: the routed parts
     of the four shares, plus the shared expert ONCE, are the uncut layer's
     output, which is in turn the plain reference's (the family's ``route``,
     then every picked expert in a numpy loop)."""
+    config = json.loads((REPO / "benchmark" / "configs"
+                         / f"{twin}.json").read_text())
+    family = families.of(config)
+    dims = family.dims_of(config)
     rng = np.random.default_rng(6)
-    h, m, e = CONFIG["hidden_size"], CONFIG["moe_intermediate_size"], EXPERTS
+    h, m, e = dims["hidden"], dims["moe_intermediate"], dims["moe_experts"]
+    assert e == EXPERTS and dims["moe_experts_held"] == 4
+
+    def _ffn(*args):
+        return _routed_ffn(dims, *args)
     full = {
         "router": rng.normal(size=(h, e)).astype(np.float32) / np.sqrt(h),
         "e_score_correction_bias":
@@ -381,11 +396,11 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_layer():
             tree[name] = full[name][first:first + held]
         return tree
 
-    whole = _routed_ffn(0, 0, 1, {**full, **shared}, x)
-    routed = sum(_routed_ffn(4, first, 0, share(first, 4), x)
+    whole = _ffn(0, 0, 1, {**full, **shared}, x)
+    routed = sum(_ffn(4, first, 0, share(first, 4), x)
                  for first in (0, 4, 8, 12))
-    shared_once = _routed_ffn(4, 4, 1, {**share(4, 4), **shared}, x) \
-        - _routed_ffn(4, 4, 0, share(4, 4), x)
+    shared_once = _ffn(4, 4, 1, {**share(4, 4), **shared}, x) \
+        - _ffn(4, 4, 0, share(4, 4), x)
     np.testing.assert_allclose(routed + shared_once, whole, atol=2e-5, rtol=0)
     assert np.abs(routed).max() > 0.1 and np.abs(shared_once).max() > 0.1
 
@@ -393,8 +408,8 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_layer():
     silu = jax.nn.silu
     tokens = np.asarray(x).reshape(-1, h)
     scores = np.asarray(jax.nn.sigmoid(tokens @ full["router"]))
-    chosen, gates = FAMILY.route(jnp.asarray(scores),
-                                 full["e_score_correction_bias"], DIMS)
+    chosen, gates = family.route(jnp.asarray(scores),
+                                 full["e_score_correction_bias"], dims)
     want = np.zeros_like(tokens)
     for t in range(len(tokens)):
         for i, g in zip(np.asarray(chosen[t]), np.asarray(gates[t])):

@@ -57,7 +57,14 @@ PR 39 (an attention kind a LAYER: ``LlamaConfig.layer_kinds``, the
 constructors and the window buckets read a layer's own leaves, three muP
 scalars in block and model) moved NONE of them either: a model without
 ``layer_kinds`` takes every path it took, and the scalars are python
-comparisons against 1.0 that add nothing to a program."""
+comparisons against 1.0 that add nothing to a program.
+
+PR 41 (``latent`` a kind a LAYER may have beside the new ``kda``; the cache
+constructors, ``_kv_store`` and the holders ask the layer's kind, the latent
+path gains an output gate behind ``attn_output_gate``) moved NONE of them
+either, and holds two more models at their cells' own shape keys, as its
+parent lowers them: ``deepseek-v32-exp`` and ``minicpm-sala``
+(``KINDS_GOLDEN``), beside its own ``ling3-flash``."""
 
 import hashlib
 import json
@@ -149,6 +156,51 @@ def test_an_accepted_cells_programs_keep_their_text_at_the_cells_shapes(name):
     join_ops = jax.eval_shape(lambda: server._aot_examples(join_key))[0]
     got = (text_hash(prefill, params, *pre_ops),
            text_hash(server._stream_fns(*join_key[1:])[0], params, *join_ops),
+           text_hash(seg, params, *seg_ops),
+           text_hash(server._windowed_seg_fn(slots, cache_len, window, 16),
+                     params, *seg_ops))
+    assert got == golden
+
+
+# configuration -> (a window bucket its cell decodes in, a solo prompt bucket;
+# hashes of: that solo prefill, the full-window segment, the window-bucketed
+# segment) at the cell's own slots and engine window. ``deepseek-v32-exp``
+# (the sparse latent kind, a chip's share of the experts) and ``minicpm-sala``
+# (kinds a layer) as PR 41's PARENT lowers them (commit 3756d35): PR 41 made
+# ``latent`` a kind a layer may have, gave ``_kv_store`` the layer and the
+# latent path an output gate that these models do not switch on, and moved
+# none of their text. ``ling3-flash`` (kda and latent layers, PR 41) came
+# with that PR and moves with ``models/kda.py`` and the block's latent path
+KINDS_GOLDEN = {
+    "deepseek-v32-exp": (8192, 4096, (
+        "7f9f60f12f08", "fe1dc5bf5b0e", "c8e68c3abb9a")),
+    "minicpm-sala": (16384, 4096, (
+        "83660f79b20c", "28531c5eb28d", "9e6b4880e9e1")),
+    "ling3-flash": (2048, 1024, (
+        "deb0bf1dc4e8", "65c090cd74bc", "117d8445beac")),
+}
+
+
+@pytest.mark.parametrize("name", list(KINDS_GOLDEN))
+def test_a_model_of_newer_kinds_keeps_its_text_at_its_cells_shapes(name):
+    assert LlamaServer._AOT_GEN == "g7", "new generation: take the hashes anew"
+    window, bucket, golden = KINDS_GOLDEN[name]
+    config = json.loads((Path(__file__).parents[1] / "benchmark" / "configs"
+                         / f"{name}.json").read_text())
+    adapter = registry.get(config["model"]).build(
+        dtype=config["precision"]["activations"],
+        quant=config["precision"]["weights"],
+        extra=families.of(config).dims_of(config))
+    params = jax.eval_shape(lambda: adapter.init_params(seed=0))
+    server = adapter.make_server(params)
+    slots = config["recipe_extra"]["batch_max"]
+    cache_len = config["engine_window"]
+    key = ("stream", slots, 16, cache_len, 16)
+    _, seg = server._stream_fns(*key[1:])
+    _, seg_ops = jax.eval_shape(lambda: server._aot_examples(key))
+    solo = ("stream", 1, bucket, cache_len, 16)
+    pre_ops = jax.eval_shape(lambda: server._aot_examples(solo))[0]
+    got = (text_hash(server._stream_fns(*solo[1:])[0], params, *pre_ops),
            text_hash(seg, params, *seg_ops),
            text_hash(server._windowed_seg_fn(slots, cache_len, window, 16),
                      params, *seg_ops))
