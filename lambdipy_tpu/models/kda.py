@@ -23,10 +23,13 @@ With ``a`` the normed input:
 5. ``o <- rmsnorm(o)`` over all ``heads x d`` values (``o_norm``), then the
    block's output gate and ``o_proj``.
 
-A decode step (scopes ``kda_conv``, ``kda_gate``, ``kda_state``) reads the
-state twice and writes it once: one pass takes ``S^T (alpha * k)`` and ``S^T
-(alpha * q)`` together, the second writes ``alpha * S + k u^T``; ``o`` is
-``S^T (alpha * q) + (k . q) u``. A prefill runs the CHUNKED form (scope
+A decode step (scopes ``kda_conv``, ``kda_gate``, ``kda_state``) takes ``S^T
+(alpha * k)`` and ``S^T (alpha * q)`` together, then writes ``alpha * S + k
+u^T``; ``o`` is ``S^T (alpha * q) + (k . q) u``. Where Mosaic compiles, one
+kernel does both in the fast memory and steps the state leaf in place: every
+state read once and written once (``ops/state_step.py``); XLA's form, served
+elsewhere, reads the state twice and writes it once. A prefill runs the
+CHUNKED form (scope
 ``kda_scan``) over chunks of ``KDA_CHUNK`` positions. With ``G_t`` the
 running sum of ``log alpha`` inside a chunk and ``S_0`` the state it begins
 with, the ``u_t`` solve the unit lower-triangular system ``u_t = beta_t [v_t
@@ -57,6 +60,9 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from lambdipy_tpu.models.llama import QDense, RMSNorm, output_gate
+from lambdipy_tpu.ops import kernels_compile_here
+from lambdipy_tpu.ops.state_step import (kernel_fits, stepped_in_place,
+                                         stepped_reference)
 
 NAME = "kda"
 # positions one turn of the prefill's scan takes: a turn's [heads, C, C, d]
@@ -106,6 +112,15 @@ def state_bytes_a_step(cfg) -> int:
     wide = cfg.kda_heads * cfg.kda_head_dim
     return 2 * (4 * wide * cfg.kda_head_dim
                 + (cfg.kda_conv - 1) * 3 * wide * jnp.dtype(cfg.dtype).itemsize)
+
+
+def steps_in_place(cfg) -> bool:
+    """Whether a decode step takes the kernel that steps the state leaf in
+    place, one read and one write of every state (``ops/state_step.py``):
+    where Mosaic compiles, the one thing this code can observe, as
+    ``RoutedMLP`` chooses its experts' kernel."""
+    return kernels_compile_here() and kernel_fits(
+        cfg.kda_heads, cfg.kda_head_dim, cfg.kda_head_dim)
 
 
 def scan_chunks(s: int) -> int:
@@ -192,18 +207,9 @@ def chunked_scan(q, k, v, g, beta, state0=None, chunk: int = 0):
 def step(state, q, k, v, g, beta):
     """One position: ``state`` ``[b, heads, d, d]``, ``q``, ``k``, ``v``,
     ``g`` ``[b, heads, d]``, ``beta`` ``[b, heads]``, float32. Returns ``(o
-    [b, heads, d], the new state)``. Multiply-reduces in float32: 16 rows x
-    32 heads x 128 x 128."""
-    alpha = jnp.exp(g)
-    # (alpha S)^T k and (alpha S)^T q in ONE pass over the state, the decay
-    # folded into the two vectors: a decayed copy of the state would be
-    # written and read back (compiled text for a v5e, PR 41)
-    both = jnp.sum(state[:, :, None]
-                   * (alpha[:, :, None] * jnp.stack([k, q], axis=2))[..., None],
-                   axis=-2)
-    u = beta[..., None] * (v - both[:, :, 0])
-    out = both[:, :, 1] + jnp.sum(k * q, axis=-1, keepdims=True) * u
-    return out, alpha[..., None] * state + k[..., :, None] * u[..., None, :]
+    [b, heads, d], the new state)``: XLA's form, two reads and a write of
+    every state (``ops/state_step.py``)."""
+    return stepped_reference(state, q, k, v, jnp.exp(g), beta)
 
 
 def attend(block, x, positions, mask, cache, lengths):
@@ -266,8 +272,14 @@ def attend(block, x, positions, mask, cache, lengths):
                 q, k, v, jnp.where(live[..., None, None], g, 0.0),
                 jnp.where(live[..., None], beta, 0.0))
         else:
-            out, state = step(cache["state"].reshape(b, heads, d, d),
-                              q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+            if steps_in_place(cfg):
+                out, state = stepped_in_place(
+                    cache["state"], q[:, 0], k[:, 0], v[:, 0],
+                    jnp.exp(g[:, 0]), beta[:, 0])
+            else:
+                out, state = step(cache["state"].reshape(b, heads, d, d),
+                                  q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                  beta[:, 0])
             out = out[:, None]
         out = RMSNorm(cfg.norm_eps, name="o_norm")(out.reshape(b, s, wide))
         out = out.astype(cfg.dtype)

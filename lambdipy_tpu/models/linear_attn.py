@@ -1,12 +1,17 @@
 """Attention kind ``linear``: Lightning linear attention, a layer's module.
 
 A layer of this kind keeps NO row a token. Its cache entry is one leaf,
-``state`` ``[rows, 1, heads, d x d]`` float32: a head's running sum
+``state`` ``[rows, 1, heads x d, d]`` float32 (a head's ``[d_k, d_v]`` matrix
+with its ``d_v`` axis last, as ``models/kda.py`` keeps its state and for its
+reason: the step's ``[rows, heads, d_k, d_v]`` view is the leaf's own
+tiling): a head's running sum
 ``S_t = lambda S_t-1 + k_t^T v_t`` with a fixed decay a head, ``lambda_i =
 exp(-2^(-8 i / heads))`` (``i`` = 1 .. heads: Lightning Attention's
 data-independent schedule), and a token's output is ``d^-1/2 q_t S_t``. A
 decode step reads the state, scales it, adds one outer product, writes it and
-takes one ``q S`` (scope ``lin_state``); a prefill runs the CHUNKED form
+takes one ``q S`` (scope ``lin_state``; where Mosaic compiles, the kernel of
+``ops/state_step.py`` in place: its step with the delta rule off and a head's
+decay); a prefill runs the CHUNKED form
 (scope ``lin_scan``): inside a chunk of ``LIN_CHUNK`` positions ``(Q K^T * D)
 V`` with ``D_ts = lambda^(t-s)`` for ``s <= t``, across chunks ``Lambda Q
 S_prev`` and ``S_next = lambda^C S_prev + (K * lambda^(C-1-s))^T V``: one scan
@@ -29,6 +34,8 @@ import jax
 import jax.numpy as jnp
 
 from lambdipy_tpu.models.llama import QDense, RMSNorm, rope
+from lambdipy_tpu.ops import kernels_compile_here
+from lambdipy_tpu.ops.state_step import kernel_fits, stepped_in_place
 
 NAME = "linear"
 # positions one turn of the prefill's scan takes: the [heads, C, C] float32
@@ -44,7 +51,7 @@ def validate(cfg) -> None:
 
 
 def cache_layout(cfg) -> dict:
-    return {"state": (cfg.lin_heads, cfg.lin_head_dim * cfg.lin_head_dim)}
+    return {"state": (cfg.lin_heads * cfg.lin_head_dim, cfg.lin_head_dim)}
 
 
 def cache_positions(cfg, max_len: int) -> dict:
@@ -67,6 +74,14 @@ def refusal(cfg, holder: str) -> str:
             f"{cfg.lin_head_dim} x {cfg.lin_head_dim} float32) with no "
             "position axis: a span of positions is no slice of it (PERF.md "
             "section 7)")
+
+
+def steps_in_place(cfg) -> bool:
+    """Whether a decode step takes the kernel that steps the state leaf in
+    place (``ops/state_step.py``): where Mosaic compiles, the one thing this
+    code can observe, as ``RoutedMLP`` chooses its experts' kernel."""
+    return kernels_compile_here() and kernel_fits(
+        cfg.lin_heads, cfg.lin_head_dim, cfg.lin_head_dim)
 
 
 def slopes(heads: int):
@@ -161,13 +176,18 @@ def attend(block, x, positions, mask, cache, lengths):
                 lengths = jnp.full((b,), s, jnp.int32)
             out, state = chunked_scan(q, k, v, lengths)
         else:
-            lam = jnp.exp(-slopes(heads))[None, :, None, None]
-            k32, v32 = (a[:, 0].astype(jnp.float32) for a in (k, v))
-            state = lam * cache["state"].reshape(b, heads, d, d) \
-                + k32[..., :, None] * v32[..., None, :]
-            # a multiply-reduce in float32: 8 rows x 32 heads x 128 x 128
-            out = jnp.sum(q[:, 0].astype(jnp.float32)[..., :, None] * state,
-                          axis=-2)[:, None] * jnp.float32(d ** -0.5)
+            lam = jnp.exp(-slopes(heads))
+            q32, k32, v32 = (a[:, 0].astype(jnp.float32) for a in (q, k, v))
+            if steps_in_place(cfg):
+                out, state = stepped_in_place(cache["state"], q32, k32, v32,
+                                              lam)
+            else:
+                state = lam[None, :, None, None] \
+                    * cache["state"].reshape(b, heads, d, d) \
+                    + k32[..., :, None] * v32[..., None, :]
+                # a multiply-reduce in float32: 8 rows x 32 heads x 128 x 128
+                out = jnp.sum(q32[..., :, None] * state, axis=-2)
+            out = out[:, None] * jnp.float32(d ** -0.5)
         if cfg.lin_output_norm:
             out = RMSNorm(cfg.norm_eps, name="o_norm")(out)
         out = out.astype(cfg.dtype).reshape(b, s, heads * d)
@@ -175,4 +195,4 @@ def attend(block, x, positions, mask, cache, lengths):
         with jax.named_scope("qkv_proj"):
             out = out * jax.nn.sigmoid(QDense(
                 heads * d, cfg.quant, cfg.dtype, name="out_gate_proj")(h))
-    return out, {"state": state.reshape(b, 1, heads, d * d)}
+    return out, {"state": state.reshape(b, 1, heads * d, d)}

@@ -469,6 +469,19 @@ class LlamaConfig:
         return attn_kind_module("kda").state_bytes_a_step(self) \
             if self.kda_layers else 0
 
+    @property
+    def kda_steps_in_place(self) -> bool:
+        """Whether the kda layers' decode step takes the kernel that steps
+        the state in place (``ops/state_step.py``): the layers' own choice."""
+        return bool(self.kda_layers) and attn_kind_module(
+            "kda").steps_in_place(self)
+
+    @property
+    def linear_steps_in_place(self) -> bool:
+        """Whether the linear layers' decode step takes that kernel."""
+        return "linear" in tuple(self.layer_kinds) and attn_kind_module(
+            "linear").steps_in_place(self)
+
     def kda_scan_chunks(self, rows: int, s: int) -> int:
         """Chunks the kda layers' chunked form scans in ONE prefill of
         ``rows`` rows padded to ``s`` positions (``handler.kda``)."""
@@ -3431,8 +3444,11 @@ class LlamaServer:
     # 38: the set of names changes (``seg_w`` has one) and a server saves
     # at first use, so an executable is trusted by its NAME in many more
     # places: from here on a change of a window-bucketed segment's text
-    # bumps this too.
-    _AOT_GEN = "g7"
+    # bumps this too. g8 = PR 42: on a TPU a kda or linear layer's decode
+    # step is a Mosaic call that steps the state leaf in place
+    # (ops/state_step.py), and the linear state leaf is [slots, 1, heads x
+    # d, d]: an executable of the old text would take the old leaf.
+    _AOT_GEN = "g8"
 
     @classmethod
     def aot_prefix(cls) -> str:

@@ -287,13 +287,15 @@ class ContinuousBatcher:
         # inside dense_len and the compressed keys they wrote
         # (llama._scan_decode count_sala; /metrics handler.sala)
         self.sala_stats = SalaKeyStats(
-            step_state_bytes=getattr(cfg, "state_bytes_a_step", 0))
+            step_state_bytes=getattr(cfg, "state_bytes_a_step", 0),
+            state_kernel=bool(getattr(cfg, "linear_steps_in_place", False)))
         self._counts_sala = bool(getattr(cfg, "counts_sala_keys", False))
         # a model with kda layers: the layer-steps its booked rows took and
         # the chunks its prefills scanned, from shapes (/metrics handler.kda)
         self.kda_stats = KdaStats(
             layers=int(getattr(cfg, "kda_layers", 0)),
-            layer_bytes=int(getattr(cfg, "kda_step_bytes", 0)))
+            layer_bytes=int(getattr(cfg, "kda_step_bytes", 0)),
+            state_kernel=bool(getattr(cfg, "kda_steps_in_place", False)))
         self._routed_layers = (cfg.layers - cfg.first_dense_layers
                                if getattr(cfg, "counts_moe_load", False)
                                else 0)

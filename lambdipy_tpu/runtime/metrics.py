@@ -211,9 +211,13 @@ class SalaKeyStats:
     ``sparse_dense_len``; ``kc_writes``: the compressed keys written (one
     every ``sparse_stride``-th step a row); ``state_bytes``: the recurrent
     state the linear layers read and wrote (``step_state_bytes`` a row-step:
-    ``LlamaConfig.state_bytes_a_step``)."""
+    ``LlamaConfig.state_bytes_a_step``); ``kernel_row_steps``: the row-steps
+    whose linear states the kernel stepped in place (``state_kernel``:
+    ``LlamaConfig.linear_steps_in_place``, the layers' own static choice):
+    ``row_steps`` where Mosaic compiles, 0 elsewhere."""
 
     step_state_bytes: int = 0
+    state_kernel: bool = False
     row_steps: int = 0
     keys_attended: int = 0
     keys_visible: int = 0
@@ -239,7 +243,8 @@ class SalaKeyStats:
                     "keys_visible": self.keys_visible,
                     "dense_steps": self.dense_steps,
                     "kc_writes": self.kc_writes,
-                    "state_bytes": self.row_steps * self.step_state_bytes}
+                    "state_bytes": self.row_steps * self.step_state_bytes,
+                    "kernel_row_steps": self.row_steps * self.state_kernel}
 
 
 @dataclass
@@ -251,10 +256,14 @@ class KdaStats:
     scanned, every row and kda layer of every prefill program run
     (``LlamaConfig.kda_scan_chunks``). ``state_bytes``: the state and conv
     tail those layer-steps read and wrote, once each way (``layer_bytes`` a
-    row's step in one layer: ``models/kda.py state_bytes_a_step``)."""
+    row's step in one layer: ``models/kda.py state_bytes_a_step``).
+    ``kernel_row_steps``: the layer-steps whose state the kernel stepped in
+    place (``state_kernel``: ``LlamaConfig.kda_steps_in_place``, the layers'
+    own static choice): ``row_steps`` where Mosaic compiles, 0 elsewhere."""
 
     layers: int = 0
     layer_bytes: int = 0
+    state_kernel: bool = False
     row_steps: int = 0
     scan_chunks: int = 0
     _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
@@ -271,7 +280,8 @@ class KdaStats:
         with self._lock:
             return {"row_steps": self.row_steps,
                     "scan_chunks": self.scan_chunks,
-                    "state_bytes": self.row_steps * self.layer_bytes}
+                    "state_bytes": self.row_steps * self.layer_bytes,
+                    "kernel_row_steps": self.row_steps * self.state_kernel}
 
 
 @dataclass

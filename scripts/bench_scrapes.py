@@ -3,7 +3,10 @@ counters over it, which no benchmark metric reads: garbage segments a request
 (``wasted_overdecode_tokens``), the drains by cause (``handover``, ``joiner``,
 ``complete``), the in-flight histogram, ``rows_per_segment`` untraced, and
 ``sched`` ``running`` / ``queued`` / ``granted_ahead`` in the middle of the
-window. It wraps ``python3 -m benchmark.run`` (same arguments after ``--``,
+window, and of a model with recurrent states the row-steps its states took
+and how many of them the in-place kernel stepped (``handler.kda`` /
+``handler.sala`` ``row_steps``, ``kernel_row_steps``: equal on the chip, the
+second 0 on a CPU). It wraps ``python3 -m benchmark.run`` (same arguments after ``--``,
 same result line) and changes nothing under ``benchmark/``: the harness's two
 scrapes are kept and one more is made mid-window.
 
@@ -33,7 +36,8 @@ from pathlib import Path
 
 def pick(metrics: dict) -> dict:
     """The blocks of one ``/metrics`` document this script keeps."""
-    batching = (metrics.get("handler") or {}).get("batching") or {}
+    handler = metrics.get("handler") or {}
+    batching = handler.get("batching") or {}
     spans = {k: {"count": v.get("count"), "sum_s": v.get("sum_s")}
              for k, v in (metrics.get("spans") or {}).items()
              if k.startswith(("eng.", "req."))}
@@ -41,6 +45,9 @@ def pick(metrics: dict) -> dict:
                 "segments_run", "rows_in_segments", "requests_served",
                 "prefill_groups", "rows_group_prefilled", "pipeline")
                 if k in batching},
+            "states": {kind: {k: handler[kind].get(k) for k in (
+                "row_steps", "kernel_row_steps")}
+                for kind in ("kda", "sala") if kind in handler},
             "sched": metrics.get("sched"), "spans": spans,
             "peak_bytes": [d.get("peak_bytes_in_use") for d in (
                 metrics.get("device") or {}).get("memory", [])]}
@@ -66,6 +73,9 @@ def deltas(opened: dict, closed: dict) -> dict:
             "wasted_overdecode_tokens": wasted,
             "wasted_a_request": round(wasted / max(served, 1), 2),
             "prefill_groups": d(b0, b1, "prefill_groups"),
+            "states": {kind: {k: d(opened["states"].get(kind, {}), now, k)
+                              for k in now}
+                       for kind, now in closed["states"].items()},
             "drains": dd("drains"), "in_flight": dd("in_flight")}
 
 

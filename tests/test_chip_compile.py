@@ -136,6 +136,29 @@ def test_picked_experts_compiles(v5e, tokens):
                  *stack(EXPERT_MLP, EXPERT_HIDDEN))
 
 
+@pytest.mark.parametrize("rows,kind", [(16, "kda"), (8, "lightning")])
+def test_stepped_in_place_compiles(v5e, rows, kind):
+    """The recurrent-state step at the two cells' shapes, 32 heads of 128 x
+    128: ``ling3-flash``'s 16 rows (the delta rule, a channel's decay) and
+    ``minicpm-sala``'s 8 (neither). A row's states are 2 MB each way, twice
+    for the pipeline; the state operand is the result's buffer."""
+    from lambdipy_tpu.ops.state_step import stepped_in_place
+
+    heads, d = 32, 128
+    vec = ((rows, heads, d), jnp.float32)
+    shapes = [((rows, 1, heads * d, d), jnp.float32), vec, vec, vec] + (
+        [vec, ((rows, heads), jnp.float32)] if kind == "kda"
+        else [((heads,), jnp.float32)])
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+            for shape, dtype in shapes]
+    text = jax.jit(stepped_in_place, donate_argnums=0).lower(
+        *args).compile().as_text()
+    call = next(ln for ln in text.splitlines() if " custom-call(" in ln
+                and "tpu_custom_call" in ln)
+    assert re.search(r"output_to_operand_aliasing=\{\{1\}: \(\d, \{\}\)\}",
+                     call), call[:400]
+
+
 def _decode_segment_text(chip, *, steps, window=512, cache_len=T, layers=1,
                          rows=B, **widths):
     """Optimized HLO of the decode segment (`jit_seg`, the window-bucketed
@@ -309,6 +332,28 @@ def cache_writes_in_loops(text: str, leaf_shapes: set) -> list:
             found.append((op.group(1), op.group(3),
                           name.group(1) if name else ""))
     return found
+
+
+def state_kernel_calls(text: str, leaf: tuple, scope: str) -> list:
+    """The lines of ``text`` that are a Mosaic call of ``ops/state_step.py``
+    under ``scope``, each asserted to step a state leaf of shape ``leaf`` in
+    place: the leaf is an operand in HBM and the result's buffer."""
+    calls = [ln for ln in text.splitlines() if " custom-call(" in ln
+             and "tpu_custom_call" in ln and "stepped_in_place" in ln]
+    dims = ",".join(str(n) for n in leaf)
+    for line in calls:
+        assert f"/{scope}/stepped_in_place" in line, line[:600]
+        result = line.split(" custom-call(")[0]
+        assert re.search(rf"f32\[{dims}\]\{{3,2,1,0:T\(8,128\)\}}\)$",
+                         result), result       # home in HBM, not S(1)
+        aliased = re.search(
+            r"output_to_operand_aliasing=\{\{1\}: \((\d+), \{\}\)\}", line)
+        constraints = re.search(
+            r"operand_layout_constraints=\{(.*?)\}, output_to_operand",
+            line).group(1).split("}, ")
+        assert aliased and constraints[int(aliased.group(1))].startswith(
+            f"f32[{dims}]"), line[:900]
+    return calls
 
 
 @pytest.mark.parametrize("widths,layers,window,cache_len", [
@@ -599,7 +644,8 @@ SALA_KINDS = ("sparse_kv",) + ("linear",) * 6 + ("sparse_kv",) * 2 \
     + ("linear",) * 4 + ("sparse_kv",) + ("linear",) * 2
 
 
-def test_a_sala_segment_compiles_and_what_it_does_with_its_leaves(v5e):
+def test_a_sala_segment_compiles_and_what_it_does_with_its_leaves(
+        v5e, monkeypatch):
     """The engine's segment at ``minicpm-sala.long-document``'s own shape key
     (8 slots of 32768, the full window, 16 layers of kinds a layer, 16
     steps), lowered as a TPU backend lowers it. ~40 s.
@@ -618,12 +664,20 @@ def test_a_sala_segment_compiles_and_what_it_does_with_its_leaves(v5e):
     ``sala_compress`` with the compressed keys' shape, each aliasing its
     operand; nothing copies a K/V leaf home. A linear layer's state (8 x
     2.1 MB a layer) is a carry that is rewritten whole every step by
-    nature: the compiler moves it between HBM and the fast memory
-    (``copy-done``), at most three such moves a layer a step, which is the
-    read and the write the recurrence needs and no more than a third of a
-    step's 0.4 GB on top."""
+    nature. With the step XLA's (PR 39 to PR 41) the compiler moved it
+    between HBM and the fast memory (``copy-done``), up to three such moves
+    a layer a step beside a ``reshape`` of the leaf ``[slots, 1, heads, d x
+    d]``, and with the leaf as it is now it writes ``v`` broadcast to the
+    state's shape besides (PERF.md section 6, PR 42). With the kernel of
+    ``ops/state_step.py`` (PR 42: what a TPU backend lowers) ONE Mosaic call
+    a linear layer under ``lin_state`` steps the leaf in place, its state
+    operand aliased to its result, and nothing else in the loop has the
+    leaf's shape in either view: no ``copy-done``, no ``reshape``."""
     from benchmark.families import minicpm_sala
-    from lambdipy_tpu.models import llama
+    from lambdipy_tpu.models import linear_attn, llama
+
+    # (this process's backend is the CPU: say what a TPU's would)
+    monkeypatch.setattr(linear_attn, "kernels_compile_here", lambda: True)
 
     layers, slots, window = 16, 8, 32768
     text = _decode_segment_text(v5e, steps=16, layers=layers, window=window,
@@ -648,10 +702,10 @@ def test_a_sala_segment_compiles_and_what_it_does_with_its_leaves(v5e):
         line = next(ln for ln in text.splitlines()
                     if f"%{name} = " in ln)
         assert '"aliasing_operands"' in line, name
-    states = cache_writes_in_loops(text, {(slots, 1, 32, 128 * 128)})
-    moves = [op for _, op, _ in states if op == "copy-done"]
-    assert {op for _, op, _ in states} <= {"copy-done", "reshape"}
-    assert len(moves) <= 3 * 12
+    leaf = (slots, 1, 32 * 128, 128)
+    states = cache_writes_in_loops(text, {leaf, (slots, 32, 128, 128)})
+    assert len(state_kernel_calls(text, leaf, "lin_state")) == 12
+    assert not states, states
     assert not llama.segment_keeps_tail(llama.LlamaConfig(
         layers=layers, layer_kinds=SALA_KINDS, **MINICPM_SALA))
 
@@ -682,10 +736,11 @@ def test_a_kda_segment_compiles_with_its_two_leaves_and_the_latent_rows(
     the latent layer's ``ckv`` and ``kpe`` alone: no operation of the loop
     has a kda leaf's shape at another length than its own."""
     from benchmark.families import bailing_hybrid
-    from lambdipy_tpu.models import llama, moe
+    from lambdipy_tpu.models import kda, llama, moe
 
     # (this process's backend is the CPU: say what a TPU's would)
     monkeypatch.setattr(moe, "kernels_compile_here", lambda: True)
+    monkeypatch.setattr(kda, "kernels_compile_here", lambda: True)
     slots, window = 16, 8192
     text = _decode_segment_text(v5e, steps=16, layers=2, window=2048,
                                 cache_len=window, rows=slots,
@@ -694,7 +749,7 @@ def test_a_kda_segment_compiles_with_its_two_leaves_and_the_latent_rows(
     for op_name in re.findall(r'op_name="([^"]*)"', text):
         found.update(op_name.split("/"))
     assert set(bailing_hybrid.SCOPES) - {"kda_scan"} <= found
-    assert "tpu_custom_call" in text            # the picked-experts kernel
+    assert "picked_experts" in text             # the experts' kernel
     shapes = {tuple(int(n) for n in dims.split(","))
               for dims in re.findall(r"\w+\[([\d,]+)\]", text)}
     assert (slots, 2048, 1, 512) in shapes and (slots, window, 1, 512) in shapes
@@ -707,6 +762,13 @@ def test_a_kda_segment_compiles_with_its_two_leaves_and_the_latent_rows(
     # (a reshape of 33.5 MB a layer each way: kda_state_hbm_pct 21, my chip
     # run, PR 41)
     assert not re.findall(r' reshape\([^\n]*op_name="[^"]*kda_state', text)
+    # ONE Mosaic call a kda layer under kda_state steps the leaf in place
+    # (ops/state_step.py, PR 42): its state operand is its result's buffer,
+    # and no other operation of the loop has the leaf's shape in either
+    # view: no second read, no reshape, no copy-done of it
+    leaf = (slots, 1, 4096, 128)
+    assert len(state_kernel_calls(text, leaf, "kda_state")) == 1
+    assert not cache_writes_in_loops(text, {leaf, (slots, 32, 128, 128)})
     assert not llama.segment_keeps_tail(llama.LlamaConfig(
         layers=2, layer_kinds=("kda", "latent"), **LING3_FLASH))
 

@@ -250,6 +250,8 @@ def test_the_engine_serves_kda_and_latent_layers_and_counts_both(adapter,
     row_steps = stats["rows_in_segments"] * 8
     assert counted["row_steps"] == row_steps * 6
     assert counted["state_bytes"] == counted["row_steps"] * LAYER_BYTES
+    # no Mosaic here: no layer-step's state went through the in-place kernel
+    assert counted["kernel_row_steps"] == 0 and not cfg.kda_steps_in_place
     assert cfg.kda_step_bytes == LAYER_BYTES
     assert cfg.state_bytes_a_step == 6 * LAYER_BYTES
     # every prefill here was one row: a prompt bucket's chunks of 16, 6 layers

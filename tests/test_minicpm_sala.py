@@ -255,7 +255,7 @@ def test_the_engine_serves_a_cache_whose_leaves_differ_by_layer(adapter,
     assert shapes[0] == {"k": ((3, 64, 2, 16), jnp.float32),
                          "v": ((3, 64, 2, 16), jnp.float32),
                          "kc": ((3, 32, 2, 16), jnp.float32)}
-    assert shapes[1] == {"state": ((3, 1, 8, 256), jnp.float32)}
+    assert shapes[1] == {"state": ((3, 1, 128, 16), jnp.float32)}
     assert [list(s) for s in shapes] == [
         ["k", "v", "kc"], ["state"], ["state"], ["state"], ["k", "v", "kc"],
         ["state"]]
@@ -285,6 +285,8 @@ def test_the_engine_serves_a_cache_whose_leaves_differ_by_layer(adapter,
     stats, sala = eng.stats(), eng.sala_stats.report()
     assert sala["row_steps"] == stats["rows_in_segments"] * 8
     assert sala["state_bytes"] == sala["row_steps"] * 4 * 2 * 4 * 8 * 16 * 16
+    # no Mosaic here: no row-step's states went through the in-place kernel
+    assert sala["kernel_row_steps"] == 0 and not cfg.linear_steps_in_place
     assert 0 < sala["dense_steps"] < sala["row_steps"]
     # a row writes a compressed key every second step
     assert abs(2 * sala["kc_writes"] - sala["row_steps"]) <= 2 * len(prompts)
@@ -313,7 +315,7 @@ def test_the_description_is_what_the_constructors_read(adapter):
     assert (cfg.first_layer_of("sparse_kv"), cfg.first_layer_of("linear"),
             cfg.first_layer_of("eva")) == (0, 1, -1)
     assert cfg.cache_layout(0) == {"k": (2, 16), "v": (2, 16), "kc": (2, 16)}
-    assert cfg.cache_layout(1) == {"state": (8, 256)}
+    assert cfg.cache_layout(1) == {"state": (128, 16)}
     assert cfg.cache_positions(100, 0) == {"k": 100, "v": 100, "kc": 50}
     assert cfg.cache_positions(100, 1) == {"state": 1}
     assert cfg.cache_dtypes(1) == {"state": jnp.float32}
