@@ -406,10 +406,11 @@ class LlamaConfig:
             # linear one a scan over chunks: whole blocks (a 20k prompt
             # must not pad to 32k)
             return -(-s // SALA_PROMPT_BLOCK) * SALA_PROMPT_BLOCK
-        if self.index_topk and s > DSA_KEY_BLOCK:
-            # a sparse prefill runs one body a block of keys (its queries
-            # score the keys up to their own block's end): whole blocks
-            return -(-s // DSA_KEY_BLOCK) * DSA_KEY_BLOCK
+        if self.index_topk and s > DSA_PROMPT_BLOCK:
+            # a sparse prefill's cost follows the prompt, not the bucket (it
+            # runs no turn of queries that holds only padding): whole
+            # blocks, a program each
+            return -(-s // DSA_PROMPT_BLOCK) * DSA_PROMPT_BLOCK
         return _next_bucket(s, lo)
 
     @property
@@ -481,6 +482,15 @@ class LlamaConfig:
         """Whether the linear layers' decode step takes that kernel."""
         return "linear" in tuple(self.layer_kinds) and attn_kind_module(
             "linear").steps_in_place(self)
+
+    def dsa_prefill_pairs(self, lengths, rows: int, s: int) -> tuple:
+        """``(run, causal)``: the query-key pairs, a layer, that the turns
+        of ONE sparse prefill of ``rows`` rows padded to ``s`` positions are
+        given (every row runs the turns the longest needs), and those that
+        causality needs of the real rows' ``lengths`` (``handler.dsa``)."""
+        run = sum(live * min(s, DSA_QUERY_BLOCK) * t
+                  for _, t, live in dsa_prefill_turns(max(lengths), s))
+        return rows * run, sum(n * (n + 1) // 2 for n in lengths)
 
     def kda_scan_chunks(self, rows: int, s: int) -> int:
         """Chunks the kda layers' chunked form scans in ONE prefill of
@@ -1008,24 +1018,52 @@ def _eva_prefill_attend(q, k, v, sk, sv, mask, win: int, chunk: int):
     return jnp.moveaxis(out, 0, 1).reshape(b, n_win * win, h, d)[:, :s]
 
 
-# A sparse (DeepSeek Sparse Attention) prefill runs one body a block of
-# DSA_QUERY_BLOCK queries (``lax.map``) inside each block of DSA_KEY_BLOCK
-# keys: a query block scores, selects among and attends the keys up to its
-# own key block's end, so a prompt of n key blocks costs n (n + 1) / 2 of
-# them, not n x n. Prompts past one key block prefill at whole key blocks
-# (``LlamaConfig.prompt_bucket``). Inside a turn the heads (the indexer's,
-# then the attention's) go a group at a time, so that no float32 score
-# tensor ``[heads of a group, queries, keys]`` is larger than
-# DSA_SCORE_BYTES: what the v5e compiler keeps in its fast memory through
-# every pass of the softmax (``tests/test_chip_compile.py``: 24 MiB it
-# keeps, 32 it spills); whole, 128 heads x 128 x 12288 float32 scores are
-# 0.8 GB a turn. 128 queries a turn is what this tree's served runs were
-# made at; alone on the chip (zero weights, no server) the 12288 prefill of
-# a routed layer took 0.337 s at 128 queries a turn, 0.318 at 256 and 0.312
-# at 512 (PERF.md section 6, PR 35, which also says why 128 stayed).
+# A sparse (DeepSeek Sparse Attention) prefill runs one body a turn of
+# DSA_QUERY_BLOCK queries inside each block of DSA_KEY_BLOCK keys: a turn
+# scores, selects among and attends the keys up to its own key block's end,
+# so a prompt of n key blocks is given n (n + 1) / 2 of them, not n x n; and
+# a key block runs only the turns that BEGIN before the last real token of
+# the longest row (a loop whose trip count is the rows' length operand's):
+# the turns that hold nothing but a bucket's padding are not run, and their
+# outputs, which no real position reads, are zeros (:func:`dsa_prefill_turns`,
+# the iteration space, which the engine's counter reads too). Two constants
+# on purpose. DSA_KEY_BLOCK, the loop's, is ``index_topk`` of the model
+# served: a key block is the overhang of a turn's keys over its causal
+# frontier, so the smaller the less is run that causality hides, and the
+# first block (no more keys than the selection takes) runs neither indexer
+# score nor selection. DSA_PROMPT_BLOCK, the bucket's, is what prompts past
+# it are padded to whole multiples of (``LlamaConfig.prompt_bucket``): a
+# program a bucket, so the coarser the fewer programs; what it pads, the
+# loop does not run. Inside a turn the heads (the indexer's, then the
+# attention's) go a group at a time, so that no float32 score tensor
+# ``[heads of a group, queries, keys]`` is larger than DSA_SCORE_BYTES: what
+# the v5e compiler keeps in its fast memory through every pass of the
+# softmax (``tests/test_chip_compile.py``: 24 MiB it keeps, 32 it spills);
+# whole, 128 heads x 128 x 12288 float32 scores are 0.8 GB a turn. 128
+# queries a turn is what this tree's served runs were made at; alone on the
+# chip (zero weights, no server) the 12288 prefill of a routed layer took
+# 0.337 s at 128 queries a turn, 0.318 at 256 and 0.312 at 512 (PERF.md
+# section 6, PR 35, which also says why 128 stayed; PR 43 for the blocks).
 DSA_QUERY_BLOCK = 128
-DSA_KEY_BLOCK = 4096
+DSA_KEY_BLOCK = 2048
+DSA_PROMPT_BLOCK = 4096
 DSA_SCORE_BYTES = 24 << 20
+
+
+def dsa_prefill_turns(longest, s: int, clip=None):
+    """The iteration space of a sparse prefill padded to ``s`` positions
+    whose longest row has ``longest`` real tokens: for each key block
+    ``(at, t, turns run)``: the queries ``at .. t - 1`` go, DSA_QUERY_BLOCK
+    a turn, against the keys ``0 .. t - 1``, and only the turns that begin
+    before position ``longest`` are run. ``longest`` is an int (the engine's
+    counter) or a traced scalar with ``clip=jnp.clip`` (the program's trip
+    counts): one arithmetic for both."""
+    block = min(s, DSA_QUERY_BLOCK)
+    clip = clip or (lambda x, lo, hi: max(lo, min(x, hi)))
+    for at in range(0, s, DSA_KEY_BLOCK):
+        t = min(at + DSA_KEY_BLOCK, s)
+        yield at, t, clip(-(-(longest - at) // block), 0,
+                          -(-(t - at) // block))
 
 
 def _head_group(heads: int, elements_a_head: int) -> int:
@@ -1066,15 +1104,21 @@ def _dsa_scores(q_idx, w_idx, k_idx):
 
 def _dsa_block_attend(q, k_groups, v_groups, seen, scale, dtype):
     """A block of queries under its selection: ``q`` ``[b, block, heads,
-    d]``; ``k_groups`` / ``v_groups`` ``[groups, b, t, heads a group, d]``
-    (the expanded keys and values, regrouped once a key block); ``seen``
-    ``[b, block, t]`` bool. One float32 softmax a head over the seen keys,
-    a group of heads a turn. ``[b, block, heads, v width]``."""
-    groups, b, t, group, _ = k_groups.shape
-    block = q.shape[1]
+    d]``; ``k_groups`` / ``v_groups`` ``[groups, b, t or more, heads a
+    group, d]`` (the expanded keys and values, regrouped once a size of
+    group: a turn reads the first ``t`` where they lie); ``seen`` ``[b,
+    block, t]`` bool. One float32 softmax a head over the seen keys, a
+    group of heads a turn. ``[b, block, heads, v width]``."""
+    groups, b, _, group, _ = k_groups.shape
+    block, t = q.shape[1], seen.shape[-1]
 
     def turn(args):
-        q_g, k_g, v_g = args
+        g, q_g = args
+        # (one dynamic slice of the extent read: a slice of a whole group
+        # would be a copy of it a turn)
+        k_g, v_g = (jax.lax.dynamic_slice(
+            x, (g, 0, 0, 0, 0), (1, b, t, group, x.shape[-1]))[0]
+            for x in (k_groups, v_groups))
         logits = jnp.einsum("bqhd,bthd->bhqt", q_g, k_g,
                             preferred_element_type=jnp.float32) * scale
         logits = jnp.where(seen[:, None], logits, jnp.float32(-1e9))
@@ -1084,8 +1128,8 @@ def _dsa_block_attend(q, k_groups, v_groups, seen, scale, dtype):
     q_groups = jnp.moveaxis(q.reshape(b, block, groups, group, q.shape[-1]),
                             2, 0)
     if groups == 1:
-        return turn((q_groups[0], k_groups[0], v_groups[0]))
-    out = jax.lax.map(turn, (q_groups, k_groups, v_groups))
+        return turn((0, q_groups[0]))
+    out = jax.lax.map(turn, (jnp.arange(groups), q_groups))
     return jnp.moveaxis(out, 0, 2).reshape(b, block, groups * group, -1)
 
 
@@ -1265,7 +1309,7 @@ class LlamaBlock(nn.Module):
                                          lengths)
         elif spec.attn == "latent":
             out, new_cache = self._latent_attend(x, positions, mask, cache,
-                                                 band)
+                                                 band, lengths)
         elif spec.attn == "eva":
             out, new_cache = self._eva_attend(x, positions, mask, cache,
                                               lengths)
@@ -1315,7 +1359,8 @@ class LlamaBlock(nn.Module):
                                       name="down_proj")(nn.silu(gate) * up))
         return x, new_cache
 
-    def _latent_attend(self, x, positions, mask, cache, band: int):
+    def _latent_attend(self, x, positions, mask, cache, band: int,
+                       lengths=None):
         """Multi-head latent attention: returns the heads' outputs
         ``[b, s, heads, v_head]`` and the new cache entry, the latent
         ``ckv`` (after its norm) and the one rotary key ``kpe`` (after
@@ -1391,7 +1436,8 @@ class LlamaBlock(nn.Module):
                 q = jnp.concatenate([q_nope, q_pe], axis=-1)
             if cfg.index_topk:
                 out = self._sparse_prefill_attend(q, k, kv[..., dn:], q_idx,
-                                                  k_idx[:, :, 0], w_idx, mask)
+                                                  k_idx[:, :, 0], w_idx, mask,
+                                                  lengths)
                 return out, {"ckv": ckv, "kpe": k_pe, "kidx": k_idx}
             with jax.named_scope("attend"):
                 causal = jnp.tril(jnp.ones((s, s), dtype=jnp.bool_))
@@ -1476,40 +1522,58 @@ class LlamaBlock(nn.Module):
             * jnp.float32((n_idx * d_idx) ** -0.5)
         return q_idx, k_idx, w_idx
 
-    def _sparse_prefill_attend(self, q, k, v, q_idx, k_idx, w_idx, mask):
+    def _sparse_prefill_attend(self, q, k, v, q_idx, k_idx, w_idx, mask,
+                               lengths=None):
         """A sparse prefill's attention over the expanded keys ``k`` and
-        values ``v`` ``[b, s, heads, ..]``: ONE body runs a block of
-        ``DSA_QUERY_BLOCK`` queries a turn inside each block of
-        ``DSA_KEY_BLOCK`` keys: it scores the keys up to the key block's end
-        (``q_idx``, ``w_idx``; ``k_idx`` ``[b, s, d]``), selects
+        values ``v`` ``[b, s, heads, ..]``: ONE body runs a turn of
+        ``DSA_QUERY_BLOCK`` queries inside each block of ``DSA_KEY_BLOCK``
+        keys: it scores the keys up to the key block's end (``q_idx``,
+        ``w_idx``; ``k_idx`` ``[b, s, d]``), selects
         (:func:`_dsa_select_mask`) and attends under the selection's mask,
         a group of heads at a time (:func:`_dsa_block_attend`). ``[b, s,
-        heads, v width]``."""
+        heads, v width]``.
+
+        What is run (:func:`dsa_prefill_turns`): in a key block, the turns
+        that begin before the longest row's last real token (``lengths``
+        ``[b]``, each right-padded row's; without it, a row ends where its
+        ``mask`` does), each against the keys up to its own key block's end.
+        What is not: a turn that holds only padding, hence a key block past
+        the prompt, and the keys past a turn's key block (causally hidden
+        from every query of it). A turn not run leaves zeros, at positions
+        no real position reads (a real query sees ``mask & causal``, the
+        logits are read at ``length - 1``, the cache's index is the
+        length)."""
         cfg = self.cfg
         heads, topk = cfg.heads, cfg.index_topk
         b, s = q.shape[:2]
         scale = jnp.float32(cfg.attn_scale_mult / math.sqrt(q.shape[-1]))
         block = min(s, DSA_QUERY_BLOCK)
+        if lengths is None:
+            lengths = jnp.max(jnp.where(mask, jnp.arange(1, s + 1), 0), axis=-1)
+        plan = [(at, t, live, _head_group(heads, b * block * t))
+                for at, t, live in dsa_prefill_turns(jnp.max(lengths), s,
+                                                     jnp.clip)]
+        # the keys and values regrouped ONCE a size of head group, to the
+        # farthest key any block of that size reads: a key block takes a
+        # prefix of it
+        reach = {group: t for _, t, _, group in plan}
+        grouped = {group: tuple(jnp.moveaxis(x[:, :t].reshape(
+            b, t, heads // group, group, x.shape[-1]), 2, 0) for x in (k, v))
+            for group, t in reach.items()}
         outs = []
-        for at in range(0, s, DSA_KEY_BLOCK):
+        for at, t, live, group in plan:
             # the queries at .. t - 1 against the keys 0 .. t - 1
-            t = min(at + DSA_KEY_BLOCK, s)
-            turns = -(-(t - at) // block)
-            group = _head_group(heads, b * block * t)
+            width = -(-(t - at) // block) * block
+            k_t, v_t = grouped[group]
+            q_t, qi_t, wi_t = (
+                jnp.pad(x[:, at:t], ((0, 0), (0, width - (t - at)))
+                        + ((0, 0),) * (x.ndim - 2))
+                for x in (q, q_idx, w_idx))
 
-            def grouped(x, t=t, group=group):
-                return jnp.moveaxis(x[:, :t].reshape(
-                    b, t, heads // group, group, x.shape[-1]), 2, 0)
-
-            def blocks(x, at=at, t=t, turns=turns):
-                x = jnp.pad(x[:, at:t], ((0, 0), (0, turns * block
-                                                  - (t - at)))
-                            + ((0, 0),) * (x.ndim - 2))
-                return jnp.moveaxis(
-                    x.reshape(b, turns, block, *x.shape[2:]), 1, 0)
-
-            def body(args, at=at, t=t, k_t=grouped(k), v_t=grouped(v)):
-                i, q_i, qi_i, wi_i = args
+            def turn(i, out, at=at, t=t, k_t=k_t, v_t=v_t, q_t=q_t,
+                     qi_t=qi_t, wi_t=wi_t):
+                q_i, qi_i, wi_i = (jax.lax.dynamic_slice_in_dim(
+                    x, i * block, block, 1) for x in (q_t, qi_t, wi_t))
                 pos = at + i * block + jnp.arange(block)
                 seen = mask[:, None, :t] & (jnp.arange(t)[None, :]
                                             <= pos[:, None])[None]
@@ -1519,13 +1583,14 @@ class LlamaBlock(nn.Module):
                     with jax.named_scope("dsa_select"):
                         seen = _dsa_select_mask(score, seen, topk)
                 with jax.named_scope("attend"):
-                    return _dsa_block_attend(q_i, k_t, v_t, seen, scale,
-                                             cfg.dtype)
+                    return jax.lax.dynamic_update_slice_in_dim(
+                        out, _dsa_block_attend(q_i, k_t, v_t, seen, scale,
+                                               cfg.dtype), i * block, 1)
 
-            out = jax.lax.map(body, (jnp.arange(turns), blocks(q),
-                                     blocks(q_idx), blocks(w_idx)))
-            outs.append(jnp.moveaxis(out, 0, 1).reshape(
-                b, turns * block, heads, v.shape[-1])[:, :t - at])
+            out = jax.lax.fori_loop(
+                0, live, turn,
+                jnp.zeros((b, width, heads, v.shape[-1]), cfg.dtype))
+            outs.append(out[:, :t - at])
         return jnp.concatenate(outs, axis=1)
 
     def _sparse_select(self, cache, q_idx, k_idx, w_idx, valid):
@@ -3447,8 +3512,11 @@ class LlamaServer:
     # bumps this too. g8 = PR 42: on a TPU a kda or linear layer's decode
     # step is a Mosaic call that steps the state leaf in place
     # (ops/state_step.py), and the linear state leaf is [slots, 1, heads x
-    # d, d]: an executable of the old text would take the old leaf.
-    _AOT_GEN = "g8"
+    # d, d]: an executable of the old text would take the old leaf. g9 =
+    # PR 43: a sparse prefill's loops take their trip counts from the rows'
+    # length operand and walk key blocks of 2048 (_sparse_prefill_attend):
+    # same operands, another text.
+    _AOT_GEN = "g9"
 
     @classmethod
     def aot_prefix(cls) -> str:

@@ -853,6 +853,9 @@ class ContinuousBatcher:
         if self.kda_stats.layers:
             self.kda_stats.record_prefill(
                 server.model.cfg.kda_scan_chunks(1, sb))
+        if self._counts_dsa:
+            self.dsa_stats.record_prefill(
+                *server.model.cfg.dsa_prefill_pairs([s], 1, sb))
         with server._mesh_ctx():
             return prefill(server.params, prompt_op, length_op, *knobs)
 
@@ -893,6 +896,9 @@ class ContinuousBatcher:
         if self.kda_stats.layers:
             self.kda_stats.record_prefill(
                 server.model.cfg.kda_scan_chunks(bb, sb))
+        if self._counts_dsa:
+            self.dsa_stats.record_prefill(
+                *server.model.cfg.dsa_prefill_pairs(lens, bb, sb))
         with server._mesh_ctx():
             return prefill(server.params, prompt_op, length_op, *knobs)
 

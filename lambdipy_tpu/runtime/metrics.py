@@ -170,17 +170,26 @@ class MoeLoadStats:
 
 @dataclass
 class DsaKeyStats:
-    """Counters of a sparse-attention model's decode segments (the
-    ``handler.dsa`` block on ``/metrics``), only growing, from the segment
-    programs' own masks, for the rows the collector books. ``row_steps``:
+    """Counters of a sparse-attention model (the ``handler.dsa`` block on
+    ``/metrics``), only growing. Of its decode segments, from the segment
+    programs' own masks, for the rows the collector books: ``row_steps``:
     booked rows x segment steps. ``keys_selected``: the cached positions
     those steps attended, summed: ``min(context, index_topk)`` a step
     exactly, so more or fewer shows as a difference. ``keys_visible``: the
-    positions they were chosen from (the step's context)."""
+    positions they were chosen from (the step's context). Of the prefills
+    the engine dispatched, booked on the host from the iteration space the
+    program's loops take their trip counts from
+    (``llama.dsa_prefill_turns``): ``prefill_pairs_run``: the query-key
+    pairs their turns were given, a layer; ``prefill_pairs_causal``: the
+    pairs causality needs, ``L (L + 1) / 2`` a prompt of ``L`` tokens. Their
+    ratio is the prefill's overwork: padding and the overhang of a key
+    block over the causal frontier."""
 
     row_steps: int = 0
     keys_selected: int = 0
     keys_visible: int = 0
+    prefill_pairs_run: int = 0
+    prefill_pairs_causal: int = 0
     _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
 
     def record_segment(self, rows, *, steps: int) -> None:
@@ -191,11 +200,19 @@ class DsaKeyStats:
             self.keys_selected += int(rows[:, 0].sum())
             self.keys_visible += int(rows[:, 1].sum())
 
+    def record_prefill(self, run: int, causal: int) -> None:
+        """One dispatched prefill (``LlamaConfig.dsa_prefill_pairs``)."""
+        with self._lock:
+            self.prefill_pairs_run += run
+            self.prefill_pairs_causal += causal
+
     def report(self) -> dict:
         with self._lock:
             return {"row_steps": self.row_steps,
                     "keys_selected": self.keys_selected,
-                    "keys_visible": self.keys_visible}
+                    "keys_visible": self.keys_visible,
+                    "prefill_pairs_run": self.prefill_pairs_run,
+                    "prefill_pairs_causal": self.prefill_pairs_causal}
 
 
 @dataclass

@@ -6,7 +6,9 @@ counters over it, which no benchmark metric reads: garbage segments a request
 window, and of a model with recurrent states the row-steps its states took
 and how many of them the in-place kernel stepped (``handler.kda`` /
 ``handler.sala`` ``row_steps``, ``kernel_row_steps``: equal on the chip, the
-second 0 on a CPU). It wraps ``python3 -m benchmark.run`` (same arguments after ``--``,
+second 0 on a CPU), and of a sparse-attention model the keys its steps
+selected and the pairs its prefills ran over those causality needs
+(``handler.dsa``; ``prefill_overwork``). It wraps ``python3 -m benchmark.run`` (same arguments after ``--``,
 same result line) and changes nothing under ``benchmark/``: the harness's two
 scrapes are kept and one more is made mid-window.
 
@@ -48,6 +50,9 @@ def pick(metrics: dict) -> dict:
             "states": {kind: {k: handler[kind].get(k) for k in (
                 "row_steps", "kernel_row_steps")}
                 for kind in ("kda", "sala") if kind in handler},
+            "dsa": {k: v for k, v in (handler.get("dsa") or {}).items()
+                    if k in ("keys_selected", "keys_visible",
+                             "prefill_pairs_run", "prefill_pairs_causal")},
             "sched": metrics.get("sched"), "spans": spans,
             "peak_bytes": [d.get("peak_bytes_in_use") for d in (
                 metrics.get("device") or {}).get("memory", [])]}
@@ -67,7 +72,12 @@ def deltas(opened: dict, closed: dict) -> dict:
 
     served, segs = d(b0, b1, "requests_served"), d(b0, b1, "segments_run")
     wasted = d(p0, p1, "wasted_overdecode_tokens")
-    return {"served": served, "segments": segs,
+    dsa = {k: d(opened["dsa"], closed["dsa"], k) for k in closed["dsa"]}
+    if dsa.get("prefill_pairs_causal"):
+        # pairs the window's sparse prefills ran over those causality needs
+        dsa["prefill_overwork"] = round(
+            dsa["prefill_pairs_run"] / dsa["prefill_pairs_causal"], 4)
+    return {"served": served, "segments": segs, "dsa": dsa,
             "rows_per_segment": round(
                 d(b0, b1, "rows_in_segments") / max(segs, 1), 4),
             "wasted_overdecode_tokens": wasted,

@@ -9,6 +9,7 @@ that the program lowers to a ``tpu_custom_call`` and the compiler accepts
 it. ~1 s each; skipped where the topology cannot be described.
 """
 
+import math
 import os
 import re
 
@@ -586,15 +587,22 @@ def test_a_sparse_segment_compiles_and_what_it_does_with_its_three_leaves(
 
 def test_a_sparse_prefill_keeps_a_turns_scores_in_the_fast_memory(v5e,
                                                                   monkeypatch):
-    """The solo prefill of the cell's 12288 bucket (three key blocks of
-    4096), one layer. ~45 s.
+    """The solo prefill of the cell's 12288 bucket (six key blocks of 2048),
+    one layer. ~45 s.
 
     No operation of the program produces a ``[heads, s, s]`` score: the
     largest float32 tensor a fusion hands on is a GROUP of heads' scores of
     one block of 128 queries (``llama.DSA_SCORE_BYTES``: 4 heads at 12288
-    keys, 24 MiB), and every one of them lies in the chip's fast memory
-    (``S(1)`` in its layout), as do the index scores a turn selects by;
-    whole, a turn's 128 heads x 128 x 12288 float32 scores are 0.8 GB."""
+    keys, 24 MiB; 16 heads at the first key block's 2048), and every one of
+    them lies in the chip's fast memory (``S(1)`` in its layout), as do the
+    index scores a turn selects by; whole, a turn's 128 heads x 128 x 12288
+    float32 scores are 0.8 GB.
+
+    The turns of a key block are a loop whose trip count is no constant of
+    the program: its condition compares the counter with an operand of the
+    loop (what the rows' length operand makes it), one such loop a key
+    block, where the loops inside a turn (the head groups, the threshold's
+    32 bits) compare with constants."""
     from lambdipy_tpu.models import llama, moe
 
     monkeypatch.setattr(moe, "kernels_compile_here", lambda: True)
@@ -622,15 +630,30 @@ def test_a_sparse_prefill_keeps_a_turns_scores_in_the_fast_memory(v5e,
     handed_on = re.findall(
         r"= \(?((?:\w+\[[\d,]+\]\{[^}]*\}(?:, )?)+)\)? fusion\("
         r"(?![^\n]*calls=%bitcast_fusion)", text)
+    extents = "|".join(str(t) for t in range(2048, 12288 + 1, 2048))
     scores = [layout for shapes in handed_on for layout in re.findall(
-        r"f32\[(?:\d+,)+(?:12288|8192)\]\{[^}]*\}", shapes)
+        rf"f32\[(?:\d+,)+(?:{extents})\]\{{[^}}]*\}}", shapes)
         if layout.count(",") >= 3]
     assert any(s.startswith("f32[4,128,12288]") for s in scores), scores[:5]
+    assert any(s.startswith("f32[16,128,2048]") for s in scores), scores[:5]
+    assert max(math.prod(map(int, re.findall(r"\d+", s.split("]")[0])[1:]))
+               for s in scores) == 4 * 128 * 12288
     assert all("S(1)" in layout for layout in scores), scores
     found = set()
     for op_name in re.findall(r'op_name="([^"]*)"', text):
         found.update(op_name.split("/"))
     assert {"dsa_index", "dsa_select", "attend", "qkv_proj"} <= found
+    conditions = dict(re.findall(r"^%([\w.]+) \([^\n]*\{\n(.*?)^\}", text,
+                                 re.S | re.M))
+    loops = re.findall(r' while\([^\n]*condition=%([\w.]+), body=[^\n]*'
+                       r'op_name="([^"]*)"', text)
+    turns = [conditions[c] for c, name in loops
+             if name.endswith("_sparse_prefill_attend/while")]
+    inner = [conditions[c] for c, name in loops
+             if "_sparse_prefill_attend/while/body/" in name]
+    assert len(turns) == 12288 // llama.DSA_KEY_BLOCK == 6
+    assert not any("constant(" in cond for cond in turns)
+    assert inner and all("constant(" in cond for cond in inner)
 
 
 # minicpm-sala's widths (benchmark/configs/minicpm-sala.json): 8 slots of

@@ -130,6 +130,84 @@ def test_the_whole_forward_is_the_references(blocks, adapter, params,
     np.testing.assert_allclose(got, ref, atol=LOGIT_TOL, rtol=0)
 
 
+@pytest.mark.parametrize("lengths,turns", [
+    pytest.param((66,), [4, 4, 1],
+                 id="the last token in a key block's first turn"),
+    pytest.param((64,), [4, 4, 0],
+                 id="the last token on a key block's last position"),
+    pytest.param((27,), [4, 0, 0], id="two trailing key blocks of padding"),
+    pytest.param((72, 45), [4, 4, 1], id="two rows of different lengths"),
+])
+def test_a_prompt_shorter_than_its_bucket_runs_only_its_own_turns(
+        lengths, turns, adapter, params, monkeypatch):
+    """Right-padded rows in a bucket of 96 (three key blocks of 32, turns of
+    8 queries): a key block runs the turns that begin before the longest
+    row's last token and no other. Every real position's logits are the
+    reference's, and the three cache leaves at the real positions are those
+    of the same row run alone at its own length (another program: to the
+    order of its float32 sums)."""
+    monkeypatch.setattr(llama, "DSA_KEY_BLOCK", 32)
+    monkeypatch.setattr(llama, "DSA_QUERY_BLOCK", 8)
+    bucket = 96
+    assert [live for _, _, live in llama.dsa_prefill_turns(
+        max(lengths), bucket)] == turns
+    rng = np.random.default_rng(7)
+    rows = [rng.integers(1, 512, n).astype(np.int32) for n in lengths]
+    ids = np.zeros((len(rows), bucket), np.int32)
+    for r, row in enumerate(rows):
+        ids[r, :len(row)] = row
+
+    @jax.jit
+    def run(ids, lengths):
+        return adapter.module.apply(params, ids, lengths=lengths)
+
+    logits, cache = run(jnp.asarray(ids), jnp.asarray(lengths, jnp.int32))
+    for r, row in enumerate(rows):
+        n = len(row)
+        np.testing.assert_allclose(np.asarray(logits[r, :n]),
+                                   walk_logits(row[None])[0],
+                                   atol=LOGIT_TOL, rtol=0)
+        _, alone = run(jnp.asarray(row[None]), jnp.asarray([n], jnp.int32))
+        for entry, entry_alone in zip(cache, alone):
+            assert set(entry) == {"ckv", "kpe", "kidx"}
+            for leaf in entry:
+                np.testing.assert_allclose(
+                    np.asarray(entry[leaf][r, :n]),
+                    np.asarray(entry_alone[leaf][0]), atol=LOGIT_TOL, rtol=0)
+
+
+def test_the_iteration_space_is_what_the_engine_books(adapter):
+    """The cell's bucket of 12288 (six key blocks of 2048, 16 turns of 128
+    queries each) at three prompt lengths: the pairs a layer the turns are
+    given, from the one function the loops take their trip counts from, and
+    ``handler.dsa`` after the engine's booking of those three prefills."""
+    from lambdipy_tpu.runtime.metrics import DsaKeyStats
+
+    assert (llama.DSA_KEY_BLOCK, llama.DSA_QUERY_BLOCK) == (2048, 128)
+    want = {8193: 2048 * (2048 + 4096 + 6144 + 8192) + 128 * 10240,
+            10240: 2048 * (2048 + 4096 + 6144 + 8192 + 10240),
+            12288: 2048 * sum(range(2048, 12289, 2048))}
+    assert [round(n / 1e6, 1) for n in want.values()] == [43.3, 62.9, 88.1]
+    assert [live for _, _, live in llama.dsa_prefill_turns(8193, 12288)] \
+        == [16, 16, 16, 16, 1, 0]
+    stats, cfg = DsaKeyStats(), adapter.module.cfg
+    for length, pairs in want.items():
+        assert cfg.dsa_prefill_pairs([length], 1, 12288) == (
+            pairs, length * (length + 1) // 2)
+        stats.record_prefill(*cfg.dsa_prefill_pairs([length], 1, 12288))
+    report = stats.report()
+    assert report["prefill_pairs_run"] == sum(want.values())
+    assert report["prefill_pairs_causal"] == sum(
+        n * (n + 1) // 2 for n in want)
+    # (what the parent ran, whatever the length: 32 turns in each of three
+    # key blocks of 4096, each against all keys to its block's end)
+    assert round(4096 * (4096 + 8192 + 12288) / (10240 * 10241 / 2), 2) == 1.92
+    assert round(want[10240] / (10240 * 10241 / 2), 2) == 1.2
+    # two rows in a bucket of two: both run what the longer needs
+    assert cfg.dsa_prefill_pairs([9000, 12288], 2, 12288) == (
+        2 * want[12288], 9000 * 9001 // 2 + 12288 * 12289 // 2)
+
+
 def test_the_int8_layout_is_what_the_programs_converter_writes(params):
     floats = build(None)
     quantized = llama.quantize_params(floats.init_params(seed=1))
@@ -329,6 +407,12 @@ def test_the_continuous_engine_with_ragged_joiners_counts_exactly(server):
     assert dsa["row_steps"] == row_steps
     assert dsa["keys_selected"] == INDEX_TOPK * row_steps
     assert dsa["keys_visible"] > 2 * dsa["keys_selected"]
+    # the prefills' pairs: what causality needs of the seven prompts, and
+    # what their group prefills ran (whole buckets of 32 or 64 a row)
+    assert dsa["prefill_pairs_causal"] == sum(
+        len(r) * (len(r) + 1) // 2 for r in rows)
+    assert dsa["prefill_pairs_run"] % (32 * 32) == 0
+    assert dsa["prefill_pairs_run"] > dsa["prefill_pairs_causal"]
     assert load["assignments"] == row_steps * ROUTED_LAYERS * TOP_K
     assert len(load["load"]) == EXPERTS
     assert sum(load["load"]) == load["assignments"]
