@@ -47,10 +47,8 @@ state as it was, so the state a row hands on is the one at ITS length, and
 its conv tail is gathered there. States, gates, running sums and every
 product that touches a state are float32 (``highest`` on a TPU).
 
-What :class:`~lambdipy_tpu.models.llama.LlamaBlock` asks of a kind's module:
-``validate``, ``cache_layout``, ``cache_positions``, ``cache_dtypes``,
-``cache_slot``, ``refusal`` and ``attend``. Keys: ``kda_heads``,
-``kda_head_dim``, ``kda_conv``, ``kda_lower_bound``, ``attn_output_gate``,
+The interface is ``llama.ATTN_KINDS``'. Keys: ``kda_heads``, ``kda_head_dim``,
+``kda_conv``, ``kda_lower_bound``, ``attn_output_gate``,
 ``attn_gate_headwise`` (``LlamaConfig``)."""
 
 from __future__ import annotations
@@ -59,12 +57,14 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from lambdipy_tpu.models.llama import QDense, RMSNorm, output_gate
+from lambdipy_tpu.models.llama import (Counters, QDense, RMSNorm, output_gate,
+                                       require_own_leaves, whole_prompt_blocks)
 from lambdipy_tpu.ops import kernels_compile_here
 from lambdipy_tpu.ops.state_step import (kernel_fits, stepped_in_place,
                                          stepped_reference)
 
 NAME = "kda"
+PLACES = ("layer_kinds",)
 # positions one turn of the prefill's scan takes: a turn's [heads, C, C, d]
 # decays are 17 MB a row at 32 heads of 128, and a 1536 prompt is 48 turns
 KDA_CHUNK = 32
@@ -72,6 +72,7 @@ L2_EPS = 1e-6
 
 
 def validate(cfg) -> None:
+    require_own_leaves(cfg, NAME)
     if min(cfg.kda_heads, cfg.kda_head_dim) <= 0 or cfg.kda_conv < 2 \
             or not cfg.kda_lower_bound < 0:
         raise ValueError("kda attention needs kda_heads, kda_head_dim, "
@@ -126,6 +127,38 @@ def steps_in_place(cfg) -> bool:
 def scan_chunks(s: int) -> int:
     """Turns the chunked form takes over ``s`` (padded) positions."""
     return -(-s // min(KDA_CHUNK, s))
+
+
+prompt_block = whole_prompt_blocks
+
+
+def counters(cfg) -> tuple:
+    """The ``handler.kda`` block on ``/metrics``, only growing, from shapes.
+    ``row_steps``: booked rows x segment steps x kda layers: the
+    layer-steps that stepped a state for somebody. ``scan_chunks``: the
+    chunks the prefills' chunked form scanned, every row and kda layer of
+    every prefill program run (:func:`scan_chunks`). ``state_bytes``: the
+    state and conv tail those layer-steps read and wrote, once each way
+    (:func:`state_bytes_a_step` a row's step in one layer).
+    ``kernel_row_steps``: the layer-steps whose state the kernel stepped in
+    place (:func:`steps_in_place`, the layers' own static choice):
+    ``row_steps`` where Mosaic compiles, 0 elsewhere."""
+    layers = tuple(cfg.layer_kinds).count(NAME)
+    a_step, kernel = state_bytes_a_step(cfg), steps_in_place(cfg)
+
+    def segment(sown, rows: int, steps: int) -> dict:
+        took = rows * steps * layers
+        return {"row_steps": took, "state_bytes": took * a_step,
+                "kernel_row_steps": took * kernel}
+
+    def prefill(lengths, rows: int, s: int) -> dict:
+        return {"scan_chunks": rows * layers * scan_chunks(s)}
+
+    return (Counters(
+        "kda", "a model with kda layers",
+        dict.fromkeys(("row_steps", "scan_chunks", "state_bytes",
+                       "kernel_row_steps"), 0),
+        segment=segment, prefill=prefill),)
 
 
 def _state_dot(a, b, spec: str):

@@ -22,9 +22,7 @@ are float32 (``highest`` on a TPU); ``Q K^T`` and ``A V`` inside a chunk take
 the activations' precision with float32 accumulation, as every attention
 here.
 
-What :class:`~lambdipy_tpu.models.llama.LlamaBlock` asks of a kind's module:
-``validate``, ``cache_layout``, ``cache_positions``, ``cache_dtypes``,
-``cache_slot``, ``refusal`` and ``attend``. Keys: ``lin_heads``, ``lin_head_dim``,
+The interface is ``llama.ATTN_KINDS``'. Keys: ``lin_heads``, ``lin_head_dim``,
 ``lin_rope``, ``lin_output_norm``, ``qk_norm``, ``attn_output_gate``
 (``LlamaConfig``)."""
 
@@ -33,17 +31,21 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from lambdipy_tpu.models.llama import QDense, RMSNorm, rope
+from lambdipy_tpu.models.llama import (Counters, QDense, RMSNorm,
+                                       require_own_leaves, rope,
+                                       whole_prompt_blocks)
 from lambdipy_tpu.ops import kernels_compile_here
 from lambdipy_tpu.ops.state_step import kernel_fits, stepped_in_place
 
 NAME = "linear"
+PLACES = ("layer_kinds",)
 # positions one turn of the prefill's scan takes: the [heads, C, C] float32
 # scores of a turn are 8 MB at 32 heads, and a 20480 prompt is 80 turns
 LIN_CHUNK = 256
 
 
 def validate(cfg) -> None:
+    require_own_leaves(cfg, NAME)
     if min(cfg.lin_heads, cfg.lin_head_dim) <= 0 or (
             cfg.lin_rope and cfg.lin_head_dim % 2):
         raise ValueError("linear attention needs lin_heads and lin_head_dim "
@@ -82,6 +84,31 @@ def steps_in_place(cfg) -> bool:
     code can observe, as ``RoutedMLP`` chooses its experts' kernel."""
     return kernels_compile_here() and kernel_fits(
         cfg.lin_heads, cfg.lin_head_dim, cfg.lin_head_dim)
+
+
+prompt_block = whole_prompt_blocks
+
+
+def counters(cfg) -> tuple:
+    """This kind's share of the ``handler.sala`` block on ``/metrics``, only
+    growing, from shapes. ``state_bytes``: the recurrent state the linear
+    layers read and wrote, once each way: booked rows x segment steps x
+    linear layers x a head's float32 ``[d, d]`` x heads x 2.
+    ``kernel_row_steps``: the row-steps whose linear states the kernel
+    stepped in place (:func:`steps_in_place`, the layers' own static
+    choice): booked rows x segment steps where Mosaic compiles, 0
+    elsewhere."""
+    layers = tuple(cfg.layer_kinds).count(NAME)
+    a_step = 2 * 4 * cfg.lin_heads * cfg.lin_head_dim ** 2 * layers
+    kernel = steps_in_place(cfg)
+
+    def segment(sown, rows: int, steps: int) -> dict:
+        return {"state_bytes": rows * steps * a_step,
+                "kernel_row_steps": rows * steps * kernel}
+
+    return (Counters("sala", "a model with linear-attention layers",
+                     {"state_bytes": 0, "kernel_row_steps": 0},
+                     segment=segment),)
 
 
 def slopes(heads: int):

@@ -18,6 +18,8 @@ TPU-first choices:
 from __future__ import annotations
 
 import dataclasses
+import functools
+import importlib
 import math
 import threading
 from typing import Any, NamedTuple
@@ -32,8 +34,78 @@ log = get_logger("lambdipy.llama")
 
 
 class LayerSpec(NamedTuple):
-    attn: str  # "kv" | "latent" | "eva" | "sparse_kv" | "linear" | "kda"
+    attn: str  # one of ATTN_KINDS
     ffn: str   # "dense" | "capacity" | "routed"
+
+
+# The attention kinds: ``{kind: its module}``, in the order their counters
+# leave a segment program; a new kind is its file and its line here. What
+# the block, the cache constructors, the segment programs and the engine ask
+# of a kind's module, and nothing else of a kind:
+#
+#   NAME, PLACES                      where it may stand: "attn_kind" (the
+#                                     model's one), "layer_kinds", or both
+#   validate(cfg)                     raise for a wrong description
+#   cache_layout(cfg)                 {leaf: (heads, width)}
+#   cache_positions(cfg, max_len)     {leaf: slots}
+#   cache_dtypes(cfg)                 {leaf: dtype}
+#   cache_slot(cfg, leaf, position)   where a position lies in a leaf
+#   refusal(cfg, holder)              why a holder of per-head K/V rows, one
+#                                     a token and every one attended, cannot
+#                                     take it (None: it can)
+#   attend(block, x, positions, mask, cache, lengths)
+#                                     -> (heads' outputs, new cache entry)
+#
+# and, each OPTIONAL (:func:`_ask`):
+#
+#   FORMS                   which of the block's static forms, ``sp_prefill``
+#                           and ``band``, ``attend`` takes as keywords; any
+#                           other is refused
+#   absent(cfg)             raise for a model WITHOUT the kind that sets
+#                           what only the kind reads
+#   row_a_token(cfg)        whether a holder that only cuts, joins or extends
+#                           ONE position axis can take it all the same
+#   scales_softmax(cfg)     whether it applies YaRN's ``attn_scale_mult``
+#   prompt_block(cfg)       (block, past): a prompt longer than ``past``
+#                           prefills at whole multiples of ``block``
+#   counters(cfg)           its :class:`Counters`
+#   keeps_tail(cfg), tail_fits(cfg, steps, spans), tail_init(cfg, frozen,
+#   base, steps), tail_step(cfg, frozen, tails, base, j), tail_merge(cfg,
+#   full, tails, base, steps)
+#                           a decode segment whose cache is read-only inside
+#                           its scan (:func:`_scan_decode`, ``tail_window``):
+#                           what the scan carries in the cache's place, what
+#                           a step's layers read, the one write after it
+ATTN_KINDS = {"kv": "lambdipy_tpu.models.kv",
+              "eva": "lambdipy_tpu.models.eva",
+              "latent": "lambdipy_tpu.models.latent",
+              "sparse_kv": "lambdipy_tpu.models.sparse_kv",
+              "linear": "lambdipy_tpu.models.linear_attn",
+              "kda": "lambdipy_tpu.models.kda"}
+
+
+class Counters(NamedTuple):
+    """What one kind (an attention kind, or the routed FFN) counts for one
+    block of ``/metrics`` -> ``handler``: its own declaration, which
+    :func:`_scan_decode`, the engine's collector and its recorder
+    (``runtime/metrics.py KindCounters``) follow without knowing the kind.
+    Kinds that name the same block share it, their fields in the kinds'
+    order."""
+
+    block: str    # the /metrics block: handler.<block>
+    what: str     # "a ... model", for a refusal's words
+    fields: dict  # {report key: 0, or [] for a vector}, in the report's order
+    # {collection: zero(b)}: what the kind sows into a segment program's
+    # steps, and where each sum starts for ``b`` rows; the program returns
+    # the sums behind its tokens, in this order
+    sown: dict = {}
+    # (sown, rows, steps) -> {field: increment} of one fetched plain segment.
+    # ``sown``: the kind's collections as host arrays, those with a row axis
+    # cut to the ``rows`` rows the collector books
+    segment: Any = None
+    # (lengths, rows, s) -> {field: increment} of one dispatched prefill of
+    # ``rows`` rows padded to ``s`` positions (``lengths``: the real rows')
+    prefill: Any = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,20 +157,15 @@ class LlamaConfig:
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
     moe_group_size: int = 256  # routing-group size (models/moe.py)
-    # -- the per-layer description (ROADMAP D3 in the small): what the ONE
-    # block, the cache constructors and the registry read, so that an
-    # architecture is a setting of these fields and not a fork of this
-    # file. ``layer_spec(i)`` and ``cache_layout()`` below are the only
-    # readers of the two kinds.
-    # Attention kind: "kv" (per-head K/V rows: ``kv_heads`` x ``head_dim``,
-    # the llama block) or "latent" (multi-head latent attention as
-    # DeepSeek-V2/V3 publish it: the cache row of a token is ONE compressed
-    # latent of ``kv_lora_rank`` values, after its norm, plus ONE rotary
-    # key of ``qk_rope`` values shared by all heads, after rope; query/key
-    # heads are ``qk_nope + qk_rope`` wide, value heads ``v_head``; no
-    # query compression). Prefill attends expanded keys and values; every
-    # program that attends a cache absorbs the up-projection into the
-    # query and the output instead of re-expanding the window.
+    # -- the per-layer description: what the ONE block, the cache
+    # constructors and the registry read, so that an architecture is a
+    # setting of these fields and a kind's module (``ATTN_KINDS``), not a
+    # fork of this file. A kind's settings are flat fields here because
+    # recipes' TOML, bundle manifests and ``registry.py`` name them (ROADMAP
+    # D21); only the kind's module reads them.
+    # The model's one attention kind: "kv" (models/kv.py), "latent"
+    # (models/latent.py: ``qk_nope``, ``qk_rope``, ``v_head``,
+    # ``kv_lora_rank``) or "eva" (models/eva.py).
     attn_kind: str = "kv"
     qk_nope: int = 0
     qk_rope: int = 0
@@ -120,15 +187,8 @@ class LlamaConfig:
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
     scoring_func: str = "softmax"  # "softmax" | "sigmoid"
-    # attn_kind "eva" (EVA chunked linearized attention, as EvaByte
-    # publishes it): positions lie in windows of ``window_size``; a query
-    # attends the keys of ITS window exactly (causal) and, under the same
-    # softmax, ONE learned summary (a pooled key and a pooled value) for
-    # every ``chunk_size`` positions of every earlier window. A layer's
-    # cache is therefore two kinds of leaf of different lengths: a ring of
-    # ``window_size`` K/V rows (position t at slot t mod window_size) and
-    # one summary row for every chunk the cache may serve (position t's
-    # chunk at t // chunk_size): ``cache_positions`` / ``cache_slot``.
+    # attn_kind "eva": a ring of ``window_size`` rows beside one pooled
+    # summary every ``chunk_size`` positions (models/eva.py)
     window_size: int = 0
     chunk_size: int = 0
     # prediction heads: the head has ``pred_heads x vocab_size`` columns
@@ -137,16 +197,10 @@ class LlamaConfig:
     pred_heads: int = 1
     # RMSNorm multiplies by (1 + gain) and its gain starts at zero
     norm_unit_offset: bool = False
-    # -- the latent kind as DeepSeek-V3.2 publishes it. ``q_lora_rank`` > 0:
-    # the query is compressed (``q_a_proj``, ``q_a_norm``, ``q_b_proj`` in
-    # place of ``q_proj``). ``index_topk`` > 0: DeepSeek Sparse Attention. A
-    # lightning indexer (``index_heads`` heads of ``index_head_dim``, fed
-    # by the compressed query) scores every cached position against one
-    # indexer key a token, the third cache leaf ``kidx``; a query attends
-    # only the ``index_topk`` positions of largest score (all of them while
-    # its context is shorter), chosen exactly, ties to the lowest position:
-    # the key set is chosen by CONTENT, so no holder that cuts a cache by
-    # position alone can take it (:func:`require_row_a_token`).
+    # -- the latent kind as DeepSeek-V3.2 publishes it (models/latent.py):
+    # ``q_lora_rank`` > 0 compresses the query; ``index_topk`` > 0 is
+    # DeepSeek Sparse Attention, a lightning indexer of ``index_heads`` heads
+    # of ``index_head_dim`` that picks the positions a query attends
     q_lora_rank: int = 0
     index_heads: int = 0
     index_head_dim: int = 0
@@ -165,19 +219,13 @@ class LlamaConfig:
     # written: PERF.md section 7).
     moe_experts_held: int = 0
     moe_first_expert: int = 0
-    # -- the attention kind a LAYER (ROADMAP R2, R4). ``layer_kinds``: one
-    # kind a layer in place of the model's one ``attn_kind``: "kv" (the
-    # llama block's per-step-write form) and the kinds that are modules of
-    # their own (:func:`attn_kind_module`): "sparse_kv" (models/sparse_kv.py:
-    # grouped-query K/V whose attended blocks are chosen by content from a
-    # second leaf of compressed keys: ``sparse_*``) and "linear"
-    # (models/linear_attn.py: a recurrent state a slot and no row a token:
-    # ``lin_*``) and "kda" (models/kda.py: a gated delta-rule state and the
-    # tail of a short convolution a slot: ``kda_*``), and "latent" (the
-    # block's own, above, without ``index_topk``). A layer's cache entry,
-    # its prefill, its step and what refuses it are its kind's;
-    # ``cache_layout`` / ``cache_positions`` / ``cache_slot`` take the
-    # layer. The eva kind stays one a model (``attn_kind``).
+    # -- the attention kind a LAYER, in place of the model's one
+    # ``attn_kind`` (which stays "kv"): of the kinds whose ``PLACES`` say so
+    # (``ATTN_KINDS``): "kv", "latent" without ``index_topk``, "sparse_kv"
+    # (``sparse_*``), "linear" (``lin_*``), "kda" (``kda_*``). A layer's
+    # cache entry, its prefill, its step and what refuses it are its
+    # kind's; ``cache_layout`` / ``cache_positions`` / ``cache_slot`` take
+    # the layer.
     layer_kinds: tuple = ()
     # RMSNorm with a learned gain over each head's query and key
     qk_norm: bool = False
@@ -209,77 +257,34 @@ class LlamaConfig:
     logit_divisor: float = 1.0
 
     def __post_init__(self):
-        if self.attn_kind not in ("kv", "latent", "eva"):
+        modules = {kind: attn_kind_module(kind) for kind in ATTN_KINDS}
+        one = [kind for kind, m in modules.items() if "attn_kind" in m.PLACES]
+        if self.attn_kind not in one:
             raise ValueError(f"unknown attn_kind {self.attn_kind!r}; "
-                             "supported: kv, latent, eva")
+                             f"supported: {', '.join(one)}")
         if self.layer_kinds:
             kinds = tuple(self.layer_kinds)
+            each = [kind for kind, m in modules.items()
+                    if "layer_kinds" in m.PLACES]
             if len(kinds) != self.layers or self.attn_kind != "kv" \
-                    or not set(kinds) <= {"kv", "latent", *ATTN_KIND_MODULES}:
+                    or not set(kinds) <= set(each):
                 raise ValueError(
                     f"layer_kinds {kinds!r}: one kind for each of the "
-                    f"{self.layers} layers, of kv, latent, "
-                    f"{', '.join(ATTN_KIND_MODULES)} (attn_kind stays kv: "
-                    "the eva kind is one a model)")
-            for kind in sorted(set(kinds) - {"kv"}):
-                if kind != "latent":
-                    attn_kind_module(kind).validate(self)
-                if self.kv_quant is not None \
-                        or self.attn_backend != "dense":
-                    raise NotImplementedError(
-                        f"kv_quant={self.kv_quant!r} / attn_backend="
-                        f"{self.attn_backend!r}: the int8 cache layout and "
-                        "the flash, blocked and ring backends hold one "
-                        f"per-head K/V row a token; a {kind} layer runs "
-                        "the dense backend over its own leaves")
-        if self.attn_kind == "eva":
-            if self.chunk_size < 1 or self.window_size < self.chunk_size \
-                    or self.window_size % self.chunk_size:
-                raise ValueError(
-                    "eva attention needs window_size, a multiple of "
-                    "chunk_size >= 1")
-            if self.heads != self.kv_heads:
-                raise ValueError(
-                    "eva attention is multi-head: kv_heads must equal heads")
-            if self.kv_quant is not None:
-                raise NotImplementedError(
-                    f"kv_quant={self.kv_quant!r} cannot hold an eva cache: "
-                    "the int8 cache layout quantizes one K/V row a token "
-                    "(_kv_store), not a ring beside pooled summaries")
-            if self.attn_backend != "dense":
-                raise NotImplementedError(
-                    f"attn_backend={self.attn_backend!r} attends one K/V "
-                    "row a token; eva attention runs the dense backend")
+                    f"{self.layers} layers, of {', '.join(each)} "
+                    "(attn_kind stays kv: the others are one a model)")
+        for kind, module in sorted(modules.items()):
+            if kind in self.attn_kinds:
+                module.validate(self)
+            else:
+                _ask(module, "absent", None, self)
         if self.pred_heads < 1:
             raise ValueError("pred_heads must be >= 1")
         if self.ffn_kind not in ("dense", "routed"):
             raise ValueError(f"unknown ffn_kind {self.ffn_kind!r}; "
                              "supported: dense, routed")
-        if "latent" in self.attn_kinds:
-            if min(self.qk_nope, self.qk_rope, self.v_head,
-                   self.kv_lora_rank) <= 0 or self.qk_rope % 2:
-                raise ValueError(
-                    "latent attention needs qk_nope, qk_rope (even), v_head "
-                    "and kv_lora_rank")
-            if self.kv_quant is not None:
-                raise NotImplementedError(
-                    f"kv_quant={self.kv_quant!r} cannot hold a latent cache: "
-                    "the int8 cache layout quantizes per-head K/V rows "
-                    "(_kv_store)")
-            if self.attn_backend != "dense":
-                raise NotImplementedError(
-                    f"attn_backend={self.attn_backend!r} attends per-head "
-                    "K/V; latent attention runs the dense backend")
-        if self.index_topk:
-            if self.attn_kind != "latent" or not self.q_lora_rank \
-                    or min(self.index_heads, self.index_head_dim) <= 0 \
-                    or not self.qk_rope <= self.index_head_dim:
-                raise ValueError(
-                    "sparse attention (index_topk) needs the latent kind "
-                    "with q_lora_rank, index_heads and index_head_dim >= "
-                    "qk_rope")
-        if self.rope_scaling and self.rope_scaling[0] == "yarn" \
-                and not self.index_topk:
+        if self.rope_scaling and self.rope_scaling[0] == "yarn" and not all(
+                _ask(attn_kind_module(kind), "scales_softmax", False, self)
+                for kind in self.attn_kinds):
             # only the sparse paths multiply the softmax scale by
             # attn_scale_mult: elsewhere prefill and decode would disagree
             raise NotImplementedError(
@@ -332,86 +337,63 @@ class LlamaConfig:
         return next((i for i in range(self.layers)
                      if self.layer_spec(i).attn == kind), -1)
 
+    def kind_of(self, layer: int = 0):
+        """The module of layer ``layer``'s attention kind."""
+        return attn_kind_module(self.layer_spec(layer).attn)
+
     def cache_layout(self, layer: int = 0) -> dict:
         """The cache row of one token of layer ``layer``: ``{leaf: (heads,
         width)}`` in storage order. Every leaf is 4-D ``[rows, positions,
-        heads, width]`` (a latent leaf has a head axis of 1, a linear
-        layer's state a position axis of 1), so whatever iterates a cache
+        heads, width]`` (a leaf without heads has a head axis of 1, a
+        recurrent state a position axis of 1), so whatever iterates a cache
         entry's leaves -- slicing, copying, window buckets, the engine's
         pack -- never asks which kind it holds."""
-        module = attn_kind_module(self.layer_spec(layer).attn)
-        if module is not None:
-            return module.cache_layout(self)
-        if self.layer_spec(layer).attn == "latent":
-            row = {"ckv": (1, self.kv_lora_rank), "kpe": (1, self.qk_rope)}
-            if self.index_topk:
-                # the indexer's key of the token, on the same position axis
-                row["kidx"] = (1, self.index_head_dim)
-            return row
-        row = (self.kv_heads, self.head_dim)
-        if self.attn_kind == "eva":
-            # the ring, then the chunk summaries: ``cache_positions`` says
-            # how long each is and ``cache_slot`` where a position lies
-            return {"k": row, "v": row, "sk": row, "sv": row}
-        return {"k": row, "v": row}
+        return self.kind_of(layer).cache_layout(self)
 
     def cache_positions(self, max_len: int, layer: int = 0) -> dict:
         """``{leaf: slots}``: the length of each leaf's position axis in a
-        cache that serves absolute positions ``0 .. max_len - 1``. One row
-        a token for the "kv" and "latent" kinds; an eva ring never grows
-        past its window and a summary leaf holds one row a chunk; a
-        block-sparse layer has a compressed key every ``sparse_stride``
-        positions beside its rows; a linear layer's state has one slot."""
-        module = attn_kind_module(self.layer_spec(layer).attn)
-        if module is not None:
-            return module.cache_positions(self, max_len)
-        if self.attn_kind != "eva":
-            return dict.fromkeys(self.cache_layout(layer), max_len)
-        ring = min(self.window_size, max_len)
-        chunks = -(-max_len // self.chunk_size)
-        return {"k": ring, "v": ring, "sk": chunks, "sv": chunks}
+        cache that serves absolute positions ``0 .. max_len - 1``:
+        ``max_len`` where a leaf holds one row a token; a ring, a leaf of
+        pooled or compressed rows or a state says its own."""
+        return self.kind_of(layer).cache_positions(self, max_len)
 
     def cache_dtypes(self, layer: int = 0) -> dict:
         """``{leaf: dtype}`` of layer ``layer``'s entry: ``dtype`` but for a
-        kind that says otherwise (a linear state is float32)."""
-        module = attn_kind_module(self.layer_spec(layer).attn)
-        if module is not None:
-            return module.cache_dtypes(self)
-        return dict.fromkeys(self.cache_layout(layer), self.dtype)
+        kind that says otherwise (a recurrent state is float32)."""
+        return self.kind_of(layer).cache_dtypes(self)
 
     def cache_slot(self, leaf: str, position, layer: int = 0):
         """The slot of ``leaf``'s position axis (in layer ``layer``'s entry)
         that holds absolute position ``position`` (an int or an int
         array): for a compressed key or a summary, the one whose span the
         position BEGINS in; a state has one slot."""
-        module = attn_kind_module(self.layer_spec(layer).attn)
-        if module is not None:
-            return module.cache_slot(self, leaf, position)
-        if self.attn_kind != "eva":
-            return position
-        if leaf in ("sk", "sv"):
-            return position // self.chunk_size
-        return position % self.window_size
+        return self.kind_of(layer).cache_slot(self, leaf, position)
 
     def prompt_bucket(self, s: int, lo: int) -> int:
         """The padded length a prompt of ``s`` tokens prefills at: the next
-        power of two from ``lo``. An eva prompt past one window takes the
-        next whole window instead: its prefill is one body a window, so a
-        bucket of three windows costs three turns where the power of two
-        above it would cost four."""
-        if self.attn_kind == "eva" and s > self.window_size:
-            return -(-s // self.window_size) * self.window_size
-        if self.layer_kinds and s > 2 * SALA_PROMPT_BLOCK:
-            # a block-sparse prefill runs one body a block of keys and a
-            # linear one a scan over chunks: whole blocks (a 20k prompt
-            # must not pad to 32k)
-            return -(-s // SALA_PROMPT_BLOCK) * SALA_PROMPT_BLOCK
-        if self.index_topk and s > DSA_PROMPT_BLOCK:
-            # a sparse prefill's cost follows the prompt, not the bucket (it
-            # runs no turn of queries that holds only padding): whole
-            # blocks, a program each
-            return -(-s // DSA_PROMPT_BLOCK) * DSA_PROMPT_BLOCK
+        power of two from ``lo``, but for a long prompt of a model whose
+        kinds prefill a block a turn (their ``prompt_block``: whole blocks,
+        so that the prefill's cost follows the prompt and not the power of
+        two above it)."""
+        asked = [block for block in (
+            _ask(attn_kind_module(kind), "prompt_block", None, self)
+            for kind in self.attn_kinds) if block]
+        if asked and s > max(past for _, past in asked):
+            block = math.lcm(*(block for block, _ in asked))
+            return -(-s // block) * block
         return _next_bucket(s, lo)
+
+    def counters(self) -> tuple:
+        """What this model's kinds count for ``/metrics`` (:class:`Counters`),
+        in the order their sums leave a segment program: the routed FFN's,
+        then the attention kinds' in ``ATTN_KINDS``' order."""
+        from lambdipy_tpu.models import moe
+
+        found = list(moe.counters(self))
+        for kind in ATTN_KINDS:
+            if kind in self.attn_kinds:
+                found += _ask(attn_kind_module(kind), "counters", (), self)
+        return tuple(found)
 
     @property
     def moe_held(self) -> tuple:
@@ -432,162 +414,101 @@ class LlamaConfig:
             return (0.1 * mscale * math.log(factor) + 1.0) ** 2
         return 1.0
 
-    @property
-    def counts_moe_load(self) -> bool:
-        """Whether the engine's segment programs return the routed FFN's
-        per-row expert load beside their tokens (``handler.moe``)."""
-        return self.ffn_kind == "routed"
 
-    @property
-    def counts_eva_keys(self) -> bool:
-        """Whether the engine's segment programs return, a row, the keys
-        its steps had visible and the chunk summaries they wrote
-        (``handler.eva``)."""
-        return self.attn_kind == "eva"
-
-    @property
-    def counts_dsa_keys(self) -> bool:
-        """Whether the engine's segment programs return, a row, the keys
-        its steps selected and the keys they chose from (``handler.dsa``)."""
-        return bool(self.index_topk)
-
-    @property
-    def counts_sala_keys(self) -> bool:
-        """Whether the engine's segment programs return, a row, what its
-        block-sparse steps attended, could see, whether they lay inside
-        ``sparse_dense_len`` and the compressed keys they wrote
-        (``handler.sala``)."""
-        return "sparse_kv" in self.layer_kinds
-
-    @property
-    def kda_layers(self) -> int:
-        return tuple(self.layer_kinds).count("kda")
-
-    @property
-    def kda_step_bytes(self) -> int:
-        """Bytes one row's decode step moves in ONE kda layer (state and
-        conv tail, once each way), 0 without such a layer."""
-        return attn_kind_module("kda").state_bytes_a_step(self) \
-            if self.kda_layers else 0
-
-    @property
-    def kda_steps_in_place(self) -> bool:
-        """Whether the kda layers' decode step takes the kernel that steps
-        the state in place (``ops/state_step.py``): the layers' own choice."""
-        return bool(self.kda_layers) and attn_kind_module(
-            "kda").steps_in_place(self)
-
-    @property
-    def linear_steps_in_place(self) -> bool:
-        """Whether the linear layers' decode step takes that kernel."""
-        return "linear" in tuple(self.layer_kinds) and attn_kind_module(
-            "linear").steps_in_place(self)
-
-    def dsa_prefill_pairs(self, lengths, rows: int, s: int) -> tuple:
-        """``(run, causal)``: the query-key pairs, a layer, that the turns
-        of ONE sparse prefill of ``rows`` rows padded to ``s`` positions are
-        given (every row runs the turns the longest needs), and those that
-        causality needs of the real rows' ``lengths`` (``handler.dsa``)."""
-        run = sum(live * min(s, DSA_QUERY_BLOCK) * t
-                  for _, t, live in dsa_prefill_turns(max(lengths), s))
-        return rows * run, sum(n * (n + 1) // 2 for n in lengths)
-
-    def kda_scan_chunks(self, rows: int, s: int) -> int:
-        """Chunks the kda layers' chunked form scans in ONE prefill of
-        ``rows`` rows padded to ``s`` positions (``handler.kda``)."""
-        if not self.kda_layers:
-            return 0
-        return rows * self.kda_layers * attn_kind_module("kda").scan_chunks(s)
-
-    @property
-    def state_bytes_a_step(self) -> int:
-        """Bytes of recurrent state one row's decode step reads and writes:
-        every linear layer's state and every kda layer's state and conv
-        tail, once each way."""
-        return 2 * 4 * self.lin_heads * self.lin_head_dim ** 2 \
-            * tuple(self.layer_kinds).count("linear") \
-            + self.kda_layers * self.kda_step_bytes
-
-
-# the kinds that are modules of their own, by name (imported on demand: they
-# import this module's layers)
-ATTN_KIND_MODULES = ("kda", "linear", "sparse_kv")
-# prompts past two of these prefill at whole multiples of it (models with
-# ``layer_kinds``): the keys of one block of a block-sparse prefill
-# (``sparse_kv.SPARSE_KEY_BLOCK``)
+# prompts past two of these prefill at whole multiples of it (the kinds that
+# stand in ``layer_kinds`` alone: their ``prompt_block``): the keys of one
+# block of a block-sparse prefill (``sparse_kv.SPARSE_KEY_BLOCK``); a state's
+# prefill is a scan over chunks. A 20k prompt must not pad to 32k
 SALA_PROMPT_BLOCK = 4096
 
 
+def whole_prompt_blocks(cfg) -> tuple:
+    return SALA_PROMPT_BLOCK, 2 * SALA_PROMPT_BLOCK
+
+
 def attn_kind_module(kind: str):
-    """The module of attention kind ``kind`` (its cache layout, its prefill
-    and step, its refusals), None for the kinds ``LlamaBlock`` holds itself
-    (kv, latent, eva)."""
-    if kind == "sparse_kv":
-        from lambdipy_tpu.models import sparse_kv
+    """The module of attention kind ``kind``: its cache layout, its prefill
+    and step, its refusals, its counters (the table above ``ATTN_KINDS``).
+    Imported on demand: the modules import this one's layers."""
+    module = ATTN_KINDS[kind]
+    return importlib.import_module(module) if isinstance(module, str) \
+        else module
 
-        return sparse_kv
-    if kind == "linear":
-        from lambdipy_tpu.models import linear_attn
 
-        return linear_attn
-    if kind == "kda":
-        from lambdipy_tpu.models import kda
-
-        return kda
-    return None
+def _ask(module, word: str, default, *args):
+    """What a kind's module says under an OPTIONAL word of the interface,
+    ``default`` where it has none."""
+    said = getattr(module, word, None)
+    return default if said is None else said(*args)
 
 
 def require_kv_cache(cfg: LlamaConfig, holder: str) -> None:
     """Raise for a cache holder that knows only per-head K/V leaves (no
     silent fallback: the holder would store, ship or page rows of the
-    wrong layout)."""
-    _refuse_kind_modules(cfg, holder)
-    other = next((layer for layer in range(cfg.layers)
-                  if cfg.layer_spec(layer).attn != "kv"), None) \
-        if hasattr(cfg, "layer_spec") else None
-    if other is not None:
-        raise NotImplementedError(
-            f"{holder} holds per-head k/v cache leaves and cannot take the "
-            f"{cfg.layer_spec(other).attn} cache layout "
-            f"{sorted(cfg.cache_layout(other))} (PERF.md section 7)")
-
-
-def _refuse_kind_modules(cfg, holder: str) -> None:
-    """Raise, in the kind's own words, for a model one of whose layers is of
-    a kind that is a module of its own: none of them keeps plain per-head
-    K/V rows that every query reads whole."""
-    for kind in getattr(cfg, "layer_kinds", ()):
-        module = attn_kind_module(kind)
-        if module is not None:
-            raise NotImplementedError(module.refusal(cfg, holder))
+    wrong layout), in the words of the first kind that is none."""
+    for kind in getattr(cfg, "attn_kinds", ()):
+        words = attn_kind_module(kind).refusal(cfg, holder)
+        if words:
+            raise NotImplementedError(words)
 
 
 def require_row_a_token(cfg: LlamaConfig, holder: str) -> None:
     """Raise for a holder that cuts, joins or extends a cache along ONE
-    position axis (a prefix carried over, a chunk continued, a draft
-    verified and rolled back): an eva cache has a ring that forgets and
-    summaries that pool, so a span of positions is no slice of it; and a
-    sparse latent cache is attended through a selection that only the
-    whole-prompt prefill and the one-token step compute; so is a block-sparse
-    layer's, and a linear layer's state has no position axis at all."""
-    _refuse_kind_modules(cfg, holder)
-    if getattr(cfg, "attn_kind", "kv") == "eva":
-        raise NotImplementedError(
-            f"{holder} keeps one cache row a token on one position axis "
-            "and cannot take the eva cache layout (a ring of "
-            f"{cfg.window_size} beside one summary for every "
-            f"{cfg.chunk_size} positions; PERF.md section 7)")
-    if getattr(cfg, "index_topk", 0):
-        raise NotImplementedError(
-            f"{holder} attends every cached position a query may see and "
-            "cannot take sparse attention, whose indexer chooses the "
-            f"{cfg.index_topk} positions a query attends by content from a "
-            "third cache leaf (kidx; PERF.md section 7)")
+    position axis, every row of which a query may attend (a prefix carried
+    over, a chunk continued, a draft verified and rolled back), in the words
+    of the first kind that keeps no such axis (its ``row_a_token``): a ring
+    that forgets beside summaries that pool; a selection by content that
+    only the whole-prompt prefill and the one-token step compute; a
+    recurrent state, which has no position axis at all."""
+    for kind in getattr(cfg, "attn_kinds", ()):
+        module = attn_kind_module(kind)
+        if not _ask(module, "row_a_token", False, cfg):
+            raise NotImplementedError(module.refusal(cfg, holder))
 
 
-LLAMA3_8B = LlamaConfig()
-LLAMA_TINY = LlamaConfig(vocab_size=512, hidden=64, layers=2, heads=4,
-                         kv_heads=2, mlp=128, max_len=128, dtype=jnp.float32)
+def require_own_leaves(cfg: LlamaConfig, kind: str) -> None:
+    """A kind's ``validate``: raise for the options that belong to per-head
+    K/V rows, for a kind that keeps leaves of its own."""
+    if cfg.kv_quant is not None or cfg.attn_backend != "dense":
+        raise NotImplementedError(
+            f"kv_quant={cfg.kv_quant!r} / attn_backend="
+            f"{cfg.attn_backend!r}: the int8 cache layout and "
+            "the flash, blocked and ring backends hold one "
+            f"per-head K/V row a token; a {kind} layer runs "
+            "the dense backend over its own leaves")
+
+
+def block_method(fn):
+    """``fn(block, ...)``, a part of a kind's ``attend``, under the scope a
+    ``LlamaBlock`` method of its name had while the kind lived in the block
+    (flax names a method's operations ``<module>.<method>``): the op_names
+    of the device operations, which a trace is read by, stay what programs
+    compiled before PR 44 carry (``utils/compile_cache.NAMES_GEN``)."""
+    @functools.wraps(fn)
+    def scoped(block, *args, **kwargs):
+        with jax.named_scope(
+                f"{block.name or type(block).__name__}.{fn.__name__}"):
+            return fn(block, *args, **kwargs)
+
+    return scoped
+
+
+def __getattr__(name: str):
+    """``LLAMA3_8B`` and ``LLAMA_TINY``, built when first asked for: a
+    config asks its kinds' modules to validate it, and they import this
+    module's layers."""
+    if name not in _PRESETS:
+        raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+    if not isinstance(_PRESETS[name], LlamaConfig):
+        _PRESETS[name] = LlamaConfig(**_PRESETS[name])
+    return _PRESETS[name]
+
+
+_PRESETS = {"LLAMA3_8B": {},
+            "LLAMA_TINY": dict(vocab_size=512, hidden=64, layers=2, heads=4,
+                               kv_heads=2, mlp=128, max_len=128,
+                               dtype=jnp.float32)}
+
 
 
 class RMSNorm(nn.Module):
@@ -810,16 +731,15 @@ def _kv_store(cfg, k, v, *more, layer: int = 0) -> dict:
     decode path, and prefill embedding all consume it. ``k`` and ``v``
     (and ``more``) are the parts of ``cfg.cache_layout(layer)`` in its order
     (a latent cache: the compressed latent, the shared rotary key and, under
-    sparse attention, the indexer's key)."""
-    if cfg.layer_spec(layer).attn != "kv":
-        return {name: part.astype(cfg.dtype)
-                for name, part in zip(cfg.cache_layout(layer), (k, v, *more))}
+    sparse attention, the indexer's key). ``kv_quant`` is the per-head K/V
+    rows' alone: every other kind's ``validate`` refuses it."""
     if cfg.kv_quant == "int8":
         k_q, k_s = _kv_quantize(k)
         v_q, v_s = _kv_quantize(v)
         return {"k_int8": k_q, "k_scale": k_s,
                 "v_int8": v_q, "v_scale": v_s}
-    return {"k": k.astype(cfg.dtype), "v": v.astype(cfg.dtype)}
+    return {name: part.astype(cfg.dtype)
+            for name, part in zip(cfg.cache_layout(layer), (k, v, *more))}
 
 
 def _active_sp_mesh():
@@ -909,161 +829,13 @@ def _attend(q, k, v, mask, tail=None):
     return shard_hint(out.reshape(b, s, h, v.shape[-1]), "dp", "sp", "tp")
 
 
-# queries one turn of an eva prefill's window loop attends. 32 heads x 128 x
-# (2048 + 384) float32 scores are 40 MB a row: the v5e compiler keeps them,
-# and every pass of the softmax over them, in the fast memory (compiled text
-# for a described v5e, PR 33), and a turn reads its window's K/V and the
-# summaries from HBM, 40 MB: 1.9 GB a layer of a 6144 prompt. At 512 queries
-# a turn the scores (160 MB) go to HBM, written twice and read three times:
-# 8.9 GB a layer, half the prefill's time; at 256 one of the two copies does
-EVA_QUERY_BLOCK = 128
-
-
-def _eva_pool(k, v, mu, phi, dtype):
-    """One summary a chunk: ``k``, ``v`` ``[..., chunk, heads, d]`` (keys
-    after rope) -> ``(sk, sv)`` ``[..., heads, d]`` in ``dtype``. The key
-    summary is the chunk's keys under softmax_j(k_j . mu_h), the value
-    summary its values under softmax_j(k_j . phi_h / sqrt(d)); both
-    softmaxes and sums in float32, as multiply-reduces (no product at the
-    MXU's precision)."""
-    k32, v32 = k.astype(jnp.float32), v.astype(jnp.float32)
-    kw = jax.nn.softmax(jnp.sum(k32 * mu, axis=-1), axis=-2)
-    vw = jax.nn.softmax(jnp.sum(k32 * phi, axis=-1)
-                        / jnp.sqrt(jnp.float32(k.shape[-1])), axis=-2)
-    return (jnp.sum(kw[..., None] * k32, axis=-3).astype(dtype),
-            jnp.sum(vw[..., None] * v32, axis=-3).astype(dtype))
-
-
-def _eva_ring(x, lengths, win: int):
-    """``x`` ``[b, s, h, d]`` (a prefill's keys or values) -> ``[b, win, h,
-    d]``: of each row the window its next position ``lengths[r]`` lies in,
-    position t at slot ``t mod win`` (zeros past the sequence; where the
-    next position opens a window past it, the last one, which the step
-    masks whole)."""
-    b, s = x.shape[:2]
-    n_win = -(-s // win)
-    x = jnp.pad(x, ((0, 0), (0, n_win * win - s), (0, 0), (0, 0)))
-    at = jnp.minimum(lengths // win, n_win - 1)
-    return jnp.take_along_axis(x.reshape(b, n_win, win, *x.shape[2:]),
-                               at[:, None, None, None, None], axis=1)[:, 0]
-
-
-def _eva_chunk_rows(leaf, at, chunk: int):
-    """``leaf`` ``[b, ring, h, d]``, ``at`` ``[b]`` (or a scalar a row) ->
-    ``[b, chunk, h, d]``: of each row the ``chunk`` ring slots from
-    ``at[r]``. A slice a row: as ONE gather the compiler copies the whole
-    ring into a layout of the gather's liking, every layer of every step
-    (compiled text for a v5e, PR 33)."""
-    return jnp.concatenate(
-        [jax.lax.dynamic_slice(leaf, (r, at[r], 0, 0),
-                               (1, chunk) + leaf.shape[2:])
-         for r in range(leaf.shape[0])], axis=0)
-
-
-def _eva_softmax_sum(q, parts):
-    """ONE float32 softmax over several key sets: ``q`` ``[b, s, h, d]``;
-    ``parts``: ``(keys [b, t, h, d], values [b, t, h, d], mask [b, s, t])``
-    each. Returns ``[b, s, h, d]``, float32 sums of the parts cast once."""
-    d = q.shape[-1]
-    logits = [jnp.where(
-        mask[:, None, :, :],
-        jnp.einsum("bshd,bthd->bhst", q, keys,
-                   preferred_element_type=jnp.float32)
-        / jnp.sqrt(d).astype(jnp.float32), jnp.float32(-1e9))
-        for keys, _, mask in parts]
-    probs = jax.nn.softmax(jnp.concatenate(logits, axis=-1), axis=-1)
-    out, at = 0.0, 0
-    for _, values, mask in parts:
-        t = mask.shape[-1]
-        out = out + jnp.einsum(
-            "bhst,bthd->bshd", probs[..., at:at + t].astype(values.dtype),
-            values, preferred_element_type=jnp.float32)
-        at += t
-    return out.astype(parts[0][1].dtype)
-
-
-def _eva_prefill_attend(q, k, v, sk, sv, mask, win: int, chunk: int):
-    """Prefill of more than one window: ONE body, a block of at most
-    ``EVA_QUERY_BLOCK`` queries a turn (``lax.map``), so the float32 scores
-    are ``[heads, block, win + chunks]`` whatever the prompt's length. A
-    query of window w attends that window's keys causally and the summaries
-    of the chunks before it."""
-    b, s, h, d = q.shape
-    n_win = -(-s // win)
-    pad = n_win * win - s
-    block = min(win, EVA_QUERY_BLOCK)
-    per_win = win // block
-
-    def cut(x, size):
-        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-        return jnp.moveaxis(x.reshape(b, -1, size, *x.shape[2:]), 1, 0)
-
-    kw, vw, mw = cut(k, win), cut(v, win), cut(mask, win)
-    chunks = jnp.arange(sk.shape[1])
-
-    def body(args):
-        i, qi = args
-        w = i // per_win
-        at = (i % per_win) * block + jnp.arange(block)   # place in the window
-        own = jax.lax.dynamic_index_in_dim(mw, w, 0, False)[:, None, :] \
-            & (jnp.arange(win)[None, :] <= at[:, None])[None]
-        earlier = jnp.broadcast_to(chunks < w * (win // chunk),
-                                   (b, block, chunks.shape[0]))
-        return _eva_softmax_sum(
-            qi, ((jax.lax.dynamic_index_in_dim(kw, w, 0, False),
-                  jax.lax.dynamic_index_in_dim(vw, w, 0, False), own),
-                 (sk, sv, earlier)))
-
-    out = jax.lax.map(body, (jnp.arange(n_win * per_win), cut(q, block)))
-    return jnp.moveaxis(out, 0, 1).reshape(b, n_win * win, h, d)[:, :s]
-
-
-# A sparse (DeepSeek Sparse Attention) prefill runs one body a turn of
-# DSA_QUERY_BLOCK queries inside each block of DSA_KEY_BLOCK keys: a turn
-# scores, selects among and attends the keys up to its own key block's end,
-# so a prompt of n key blocks is given n (n + 1) / 2 of them, not n x n; and
-# a key block runs only the turns that BEGIN before the last real token of
-# the longest row (a loop whose trip count is the rows' length operand's):
-# the turns that hold nothing but a bucket's padding are not run, and their
-# outputs, which no real position reads, are zeros (:func:`dsa_prefill_turns`,
-# the iteration space, which the engine's counter reads too). Two constants
-# on purpose. DSA_KEY_BLOCK, the loop's, is ``index_topk`` of the model
-# served: a key block is the overhang of a turn's keys over its causal
-# frontier, so the smaller the less is run that causality hides, and the
-# first block (no more keys than the selection takes) runs neither indexer
-# score nor selection. DSA_PROMPT_BLOCK, the bucket's, is what prompts past
-# it are padded to whole multiples of (``LlamaConfig.prompt_bucket``): a
-# program a bucket, so the coarser the fewer programs; what it pads, the
-# loop does not run. Inside a turn the heads (the indexer's, then the
-# attention's) go a group at a time, so that no float32 score tensor
-# ``[heads of a group, queries, keys]`` is larger than DSA_SCORE_BYTES: what
-# the v5e compiler keeps in its fast memory through every pass of the
-# softmax (``tests/test_chip_compile.py``: 24 MiB it keeps, 32 it spills);
-# whole, 128 heads x 128 x 12288 float32 scores are 0.8 GB a turn. 128
-# queries a turn is what this tree's served runs were made at; alone on the
-# chip (zero weights, no server) the 12288 prefill of a routed layer took
-# 0.337 s at 128 queries a turn, 0.318 at 256 and 0.312 at 512 (PERF.md
-# section 6, PR 35, which also says why 128 stayed; PR 43 for the blocks).
-DSA_QUERY_BLOCK = 128
-DSA_KEY_BLOCK = 2048
-DSA_PROMPT_BLOCK = 4096
+# The float32 scores of one turn that the v5e compiler keeps in its fast
+# memory through every pass of the softmax (``tests/test_chip_compile.py``:
+# 24 MiB it keeps, 32 it spills): the prefills that select their keys by
+# content (``models/latent.py``, ``models/sparse_kv.py``) run their heads a
+# group at a time under it (:func:`_head_group`) and pick by the same exact
+# threshold (:func:`_dsa_select_mask`).
 DSA_SCORE_BYTES = 24 << 20
-
-
-def dsa_prefill_turns(longest, s: int, clip=None):
-    """The iteration space of a sparse prefill padded to ``s`` positions
-    whose longest row has ``longest`` real tokens: for each key block
-    ``(at, t, turns run)``: the queries ``at .. t - 1`` go, DSA_QUERY_BLOCK
-    a turn, against the keys ``0 .. t - 1``, and only the turns that begin
-    before position ``longest`` are run. ``longest`` is an int (the engine's
-    counter) or a traced scalar with ``clip=jnp.clip`` (the program's trip
-    counts): one arithmetic for both."""
-    block = min(s, DSA_QUERY_BLOCK)
-    clip = clip or (lambda x, lo, hi: max(lo, min(x, hi)))
-    for at in range(0, s, DSA_KEY_BLOCK):
-        t = min(at + DSA_KEY_BLOCK, s)
-        yield at, t, clip(-(-(longest - at) // block), 0,
-                          -(-(t - at) // block))
 
 
 def _head_group(heads: int, elements_a_head: int) -> int:
@@ -1074,63 +846,6 @@ def _head_group(heads: int, elements_a_head: int) -> int:
                          or 4 * group * elements_a_head > DSA_SCORE_BYTES):
         group -= 1
     return group
-
-
-def _dsa_scores(q_idx, w_idx, k_idx):
-    """The lightning indexer's score of every key for every query:
-    ``q_idx`` ``[b, s, index heads, d]``, ``w_idx`` ``[b, s, index heads]``
-    float32 (already scaled), ``k_idx`` ``[b, t, d]`` -> ``[b, s, t]``
-    float32, ``sum_j w_j ReLU(q_j . k)``: the per-head products accumulate
-    in float32 and the weighted sum over heads is a float32
-    multiply-reduce, a group of heads at a time where all at once would be
-    a large tensor (:func:`_head_group`)."""
-    b, s, heads, d = q_idx.shape
-
-    def part(q_g, w_g):
-        dots = jnp.einsum("bsjd,btd->bsjt", q_g, k_idx,
-                          preferred_element_type=jnp.float32)
-        return jnp.sum(jax.nn.relu(dots) * w_g[..., None], axis=2)
-
-    group = _head_group(heads, b * s * k_idx.shape[1])
-    if group == heads:
-        return part(q_idx, w_idx)
-    q_g = jnp.moveaxis(q_idx.reshape(b, s, heads // group, group, d), 2, 0)
-    w_g = jnp.moveaxis(w_idx.reshape(b, s, heads // group, group), 2, 0)
-    total, _ = jax.lax.scan(
-        lambda acc, qw: (acc + part(*qw), None),
-        jnp.zeros((b, s, k_idx.shape[1]), jnp.float32), (q_g, w_g))
-    return total
-
-
-def _dsa_block_attend(q, k_groups, v_groups, seen, scale, dtype):
-    """A block of queries under its selection: ``q`` ``[b, block, heads,
-    d]``; ``k_groups`` / ``v_groups`` ``[groups, b, t or more, heads a
-    group, d]`` (the expanded keys and values, regrouped once a size of
-    group: a turn reads the first ``t`` where they lie); ``seen`` ``[b,
-    block, t]`` bool. One float32 softmax a head over the seen keys, a
-    group of heads a turn. ``[b, block, heads, v width]``."""
-    groups, b, _, group, _ = k_groups.shape
-    block, t = q.shape[1], seen.shape[-1]
-
-    def turn(args):
-        g, q_g = args
-        # (one dynamic slice of the extent read: a slice of a whole group
-        # would be a copy of it a turn)
-        k_g, v_g = (jax.lax.dynamic_slice(
-            x, (g, 0, 0, 0, 0), (1, b, t, group, x.shape[-1]))[0]
-            for x in (k_groups, v_groups))
-        logits = jnp.einsum("bqhd,bthd->bhqt", q_g, k_g,
-                            preferred_element_type=jnp.float32) * scale
-        logits = jnp.where(seen[:, None], logits, jnp.float32(-1e9))
-        probs = jax.nn.softmax(logits, axis=-1)
-        return jnp.einsum("bhqt,bthd->bqhd", probs.astype(dtype), v_g)
-
-    q_groups = jnp.moveaxis(q.reshape(b, block, groups, group, q.shape[-1]),
-                            2, 0)
-    if groups == 1:
-        return turn((0, q_groups[0]))
-    out = jax.lax.map(turn, (jnp.arange(groups), q_groups))
-    return jnp.moveaxis(out, 0, 2).reshape(b, block, groups * group, -1)
 
 
 def _dsa_select_mask(scores, visible, k: int):
@@ -1210,65 +925,9 @@ def _cache_write(cache, store, idx, b: int, s: int, band: int = 0):
     return new_cache, valid, t
 
 
-def _tail_write(cache, store):
-    """A decode segment's write (:func:`_scan_decode`, ``tail_window``):
-    the layer's cache leaves are READ here and never written; this step's
-    ``store`` leaves go to position ``cache["step"]`` of the segment's
-    tail, ``cache["tail"]`` (a leaf ``[b, segment, ...]`` for each cache
-    leaf, in its dtype), the same position for every row because a
-    segment's rows advance in lockstep. Returns ``(new tail, valid [b, 1,
-    t], seen [segment])``: a row attends what its cache held when the
-    segment began (``t < index``) and the tail positions written so far."""
-    from lambdipy_tpu.parallel.sharding import shard_hint
-
-    j = cache["step"]
-    tail = {name: shard_hint(
-                jax.lax.dynamic_update_slice(cache["tail"][name], val,
-                                             (0, j, 0, 0)), "dp", None, "tp")
-            for name, val in store.items()}
-    first = next(iter(store))
-    valid = (jnp.arange(cache[first].shape[1])[None, None, :]
-             < cache["index"][:, None, None])
-    return tail, valid, jnp.arange(tail[first].shape[1]) <= j
-
-
 class LlamaBlock(nn.Module):
     cfg: LlamaConfig
     layer: int = 0  # which layer of the model: cfg.layer_spec(layer)
-
-    def _prefill_attend(self, q, k, v, mask, sp_prefill: int = 0):
-        """Causal prefill attention via the configured backend.
-
-        ``sp_prefill >= 2`` requests the whole-prompt sequence-parallel
-        tier regardless of the configured backend: the first chunk of an
-        sp-prefill program ring-shards the full prompt's attention over
-        the sp axis. Falls through to the configured backend when no
-        usable sp mesh exists (the caller counts the stand-down)."""
-        cfg = self.cfg
-        s = q.shape[1]
-        backend = cfg.attn_backend
-        if backend == "ring" or sp_prefill >= 2:
-            from lambdipy_tpu.parallel.ring import ring_attention
-
-            mesh = _active_sp_mesh()
-            if mesh is not None:
-                # sequence-parallel long-context path; the padding mask is
-                # threaded as the ring's key-validity mask, so padded
-                # batches match the dense backend exactly
-                return ring_attention(q, k, v, mesh, causal=True,
-                                      kv_mask=mask)
-            backend = cfg.attn_backend if backend != "ring" else "dense"
-        if backend == "flash":
-            from lambdipy_tpu.ops import kernels_compile_here
-            from lambdipy_tpu.ops.attention import (flash_attention,
-                                                    mha_reference)
-
-            if kernels_compile_here():
-                return flash_attention(q, k, v, causal=True)
-            return mha_reference(q, k, v, causal=True)
-        causal = jnp.tril(jnp.ones((s, s), dtype=jnp.bool_))
-        attn_mask = mask[:, None, :] & causal[None, :, :]
-        return _attend(q, k, v, attn_mask)
 
     @nn.compact
     def __call__(self, x, positions, mask, cache, sp_prefill: int = 0,
@@ -1291,31 +950,23 @@ class LlamaBlock(nn.Module):
         have exposed chunk by chunk."""
         cfg = self.cfg
         spec = cfg.layer_spec(self.layer)
-        # the scope names (qkv_proj, kv_write, attend, o_proj, mlp; embed,
-        # lm_head, sample further down; mla_absorb, router, experts,
-        # shared_expert of the latent and routed kinds) reach each device
-        # operation's op_name: the trace is split by them (PERF.md).
+        # the scope names (o_proj, mlp; embed, lm_head, sample further
+        # down; qkv_proj, kv_write, attend and a kind's own in its module;
+        # router, experts, shared_expert of the routed FFN) reach each
+        # device operation's op_name: the trace is split by them (PERF.md).
         # Renaming or moving one: bump utils/compile_cache.NAMES_GEN
         # and LlamaServer._AOT_GEN
         b, s, _ = x.shape
         kind = attn_kind_module(spec.attn)
-        if kind is not None:
-            if band or sp_prefill:
-                raise NotImplementedError(
-                    f"a {spec.attn} layer under a sliding band or the "
-                    "sequence-parallel prefill is not written (PERF.md "
-                    "section 7)")
-            out, new_cache = kind.attend(self, x, positions, mask, cache,
-                                         lengths)
-        elif spec.attn == "latent":
-            out, new_cache = self._latent_attend(x, positions, mask, cache,
-                                                 band, lengths)
-        elif spec.attn == "eva":
-            out, new_cache = self._eva_attend(x, positions, mask, cache,
-                                              lengths)
-        else:
-            out, new_cache = self._kv_attend(x, positions, mask, cache,
-                                             sp_prefill, band)
+        forms = {name: form for name, form in (("sp_prefill", sp_prefill),
+                                               ("band", band)) if form}
+        if not set(forms) <= set(getattr(kind, "FORMS", ())):
+            raise NotImplementedError(
+                f"a {spec.attn} layer under a sliding band or the "
+                "sequence-parallel prefill is not written (PERF.md "
+                "section 7)")
+        out, new_cache = kind.attend(self, x, positions, mask, cache,
+                                     lengths, **forms)
 
         # (1.0 adds nothing to the program; the product in float32: as a
         # bfloat16 constant 1.4 / sqrt(32) would be off by 0.17 %, in every
@@ -1358,600 +1009,6 @@ class LlamaBlock(nn.Module):
                 x = x + scaled(QDense(cfg.hidden, cfg.quant, cfg.dtype,
                                       name="down_proj")(nn.silu(gate) * up))
         return x, new_cache
-
-    def _latent_attend(self, x, positions, mask, cache, band: int,
-                       lengths=None):
-        """Multi-head latent attention: returns the heads' outputs
-        ``[b, s, heads, v_head]`` and the new cache entry, the latent
-        ``ckv`` (after its norm) and the one rotary key ``kpe`` (after
-        rope) of each token. Without a cache (prefill) keys and values are
-        expanded through ``kv_b_proj`` and attended like any multi-head
-        layer. With a cache the same function is computed absorbed: the
-        key half of ``kv_b_proj`` goes into the query (per head, qk_nope ->
-        kv_lora_rank), scores and the weighted sum run over the cached
-        latents themselves, and the value half maps the sum to v_head; the
-        window is never re-expanded (8 rows x 400 tokens x 32 heads x 256
-        through a 512-wide matmul would be 0.4 TFLOP a step).
-
-        ``q_lora_rank`` > 0 compresses the query (``q_a_proj``,
-        ``q_a_norm``, ``q_b_proj`` in place of ``q_proj``). ``index_topk``
-        > 0 is DeepSeek Sparse Attention: a lightning indexer
-        (:meth:`_indexer`) scores every visible position for every query,
-        a query attends the ``index_topk`` positions of largest score
-        (exact, ties to the lowest position; every visible position while
-        they are fewer), and the entry gains the indexer's key ``kidx`` of
-        each token. Prefill then attends in blocks of queries and never
-        builds a ``[heads, s, s]`` score (:meth:`_sparse_prefill_attend`);
-        a one-token step scores the window's cached ``kidx`` rows, finds
-        the selection as a mask (:func:`_dsa_select_mask`) and attends the
-        window's rows under it, absorbed as ever."""
-        cfg = self.cfg
-        heads, dn, dr = cfg.heads, cfg.qk_nope, cfg.qk_rope
-        dv, rank = cfg.v_head, cfg.kv_lora_rank
-        b, s, _ = x.shape
-        if cfg.index_topk and (band or (cache is not None and s != 1)):
-            raise NotImplementedError(
-                "sparse attention is computed by the whole-prompt prefill "
-                f"and the one-token step: a chunk of {s} positions against "
-                "a cache (a prefix continued, a draft verified) or a "
-                "sliding band is not written (PERF.md section 7)")
-        with jax.named_scope("qkv_proj"):
-            h = RMSNorm(cfg.norm_eps, name="attn_norm")(x)
-            if cfg.q_lora_rank:
-                # the compressed query, which the indexer shares
-                c_q = RMSNorm(cfg.norm_eps, name="q_a_norm")(
-                    QDense(cfg.q_lora_rank, cfg.quant, cfg.dtype,
-                           name="q_a_proj")(h))
-                q = QDense(heads * (dn + dr), cfg.quant, cfg.dtype,
-                           name="q_b_proj")(c_q)
-            else:
-                q = QDense(heads * (dn + dr), cfg.quant, cfg.dtype,
-                           name="q_proj")(h)
-            kva = QDense(rank + dr, cfg.quant, cfg.dtype, name="kv_a_proj")(h)
-            ckv = RMSNorm(cfg.norm_eps, name="kv_a_norm")(kva[..., :rank])
-            q = q.reshape(b, s, heads, dn + dr)
-            q_nope, q_pe = q[..., :dn], q[..., dn:]
-            k_pe = kva[..., rank:].reshape(b, s, 1, dr)
-            if cfg.rope_interleave:
-                q_pe, k_pe = _deinterleave(q_pe), _deinterleave(k_pe)
-            q_pe, k_pe = rope(q_pe, k_pe, positions, cfg.rope_theta,
-                              cfg.rope_scaling)
-            ckv = ckv.reshape(b, s, 1, rank)
-        w, w_scale = QKernel(rank, heads * (dn + dv), cfg.quant, cfg.dtype,
-                             name="kv_b_proj")()
-        if cfg.index_topk:
-            with jax.named_scope("dsa_index"):
-                q_idx, k_idx, w_idx = self._indexer(h, c_q, positions)
-
-        if cache is None:
-            with jax.named_scope("qkv_proj"):
-                kv = jnp.matmul(ckv[:, :, 0], w.astype(cfg.dtype),
-                                preferred_element_type=jnp.float32)
-                if w_scale is not None:
-                    kv = kv * w_scale
-                kv = kv.astype(cfg.dtype).reshape(b, s, heads, dn + dv)
-                k = jnp.concatenate(
-                    [kv[..., :dn],
-                     jnp.broadcast_to(k_pe, (b, s, heads, dr))], axis=-1)
-                q = jnp.concatenate([q_nope, q_pe], axis=-1)
-            if cfg.index_topk:
-                out = self._sparse_prefill_attend(q, k, kv[..., dn:], q_idx,
-                                                  k_idx[:, :, 0], w_idx, mask,
-                                                  lengths)
-                return out, {"ckv": ckv, "kpe": k_pe, "kidx": k_idx}
-            with jax.named_scope("attend"):
-                causal = jnp.tril(jnp.ones((s, s), dtype=jnp.bool_))
-                out = _attend(q, k, kv[..., dn:],
-                              mask[:, None, :] & causal[None, :, :])
-            return output_gate(cfg, out, h), {"ckv": ckv, "kpe": k_pe}
-
-        with jax.named_scope("kv_write"):
-            new_cache, valid, t = _cache_write(
-                cache, _kv_store(cfg, ckv, k_pe, layer=self.layer),
-                cache["index"], b, s, band)
-        if cfg.index_topk:
-            new_cache["kidx"], valid = self._sparse_select(
-                cache, q_idx, k_idx, w_idx, jnp.broadcast_to(valid, (b, s, t)))
-        w = w.reshape(rank, heads, dn + dv)
-        with jax.named_scope("mla_absorb"):
-            # q' = q_nope W_k^T per head; a per-output-channel scale sits
-            # on the contracted axis here, so it multiplies the query
-            if w_scale is not None:
-                q_nope = (q_nope.astype(jnp.float32)
-                          * w_scale.reshape(heads, dn + dv)[:, :dn]
-                          ).astype(cfg.dtype)
-            q_lat = jnp.einsum("bshd,rhd->bshr", q_nope,
-                               w[..., :dn].astype(cfg.dtype))
-        with jax.named_scope("attend"):
-            lat, kpe = new_cache["ckv"][:, :, 0], new_cache["kpe"][:, :, 0]
-            logits = (jnp.einsum("bshr,btr->bhst", q_lat, lat,
-                                 preferred_element_type=jnp.float32)
-                      + jnp.einsum("bshd,btd->bhst", q_pe, kpe,
-                                   preferred_element_type=jnp.float32))
-            logits = logits / jnp.sqrt(dn + dr).astype(jnp.float32)
-            if cfg.attn_scale_mult != 1.0:
-                logits = logits * jnp.float32(cfg.attn_scale_mult)
-            logits = jnp.where(
-                jnp.broadcast_to(valid, (b, s, t))[:, None, :, :], logits,
-                jnp.float32(-1e9))
-            probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
-            ctx = jnp.einsum("bhst,btr->bshr", probs, lat)
-        with jax.named_scope("mla_absorb"):
-            out = jnp.einsum("bshr,rhd->bshd", ctx,
-                             w[..., dn:].astype(cfg.dtype),
-                             preferred_element_type=jnp.float32)
-            if w_scale is not None:
-                out = out * w_scale.reshape(heads, dn + dv)[:, dn:]
-        return output_gate(cfg, out.astype(cfg.dtype), h), new_cache
-
-    def _indexer(self, h, c_q, positions):
-        """The lightning indexer's projections (under ``dsa_index``), from
-        the normed input ``h`` and the compressed query ``c_q``:
-        ``index_heads`` queries ``q_j = W_qb,j c_q`` ``[b, s, heads, d]``,
-        ONE key a token ``k = LayerNorm(W_k h)`` ``[b, s, 1, d]`` (gain and
-        bias), the first ``qk_rope`` dims of both roped with the
-        attention's frequencies as HALVES (never interleaved), and a
-        float32 weight a head ``w = W_w h x index_heads^-1/2 x
-        index_head_dim^-1/2`` ``[b, s, heads]``."""
-        cfg = self.cfg
-        n_idx, d_idx, dr = cfg.index_heads, cfg.index_head_dim, cfg.qk_rope
-        b, s, _ = h.shape
-        q_idx = QDense(n_idx * d_idx, cfg.quant, cfg.dtype,
-                       name="index_wq_b")(c_q).reshape(b, s, n_idx, d_idx)
-        k32 = QDense(d_idx, cfg.quant, cfg.dtype, name="index_wk")(h).astype(
-            jnp.float32)
-        k32 = k32 - jnp.mean(k32, axis=-1, keepdims=True)
-        k32 = k32 * jax.lax.rsqrt(
-            jnp.mean(k32 * k32, axis=-1, keepdims=True) + 1e-6)
-        k_idx = (k32 * self.param("index_k_norm_scale", nn.initializers.ones,
-                                  (d_idx,), jnp.float32)
-                 + self.param("index_k_norm_bias", nn.initializers.zeros,
-                              (d_idx,), jnp.float32)
-                 ).astype(cfg.dtype).reshape(b, s, 1, d_idx)
-        q_rot, k_rot = rope(q_idx[..., :dr], k_idx[..., :dr], positions,
-                            cfg.rope_theta, cfg.rope_scaling)
-        q_idx = jnp.concatenate([q_rot, q_idx[..., dr:]], axis=-1)
-        k_idx = jnp.concatenate([k_rot, k_idx[..., dr:]], axis=-1)
-        # float32 all the way, like the router: the weights decide a top-k
-        # with near-ties
-        w_idx = jnp.matmul(
-            h.astype(jnp.float32),
-            self.param("index_weights_proj", nn.initializers.lecun_normal(),
-                       (h.shape[-1], n_idx), jnp.float32),
-            precision=jax.lax.Precision.HIGHEST) \
-            * jnp.float32((n_idx * d_idx) ** -0.5)
-        return q_idx, k_idx, w_idx
-
-    def _sparse_prefill_attend(self, q, k, v, q_idx, k_idx, w_idx, mask,
-                               lengths=None):
-        """A sparse prefill's attention over the expanded keys ``k`` and
-        values ``v`` ``[b, s, heads, ..]``: ONE body runs a turn of
-        ``DSA_QUERY_BLOCK`` queries inside each block of ``DSA_KEY_BLOCK``
-        keys: it scores the keys up to the key block's end (``q_idx``,
-        ``w_idx``; ``k_idx`` ``[b, s, d]``), selects
-        (:func:`_dsa_select_mask`) and attends under the selection's mask,
-        a group of heads at a time (:func:`_dsa_block_attend`). ``[b, s,
-        heads, v width]``.
-
-        What is run (:func:`dsa_prefill_turns`): in a key block, the turns
-        that begin before the longest row's last real token (``lengths``
-        ``[b]``, each right-padded row's; without it, a row ends where its
-        ``mask`` does), each against the keys up to its own key block's end.
-        What is not: a turn that holds only padding, hence a key block past
-        the prompt, and the keys past a turn's key block (causally hidden
-        from every query of it). A turn not run leaves zeros, at positions
-        no real position reads (a real query sees ``mask & causal``, the
-        logits are read at ``length - 1``, the cache's index is the
-        length)."""
-        cfg = self.cfg
-        heads, topk = cfg.heads, cfg.index_topk
-        b, s = q.shape[:2]
-        scale = jnp.float32(cfg.attn_scale_mult / math.sqrt(q.shape[-1]))
-        block = min(s, DSA_QUERY_BLOCK)
-        if lengths is None:
-            lengths = jnp.max(jnp.where(mask, jnp.arange(1, s + 1), 0), axis=-1)
-        plan = [(at, t, live, _head_group(heads, b * block * t))
-                for at, t, live in dsa_prefill_turns(jnp.max(lengths), s,
-                                                     jnp.clip)]
-        # the keys and values regrouped ONCE a size of head group, to the
-        # farthest key any block of that size reads: a key block takes a
-        # prefix of it
-        reach = {group: t for _, t, _, group in plan}
-        grouped = {group: tuple(jnp.moveaxis(x[:, :t].reshape(
-            b, t, heads // group, group, x.shape[-1]), 2, 0) for x in (k, v))
-            for group, t in reach.items()}
-        outs = []
-        for at, t, live, group in plan:
-            # the queries at .. t - 1 against the keys 0 .. t - 1
-            width = -(-(t - at) // block) * block
-            k_t, v_t = grouped[group]
-            q_t, qi_t, wi_t = (
-                jnp.pad(x[:, at:t], ((0, 0), (0, width - (t - at)))
-                        + ((0, 0),) * (x.ndim - 2))
-                for x in (q, q_idx, w_idx))
-
-            def turn(i, out, at=at, t=t, k_t=k_t, v_t=v_t, q_t=q_t,
-                     qi_t=qi_t, wi_t=wi_t):
-                q_i, qi_i, wi_i = (jax.lax.dynamic_slice_in_dim(
-                    x, i * block, block, 1) for x in (q_t, qi_t, wi_t))
-                pos = at + i * block + jnp.arange(block)
-                seen = mask[:, None, :t] & (jnp.arange(t)[None, :]
-                                            <= pos[:, None])[None]
-                if t > topk:
-                    with jax.named_scope("dsa_index"):
-                        score = _dsa_scores(qi_i, wi_i, k_idx[:, :t])
-                    with jax.named_scope("dsa_select"):
-                        seen = _dsa_select_mask(score, seen, topk)
-                with jax.named_scope("attend"):
-                    return jax.lax.dynamic_update_slice_in_dim(
-                        out, _dsa_block_attend(q_i, k_t, v_t, seen, scale,
-                                               cfg.dtype), i * block, 1)
-
-            out = jax.lax.fori_loop(
-                0, live, turn,
-                jnp.zeros((b, width, heads, v.shape[-1]), cfg.dtype))
-            outs.append(out[:, :t - at])
-        return jnp.concatenate(outs, axis=1)
-
-    def _sparse_select(self, cache, q_idx, k_idx, w_idx, valid):
-        """A sparse one-token step's selection: writes the step's indexer
-        key into the ``kidx`` leaf and scores every cached position of the
-        window (under ``dsa_index``), then finds, of the ``valid`` ``[b, 1,
-        t]`` positions, the ``index_topk`` of largest score as a MASK
-        (under ``dsa_select``; a window no longer than that keeps them
-        all). Returns ``(the new kidx leaf, the mask [b, 1, t])``. The
-        window's rows are then attended where they lie: at 4 rows of 16384
-        a gather of the picked rows behind ``jax.lax.top_k`` took 4.2 ms a
-        step of 7 layers (3.5 of it the gather, 0.6 the sort) where the
-        mask and the masked read take 1.6 (PERF.md section 6, PR 35); a
-        window many times ``index_topk`` long would want the gather back."""
-        cfg = self.cfg
-        b, _, t = valid.shape
-        with jax.named_scope("dsa_index"):
-            kidx = _cache_write(cache, {"kidx": k_idx.astype(cfg.dtype)},
-                                cache["index"], b, 1)[0]["kidx"]
-            score = _dsa_scores(q_idx, w_idx, kidx[:, :, 0])
-        picked = valid
-        if t > cfg.index_topk:
-            with jax.named_scope("dsa_select"):
-                picked = _dsa_select_mask(score, valid, cfg.index_topk)
-        if self.layer == 0:
-            # the keys a row's step attended and those it chose from: every
-            # layer's counts are the same (_scan_decode, count_dsa; /metrics
-            # handler.dsa)
-            self.sow("dsa_stats", "keys", jnp.stack(
-                [picked.sum(-1)[:, 0], valid.sum(-1)[:, 0]],
-                axis=-1).astype(jnp.int32))
-        return kidx, picked
-
-    def _project_qkv(self, x, positions):
-        """The per-head kinds' projections: ``q`` ``[b, s, heads, d]``,
-        ``k`` / ``v`` ``[b, s, kv_heads, d]``, ``q`` and ``k`` after rope."""
-        cfg = self.cfg
-        d = cfg.head_dim
-        b, s, _ = x.shape
-        with jax.named_scope("qkv_proj"):
-            h = RMSNorm(cfg.norm_eps, cfg.norm_unit_offset,
-                        name="attn_norm")(x)
-            q = QDense(cfg.heads * d, cfg.quant, cfg.dtype, name="q_proj")(h)
-            k = QDense(cfg.kv_heads * d, cfg.quant, cfg.dtype, name="k_proj")(h)
-            v = QDense(cfg.kv_heads * d, cfg.quant, cfg.dtype, name="v_proj")(h)
-            q = q.reshape(b, s, cfg.heads, d)
-            k = k.reshape(b, s, cfg.kv_heads, d)
-            v = v.reshape(b, s, cfg.kv_heads, d)
-            q, k = rope(q, k, positions, cfg.rope_theta, cfg.rope_scaling)
-        return q, k, v
-
-    def _kv_attend(self, x, positions, mask, cache, sp_prefill: int,
-                   band: int):
-        """Per-head K/V attention (the llama block): returns the heads'
-        outputs ``[b, s, heads, head_dim]`` and the new cache entry."""
-        cfg = self.cfg
-        b, s, _ = x.shape
-        q, k, v = self._project_qkv(x, positions)
-
-        if cache is None:
-            with jax.named_scope("attend"):
-                out = self._prefill_attend(q, k, v, mask, sp_prefill)
-            new_cache = {"k": k, "v": v}
-        else:
-            from lambdipy_tpu.parallel.sharding import shard_hint
-
-            # decode: append this step's k/v at cache index, attend over
-            # prefix. The cache stays kv-head-sharded over tp across the
-            # scan — the dominant serving HBM object must never be
-            # gathered per step
-            idx = cache["index"]  # int32 scalar, or [b] per-row positions
-            # sequence-parallel decode (attn_backend="ring" + an sp
-            # mesh): the cache seq dim stays SHARDED over sp for the
-            # whole scan and each step combines per-shard online-softmax
-            # partials with O(b*h*d) collectives — the long-context
-            # decode path, pairing with ring-attention prefill
-            # (parallel/spdecode.py). Composes with kv_quant: the int8
-            # cache leaves shard the same way and the per-shard dequant
-            # fuses into the local attention einsum.
-            sp_done = False
-            if jnp.ndim(idx) != 0 and cfg.attn_backend == "ring":
-                sp_mesh = _active_sp_mesh()
-                if sp_mesh is not None and s == 1:
-                    from lambdipy_tpu.parallel.spdecode import (
-                        sp_decode_step)
-
-                    sp_new = _kv_store(cfg, k, v, layer=self.layer)
-                    sp_cache = {name: cache[name] for name in sp_new}
-                    with jax.named_scope("attend"):
-                        out, new_cache = sp_decode_step(
-                            q, sp_new, sp_cache, idx, sp_mesh)
-                    sp_done = True
-                elif sp_mesh is not None:
-                    # a multi-token verify chunk under the ring backend:
-                    # sp decode is a one-token-step formulation, so the
-                    # chunk runs the replicated dense path — observable,
-                    # not silent (ROADMAP direction-2 note)
-                    from lambdipy_tpu.parallel.spdecode import (
-                        note_standdown)
-
-                    note_standdown("multi_token_chunk")
-            elif jnp.ndim(idx) != 0 and _active_sp_mesh() is not None:
-                # the mesh HAS an sp axis but the configured backend
-                # (blocked/dense/flash) routes decode around sp_decode:
-                # the cache this step reads is replicated despite the
-                # sharding the operator asked for. Count + log once per
-                # reason so the condition is visible on /metrics.
-                from lambdipy_tpu.parallel.spdecode import note_standdown
-
-                note_standdown(f"attn_backend={cfg.attn_backend}")
-
-            def kv_of(leaves):
-                if cfg.kv_quant == "int8":
-                    return (_kv_dequantize(leaves["k_int8"],
-                                           leaves["k_scale"], cfg.dtype),
-                            _kv_dequantize(leaves["v_int8"],
-                                           leaves["v_scale"], cfg.dtype))
-                return leaves["k"], leaves["v"]
-
-            if "tail" in cache:
-                # a segment's step (_scan_decode, tail_window): the cache
-                # is read as the segment found it, this step's k/v joins
-                # the tail, one softmax over both
-                with jax.named_scope("kv_write"):
-                    new_cache, valid, seen = _tail_write(
-                        cache, _kv_store(cfg, k, v, layer=self.layer))
-                with jax.named_scope("attend"):
-                    out = _attend(q, *kv_of(cache), valid,
-                                  tail=(*kv_of(new_cache), seen))
-            elif not sp_done:
-                with jax.named_scope("kv_write"):
-                    # quantize this chunk's k/v once under kv_quant; the
-                    # cache stays int8 in HBM and the dequant fuses into
-                    # the attention einsum
-                    new_cache, valid, t = _cache_write(
-                        cache, _kv_store(cfg, k, v, layer=self.layer), idx,
-                        b, s, band)
-                with jax.named_scope("attend"):
-                    # length-aware blocked decode attention: one-token steps
-                    # read each row's ACTIVE window instead of the full
-                    # static cache (bytes scale with context actually held).
-                    # Manual (unpartitioned) op like QDense's pallas backend:
-                    # only taken with no ambient mesh; the valid mask built
-                    # above is exactly "position < index + 1", so active_len
-                    # = idx + 1 reproduces it row for row.
-                    blocked = False
-                    if cfg.attn_backend == "blocked" and s == 1:
-                        from lambdipy_tpu.ops.decode_attention import (
-                            decode_attention)
-                        from lambdipy_tpu.parallel.mesh import current_mesh
-
-                        if current_mesh() is None:
-                            active = jnp.broadcast_to(
-                                jnp.asarray(idx, jnp.int32) + 1, (b,))
-                            if cfg.kv_quant == "int8":
-                                out = decode_attention(
-                                    q, new_cache["k_int8"],
-                                    new_cache["v_int8"], active,
-                                    k_scale=new_cache["k_scale"],
-                                    v_scale=new_cache["v_scale"])
-                            else:
-                                out = decode_attention(
-                                    q, new_cache["k"], new_cache["v"], active)
-                            blocked = True
-                    if not blocked:
-                        ck, cv = kv_of(new_cache)
-                        attn_mask = jnp.broadcast_to(valid, (b, s, t))
-                        sp_mesh = (_active_sp_mesh()
-                                   if (sp_prefill >= 2 and s > 1
-                                       and jnp.ndim(idx) == 0
-                                       and s % sp_prefill == 0) else None)
-                        if sp_mesh is not None:
-                            # sp-prefill continuation chunk: queries shard
-                            # over sp, the cache stays replicated (as decode
-                            # keeps it) — score memory and the softmax walk
-                            # split across the mesh, no per-layer collective
-                            from lambdipy_tpu.parallel.ring import (
-                                sp_chunk_attention)
-
-                            out = sp_chunk_attention(q, ck, cv, attn_mask,
-                                                     sp_mesh)
-                        else:
-                            out = _attend(q, ck, cv, attn_mask)
-        return out, new_cache
-
-    def _eva_attend(self, x, positions, mask, cache, lengths):
-        """EVA attention (``attn_kind`` "eva"): returns the heads' outputs
-        ``[b, s, heads, head_dim]`` and the new cache entry. Position t
-        attends the keys of its own window of ``window_size`` positions
-        exactly (causal) and, under the same float32 softmax, one pooled
-        key and value for every chunk of ``chunk_size`` positions of every
-        EARLIER window (:func:`_eva_pool`, the two learned vectors a head).
-
-        Without a cache (prefill, the whole forward) the sequence is cut
-        into windows and ONE body runs a block of a window's queries a turn
-        (:func:`_eva_prefill_attend`); the entry returned is already a
-        slot's: ``k`` / ``v`` the ring ``[b, window_size, ..]``
-        of the window each row's NEXT position ``lengths[r]`` lies in (the
-        whole sequence where ``lengths`` is None), each position at its
-        ``mod window_size`` slot, and ``sk`` / ``sv`` ALL the chunks'
-        summaries (:func:`_eva_ring`; a layer hands on a window, not the
-        sequence: 16 layers of an 8192 bucket would hold 2 GB). Right
-        padding is safe: a summary is attended only from a LATER window,
-        so a chunk that holds padding is attended by padding alone.
-
-        With a cache (one token a row): the step's K/V go to ring slot
-        ``t mod window_size``; the ring is attended under ``slot <= t mod
-        window_size`` and the summaries under ``chunk < (t // window_size)
-        * chunks a window``; when the step completes a chunk its rows,
-        all in the ring, are pooled and written at ``t // chunk_size``, and
-        otherwise that write drops (an out-of-range index, like a finished
-        slot's write). A window's summaries are all written before its ring
-        slots are overwritten, so nothing happens at a window's edge."""
-        cfg = self.cfg
-        d, heads = cfg.head_dim, cfg.heads
-        win, chunk = cfg.window_size, cfg.chunk_size
-        b, s, _ = x.shape
-        q, k, v = self._project_qkv(x, positions)
-        mu = self.param("adaptive_mu_k", nn.initializers.normal(1.0),
-                        (heads, d), jnp.float32)
-        phi = self.param("adaptive_phi", nn.initializers.normal(1.0),
-                         (heads, d), jnp.float32)
-
-        if cache is None:
-            with jax.named_scope("eva_summarize"):
-                n_chunks = -(-s // chunk)
-                pad = ((0, 0), (0, n_chunks * chunk - s), (0, 0), (0, 0))
-                sk, sv = _eva_pool(
-                    jnp.pad(k, pad).reshape(b, n_chunks, chunk, heads, d),
-                    jnp.pad(v, pad).reshape(b, n_chunks, chunk, heads, d),
-                    mu, phi, cfg.dtype)
-            with jax.named_scope("attend"):
-                if s <= win:  # one window: plain causal attention
-                    causal = jnp.tril(jnp.ones((s, s), dtype=jnp.bool_))
-                    out = _attend(q, k, v,
-                                  mask[:, None, :] & causal[None, :, :])
-                else:
-                    out = _eva_prefill_attend(q, k, v, sk, sv, mask, win,
-                                              chunk)
-            if lengths is None:
-                lengths = jnp.full((b,), s, jnp.int32)
-            return out, {"k": _eva_ring(k, lengths, win),
-                         "v": _eva_ring(v, lengths, win), "sk": sk, "sv": sv}
-
-        if s != 1:
-            raise NotImplementedError(
-                "an eva cache is stepped one token a row: a chunk of "
-                f"{s} positions against it (a prefix continued, a draft "
-                "verified) is not written (PERF.md section 7)")
-        if "tail" in cache:
-            return self._eva_tail_attend(q, k, v, mu, phi, cache)
-        idx = jnp.broadcast_to(cache["index"], (b,))
-        rows = jnp.arange(b)
-        ring, n_sum = cache["k"].shape[1], cache["sk"].shape[1]
-        slot = cfg.cache_slot("k", idx)
-        with jax.named_scope("kv_write"):
-            new_cache = {
-                "k": cache["k"].at[rows, slot].set(k[:, 0].astype(cfg.dtype)),
-                "v": cache["v"].at[rows, slot].set(v[:, 0].astype(cfg.dtype))}
-        with jax.named_scope("attend"):
-            seen = jnp.arange(ring)[None, :] <= slot[:, None]
-            earlier = (jnp.arange(n_sum)[None, :]
-                       < cfg.cache_slot("sk", idx // win * win)[:, None])
-            out = _eva_softmax_sum(
-                q, ((new_cache["k"], new_cache["v"], seen[:, None, :]),
-                    (cache["sk"], cache["sv"], earlier[:, None, :])))
-            if self.layer == 0:
-                # what a row's step had visible, whether it completed a
-                # chunk, and (a tail segment's column: 0 here) whether it
-                # came after a window edge inside its segment: every
-                # layer's are the same (_scan_decode, count_keys; /metrics
-                # handler.eva)
-                self.sow("eva_stats", "keys", jnp.stack(
-                    [seen.sum(-1) + earlier.sum(-1),
-                     idx % chunk == chunk - 1, jnp.zeros_like(idx)],
-                    axis=-1).astype(jnp.int32))
-        with jax.named_scope("eva_summarize"):
-            # the chunk this position lies in: its rows are ring slots
-            # first .. first + chunk - 1, this step's own among them
-            first = slot // chunk * chunk
-            sk, sv = _eva_pool(_eva_chunk_rows(new_cache["k"], first, chunk),
-                               _eva_chunk_rows(new_cache["v"], first, chunk),
-                               mu, phi, cfg.dtype)
-            at = jnp.where(idx % chunk == chunk - 1,
-                           cfg.cache_slot("sk", idx), n_sum)
-            new_cache["sk"] = cache["sk"].at[rows, at].set(sk)
-            new_cache["sv"] = cache["sv"].at[rows, at].set(sv)
-        return out, new_cache
-
-    def _eva_tail_attend(self, q, k, v, mu, phi, cache):
-        """A tail segment's step (:func:`_scan_decode`, ``tail_window``):
-        ring and summaries are READ as the segment found them and never
-        written. The segment's own rows lie in ``cache["tail"]``:
-
-        - ``k``, ``v`` ``[b, whole chunks, ..]``: consecutive positions
-          from the first of the chunk that was open when the segment
-          began, ``base[r] // chunk_size * chunk_size``: the ring's rows
-          of that chunk (:func:`_eva_tail_init`), over which, from
-          ``base[r]`` on, the segment's steps write theirs;
-        - ``sk``, ``sv`` ``[b, chunks, ..]``: slot m holding the summary of
-          chunk ``base[r] // chunk_size + m``, the m-th a row can complete
-          inside the segment.
-
-        The step, at position ``t = base[r] + j``, writes its K/V where the
-        tail holds t and attends under the ONE softmax
-
-        - the frozen ring while the row is in the window it began the
-          segment in, the slots written before the segment;
-        - the ring tail's positions so far that lie in t's window;
-        - the frozen summaries of the chunks of earlier windows that were
-          complete when the segment began;
-        - the summary tail's chunks of earlier windows: completed inside
-          the segment, before an edge the row has crossed since.
-
-        Where each lies is ``cache["plan"]``, the same for every layer
-        (:func:`_eva_tail_plan`). The same keys, values and probabilities
-        as the per-step write, the sum's order apart. The chunk t lies in
-        is pooled as the per-step write pools it, from ``chunk_size``
-        consecutive rows of ``k`` / ``v`` (open chunk and tail are one
-        array for that, and t's chunk one of its whole chunks), and lands
-        in the summary tail when t completes it; a chunk some row of which
-        is not yet written pools to garbage, which nothing selects.
-        Nothing of either tail is read as a number before its step wrote
-        it: keys are masked, values selected to zero (the v5e compiler
-        hands the scan a tail it has not initialised, and 0 x NaN is NaN).
-        Returns the heads' outputs and the new tail."""
-        cfg = self.cfg
-        tail, plan = cache["tail"], cache["plan"]
-        with jax.named_scope("kv_write"):
-            new_tail = {
-                name: tail[name].at[plan["rows"], plan["at"]].set(
-                    val[:, 0].astype(cfg.dtype))
-                for name, val in (("k", k), ("v", v))}
-        with jax.named_scope("attend"):
-            own = plan["own"][:, :, None, None]
-            inside = plan["inside"][:, :, None, None]
-            out = _eva_softmax_sum(q, (
-                (cache["k"], cache["v"], plan["held"][:, None, :]),
-                (new_tail["k"], jnp.where(own, new_tail["v"], 0),
-                 plan["here"][:, None, :]),
-                (cache["sk"], cache["sv"], plan["before"][:, None, :]),
-                (tail["sk"], jnp.where(inside, tail["sv"], 0),
-                 plan["inside"][:, None, :])))
-            if self.layer == 0:
-                # every layer's are the same (_scan_decode, count_keys;
-                # /metrics handler.eva)
-                self.sow("eva_stats", "keys", plan["stats"])
-        with jax.named_scope("eva_summarize"):
-            # the tail begins at a chunk's first position, so the chunk t
-            # lies in is one of its whole chunks
-            def chunk_of(leaf):
-                whole = leaf.reshape(leaf.shape[0], -1, cfg.chunk_size,
-                                     *leaf.shape[2:])
-                return jnp.take_along_axis(whole, plan["chunk"], axis=1)[:, 0]
-
-            sk, sv = _eva_pool(chunk_of(new_tail["k"]),
-                               chunk_of(new_tail["v"]), mu, phi, cfg.dtype)
-            lands = plan["lands"][:, :, None, None]
-            new_tail["sk"] = jnp.where(lands, sk[:, None], tail["sk"])
-            new_tail["sv"] = jnp.where(lands, sv[:, None], tail["sv"])
-        return out, new_tail
 
 
 class LlamaModel(nn.Module):
@@ -2020,20 +1077,16 @@ class LlamaModel(nn.Module):
 
 def _empty_cache_entry(cfg: LlamaConfig, batch: int, max_len: int,
                        layer: int = 0) -> dict:
-    if cfg.layer_kinds or cfg.attn_kind != "kv":
-        slots = cfg.cache_positions(max_len, layer)
-        dtypes = cfg.cache_dtypes(layer)
-        return {name: jnp.zeros((batch, slots[name], heads, width),
-                                dtypes[name])
-                for name, (heads, width) in cfg.cache_layout(layer).items()}
-    shape = (batch, max_len, cfg.kv_heads, cfg.head_dim)
-    if cfg.kv_quant == "int8":
+    if cfg.kv_quant == "int8":  # per-head K/V rows alone (_kv_store)
+        shape = (batch, max_len, cfg.kv_heads, cfg.head_dim)
         return {"k_int8": jnp.zeros(shape, jnp.int8),
                 "k_scale": jnp.full(shape[:3] + (1,), 1e-8, jnp.float32),
                 "v_int8": jnp.zeros(shape, jnp.int8),
                 "v_scale": jnp.full(shape[:3] + (1,), 1e-8, jnp.float32)}
-    return {"k": jnp.zeros(shape, cfg.dtype),
-            "v": jnp.zeros(shape, cfg.dtype)}
+    slots = cfg.cache_positions(max_len, layer)
+    dtypes = cfg.cache_dtypes(layer)
+    return {name: jnp.zeros((batch, slots[name], heads, width), dtypes[name])
+            for name, (heads, width) in cfg.cache_layout(layer).items()}
 
 
 def init_decode_cache(cfg: LlamaConfig, batch: int, max_len: int):
@@ -2090,19 +1143,15 @@ def validate_serving_mesh(cfg: LlamaConfig, mesh) -> None:
     ``tp=8`` over 4 kv heads would then pay an 8-chip mesh to replicate
     its dominant HBM object. Raise loudly instead."""
     shape = dict(getattr(mesh, "shape", {}) or {})
-    if (cfg.attn_kind != "kv" or cfg.ffn_kind != "dense"
+    if (cfg.attn_kinds != ("kv",) or cfg.ffn_kind != "dense"
             or cfg.layer_kinds) \
             and any(int(n) > 1 for n in shape.values()):
-        _refuse_kind_modules(
+        require_kv_cache(
             cfg, f"mesh {shape}: the cache layout sharded by kv head")
         raise NotImplementedError(
-            f"mesh {shape}: no sharding is written yet for latent attention "
-            "(a cache row has no head axis to split), eva attention (a "
-            "ring beside pooled summaries), kinds chosen a layer (a "
-            "block-sparse layer's compressed keys, a linear layer's state) "
-            "or the dropless routed "
-            "FFN (a chip's share of the experts): serve this model on one "
-            "device (PERF.md section 7)")
+            f"mesh {shape}: no sharding is written yet for kinds chosen a "
+            "layer or the dropless routed FFN (a chip's share of the "
+            "experts): serve this model on one device (PERF.md section 7)")
     tp = int(shape.get("tp", 1))
     if tp <= 1:
         return
@@ -2292,28 +1341,24 @@ def prefill_into_cache(cfg: LlamaConfig, prefill_cache, batch: int, max_len: int
     (kv-heads over tp) so prefill-produced caches — the prefix store's
     full-window entries included — leave their program tp-sharded
     instead of whatever replicated layout propagation falls back to
-    (no-op without an ambient mesh). An eva entry is already a slot's
-    (each row's ring, every chunk's summaries: ``LlamaBlock._eva_attend``)
-    and is cut to the slots the cache has: ring slots from the row's length
-    on hold what the step masks until it has written them, and the trailing
-    partial chunk's summary is overwritten when decode completes the chunk,
-    before anything may see it."""
+    (no-op without an ambient mesh). A kind whose leaves are not one row a
+    token hands on a slot's entry already (a ring and every chunk's
+    summaries, a state: its ``attend``), which is cut to the slots the
+    cache has: what lies past a row's length there is masked, or
+    overwritten, before anything may see it."""
     from lambdipy_tpu.parallel.sharding import shard_hint
 
     out = []
     for layer, entry in enumerate(prefill_cache):
-        slots = cfg.cache_positions(max_len, layer)
-        if cfg.attn_kind == "eva" \
-                or attn_kind_module(cfg.layer_spec(layer).attn) is not None:
-            # a slot's entry already (a kind's module hands on its leaves,
-            # each in its own dtype)
-            dtypes = cfg.cache_dtypes(layer)
-            store = {name: entry[name][:, :slots[name]].astype(dtypes[name])
-                     for name in cfg.cache_layout(layer)}
-        else:
+        if cfg.kv_quant:
             store = _kv_store(cfg, *(entry[name]
                                      for name in cfg.cache_layout(layer)),
                               layer=layer)
+        else:
+            slots = cfg.cache_positions(max_len, layer)
+            dtypes = cfg.cache_dtypes(layer)
+            store = {name: entry[name][:, :slots[name]].astype(dtypes[name])
+                     for name in cfg.cache_layout(layer)}
         dest = _empty_cache_entry(cfg, batch, max_len, layer)
         for name, val in store.items():
             dest[name] = shard_hint(
@@ -2494,49 +1539,18 @@ def _split_rows(keys):
 
 def segment_keeps_tail(cfg: LlamaConfig) -> bool:
     """Whether a decode segment leaves its cache unwritten until its end
-    (:func:`_scan_decode`, ``tail_window``). Decided from the shapes, by
-    what the v5e compiler does with the per-step write (PERF.md section
-    6, PR 30; ``tests/test_chip_compile.py`` holds both halves):
-
-    - ONE query a KV head (multi-head K/V): the scores are a multiply-
-      reduce served from a prefetched copy of the cache, the scatter
-      updates that copy, and the WHOLE copy goes home every layer of every
-      step. The tail takes the write out of the loop: 15.6 -> 12.6 ms a
-      step at DeepSeek-7B widths.
-    - several queries a KV head (grouped-query K/V, the latent cache's one
-      shared row): the scores are a convolution that reads HBM and the
-      scatter is in place there, 0.6 ms a step at Mistral-7B widths; with
-      a read-only cache the compiler prefetches the leaves in place of
-      weights, 13.0 -> 13.7 ms at the full 2048 window. They keep the
-      per-step write.
-
-    - an eva cache is multi-head too (a ring and chunk summaries, one
-      query a KV head): with a per-step write the compiler updates 14 of
-      32 ring leaves in the fast memory and copies each home whole, 0.94
-      GB a step at EvaByte widths (PERF.md section 6, PR 34). Its tail is
-      its own (:meth:`LlamaBlock._eva_tail_attend`): the segment's rows
-      behind those of the chunk that was open when it began, so that a
-      chunk is pooled from the tail alone, AND the summaries they
-      complete, since a row that completes chunk 127 at position 2047
-      attends it at 2048; the merge wraps round the ring
-      (:func:`_eva_tail_merge`).
-
-    The blocked Pallas kernel and the sp-sharded decode step
-    (``parallel/spdecode.py``) attend the ONE cache they are handed, so
-    their segments write it every step too. Asked while a segment program
-    is traced, under its mesh."""
-    if cfg.layer_kinds:
-        # a linear or kda state is a carry of the scan by nature; a
-        # block-sparse layer is grouped-query K/V, whose per-step write is
-        # in place, and its compressed key is pooled from rows the step must
-        # find in the cache. (A "kv" or "latent" layer beside them keeps the
-        # per-step write too.)
+    (:func:`_scan_decode`, ``tail_window``): where every layer is of ONE
+    kind and that kind says so under the model's shapes (its ``keeps_tail``,
+    which holds the measurements: what the v5e compiler does with the
+    per-step write of such leaves). A kind without the word keeps the
+    per-step write: a recurrent state is a carry of the scan by nature, and
+    rows whose per-step write is in place gain nothing. So does a model of
+    several kinds a layer, whatever they say: the tail's init, what a step
+    reads and the merge are one kind's over all the layers. Asked while a
+    segment program is traced, under its mesh."""
+    if len(cfg.attn_kinds) != 1:
         return False
-    if cfg.attn_kind == "latent" or cfg.heads != cfg.kv_heads:
-        return False
-    if cfg.attn_backend == "blocked":
-        return False
-    return cfg.attn_backend != "ring" or _active_sp_mesh() is None
+    return _ask(cfg.kind_of(0), "keeps_tail", False, cfg)
 
 
 def _leaf_spans(cfg: LlamaConfig, entry: dict, positions: int,
@@ -2544,115 +1558,10 @@ def _leaf_spans(cfg: LlamaConfig, entry: dict, positions: int,
     """``{leaf: slots}`` for the leaves of layer ``layer``'s cache entry
     ``entry``: the slots of each that ``positions`` consecutive positions
     from 0 lie in: ``positions`` itself where a leaf holds one row a
-    token."""
-    spans = cfg.cache_positions(positions, layer) \
-        if cfg.attn_kind == "eva" or cfg.layer_kinds else {}
+    token (and for the leaves ``kv_quant`` stores in a row's place)."""
+    spans = cfg.cache_positions(positions, layer)
     return {name: spans.get(name, positions)
             for name in entry if name != "index"}
-
-
-def _eva_tail_init(cfg: LlamaConfig, frozen: list, base, steps: int) -> list:
-    """An eva tail segment's tails, a layer, before its scan
-    (:meth:`LlamaBlock._eva_tail_attend` says what they hold). ``k``, ``v``
-    begin with the ring slots of the chunk each row's position ``base[r]``
-    lies in, the one chunk the segment completes whose first rows may lie
-    BEFORE it (every later chunk lies in the tail whole). Fetched here,
-    once a segment: sliced from the ring inside the scan, the pooled rows
-    hand the ring the tail's layout and the compiler transposes every ring
-    at the head of every segment (compiled text for a v5e, PR 34). The
-    rest is zeros; on the chip the compiler hands over uninitialised what
-    it sees the loop write."""
-    chunk = cfg.chunk_size
-    at = list(cfg.cache_slot("k", base // chunk * chunk))
-    k, sk = frozen[0]["k"], frozen[0]["sk"]
-    rest = jnp.zeros((k.shape[0], -(-steps // chunk) * chunk) + k.shape[2:],
-                     k.dtype)
-    none = jnp.zeros((sk.shape[0], cfg.cache_positions(steps)["sk"])
-                     + sk.shape[2:], sk.dtype)
-    return [{"k": jnp.concatenate(
-                 [_eva_chunk_rows(entry["k"], at, chunk), rest], axis=1),
-             "v": jnp.concatenate(
-                 [_eva_chunk_rows(entry["v"], at, chunk), rest], axis=1),
-             "sk": none, "sv": none} for entry in frozen]
-
-
-def _eva_tail_plan(cfg: LlamaConfig, entry: dict, tail: dict, base, j) -> dict:
-    """What every layer of an eva tail segment's step ``j`` reads alike,
-    computed once a step (``entry``, ``tail``: one layer's frozen leaves
-    and tails, for their lengths; ``base``: each row's position when the
-    segment began). With ``t = base[r] + j`` the step's position:
-
-    - ``rows``, ``at``: where the step's K/V go in the ring tail, a row's
-      own place (no lockstep: the tail begins at each row's open chunk);
-    - ``held`` ``[b, ring]``: the frozen ring's slots written before the
-      segment, while no window edge was crossed; ``own`` ``[b, tail]``:
-      the tail rows the segment has written so far, and ``here``: those
-      of t's window; ``before`` ``[b, summaries]``: the frozen summaries
-      of earlier windows complete when the segment began; ``inside``
-      ``[b, tail chunks]``: the summary tail's chunks of earlier windows;
-    - ``chunk`` ``[b, 1, 1, 1, 1]``: which of the ring tail's chunks t
-      lies in; ``lands`` ``[b, tail chunks]``: the slot t completes;
-    - ``stats`` int32 ``[b, 3]``: the keys visible, whether t completes a
-      chunk, whether t comes after a window edge inside the segment."""
-    win, chunk = cfg.window_size, cfg.chunk_size
-    t = base + j
-    ring, n_sum = entry["k"].shape[1], entry["sk"].shape[1]
-    # the position each row of the tail's k / v holds, and the chunk each
-    # slot of its sk / sv is for
-    held_at = (base // chunk * chunk)[:, None] \
-        + jnp.arange(tail["k"].shape[1])[None, :]
-    chunks = cfg.cache_slot("sk", base)[:, None] \
-        + jnp.arange(tail["sk"].shape[1])[None, :]
-    same = base // win == t // win          # no window edge crossed yet
-    held = same[:, None] & (jnp.arange(ring)[None, :]
-                            < cfg.cache_slot("k", base)[:, None])
-    own = (held_at >= base[:, None]) & (held_at <= t[:, None])
-    here = own & (held_at // win == (t // win)[:, None])
-    earlier = cfg.cache_slot("sk", t // win * win)[:, None]
-    before = jnp.arange(n_sum)[None, :] < jnp.minimum(earlier, chunks[:, :1])
-    inside = chunks < earlier
-    ends = t % chunk == chunk - 1
-    return {
-        "rows": jnp.arange(base.shape[0]), "at": base % chunk + j,
-        "held": held, "own": own, "here": here, "before": before,
-        "inside": inside,
-        "chunk": (t // chunk - base // chunk)[:, None, None, None, None],
-        "lands": (chunks == (t // chunk)[:, None]) & ends[:, None],
-        "stats": jnp.stack(
-            [held.sum(-1) + here.sum(-1) + before.sum(-1) + inside.sum(-1),
-             ends, ~same], axis=-1).astype(jnp.int32)}
-
-
-def _eva_tail_merge(cfg: LlamaConfig, full: list, tails: list, base,
-                    steps: int) -> list:
-    """An eva tail segment's ONE write of each layer's cache entry, after
-    its scan: the tail's row of position ``base[r] + j'`` goes to slot
-    ``(base[r] + j') mod window_size`` for each of the segment's steps j'
-    (it wraps where the row crossed a window's edge), and the
-    summary tail's slot m to chunk ``base[r] // chunk_size + m`` where the
-    segment completed that chunk; the others drop (an out-of-range index,
-    as the per-step write drops a step that completes none)."""
-    chunk = cfg.chunk_size
-    rows = jnp.arange(base.shape[0])[:, None]
-    slots = cfg.cache_slot("k", base[:, None] + jnp.arange(steps)[None, :])
-    # where in the ring tail the segment's own rows lie
-    own = ((base % chunk)[:, None]
-           + jnp.arange(steps)[None, :])[:, :, None, None]
-    chunks = cfg.cache_slot("sk", base)[:, None] \
-        + jnp.arange(tails[0]["sk"].shape[1])[None, :]
-    complete = (chunks + 1) * chunk <= base[:, None] + steps
-    at = jnp.where(complete, chunks, full[0]["sk"].shape[1])
-    merged = []
-    for entry, tail in zip(full, tails):
-        with jax.named_scope("kv_write"):
-            ring = {name: entry[name].at[rows, slots].set(
-                        jnp.take_along_axis(tail[name], own, axis=1))
-                    for name in ("k", "v")}
-        with jax.named_scope("eva_summarize"):
-            merged.append({**ring, **{
-                name: entry[name].at[rows, at].set(tail[name])
-                for name in ("sk", "sv")}})
-    return merged
 
 
 def _segment_decode(model: LlamaModel, params, select, first, lp, cache, pos,
@@ -2660,52 +1569,23 @@ def _segment_decode(model: LlamaModel, params, select, first, lp, cache, pos,
     """One segment of the continuous engine's plain programs: ``segment``
     steps whose attention reads the first ``window`` positions of the
     B-slot cache; returns ``(emitted, carry)`` with the FULL cache in the
-    carry, advanced."""
+    carry, advanced. Where the segment keeps no tail, each layer's leaves
+    are cut to THEIR spans of the window (rows a token; a ring, compressed
+    keys or summaries by their own arithmetic; a state has one slot whatever
+    the window and is handed through), the scan writes every step, and the
+    advanced windows go back into the full carry."""
     cfg = model.cfg
 
     def scan(cache, **form):
         return _scan_decode(model, params, select, first, lp, cache, pos,
                             done, keys, eos_id, segment, return_carry=True,
-                            count_load=cfg.counts_moe_load,
-                            count_keys=cfg.counts_eva_keys,
-                            count_dsa=cfg.counts_dsa_keys,
-                            count_sala=cfg.counts_sala_keys, **form)
+                            counters=cfg.counters(), **form)
 
-    if cfg.layer_kinds:
-        return _kinds_segment_decode(cfg, scan, cache, window)
-    spans = _leaf_spans(cfg, cache[0], window)
-    # (one merge would write an eva ring shorter than the segment twice:
-    # a cache of a few positions keeps the per-step write)
-    if segment_keeps_tail(cfg) and (cfg.attn_kind != "eva"
-                                    or segment <= spans["k"]):
-        return scan(cache, tail_window=window)
-    if all(cache[0][name].shape[1] == span for name, span in spans.items()):
-        return scan(cache)
-    # the window's two copies per segment have a scope of their own,
-    # apart from the step's kv_write and attend
-    with jax.named_scope("kv_window"):
-        win = [{name: (val if name == "index"
-                       else jax.lax.slice_in_dim(val, 0, spans[name], axis=1))
-                for name, val in entry.items()} for entry in cache]
-    out, carry = scan(win)
-    f2, lp2, wcache, pos2, done2, keys2 = carry
-    with jax.named_scope("kv_window"):
-        merged = [{name: (val if name == "index"
-                          else jax.lax.dynamic_update_slice_in_dim(
-                              cache[i][name], val, 0, axis=1))
-                   for name, val in entry.items()}
-                  for i, entry in enumerate(wcache)]
-    return out, (f2, lp2, merged, pos2, done2, keys2)
-
-
-def _kinds_segment_decode(cfg: LlamaConfig, scan, cache, window: int):
-    """:func:`_segment_decode` for a model of kinds a layer: each layer's
-    leaves are cut to THEIR spans of the window (a block-sparse layer's
-    rows and its compressed keys; a linear state has one slot whatever the
-    window and is handed through), the scan writes every step, and the
-    advanced windows go back into the full carry."""
     spans = [_leaf_spans(cfg, entry, window, layer)
              for layer, entry in enumerate(cache)]
+    if segment_keeps_tail(cfg) and _ask(cfg.kind_of(0), "tail_fits", True,
+                                        cfg, segment, spans[0]):
+        return scan(cache, tail_window=window)
 
     def short(layer, name, val):     # whether the window cuts this leaf
         return name != "index" and spans[layer][name] < val.shape[1]
@@ -2713,6 +1593,8 @@ def _kinds_segment_decode(cfg: LlamaConfig, scan, cache, window: int):
     if not any(short(i, name, val) for i, entry in enumerate(cache)
                for name, val in entry.items()):
         return scan(cache)
+    # the window's two copies per segment have a scope of their own,
+    # apart from the step's kv_write and attend
     with jax.named_scope("kv_window"):
         win = [{name: (jax.lax.slice_in_dim(val, 0, spans[i][name], axis=1)
                        if short(i, name, val) else val)
@@ -2732,9 +1614,7 @@ def _kinds_segment_decode(cfg: LlamaConfig, scan, cache, window: int):
 def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
                  start, done0, keys, eos_id, decode_steps: int,
                  return_carry: bool = False, pos_offset=None,
-                 count_load: bool = False, tail_window: int | None = None,
-                 count_keys: bool = False, count_dsa: bool = False,
-                 count_sala: bool = False):
+                 counters: tuple = (), tail_window: int | None = None):
     """The decode scan shared by the exact-shape path (:func:`_decode`),
     the bucketed serving path (:func:`_serve_decode`) and the streaming
     segment path: one compiled step per token over a static-shape cache.
@@ -2756,103 +1636,46 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
     the token's true logical position. None keeps every existing path
     byte-identical (no extra operand is traced).
 
-    ``count_load`` (a routed-FFN model's engine segments,
-    ``cfg.counts_moe_load``): the emitted tuple gains two members, both
-    summed over the routed layers and the steps as ``RoutedMLP`` sows
-    them: the assignments each row sent to each expert, int32
-    ``[b, experts]`` (``moe_stats/load``), and the distinct experts a
-    layer's call picked, one int32 (``moe_reads/experts``); the carry is
+    ``counters`` (the engine's plain segments: ``cfg.counters()``, what the
+    model's kinds count for ``/metrics``, :class:`Counters`): the emitted
+    tuple gains, behind tokens and logprobs, one member for every
+    collection the declarations name, in their order: the sum, over the
+    steps and over whatever layers sow it, of what the model sows into that
+    collection, from the declaration's zero for ``b`` rows on; the carry is
     what it was.
-
-    ``count_keys`` (an eva model's engine segments,
-    ``cfg.counts_eva_keys``): the emitted tuple gains one member, int32
-    ``[b, 3]`` summed over the steps as the block sows it (``eva_stats``):
-    the keys each row's steps had visible (ring rows and summaries), the
-    chunk summaries they wrote, and the steps taken after a window's edge
-    crossed inside the segment (a tail segment's rare branch; 0 from the
-    per-step write).
-
-    ``count_dsa`` (a sparse-attention model's engine segments,
-    ``cfg.counts_dsa_keys``): the emitted tuple gains, LAST, one member,
-    int32 ``[b, 2]`` summed over the steps as layer 0 sows it
-    (``dsa_stats``): the keys each row's steps attended and the keys they
-    chose them from. It combines with ``count_load``.
-
-    ``count_sala`` (the engine segments of a model with block-sparse
-    layers, ``cfg.counts_sala_keys``): the emitted tuple gains, LAST, one
-    member, int32 ``[b, 4]`` summed over the steps as the first such layer
-    sows it (``sala_stats``): the keys each row's steps attended, the keys
-    they could see, the steps that lay inside ``sparse_dense_len``, and the
-    compressed keys they wrote.
 
     ``tail_window`` (the engine's plain segments, where
     :func:`segment_keeps_tail`): inside the scan the cache is READ-ONLY,
-    its first ``tail_window`` positions a loop invariant. The scan carries
-    instead, a layer and leaf, a tail ``[b, decode_steps, ...]`` of the
-    segment's own positions and the step number; a step writes tail
-    position ``j`` and attends cache (``t < start[r]``) and tail
-    (``j' <= j``) under one softmax (:func:`_tail_write`); after the scan
-    ONE scatter a leaf puts the tails at ``start[r] ..`` of the FULL cache
-    (:func:`_cache_write`'s ragged chunk: out-of-range positions drop, so
-    a finished slot's stale position lands nowhere live). The same keys,
-    values and probabilities as the per-step write, the sum's order apart;
-    done rows step as garbage into their own row's tail as they did into
-    their own row. Why: a cache the loop writes is prefetched whole,
-    updated and written back WHOLE every layer of every step (PERF.md
-    section 6, PR 30); a scan of hundreds of steps keeps the per-step
-    write, its tail would be a second cache.
-
-    An eva cache's tail is two (PR 34), with a write, masks and a merge of
-    their own: the frozen leaves are the whole ring and the summaries of
-    the first ``tail_window`` positions; the scan carries, a layer, a ring
-    tail ``k``, ``v`` that begins with the rows of each row's open chunk
-    (:func:`_eva_tail_init`) and has room behind them for the whole chunks
-    ``decode_steps`` positions reach into, and a summary tail ``sk``,
-    ``sv`` with one slot for every chunk ``decode_steps`` consecutive
-    positions can complete; what every layer of a step reads alike (the
-    four masks, the step's place in the tail, the counter's row) is
-    computed once a step (:func:`_eva_tail_plan`); a step attends frozen
-    ring, ring tail, frozen summaries and summary tail under one softmax
-    and pools the chunk it lies in from one whole chunk of the ring tail
-    (:meth:`LlamaBlock._eva_tail_attend`); after the scan one scatter a
-    leaf wraps the segment's rows round the ring and puts the completed
-    chunks at their slots (:func:`_eva_tail_merge`)."""
+    its first ``tail_window`` positions a loop invariant. What the scan
+    carries in the cache's place, what a step's layers read and the ONE
+    write of the full cache after the scan are the kind's (``tail_init``,
+    ``tail_step``, ``tail_merge``; ``models/kv.py`` says it for per-head K/V
+    rows: a tail ``[b, decode_steps, ...]`` a leaf; ``models/eva.py`` for a
+    ring tail and a summary tail). The same keys, values and probabilities
+    as the per-step write, the sum's order apart; done rows step as garbage
+    into their own row's tail as they did into their own row. Why: a cache
+    the loop writes is prefetched whole, updated and written back WHOLE
+    every layer of every step (PERF.md section 6, PR 30); a scan of
+    hundreds of steps keeps the per-step write, its tail would be a second
+    cache."""
     b = first.shape[0]
     has_eos = eos_id >= 0
-    eva = model.cfg.attn_kind == "eva"
+    cfg = model.cfg
     if tail_window is not None:
+        kind = cfg.kind_of(0)   # every layer's (segment_keeps_tail)
         full, base = cache, jnp.broadcast_to(start, (b,))
-        spans = _leaf_spans(model.cfg, full[0], tail_window)
+        spans = _leaf_spans(cfg, full[0], tail_window)
         with jax.named_scope("kv_window"):
             frozen = [{name: jax.lax.slice_in_dim(val, 0, spans[name], axis=1)
                        for name, val in entry.items() if name != "index"}
                       for entry in full]
-        if eva:
-            with jax.named_scope("eva_summarize"):
-                tails = _eva_tail_init(model.cfg, frozen, base, decode_steps)
-        else:
-            # zeros here; on the chip the compiler sees that the loop
-            # writes every position and hands it the buffer uninitialised
-            # (AllocateBuffer), whatever the value: _attend reads no
-            # position before its step wrote it
-            tails = [{name: jnp.zeros((b, decode_steps) + val.shape[2:],
-                                      val.dtype)
-                      for name, val in entry.items()} for entry in frozen]
-        cache = (tails, jnp.int32(0))
+        cache = (kind.tail_init(cfg, frozen, base, decode_steps),
+                 jnp.int32(0))
 
     # what the program counts beside its tokens: the collections the model
     # sows, and where each one's sum starts
-    counted = {}
-    if count_load:
-        counted["moe_stats"] = jnp.zeros((b, model.cfg.moe_experts),
-                                         jnp.int32)
-        counted["moe_reads"] = jnp.int32(0)
-    elif count_keys:
-        counted["eva_stats"] = jnp.zeros((b, 3), jnp.int32)
-    if count_dsa:
-        counted["dsa_stats"] = jnp.zeros((b, 2), jnp.int32)
-    if count_sala:
-        counted["sala_stats"] = jnp.zeros((b, 4), jnp.int32)
+    counted = {name: zero(b) for kind_counters in counters
+               for name, zero in kind_counters.sown.items()}
 
     def step(carry, _):
         if counted:
@@ -2863,13 +1686,7 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
                      else jnp.broadcast_to(rope_pos[None, None], (b, 1)))
         if tail_window is not None:
             tails, j = cache
-            if eva:
-                plan = _eva_tail_plan(model.cfg, frozen[0], tails[0], base, j)
-                cache = [{**entry, "index": base, "tail": tail, "plan": plan}
-                         for entry, tail in zip(frozen, tails)]
-            else:
-                cache = [{**entry, "index": base, "tail": tail, "step": j}
-                         for entry, tail in zip(frozen, tails)]
+            cache = kind.tail_step(cfg, frozen, tails, base, j)
         if counted:
             (logits, new_cache), sown = model.apply(
                 params, tok[:, None], positions=positions, cache=cache,
@@ -2904,14 +1721,7 @@ def _scan_decode(model: LlamaModel, params, select_fn, first, lp0, cache,
         out = (*out, *counts)
     if tail_window is not None:
         tok, lp, (tails, _), pos, done, keys = carry
-        if eva:
-            merged = _eva_tail_merge(model.cfg, full, tails, base,
-                                     decode_steps)
-        else:
-            with jax.named_scope("kv_write"):
-                # the ragged write of a chunk: out-of-range positions drop
-                merged = [_cache_write(entry, tail, base, b, decode_steps)[0]
-                          for entry, tail in zip(full, tails)]
+        merged = kind.tail_merge(cfg, full, tails, base, decode_steps)
         for entry in merged:
             entry["index"] = pos
         carry = (tok, lp, merged, pos, done, keys)
@@ -3515,7 +2325,9 @@ class LlamaServer:
     # d, d]: an executable of the old text would take the old leaf. g9 =
     # PR 43: a sparse prefill's loops take their trip counts from the rows'
     # length operand and walk key blocks of 2048 (_sparse_prefill_attend):
-    # same operands, another text.
+    # same operands, another text. (PR 44 moved the kv, latent and eva
+    # kinds out of the block into modules: no text moved and, under
+    # ``block_method``, no op_name, so no generation.)
     _AOT_GEN = "g9"
 
     @classmethod

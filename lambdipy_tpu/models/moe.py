@@ -460,6 +460,51 @@ class RoutedMLP(nn.Module):
         return out
 
 
+def counters(cfg) -> tuple:
+    """The ``handler.moe`` block on ``/metrics`` of a routed-FFN model's
+    decode segments, only growing (``llama.Counters``; :class:`RoutedMLP`
+    sows ``moe_stats`` and ``moe_reads``, and a segment program returns
+    their sums over the routed layers and its steps). ``assignments``:
+    (token, expert) pairs the booked rows' steps sent to routed experts,
+    summed over the layers: with dropless routing exactly booked rows x
+    segment steps x routed layers x experts per token, so a dropped or
+    doubled assignment shows as a difference. ``load``: the same count per
+    expert. The engine's collector adds each booked row's vector from the
+    segment's own fetch; rows the device stepped for nobody (empty slots,
+    over-decode) are left out. ``experts_read`` / ``layer_steps``: the mean
+    number of DISTINCT experts one routed layer's call picked in one decode
+    step, as sum and count: what a form that fetches only the picked experts
+    reads (``ops/grouped_experts.py picked_experts``; one that streams reads
+    them all, whatever this says). Counted by the program over ALL the
+    slots' rows, because a cache step routes every slot, live or empty;
+    ``layer_steps`` is fetched segments x segment steps x routed layers.
+    ``local_assignments``: of ``assignments``, those to the experts this
+    chip holds (``LlamaConfig.moe_held``): all of them where it holds every
+    expert, the share routing really gave it where it holds a share."""
+    from lambdipy_tpu.models.llama import Counters
+
+    if cfg.ffn_kind != "routed":
+        return ()
+    first, held = cfg.moe_held
+    layers = cfg.layers - cfg.first_dense_layers
+
+    def segment(sown, rows: int, steps: int) -> dict:
+        add = {"experts_read": sown["moe_reads"],
+               "layer_steps": steps * layers}
+        if rows:
+            load = sown["moe_stats"].sum(axis=0)
+            add.update(assignments=load.sum(), load=load,
+                       local_assignments=load[first:first + held].sum())
+        return add
+
+    return (Counters(
+        "moe", "a routed-FFN model",
+        {"assignments": 0, "local_assignments": 0, "load": [],
+         "experts_read": 0, "layer_steps": 0},
+        {"moe_stats": lambda b: jnp.zeros((b, cfg.moe_experts), jnp.int32),
+         "moe_reads": lambda b: jnp.int32(0)}, segment),)
+
+
 def moe_aux_loss(intermediates) -> jax.Array:
     """Sum every sown ``moe_aux_loss`` in an intermediates collection."""
     leaves = [

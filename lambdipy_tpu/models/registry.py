@@ -413,7 +413,7 @@ def _build_deepseek_v32(dtype: str = "bfloat16", quant: str | None = None,
     """The ``deepseek_v32`` architecture through the one block: the
     ``deepseek-v3`` kinds with the query compressed (``q_lora_rank``),
     DeepSeek Sparse Attention (``index_heads``, ``index_head_dim``,
-    ``index_topk``: models/llama.py ``LlamaBlock._latent_attend``),
+    ``index_topk``: models/latent.py),
     group-limited routing (``moe_n_group``, ``moe_topk_group``), a chip's
     share of each layer's routed experts (``moe_experts_held``,
     ``moe_first_expert``) and YaRN. A recipe's TOML cannot carry the

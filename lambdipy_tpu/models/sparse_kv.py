@@ -25,21 +25,22 @@ of ``SPARSE_QUERY_BLOCK`` queries a turn inside each block of
 score is ``[heads, s, s]``.
 
 Scopes: ``sala_compress`` (the compressed keys), ``sala_select`` (scores,
-pool, threshold), ``attend``, ``kv_write``, ``qkv_proj``. What
-:class:`~lambdipy_tpu.models.llama.LlamaBlock` asks of a kind's module:
-``validate``, ``cache_layout``, ``cache_positions``, ``cache_dtypes``,
-``cache_slot``, ``refusal`` and ``attend``."""
+pool, threshold), ``attend``, ``kv_write``, ``qkv_proj``. The interface is
+``llama.ATTN_KINDS``'."""
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from lambdipy_tpu.models.llama import (SALA_PROMPT_BLOCK, QDense, RMSNorm,
-                                       _attend, _cache_write,
-                                       _dsa_select_mask, _head_group)
+from lambdipy_tpu.models.llama import (Counters, QDense, RMSNorm,
+                                       SALA_PROMPT_BLOCK, _attend,
+                                       _cache_write, _dsa_select_mask,
+                                       _head_group, require_own_leaves,
+                                       whole_prompt_blocks)
 
 NAME = "sparse_kv"
+PLACES = ("layer_kinds",)
 # a prefill's turn: queries a turn, and the keys of one python-level block
 # (a prompt past DENSE_PREFILL_MAX prefills at whole key blocks,
 # ``LlamaConfig.prompt_bucket``)
@@ -51,6 +52,7 @@ DENSE_PREFILL_MAX = 2048
 
 
 def validate(cfg) -> None:
+    require_own_leaves(cfg, NAME)
     stride, kernel, block = (cfg.sparse_stride, cfg.sparse_kernel,
                              cfg.sparse_block)
     if stride < 1 or kernel != 2 * stride or block % stride \
@@ -97,6 +99,35 @@ def refusal(cfg, holder: str) -> str:
             f"tokens) and whose {cfg.sparse_topk} attended blocks are chosen "
             "by content by the whole-prompt prefill and the one-token step "
             "alone (PERF.md section 7)")
+
+
+prompt_block = whole_prompt_blocks
+
+
+def counters(cfg) -> tuple:
+    """This kind's share of the ``handler.sala`` block on ``/metrics``, only
+    growing, from the segment programs' own masks, for the rows the
+    collector books (the first block-sparse layer sows ``sala_stats``, int32
+    ``[b, 4]`` a step: every such layer's are the same; a segment program
+    returns their sum over its steps). ``row_steps``: booked rows x segment
+    steps. ``keys_attended``: the keys a block-sparse layer's steps
+    attended, summed (a step's context while it lies inside
+    ``sparse_dense_len``, at most ``sparse_topk x sparse_block`` past it);
+    ``keys_visible``: the positions they could see; ``dense_steps``: the
+    row-steps at or under ``sparse_dense_len``; ``kc_writes``: the
+    compressed keys written (one every ``sparse_stride``-th step a row)."""
+    def segment(sown, rows: int, steps: int) -> dict:
+        keys = sown["sala_stats"]
+        return {"row_steps": rows * steps, "keys_attended": keys[:, 0].sum(),
+                "keys_visible": keys[:, 1].sum(),
+                "dense_steps": keys[:, 2].sum(),
+                "kc_writes": keys[:, 3].sum()}
+
+    return (Counters(
+        "sala", "a model with block-sparse layers",
+        dict.fromkeys(("row_steps", "keys_attended", "keys_visible",
+                       "dense_steps", "kc_writes"), 0),
+        {"sala_stats": lambda b: jnp.zeros((b, 4), jnp.int32)}, segment),)
 
 
 def compress(k, stride: int):
@@ -280,7 +311,7 @@ def attend(block, x, positions, mask, cache, lengths):
         with jax.named_scope("sala_compress"):
             # the compressed key whose window this position completes: a
             # slice a row (one gather would copy the leaf into a layout of
-            # its liking: llama._eva_chunk_rows)
+            # its liking: eva._eva_chunk_rows)
             lands = ((idx + 1) % stride == 0) & (idx + 1 >= kernel)
             first = jnp.maximum(idx + 1 - kernel, 0)
             window = jnp.concatenate(
@@ -304,8 +335,7 @@ def attend(block, x, positions, mask, cache, lengths):
         if block.layer == cfg.first_layer_of(NAME):
             # what a row's step attended and could see, whether it lay inside
             # dense_len, whether it wrote a compressed key: every sparse
-            # layer's are the same (_scan_decode, count_sala; /metrics
-            # handler.sala)
+            # layer's are the same (counters; /metrics handler.sala)
             block.sow("sala_stats", "keys", jnp.stack(
                 [seen.sum((-1, -2, -3)) // kvh, idx + 1,
                  idx + 1 <= cfg.sparse_dense_len, lands],

@@ -242,14 +242,10 @@ class ContinuousBatcher:
         import jax
 
         from lambdipy_tpu.runtime.metrics import (DecodeWindowStats,
-                                                  DsaKeyStats,
                                                   EngineFaultStats,
-                                                  EvaKeyStats,
-                                                  KdaStats,
-                                                  MoeLoadStats,
+                                                  KindCounters,
                                                   PipelineStats,
                                                   PrefillStats,
-                                                  SalaKeyStats,
                                                   SpecDecodeStats)
 
         self.server = server
@@ -265,40 +261,17 @@ class ContinuousBatcher:
         # plain segment program still serves windows at the cache cap.
         self.window_bucketing = bool(window_bucketing)
         self.window_stats = DecodeWindowStats()
-        # a routed-FFN model's segment programs return each row's expert
-        # load and the distinct experts its layer-steps picked beside the
-        # tokens (llama._scan_decode count_load); the collector books them
-        # here (/metrics handler.moe)
-        self.moe_stats = MoeLoadStats(
-            held=getattr(cfg, "moe_held", (0, None)))
-        # a sparse-attention model's segment programs return, a row and
-        # LAST, the keys its steps attended and chose from
-        # (llama._scan_decode count_dsa; /metrics handler.dsa)
-        self.dsa_stats = DsaKeyStats()
-        self._counts_dsa = bool(getattr(cfg, "counts_dsa_keys", False))
-        # an eva-attention model's segment programs return, a row, the keys
-        # its steps had visible, the summaries they wrote and the steps it
-        # took past a window edge inside the segment
-        # (llama._scan_decode count_keys; /metrics handler.eva)
-        self.eva_stats = EvaKeyStats()
-        self._counts_eva = bool(getattr(cfg, "counts_eva_keys", False))
-        # the segment programs of a model with block-sparse layers return,
-        # a row and LAST, what its steps attended, could see, how many lay
-        # inside dense_len and the compressed keys they wrote
-        # (llama._scan_decode count_sala; /metrics handler.sala)
-        self.sala_stats = SalaKeyStats(
-            step_state_bytes=getattr(cfg, "state_bytes_a_step", 0),
-            state_kernel=bool(getattr(cfg, "linear_steps_in_place", False)))
-        self._counts_sala = bool(getattr(cfg, "counts_sala_keys", False))
-        # a model with kda layers: the layer-steps its booked rows took and
-        # the chunks its prefills scanned, from shapes (/metrics handler.kda)
-        self.kda_stats = KdaStats(
-            layers=int(getattr(cfg, "kda_layers", 0)),
-            layer_bytes=int(getattr(cfg, "kda_step_bytes", 0)),
-            state_kernel=bool(getattr(cfg, "kda_steps_in_place", False)))
-        self._routed_layers = (cfg.layers - cfg.first_dense_layers
-                               if getattr(cfg, "counts_moe_load", False)
-                               else 0)
+        # what the model's kinds count (models/llama.py Counters: an
+        # attention kind's or the routed FFN's own declaration): the
+        # segment programs return, behind their tokens, the sum of every
+        # collection the declarations name, in their order; the collector
+        # books them, and the prefill paths what a kind counts of a prefill
+        # from shapes, into one recorder a /metrics block (handler.<block>)
+        kinds = getattr(cfg, "counters", tuple)()
+        self.counters: dict = {}
+        for kind in kinds:
+            self.counters.setdefault(kind.block, KindCounters()).add(kind)
+        self._sown = [name for kind in kinds for name in kind.sown]
         # segments kept in flight on the device before the host fetches
         # the oldest: 1 = the fully synchronous loop (dispatch, fetch,
         # book, repeat — the device idles through every fetch RTT +
@@ -321,33 +294,19 @@ class ContinuousBatcher:
         # stays bounded.
         self.spec_k = 0
         if spec_k and int(spec_k) >= 2:
-            from lambdipy_tpu.models.llama import _next_bucket
+            from lambdipy_tpu.models.llama import (_next_bucket,
+                                                   require_row_a_token)
 
             self.spec_k = max(2, _next_bucket(int(spec_k), 2))
-            if self._counts_dsa:
-                raise NotImplementedError(
-                    "spec_k on a sparse-attention model: a verify chunk is "
-                    "several positions wide, and the selection is computed "
-                    "by the whole-prompt prefill and the one-token step "
-                    "alone (PERF.md section 7)")
-            if getattr(cfg, "layer_kinds", ()):
-                raise NotImplementedError(
-                    "spec_k on a model of attention kinds a layer: a verify "
-                    "chunk is several positions wide and a rejected tail is "
-                    "rolled back, which neither a block-sparse layer's "
-                    "selection nor a linear layer's recurrent state can "
-                    "take (PERF.md section 7)")
-            if self._counts_eva:
-                raise NotImplementedError(
-                    "spec_k on an eva-attention model: a verify chunk is "
-                    "several positions wide and a rejected tail is rolled "
-                    "back, which a ring that forgets and summaries that "
-                    "pool cannot take (PERF.md section 7)")
-            if getattr(server.model.cfg, "counts_moe_load", False):
-                raise NotImplementedError(
-                    "spec_k on a routed-FFN model: the verify segments "
-                    "return no expert load, so handler.moe would stop "
-                    "counting in silence")
+            require_row_a_token(
+                cfg, "spec_k (a verify chunk is several positions wide and "
+                "a rejected tail is rolled back; no such engine)")
+            for kind in kinds:
+                if kind.sown:
+                    raise NotImplementedError(
+                        f"spec_k on {kind.what}: the verify segments "
+                        f"return none of what it counts, so handler."
+                        f"{kind.block} would stop counting in silence")
         # spec verify chunks are multi-token steps, which the
         # sequence-parallel decode path cannot serve (spdecode is a
         # one-token formulation): under an sp mesh every verify would
@@ -829,6 +788,12 @@ class ContinuousBatcher:
             pool.arena = new_arena
         return (first, lp0, start, done0, keys)
 
+    def _book_prefill(self, lengths: list, rows: int, sb: int) -> None:
+        """What the model's kinds count of one dispatched prefill of
+        ``rows`` rows padded to ``sb`` positions."""
+        for recorder in self.counters.values():
+            recorder.record_prefill(lengths, rows, sb)
+
     def _prefill_row(self, row, s: int, entry: dict):
         """Single-row bucketed prefill -> 1-row carry over the engine's
         cache_len (reuses the streaming prefill program family, so a
@@ -850,12 +815,7 @@ class ContinuousBatcher:
         knobs = server._knob_operands(
             entry["temperature"], entry["top_k"], entry["top_p"],
             entry["seed"], None, b=1)
-        if self.kda_stats.layers:
-            self.kda_stats.record_prefill(
-                server.model.cfg.kda_scan_chunks(1, sb))
-        if self._counts_dsa:
-            self.dsa_stats.record_prefill(
-                *server.model.cfg.dsa_prefill_pairs([s], 1, sb))
+        self._book_prefill([s], 1, sb)
         with server._mesh_ctx():
             return prefill(server.params, prompt_op, length_op, *knobs)
 
@@ -893,12 +853,7 @@ class ContinuousBatcher:
             [e["top_p"] for e in entries],
             [e["seed"] for e in entries],
             None, b=bb)
-        if self.kda_stats.layers:
-            self.kda_stats.record_prefill(
-                server.model.cfg.kda_scan_chunks(bb, sb))
-        if self._counts_dsa:
-            self.dsa_stats.record_prefill(
-                *server.model.cfg.dsa_prefill_pairs(lens, bb, sb))
+        self._book_prefill(lens, bb, sb)
         with server._mesh_ctx():
             return prefill(server.params, prompt_op, length_op, *knobs)
 
@@ -1614,16 +1569,16 @@ class ContinuousBatcher:
                     want.append(rec["lps"])
                 if kb_rec:
                     want += [rec["counts"], rec["pending"]]
-                want += rec["moe"]
+                want += rec["sown"]
                 got = [np.asarray(x)
                        for x in jax.device_get(tuple(want))]
                 blk = got.pop(0)
                 lp = got.pop(0) if rec["need_lp"] else None
                 cnt = got.pop(0) if kb_rec else None
                 pend = got.pop(0) if kb_rec else None
-                return blk, lp, cnt, pend, got
+                return blk, lp, cnt, pend, dict(zip(self._sown, got))
 
-            block, lp_block, counts_h, pending_h, moe_h = \
+            block, lp_block, counts_h, pending_h, sown_h = \
                 self._device_wait("segment_fetch", gen, fetch)
             t_end = time.monotonic()
             phase.enter("eng.book", rids=served)
@@ -1650,24 +1605,12 @@ class ContinuousBatcher:
                 if self.mesh_stats is not None:
                     self.mesh_stats.record_segment()
                 booked = [slot for slot, e in rec["rows"] if not e["done"]]
-                if moe_h and self._counts_dsa:
-                    self.dsa_stats.record_segment(moe_h.pop()[booked],
-                                                  steps=block.shape[1])
-                if moe_h and self._counts_sala:
-                    self.sala_stats.record_segment(moe_h.pop()[booked],
-                                                   steps=block.shape[1])
-                if self.kda_stats.layers:
-                    self.kda_stats.record_segment(len(booked),
-                                                  steps=block.shape[1])
-                if moe_h and self._counts_eva:
-                    self.eva_stats.record_segment(moe_h[0][booked],
-                                                  steps=block.shape[1])
-                elif moe_h:
-                    load_h, read_h = moe_h
-                    self.moe_stats.record_segment(
-                        load_h[booked],
-                        experts_read=int(read_h),
-                        layer_steps=block.shape[1] * self._routed_layers)
+                if not kb_rec:
+                    # (a verify segment returns none of it: no model that
+                    # counts is served with spec_k)
+                    for recorder in self.counters.values():
+                        recorder.record_segment(sown_h, booked,
+                                                block.shape[1])
                 for slot, entry in rec["rows"]:
                     # per-row accepted width: everything for a plain
                     # segment; counts_h[slot] (1..kb) for a verify step
@@ -2248,14 +2191,13 @@ class ContinuousBatcher:
                     tok, lp = self._carry[:2]
                     outs, self._carry = self._device_wait(
                         "segment_dispatch", gen, dispatch)
-                    moe = []
+                    sown = []
                     if kb:
                         toks, lps, counts_op, pending_op = outs
                     else:
-                        # a routed-FFN model's plain segments also return
-                        # the rows' expert load and the distinct experts
-                        # its layer-steps picked
-                        toks, lps, *moe = outs
+                        # a plain segment also returns the sums of what
+                        # the model's kinds sow (self._sown, in order)
+                        toks, lps, *sown = outs
                     # attended = per-row sum of positions each step's
                     # attention actually covered (pos + 1 keys at write
                     # index pos); a verify chunk computes all kb
@@ -2263,7 +2205,7 @@ class ContinuousBatcher:
                     # honest width either way
                     rec = {
                         "toks": toks, "lps": lps, "need_lp": need_lp,
-                        "moe": moe,
+                        "sown": sown,
                         "rows": live, "window": window,
                         "t_dispatch": t_disp,
                         "attended": sum(adv * p + adv * (adv + 1) // 2

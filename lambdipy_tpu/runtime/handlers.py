@@ -1618,29 +1618,13 @@ def generate_handler(spec: dict, ctx) -> HandlerState:
                 "seconds": preload_state.get("seconds")}
         if batcher is not None:
             out["batching"] = batcher.stats()
-        if continuous is not None and getattr(
-                server.model.cfg, "counts_moe_load", False):
-            # the routed FFN's dropless check and per-expert load, booked
-            # by the engine's collector from each segment's fetch
-            out["moe"] = continuous.moe_stats.report()
-        if continuous is not None and getattr(
-                server.model.cfg, "counts_eva_keys", False):
-            # what the eva segments' rows attended and summarised
-            out["eva"] = continuous.eva_stats.report()
-        if continuous is not None and getattr(
-                server.model.cfg, "counts_dsa_keys", False):
-            # what the sparse segments' rows selected, and from how many
-            out["dsa"] = continuous.dsa_stats.report()
-        if continuous is not None and getattr(
-                server.model.cfg, "counts_sala_keys", False):
-            # what the block-sparse layers' steps attended and wrote, and
-            # the states the linear layers carried
-            out["sala"] = continuous.sala_stats.report()
-        if continuous is not None and getattr(
-                server.model.cfg, "kda_layers", 0):
-            # the layer-steps the kda layers' states took and the chunks
-            # their prefills scanned
-            out["kda"] = continuous.kda_stats.report()
+        if continuous is not None:
+            # what the model's kinds count (a block each: the routed FFN's
+            # dropless check and load, an attention kind's keys or states),
+            # booked by the engine's collector from each segment's fetch
+            # and by its prefill paths
+            out.update({block: recorder.report()
+                        for block, recorder in continuous.counters.items()})
         if getattr(server, "spec_metrics", None) is not None:
             # the solo `"speculative": k` path's cumulative acceptance
             # counters (the engine's batching.spec block shares this

@@ -111,236 +111,57 @@ class DecodeWindowStats:
             }
 
 
-@dataclass
-class MoeLoadStats:
-    """Counters of a routed-FFN model's decode segments (the
-    ``handler.moe`` block on ``/metrics``), only growing. ``assignments``:
-    (token, expert) pairs the booked rows' steps sent to routed experts,
-    summed over the layers: with dropless routing exactly booked rows x
-    segment steps x routed layers x experts per token, so a dropped or
-    doubled assignment shows as a difference. ``load``: the same count per
-    expert. The engine's collector adds each booked row's vector from the
-    segment's own fetch; rows the device stepped for nobody (empty slots,
-    over-decode) are left out. ``experts_read`` / ``layer_steps``: the mean
-    number of DISTINCT experts one routed layer's call picked in one decode
-    step, as sum and count: what a form that fetches only the picked experts
-    reads (``ops/grouped_experts.py picked_experts``; one that streams reads
-    them all, whatever this says). Counted by the program over ALL the
-    slots' rows, because a cache step routes every slot, live or empty;
-    ``layer_steps`` is fetched segments x segment steps x routed layers.
-    ``local_assignments``: of ``assignments``, those to the experts this
-    chip holds (``held``: first id and count; ``LlamaConfig.moe_held``):
-    all of them where it holds every expert, the share routing really gave
-    it where it holds a share."""
+class KindCounters:
+    """One ``handler.<block>`` block of ``/metrics``: the recorder of what a
+    model's kinds declare for it (``models/llama.py Counters``; what each
+    key means is written there, beside the code that counts it). Sums that
+    only grow, under one lock; kinds that share a block report their fields
+    in the kinds' order."""
 
-    assignments: int = 0
-    load: list = field(default_factory=list)   # per expert
-    experts_read: int = 0
-    layer_steps: int = 0
-    held: tuple = (0, None)
-    local_assignments: int = 0
-    _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
+    def __init__(self):
+        self._kinds: list = []
+        self._sums: dict = {}
+        self._lock = threading.Lock()
 
-    def record_segment(self, rows, *, experts_read: int,
-                       layer_steps: int) -> None:
-        """One fetched segment. ``rows``: int array [booked rows,
-        experts]."""
-        total = rows.sum(axis=0)
+    def add(self, kind) -> None:
+        self._kinds.append(kind)
+        self._sums.update({name: list(zero) if isinstance(zero, list)
+                           else zero for name, zero in kind.fields.items()})
+
+    def _grow(self, adds: dict) -> None:
         with self._lock:
-            self.experts_read += experts_read
-            self.layer_steps += layer_steps
-            if not len(rows):
-                return
-            if not self.load:
-                self.load = [0] * len(total)
-            self.load = [a + int(b) for a, b in zip(self.load, total)]
-            self.assignments += int(total.sum())
-            first, count = self.held
-            self.local_assignments += int(
-                total[first:None if count is None else first + count].sum())
+            for name, add in adds.items():
+                old = self._sums[name]
+                if isinstance(old, list):   # a vector, empty until first fed
+                    add = [int(x) for x in add]
+                    self._sums[name] = [a + b for a, b in zip(old, add)] \
+                        if old else add
+                else:
+                    self._sums[name] = old + int(add)
+
+    def record_segment(self, sown: dict, booked: list, steps: int) -> None:
+        """One fetched plain segment of ``steps`` steps. ``sown``: what the
+        program summed of every collection, as host arrays; ``booked``: the
+        slots of the rows the collector books: only theirs are counted of
+        whatever has a row axis."""
+        for kind in self._kinds:
+            if kind.segment is not None:
+                self._grow(kind.segment(
+                    {name: sown[name][booked] if sown[name].ndim
+                     else int(sown[name]) for name in kind.sown},
+                    len(booked), steps))
+
+    def record_prefill(self, lengths, rows: int, s: int) -> None:
+        """One dispatched prefill of ``rows`` rows padded to ``s``
+        positions, the real rows ``lengths`` long."""
+        for kind in self._kinds:
+            if kind.prefill is not None:
+                self._grow(kind.prefill(lengths, rows, s))
 
     def report(self) -> dict:
         with self._lock:
-            return {"assignments": self.assignments,
-                    "local_assignments": self.local_assignments,
-                    "load": list(self.load),
-                    "experts_read": self.experts_read,
-                    "layer_steps": self.layer_steps}
-
-
-@dataclass
-class DsaKeyStats:
-    """Counters of a sparse-attention model (the ``handler.dsa`` block on
-    ``/metrics``), only growing. Of its decode segments, from the segment
-    programs' own masks, for the rows the collector books: ``row_steps``:
-    booked rows x segment steps. ``keys_selected``: the cached positions
-    those steps attended, summed: ``min(context, index_topk)`` a step
-    exactly, so more or fewer shows as a difference. ``keys_visible``: the
-    positions they were chosen from (the step's context). Of the prefills
-    the engine dispatched, booked on the host from the iteration space the
-    program's loops take their trip counts from
-    (``llama.dsa_prefill_turns``): ``prefill_pairs_run``: the query-key
-    pairs their turns were given, a layer; ``prefill_pairs_causal``: the
-    pairs causality needs, ``L (L + 1) / 2`` a prompt of ``L`` tokens. Their
-    ratio is the prefill's overwork: padding and the overhang of a key
-    block over the causal frontier."""
-
-    row_steps: int = 0
-    keys_selected: int = 0
-    keys_visible: int = 0
-    prefill_pairs_run: int = 0
-    prefill_pairs_causal: int = 0
-    _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
-
-    def record_segment(self, rows, *, steps: int) -> None:
-        """One fetched segment. ``rows``: int array [booked rows, 2], each
-        row's (keys selected, keys visible) summed over the steps."""
-        with self._lock:
-            self.row_steps += len(rows) * steps
-            self.keys_selected += int(rows[:, 0].sum())
-            self.keys_visible += int(rows[:, 1].sum())
-
-    def record_prefill(self, run: int, causal: int) -> None:
-        """One dispatched prefill (``LlamaConfig.dsa_prefill_pairs``)."""
-        with self._lock:
-            self.prefill_pairs_run += run
-            self.prefill_pairs_causal += causal
-
-    def report(self) -> dict:
-        with self._lock:
-            return {"row_steps": self.row_steps,
-                    "keys_selected": self.keys_selected,
-                    "keys_visible": self.keys_visible,
-                    "prefill_pairs_run": self.prefill_pairs_run,
-                    "prefill_pairs_causal": self.prefill_pairs_causal}
-
-
-@dataclass
-class SalaKeyStats:
-    """Counters of the decode segments of a model with block-sparse and
-    linear-attention layers (the ``handler.sala`` block on ``/metrics``),
-    only growing, from the segment programs' own masks, for the rows the
-    collector books. ``row_steps``: booked rows x segment steps.
-    ``keys_attended``: the keys a block-sparse layer's steps attended,
-    summed (a step's context while it lies inside ``sparse_dense_len``, at
-    most ``sparse_topk x sparse_block`` past it); ``keys_visible``: the
-    positions they could see; ``dense_steps``: the row-steps at or under
-    ``sparse_dense_len``; ``kc_writes``: the compressed keys written (one
-    every ``sparse_stride``-th step a row); ``state_bytes``: the recurrent
-    state the linear layers read and wrote (``step_state_bytes`` a row-step:
-    ``LlamaConfig.state_bytes_a_step``); ``kernel_row_steps``: the row-steps
-    whose linear states the kernel stepped in place (``state_kernel``:
-    ``LlamaConfig.linear_steps_in_place``, the layers' own static choice):
-    ``row_steps`` where Mosaic compiles, 0 elsewhere."""
-
-    step_state_bytes: int = 0
-    state_kernel: bool = False
-    row_steps: int = 0
-    keys_attended: int = 0
-    keys_visible: int = 0
-    dense_steps: int = 0
-    kc_writes: int = 0
-    _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
-
-    def record_segment(self, rows, *, steps: int) -> None:
-        """One fetched segment. ``rows``: int array [booked rows, 4], each
-        row's (keys attended, keys visible, dense steps, compressed keys
-        written) summed over the steps."""
-        with self._lock:
-            self.row_steps += len(rows) * steps
-            self.keys_attended += int(rows[:, 0].sum())
-            self.keys_visible += int(rows[:, 1].sum())
-            self.dense_steps += int(rows[:, 2].sum())
-            self.kc_writes += int(rows[:, 3].sum())
-
-    def report(self) -> dict:
-        with self._lock:
-            return {"row_steps": self.row_steps,
-                    "keys_attended": self.keys_attended,
-                    "keys_visible": self.keys_visible,
-                    "dense_steps": self.dense_steps,
-                    "kc_writes": self.kc_writes,
-                    "state_bytes": self.row_steps * self.step_state_bytes,
-                    "kernel_row_steps": self.row_steps * self.state_kernel}
-
-
-@dataclass
-class KdaStats:
-    """Counters of a model with kda layers (the ``handler.kda`` block on
-    ``/metrics``), only growing, from shapes. ``row_steps``: booked rows x
-    segment steps x kda layers: the layer-steps that stepped a state for
-    somebody. ``scan_chunks``: the chunks the prefills' chunked form
-    scanned, every row and kda layer of every prefill program run
-    (``LlamaConfig.kda_scan_chunks``). ``state_bytes``: the state and conv
-    tail those layer-steps read and wrote, once each way (``layer_bytes`` a
-    row's step in one layer: ``models/kda.py state_bytes_a_step``).
-    ``kernel_row_steps``: the layer-steps whose state the kernel stepped in
-    place (``state_kernel``: ``LlamaConfig.kda_steps_in_place``, the layers'
-    own static choice): ``row_steps`` where Mosaic compiles, 0 elsewhere."""
-
-    layers: int = 0
-    layer_bytes: int = 0
-    state_kernel: bool = False
-    row_steps: int = 0
-    scan_chunks: int = 0
-    _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
-
-    def record_segment(self, rows: int, *, steps: int) -> None:
-        with self._lock:
-            self.row_steps += rows * steps * self.layers
-
-    def record_prefill(self, chunks: int) -> None:
-        with self._lock:
-            self.scan_chunks += chunks
-
-    def report(self) -> dict:
-        with self._lock:
-            return {"row_steps": self.row_steps,
-                    "scan_chunks": self.scan_chunks,
-                    "state_bytes": self.row_steps * self.layer_bytes,
-                    "kernel_row_steps": self.row_steps * self.state_kernel}
-
-
-@dataclass
-class EvaKeyStats:
-    """Counters of an eva-attention model's decode segments (the
-    ``handler.eva`` block on ``/metrics``), only growing, from the segment
-    programs' own fetch, for the rows the collector books (the rows the
-    device stepped for nobody are left out, as in :class:`MoeLoadStats`).
-    ``row_steps``: booked rows x segment steps. ``keys_attended``: the ring
-    rows and chunk summaries those steps had visible, summed:
-    ``keys_attended / row_steps`` is the mean number of keys a query
-    attended, beside the rows' mean context the compression the traffic
-    really got. ``chunks_written``: the summaries those steps wrote; a row
-    writes one every ``chunk_size`` steps, so ``chunks_written x chunk_size``
-    is ``row_steps`` to within one partial chunk a booked request.
-    ``edge_row_steps``: the steps a row took AFTER a window's edge crossed
-    inside their segment, the rare branch of a segment that keeps its ring
-    read-only (``llama.LlamaBlock._eva_tail_attend``): there the frozen
-    ring is masked whole and the row attends its tail alone."""
-
-    row_steps: int = 0
-    keys_attended: int = 0
-    chunks_written: int = 0
-    edge_row_steps: int = 0
-    _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
-
-    def record_segment(self, rows, *, steps: int) -> None:
-        """One fetched segment. ``rows``: int array [booked rows, 3], each
-        row's (keys visible, chunks written, steps after a window edge
-        inside the segment) summed over the steps."""
-        with self._lock:
-            self.row_steps += len(rows) * steps
-            self.keys_attended += int(rows[:, 0].sum())
-            self.chunks_written += int(rows[:, 1].sum())
-            self.edge_row_steps += int(rows[:, 2].sum())
-
-    def report(self) -> dict:
-        with self._lock:
-            return {"row_steps": self.row_steps,
-                    "keys_attended": self.keys_attended,
-                    "chunks_written": self.chunks_written,
-                    "edge_row_steps": self.edge_row_steps}
+            return {name: list(val) if isinstance(val, list) else val
+                    for name, val in self._sums.items()}
 
 
 @dataclass

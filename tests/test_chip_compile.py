@@ -433,7 +433,7 @@ def test_an_eva_segment_compiles_and_copies_no_ring(v5e):
     ``eva_summarize``, beside the llama block's scopes.
 
     The segment keeps ring and summaries READ-ONLY inside its scan (PR 34,
-    ``llama.LlamaBlock._eva_tail_attend``): nothing in the loop produces an
+    ``models/eva.py _eva_tail_attend``): nothing in the loop produces an
     array of a cache leaf's whole shape but the loop's own tuple and the
     read prefetch, and after the loop ONE scatter a leaf merges the tails,
     four a layer: two ring leaves under ``kv_write``, two summary leaves
@@ -448,7 +448,7 @@ def test_an_eva_segment_compiles_and_copies_no_ring(v5e):
     does not), and with those slices taken from the frozen ring INSIDE the
     scan, where they are pooled beside the tail's rows and hand the ring
     the tail's layout (batch next to the lanes): 32 transposing copies at
-    the head of every segment. ``_eva_tail_init`` fetches the open chunk
+    the head of every segment. ``eva.tail_init`` fetches the open chunk
     once, before the scan, a slice a row.
 
     The no-write assertion also holds two forms of the merge and of that
@@ -493,9 +493,9 @@ def test_an_eva_prefill_keeps_a_turns_scores_in_the_fast_memory(
     384 summaries) lies in the chip's fast memory (``S(1)`` in the layout),
     so the softmax's passes cost no HBM traffic; at 512 the 2048-key scores
     are HBM buffers, written twice and read three times a turn."""
-    from lambdipy_tpu.models import llama
+    from lambdipy_tpu.models import eva, llama
 
-    monkeypatch.setattr(llama, "EVA_QUERY_BLOCK", block)
+    monkeypatch.setattr(eva, "EVA_QUERY_BLOCK", block)
     cfg = llama.LlamaConfig(layers=1, dtype=jnp.bfloat16, quant="int8",
                             **EVABYTE)
     model = llama.LlamaModel(cfg)
@@ -603,7 +603,7 @@ def test_a_sparse_prefill_keeps_a_turns_scores_in_the_fast_memory(v5e,
     loop (what the rows' length operand makes it), one such loop a key
     block, where the loops inside a turn (the head groups, the threshold's
     32 bits) compare with constants."""
-    from lambdipy_tpu.models import llama, moe
+    from lambdipy_tpu.models import latent, llama, moe
 
     monkeypatch.setattr(moe, "kernels_compile_here", lambda: True)
     cfg = llama.LlamaConfig(layers=1, dtype=jnp.bfloat16, quant="int8",
@@ -620,7 +620,7 @@ def test_a_sparse_prefill_keeps_a_turns_scores_in_the_fast_memory(v5e,
     server = llama.LlamaServer(model, None)
     key = ("stream", 1, cfg.prompt_bucket(9000, 16), 16384, 16)
     assert key[2] == 12288
-    assert llama.DSA_QUERY_BLOCK == 128
+    assert latent.DSA_QUERY_BLOCK == 128
     assert llama._head_group(128, 128 * 12288) == 4
     operands = on_chip(jax.eval_shape(lambda: server._aot_examples(key))[0])
     text = server._stream_fns(*key[1:])[0].lower(
@@ -651,7 +651,7 @@ def test_a_sparse_prefill_keeps_a_turns_scores_in_the_fast_memory(v5e,
              if name.endswith("_sparse_prefill_attend/while")]
     inner = [conditions[c] for c, name in loops
              if "_sparse_prefill_attend/while/body/" in name]
-    assert len(turns) == 12288 // llama.DSA_KEY_BLOCK == 6
+    assert len(turns) == 12288 // latent.DSA_KEY_BLOCK == 6
     assert not any("constant(" in cond for cond in turns)
     assert inner and all("constant(" in cond for cond in inner)
 

@@ -204,7 +204,7 @@ def test_the_continuous_engine_with_ragged_joiners(server):
         t.join()
     assert [len(g) for g in got] == want
     assert served_gap(list(zip(rows, got))) <= GAP_TOL
-    stats, load = eng.stats(), eng.moe_stats.report()
+    stats, load = eng.stats(), eng.counters["moe"].report()
     assert stats["rows_in_segments"] > stats["segments_run"]    # rows shared
     assert load["assignments"] == stats["rows_in_segments"] * stats["segment"] \
         * ROUTED_LAYERS * TOP_K
@@ -217,9 +217,9 @@ def test_the_continuous_engine_with_ragged_joiners(server):
         * ROUTED_LAYERS
     assert TOP_K <= load["experts_read"] / load["layer_steps"] \
         <= min(CONFIG["n_routed_experts"], 4 * TOP_K)
-    again = eng.moe_stats.report()
+    again = eng.counters["moe"].report()
     eng.generate(rows[0], max_new_tokens=8)
-    after = eng.moe_stats.report()
+    after = eng.counters["moe"].report()
     assert after["assignments"] > again["assignments"]
     grown = after["layer_steps"] - again["layer_steps"]
     assert grown > 0 and grown % (8 * ROUTED_LAYERS) == 0

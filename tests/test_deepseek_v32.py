@@ -33,7 +33,7 @@ import numpy as np
 import pytest
 
 from benchmark import families, reference, weights
-from lambdipy_tpu.models import llama, moe, registry
+from lambdipy_tpu.models import latent, llama, moe, registry
 from lambdipy_tpu.runtime.continuous import ContinuousBatcher
 
 REPO = Path(__file__).resolve().parents[1]
@@ -117,10 +117,10 @@ def test_the_whole_forward_is_the_references(blocks, adapter, params,
     three key blocks of 32 (the last a partial one) in query blocks of 8,
     and the head groups of the indexer's scores and of the attention."""
     if blocks == "key blocks of 32":
-        monkeypatch.setattr(llama, "DSA_KEY_BLOCK", 32)
-        monkeypatch.setattr(llama, "DSA_QUERY_BLOCK", 8)
+        monkeypatch.setattr(latent, "DSA_KEY_BLOCK", 32)
+        monkeypatch.setattr(latent, "DSA_QUERY_BLOCK", 8)
     if blocks == "two heads a turn":
-        monkeypatch.setattr(llama, "DSA_QUERY_BLOCK", 16)
+        monkeypatch.setattr(latent, "DSA_QUERY_BLOCK", 16)
         monkeypatch.setattr(llama, "DSA_SCORE_BYTES", 4 * 2 * 3 * 16 * 72)
         assert llama._head_group(8, 3 * 16 * 72) == 2
     ids = np.random.default_rng(1).integers(1, 512, (3, 72)).astype(np.int32)
@@ -146,10 +146,10 @@ def test_a_prompt_shorter_than_its_bucket_runs_only_its_own_turns(
     reference's, and the three cache leaves at the real positions are those
     of the same row run alone at its own length (another program: to the
     order of its float32 sums)."""
-    monkeypatch.setattr(llama, "DSA_KEY_BLOCK", 32)
-    monkeypatch.setattr(llama, "DSA_QUERY_BLOCK", 8)
+    monkeypatch.setattr(latent, "DSA_KEY_BLOCK", 32)
+    monkeypatch.setattr(latent, "DSA_QUERY_BLOCK", 8)
     bucket = 96
-    assert [live for _, _, live in llama.dsa_prefill_turns(
+    assert [live for _, _, live in latent.dsa_prefill_turns(
         max(lengths), bucket)] == turns
     rng = np.random.default_rng(7)
     rows = [rng.integers(1, 512, n).astype(np.int32) for n in lengths]
@@ -181,20 +181,23 @@ def test_the_iteration_space_is_what_the_engine_books(adapter):
     queries each) at three prompt lengths: the pairs a layer the turns are
     given, from the one function the loops take their trip counts from, and
     ``handler.dsa`` after the engine's booking of those three prefills."""
-    from lambdipy_tpu.runtime.metrics import DsaKeyStats
+    from lambdipy_tpu.runtime.metrics import KindCounters
 
-    assert (llama.DSA_KEY_BLOCK, llama.DSA_QUERY_BLOCK) == (2048, 128)
+    assert (latent.DSA_KEY_BLOCK, latent.DSA_QUERY_BLOCK) == (2048, 128)
     want = {8193: 2048 * (2048 + 4096 + 6144 + 8192) + 128 * 10240,
             10240: 2048 * (2048 + 4096 + 6144 + 8192 + 10240),
             12288: 2048 * sum(range(2048, 12289, 2048))}
     assert [round(n / 1e6, 1) for n in want.values()] == [43.3, 62.9, 88.1]
-    assert [live for _, _, live in llama.dsa_prefill_turns(8193, 12288)] \
+    assert [live for _, _, live in latent.dsa_prefill_turns(8193, 12288)] \
         == [16, 16, 16, 16, 1, 0]
-    stats, cfg = DsaKeyStats(), adapter.module.cfg
+    stats, cfg = KindCounters(), adapter.module.cfg
+    dsa, = latent.counters(cfg)
+    stats.add(dsa)
     for length, pairs in want.items():
-        assert cfg.dsa_prefill_pairs([length], 1, 12288) == (
-            pairs, length * (length + 1) // 2)
-        stats.record_prefill(*cfg.dsa_prefill_pairs([length], 1, 12288))
+        assert dsa.prefill([length], 1, 12288) == {
+            "prefill_pairs_run": pairs,
+            "prefill_pairs_causal": length * (length + 1) // 2}
+        stats.record_prefill([length], 1, 12288)
     report = stats.report()
     assert report["prefill_pairs_run"] == sum(want.values())
     assert report["prefill_pairs_causal"] == sum(
@@ -204,8 +207,9 @@ def test_the_iteration_space_is_what_the_engine_books(adapter):
     assert round(4096 * (4096 + 8192 + 12288) / (10240 * 10241 / 2), 2) == 1.92
     assert round(want[10240] / (10240 * 10241 / 2), 2) == 1.2
     # two rows in a bucket of two: both run what the longer needs
-    assert cfg.dsa_prefill_pairs([9000, 12288], 2, 12288) == (
-        2 * want[12288], 9000 * 9001 // 2 + 12288 * 12289 // 2)
+    assert dsa.prefill([9000, 12288], 2, 12288) == {
+        "prefill_pairs_run": 2 * want[12288],
+        "prefill_pairs_causal": 9000 * 9001 // 2 + 12288 * 12289 // 2}
 
 
 def test_the_int8_layout_is_what_the_programs_converter_writes(params):
@@ -400,7 +404,7 @@ def test_the_continuous_engine_with_ragged_joiners_counts_exactly(server):
     assert [len(g) for g in got] == want
     assert served_gap(list(zip(rows, got))) <= GAP_TOL
     stats = eng.stats()
-    dsa, load = eng.dsa_stats.report(), eng.moe_stats.report()
+    dsa, load = eng.counters["dsa"].report(), eng.counters["moe"].report()
     assert {b for b in eng.window_stats.report()["buckets"]} \
         >= {"64", "128"}                        # bucketed and full-window
     row_steps = stats["rows_in_segments"] * stats["segment"]
@@ -682,8 +686,8 @@ def test_the_description_is_what_the_constructors_read(adapter):
     assert cfg.cache_layout() == {"ckv": (1, 32), "kpe": (1, 8),
                                   "kidx": (1, 16)}
     assert cfg.cache_positions(64) == {"ckv": 64, "kpe": 64, "kidx": 64}
-    assert cfg.moe_held == HELD and cfg.counts_dsa_keys \
-        and cfg.counts_moe_load and not cfg.counts_eva_keys
+    assert cfg.moe_held == HELD \
+        and [c.block for c in cfg.counters()] == ["moe", "dsa"]
     cache = llama.init_decode_cache(cfg, 3, 64)
     assert {k: v.shape for k, v in cache[0].items() if k != "index"} == {
         "ckv": (3, 64, 1, 32), "kpe": (3, 64, 1, 8), "kidx": (3, 64, 1, 16)}
@@ -697,7 +701,7 @@ def test_the_description_is_what_the_constructors_read(adapter):
         and k not in ("q_lora_rank", "index_heads", "index_head_dim",
                       "index_topk")}).config
     assert plain.cache_layout() == {"ckv": (1, 32), "kpe": (1, 8)}
-    assert plain.prompt_bucket(5000, 16) == 8192 and not plain.counts_dsa_keys
+    assert plain.prompt_bucket(5000, 16) == 8192 and [c.block for c in plain.counters()] == ["moe"]
 
 
 @pytest.mark.parametrize("holder", [

@@ -27,7 +27,7 @@ import numpy as np
 import pytest
 
 from benchmark import families, reference, weights
-from lambdipy_tpu.models import llama, registry
+from lambdipy_tpu.models import eva, llama, registry
 from lambdipy_tpu.runtime.continuous import ContinuousBatcher
 
 REPO = Path(__file__).resolve().parents[1]
@@ -117,7 +117,7 @@ def test_a_window_attended_in_blocks_of_queries_is_the_same_window(
         block, adapter, params, full, sample, monkeypatch):
     """At the published window a prefill attends 128 queries a turn,
     sixteen turns a window; here 8 or 16 of 32."""
-    monkeypatch.setattr(llama, "EVA_QUERY_BLOCK", block)
+    monkeypatch.setattr(eva, "EVA_QUERY_BLOCK", block)
     got = np.asarray(adapter.module.apply(params, jnp.asarray(sample))[0])
     np.testing.assert_allclose(got, full, atol=LOGIT_TOL, rtol=0)
 
@@ -231,7 +231,7 @@ def test_the_continuous_engine_is_solo_generation_token_for_token(server):
     # the 70-token prompt prefilled at three whole windows, not at 128
     prompts = {key[2] for key in server.buckets if key[0] == "stream"}
     assert 3 * WIN in prompts and 128 not in prompts
-    stats, eva = eng.stats(), eng.eva_stats.report()
+    stats, eva = eng.stats(), eng.counters["eva"].report()
     assert stats["rows_in_segments"] > stats["segments_run"]
     assert eva["row_steps"] == stats["rows_in_segments"] * 16
     # a row writes a summary every CHUNK steps
@@ -241,9 +241,9 @@ def test_the_continuous_engine_is_solo_generation_token_for_token(server):
     assert 1 <= keys <= WIN + 128 // CHUNK
     # by hand for one more request alone: 16 steps from position 40, each
     # seeing its window's ring rows so far and the first window's 8 chunks
-    before = eng.eva_stats.report()
+    before = eng.counters["eva"].report()
     eng.generate(rows[0][:40], max_new_tokens=16)
-    after = eng.eva_stats.report()
+    after = eng.counters["eva"].report()
     assert after["row_steps"] - before["row_steps"] == 16
     assert after["keys_attended"] - before["keys_attended"] == sum(
         (t % WIN + 1) + (t // WIN) * (WIN // CHUNK) for t in range(40, 56))
@@ -252,7 +252,7 @@ def test_the_continuous_engine_is_solo_generation_token_for_token(server):
     # and from position 20: the segment crosses into the second window at
     # 32, so its last four steps attend no frozen ring row
     eng.generate(rows[0][:20], max_new_tokens=16)
-    edge = eng.eva_stats.report()
+    edge = eng.counters["eva"].report()
     assert edge["edge_row_steps"] - after["edge_row_steps"] == 4
     assert edge["keys_attended"] - after["keys_attended"] == sum(
         (t % WIN + 1) + (t // WIN) * (WIN // CHUNK) for t in range(20, 36))
@@ -311,7 +311,7 @@ def test_the_description_is_what_the_constructors_read(adapter):
                                          "sv": 2048}
     assert cfg.cache_positions(20) == {"k": 20, "v": 20, "sk": 5, "sv": 5}
     assert (cfg.cache_slot("k", 70), cfg.cache_slot("sv", 70)) == (6, 17)
-    assert cfg.counts_eva_keys and not cfg.counts_moe_load
+    assert [c.block for c in cfg.counters()] == ["eva"]
     assert llama.segment_keeps_tail(cfg)       # a ring and a summary tail
     cache = llama.init_decode_cache(cfg, 3, 64)
     assert {k: v.shape for k, v in cache[0].items() if k != "index"} == {
@@ -320,7 +320,7 @@ def test_the_description_is_what_the_constructors_read(adapter):
     # the kinds that hold one row a token say so through the same questions
     tiny = registry.get("llama-tiny").build().config
     assert tiny.cache_positions(64) == {"k": 64, "v": 64}
-    assert tiny.cache_slot("k", 70) == 70 and not tiny.counts_eva_keys
+    assert tiny.cache_slot("k", 70) == 70 and not tiny.counters()
     # int8 leaves the two learned vectors a head float32
     tree = jax.eval_shape(lambda: adapter.init_params(seed=0))["params"]
     assert tree["layer_0"]["adaptive_mu_k"].dtype == jnp.float32

@@ -246,28 +246,29 @@ def test_the_engine_serves_kda_and_latent_layers_and_counts_both(adapter,
     assert got == solo
     keys = {key[:1] + key[2:4] for key in server.buckets if key[0] == "seg_w"}
     assert ("seg_w", 128, 64) in keys
-    stats, counted = eng.stats(), eng.kda_stats.report()
+    stats, counted = eng.stats(), eng.counters["kda"].report()
     row_steps = stats["rows_in_segments"] * 8
     assert counted["row_steps"] == row_steps * 6
     assert counted["state_bytes"] == counted["row_steps"] * LAYER_BYTES
     # no Mosaic here: no layer-step's state went through the in-place kernel
-    assert counted["kernel_row_steps"] == 0 and not cfg.kda_steps_in_place
-    assert cfg.kda_step_bytes == LAYER_BYTES
-    assert cfg.state_bytes_a_step == 6 * LAYER_BYTES
+    assert counted["kernel_row_steps"] == 0 and not kda.steps_in_place(cfg)
+    assert kda.state_bytes_a_step(cfg) == LAYER_BYTES
+    assert kda.counters(cfg)[0].segment({}, 1, 1)["state_bytes"] \
+        == 6 * LAYER_BYTES
     # every prefill here was one row: a prompt bucket's chunks of 16, 6 layers
     assert counted["scan_chunks"] > 0 and counted["scan_chunks"] % 6 == 0
     # the routed FFN's load comes out of a segment whose entries differ by
     # layer: 6 routed layers x top-4 a booked row-step, a quarter held here
-    load = eng.moe_stats.report()
+    load = eng.counters["moe"].report()
     assert load["assignments"] == row_steps * 6 * 4
     assert len(load["load"]) == 16 and sum(load["load"]) == load["assignments"]
     assert load["local_assignments"] == sum(load["load"][4:8])
     assert 0 < load["local_assignments"] < load["assignments"]
     assert load["layer_steps"] == stats["segments_run"] * 8 * 6
     assert 0 < load["experts_read"] / load["layer_steps"] <= 4
-    before = eng.kda_stats.report()
+    before = eng.counters["kda"].report()
     eng.generate(prompts[1], max_new_tokens=24)
-    after = eng.kda_stats.report()
+    after = eng.counters["kda"].report()
     assert after["row_steps"] - before["row_steps"] == 24 * 6
     # a 40-token prompt prefills at 64 positions: 4 chunks of 16 a layer
     assert after["scan_chunks"] - before["scan_chunks"] == 4 * 6
@@ -291,8 +292,8 @@ def test_the_description_is_what_the_constructors_read(adapter):
     assert cfg.cache_dtypes(0) == {"state": jnp.float32, "conv": jnp.float32}
     assert (cfg.cache_slot("state", 70, 0), cfg.cache_slot("conv", 70, 0),
             cfg.cache_slot("ckv", 70, 6)) == (0, 0, 70)
-    assert cfg.kda_layers == 6 and cfg.counts_moe_load
-    assert not cfg.counts_sala_keys and not cfg.counts_dsa_keys
+    assert tuple(cfg.layer_kinds).count("kda") == 6
+    assert [c.block for c in cfg.counters()] == ["moe", "kda"]
     assert cfg.attn_output_gate and cfg.attn_gate_headwise
     assert cfg.moe_held == (4, 4) and cfg.moe_n_group == 4
     # the kinds that are one a model answer the same questions as before
@@ -326,14 +327,14 @@ def test_the_description_is_what_the_constructors_read(adapter):
     "kda_chunk", "pipeline"])
 def test_a_holder_that_cannot_take_the_kind_names_it(holder, adapter, params):
     """Every holder that cuts a cache along one position axis refuses the
-    model in ``kda.refusal``'s words (through ``ATTN_KIND_MODULES``), or in
+    model in ``kda.refusal``'s words (through ``ATTN_KINDS``), or in
     its own where the layout never reaches it."""
     from lambdipy_tpu.runtime import kvwire
     from lambdipy_tpu.runtime.offload import OffloadArena
     from lambdipy_tpu.runtime.prefixstore import PrefixStore
 
     cfg = adapter.config
-    assert "kda" in llama.ATTN_KIND_MODULES
+    assert llama.attn_kind_module("kda") is kda
     block = [{name: np.zeros((1, 16, heads, width), np.float32)
               for name, (heads, width) in cfg.cache_layout(layer).items()}
              for layer in range(cfg.layers)]
@@ -353,12 +354,16 @@ def test_a_holder_that_cannot_take_the_kind_names_it(holder, adapter, params):
         return adapter.make_server(params)
 
     kind = "a kda layer, whose cache is a gated delta-rule state"
+
+    def kinds_words():    # what every refusal above is made of
+        raise NotImplementedError(
+            llama.attn_kind_module("kda").refusal(cfg, "X"))
+
     calls = {
         "require_kv_cache": (lambda: llama.require_kv_cache(cfg, "X"), kind),
         "require_row_a_token": (
             lambda: llama.require_row_a_token(cfg, "X"), kind),
-        "refuse_kind_modules": (
-            lambda: llama._refuse_kind_modules(cfg, "X"), kind),
+        "refuse_kind_modules": (kinds_words, kind),
         "kvwire": (lambda: kvwire.encode_frame(list(range(16)), 16, [block]),
                    "kvwire"),
         "mesh": (lambda: llama.validate_serving_mesh(cfg, Mesh()),

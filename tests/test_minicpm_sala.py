@@ -282,20 +282,20 @@ def test_the_engine_serves_a_cache_whose_leaves_differ_by_layer(adapter,
     assert got == solo
     keys = {key[:1] + key[2:4] for key in server.buckets if key[0] == "seg_w"}
     assert ("seg_w", 128, 64) in keys
-    stats, sala = eng.stats(), eng.sala_stats.report()
+    stats, sala = eng.stats(), eng.counters["sala"].report()
     assert sala["row_steps"] == stats["rows_in_segments"] * 8
     assert sala["state_bytes"] == sala["row_steps"] * 4 * 2 * 4 * 8 * 16 * 16
     # no Mosaic here: no row-step's states went through the in-place kernel
-    assert sala["kernel_row_steps"] == 0 and not cfg.linear_steps_in_place
+    assert sala["kernel_row_steps"] == 0 and not linear_attn.steps_in_place(cfg)
     assert 0 < sala["dense_steps"] < sala["row_steps"]
     # a row writes a compressed key every second step
     assert abs(2 * sala["kc_writes"] - sala["row_steps"]) <= 2 * len(prompts)
     assert sala["keys_attended"] < sala["keys_visible"]
     # by hand, one more request alone: 24 steps from position 40 (past
     # dense_len: 4 blocks of 8, the last one as far as the step has come)
-    before = eng.sala_stats.report()
+    before = eng.counters["sala"].report()
     eng.generate(prompts[1], max_new_tokens=24)
-    after = eng.sala_stats.report()
+    after = eng.counters["sala"].report()
     assert after["row_steps"] - before["row_steps"] == 24
     assert after["keys_attended"] - before["keys_attended"] == sum(
         3 * 8 + t % 8 + 1 for t in range(40, 64))
@@ -321,8 +321,9 @@ def test_the_description_is_what_the_constructors_read(adapter):
     assert cfg.cache_dtypes(1) == {"state": jnp.float32}
     assert (cfg.cache_slot("k", 70, 0), cfg.cache_slot("kc", 70, 0),
             cfg.cache_slot("state", 70, 1)) == (70, 35, 0)
-    assert cfg.counts_sala_keys and not cfg.counts_dsa_keys
-    assert cfg.state_bytes_a_step == 4 * 2 * 4 * 8 * 16 * 16
+    assert [c.block for c in cfg.counters()] == ["sala", "sala"]
+    assert linear_attn.counters(cfg)[0].segment({}, 1, 1)["state_bytes"] \
+        == 4 * 2 * 4 * 8 * 16 * 16
     assert (cfg.embed_scale, cfg.logit_divisor) == (12.0, 4.0)
     assert cfg.residual_scale == pytest.approx(1.4 / 6 ** 0.5)
     # past two blocks of 4096 a prompt prefills at whole blocks
@@ -332,7 +333,7 @@ def test_the_description_is_what_the_constructors_read(adapter):
     # the kinds that are one a model answer the same questions as before
     tiny = registry.get("llama-tiny").build().config
     assert tiny.cache_positions(64) == {"k": 64, "v": 64}
-    assert not tiny.layer_kinds and not tiny.counts_sala_keys
+    assert not tiny.layer_kinds and not tiny.counters()
     assert tiny.prompt_bucket(9000, 16) == 16384
     tree = jax.eval_shape(lambda: adapter.init_params(seed=0))["params"]
     assert set(tree["layer_0"]) == {

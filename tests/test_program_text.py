@@ -36,7 +36,7 @@ eva path alone.
 Generation g6 (PR 34) moved TWO of those four and nothing else: the eva
 model's full-window and 4096-bucket segment programs keep ring and
 summaries read-only inside their scan (a ring tail and a summary tail,
-``llama.LlamaBlock._eva_tail_attend``). The two solo prefills and every
+``eva._eva_tail_attend`` since PR 44). The two solo prefills and every
 llama, ``mistral7b`` and ``deepseek7b`` hash are what they were.
 
 Generation g7 (PR 38) moved NONE of them: it changed the set of NAMES (a
@@ -80,7 +80,7 @@ another text, which ``tests/test_chip_compile.py`` compiles.
 
 Generation g9 (PR 43: a sparse prefill runs, a key block of 2048, only the
 turns of queries that begin before the longest row's last token:
-``LlamaBlock._sparse_prefill_attend``) moved ONE hash of all those held
+``latent._sparse_prefill_attend`` since PR 44) moved ONE hash of all those held
 here: ``deepseek-v32-exp``'s solo prefill. Its two segments, and every
 program of the seven other configurations, keep their parents' hashes:
 nobody else runs the changed function, and the ``latent`` kind's dense

@@ -16,7 +16,7 @@ layout: the tail's arithmetic does not depend on the rule that picks it).
 
 An eva cache (a ring beside chunk summaries, PR 34) keeps a tail of its
 own: the segment's rows AND the summaries they complete, a merge that wraps
-round the ring (``LlamaBlock._eva_tail_attend``, ``llama._eva_tail_merge``).
+round the ring (``eva._eva_tail_attend``, ``eva.tail_merge``).
 Its cases are at the end: the toy twin of ``tests/test_evabyte.py`` (window
 32) with chunks of 4, where a 16-step segment completes several, and of 16,
 where it completes one, rows placed on every side of a window's edge.
@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from lambdipy_tpu.models import eva as eva_kind
 from lambdipy_tpu.models import llama, registry
 
 B, SB, CACHE_LEN, WINDOW, SEGMENT = 4, 32, 128, 64, 16
@@ -508,13 +509,13 @@ def test_an_eva_step_reads_no_tail_slot_before_its_step_wrote_it(eva):
     model = eva.adapter.make_server(eva.params).model
     carry, _, _ = eva.carry()
     first, _, cache, pos, _, _ = carry
-    tails = llama._eva_tail_init(cfg, cache, pos, SEGMENT)
+    tails = eva_kind.tail_init(cfg, cache, pos, SEGMENT)
     n_sum = tails[0]["sk"].shape[1]
     assert n_sum == -(-SEGMENT // chunk)
     assert tails[0]["k"].shape[1] == (1 + n_sum) * chunk
 
     def step(tails, j, tok):
-        plan = llama._eva_tail_plan(cfg, cache[0], tails[0], pos, jnp.int32(j))
+        plan = eva_kind.tail_plan(cfg, cache[0], tails[0], pos, jnp.int32(j))
         entries = [{**entry, "index": pos, "tail": t, "plan": plan}
                    for entry, t in zip(cache, tails)]
         return model.apply(eva.params, tok[:, None],
